@@ -16,9 +16,9 @@ Python/C++.
 __version__ = "0.1.0"
 
 # The top-level conveniences resolve lazily (PEP 562): the network classes
-# pull in jax, and control-plane consumers — bench.py's pre-probe telemetry
-# import, __graft_entry__'s dryrun parent — must be able to import
-# ``deeplearning4j_tpu.monitor`` (stdlib-only) BEFORE any jax/backend
+# pull in jax, and control-plane consumers (scripts/, a parent that must
+# leave the chip to its child) must be able to import
+# ``deeplearning4j_tpu.monitor`` (stdlib-only) WITHOUT any jax/backend
 # initialization. ``from deeplearning4j_tpu import MultiLayerNetwork`` is
 # unchanged for users.
 _LAZY_ATTRS = {

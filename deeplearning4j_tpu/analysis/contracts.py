@@ -75,22 +75,10 @@ COLLECTIVE_PRIMITIVES = frozenset({
 # ---------------------------------------------------------------------------
 
 
-def _jax_core():
-    """jax.extend.core moved ClosedJaxpr/Jaxpr out of jax.core (which
-    deprecates them from 0.4.36 and drops them later); prefer the
-    stable home, fall back for older jax."""
-    try:
-        from jax.extend import core as jcore
-        jcore.ClosedJaxpr  # noqa: B018 — probe the moved symbol
-    except (ImportError, AttributeError):
-        import jax.core as jcore
-    return jcore
-
-
 def _iter_eqns(jaxpr):
     """Every equation in ``jaxpr``, recursing through call/control-flow
     sub-jaxprs (scan bodies, cond branches, pjit calls, shard_map...)."""
-    jcore = _jax_core()
+    from jax.extend import core as jcore
 
     seen = set()
     stack = [jaxpr]
@@ -108,7 +96,7 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(val):
-    jcore = _jax_core()
+    from jax.extend import core as jcore
 
     if isinstance(val, (jcore.Jaxpr, jcore.ClosedJaxpr)):
         return [val]

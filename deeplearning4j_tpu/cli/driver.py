@@ -356,4 +356,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     return args.fn(args)

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
+from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.ops.attention import (
     dot_product_attention,
     grouped_query_attention,
@@ -397,6 +398,7 @@ class TransformerLM:
 
     def make_train_step(self, *, mesh: Optional[Mesh] = None,
                         sequence_parallel: bool = False, donate: bool = True):
+        ensure_compile_cache()
         step = self._step_body(mesh=mesh, sequence_parallel=sequence_parallel)
         return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
@@ -406,6 +408,7 @@ class TransformerLM:
         """K optimizer steps fused into ONE XLA program (``lax.scan`` over
         the shared step body): one host dispatch + one token transfer per
         K steps, isolating the chip from the per-dispatch floor."""
+        ensure_compile_cache()
         step = self._step_body(mesh=mesh, sequence_parallel=sequence_parallel)
 
         def multi(params, opt_state, tokens, step_count):

@@ -514,7 +514,7 @@ def classify_end_state(records: List[dict],
       evidence (watchdog stall / grant watchdog) follows the last
       progress record, or heartbeats kept arriving for longer than
       ``wedge_factor × interval`` after progress stopped — the process
-      was alive but stuck (the BENCH_r04/r05 grant-wedge shape).
+      was alive but stuck.
     - ``crashed``   — records stop abruptly (heartbeats die with the
       progress), or the run closed with an error status: the process
       (or the program) died mid-work.
@@ -561,8 +561,8 @@ def classify_end_state(records: List[dict],
     # an orderly ending needs positive evidence: either a run actually
     # closed (run.end) with nothing started after it, or the recorder
     # itself closed with nothing in flight. A timeline with NO run and
-    # no close — the BENCH_r04/r05 shape, where the grant wedges before
-    # any section starts — falls through to the stuck-or-dead analysis.
+    # no close — an acquisition that blocks before any run starts —
+    # falls through to the stuck-or-dead analysis.
     orderly = (open_run is None
                and (last_close is not None
                     or last_progress.get("kind") == "flight.close"))

@@ -24,7 +24,7 @@ states by priority:
 | ``watchdog_stall`` | ``watchdog.stall`` events (interval re-derived from ``stalled_s``) |
 | ``preemption_recovery`` | ``checkpoint.resume`` |
 | ``reshard`` | ``reshard.elastic`` — the chunk-boundary device snapshot → respec → continue of a mid-run mesh grow/shrink |
-| ``grant_wait`` | ``grant.probe`` / ``grant.acquire`` / ``grant.reacquire`` / ``grant.backoff`` / ``grant.subprocess`` — including every lease re-acquire cycle, so a rescued wedge is booked as grant badput instead of a lost round |
+| ``grant_wait`` | ``grant.acquire`` / ``grant.reacquire`` / ``grant.backoff`` — including every lease re-acquire cycle, so a rescued wedge is booked as grant badput instead of a lost round |
 | ``idle`` | outside any run window and any classified span |
 
 Goodput % is ``compute / (window − idle)``; the badput breakdown is the
@@ -61,11 +61,9 @@ BADPUT_SPAN_STATES = {
     "checkpoint.resume": "preemption_recovery",
     "retry.sleep": "retry_backoff",
     "reshard.elastic": "reshard",
-    "grant.probe": "grant_wait",
     "grant.acquire": "grant_wait",
     "grant.reacquire": "grant_wait",
     "grant.backoff": "grant_wait",
-    "grant.subprocess": "grant_wait",
 }
 
 #: overlap resolution: a second covered by several intervals takes the

@@ -198,15 +198,12 @@ def _signature_of(args) -> Tuple:
 
 def _harvest_cost(compiled, profile: ProgramProfile) -> None:
     """``compiled.cost_analysis()`` → FLOPs / bytes-accessed / optimal
-    seconds (a list of per-partition dicts on some jax versions, a dict
-    on others; missing keys stay None)."""
+    seconds (missing keys stay None)."""
     try:
         ca = compiled.cost_analysis()
     except Exception as e:  # backend without cost analysis
         profile.error = f"cost_analysis: {type(e).__name__}: {e}"[:200]
         return
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not ca:
         return
     profile.flops = _maybe_float(ca.get("flops"))

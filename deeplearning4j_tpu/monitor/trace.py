@@ -1,7 +1,7 @@
 """Span tracer: timestamped, nested spans over the training control plane.
 
-The fused pipeline's failure mode is a TIMELINE problem: a wedged device
-grant (BENCH_r04/r05) or a stalled chunk leaves a bare error line with no
+The fused pipeline's failure mode is a TIMELINE problem: a blocked
+backend acquisition or a stalled chunk leaves a bare error line with no
 record of what the host was doing or for how long. Spans fix that: every
 interesting host-side operation — chunk dispatch, sentinel readback, cache
 build, checkpoint save/verify, backend/grant acquisition, retry sleeps —

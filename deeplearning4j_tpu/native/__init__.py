@@ -6,14 +6,18 @@ with JAX); this module is the native *host* layer: record parsing and
 read-ahead streaming off the Python heap.
 
 The shared library is compiled on first use with g++ (no pybind11 in the
-image; plain C ABI + ctypes) and cached next to this file. Every entry
-point has a pure-Python fallback — ``is_available()`` is advisory, and
-callers degrade gracefully when the toolchain is missing.
+image; plain C ABI + ctypes) next to this file, under a name that carries a
+hash of the committed source — a library built from any other source is
+never loaded. Every entry point has a pure-Python fallback for machines
+without the toolchain — ``is_available()`` is advisory. With g++ present a
+failed build raises: the Python path is not a silent substitute for a
+broken native one.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,29 +28,48 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(os.path.dirname(os.path.dirname(_HERE)),
                     "native", "dl4j_host.cpp")
-_SO = os.path.join(_HERE, "_dl4j_host.so")
+_SO_PREFIX = "_dl4j_host-"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """Where the library built from the source AS COMMITTED lives: the
+    name carries the source's content hash, so staleness never depends on
+    mtimes (which a copy or checkout resets)."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"{_SO_PREFIX}{digest}.so")
+
+
+def _build(so: str) -> bool:
+    """Compile ``_SRC`` to ``so``. False when there is no g++ (callers
+    take the Python path); a compile that fails raises."""
     # compile to a private temp path, then atomically publish: concurrent
     # processes (multi-host launcher workers) must never dlopen a torn .so
-    tmp = f"{_SO}.build-{os.getpid()}"
+    tmp = f"{so}.build-{os.getpid()}"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-        os.replace(tmp, _SO)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError):
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
+        os.replace(tmp, so)
+    except FileNotFoundError:
         return False
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building {_SRC} failed (rc={e.returncode}):\n"
+            f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    # libraries of other source revisions are dead weight
+    for name in os.listdir(_HERE):
+        if (name.startswith(_SO_PREFIX) and name.endswith(".so")
+                and os.path.join(_HERE, name) != so):
+            os.unlink(os.path.join(_HERE, name))
+    return True
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -83,17 +106,14 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not os.path.exists(_SRC) or not _build():
-                _load_failed = True
-                return None
-        try:
-            _lib = _bind(ctypes.CDLL(_SO))
-        except OSError:
+        if not os.path.exists(_SRC):
             _load_failed = True
             return None
+        so = _so_path()
+        if not os.path.exists(so) and not _build(so):
+            _load_failed = True
+            return None
+        _lib = _bind(ctypes.CDLL(so))
         return _lib
 
 

@@ -20,8 +20,8 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning4j_tpu.compat import shard_map
 
 from deeplearning4j_tpu.nlp.word2vec import Word2Vec, _neg_sampling_math
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS

@@ -52,10 +52,10 @@ from typing import List, Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deeplearning4j_tpu.analysis.annotations import traced
-from deeplearning4j_tpu.compat import shard_map
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS
 from deeplearning4j_tpu.perf.bucketing import bucket_size
 from deeplearning4j_tpu.perf.epoch_cache import (

@@ -532,8 +532,7 @@ class Word2Vec:
                     if len(c) < batch_size:
                         # wrap-around pad to the CONSTANT batch shape: one
                         # compiled program per fit (a ragged tail would
-                        # recompile — expensive on remote-compile TPU
-                        # backends); duplicate pairs collapse to a mean
+                        # recompile); duplicate pairs collapse to a mean
                         # under the per-row scaling, so padding only
                         # re-weights real pairs slightly
                         c = np.resize(c, batch_size)

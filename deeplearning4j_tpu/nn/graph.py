@@ -316,8 +316,8 @@ class ComputationGraph:
         ONE flattened sweep per (spec, lr, dtype) leaf group instead of
         a per-vertex Python loop (``grouped_apply_updaters``; bitwise
         the per-layer math); heterogeneously-sharded state (TP/FSDP
-        placements) takes the per-layer fallback — GSPMD miscompiles
-        the ravel→concat→slice chain over mixed shardings (see
+        placements) takes the per-layer apply — a concat over mixed
+        shardings would replicate every leaf on every chip (see
         ``flat_apply_safe``). Under the master-weights policy ``params``
         are the f32 masters and ``grads`` arrive already upcast."""
         scale = self._lr_scale(iteration, lr_scale_host)
@@ -772,8 +772,10 @@ class ComputationGraph:
         Falls back to the per-step loop for TBPTT and ``iterations >
         1``; over-budget datasets stream with N-deep async device
         prefetch."""
+        from deeplearning4j_tpu.compile_cache import ensure_compile_cache
         from deeplearning4j_tpu.resilience.guard import nan_guard_policy
 
+        ensure_compile_cache()
         self._ensure_init()
         if num_epochs <= 0:
             return None

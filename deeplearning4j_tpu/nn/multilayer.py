@@ -231,8 +231,8 @@ class MultiLayerNetwork:
         is a fused region whose updater-math op count does not scale
         with depth (``grouped_apply_updaters``; bitwise the per-layer
         math). Heterogeneously-sharded state (tensor-parallel / FSDP
-        placements) takes the per-layer fallback — GSPMD miscompiles the
-        ravel→concat→slice chain over mixed shardings (see
+        placements) takes the per-layer apply — a concat over mixed
+        shardings would replicate every leaf on every chip (see
         ``flat_apply_safe``); the trace-time gate reads the LIVE params'
         placements, consistent because jit re-traces per sharding.
         Under the master-weights policy ``params`` are the f32 masters
@@ -807,8 +807,10 @@ class MultiLayerNetwork:
         run the plain per-step loop; datasets over the HBM budget
         (``DL4J_DEVICE_CACHE_MB``) stream through an N-deep async device
         prefetch instead (``DL4J_PREFETCH_DEPTH``)."""
+        from deeplearning4j_tpu.compile_cache import ensure_compile_cache
         from deeplearning4j_tpu.resilience.guard import nan_guard_policy
 
+        ensure_compile_cache()
         self._ensure_init()
         if num_epochs <= 0:
             return None
@@ -1090,9 +1092,9 @@ class MultiLayerNetwork:
     # Every entry point pads the batch axis up the shape-bucket ladder
     # (perf/bucketing) before hitting its jitted program, so a stream of
     # ragged batch sizes compiles once per BUCKET, not once per shape —
-    # under remote compile a recompile costs seconds (PERF.md). Pad rows
-    # are row-independent through the forward pass and sliced off (output/
-    # predict) or masked out of the reduction (score/evaluate).
+    # a recompile costs seconds. Pad rows are row-independent through the
+    # forward pass and sliced off (output/predict) or masked out of the
+    # reduction (score/evaluate).
     # ------------------------------------------------------------------
     def output(self, x, train: bool = False):
         self._ensure_init()

@@ -260,16 +260,19 @@ def apply_updater(
 
 def flat_apply_safe(live_params) -> bool:
     """True when the live parameter leaves all carry the SAME placement,
-    making the flattened (concat) updater sweep safe to trace.
+    so the flattened (concat) updater sweep keeps that placement.
 
-    GSPMD miscompiles a ravel→concat→slice chain over leaves with
-    HETEROGENEOUS shardings (verified on jax 0.4.37: a 15-line
-    concat-of-(P(None,'model'), P('model'), P()) repro returns wrong
-    values under jit while eager is exact), so tensor-parallel and
-    FSDP-sharded state must take the per-layer apply instead. The
-    decision is made at TRACE time from the network's live (concrete)
-    params — consistent with the traced call because jit re-traces
-    whenever input shardings change."""
+    A ravel→concat→slice chain over leaves with HETEROGENEOUS shardings
+    (P(None,'model'), P('model'), P()) is exact on jax 0.9.0 — the wrong
+    values jax 0.4.37's GSPMD returned for it are gone — but the
+    partitioner can only build the concat by replicating every leaf
+    first (XLA logs "Involuntary full rematerialization" for it): each
+    chip would gather the full parameter set every step, which is the
+    memory tensor-parallel and FSDP placements exist to avoid. Such
+    state takes the per-layer apply instead. The decision is made at
+    TRACE time from the network's live (concrete) params — consistent
+    with the traced call because jit re-traces whenever input shardings
+    change."""
     shardings = set()
     for leaf in jax.tree_util.tree_leaves(live_params):
         s = getattr(leaf, "sharding", None)
@@ -287,9 +290,10 @@ def flat_apply_safe(live_params) -> bool:
 def per_layer_apply_updaters(items, params, updater_state, grads,
                              lr_scale, step_count):
     """The classic per-layer loop (one :func:`apply_updater` per layer)
-    — the sharding-agnostic fallback of :func:`grouped_apply_updaters`,
-    factored out of both network classes. Same math, L unrolled
-    copies."""
+    — the placement-preserving form of :func:`grouped_apply_updaters`
+    for state whose leaves are sharded differently (see
+    :func:`flat_apply_safe`), factored out of both network classes.
+    Same math, L unrolled copies."""
     new_params, new_updater = {}, {}
     for key, spec in items:
         steps_i, upd_i = apply_updater(

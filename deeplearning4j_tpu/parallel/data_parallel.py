@@ -310,6 +310,7 @@ class ParallelWrapper:
         the wrapper's step for MultiLayerNetwork, the network's own
         single-device fit for ComputationGraph, which does not speak the
         per-batch sharded-step protocol)."""
+        from deeplearning4j_tpu.compile_cache import ensure_compile_cache
         from deeplearning4j_tpu.monitor import fused_metrics_stride
         from deeplearning4j_tpu.perf.epoch_cache import (
             DeviceDataSetCache, DeviceMultiDataSetCache,
@@ -317,6 +318,7 @@ class ParallelWrapper:
             stream_epochs)
         from deeplearning4j_tpu.resilience.guard import nan_guard_policy
 
+        ensure_compile_cache()
         net = self.network
         net._ensure_init()
         if num_epochs <= 0:

@@ -31,9 +31,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.compat import shard_map
 
 from deeplearning4j_tpu.ops.attention import NEG_INF, causal_band_mask
 from deeplearning4j_tpu.parallel.mesh import SEQUENCE_AXIS

@@ -23,9 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from deeplearning4j_tpu.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.ops.attention import NEG_INF, causal_band_mask

@@ -22,9 +22,9 @@ package instead of hand-rolling sleeps and bare ``except`` clauses:
   latches SIGTERM / injected ``preempt.chunk`` faults so fused training
   checkpoints and stops at a chunk boundary instead of dying mid-run.
 - :mod:`~deeplearning4j_tpu.resilience.lease` — ``GrantLease`` bounded
-  watchdog around every backend acquisition (bench probe, dryrun child,
-  serve replica warm-up): a wedged grant releases and re-acquires under
-  escalating backoff instead of recording an error line and dying.
+  watchdog around a backend acquisition that may block (serve replica
+  warm-up): a wedged attempt releases and re-acquires under escalating
+  backoff instead of recording an error line and dying.
 - :mod:`~deeplearning4j_tpu.resilience.autopilot` —
   ``GoodputAutopilot`` closes the observe→act loop over the PR-9 fleet
   gauges: goodput below floor / straggler flagged / heartbeat silence

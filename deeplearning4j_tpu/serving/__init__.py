@@ -24,9 +24,6 @@ serving/training split applied to the fused-program framework:
   the continuous-batching loop: admit into free slots at step
   boundaries, one batched decode step, retire finished sequences; never
   recompiles past one program per (slot-count, prefill-bucket).
-- :mod:`~deeplearning4j_tpu.serving.compile_cache` — persisted XLA
-  compilation cache (``DL4J_COMPILE_CACHE_DIR``) so fleet cold-start
-  replays compiles from disk.
 - :mod:`~deeplearning4j_tpu.serving.loadgen` — open-loop Poisson load
   generator + p50/p99/TTFT/TPOT report with per-drop timestamps (the
   ``serve`` bench section).
@@ -40,11 +37,6 @@ See ``docs/inference.md`` §Serving for the architecture and the slot
 lifecycle, ``docs/observability.md`` for the serve metric/span taxonomy.
 """
 
-from deeplearning4j_tpu.serving.compile_cache import (  # noqa: F401
-    compile_cache_dir,
-    compile_cache_stats,
-    ensure_compile_cache,
-)
 from deeplearning4j_tpu.serving.kv_cache import (  # noqa: F401
     SlotKVCache,
     kv_pool_nbytes,
@@ -86,8 +78,7 @@ __all__ = [
     "AdmissionVerdict", "Arrival", "CRITICALITIES", "DecodeEngine",
     "DecodeServer", "LoadReport", "RequestQueue", "RetryBudget",
     "ServeQueueFull", "ServeRequest", "SlotKVCache",
-    "compile_cache_dir", "compile_cache_stats", "criticality_rank",
-    "ensure_compile_cache", "kv_pool_nbytes", "max_slots_in_budget",
+    "criticality_rank", "kv_pool_nbytes", "max_slots_in_budget",
     "poisson_schedule", "request_cost", "resolve_kv_dtype",
     "run_open_loop", "serve_deadline_s", "serve_draft_layers",
     "serve_evict_s", "serve_fuse_steps", "serve_hedge_s",

@@ -59,9 +59,9 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.analysis.annotations import traced
+from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
-from deeplearning4j_tpu.serving.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.serving.kv_cache import (
     SlotKVCache, dequant_slab, requant_write_slab)
 
@@ -489,6 +489,9 @@ class DecodeEngine:
         if top_k is not None and not 1 <= top_k <= model.vocab_size:
             raise ValueError(
                 f"top_k={top_k} must be in [1, vocab={model.vocab_size}]")
+        # a cold replica replays its program set from the persistent
+        # compilation cache instead of paying XLA again
+        ensure_compile_cache()
         model._ensure_init()
         self.model = model
         # ``mesh=`` serves tensor-parallel: the model's sharding registry
@@ -559,9 +562,6 @@ class DecodeEngine:
             self.draft_cache = SlotKVCache(
                 self.draft_model, self.slots, self.max_len, kv_dtype,
                 registry=draft_reg)
-        # the fleet story: point jax's persistent compilation cache at
-        # DL4J_COMPILE_CACHE_DIR before this engine's first compile
-        ensure_compile_cache()
 
     @property
     def spec(self) -> bool:

@@ -316,8 +316,7 @@ class ServeReplica:
     def wedge(self) -> None:
         """Test/bench hook: alive-but-stuck — the loop stops making
         progress AND the beats stop, but the dead flag stays down, so
-        only heartbeat-silence-past-timeout can catch it (the wedged-
-        grant failure shape from BENCH_r04/r05, serve-side)."""
+        only heartbeat-silence-past-timeout can catch it."""
         if self._stop is not None:
             self._stop.set()
         if self.monitor is not None:

@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Bench-trajectory report: round-over-round table + regression gate.
 
-The BENCH_r01..r05 trajectory degraded silently: rounds 4-5 recorded
-wedged-grant error lines and nothing machine-readable ever diffed one
-round against the last honest one. This reads every ``BENCH_r*.json``
+Nothing machine-readable diffs one bench round against the last honest
+one unless a tool does it. This reads every ``BENCH_r*.json``
 (the driver sidecar shape ``{n, rc, tail, parsed}``; bare result lines
 ``{metric, value, extras}`` are accepted too, so synthetic fixtures and
 fresh ``bench.py`` output both feed it), classifies each round —

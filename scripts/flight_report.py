@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Flight-recorder postmortem: reconstruct a dead run's final timeline.
 
-Rounds r04/r05 died to wedged device grants leaving one error line and no
-record of what the process was doing. With ``DL4J_FLIGHT`` on, the flight
+A run that dies mid-work leaves at best one error line and no record of
+what the process was doing. With ``DL4J_FLIGHT`` on, the flight
 recorder (``deeplearning4j_tpu/monitor/flight.py``) leaves a bounded
 segment ring on disk that survives SIGKILL; this script reads whatever
 segments survived, prints the final timeline, and classifies the end
@@ -14,8 +14,7 @@ state:
   latch
 - ``wedged``    — the process was ALIVE but stuck: writer heartbeats
   kept arriving long after the last progress record, or explicit wedge
-  evidence (grant watchdog, chunk stall) ends the timeline — the
-  BENCH_r04/r05 grant-wedge shape
+  evidence (grant watchdog, chunk stall) ends the timeline
 - ``crashed``   — records stop abruptly (the heartbeats died with the
   progress): SIGKILL, OOM, segfault
 - ``reacquired`` — clean-with-recovery: the run finished, but the

@@ -60,10 +60,7 @@
 #                                      exit on any NEW finding)
 #        scripts/verify.sh --profile  (performance observatory: the
 #                                      ProgramProfile/HBM-watermark
-#                                      suite + bench_report.py --check
-#                                      over the committed BENCH_r*.json
-#                                      trajectory; nonzero exit on a
-#                                      bench regression)
+#                                      suite)
 #        scripts/verify.sh --autopilot (always-on fleet: the grant-lease
 #                                      protocol, elastic mid-run reshard
 #                                      equivalence, goodput-autopilot
@@ -98,10 +95,9 @@
 #                                      totality, fused-epoch parity,
 #                                      topology reshard, TP serving —
 #                                      plus the TP/PP parallel suites,
-#                                      the adhoc-out-shardings lint
-#                                      (every placement decision routes
-#                                      through the registry) and the
-#                                      bench trajectory check)
+#                                      and the adhoc-out-shardings
+#                                      lint (every placement decision
+#                                      routes through the registry))
 # The eval/epoch/dp/heal/obs/serve/fleet/serve-slo/lint/profile/mfu/
 # mesh tests are part of the default tier-1 run; --eval/--epoch/--dp/
 # --heal/--obs/--serve/--fleet/--serve-slo/--lint/--profile/--mfu/
@@ -171,10 +167,6 @@ elif [ "${1:-}" = "--lint" ]; then
 elif [ "${1:-}" = "--profile" ]; then
     shift
     TARGET=tests/test_profile.py
-    # the trajectory gate rides along: the committed BENCH artifacts
-    # must show no silent round-over-round regression (wedge/error
-    # rounds are called out but never scored)
-    python scripts/bench_report.py --check BENCH_r*.json || exit 1
 elif [ "${1:-}" = "--autopilot" ]; then
     shift
     TARGET=tests/test_autopilot.py
@@ -209,9 +201,6 @@ elif [ "${1:-}" = "--mesh" ]; then
     # out_shardings= pins belong in parallel/sharding_registry.py (or
     # carry a per-site suppression naming the sanctioned builder)
     python scripts/dl4j_lint.py --select adhoc-out-shardings || exit 1
-    # the mesh_sweep TRACKED series (tp step time, per-chip HBM) gate
-    # the committed trajectory like every other bench series
-    python scripts/bench_report.py --check BENCH_r*.json || exit 1
 fi
 
 rm -f /tmp/_t1.log

@@ -406,11 +406,12 @@ class TestFusedUpdaterSweep:
                 == jax.tree_util.tree_structure(ref_p))
 
     def test_tp_sharded_state_takes_the_per_layer_fallback(self):
-        """GSPMD miscompiles the ravel→concat→slice chain over leaves
-        with MIXED shardings (verified on jax 0.4.37) — the flat sweep
-        must refuse tensor-parallel placements and fall back to the
-        per-layer apply. End-to-end: a TP-sharded per-step fit matches
-        the unsharded reference (the pre-PR-14 test_parallel contract)."""
+        """A concat over leaves with MIXED shardings makes the
+        partitioner replicate every leaf (exact on jax 0.9.0, but each
+        chip gathers the whole parameter set) — the flat sweep must
+        refuse tensor-parallel placements and take the per-layer apply.
+        End-to-end: a TP-sharded per-step fit matches the unsharded
+        reference (the pre-PR-14 test_parallel contract)."""
         from deeplearning4j_tpu.datasets.dataset import DataSet
         from deeplearning4j_tpu.nn.updater import flat_apply_safe
         from deeplearning4j_tpu.parallel import MeshSpec, build_mesh

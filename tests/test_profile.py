@@ -483,19 +483,20 @@ class TestProfileReadbackLint:
 # ---------------------------------------------------------------------------
 
 
-class TestBenchProfileFlush:
-    def _load_bench(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench_under_test", os.path.join(REPO, "bench.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+def _load_bench():
+    spec = importlib.util.spec_from_file_location(
+        "bench_under_test", os.path.join(REPO, "bench.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
+
+class TestBenchProfileFlush:
     def test_error_path_flushes_collected_profiles(self):
         """The PR-6 partial-flush hardening extends to profile data: an
         error-path artifact still carries every ProgramProfile captured
         before the wedge, beside the telemetry block."""
-        bench = self._load_bench()
+        bench = _load_bench()
         f = jax.jit(lambda a: a * 3.0)
         capture_program_profile(f, (jnp.ones(16),), name="pre_wedge")
         extras = {"error": "backend unavailable: wedged device grant"}
@@ -506,7 +507,7 @@ class TestBenchProfileFlush:
         json.dumps(extras)  # artifact stays JSON-serializable
 
     def test_flops_entry_and_divergence_flag(self):
-        bench = self._load_bench()
+        bench = _load_bench()
         f = jax.jit(lambda a, b: a @ b)
         a = jnp.ones((64, 64), jnp.float32)
         prof, _ = capture_program_profile(f, (a, a), name="gemm64")
@@ -518,6 +519,53 @@ class TestBenchProfileFlush:
         # an off-by-2x analytic formula trips the flag
         entry2 = bench._flops_entry(4.0 * 64 ** 3, "4n^3", prof, 1)
         assert entry2["flops_divergence_flag"] is True
+
+
+class TestBenchFailuresAreLoud:
+    """bench.py measures a TPU or fails: no CPU under a device metric's
+    name, no default peaks, no failed section behind rc=0."""
+
+    def test_unknown_device_kind_is_an_error(self):
+        bench = _load_bench()
+        with pytest.raises(ValueError, match="no peak"):
+            bench._peaks_for("TPU v99 imaginary")
+        assert bench._peaks_for("TPU v5 lite")["source"]
+
+    def test_no_tpu_exits_nonzero_in_process(self):
+        bench = _load_bench()
+        with pytest.raises(SystemExit) as e:
+            bench._device_stamp()
+        assert e.value.code not in (0, None)
+
+    def test_raising_section_makes_exit_code_nonzero(
+            self, tmp_path, monkeypatch, capsys):
+        bench = _load_bench()
+        monkeypatch.chdir(tmp_path)  # bench_partial.json lands here
+        monkeypatch.setenv("BENCH_ONLY", "gemm")
+        monkeypatch.setenv("DL4J_PROFILE", "0")
+        monkeypatch.setattr(bench, "_device_stamp", lambda: {
+            "platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+        monkeypatch.setattr(bench, "_peaks",
+                            lambda: bench.PEAKS["TPU v5 lite"])
+        monkeypatch.setattr(
+            bench, "bench_transformer",
+            lambda on_progress=None: {"tokens_per_sec": 1.0})
+
+        def boom():
+            raise RuntimeError("section blew up")
+
+        monkeypatch.setattr(bench, "bench_gemm", boom)
+        assert bench.main() == 1
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert line["value"] == 1.0  # later sections still ran
+        assert line["extras"]["gemm"]["error"] == "section blew up"
+        assert line["extras"]["failed"] == ["gemm"]
+        assert line["extras"]["device"]["kind"] == "TPU v5 lite"
+
+        monkeypatch.setattr(bench, "bench_gemm", lambda: {"tflops": 1.0})
+        assert bench.main() == 0
+        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert "failed" not in line["extras"]
 
 
 # ---------------------------------------------------------------------------
@@ -653,18 +701,6 @@ class TestBenchReport:
                              "train_step_bf16_speedup": 1.0}}),
         ]
         assert bench_report.main(["--check"] + files) == 1
-
-    def test_committed_trajectory(self, capsys):
-        """The real BENCH_r01-r05 artifacts: rounds 4-5 flag as wedge
-        rounds, round 2 as an error round, and the gate passes (the two
-        honest rounds have disjoint metrics)."""
-        files = [os.path.join(REPO, f"BENCH_r0{i}.json")
-                 for i in range(1, 6)]
-        rc = bench_report.main(["--check"] + files)
-        out = capsys.readouterr().out
-        assert rc == 0
-        assert out.count("WEDGE") >= 2
-        assert "r04" in out and "r05" in out
 
     def test_load_error_exit_code(self, tmp_path, capsys):
         missing = str(tmp_path / "BENCH_r99.json")

@@ -22,18 +22,14 @@ import os
 import numpy as np
 import pytest
 
-import jax
-
 from deeplearning4j_tpu.models.transformer import TransformerLM
 from deeplearning4j_tpu.monitor import metrics, set_tracer, SpanTracer
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
 from deeplearning4j_tpu.serving import (
-    DecodeServer, ServeQueueFull, SlotKVCache, compile_cache_stats,
-    ensure_compile_cache, kv_pool_nbytes, max_slots_in_budget,
-    poisson_schedule, run_open_loop, serve_draft_layers,
-    serve_fuse_steps, serve_max_queue, serve_slots)
-from deeplearning4j_tpu.serving import compile_cache as compile_cache_mod
+    DecodeServer, ServeQueueFull, SlotKVCache, kv_pool_nbytes,
+    max_slots_in_budget, poisson_schedule, run_open_loop,
+    serve_draft_layers, serve_fuse_steps, serve_max_queue, serve_slots)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -327,35 +323,6 @@ class TestContinuousBatching:
         monkeypatch.delenv("DL4J_SERVE_MAX_QUEUE")
         assert serve_slots() == 8
         assert serve_max_queue() == 64
-
-
-# ---------------------------------------------------------------------------
-# persisted XLA compilation cache
-# ---------------------------------------------------------------------------
-class TestCompileCache:
-    def test_lazy_configuration(self, tmp_path, monkeypatch):
-        prev = jax.config.jax_compilation_cache_dir
-        d = str(tmp_path / "xla-cache")
-        monkeypatch.setenv("DL4J_COMPILE_CACHE_DIR", d)
-        compile_cache_mod._reset_for_tests()
-        try:
-            assert ensure_compile_cache() == d
-            assert jax.config.jax_compilation_cache_dir == d
-            assert os.path.isdir(d)
-            stats = compile_cache_stats()
-            assert stats["dir"] == d and stats["configured"]
-            # idempotent: second call is a no-op, same answer
-            assert ensure_compile_cache() == d
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-            compile_cache_mod._reset_for_tests()
-
-    def test_unset_env_is_a_noop(self, monkeypatch):
-        monkeypatch.delenv("DL4J_COMPILE_CACHE_DIR", raising=False)
-        compile_cache_mod._reset_for_tests()
-        assert ensure_compile_cache() is None
-        assert compile_cache_stats() == {
-            "dir": None, "configured": False, "entries": 0, "bytes": 0}
 
 
 # ---------------------------------------------------------------------------

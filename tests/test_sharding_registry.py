@@ -419,7 +419,7 @@ class TestRegistryContracts:
         flag it."""
         from deeplearning4j_tpu.analysis.contracts import (
             check_network_contracts)
-        from deeplearning4j_tpu.compat import shard_map
+        from jax import shard_map
 
         net = _ff_net(seed=5)
         mesh = build_mesh(DPTP)
@@ -438,9 +438,12 @@ class TestRegistryContracts:
                     lambda v: jax.lax.psum(v, MODEL_AXIS),
                     lambda v: v, x)
 
+            # check_vma=False: jax 0.9.0's varying-axes typing rejects
+            # this deliberately sloppy cond before the checker sees it
             leak = shard_map(body, mesh=mesh,
                              in_specs=P(DATA_AXIS, MODEL_AXIS),
-                             out_specs=P(DATA_AXIS, MODEL_AXIS))(
+                             out_specs=P(DATA_AXIS, MODEL_AXIS),
+                             check_vma=False)(
                                  jnp.ones((2, 4), jnp.float32))
             return out[:3] + (out[3] + jnp.sum(leak) * 0.0,) + out[4:]
 

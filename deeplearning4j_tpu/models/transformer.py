@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+from deeplearning4j_tpu.monitor import tracer
 from deeplearning4j_tpu.ops.attention import (
     dot_product_attention,
     grouped_query_attention,
@@ -425,15 +426,23 @@ class TransformerLM:
 
     def fit_batch(self, tokens, train_step=None, block: bool = True):
         """``block=False`` returns the on-device loss scalar without a
-        host round-trip, letting steps pipeline (read it when needed)."""
+        host round-trip, letting steps pipeline (read it when needed).
+
+        Spans: ``train.step`` (``step``) from the step program's dispatch
+        to the return, and inside it, with ``block=True`` only,
+        ``train.sync`` round the wait for the loss."""
         if self.params is None:
             self.init()
         train_step = train_step or self._default_step
-        self.params, self.opt_state, loss = train_step(
-            self.params, self.opt_state, jnp.asarray(tokens, jnp.int32),
-            jnp.asarray(self.step_count, jnp.int32))
-        self.step_count += 1
-        return float(loss) if block else loss
+        with tracer().span("train.step", step=self.step_count):
+            self.params, self.opt_state, loss = train_step(
+                self.params, self.opt_state, jnp.asarray(tokens, jnp.int32),
+                jnp.asarray(self.step_count, jnp.int32))
+            self.step_count += 1
+            if not block:
+                return loss
+            with tracer().span("train.sync"):
+                return float(loss)
 
     def fit_batch_multi(self, tokens, *, multi_step, k: int,
                         block: bool = True):

@@ -45,6 +45,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from deeplearning4j_tpu.monitor import trace as _trace
 from deeplearning4j_tpu.monitor.exporters import _json_default
 from deeplearning4j_tpu.utils.fileio import _fsync_dir
 
@@ -201,8 +202,8 @@ class FlightRecorder:
             self.records_dropped += 1
 
     def record_span(self, span_dict: dict) -> None:
-        """Forward one finished tracer span (``trace._record`` wires in
-        here via :func:`flight`)."""
+        """Forward one finished tracer span (the global recorder is a
+        ``trace.add_sink`` sink while it is installed)."""
         self.record("span", **span_dict)
 
     def flush(self, timeout: float = 5.0) -> bool:
@@ -386,22 +387,33 @@ def flight() -> Optional[FlightRecorder]:
                 d = flight_dir()
                 if d is not None:
                     try:
-                        _RECORDER = FlightRecorder(d)
+                        _install(FlightRecorder(d))
                     except OSError as e:
                         logger.warning("flight recorder disabled: cannot "
                                        "open %s: %s", d, e)
-                        _RECORDER = None
                 _DERIVED = True
     return _RECORDER
+
+
+def _install(recorder: Optional[FlightRecorder]) -> None:
+    """Make ``recorder`` the global one and the tracers' span sink (every
+    finished span is part of the postmortem timeline); caller holds
+    ``_LOCK``."""
+    global _RECORDER
+    if _RECORDER is not None:
+        _trace.remove_sink(_RECORDER.record_span)
+    _RECORDER = recorder
+    if recorder is not None:
+        _trace.add_sink(recorder.record_span)
 
 
 def set_flight(recorder: Optional[FlightRecorder]) -> None:
     """Install a recorder explicitly (bench, tests); ``None`` resets to
     env derivation on next use. Does NOT close the previous recorder —
     the caller that created it owns its lifecycle."""
-    global _RECORDER, _DERIVED
+    global _DERIVED
     with _LOCK:
-        _RECORDER = recorder
+        _install(recorder)
         _DERIVED = recorder is not None
 
 

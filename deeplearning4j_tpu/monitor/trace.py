@@ -11,23 +11,69 @@ ring of finished spans with monotonic start/end timestamps and parent ids
 into a JSONL event log or the summary block embedded in bench artifacts.
 
 The clock is injectable (tests drive a fake), span recording is a deque
-append under a lock (no I/O on the hot path — a ``sink`` callback, when
-configured, forwards each finished span to the JSONL exporter), and a
-tracer with no sink and no reader costs two clock reads per span.
+append under a lock (no I/O on the hot path — each finished span is
+forwarded to the sinks: the tracer's own, which the JSONL exporter wires
+in, and the process-wide ones of :func:`add_sink`, where the flight
+recorder registers itself), and a tracer with no sink and no reader costs
+two clock reads per span.
+
+Every context-manager span is also a ``dl4j.<name>`` event in any
+``jax.profiler`` trace taken while it runs: on entry the span enters a
+``jax.profiler.TraceAnnotation`` carrying its scalar attrs, on exit it
+leaves it, so the program's spans share the device trace's clock. The
+annotation is inert unless a profiler session is running; the class is
+bound lazily, and only once ``jax`` has been imported by someone else —
+this module never imports it, so the control plane stays importable
+without jax and the mirror is then a no-op.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["Span", "SpanTracer", "tracer", "set_tracer"]
+__all__ = ["Span", "SpanTracer", "tracer", "set_tracer", "add_sink",
+           "remove_sink"]
 
 DEFAULT_CAPACITY = 4096
+PROFILER_PREFIX = "dl4j."
+
+# sinks every tracer of the process feeds besides its own (the flight
+# recorder registers here when ``set_flight`` or ``DL4J_FLIGHT`` enables it)
+_SINKS: List[Callable[[dict], None]] = []
+
+# ``jax.profiler.TraceAnnotation`` once jax is there to take it from
+_ANNOTATION = None
+
+
+def add_sink(sink: Callable[[dict], None]) -> None:
+    """Forward every finished span of every tracer to ``sink(span_dict)``
+    until :func:`remove_sink`. Adding a sink twice is adding it once."""
+    if sink not in _SINKS:
+        _SINKS.append(sink)
+
+
+def remove_sink(sink: Callable[[dict], None]) -> None:
+    if sink in _SINKS:
+        _SINKS.remove(sink)
+
+
+def _annotation():
+    """The profiler's annotation class, or None while nothing has imported
+    jax: the tracer is stdlib-only and stays so."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:     # jax half-imported: try again next span
+            return None
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class Span:
@@ -75,6 +121,11 @@ class SpanTracer:
       An exception inside the body stamps ``attrs["error"]`` before the
       span closes (the timeline records WHAT failed, not just that
       something did).
+      The span is mirrored into the profiler as ``dl4j.<name>`` with
+      the scalar attrs it was opened with (module docstring).
+    - ``record(name, start_s, end_s, **attrs)`` — a span whose interval
+      is known only when it ends (a request's wait in a queue); it goes
+      to the ring and the sinks, not to the profiler.
     - ``event(name, **attrs)`` — zero-duration span, recorded
       immediately (watchdog fired, preemption latched).
     - ``clock`` is injectable; ``sink(span_dict)`` forwards each
@@ -105,26 +156,18 @@ class SpanTracer:
     def _record(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
-        if self._sink is not None:
+        sinks = tuple(_SINKS) if self._sink is None else (self._sink,
+                                                          *_SINKS)
+        if not sinks:
+            return
+        as_dict = span.to_dict()
+        for sink in sinks:
             try:
-                self._sink(span.to_dict())
+                sink(as_dict)
             except Exception:
-                # the sink is best-effort I/O; a full disk must not turn
+                # a sink is best-effort I/O; a full disk must not turn
                 # into a training failure
                 pass
-        # the flight recorder (when enabled) gets every finished span —
-        # the postmortem timeline a crash is reconstructed from.
-        # (import from the submodule: the package re-exports a `flight`
-        # FUNCTION that shadows the module attribute of the same name)
-        try:
-            from deeplearning4j_tpu.monitor.flight import (
-                flight as _active_flight)
-
-            rec = _active_flight()
-            if rec is not None:
-                rec.record_span(span.to_dict())
-        except Exception:
-            pass
 
     # ------------------------------------------------------------------
     @contextmanager
@@ -135,6 +178,12 @@ class SpanTracer:
                   self._clock(), attrs)
         stack = self._stack()
         stack.append(sp)
+        ann = _annotation()
+        if ann is not None:
+            ann = ann(PROFILER_PREFIX + name,
+                      **{k: v for k, v in attrs.items()
+                         if isinstance(v, (int, float, str))})
+            ann.__enter__()
         try:
             yield sp
         except BaseException as e:
@@ -149,16 +198,25 @@ class SpanTracer:
                     stack.remove(sp)
                 except ValueError:
                     pass
+            if ann is not None:
+                ann.__exit__(None, None, None)
             self._record(sp)
+
+    def record(self, name: str, start_s: float, end_s: float,
+               **attrs) -> Span:
+        """A finished span with the given times on this tracer's clock,
+        child of the span open now."""
+        parent = self.current()
+        sp = Span(name, next(self._ids),
+                  None if parent is None else parent.span_id, start_s,
+                  attrs)
+        sp.end_s = end_s
+        self._record(sp)
+        return sp
 
     def event(self, name: str, **attrs) -> Span:
         now = self._clock()
-        parent = self.current()
-        sp = Span(name, next(self._ids),
-                  None if parent is None else parent.span_id, now, attrs)
-        sp.end_s = now
-        self._record(sp)
-        return sp
+        return self.record(name, now, now, **attrs)
 
     # ------------------------------------------------------------------
     def spans(self) -> List[Span]:
@@ -197,14 +255,17 @@ _TRACER_LOCK = threading.Lock()
 
 def tracer() -> SpanTracer:
     """The process-global tracer. First use wires the JSONL sink when
-    ``DL4J_TELEMETRY_DIR`` is set (see ``monitor.exporters``)."""
+    ``DL4J_TELEMETRY_DIR`` is set (see ``monitor.exporters``) and lets the
+    flight recorder register itself when ``DL4J_FLIGHT`` is."""
     global _TRACER
     if _TRACER is None:
         with _TRACER_LOCK:
             if _TRACER is None:
                 from deeplearning4j_tpu.monitor import exporters
+                from deeplearning4j_tpu.monitor.flight import flight
 
                 _TRACER = SpanTracer(sink=exporters.span_sink_from_env())
+                flight()
     return _TRACER
 
 

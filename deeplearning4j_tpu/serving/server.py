@@ -31,9 +31,32 @@ counters (``serve_decode_steps_total`` counts DISPATCHES — with fusion
 one dispatch covers up to K·(G+1) tokens; ``stats()`` derives
 dispatches/token and accepted-tokens/dispatch, the fast-path headline
 metrics), speculative proposed/accepted counters, TTFT/TPOT/latency
-histograms (``monitor/registry``), ``serve.step`` and ``serve.prefill``
-spans (``monitor/trace`` — forwarded to the flight recorder when one is
-live, like every span).
+histograms (``monitor/registry``), and the spans below (``monitor/trace``
+— forwarded to the flight recorder when one is live and, like every span,
+a ``dl4j.<name>`` event on the device trace's clock in any
+``jax.profiler`` trace taken while the server runs):
+
+- ``serve.step`` (``live``, ``admitted``) — one scheduler iteration;
+  parent of the three phases:
+- ``serve.admit`` (``n``) — all admissions of the step; opened only when
+  a slot is free and a request or hand-off is waiting. Holds, per
+  request, ``serve.queued`` (``request``, ``criticality``: ``submit_s``
+  → popped from the queue; recorded when it ends) and ``serve.prefill``
+  (``request``, ``slot``, ``prompt_len``, ``bucket``, ``queue_wait_us``:
+  key, pad, prefill dispatch, cursor, the first token's read-back, up to
+  ``first_token_s``), or ``serve.handoff.install``.
+- ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
+  ``spec``) — host arrays → decode dispatch → cursors advanced → the
+  token block on the host.
+- ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop,
+  histograms, retirement.
+- ``serve.request`` (``request``, ``tokens``, ``slot``) — ``submit_s`` →
+  ``finish_s``, recorded at retirement; ``request`` is
+  ``ServeRequest.id`` on every span of one request.
+
+Sampling runs inside the prefill and decode programs and is no host
+phase. ``serve.queued`` and ``serve.request`` carry the SERVER clock's
+times; that is the tracer's clock unless one of the two was injected.
 """
 
 from __future__ import annotations
@@ -121,6 +144,8 @@ class DecodeServer:
         self.slot_dispatches = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        self._decode_kind = ("spec" if self.engine.spec else
+                             "fused" if self.fuse_steps > 1 else "plain")
         self._reg = metrics()
 
     def _zero_keys(self):
@@ -320,10 +345,18 @@ class DecodeServer:
             self._retire(slot, now)
 
     def _admit(self) -> int:
+        free = self._free_slots()
+        if not free or not (self._handoffs or len(self.queue)):
+            return 0
+        with tracer().span("serve.admit") as sp:
+            sp.attrs["n"] = admitted = self._admit_into(free)
+        return admitted
+
+    def _admit_into(self, free: List[int]) -> int:
         import jax
 
         admitted = 0
-        for slot in self._free_slots():
+        for slot in free:
             # handed-off slabs first: their prefill compute is already
             # spent — a queued prompt admitted ahead of them would idle
             # a finished prefill while burning a slot on new work
@@ -347,9 +380,14 @@ class DecodeServer:
                 req = self.queue.pop()
             if req is None:
                 break
-            with tracer().span("serve.prefill", request=req.id,
-                               slot=slot,
-                               prompt_len=int(req.prompt.shape[0])):
+            prompt_len = int(req.prompt.shape[0])
+            tracer().record("serve.queued", req.submit_s, now,
+                            request=req.id, criticality=req.criticality)
+            with tracer().span(
+                    "serve.prefill", request=req.id, slot=slot,
+                    prompt_len=prompt_len,
+                    bucket=self.engine.prompt_bucket(prompt_len),
+                    queue_wait_us=int(1e6 * (now - req.submit_s))):
                 key = jax.random.PRNGKey(req.seed)
                 if self.engine.spec:
                     # an independent per-slot draft stream (only the
@@ -358,10 +396,10 @@ class DecodeServer:
                         jax.random.fold_in(key, 0x5bec))
                 tok, key = self.engine.prefill(req.prompt, slot, key)
                 tok = int(tok)
-            now = self.clock()
-            req.state = "running"
-            req.slot = slot
-            req.first_token_s = now
+                now = self.clock()
+                req.state = "running"
+                req.slot = slot
+                req.first_token_s = now
             req.tokens.append(tok)
             self._slot_req[slot] = req
             self._last_tok[slot] = tok
@@ -388,6 +426,9 @@ class DecodeServer:
             self._reg.histogram("serve_request_latency_seconds",
                                 buckets=_LATENCY_BUCKETS
                                 ).observe(req.latency_s)
+            tracer().record("serve.request", req.submit_s, now,
+                            request=req.id, tokens=len(req.tokens),
+                            slot=slot)
 
     def _dispatch(self, live: List[int]):
         """ONE decode dispatch for the current live set. Returns
@@ -440,67 +481,79 @@ class DecodeServer:
         live (the caller may idle)."""
         with tracer().span("serve.step") as sp:
             self._sweep_expired()
-            self._admit()
+            sp.attrs["admitted"] = self._admit()
             live = self._live_slots()
             self._reg.gauge("serve_queue_depth").set(len(self.queue))
             self._reg.gauge("serve_slot_occupancy").set(
                 len(live) / self.slots)
             if not live:
                 return False
-            toks, counts = self._dispatch(live)
-            now = self.clock()
-            self.steps += 1
-            self.slot_dispatches += len(live)
             sp.attrs["live"] = len(live)
-            self._reg.counter("serve_decode_steps_total").inc()
-            tpot = self._reg.histogram("serve_tpot_seconds",
-                                       buckets=_LATENCY_BUCKETS)
-            emitted_total = 0
-            proposed0, accepted0 = self.spec_proposed, self.spec_accepted
-            for slot in live:
-                req = self._slot_req[slot]
-                rem = req.max_new_tokens - len(req.tokens)
-                got: List[int] = []
-                if counts is None:
-                    for r in range(min(toks.shape[0], rem)):
-                        got.append(int(toks[r, slot]))
-                else:
-                    for r in range(toks.shape[0]):
-                        c = int(counts[r, slot])
-                        if c <= 0:
-                            continue
-                        take = min(c, rem - len(got))
-                        got.extend(int(t) for t in toks[r, slot, :take])
-                        self.spec_proposed += self.engine.spec_tokens
-                        self.spec_accepted += c - 1
-                        if len(got) >= rem:
-                            break
-                req.tokens.extend(got)
-                emitted_total += len(got)
-                # with fusion the K tokens land together: spread the
-                # dispatch interval evenly so TPOT keeps one observation
-                # per token and sums to the true wall span
-                interval = (now - self._last_tok_s[slot]) / max(
-                    1, len(got))
-                for _ in got:
-                    tpot.observe(interval)
-                self._last_tok[slot] = got[-1]
-                self._last_tok_s[slot] = now
-                if len(req.tokens) >= req.max_new_tokens:
-                    self._retire(slot, now)
-            self.decode_tokens += emitted_total
-            self._reg.counter("serve_tokens_total").inc(emitted_total)
-            if self.engine.spec:
-                if self.spec_proposed > proposed0:
-                    self._reg.counter("serve_spec_proposed_total").inc(
-                        self.spec_proposed - proposed0)
-                if self.spec_accepted > accepted0:
-                    self._reg.counter("serve_spec_accepted_total").inc(
-                        self.spec_accepted - accepted0)
-            # re-publish after retirement: a drained server must read 0,
-            # not the pre-retirement batch width
-            self._reg.gauge("serve_slot_occupancy").set(self.occupancy())
+            with tracer().span("serve.decode", live=len(live),
+                               kind=self._decode_kind):
+                toks, counts = self._dispatch(live)
+            with tracer().span("serve.emit") as emit:
+                emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
+                    live, toks, counts)
             return True
+
+    def _emit(self, live: List[int], toks, counts) -> Tuple[int, int]:
+        """Book one dispatch's token block: per live slot the tokens it
+        takes, TPOT observations, retirement. Returns ``(tokens emitted,
+        requests retired)``."""
+        now = self.clock()
+        self.steps += 1
+        self.slot_dispatches += len(live)
+        self._reg.counter("serve_decode_steps_total").inc()
+        tpot = self._reg.histogram("serve_tpot_seconds",
+                                   buckets=_LATENCY_BUCKETS)
+        emitted_total = retired = 0
+        proposed0, accepted0 = self.spec_proposed, self.spec_accepted
+        for slot in live:
+            req = self._slot_req[slot]
+            rem = req.max_new_tokens - len(req.tokens)
+            got: List[int] = []
+            if counts is None:
+                for r in range(min(toks.shape[0], rem)):
+                    got.append(int(toks[r, slot]))
+            else:
+                for r in range(toks.shape[0]):
+                    c = int(counts[r, slot])
+                    if c <= 0:
+                        continue
+                    take = min(c, rem - len(got))
+                    got.extend(int(t) for t in toks[r, slot, :take])
+                    self.spec_proposed += self.engine.spec_tokens
+                    self.spec_accepted += c - 1
+                    if len(got) >= rem:
+                        break
+            req.tokens.extend(got)
+            emitted_total += len(got)
+            # with fusion the K tokens land together: spread the
+            # dispatch interval evenly so TPOT keeps one observation
+            # per token and sums to the true wall span
+            interval = (now - self._last_tok_s[slot]) / max(
+                1, len(got))
+            for _ in got:
+                tpot.observe(interval)
+            self._last_tok[slot] = got[-1]
+            self._last_tok_s[slot] = now
+            if len(req.tokens) >= req.max_new_tokens:
+                self._retire(slot, now)
+                retired += 1
+        self.decode_tokens += emitted_total
+        self._reg.counter("serve_tokens_total").inc(emitted_total)
+        if self.engine.spec:
+            if self.spec_proposed > proposed0:
+                self._reg.counter("serve_spec_proposed_total").inc(
+                    self.spec_proposed - proposed0)
+            if self.spec_accepted > accepted0:
+                self._reg.counter("serve_spec_accepted_total").inc(
+                    self.spec_accepted - accepted0)
+        # re-publish after retirement: a drained server must read 0,
+        # not the pre-retirement batch width
+        self._reg.gauge("serve_slot_occupancy").set(self.occupancy())
+        return emitted_total, retired
 
     def drain(self, max_steps: Optional[int] = None) -> int:
         """Step until queue and slots are empty; returns steps taken."""

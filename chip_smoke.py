@@ -10,7 +10,8 @@ supports (depth as configured, weights random from a seed):
                mixed_bf16, Pallas flash attention: per-step and fused-K
                training steps
   kernels      every Pallas flash entry point against the XLA attention op
-               and its jax.grad, compiled, on the chip
+               and its jax.grad, and the serving decode kernel against the
+               XLA op over the pool's slab, compiled, on the chip
   train_graph  ResNet-18 (ComputationGraph) through fit_epochs
   serve        DecodeServer on the d512/L8 LM, ragged prompts, checked
                against lm.generate
@@ -164,17 +165,23 @@ def train_lm_phase(*, lm_kwargs=LM_WIDTH, batch=16, steps=3, fused_k=2,
 # ---------------------------------------------------------------------------
 def kernels_phase(*, batch=4, heads=8, head_dim=64,
                   cases=((1024, None), (4096, 1024)), dtype="bfloat16",
-                  tol=2e-2, interpret=None) -> dict:
+                  decode=(8, 4096, 1024), tol=2e-2, interpret=None) -> dict:
     """Flash forward, dk/dv and dq against ``dot_product_attention`` and its
     ``jax.grad``, for each ``(seq_len, window)`` case. ``interpret=None``
     is the library default: compiled by Mosaic on a TPU. The reference runs
     one batch row at a time (its [h, t, t] score matrix is the memory the
-    kernel exists to avoid)."""
+    kernel exists to avoid). Then the serving decode kernel, which reads
+    one layer of a ``decode = (slots, t_max, window)`` KV pool in place,
+    against ``grouped_query_attention`` over that layer's slab."""
     import jax
     import jax.numpy as jnp
 
-    from deeplearning4j_tpu.ops.attention import dot_product_attention
-    from deeplearning4j_tpu.pallas.flash_attention import flash_attention
+    from deeplearning4j_tpu.ops.attention import (
+        dot_product_attention, grouped_query_attention)
+    from deeplearning4j_tpu.pallas.decode_attention import (
+        pool_decode_attention)
+    from deeplearning4j_tpu.pallas.flash_attention import (
+        flash_attention, flash_default_interpret)
 
     def out_and_grads(attn):
         def f(q, k, v, do):
@@ -205,8 +212,31 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
             assert bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))), (
                 f"{tag} {name}: non-finite values")
             errors[f"{tag}_{name}"] = _rel_err(g, w)
+
+    # two layers of a GQA pool (2 kv heads of 128 under 8 query heads),
+    # slots at position 0, at the pool's end and spread between
+    slots, t_max, window = decode
+    keys = jax.random.split(jax.random.PRNGKey(t_max), 3)
+    pool_k, pool_v = (jax.random.normal(kk, (2, slots, t_max, 2, 128),
+                                        jnp.dtype(dtype)) for kk in keys[:2])
+    q = jax.random.normal(keys[2], (slots, 1, 8, 128), jnp.dtype(dtype))
+    positions = (jnp.arange(slots) * (t_max - 1) // (slots - 1))[:, None]
+    live = jnp.arange(t_max)[None, None, :] <= positions[:, :, None]
+    live &= jnp.arange(t_max)[None, None, :] > positions[:, :, None] - window
+    kernel = jax.jit(lambda q, k, v, p: pool_decode_attention(
+        q, k, v, 1, p, window=window,
+        interpret=flash_default_interpret() if interpret is None
+        else interpret))
+    got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
+    first_s += s
+    got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
+    steady_s += s
+    want = jax.jit(lambda q, k, v: grouped_query_attention(
+        q, k[1], v[1], mask=live))(q, pool_k, pool_v)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    errors[f"decode_t{t_max}_w{window}"] = _rel_err(got, want)
     bad = {k: e for k, e in errors.items() if not e <= tol}
-    assert not bad, f"flash kernels off the XLA op beyond {tol}: {bad}"
+    assert not bad, f"kernels off the XLA op beyond {tol}: {bad}"
     return {"first_call_s": first_s, "steady_s": steady_s,
             "rel_err": {k: round(e, 5) for k, e in errors.items()}}
 

@@ -4,9 +4,13 @@ The role libnd4j's native op library played for the reference
 (deeplearning4j-core/pom.xml:154-158 pulls nd4j native backends): ops where
 the XLA-fused default leaves performance or memory on the table get a
 hand-scheduled kernel. Currently: flash attention (blockwise online
-softmax, O(block) memory instead of O(t^2)).
+softmax, O(block) memory instead of O(t^2)) and the serving decode
+attention that reads the KV pool in place (``decode_attention.py``).
 """
 
+from deeplearning4j_tpu.pallas.decode_attention import (  # noqa: F401
+    pool_decode_attention,
+)
 from deeplearning4j_tpu.pallas.flash_attention import (  # noqa: F401
     flash_attention,
     flash_attention_fwd,

@@ -12,8 +12,7 @@ compiles outside it:
   scatter the consumed tokens' K/V at each slot's cursor, attend each row
   against its own masked cache history (GQA-aware — the pool stores
   ``num_kv_heads``), sample one token per row from per-slot RNG streams.
-  The ``fuse_steps=1`` path: one dispatch per token, exactly the PR-10
-  program.
+  The ``fuse_steps=1`` path: one dispatch per token.
 - ``("decode_fused", S, K)`` — K decode steps as one ``lax.scan``: the
   single-step body runs K times in-program (per-slot cursors advance on
   device, RNG streams split in-program, K/V scatters land per step) and
@@ -32,6 +31,17 @@ compiles outside it:
   tokens per slot (the +1 is the target's correction/bonus token), so
   accepted-tokens/dispatch — the headline serve metric — exceeds 1
   whenever the draft agrees at all.
+
+The decode-family programs update the donated pool IN PLACE
+(``_pool_attention``): every layer scatters its new rows straight into
+the ``[L, S, T_max, Hkv, Dh]`` arrays the program was given
+(``kv_cache.write_pool_rows``), the pool is the ``lax.scan`` carry of the
+fused programs, and the pool a program returns is the buffer that was
+donated to it — no slab is copied out to be written and none is stacked
+back. Where a TPU is attached the layer's keys are read from the pool
+itself by a Pallas kernel (``pallas/decode_attention.py``), because
+XLA:TPU copies ``pool[layer]`` out before a dot may read it; elsewhere
+the XLA op attends to that view.
 
 All program bodies are ``@traced`` hot roots
 (``analysis/annotations.HOT_PATH_REGISTRY``) so dl4j-lint's host-sync
@@ -60,10 +70,11 @@ import numpy as np
 
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+from deeplearning4j_tpu.pallas.flash_attention import flash_default_interpret
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
 from deeplearning4j_tpu.serving.kv_cache import (
-    SlotKVCache, dequant_slab, requant_write_slab)
+    SlotKVCache, dequant_slab, write_pool_rows)
 
 __all__ = ["DecodeEngine"]
 
@@ -176,80 +187,111 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     return tok, key, new_kv
 
 
-def _decode_step_body(model, params, kv, tok, positions):
+@traced
+def _pool_attention(model, pool, positions, pool_kernel):
+    """``li -> attention(q, kk, vv)`` for a decode-family forward over
+    the slot pool: layer ``li`` scatters its new K/V rows at
+    ``positions [S, Q]`` straight into the carried pool
+    (``write_pool_rows``), then query ``(s, i)`` attends that layer's
+    keys ``<= positions[s, i]`` of slot ``s`` (window-clipped like
+    training). ``pool`` is the caller's own dict of the pool arrays;
+    every layer rebinds its entries, so after the layer loop it holds the
+    program's output pool — the arrays it was given, updated in place
+    when the program's pool argument is donated. Nothing here stacks
+    slabs back into a pool.
+
+    The read has two forms. XLA:TPU copies ``pool[li]`` out as a slab
+    before any dot may use it, so where the Pallas kernel applies
+    (``pool_kernel``: a TPU is attached unless the caller says otherwise;
+    an unquantized pool whose head size fills the lanes)
+    ``pool_decode_attention`` reads the layer's live key blocks from the
+    pool itself. Everywhere else — the CPU, int8 pools, a pool sharded
+    over a mesh — the XLA op attends to the ``pool[li]`` view."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops.attention import grouped_query_attention
+    from deeplearning4j_tpu.pallas import decode_attention as kernel
+
+    window = model.attn_window
+    dtype = model.policy.compute_dtype
+    rows = jnp.arange(positions.shape[0])
+    if pool_kernel is None:
+        pool_kernel = not flash_default_interpret()
+    block = None
+    if pool_kernel and "k_scale" not in pool:
+        block = kernel.pool_block_rows(pool["k"].shape, pool["k"].dtype)
+    if block is None:
+        keys = jnp.arange(pool["k"].shape[2])
+        live = keys <= positions[:, :, None]               # [S, Q, T]
+        if window is not None:
+            live &= keys > positions[:, :, None] - window
+
+    def layer(li):
+        def attn(q, kk, vv):
+            for name, new in (("k", kk), ("v", vv)):
+                pool[name], scale = write_pool_rows(
+                    pool[name], pool.get(name + "_scale"), li, new, rows,
+                    positions)
+                if scale is not None:
+                    pool[name + "_scale"] = scale
+            if block is not None:
+                return kernel.pool_decode_attention(
+                    q, pool["k"], pool["v"], li, positions, window=window,
+                    block_rows=block, interpret=flash_default_interpret())
+            views = (dequant_slab(
+                pool[name][li],
+                pool[name + "_scale"][li] if name + "_scale" in pool
+                else None, dtype) for name in ("k", "v"))
+            return grouped_query_attention(q, *views, mask=live)
+        return attn
+
+    return layer
+
+
+def _decode_step_body(model, params, kv, tok, positions, *,
+                      pool_kernel=None):
     """ONE decode forward for all S slots: consume ``tok[s]`` at
     ``positions[s]``, write its (de/re)quantized K/V at that cursor,
-    attend keys ``<= positions[s]`` (window-clipped like training).
+    attend keys ``<= positions[s]`` (``_pool_attention``).
     Returns ``(logits [S, V], new_kv)`` — sampling happens in the
-    callers so the draft path can keep the proposal distribution. Free
+    callers so the draft path can keep the proposal distribution.
+    ``new_kv`` is ``kv`` with one row per slot and layer scattered in:
+    donated, it is the same buffer. Free
     slots ride along computing garbage no one reads — their rows are
     masked out of nothing (rows are independent) and their pool writes
     land at frozen cursors the admission prefill overwrites."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.ops.attention import grouped_query_attention
 
-    policy = model.policy
-    cdt = policy.compute_dtype
-    s = tok.shape[0]
-    t_max = kv["k"].shape[2]
-    k_scale = kv.get("k_scale")
-    v_scale = kv.get("v_scale")
     h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
     if model.pos_encoding == "learned":
         h = h + params["pos"][positions]
-    h = policy.cast_compute(h)[:, None, :]                 # [S, 1, D]
-    live = jnp.arange(t_max)[None, :] <= positions[:, None]
-    if model.attn_window is not None:
-        live &= (jnp.arange(t_max)[None, :]
-                 > positions[:, None] - model.attn_window)
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    rows = jnp.arange(s)
-
-    def cached_attention(li):
-        def attn(q, kk, vv):
-            ck, cks = requant_write_slab(
-                kv["k"][li], None if k_scale is None else k_scale[li],
-                kk, rows, positions[:, None])
-            cv, cvs = requant_write_slab(
-                kv["v"][li], None if v_scale is None else v_scale[li],
-                vv, rows, positions[:, None])
-            new_k.append(ck)
-            new_v.append(cv)
-            if cks is not None:
-                new_ks.append(cks)
-                new_vs.append(cvs)
-            return grouped_query_attention(
-                q, dequant_slab(ck, cks, cdt), dequant_slab(cv, cvs, cdt),
-                mask=live)
-        return attn
-
+    h = model.policy.cast_compute(h)[:, None, :]           # [S, 1, D]
+    new_kv = dict(kv)
+    cached_attention = _pool_attention(
+        model, new_kv, positions[:, None], pool_kernel)
     for li, blk in enumerate(params["blocks"]):
         h, _, _ = model._block(blk, h, attention=cached_attention(li),
                                positions=positions[:, None])
     logits = model._unembed(params, h[:, 0])               # [S, V]
-    out = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if new_ks:
-        out["k_scale"] = jnp.stack(new_ks)
-        out["v_scale"] = jnp.stack(new_vs)
-    return logits, out
+    return logits, new_kv
 
 
 @traced
 def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
-                       keys):
+                       keys, *, pool_kernel=None):
     """The PR-10 single-step program: one batched forward + per-slot
-    sampling. One host dispatch per token — the ``fuse_steps=1`` path,
-    kept bitwise."""
+    sampling. One host dispatch per token — the ``fuse_steps=1`` path."""
     import jax
 
-    logits, new_kv = _decode_step_body(model, params, kv, tok, positions)
+    logits, new_kv = _decode_step_body(model, params, kv, tok, positions,
+                                       pool_kernel=pool_kernel)
     toks, keys = jax.vmap(sample_row)(logits, keys)
     return toks, keys, new_kv
 
 
 @traced
 def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv,
-                             cursors, tok, remaining, keys):
+                             cursors, tok, remaining, keys, *,
+                             pool_kernel=None):
     """K decode steps as ONE ``lax.scan``: sampling, per-slot RNG
     splits, K/V scatter writes, and cursor advancement all move
     in-program. ``remaining[s]`` tokens still owed per slot gates an
@@ -266,7 +308,8 @@ def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv,
         kv, cursors, tok, remaining, keys = carry
         act = remaining > 0
         ntok, nkeys, nkv = _serve_decode_impl(
-            model, sample_row, params, kv, tok, cursors, keys)
+            model, sample_row, params, kv, tok, cursors, keys,
+            pool_kernel=pool_kernel)
         tok = jnp.where(act, ntok, tok)
         keys = jnp.where(act[:, None], nkeys, keys)
         cursors = jnp.where(act, cursors + 1, cursors)
@@ -279,7 +322,8 @@ def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv,
 
 
 @traced
-def _serve_verify_impl(model, params, kv, toks, positions):
+def _serve_verify_impl(model, params, kv, toks, positions, *,
+                       pool_kernel=None):
     """Multi-token target forward for the speculative verify: consume
     ``toks [S, Q]`` at per-row ``positions [S, Q]`` against the slot
     pool, scatter-writing every candidate's K/V at its position (the
@@ -288,59 +332,25 @@ def _serve_verify_impl(model, params, kv, toks, positions):
     causality at ragged per-slot offsets: query q attends pool keys
     ``<= positions[s, q]``. Returns ``(logits [S, Q, V], new_kv)``."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.ops.attention import grouped_query_attention
 
-    policy = model.policy
-    cdt = policy.compute_dtype
-    s = toks.shape[0]
-    t_max = kv["k"].shape[2]
-    k_scale = kv.get("k_scale")
-    v_scale = kv.get("v_scale")
     h = jnp.take(params["embed"], toks, axis=0)            # [S, Q, D]
     if model.pos_encoding == "learned":
         h = h + params["pos"][positions]
-    h = policy.cast_compute(h)
-    live = (jnp.arange(t_max)[None, None, :]
-            <= positions[:, :, None])                      # [S, Q, T]
-    if model.attn_window is not None:
-        live &= (jnp.arange(t_max)[None, None, :]
-                 > positions[:, :, None] - model.attn_window)
-    new_k, new_v, new_ks, new_vs = [], [], [], []
-    rows = jnp.arange(s)
-
-    def cached_attention(li):
-        def attn(q, kk, vv):
-            ck, cks = requant_write_slab(
-                kv["k"][li], None if k_scale is None else k_scale[li],
-                kk, rows, positions)
-            cv, cvs = requant_write_slab(
-                kv["v"][li], None if v_scale is None else v_scale[li],
-                vv, rows, positions)
-            new_k.append(ck)
-            new_v.append(cv)
-            if cks is not None:
-                new_ks.append(cks)
-                new_vs.append(cvs)
-            return grouped_query_attention(
-                q, dequant_slab(ck, cks, cdt), dequant_slab(cv, cvs, cdt),
-                mask=live)
-        return attn
-
+    h = model.policy.cast_compute(h)
+    new_kv = dict(kv)
+    cached_attention = _pool_attention(model, new_kv, positions, pool_kernel)
     for li, blk in enumerate(params["blocks"]):
         h, _, _ = model._block(blk, h, attention=cached_attention(li),
                                positions=positions)
     logits = model._unembed(params, h)                     # [S, Q, V]
-    out = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-    if new_ks:
-        out["k_scale"] = jnp.stack(new_ks)
-        out["v_scale"] = jnp.stack(new_vs)
-    return logits, out
+    return logits, new_kv
 
 
 @traced
 def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
                      k_rounds, params, draft_params, kv, draft_kv,
-                     cursors, tok, remaining, keys, draft_keys):
+                     cursors, tok, remaining, keys, draft_keys, *,
+                     pool_kernel=None):
     """K speculative rounds as ONE program. Per round and live slot:
 
     1. **draft** — ``gamma + 1`` draft-model steps from the shared
@@ -379,7 +389,8 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
         def dstep(dc, i):
             dkv, dtok, dkeys = dc
             logits, dkv = _decode_step_body(
-                draft_model, draft_params, dkv, dtok, cursors + i)
+                draft_model, draft_params, dkv, dtok, cursors + i,
+                pool_kernel=pool_kernel)
             if greedy:
                 prop = jnp.argmax(logits, axis=-1).astype(i32)
                 qdist = logits  # unused; placeholder keeps the scan pytree
@@ -402,7 +413,8 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
         # ---- verify: one multi-token target forward over tok + d_1..d_G
         vtoks = jnp.concatenate([tok[:, None], d], axis=1)  # [S, G+1]
         vpos = cursors[:, None] + jnp.arange(gamma + 1)[None, :]
-        logits, kv = _serve_verify_impl(model, params, kv, vtoks, vpos)
+        logits, kv = _serve_verify_impl(model, params, kv, vtoks, vpos,
+                                        pool_kernel=pool_kernel)
 
         # ---- accept / resample
         if greedy:
@@ -660,6 +672,17 @@ class DecodeEngine:
         self.cache.set_cursor(slot, plen)
         return tok, key
 
+    def _decode_jit(self, donate, impl, *bound):
+        """The jitted decode-family program ``impl`` with its static
+        leading arguments bound and the pool arguments donated. A pool
+        sharded over a mesh keeps the XLA read: GSPMD would gather the
+        whole pool onto every chip for the kernel's custom call."""
+        import jax
+
+        kw = {} if self.mesh is None else {"pool_kernel": False}
+        return jax.jit(functools.partial(impl, *bound, **kw),
+                       donate_argnums=donate)
+
     def decode(self, tok, positions, keys):
         """One batched step (the ``fuse_steps=1`` / PR-10 path):
         ``tok``/``positions`` [S], ``keys`` [S, 2]. Returns
@@ -669,9 +692,8 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         def build():
-            fn = functools.partial(_serve_decode_impl, self.model,
-                                   self._sample_row)
-            return jax.jit(fn, donate_argnums=(1,))
+            return self._decode_jit(
+                (1,), _serve_decode_impl, self.model, self._sample_row)
 
         run = self._program(("decode", self.slots), build)
         toks, keys, state = run(self.model.params, self.cache.state,
@@ -687,9 +709,9 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         def build():
-            fn = functools.partial(_serve_decode_fused_impl, self.model,
-                                   self._sample_row, k_steps)
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return self._decode_jit(
+                (1, 2), _serve_decode_fused_impl, self.model,
+                self._sample_row, k_steps)
 
         run = self._program(("decode_fused", self.slots, k_steps), build)
         toks, cursors, keys, state = run(
@@ -712,12 +734,11 @@ class DecodeEngine:
         greedy = self.temperature == 0.0
 
         def build():
-            fn = functools.partial(
-                _serve_spec_impl, self.model, self.draft_model,
+            return self._decode_jit(
+                (2, 3, 4), _serve_spec_impl, self.model, self.draft_model,
                 None if greedy else _filtered_logits_fn(
                     self.temperature, self.top_k),
                 self.spec_tokens, greedy, k_rounds)
-            return jax.jit(fn, donate_argnums=(2, 3, 4))
 
         run = self._program(
             ("decode_spec", self.slots, k_rounds, self.spec_tokens),

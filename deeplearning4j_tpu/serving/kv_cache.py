@@ -19,7 +19,9 @@ Slot lifecycle (the scheduler in ``serving/server.py`` drives it):
   the cursor starts at ``prompt_len`` (the pad tail ``[prompt_len,
   P_bucket)`` sits beyond the mask until generated tokens overwrite it).
 - **decoding** — each step writes the consumed token's K/V at ``cursor``
-  then attends keys ``<= cursor``; the cursor advances by one.
+  (one row per layer scattered into the pool itself:
+  ``write_pool_rows``) then attends keys ``<= cursor``; the cursor
+  advances by one.
 - **retired** — the request finished; the slot returns to free with its
   stale contents in place (the next prefill overwrites them, and the
   mask keeps them unreachable meanwhile).
@@ -40,7 +42,7 @@ Hkv]``, a ``1/(T_max·Dh)``-sized sidecar) and dequantizes inside the
 attention body; the pool shrinks 4x vs f32 and ``max_slots_in_budget``
 rises accordingly. Scales are running maxima: a write whose absmax
 exceeds the slot-head's scale requantizes that row in-program
-(``requant_write_slab``), so streamed decode writes never clip.
+(``write_pool_rows``), so streamed decode writes never clip.
 """
 
 from __future__ import annotations
@@ -59,6 +61,7 @@ __all__ = [
     "max_slots_in_budget",
     "dequant_slab",
     "requant_write_slab",
+    "write_pool_rows",
 ]
 
 _KV_DTYPES = ("float32", "bfloat16", "int8")
@@ -133,44 +136,65 @@ def dequant_slab(slab, scale, dtype):
 
 
 @traced
-def requant_write_slab(slab, scale, values, rows, positions):
+def write_pool_rows(pool, scale, layer, values, rows, positions):
     """Write ``values [S, q, Hkv, Dh]`` at ``(rows [S], positions
-    [S, q])`` into one layer's slab; returns ``(slab, scale)``.
+    [S, q])`` of layer ``layer`` (a Python int) into the whole
+    ``[L, S, T, Hkv, Dh]`` pool; returns ``(pool, scale)`` with ``scale``
+    the ``[L, S, Hkv]`` sidecar (``None`` when unquantized).
 
-    Unquantized (``scale is None``): a plain scatter in the store dtype.
-    int8: per-(slot, head) running-absmax scales — when a write's absmax
-    exceeds the stored scale, the slot-head's existing entries are
-    requantized to the grown scale in the same program (slots whose
-    scale did not grow multiply by exactly 1.0 — an int8→f32→round→int8
-    identity), then the new values quantize and scatter. Out-of-range
-    scatter positions (frozen slots riding along near ``T_max``) are
-    dropped by XLA's scatter semantics, never written."""
+    The one write the decode-family programs make. It scatters into the
+    pool itself — never into a copy of the layer's slab — so a program
+    whose pool argument is donated updates that buffer in place and the
+    only pool-sized value it produces is the buffer it was given.
+
+    Unquantized: a plain scatter in the store dtype. int8:
+    per-(slot, head) running-absmax scales — when a write's absmax
+    exceeds the stored scale, the slot-head's existing entries in this
+    layer are requantized to the grown scale in the same program (slots
+    whose scale did not grow multiply by exactly 1.0 — an
+    int8→f32→round→int8 identity), then the new values quantize and
+    scatter. Out-of-range scatter positions (frozen slots riding along
+    near ``T_max``) are dropped by XLA's scatter semantics, never
+    written."""
     import jax.numpy as jnp
 
+    at = (layer, rows[:, None], positions)
     if scale is None:
-        return slab.at[rows[:, None], positions].set(
-            values.astype(slab.dtype)), None
+        return pool.at[at].set(values.astype(pool.dtype)), None
     from jax import lax
 
     vals = values.astype(jnp.float32)
     m = jnp.max(jnp.abs(vals), axis=(1, 3))                 # [S, Hkv]
-    new_scale = jnp.maximum(scale, m)
-    denom = jnp.where(new_scale > 0, new_scale, 1.0)
-    factor = jnp.where(new_scale > 0, scale / denom, 1.0)
-    # the requant pass rewrites the whole slab, so gate it on any scale
-    # actually growing: in the steady state (absmax already seen) every
-    # factor is 1.0 and the identity rewrite would burn a full
-    # pool-read+write of bandwidth per layer per step for nothing —
-    # cond keeps the common case scatter-only
-    slab = lax.cond(
-        jnp.any(new_scale > scale),
-        lambda s: jnp.round(s.astype(jnp.float32)
-                            * factor[:, None, :, None]).astype(jnp.int8),
-        lambda s: s,
-        slab)
+    old = scale[layer]
+    new = jnp.maximum(old, m)
+    denom = jnp.where(new > 0, new, 1.0)
+    factor = jnp.where(new > 0, old / denom, 1.0)
+    # the requant pass rewrites the layer's whole slab, so gate it on any
+    # scale actually growing: in the steady state (absmax already seen)
+    # every factor is 1.0 and the identity rewrite would burn a full
+    # slab-read+write of bandwidth per layer per step for nothing —
+    # cond keeps the common case scatter-only on the pool
+    pool = lax.cond(
+        jnp.any(new > old),
+        lambda p: p.at[layer].set(
+            jnp.round(p[layer].astype(jnp.float32)
+                      * factor[:, None, :, None]).astype(jnp.int8)),
+        lambda p: p,
+        pool)
     q = jnp.clip(jnp.round(vals / denom[:, None, :, None] * 127.0),
                  -127, 127).astype(jnp.int8)
-    return slab.at[rows[:, None], positions].set(q), new_scale
+    return pool.at[at].set(q), scale.at[layer].set(new)
+
+
+@traced
+def requant_write_slab(slab, scale, values, rows, positions):
+    """``write_pool_rows`` for ONE layer's slab ``[S, T, Hkv, Dh]`` and
+    its ``[S, Hkv]`` scale (``None`` = unquantized): the slab as a
+    one-layer pool. Returns ``(slab, scale)``."""
+    pool, scales = write_pool_rows(
+        slab[None], None if scale is None else scale[None], 0, values,
+        rows, positions)
+    return pool[0], None if scales is None else scales[0]
 
 
 class SlotKVCache:
@@ -250,7 +274,9 @@ class SlotKVCache:
     @property
     def state(self) -> dict:
         """The pool pytree a jitted program consumes (and is donated):
-        ``{k, v}`` plus the int8 scale sidecars when quantized."""
+        ``{k, v}`` plus the int8 scale sidecars when quantized. The
+        engine's programs write into these buffers and hand them back
+        (``install``); once donated, the arrays returned here are dead."""
         st = {"k": self.k, "v": self.v}
         if self.k_scale is not None:
             st["k_scale"] = self.k_scale
@@ -258,8 +284,9 @@ class SlotKVCache:
         return st
 
     def install(self, state: dict) -> None:
-        """Install the pool state a jitted program returned (the old
-        buffers were donated into it)."""
+        """Install the pool state a jitted program returned: the
+        buffers ``state`` donated to it, updated in place — the same
+        device memory, not a copy of it."""
         self.k = state["k"]
         self.v = state["v"]
         self.k_scale = state.get("k_scale")

@@ -61,17 +61,17 @@ def test_train_lm_demands_flash():
 def test_kernels_tiny():
     r = chip_smoke.kernels_phase(
         batch=1, heads=2, head_dim=64, cases=((128, None), (256, 128)),
-        interpret=True)
+        decode=(3, 64, 24), interpret=True)
     assert set(r["rel_err"]) == {
         f"{case}_{k}" for case in ("t128", "t256_w128")
-        for k in ("fwd", "dq", "dk", "dv")}
+        for k in ("fwd", "dq", "dk", "dv")} | {"decode_t64_w24"}
 
 
 def test_kernels_tolerance_is_enforced():
     with pytest.raises(AssertionError, match="beyond"):
         chip_smoke.kernels_phase(batch=1, heads=1, head_dim=64,
-                                 cases=((128, None),), interpret=True,
-                                 tol=0.0)
+                                 cases=((128, None),), decode=(2, 32, 32),
+                                 interpret=True, tol=0.0)
 
 
 @pytest.fixture(scope="module")

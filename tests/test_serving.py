@@ -833,3 +833,161 @@ class TestSpeculativeDecode:
         req = srv.submit(_prompts(rng, (20,))[0], 8)  # 28 + 4 == 32
         srv.drain()
         assert len(req.tokens) == 8
+
+
+# ---------------------------------------------------------------------------
+# the decode-family programs update the donated pool in place (PR 24)
+# ---------------------------------------------------------------------------
+def _slab_step_reference(model, params, kv, toks, positions):
+    """The decode-family forward as it was before PR 24, kept here as the
+    reference: each layer's ``[S, T, Hkv, Dh]`` slab is sliced out of the
+    pool, ``requant_write_slab`` scatters into the copy, attention reads
+    it, and the slabs are stacked back into a fresh pool. ``toks`` and
+    ``positions`` are ``[S, Q]``. Returns ``(logits [S, Q, V], pool)``."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.ops.attention import grouped_query_attention
+    from deeplearning4j_tpu.serving.kv_cache import (
+        dequant_slab, requant_write_slab)
+
+    cdt = model.policy.compute_dtype
+    t_max = kv["k"].shape[2]
+    h = jnp.take(params["embed"], toks, axis=0)
+    if model.pos_encoding == "learned":
+        h = h + params["pos"][positions]
+    h = model.policy.cast_compute(h)
+    live = jnp.arange(t_max)[None, None, :] <= positions[:, :, None]
+    if model.attn_window is not None:
+        live &= (jnp.arange(t_max)[None, None, :]
+                 > positions[:, :, None] - model.attn_window)
+    rows = jnp.arange(toks.shape[0])
+    out = {name: [] for name in kv}
+
+    def cached_attention(li):
+        def attn(q, kk, vv):
+            slabs = []
+            for name, new in (("k", kk), ("v", vv)):
+                scale = kv.get(name + "_scale")
+                slab, scale = requant_write_slab(
+                    kv[name][li], None if scale is None else scale[li],
+                    new, rows, positions)
+                out[name].append(slab)
+                if scale is not None:
+                    out[name + "_scale"].append(scale)
+                slabs.append(dequant_slab(slab, scale, cdt))
+            return grouped_query_attention(q, *slabs, mask=live)
+        return attn
+
+    for li, blk in enumerate(params["blocks"]):
+        h, _, _ = model._block(blk, h, attention=cached_attention(li),
+                               positions=positions)
+    return (model._unembed(params, h),
+            {name: jnp.stack(slabs) for name, slabs in out.items()})
+
+
+def _random_pool(rng, lm, slots, max_len, kv_dtype, scale):
+    """A pool of seeded content (and, for int8, scales all ``scale``)."""
+    import jax.numpy as jnp
+
+    cache = SlotKVCache(lm, slots, max_len, kv_dtype)
+    kv = {}
+    for name, arr in cache.state.items():
+        if name.endswith("_scale"):
+            kv[name] = jnp.full(arr.shape, scale, arr.dtype)
+        elif arr.dtype == jnp.int8:
+            kv[name] = jnp.asarray(
+                rng.integers(-127, 128, arr.shape), jnp.int8)
+        else:
+            kv[name] = jnp.asarray(rng.normal(size=arr.shape), arr.dtype)
+    return kv
+
+
+class TestInPlacePool:
+    SLOTS, MAX_LEN = 3, 24
+
+    @pytest.mark.parametrize("kind", ["decode", "decode_fused", "verify"])
+    def test_program_aliases_the_pool(self, kind):
+        """Compiled with the pool donated, a decode-family program's
+        output pool IS its input pool (``alias_size_in_bytes``) and its
+        temporaries stay under half a pool: before PR 24 the slabs were
+        sliced out and stacked back, one whole pool of temporaries."""
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.serving import engine as eng
+
+        # four layers: XLA:CPU still copies one layer's K and V slabs out
+        # for the attention dots, a quarter of the pool
+        slots, max_len = 8, 512
+        lm = _lm("rope", max_len=max_len, num_layers=4)
+        kv = SlotKVCache(lm, slots, max_len, "float32").state
+        vec = jnp.zeros(slots, jnp.int32)
+        keys = jnp.zeros((slots, 2), jnp.uint32)
+        sampler = eng._row_sampler(0.0, None)
+        if kind == "decode":
+            fn = functools.partial(eng._serve_decode_impl, lm, sampler)
+            args, donate = (lm.params, kv, vec, vec, keys), (1,)
+        elif kind == "decode_fused":
+            fn = functools.partial(eng._serve_decode_fused_impl, lm,
+                                   sampler, 2)
+            args, donate = (lm.params, kv, vec, vec, vec, keys), (1, 2)
+        else:
+            fn = functools.partial(eng._serve_verify_impl, lm)
+            pos = jnp.zeros((slots, 3), jnp.int32)
+            args, donate = (lm.params, kv, pos, pos), (1,)
+        mem = jax.jit(fn, donate_argnums=donate).lower(
+            *args).compile().memory_analysis()
+        pool = sum(int(a.nbytes) for a in kv.values())
+        donated = sum(int(leaf.nbytes) for i in donate
+                      for leaf in jax.tree_util.tree_leaves(args[i]))
+        assert mem.alias_size_in_bytes == donated >= pool
+        assert mem.temp_size_in_bytes < pool // 2, (
+            mem.temp_size_in_bytes, pool)
+
+    @pytest.mark.parametrize("kv_dtype,scale", [
+        ("float32", None), ("bfloat16", None),
+        ("int8", 0.0),      # every write's absmax grows the scale
+        ("int8", 1e3),      # no write does: the scatter-only branch
+    ])
+    @pytest.mark.parametrize("queries", [1, 3])
+    def test_bitwise_equal_to_the_slab_formulation(self, rng, kv_dtype,
+                                                   scale, queries):
+        """Scattering into the pool and attending to ``pool[li]`` is the
+        old slice / scatter / stack step bit for bit: logits and the
+        returned pool, one query a slot (decode) and several (verify).
+        Slot 1 is frozen past ``T_max``: its write is dropped and, unless
+        a grown int8 scale requantizes them, its rows come back
+        untouched."""
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.serving import engine as eng
+
+        lm = _lm("rope", attn_window=16)
+        kv = _random_pool(rng, lm, self.SLOTS, self.MAX_LEN, kv_dtype,
+                          scale)
+        first = jnp.asarray([5, self.MAX_LEN, self.MAX_LEN - queries])
+        positions = first[:, None] + jnp.arange(queries)[None, :]
+        toks = jnp.asarray(
+            rng.integers(1, 61, (self.SLOTS, queries)), jnp.int32)
+        want_logits, want_kv = _slab_step_reference(
+            lm, lm.params, kv, toks, positions)
+        if queries == 1:
+            logits, new_kv = eng._decode_step_body(
+                lm, lm.params, kv, toks[:, 0], positions[:, 0])
+            logits = logits[:, None]
+        else:
+            logits, new_kv = eng._serve_verify_impl(
+                lm, lm.params, kv, toks, positions)
+        assert np.array_equal(np.asarray(logits), np.asarray(want_logits))
+        assert sorted(new_kv) == sorted(want_kv)
+        for name in kv:
+            got = np.asarray(new_kv[name].astype(jnp.float32))
+            assert np.array_equal(
+                got, np.asarray(want_kv[name].astype(jnp.float32))), name
+            if scale != 0.0:
+                assert np.array_equal(
+                    got[:, 1],
+                    np.asarray(kv[name].astype(jnp.float32))[:, 1])
+        if scale == 0.0:
+            assert (np.asarray(new_kv["k_scale"])[:, 0] > 0).all()
+        elif scale:
+            assert (np.asarray(new_kv["k_scale"]) == scale).all()

@@ -165,14 +165,26 @@ def train_lm_phase(*, lm_kwargs=LM_WIDTH, batch=16, steps=3, fused_k=2,
 # ---------------------------------------------------------------------------
 def kernels_phase(*, batch=4, heads=8, head_dim=64,
                   cases=((1024, None), (4096, 1024)), dtype="bfloat16",
-                  decode=(8, 4096, 1024), tol=2e-2, interpret=None) -> dict:
+                  decode=(8, 4096, 1024), decode_heads=((8, 2), (16, 16)),
+                  moe=(2048, 1024, 64, 8, (32, 4096)), tol=2e-2,
+                  interpret=None) -> dict:
     """Flash forward, dk/dv and dq against ``dot_product_attention`` and its
     ``jax.grad``, for each ``(seq_len, window)`` case. ``interpret=None``
     is the library default: compiled by Mosaic on a TPU. The reference runs
     one batch row at a time (its [h, t, t] score matrix is the memory the
     kernel exists to avoid). Then the serving decode kernel, which reads
     one layer of a ``decode = (slots, t_max, window)`` KV pool in place,
-    against ``grouped_query_attention`` over that layer's slab."""
+    against ``grouped_query_attention`` over that layer's slab, for each
+    ``(query heads, kv heads)`` of ``decode_heads`` (StarCoder2's 2 kv
+    heads under a window; OLMoE's 16, full causal). Then the routed
+    experts (``moe = (hidden, expert width, experts, per token, token
+    counts)``: OLMoE's widths, a decode step's 32 rows and a 4,096-token
+    prefill, so both of ``routed_ffn``'s forms) against a masked loop over
+    the experts. Both sides of that check feed the MXU bf16 operands and
+    accumulate in float32; they differ in the order of the sum over
+    experts and in where the weighted hidden state is rounded to bf16
+    (2^-9 an element), which ``tol`` holds with room and a wrong expert,
+    weight or dropped token does not."""
     import jax
     import jax.numpy as jnp
 
@@ -213,32 +225,83 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
                 f"{tag} {name}: non-finite values")
             errors[f"{tag}_{name}"] = _rel_err(g, w)
 
-    # two layers of a GQA pool (2 kv heads of 128 under 8 query heads),
-    # slots at position 0, at the pool's end and spread between
+    # two layers of a pool of 128-wide kv heads, slots at position 0, at
+    # the pool's end and spread between
     slots, t_max, window = decode
-    keys = jax.random.split(jax.random.PRNGKey(t_max), 3)
-    pool_k, pool_v = (jax.random.normal(kk, (2, slots, t_max, 2, 128),
-                                        jnp.dtype(dtype)) for kk in keys[:2])
-    q = jax.random.normal(keys[2], (slots, 1, 8, 128), jnp.dtype(dtype))
-    positions = (jnp.arange(slots) * (t_max - 1) // (slots - 1))[:, None]
-    live = jnp.arange(t_max)[None, None, :] <= positions[:, :, None]
-    live &= jnp.arange(t_max)[None, None, :] > positions[:, :, None] - window
-    kernel = jax.jit(lambda q, k, v, p: pool_decode_attention(
-        q, k, v, 1, p, window=window,
-        interpret=flash_default_interpret() if interpret is None
-        else interpret))
-    got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
-    first_s += s
-    got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
-    steady_s += s
-    want = jax.jit(lambda q, k, v: grouped_query_attention(
-        q, k[1], v[1], mask=live))(q, pool_k, pool_v)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    errors[f"decode_t{t_max}_w{window}"] = _rel_err(got, want)
+    for n, (h, hkv) in enumerate(decode_heads):
+        win = window if hkv < h else None
+        keys = jax.random.split(jax.random.PRNGKey(t_max + n), 3)
+        pool_k, pool_v = (
+            jax.random.normal(kk, (2, slots, t_max, hkv, 128),
+                              jnp.dtype(dtype)) for kk in keys[:2])
+        q = jax.random.normal(keys[2], (slots, 1, h, 128), jnp.dtype(dtype))
+        positions = (jnp.arange(slots) * (t_max - 1) // (slots - 1))[:, None]
+        live = jnp.arange(t_max)[None, None, :] <= positions[:, :, None]
+        if win is not None:
+            live &= (jnp.arange(t_max)[None, None, :]
+                     > positions[:, :, None] - win)
+        kernel = jax.jit(lambda q, k, v, p, win=win: pool_decode_attention(
+            q, k, v, 1, p, window=win,
+            interpret=flash_default_interpret() if interpret is None
+            else interpret))
+        got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
+        first_s += s
+        got, s = _timed(lambda: kernel(q, pool_k, pool_v, positions))
+        steady_s += s
+        want = jax.jit(lambda q, k, v, live=live: grouped_query_attention(
+            q, k[1], v[1], mask=live))(q, pool_k, pool_v)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        tag = f"decode_t{t_max}_kv{hkv}" + ("" if win is None
+                                             else f"_w{win}")
+        errors[tag] = _rel_err(got, want)
+    errors.update(_moe_errors(*moe, dtype=jnp.dtype(dtype)))
     bad = {k: e for k, e in errors.items() if not e <= tol}
     assert not bad, f"kernels off the XLA op beyond {tol}: {bad}"
     return {"first_call_s": first_s, "steady_s": steady_s,
             "rel_err": {k: round(e, 5) for k, e in errors.items()}}
+
+
+def _moe_errors(d_model, d_ff, n_experts, per_token, token_counts, *, dtype):
+    """``routed_ffn`` against a masked loop over the experts, float32
+    weights cast to ``dtype`` per use on both sides; ``{tag: rel_err}``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from deeplearning4j_tpu.models import routed_experts
+
+    key = jax.random.PRNGKey(d_model)
+    p = jax.jit(lambda k: routed_experts.init_experts(
+        k, d_model, d_ff, n_experts, jnp.float32))(key)
+
+    def cast(w):
+        return w.astype(dtype)
+
+    def dot(a, b):
+        return jnp.dot(a, cast(b), preferred_element_type=jnp.float32)
+
+    # the weights are arguments: a jitted closure would bake 1.6 GB of
+    # constants into each program
+    @jax.jit
+    def loop(x, p):
+        w, e = routed_experts.route(x, p["router"], per_token)
+
+        def one(i, acc):
+            wi = jnp.sum(jnp.where(e == i, w, 0.0), -1, keepdims=True)
+            hid = jax.nn.silu(dot(x, p["w_gate"][i])) * dot(x, p["w_up"][i])
+            return acc + wi * dot(hid.astype(dtype), p["w_down"][i])
+
+        return lax.fori_loop(0, n_experts, one,
+                             jnp.zeros(x.shape, jnp.float32))
+
+    ffn = jax.jit(lambda x, p: routed_experts.routed_ffn(
+        x, p, experts_per_token=per_token, cast=cast)[0])
+    errors = {}
+    for n in token_counts:
+        x = jax.random.normal(jax.random.fold_in(key, n), (n, d_model),
+                              jnp.float32).astype(dtype)
+        errors[f"moe_n{n}"] = _rel_err(ffn(x, p), loop(x, p).astype(dtype))
+    return errors
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+from deeplearning4j_tpu.models import routed_experts
 from deeplearning4j_tpu.monitor import tracer
 from deeplearning4j_tpu.ops.attention import (
     dot_product_attention,
@@ -82,6 +83,14 @@ def _layernorm(x, g, b, eps=1e-5):
     return (y * g.astype(st) + b.astype(st)).astype(x.dtype)
 
 
+def _rmsnorm(x, g, eps=1e-5):
+    # weight only; statistics in >=f32, result in x's dtype (as _layernorm)
+    st = jnp.promote_types(x.dtype, jnp.float32)
+    xs = x.astype(st)
+    y = xs * jax.lax.rsqrt(jnp.mean(xs * xs, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(st)).astype(x.dtype)
+
+
 class TransformerLM:
     def __init__(self, vocab_size: int, d_model: int = 256, num_heads: int = 8,
                  num_layers: int = 4, d_ff: Optional[int] = None,
@@ -90,8 +99,37 @@ class TransformerLM:
                  remat: bool = False, pos_encoding: str = "learned",
                  num_kv_heads: Optional[int] = None,
                  attn_window: Optional[int] = None,
-                 sp_impl: str = "ring", scan_layers: bool = False):
+                 sp_impl: str = "ring", scan_layers: bool = False,
+                 norm: str = "layernorm", qk_norm: bool = False,
+                 num_experts: int = 0, experts_per_token: int = 0,
+                 norm_topk_prob: bool = False,
+                 tie_embeddings: bool = True):
         assert d_model % num_heads == 0
+        # The block, described per model; the defaults are StarCoder2's
+        # (LayerNorm with bias, biased GELU MLP, tied unembedding).
+        # norm: "layernorm" (gain and bias) | "rmsnorm" (gain only), for
+        # the block's two norms and the final one. qk_norm: an RMSNorm
+        # over the whole q and k projections, before the split into heads
+        # and before RoPE. num_experts > 0: the feed-forward is
+        # ``experts_per_token`` of ``num_experts`` routed SwiGLU experts of
+        # width ``d_ff`` without bias (models/routed_experts.py: dropless,
+        # float32 router; ``norm_topk_prob`` renormalises the chosen
+        # weights) instead of the dense MLP. tie_embeddings=False: the
+        # unembedding is its own ``head`` leaf, shaped like ``embed``.
+        if norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"norm={norm!r} must be 'layernorm' or 'rmsnorm'")
+        if num_experts < 0 or (num_experts and not
+                               1 <= experts_per_token <= num_experts):
+            raise ValueError(
+                f"experts_per_token={experts_per_token} must be in "
+                f"[1, num_experts={num_experts}]")
+        self.norm = norm
+        self.qk_norm = bool(qk_norm)
+        self.num_experts = int(num_experts)
+        self.experts_per_token = int(experts_per_token) if num_experts else 0
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.tie_embeddings = bool(tie_embeddings)
         # "auto": Pallas flash kernel when a TPU backend is attached and
         # head_dim maps onto lane tiles; "xla" / "flash" force a path
         assert attn_impl in ("auto", "xla", "flash")
@@ -169,30 +207,48 @@ class TransformerLM:
             return jax.random.normal(key, (fan_in, fan_out), dt) * jnp.sqrt(
                 2.0 / (fan_in + fan_out)).astype(dt)
 
+        def norm(width=D):
+            p = {"g": jnp.ones((width,), dt)}
+            if self.norm == "layernorm":
+                p["b"] = jnp.zeros((width,), dt)
+            return p
+
         keys = jax.random.split(key, 2 + 6 * self.num_layers)
         params: Dict[str, Any] = {
             "embed": jax.random.normal(keys[0], (V, D), dt) * 0.02,
-            "ln_f": {"g": jnp.ones((D,), dt), "b": jnp.zeros((D,), dt)},
+            "ln_f": norm(),
             "blocks": [],
         }
         if self.pos_encoding == "learned":
             params["pos"] = jax.random.normal(keys[1], (L, D), dt) * 0.02
+        if not self.tie_embeddings:
+            params["head"] = jax.random.normal(
+                jax.random.fold_in(keys[0], 1), (V, D), dt) * 0.02
         for i in range(self.num_layers):
             k = keys[2 + 6 * i:2 + 6 * (i + 1)]
-            params["blocks"].append({
-                "ln1": {"g": jnp.ones((D,), dt), "b": jnp.zeros((D,), dt)},
+            blk = {
+                "ln1": norm(),
                 "attn": {
                     "wq": dense(k[0], D, D),
                     "wk": dense(k[1], D, self.num_kv_heads * Dh),
                     "wv": dense(k[2], D, self.num_kv_heads * Dh),
                     "wo": dense(k[3], D, D),
                 },
-                "ln2": {"g": jnp.ones((D,), dt), "b": jnp.zeros((D,), dt)},
-                "mlp": {
+                "ln2": norm(),
+            }
+            if self.qk_norm:
+                blk["attn"]["q_norm"] = {"g": jnp.ones((D,), dt)}
+                blk["attn"]["k_norm"] = {
+                    "g": jnp.ones((self.num_kv_heads * Dh,), dt)}
+            if self.num_experts:
+                blk["moe"] = routed_experts.init_experts(
+                    k[4], D, F, self.num_experts, dt)
+            else:
+                blk["mlp"] = {
                     "w1": dense(k[4], D, F), "b1": jnp.zeros((F,), dt),
                     "w2": dense(k[5], F, D), "b2": jnp.zeros((D,), dt),
-                },
-            })
+                }
+            params["blocks"].append(blk)
         self.params = params
         self.opt_state = jax.tree_util.tree_map(
             lambda p: {"m": jnp.zeros_like(p), "v": jnp.zeros_like(p)}, params)
@@ -244,8 +300,12 @@ class TransformerLM:
     @traced
     def _block(self, blk, h, *, mesh: Optional[Mesh] = None,
                sequence_parallel: bool = False, attention=None,
-               positions=None, train: bool = False):
-        """One pre-norm block on ``h`` [b, t, D]. Returns ``(h, k, v)``
+               positions=None, train: bool = False, live=None,
+               moe_info: Optional[list] = None):
+        """One pre-norm block on ``h`` [b, t, D], as the model describes
+        it (norm kind, QK-norm, dense MLP or routed experts — chosen here,
+        at trace time, for training, prefill and decode alike). Returns
+        ``(h, k, v)``
         with k/v in [b, t, H, Dh] — ``forward`` discards them (XLA DCE),
         the KV-cache prefill keeps them (k/v are post-RoPE under
         ``pos_encoding="rope"``). ``attention(q, k, v) -> o`` overrides
@@ -253,14 +313,24 @@ class TransformerLM:
         against the cache instead) while sharing every other line of
         block math. ``positions`` are the absolute positions for RoPE —
         [t] (default 0..t-1; the decode step passes its cache slot) or
-        [b, t] per-row (the serving decode, one position per slot)."""
+        [b, t] per-row (the serving decode, one position per slot).
+
+        Routed experts only: ``live`` [b, t] (bool) marks the rows that
+        hold a token (a prompt's pad tail and free slots do not), and a
+        list passed as ``moe_info`` receives this layer's routing
+        (``routed_experts.routed_ffn``'s ``info``: chosen experts, their
+        weights, the live load per expert)."""
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
-        x = _layernorm(h, blk["ln1"]["g"], blk["ln1"]["b"])
-        q = (x @ policy.cast_compute(blk["attn"]["wq"])).reshape(
-            b, t, self.num_heads, -1)
-        k = (x @ policy.cast_compute(blk["attn"]["wk"])).reshape(
-            b, t, self.num_kv_heads, -1)
+        x = self._norm(h, blk["ln1"])
+        q = x @ policy.cast_compute(blk["attn"]["wq"])
+        if self.qk_norm:
+            q = _rmsnorm(q, blk["attn"]["q_norm"]["g"])
+        q = q.reshape(b, t, self.num_heads, -1)
+        k = x @ policy.cast_compute(blk["attn"]["wk"])
+        if self.qk_norm:
+            k = _rmsnorm(k, blk["attn"]["k_norm"]["g"])
+        k = k.reshape(b, t, self.num_kv_heads, -1)
         v = (x @ policy.cast_compute(blk["attn"]["wv"])).reshape(
             b, t, self.num_kv_heads, -1)
         if self.pos_encoding == "rope":
@@ -295,12 +365,28 @@ class TransformerLM:
             o = grouped_query_attention(q, k, v, causal=True,
                                         window=self.attn_window)
         h = h + o.reshape(b, t, -1) @ policy.cast_compute(blk["attn"]["wo"])
-        x = _layernorm(h, blk["ln2"]["g"], blk["ln2"]["b"])
+        x = self._norm(h, blk["ln2"])
+        if self.num_experts:
+            y, info = routed_experts.routed_ffn(
+                x.reshape(b * t, -1), blk["moe"],
+                experts_per_token=self.experts_per_token,
+                norm_topk_prob=self.norm_topk_prob,
+                cast=policy.cast_compute,
+                live=None if live is None else live.reshape(b * t))
+            if moe_info is not None:
+                moe_info.append(info)
+            return h + y.reshape(b, t, -1), k, v
         x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
                         + policy.cast_compute(blk["mlp"]["b1"]))
         h = (h + x @ policy.cast_compute(blk["mlp"]["w2"])
              + policy.cast_compute(blk["mlp"]["b2"]))
         return h, k, v
+
+    def _norm(self, x, p):
+        """The model's norm (``norm=``) with the parameters ``p``."""
+        if self.norm == "rmsnorm":
+            return _rmsnorm(x, p["g"])
+        return _layernorm(x, p["g"], p["b"])
 
     def _repeat_kv(self, x):
         """[b, t, Hkv, d] → [b, t, H, d] by repeating each kv head over
@@ -309,10 +395,16 @@ class TransformerLM:
         return x if rep == 1 else jnp.repeat(x, rep, axis=2)
 
     def forward(self, params, tokens, *, mesh: Optional[Mesh] = None,
-                sequence_parallel: bool = False, train: bool = False):
+                sequence_parallel: bool = False, train: bool = False,
+                moe_info: Optional[list] = None):
         """tokens: [b, t] int32 → logits [b, t, V]. ``train=True`` is the
         training hot path: "auto" attention resolves to the flash kernel
-        whenever head_dim tiles (see ``_attn_impl``)."""
+        whenever head_dim tiles (see ``_attn_impl``). A list passed as
+        ``moe_info`` receives each layer's routing (``_block``; rows are
+        the b·t tokens; not under ``remat`` or ``scan_layers``, whose
+        bodies are traced apart)."""
+        if moe_info is not None and (self.remat or self.scan_layers):
+            raise ValueError("moe_info needs remat=False, scan_layers=False")
         policy = self.policy
         b, t = tokens.shape
         h = jnp.take(params["embed"], tokens, axis=0)
@@ -323,7 +415,7 @@ class TransformerLM:
         def block_fn(blk, h):
             return self._block(blk, h, mesh=mesh,
                                sequence_parallel=sequence_parallel,
-                               train=train)[0]
+                               train=train, moe_info=moe_info)[0]
 
         if self.remat:
             block_fn = jax.checkpoint(block_fn)
@@ -476,6 +568,11 @@ class TransformerLM:
             "attn_impl": self.attn_impl, "remat": self.remat,
             "pos_encoding": self.pos_encoding,
             "scan_layers": self.scan_layers,
+            "norm": self.norm, "qk_norm": self.qk_norm,
+            "num_experts": self.num_experts,
+            "experts_per_token": self.experts_per_token,
+            "norm_topk_prob": self.norm_topk_prob,
+            "tie_embeddings": self.tie_embeddings,
         }
 
     def _ensure_init(self):
@@ -503,14 +600,16 @@ class TransformerLM:
     # autoregressive decoding (KV cache)
     # ------------------------------------------------------------------
     def _unembed(self, params, h):
-        """Final layernorm + tied unembedding on [..., D] hidden →
-        [..., V] f32 logits. The matmul runs with compute-dtype (bf16)
-        operands and f32 accumulation — one of the largest matmuls in
-        the step, so a plain f32 matmul here would cost MXU rate."""
+        """Final norm + unembedding (the embedding itself when tied,
+        else the ``head`` leaf) on [..., D] hidden → [..., V] f32 logits.
+        The matmul runs with compute-dtype (bf16) operands and f32
+        accumulation — one of the largest matmuls in the step, so a
+        plain f32 matmul here would cost MXU rate."""
         policy = self.policy
-        hf = _layernorm(h, params["ln_f"]["g"], params["ln_f"]["b"])
+        hf = self._norm(h, params["ln_f"])
+        head = params["embed" if self.tie_embeddings else "head"]
         return lax.dot_general(
-            policy.cast_compute(hf), policy.cast_compute(params["embed"]),
+            policy.cast_compute(hf), policy.cast_compute(head),
             (((hf.ndim - 1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -772,21 +871,37 @@ class TransformerLM:
                 "MQA that is all of them)",
                 self.num_kv_heads, model_axis_size)
             kv_col = P()
+        def norm():
+            return ({"g": P(), "b": P()} if self.norm == "layernorm"
+                    else {"g": P()})
+
         blocks = []
         for _ in range(self.num_layers):
-            blocks.append({
-                "ln1": {"g": P(), "b": P()},
+            blk = {
+                "ln1": norm(),
                 "attn": {"wq": col, "wk": kv_col, "wv": kv_col, "wo": row},
-                "ln2": {"g": P(), "b": P()},
-                "mlp": {"w1": col, "b1": P(MODEL_AXIS), "w2": row, "b2": P()},
-            })
-        specs = {
-            "embed": row if shard_data_embed else P(),
-            "ln_f": {"g": P(), "b": P()},
-            "blocks": blocks,
-        }
+                "ln2": norm(),
+            }
+            if self.qk_norm:
+                blk["attn"]["q_norm"] = {"g": P()}
+                blk["attn"]["k_norm"] = {"g": P()}
+            if self.num_experts:
+                # every chip holds all experts, each split on its width
+                # like the dense MLP; the router is replicated
+                blk["moe"] = {"router": P(),
+                              "w_gate": P(None, None, MODEL_AXIS),
+                              "w_up": P(None, None, MODEL_AXIS),
+                              "w_down": P(None, MODEL_AXIS, None)}
+            else:
+                blk["mlp"] = {"w1": col, "b1": P(MODEL_AXIS), "w2": row,
+                              "b2": P()}
+            blocks.append(blk)
+        embed = row if shard_data_embed else P()
+        specs = {"embed": embed, "ln_f": norm(), "blocks": blocks}
         if self.pos_encoding == "learned":
             specs["pos"] = P()
+        if not self.tie_embeddings:
+            specs["head"] = embed
         return specs
 
     def shard_params(self, mesh: Mesh, specs: Optional[Dict[str, Any]] = None):

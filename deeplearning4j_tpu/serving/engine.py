@@ -127,6 +127,9 @@ def _filtered_logits_fn(temperature: float, top_k: Optional[int]):
 def _serve_prefill_impl(model, sample_row, quantized, params, kv,
                         prompt, prompt_len, slot, key):
     """Prefill one bucket-padded prompt ([1, P]) into pool slot ``slot``.
+    Returns ``(token, key, pool)``, and for a model with routed experts
+    ``(token, key, pool, routing)`` (``_stack_routing``, rows = the P
+    positions).
 
     Causality makes the pad tail inert: position ``i < prompt_len``
     attends keys ``0..i`` — all real tokens — so the K/V written at real
@@ -150,8 +153,11 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         h = h + params["pos"][:p][None]
     h = policy.cast_compute(h)
     ks, vs = [], []
+    # the pad tail holds no token: it takes no part in routed experts
+    live = (jnp.arange(p) < prompt_len)[None] if model.num_experts else None
+    moe_info: list = []
     for blk in params["blocks"]:
-        h, kk, vv = model._block(blk, h)
+        h, kk, vv = model._block(blk, h, live=live, moe_info=moe_info)
         ks.append(kk.astype(cdt))
         vs.append(vv.astype(cdt))
     kcat = jnp.stack(ks)                     # [L, 1, P, Hkv, Dh]
@@ -184,7 +190,39 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         }
     h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
     tok, key = sample_row(model._unembed(params, h_last), key)
+    if model.num_experts:
+        return tok, key, new_kv, _stack_routing(moe_info)
     return tok, key, new_kv
+
+
+def _stack_routing(moe_info):
+    """The layers' routing (``routed_experts.routed_ffn``'s ``info``, as
+    ``TransformerLM._block`` collects it) as ONE int32 array a serving
+    program returns beside its tokens, so that the host reads it in a
+    single transfer: ``[L, E + 2 N k]``, per layer the load ``[E]`` (the
+    live (token, expert) pairs each expert received), then the N rows'
+    experts ``[N k]``, then the bits of their float32 weights ``[N k]``
+    (0 on a row that holds no token). ``unpack_routing`` is its inverse."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def layer(info):
+        weights = lax.bitcast_convert_type(info["weights"], jnp.int32)
+        return jnp.concatenate([info["load"], info["experts"].reshape(-1),
+                                weights.reshape(-1)])
+
+    return jnp.stack([layer(info) for info in moe_info])
+
+
+def unpack_routing(packed, num_experts: int, experts_per_token: int):
+    """``_stack_routing``'s array on the host (numpy) -> ``(load [L, E]
+    int32, experts [L, N, k] int32, weights [L, N, k] float32)``, views."""
+    layers = packed.shape[0]
+    load, rows = packed[:, :num_experts], packed[:, num_experts:]
+    half = rows.shape[1] // 2
+    shape = (layers, -1, experts_per_token)
+    return (load, rows[:, :half].reshape(shape),
+            rows[:, half:].view(np.float32).reshape(shape))
 
 
 @traced
@@ -248,7 +286,7 @@ def _pool_attention(model, pool, positions, pool_kernel):
 
 
 def _decode_step_body(model, params, kv, tok, positions, *,
-                      pool_kernel=None):
+                      pool_kernel=None, live=None, moe_info=None):
     """ONE decode forward for all S slots: consume ``tok[s]`` at
     ``positions[s]``, write its (de/re)quantized K/V at that cursor,
     attend keys ``<= positions[s]`` (``_pool_attention``).
@@ -258,7 +296,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     donated, it is the same buffer. Free
     slots ride along computing garbage no one reads — their rows are
     masked out of nothing (rows are independent) and their pool writes
-    land at frozen cursors the admission prefill overwrites."""
+    land at frozen cursors the admission prefill overwrites. Routed
+    experts: ``live [S]`` (bool) names the slots that hold a request —
+    the others choose no expert and count in no load — and ``moe_info``
+    receives each layer's routing (``TransformerLM._block``)."""
     import jax.numpy as jnp
 
     h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
@@ -269,22 +310,31 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     cached_attention = _pool_attention(
         model, new_kv, positions[:, None], pool_kernel)
     for li, blk in enumerate(params["blocks"]):
-        h, _, _ = model._block(blk, h, attention=cached_attention(li),
-                               positions=positions[:, None])
+        h, _, _ = model._block(
+            blk, h, attention=cached_attention(li),
+            positions=positions[:, None], moe_info=moe_info,
+            live=None if live is None else live[:, None])
     logits = model._unembed(params, h[:, 0])               # [S, V]
     return logits, new_kv
 
 
 @traced
 def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
-                       keys, *, pool_kernel=None):
+                       keys, live=None, *, pool_kernel=None):
     """The PR-10 single-step program: one batched forward + per-slot
-    sampling. One host dispatch per token — the ``fuse_steps=1`` path."""
+    sampling. One host dispatch per token — the ``fuse_steps=1`` path.
+    Returns ``(tokens, keys, pool)``, and for a model with routed experts
+    ``(tokens, keys, pool, routing)`` (``_stack_routing``, rows = the S
+    slots)."""
     import jax
 
+    moe_info: list = []
     logits, new_kv = _decode_step_body(model, params, kv, tok, positions,
-                                       pool_kernel=pool_kernel)
+                                       pool_kernel=pool_kernel, live=live,
+                                       moe_info=moe_info)
     toks, keys = jax.vmap(sample_row)(logits, keys)
+    if model.num_experts:
+        return toks, keys, new_kv, _stack_routing(moe_info)
     return toks, keys, new_kv
 
 
@@ -308,8 +358,8 @@ def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv,
         kv, cursors, tok, remaining, keys = carry
         act = remaining > 0
         ntok, nkeys, nkv = _serve_decode_impl(
-            model, sample_row, params, kv, tok, cursors, keys,
-            pool_kernel=pool_kernel)
+            model, sample_row, params, kv, tok, cursors, keys, act,
+            pool_kernel=pool_kernel)[:3]
         tok = jnp.where(act, ntok, tok)
         keys = jnp.where(act[:, None], nkeys, keys)
         cursors = jnp.where(act, cursors + 1, cursors)
@@ -532,6 +582,10 @@ class DecodeEngine:
         self._sample_row = _row_sampler(self.temperature, top_k)
         self._programs: Dict[tuple, object] = {}
         self.program_builds = 0
+        # routed experts: the routing (``_stack_routing``, a device
+        # array) of the latest prefill or plain decode dispatch; None for
+        # a dense model
+        self.moe_routing = None
 
         # ---- speculative-decoding configuration
         if draft_model is not None and draft_layers:
@@ -641,12 +695,11 @@ class DecodeEngine:
             return jax.jit(fn, donate_argnums=(1,))
 
         run = self._program((kind, int(padded.shape[0])), build)
-        tok, key, state = run(model.params, cache.state,
-                              jnp.asarray(padded)[None],
-                              jnp.asarray(plen, jnp.int32),
-                              jnp.asarray(slot, jnp.int32), key)
+        tok, key, state, *routing = run(
+            model.params, cache.state, jnp.asarray(padded)[None],
+            jnp.asarray(plen, jnp.int32), jnp.asarray(slot, jnp.int32), key)
         cache.install(state)
-        return tok, key
+        return tok, key, routing[0] if routing else None
 
     def prefill(self, prompt, slot: int, key) -> Tuple[object, object]:
         """Admit one prompt ([t] int) into ``slot``: bucket-pad, run the
@@ -660,8 +713,8 @@ class DecodeEngine:
             raise ValueError(f"prompt must be [t] (got {prompt.shape})")
         bucket = self.prompt_bucket(int(prompt.shape[0]))
         padded, plen = pad_prompt(prompt, bucket)
-        tok, key = self._prefill_one("prefill", self.model, self.cache,
-                                     padded, plen, slot, key)
+        tok, key, self.moe_routing = self._prefill_one(
+            "prefill", self.model, self.cache, padded, plen, slot, key)
         if self.spec:
             # the draft pool must hold the prompt's K/V too; its sampled
             # token (and the dummy key) are discarded — the served first
@@ -683,11 +736,13 @@ class DecodeEngine:
         return jax.jit(functools.partial(impl, *bound, **kw),
                        donate_argnums=donate)
 
-    def decode(self, tok, positions, keys):
+    def decode(self, tok, positions, keys, live=None):
         """One batched step (the ``fuse_steps=1`` / PR-10 path):
         ``tok``/``positions`` [S], ``keys`` [S, 2]. Returns
         ``(next_tokens [S], new_keys)``; the pool advances in place
-        (donated buffers) and the CALLER advances the cursors."""
+        (donated buffers) and the CALLER advances the cursors. With
+        routed experts ``live`` [S] (bool) names the slots that hold a
+        request, and the step's routing is left in ``moe_routing``."""
         import jax
         import jax.numpy as jnp
 
@@ -696,9 +751,15 @@ class DecodeEngine:
                 (1,), _serve_decode_impl, self.model, self._sample_row)
 
         run = self._program(("decode", self.slots), build)
-        toks, keys, state = run(self.model.params, self.cache.state,
-                                jnp.asarray(tok, jnp.int32),
-                                jnp.asarray(positions, jnp.int32), keys)
+        args = [self.model.params, self.cache.state,
+                jnp.asarray(tok, jnp.int32),
+                jnp.asarray(positions, jnp.int32), keys]
+        if self.model.num_experts:
+            args.append(jnp.ones(self.slots, bool) if live is None
+                        else jnp.asarray(live, bool))
+        toks, keys, state, *routing = run(*args)
+        if routing:
+            self.moe_routing = routing[0]
         self.cache.install(state)
         return toks, keys
 

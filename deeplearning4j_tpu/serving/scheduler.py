@@ -340,6 +340,12 @@ class ServeRequest:   # field-wise eq would compare prompt arrays
     # a hedged duplicate that lost the race: the server retires it
     # without counting it finished the next time it looks at it
     canceled: bool = False
+    # DecodeServer(record_routing=True), routed experts only: one
+    # ``(experts, weights)`` pair of ``[layers, n, k]`` arrays for the
+    # prompt (n = its length) and one (n = 1) for each decode step that
+    # emitted a token, in order: position p of ``output[:-1]`` was served
+    # by row p of their concatenation along axis 1
+    routing: Optional[list] = None
 
     def expired(self, now: float) -> bool:
         return self.deadline_s is not None and now > self.deadline_s

@@ -68,7 +68,7 @@ from typing import Callable, Deque, List, Optional, Sequence, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.monitor import metrics, tracer
-from deeplearning4j_tpu.serving.engine import DecodeEngine
+from deeplearning4j_tpu.serving.engine import DecodeEngine, unpack_routing
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
     criticality_rank, serve_deadline_s, serve_draft_layers,
@@ -95,7 +95,7 @@ class DecodeServer:
                  kv_dtype: Optional[str] = None,
                  draft_model=None, draft_layers: Optional[int] = None,
                  spec_tokens: int = 3, mesh=None,
-                 clock=time.monotonic):
+                 clock=time.monotonic, record_routing: bool = False):
         self.fuse_steps = (fuse_steps if fuse_steps is not None
                            else serve_fuse_steps())
         if self.fuse_steps < 1:
@@ -144,8 +144,28 @@ class DecodeServer:
         self.slot_dispatches = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # routed experts: (token, expert) pairs every expert of every
+        # layer has received from live rows, prefills and plain decode
+        # steps alike ([L, E]; an empty array for a dense model)
+        self.moe_expert_load = np.zeros(
+            (model.num_layers if model.num_experts else 0,
+             model.num_experts), np.int64)
         self._decode_kind = ("spec" if self.engine.spec else
                              "fused" if self.fuse_steps > 1 else "plain")
+        # record_routing: every request keeps the experts that served
+        # each of its positions, and their weights, as the prefill and
+        # decode programs chose them (``ServeRequest.routing``): what a
+        # check against a reference needs, since activations rounded to
+        # bf16 flip near-ties of the router. They reach the host with
+        # the load whatever this says (one array, 9 KiB a step at 32
+        # slots, 4 layers, 8 of 64 experts a token); this keeps them.
+        self.record_routing = bool(record_routing)
+        if self.record_routing and not (model.num_experts
+                                        and self._decode_kind == "plain"):
+            raise ValueError(
+                "record_routing needs a model with routed experts and "
+                "the plain decode step (fuse_steps=1, no draft model)")
+        self._routing = None     # (experts, weights) of the last dispatch
         self._reg = metrics()
 
     def _zero_keys(self):
@@ -395,11 +415,14 @@ class DecodeServer:
                     self._draft_keys = self._draft_keys.at[slot].set(
                         jax.random.fold_in(key, 0x5bec))
                 tok, key = self.engine.prefill(req.prompt, slot, key)
-                tok = int(tok)
+                tok = int(self._read_block(tok))
                 now = self.clock()
                 req.state = "running"
                 req.slot = slot
                 req.first_token_s = now
+            if self.record_routing:
+                req.routing = [tuple(a[:, :prompt_len].copy()
+                                     for a in self._routing)]
             req.tokens.append(tok)
             self._slot_req[slot] = req
             self._last_tok[slot] = tok
@@ -449,12 +472,45 @@ class DecodeServer:
             toks, self._keys = self.engine.decode_fused(
                 self._last_tok, remaining, self._keys, self.fuse_steps)
             return np.asarray(toks), None        # [K, S]
-        toks, self._keys = self.engine.decode(
-            self._last_tok, self.engine.cache.cursors, self._keys)
         live_mask = np.zeros(self.slots, bool)
         live_mask[live] = True
+        toks, self._keys = self.engine.decode(
+            self._last_tok, self.engine.cache.cursors, self._keys,
+            live=live_mask)
         self.engine.cache.advance(live_mask)
-        return np.asarray(toks)[None], None      # [1, S]
+        return self._read_block(toks)[None], None      # [1, S]
+
+    def _read_block(self, toks):
+        """The dispatch's token block on the host. A model with routed
+        experts hands its ``[layers, experts]`` load over in the same
+        read-back; it is booked here: ``moe_expert_load`` (``stats()``),
+        the counter ``serve_moe_routed_pairs_total``, the gauge
+        ``serve_moe_max_expert_share`` (the busiest expert's share of its
+        layer's pairs in this dispatch) and ``experts_touched`` on the
+        span open now, ``serve.decode`` or ``serve.prefill`` (the (layer,
+        expert) cells that received a token). The rows' experts and
+        weights come in the same array (``engine._stack_routing``);
+        ``record_routing`` keeps them for the caller (``_routing``)."""
+        if self.engine.moe_routing is None:
+            return np.asarray(toks)
+        import jax
+
+        toks, packed = jax.device_get((toks, self.engine.moe_routing))
+        load, *rows = unpack_routing(packed, self.model.num_experts,
+                                     self.model.experts_per_token)
+        if self.record_routing:
+            self._routing = rows
+        self.moe_expert_load += load
+        pairs = int(load.sum())
+        if pairs:
+            self._reg.counter("serve_moe_routed_pairs_total").inc(pairs)
+            self._reg.gauge("serve_moe_max_expert_share").set(
+                float((load.max(axis=1) / np.maximum(
+                    load.sum(axis=1), 1)).max()))
+        span = tracer().current()
+        if span is not None:
+            span.attrs["experts_touched"] = int(np.count_nonzero(load))
+        return toks
 
     def _sweep_expired(self) -> None:
         """The retirement loop's deadline check: an in-flight request
@@ -528,6 +584,9 @@ class DecodeServer:
                     if len(got) >= rem:
                         break
             req.tokens.extend(got)
+            if req.routing is not None:  # the row that emitted this token
+                req.routing.append(tuple(a[:, slot:slot + 1]
+                                         for a in self._routing))
             emitted_total += len(got)
             # with fusion the K tokens land together: spread the
             # dispatch interval evenly so TPOT keeps one observation
@@ -616,6 +675,8 @@ class DecodeServer:
             "speculative": self.engine.spec,
             "compiles": self.engine.compile_counts(),
         }
+        if self.model.num_experts:
+            out["moe_expert_load"] = self.moe_expert_load.tolist()
         if self.engine.spec:
             out["spec_tokens"] = self.engine.spec_tokens
             out["spec_proposed"] = self.spec_proposed

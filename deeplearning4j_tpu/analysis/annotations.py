@@ -82,6 +82,7 @@ HOT_PATH_REGISTRY = frozenset({
     # multi-token-verify bodies.
     "_serve_prefill_impl",
     "_serve_decode_impl",
+    "_serve_decode_loop_impl",
     "_serve_decode_fused_impl",
     "_serve_spec_impl",
     "_serve_verify_impl",

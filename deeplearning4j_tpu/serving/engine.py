@@ -12,7 +12,11 @@ compiles outside it:
   scatter the consumed tokens' K/V at each slot's cursor, attend each row
   against its own masked cache history (GQA-aware — the pool stores
   ``num_kv_heads``), sample one token per row from per-slot RNG streams.
-  The ``fuse_steps=1`` path: one dispatch per token.
+  The ``fuse_steps=1`` path: one dispatch per token. Its inputs are the
+  pool and the loop state (``SlotKVCache.loop``: cursors, last tokens,
+  tokens owed, keys), all on the device, and it returns them advanced
+  (``kv_cache.advance_loop``): step *n + 1* is dispatched from step
+  *n*'s outputs before the host has read a token of them.
 - ``("decode_fused", S, K)`` — K decode steps as one ``lax.scan``: the
   single-step body runs K times in-program (per-slot cursors advance on
   device, RNG streams split in-program, K/V scatters land per step) and
@@ -20,6 +24,10 @@ compiles outside it:
   Per-slot ``remaining`` counts freeze retired/short slots mid-scan: a
   frozen slot's token/cursor/key carry unchanged while its rows ride
   along computing garbage no one reads.
+- ``("slot_admit",)`` — the loop state's one write from the host
+  (``kv_cache.slot_admit``): a request enters a slot after its prefill
+  or hand-off, or leaves it before its last token (the same write with
+  nothing owed). One small program.
 - ``("decode_spec", S, K, G)`` — speculative decoding: K rounds per
   dispatch, each round drafting G tokens with the draft model (its own
   slot pool, positions derived from the shared cursors), verifying all
@@ -74,7 +82,7 @@ from deeplearning4j_tpu.pallas.flash_attention import flash_default_interpret
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
 from deeplearning4j_tpu.serving.kv_cache import (
-    SlotKVCache, dequant_slab, write_pool_rows)
+    SlotKVCache, advance_loop, dequant_slab, slot_admit, write_pool_rows)
 
 __all__ = ["DecodeEngine"]
 
@@ -339,36 +347,41 @@ def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
 
 
 @traced
-def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv,
-                             cursors, tok, remaining, keys, *,
-                             pool_kernel=None):
-    """K decode steps as ONE ``lax.scan``: sampling, per-slot RNG
-    splits, K/V scatter writes, and cursor advancement all move
-    in-program. ``remaining[s]`` tokens still owed per slot gates an
-    active mask each step: a slot that hits zero mid-scan self-freezes —
-    token/key/cursor/remaining carry unchanged (its rows still compute,
-    writing garbage at its frozen cursor: a position beyond its mask
-    that the next prefill rewrites). Emits the ``[K, S]`` token block;
+def _serve_decode_loop_impl(model, sample_row, params, kv, loop, *,
+                            pool_kernel=None):
+    """The plain decode program (``fuse_steps=1``): the PR-10 step on
+    the device's own loop state. ``loop`` is ``SlotKVCache.loop``; a
+    slot is live while it owes a token (``remaining > 0`` — the routed
+    experts' ``live`` mask), consumes ``tok`` at its cursor and takes the
+    sampled token; ``advance_loop`` moves the state on. Returns ``(loop,
+    pool)`` and, for a model with routed experts, ``(loop, pool,
+    routing)``: the token block of the step is ``loop["tok"]``."""
+    out = _serve_decode_impl(
+        model, sample_row, params, kv, loop["tok"], loop["cursors"],
+        loop["keys"], loop["remaining"] > 0, pool_kernel=pool_kernel)
+    return (advance_loop(loop, out[0], out[1]),) + tuple(out[2:])
+
+
+@traced
+def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv, loop,
+                             *, pool_kernel=None):
+    """K decode steps as ONE ``lax.scan`` of the plain program's body:
+    sampling, per-slot RNG splits, K/V scatter writes and the loop state
+    all move in-program. A slot that owes nothing more mid-scan
+    self-freezes (``advance_loop``). Emits the ``[K, S]`` token block;
     rows past a slot's remaining repeat its final token and the host
-    truncates by its own bookkeeping."""
-    import jax.numpy as jnp
+    truncates by its own bookkeeping. Returns ``(toks, loop, pool)``."""
     from jax import lax
 
     def body(carry, _):
-        kv, cursors, tok, remaining, keys = carry
-        act = remaining > 0
-        ntok, nkeys, nkv = _serve_decode_impl(
-            model, sample_row, params, kv, tok, cursors, keys, act,
-            pool_kernel=pool_kernel)[:3]
-        tok = jnp.where(act, ntok, tok)
-        keys = jnp.where(act[:, None], nkeys, keys)
-        cursors = jnp.where(act, cursors + 1, cursors)
-        remaining = jnp.where(act, remaining - 1, remaining)
-        return (nkv, cursors, tok, remaining, keys), tok
+        kv, loop = carry
+        loop, kv = _serve_decode_loop_impl(
+            model, sample_row, params, kv, loop,
+            pool_kernel=pool_kernel)[:2]
+        return (kv, loop), loop["tok"]
 
-    (kv, cursors, _, _, keys), toks = lax.scan(
-        body, (kv, cursors, tok, remaining, keys), None, length=k_steps)
-    return toks, cursors, keys, kv
+    (kv, loop), toks = lax.scan(body, (kv, loop), None, length=k_steps)
+    return toks, loop, kv
 
 
 @traced
@@ -398,9 +411,8 @@ def _serve_verify_impl(model, params, kv, toks, positions, *,
 
 @traced
 def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
-                     k_rounds, params, draft_params, kv, draft_kv,
-                     cursors, tok, remaining, keys, draft_keys, *,
-                     pool_kernel=None):
+                     k_rounds, params, draft_params, kv, draft_kv, loop,
+                     draft_keys, *, pool_kernel=None):
     """K speculative rounds as ONE program. Per round and live slot:
 
     1. **draft** — ``gamma + 1`` draft-model steps from the shared
@@ -420,11 +432,15 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
        acceptance sample the bonus from ``p`` — which draws from the
        target model's exact (temperature/top-k filtered) distribution.
 
-    Cursors advance by ``accepted + 1``; the draft pool needs no cursor
-    of its own (positions derive from the shared cursors, and rejected
-    candidates' draft K/V sit beyond the rewound cursor exactly like the
-    target pool's). Emits ``[K, S, G + 2]`` blocks: per round,
-    ``[count, e_1..e_{G+1}]`` per slot (count = 0 for frozen slots)."""
+    ``loop`` is the target pool's loop state (``SlotKVCache.loop``);
+    cursors advance by ``accepted + 1`` and ``remaining`` falls by as
+    much (floored at zero: the host truncates the last round's tokens by
+    its own bookkeeping). The draft pool needs no cursor of its own
+    (positions derive from the shared cursors, and rejected candidates'
+    draft K/V sit beyond the rewound cursor exactly like the target
+    pool's). Emits ``[K, S, G + 2]`` blocks: per round, ``[count,
+    e_1..e_{G+1}]`` per slot (count = 0 for frozen slots). Returns
+    ``(blocks, loop, draft_keys, pool, draft_pool)``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -518,10 +534,14 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
         return (kv, draft_kv, cursors, tok, remaining, keys,
                 draft_keys), block
 
-    (kv, draft_kv, cursors, _, _, keys, draft_keys), blocks = lax.scan(
-        round_body, (kv, draft_kv, cursors, tok, remaining, keys,
-                     draft_keys), None, length=k_rounds)
-    return blocks, cursors, keys, draft_keys, kv, draft_kv
+    (kv, draft_kv, cursors, tok, remaining, keys, draft_keys), blocks = (
+        lax.scan(round_body,
+                 (kv, draft_kv, loop["cursors"], loop["tok"],
+                  loop["remaining"], loop["keys"], draft_keys), None,
+                 length=k_rounds))
+    loop = {"cursors": cursors, "tok": tok, "remaining": remaining,
+            "keys": keys}
+    return blocks, loop, draft_keys, kv, draft_kv
 
 
 class DecodeEngine:
@@ -582,10 +602,6 @@ class DecodeEngine:
         self._sample_row = _row_sampler(self.temperature, top_k)
         self._programs: Dict[tuple, object] = {}
         self.program_builds = 0
-        # routed experts: the routing (``_stack_routing``, a device
-        # array) of the latest prefill or plain decode dispatch; None for
-        # a dense model
-        self.moe_routing = None
 
         # ---- speculative-decoding configuration
         if draft_model is not None and draft_layers:
@@ -608,7 +624,7 @@ class DecodeEngine:
                     f"draft vocab {draft_model.vocab_size} != target "
                     f"vocab {model.vocab_size}")
             self.draft_model = draft_model
-        self.draft_cache = None
+        self.draft_cache = self.draft_keys = None
         if self.draft_model is not None:
             draft_reg = self.registry
             if self.registry is not None and draft_model is not None:
@@ -628,6 +644,10 @@ class DecodeEngine:
             self.draft_cache = SlotKVCache(
                 self.draft_model, self.slots, self.max_len, kv_dtype,
                 registry=draft_reg)
+            # the draft's per-slot RNG streams (only the sampled
+            # speculative path consumes them); its pool needs no loop
+            # state of its own
+            self.draft_keys = self.draft_cache.loop["keys"]
 
     @property
     def spec(self) -> bool:
@@ -674,12 +694,19 @@ class DecodeEngine:
                 "prefill_buckets": pre,
                 "total": self.program_builds}
 
-    def cursor_of(self, slot: int) -> int:
-        """Host readback of one slot's live cursor — sanctioned ONLY at
-        migration boundaries (graceful drain exports a mid-stream slot
-        once per request, like the prefill/decode handoff's export),
-        never inside the decode loop where cursors advance on device."""
-        return int(np.asarray(self.cache.cursors)[slot])
+    def slot_state(self, slot: int) -> Tuple[int, int, np.ndarray]:
+        """Host readback of one slot's ``(cursor, last token, key)`` —
+        sanctioned ONLY at migration boundaries (graceful drain exports a
+        mid-stream slot once per request, like the prefill/decode
+        handoff's export), never inside the decode loop where the state
+        advances on device. It is the state after every DISPATCHED step:
+        a server reads its unread token block first
+        (``DecodeServer.flush``)."""
+        import jax
+
+        loop = jax.device_get(self.cache.loop)
+        return (int(loop["cursors"][slot]), int(loop["tok"][slot]),
+                loop["keys"][slot])
 
     # ------------------------------------------------------------------
     def prompt_bucket(self, n: int) -> int:
@@ -701,11 +728,13 @@ class DecodeEngine:
         cache.install(state)
         return tok, key, routing[0] if routing else None
 
-    def prefill(self, prompt, slot: int, key) -> Tuple[object, object]:
-        """Admit one prompt ([t] int) into ``slot``: bucket-pad, run the
-        prefill program (plus the draft-pool prefill when speculative
-        decoding is on), start the cursor at ``prompt_len``. Returns
-        ``(first_token, new_key)`` (device scalars)."""
+    def prefill(self, prompt, slot: int, key):
+        """One prompt ([t] int) into ``slot``'s pool rows: bucket-pad, run
+        the prefill program (plus the draft-pool prefill when speculative
+        decoding is on). Returns ``(first_token, new_key, routing)``
+        (device values; ``routing`` is ``_stack_routing``'s array, None
+        for a dense model). The slot decodes once ``admit_slot`` has
+        written its loop state."""
         import jax
 
         prompt = np.asarray(prompt, np.int32)
@@ -713,7 +742,7 @@ class DecodeEngine:
             raise ValueError(f"prompt must be [t] (got {prompt.shape})")
         bucket = self.prompt_bucket(int(prompt.shape[0]))
         padded, plen = pad_prompt(prompt, bucket)
-        tok, key, self.moe_routing = self._prefill_one(
+        tok, key, routing = self._prefill_one(
             "prefill", self.model, self.cache, padded, plen, slot, key)
         if self.spec:
             # the draft pool must hold the prompt's K/V too; its sampled
@@ -722,8 +751,30 @@ class DecodeEngine:
             self._prefill_one("prefill_draft", self.draft_model,
                               self.draft_cache, padded, plen, slot,
                               jax.random.PRNGKey(0))
-        self.cache.set_cursor(slot, plen)
-        return tok, key
+        return tok, key, routing
+
+    def admit_slot(self, slot: int, tok, cursor: int, remaining: int,
+                   key) -> None:
+        """A request enters ``slot``'s loop state (one program): ``tok``
+        its last emitted token (a device scalar straight from the prefill
+        program, or a hand-off's int), ``cursor`` where that token's K/V
+        will land, ``remaining`` the tokens it is still owed, ``key`` its
+        RNG stream."""
+        import jax
+        import jax.numpy as jnp
+
+        run = self._program(("slot_admit",), lambda: jax.jit(slot_admit))
+        self.cache.loop = run(
+            self.cache.loop, np.asarray([slot, cursor, remaining], np.int32),
+            jnp.asarray(tok, jnp.int32), key)
+
+    def release_slot(self, slot: int) -> None:
+        """The request in ``slot`` leaves before its last token: the
+        slot's loop state with nothing owed, so the next decode step
+        freezes it (the admission program again: no program the first
+        request did not already run)."""
+        keys = self.cache.loop["keys"]
+        self.admit_slot(slot, 0, 0, 0, np.zeros(keys.shape[1:], keys.dtype))
 
     def _decode_jit(self, donate, impl, *bound):
         """The jitted decode-family program ``impl`` with its static
@@ -736,67 +787,46 @@ class DecodeEngine:
         return jax.jit(functools.partial(impl, *bound, **kw),
                        donate_argnums=donate)
 
-    def decode(self, tok, positions, keys, live=None):
-        """One batched step (the ``fuse_steps=1`` / PR-10 path):
-        ``tok``/``positions`` [S], ``keys`` [S, 2]. Returns
-        ``(next_tokens [S], new_keys)``; the pool advances in place
-        (donated buffers) and the CALLER advances the cursors. With
-        routed experts ``live`` [S] (bool) names the slots that hold a
-        request, and the step's routing is left in ``moe_routing``."""
-        import jax
-        import jax.numpy as jnp
-
+    def decode(self):
+        """One batched step (the ``fuse_steps=1`` / PR-10 path) from the
+        loop state on the device to the loop state on the device: no
+        argument comes from the host. Returns ``(tokens [S], routing)``
+        (device; ``routing`` None for a dense model): a live slot's
+        token is the one it just sampled, a frozen slot's its last."""
         def build():
             return self._decode_jit(
-                (1,), _serve_decode_impl, self.model, self._sample_row)
+                (1,), _serve_decode_loop_impl, self.model, self._sample_row)
 
         run = self._program(("decode", self.slots), build)
-        args = [self.model.params, self.cache.state,
-                jnp.asarray(tok, jnp.int32),
-                jnp.asarray(positions, jnp.int32), keys]
-        if self.model.num_experts:
-            args.append(jnp.ones(self.slots, bool) if live is None
-                        else jnp.asarray(live, bool))
-        toks, keys, state, *routing = run(*args)
-        if routing:
-            self.moe_routing = routing[0]
+        self.cache.loop, state, *routing = run(
+            self.model.params, self.cache.state, self.cache.loop)
         self.cache.install(state)
-        return toks, keys
+        return self.cache.loop["tok"], routing[0] if routing else None
 
-    def decode_fused(self, tok, remaining, keys, k_steps: int):
+    def decode_fused(self, k_steps: int):
         """K decode steps as ONE dispatch: returns the ``[K, S]`` token
-        block (device) + new keys; pool and cursors advance in place."""
-        import jax
-        import jax.numpy as jnp
-
+        block (device); pool and loop state advance in place."""
         def build():
             return self._decode_jit(
-                (1, 2), _serve_decode_fused_impl, self.model,
+                (1,), _serve_decode_fused_impl, self.model,
                 self._sample_row, k_steps)
 
         run = self._program(("decode_fused", self.slots, k_steps), build)
-        toks, cursors, keys, state = run(
-            self.model.params, self.cache.state, self.cache.cursors,
-            jnp.asarray(tok, jnp.int32),
-            jnp.asarray(remaining, jnp.int32), keys)
+        toks, self.cache.loop, state = run(
+            self.model.params, self.cache.state, self.cache.loop)
         self.cache.install(state)
-        self.cache.cursors = cursors
-        return toks, keys
+        return toks
 
-    def decode_spec(self, tok, remaining, keys, draft_keys,
-                    k_rounds: int):
+    def decode_spec(self, k_rounds: int):
         """K speculative rounds as ONE dispatch: returns the
         ``[K, S, spec_tokens + 2]`` block (per round and slot:
-        ``[count, tokens...]``) + new target/draft keys; both pools and
-        the cursors advance in place."""
-        import jax
-        import jax.numpy as jnp
-
+        ``[count, tokens...]``); both pools, the loop state and the
+        draft's RNG streams (``draft_keys``) advance in place."""
         greedy = self.temperature == 0.0
 
         def build():
             return self._decode_jit(
-                (2, 3, 4), _serve_spec_impl, self.model, self.draft_model,
+                (2, 3), _serve_spec_impl, self.model, self.draft_model,
                 None if greedy else _filtered_logits_fn(
                     self.temperature, self.top_k),
                 self.spec_tokens, greedy, k_rounds)
@@ -804,12 +834,10 @@ class DecodeEngine:
         run = self._program(
             ("decode_spec", self.slots, k_rounds, self.spec_tokens),
             build)
-        blocks, cursors, keys, draft_keys, state, dstate = run(
+        blocks, self.cache.loop, self.draft_keys, state, dstate = run(
             self.model.params, self.draft_model.params,
-            self.cache.state, self.draft_cache.state, self.cache.cursors,
-            jnp.asarray(tok, jnp.int32),
-            jnp.asarray(remaining, jnp.int32), keys, draft_keys)
+            self.cache.state, self.draft_cache.state, self.cache.loop,
+            self.draft_keys)
         self.cache.install(state)
         self.draft_cache.install(dstate)
-        self.cache.cursors = cursors
-        return blocks, keys, draft_keys
+        return blocks

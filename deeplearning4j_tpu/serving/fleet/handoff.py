@@ -105,10 +105,12 @@ def export_slot(engine, slot: int) -> Dict[str, np.ndarray]:
 
 
 def install_slot(engine, slot: int, handoff: SlotHandoff):
-    """Land a handoff into ``slot`` of ``engine``'s pool and start the
-    cursor; returns the device RNG key to continue the stream with.
-    Validates pool compatibility — a silent dtype or capacity mismatch
-    would decode garbage with no error."""
+    """Land a handoff's slab into ``slot`` of ``engine``'s pool; returns
+    the device RNG key to continue the stream with (the slot's loop
+    state — cursor, last token, tokens owed, key — is the admitting
+    server's to write: ``DecodeEngine.admit_slot``). Validates pool
+    compatibility — a silent dtype or capacity mismatch would decode
+    garbage with no error."""
     import jax
     import jax.numpy as jnp
 
@@ -127,7 +129,6 @@ def install_slot(engine, slot: int, handoff: SlotHandoff):
                 {k: jnp.asarray(v) for k, v in handoff.slabs.items()},
                 jnp.asarray(slot, jnp.int32))
     engine.cache.install(state)
-    engine.cache.set_cursor(slot, handoff.cursor)
     return jnp.asarray(handoff.key)
 
 
@@ -138,24 +139,24 @@ def export_live_slot(server, slot: int) -> SlotHandoff:
     the RNG key is the slot's mid-chain key, and ``first_token`` is the
     newest emitted token — installing this on a survivor continues the
     stream with ZERO recompute and zero lost tokens, where failover
-    would re-prefill prompt + emitted from scratch."""
+    would re-prefill prompt + emitted from scratch. The server's unread
+    token block is read first (``flush``): the device's loop state is
+    one dispatch ahead of the request's tokens until then."""
+    server.flush()
     engine = server.engine
+    cursor, tok, key = engine.slot_state(slot)
     return SlotHandoff(
-        slabs=export_slot(engine, slot),
-        cursor=engine.cursor_of(slot),
-        key=np.asarray(server._keys[slot]),
-        first_token=int(server._last_tok[slot]),
-        kv_dtype=engine.kv_dtype,
-        max_len=engine.max_len)
+        slabs=export_slot(engine, slot), cursor=cursor, key=key,
+        first_token=tok, kv_dtype=engine.kv_dtype, max_len=engine.max_len)
 
 
 def make_install(handoff: SlotHandoff):
-    """The ``install(engine, slot) -> (last_token, key)`` callable
-    ``DecodeServer.admit_external`` runs at the step boundary that
-    claims a free slot."""
+    """The ``install(engine, slot) -> (last_token, cursor, key)``
+    callable ``DecodeServer.admit_external`` runs at the step boundary
+    that claims a free slot."""
 
     def install(engine, slot):
         key = install_slot(engine, slot, handoff)
-        return handoff.first_token, key
+        return handoff.first_token, handoff.cursor, key
 
     return install

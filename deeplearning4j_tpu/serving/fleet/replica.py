@@ -175,8 +175,8 @@ class ServeReplica:
                            replica=self.replica_id,
                            prompt_len=int(req.prompt.shape[0])):
             key = jax.random.PRNGKey(req.seed)
-            tok, key = engine.prefill(req.prompt, _PREFILL_SCRATCH_SLOT,
-                                      key)
+            tok, key, _ = engine.prefill(req.prompt,
+                                         _PREFILL_SCRATCH_SLOT, key)
             slabs = export_slot(engine, _PREFILL_SCRATCH_SLOT)
             tok = int(tok)
         now = self.clock()

@@ -854,6 +854,10 @@ class FleetRouter:
             # re-install on a survivor — the zero-recompute move
             non_spec = [r for r in self._alive_decode()
                         if not r.server.engine.spec]
+            # the victim's last dispatched token block may still be
+            # unread: book it first, so that a request it finishes
+            # retires here and every other keeps all its tokens
+            server.flush()
             for slot in list(server._live_slots()):
                 req = server._slot_req[slot]
                 fr = self._owner.get(req.id)

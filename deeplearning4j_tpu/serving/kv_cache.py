@@ -26,13 +26,18 @@ Slot lifecycle (the scheduler in ``serving/server.py`` drives it):
   stale contents in place (the next prefill overwrites them, and the
   mask keeps them unreachable meanwhile).
 
-Cursors are a DEVICE ``[S]`` int32 array: the fused multi-token decode
-program (``("decode_fused", S, K)``) advances them in-program across K
-scan steps — per-slot active masks freeze retired/short slots mid-scan —
-so the host never reads them back. The scheduler's admission decisions
-come from its own slot table (which request occupies which slot), not
-from cursor values; cursor writes happen only at fusion boundaries
-(``set_cursor`` at prefill, ``advance`` on the unfused K=1 path).
+The decode loop's per-slot state lives beside the pool as DEVICE
+``[S]`` arrays (``SlotKVCache.loop``): the cursor, the last token (the
+next step's input), the tokens still owed (``remaining``) and the RNG
+key. Every decode-family program takes them and returns them advanced —
+a slot with ``remaining > 0`` is live, consumes its token, moves its
+cursor on by one and owes one token fewer; a slot at zero freezes
+itself — so between decode steps the host sends nothing and reads only
+the token block. The scheduler's admission decisions come from its own
+slot table (which request occupies which slot), not from these values;
+the host writes them only where a request enters or leaves a slot
+(``slot_admit``: after a prefill or a hand-off, and with nothing owed on
+a deadline or a cancel).
 
 Quantized pool (``DL4J_SERVE_KV_DTYPE`` / ``kv_dtype=``): the pool is
 the dominant HBM term at high slot counts, so the store dtype is a
@@ -50,8 +55,6 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-import numpy as np
-
 from deeplearning4j_tpu.analysis.annotations import traced
 
 __all__ = [
@@ -62,6 +65,8 @@ __all__ = [
     "dequant_slab",
     "requant_write_slab",
     "write_pool_rows",
+    "advance_loop",
+    "slot_admit",
 ]
 
 _KV_DTYPES = ("float32", "bfloat16", "int8")
@@ -197,8 +202,44 @@ def requant_write_slab(slab, scale, values, rows, positions):
     return pool[0], None if scales is None else scales[0]
 
 
+# ---------------------------------------------------------------------------
+# the decode loop's per-slot state: what a decode step and the host do to it
+# ---------------------------------------------------------------------------
+@traced
+def advance_loop(loop, ntok, nkeys):
+    """One decode step's effect on the loop state: a live slot
+    (``remaining > 0``) takes its sampled token and split key, moves its
+    cursor on by one and owes one token fewer; a slot at zero carries
+    everything unchanged (its row computed garbage no one reads, written
+    at its frozen cursor: a position beyond its mask that the next
+    prefill rewrites)."""
+    import jax.numpy as jnp
+
+    act = loop["remaining"] > 0
+    return {"cursors": jnp.where(act, loop["cursors"] + 1, loop["cursors"]),
+            "tok": jnp.where(act, ntok, loop["tok"]),
+            "remaining": jnp.where(act, loop["remaining"] - 1,
+                                   loop["remaining"]),
+            "keys": jnp.where(act[:, None], nkeys, loop["keys"])}
+
+
+@traced
+def slot_admit(loop, at, tok, key):
+    """A request enters a slot: ``at`` = ``[slot, cursor, remaining]``
+    (int32, one transfer), ``tok`` its last emitted token (the next
+    step's input), ``key`` its RNG stream. A request that leaves before
+    its last token (deadline, cancel) is the same write with nothing
+    owed: the slot freezes."""
+    slot = at[0]
+    return {"cursors": loop["cursors"].at[slot].set(at[1]),
+            "tok": loop["tok"].at[slot].set(tok),
+            "remaining": loop["remaining"].at[slot].set(at[2]),
+            "keys": loop["keys"].at[slot].set(key)}
+
+
 class SlotKVCache:
-    """``[L, S, T_max, Hkv, Dh]`` K/V pools + device per-slot cursors."""
+    """``[L, S, T_max, Hkv, Dh]`` K/V pools + the decode loop's device
+    per-slot state (cursors, last tokens, tokens owed, RNG keys)."""
 
     # validate_cache_budget (monitor/memory.py) prices any cache as
     # nbytes/n_shard vs measured per-device bytes; the slot pool is
@@ -237,15 +278,24 @@ class SlotKVCache:
             self.v = jnp.zeros(shape, jnp.dtype(self.kv_dtype))
             self.k_scale = None
             self.v_scale = None
-        # per-slot write cursor: the position the NEXT consumed token's
-        # K/V lands at (== the absolute position of the last emitted,
-        # not-yet-consumed token). DEVICE state: the fused decode scan
-        # advances it in-program; the host only writes it at fusion
-        # boundaries and never reads it back.
-        self.cursors = jnp.zeros(self.slots, jnp.int32)
+        # the decode loop's per-slot state, DEVICE arrays the decode
+        # programs take and return advanced (not donated: the token block
+        # a program returns is its ``tok``, which the host reads one step
+        # later). cursors: the position the NEXT consumed token's K/V
+        # lands at (== the absolute position of the last emitted,
+        # not-yet-consumed token); tok: that token; remaining: tokens
+        # still owed (> 0 = live); keys: the slot's RNG stream. The host
+        # writes them through ``slot_admit`` only.
+        import jax
+
+        key = jax.random.PRNGKey(0)
+        self.loop = {
+            "cursors": jnp.zeros(self.slots, jnp.int32),
+            "tok": jnp.zeros(self.slots, jnp.int32),
+            "remaining": jnp.zeros(self.slots, jnp.int32),
+            "keys": jnp.zeros((self.slots,) + key.shape, key.dtype)}
         self.registry = registry
         if registry is not None:
-            import jax
             from jax.sharding import PartitionSpec as P
 
             from deeplearning4j_tpu.parallel.sharding_registry import (
@@ -260,8 +310,8 @@ class SlotKVCache:
                            registry.kv_scale_spec(model.num_kv_heads))
                 self.k_scale = jax.device_put(self.k_scale, sc)
                 self.v_scale = jax.device_put(self.v_scale, sc)
-            self.cursors = jax.device_put(
-                self.cursors, replicated_sharding(registry.mesh))
+            self.loop = jax.device_put(
+                self.loop, replicated_sharding(registry.mesh))
             if pool_spec != P():
                 # instance attr shadows the class default 1:
                 # validate_cache_budget prices nbytes/n_shard per device
@@ -291,20 +341,6 @@ class SlotKVCache:
         self.v = state["v"]
         self.k_scale = state.get("k_scale")
         self.v_scale = state.get("v_scale")
-
-    def set_cursor(self, slot: int, value: int) -> None:
-        """Admission-boundary cursor write (prefill lands a request)."""
-        import jax.numpy as jnp
-
-        self.cursors = self.cursors.at[slot].set(jnp.int32(value))
-
-    def advance(self, live_mask) -> None:
-        """Unfused (K=1) path: advance live slots' cursors by one after
-        a decode dispatch. Fused programs advance cursors in-program."""
-        import jax.numpy as jnp
-
-        self.cursors = self.cursors + jnp.asarray(
-            np.asarray(live_mask, np.int32))
 
     @property
     def nbytes(self) -> int:

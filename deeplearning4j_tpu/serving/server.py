@@ -7,24 +7,46 @@ multiplexes S concurrent requests through ONE jitted decode dispatch.
 
 The loop, per ``step()`` (a step IS a fusion boundary):
 
-1. **admit** — pop queued requests into free slots; each admission runs
-   the bucket-compiled prefill (``serve.prefill`` span), records TTFT,
-   and may retire immediately when ``max_new_tokens == 1``. Admission
-   happens ONLY here: with ``fuse_steps=K`` a request arriving mid-scan
-   waits for the dispatch in flight to finish (the admission-boundary
-   trade — bounded added TTFT, in exchange for K tokens per dispatch).
-2. **decode** — if any slot is live, run ONE decode dispatch: the plain
-   single-step program (``fuse_steps=1``, the PR-10 path, bitwise), the
+1. **sweep** — an in-flight request past its deadline, or canceled,
+   leaves its slot (its ``remaining`` on the device goes to zero).
+2. **admit** — pop queued requests into free slots; each admission runs
+   the bucket-compiled prefill (``serve.prefill`` span), writes the
+   slot's loop state (``engine.admit_slot``), records TTFT, and may
+   retire immediately when ``max_new_tokens == 1``. Admission happens
+   ONLY here: with ``fuse_steps=K`` a request arriving mid-scan waits for
+   the dispatch in flight to finish (the admission-boundary trade —
+   bounded added TTFT, in exchange for K tokens per dispatch).
+3. **decode** — if any slot is owed a token, run ONE decode dispatch:
+   the plain single-step program (``fuse_steps=1``, the PR-10 step), the
    K-step fused program, or K speculative rounds when a draft is
-   configured. Every live slot appends up to its remaining tokens;
-   finished requests retire and free their slots.
+   configured.
+4. **read and emit** — the token block reaches the host; every slot that
+   was live in it appends its tokens; finished requests retire and free
+   their slots.
+
+The loop's state — per slot the cursor, the last token, the tokens still
+owed and the RNG key — lives ON THE DEVICE (``SlotKVCache.loop``): each
+decode program takes it and returns it advanced, and the host writes it
+only where a request enters or leaves a slot. A request ends on
+``max_new_tokens`` alone, so the host knows which slots a dispatch
+serves without reading a token. The plain path therefore runs ONE STEP
+AHEAD of the host: step 3 dispatches block *n + 1* from the device's
+state before step 4 reads block *n*, the one dispatched a step earlier
+(``_unread``), and the chip computes *n + 1* while the host books *n*.
+A slot the host learns is free one read later is admitted one step
+later. With nothing unread (the first step after the server was empty)
+the order is the old one by itself; ``busy()`` stays true while a block
+is unread, and ``drain()``, ``stats()``, ``flush()`` and the fleet's
+drain-time export read it first. The fused and speculative paths keep
+their synchronous read (a speculative round's token count is the
+device's to say).
 
 The host sees one token-block readback per dispatch ([S] at K=1,
-[K, S] fused, [K, S, G+2] speculative) — that is the decode loop's
-entire host/device chatter, and it is also the synchronization point
-the per-request results come from. Everything else (queue, slot table)
-is host bookkeeping the scheduler needs anyway; the per-slot cursors
-live ON DEVICE and advance in-program.
+[K, S] fused, [K, S, G+2] speculative; a model with routed experts
+sends its routing in the same read, carried with ITS block) — that is
+the decode loop's entire host/device chatter: between two decode steps
+with no admission nothing travels host → device. Everything else
+(queue, slot table) is host bookkeeping the scheduler needs anyway.
 
 Observability: queue depth / occupancy gauges, token + dispatch
 counters (``serve_decode_steps_total`` counts DISPATCHES — with fusion
@@ -46,10 +68,13 @@ a ``dl4j.<name>`` event on the device trace's clock in any
   key, pad, prefill dispatch, cursor, the first token's read-back, up to
   ``first_token_s``), or ``serve.handoff.install``.
 - ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
-  ``spec``) — host arrays → decode dispatch → cursors advanced → the
-  token block on the host.
-- ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop,
-  histograms, retirement.
+  ``spec``, ``ahead`` = 1 when the dispatch was issued while the
+  previous block was unread) — the decode dispatch (``live`` slots; 0:
+  none was owed a token), then the read-back of the block dispatched a
+  step earlier (plain) or just now (fused, spec). ``experts_touched`` is
+  of the block READ.
+- ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop
+  over the block read, histograms, retirement.
 - ``serve.request`` (``request``, ``tokens``, ``slot``) — ``submit_s`` →
   ``finish_s``, recorded at retirement; ``request`` is
   ``ServeRequest.id`` on every span of one request.
@@ -122,13 +147,13 @@ class DecodeServer:
             max_queue if max_queue is not None else serve_max_queue())
         self.clock = clock
         self._slot_req: List[Optional[ServeRequest]] = [None] * self.slots
-        self._last_tok = np.zeros(self.slots, np.int32)
         self._last_tok_s = np.zeros(self.slots, np.float64)
-        self._keys = self._zero_keys()
-        self._draft_keys = self._zero_keys() if self.engine.spec else None
+        # the dispatched block the host has not read: ``(tokens, routing,
+        # {slot: request})`` — device arrays and the slots live in it
+        self._unread: Optional[Tuple[object, object, dict]] = None
         # externally-prefilled requests waiting for a free slot: each
-        # entry carries an ``install(engine, slot) -> (last_tok, key)``
-        # that lands the handed-off KV slab + cursor into the slot
+        # entry carries an ``install(engine, slot) -> (last_tok, cursor,
+        # key)`` that lands the handed-off KV slab into the slot
         # (serving/fleet/handoff.py builds these)
         self._handoffs: Deque[Tuple[ServeRequest, Callable]] = deque()
         self.finished: List[ServeRequest] = []
@@ -140,6 +165,7 @@ class DecodeServer:
         self.expired_in_queue = 0
         self.expired_in_flight = 0
         self.steps = 0
+        self.decode_ahead = 0
         self.decode_tokens = 0
         self.slot_dispatches = 0
         self.spec_proposed = 0
@@ -165,15 +191,7 @@ class DecodeServer:
             raise ValueError(
                 "record_routing needs a model with routed experts and "
                 "the plain decode step (fuse_steps=1, no draft model)")
-        self._routing = None     # (experts, weights) of the last dispatch
         self._reg = metrics()
-
-    def _zero_keys(self):
-        import jax
-        import jax.numpy as jnp
-
-        return jnp.zeros((self.slots,) + jax.random.PRNGKey(0).shape,
-                         jax.random.PRNGKey(0).dtype)
 
     # ------------------------------------------------------------------
     # submission
@@ -303,9 +321,9 @@ class DecodeServer:
                        install: Callable) -> None:
         """Queue an externally-prefilled request (prefill/decode split):
         at the next step boundary a free slot is claimed and
-        ``install(engine, slot) -> (last_token, rng_key)`` lands the
-        handed-off KV slab + cursor into it — the request then decodes
-        exactly like a locally-prefilled one. ``req`` must already carry
+        ``install(engine, slot) -> (last_token, cursor, rng_key)`` lands
+        the handed-off KV slab into it — the request then decodes exactly
+        like a locally-prefilled one. ``req`` must already carry
         its first token (the prefill replica sampled it); its TTFT was
         recorded at prefill time, so this path never re-observes it."""
         if self.engine.spec:
@@ -343,21 +361,21 @@ class DecodeServer:
 
     def busy(self) -> bool:
         return (bool(self._live_slots()) or len(self.queue) > 0
-                or bool(self._handoffs))
+                or bool(self._handoffs) or self._unread is not None)
 
     def _admit_handoff(self, slot: int) -> None:
         req, install = self._handoffs.popleft()
         with tracer().span("serve.handoff.install", request=req.id,
                            slot=slot):
-            last_tok, key = install(self.engine, slot)
+            last_tok, cursor, key = install(self.engine, slot)
+            self.engine.admit_slot(slot, last_tok, cursor,
+                                   req.max_new_tokens - len(req.tokens), key)
         now = self.clock()
         req.state = "running"
         req.handoff = True
         req.slot = slot
         self._slot_req[slot] = req
-        self._last_tok[slot] = int(last_tok)
         self._last_tok_s[slot] = now
-        self._keys = self._keys.at[slot].set(key)
         # TTFT was recorded by the prefill replica; the installed slab
         # already covers every emitted token, so a request that arrived
         # complete just retires
@@ -412,22 +430,25 @@ class DecodeServer:
                 if self.engine.spec:
                     # an independent per-slot draft stream (only the
                     # sampled speculative path consumes it)
-                    self._draft_keys = self._draft_keys.at[slot].set(
-                        jax.random.fold_in(key, 0x5bec))
-                tok, key = self.engine.prefill(req.prompt, slot, key)
-                tok = int(self._read_block(tok))
+                    self.engine.draft_keys = self.engine.draft_keys.at[
+                        slot].set(jax.random.fold_in(key, 0x5bec))
+                tok, key, routing = self.engine.prefill(req.prompt, slot,
+                                                        key)
+                # the slot's loop state straight from the program's
+                # outputs, queued on the device before the host waits
+                self.engine.admit_slot(slot, tok, prompt_len,
+                                       req.max_new_tokens - 1, key)
+                tok, rows = self._read_block(tok, routing)
                 now = self.clock()
                 req.state = "running"
                 req.slot = slot
                 req.first_token_s = now
             if self.record_routing:
                 req.routing = [tuple(a[:, :prompt_len].copy()
-                                     for a in self._routing)]
-            req.tokens.append(tok)
+                                     for a in rows)]
+            req.tokens.append(int(tok))
             self._slot_req[slot] = req
-            self._last_tok[slot] = tok
             self._last_tok_s[slot] = now
-            self._keys = self._keys.at[slot].set(key)
             if req.ttft_s is not None:
                 self._reg.histogram("serve_ttft_seconds",
                                     buckets=_LATENCY_BUCKETS
@@ -453,53 +474,47 @@ class DecodeServer:
                             request=req.id, tokens=len(req.tokens),
                             slot=slot)
 
-    def _dispatch(self, live: List[int]):
-        """ONE decode dispatch for the current live set. Returns
-        ``(toks [K, S], counts [K, S] or None)`` as host arrays — the
-        loop's one sanctioned readback. ``counts`` is None outside the
-        speculative path (every fused row emits exactly one token)."""
-        remaining = np.zeros(self.slots, np.int32)
-        for slot in live:
-            req = self._slot_req[slot]
-            remaining[slot] = req.max_new_tokens - len(req.tokens)
-        if self.engine.spec:
-            block, self._keys, self._draft_keys = self.engine.decode_spec(
-                self._last_tok, remaining, self._keys, self._draft_keys,
-                self.fuse_steps)
-            block = np.asarray(block)            # [K, S, G+2]
-            return block[:, :, 1:], block[:, :, 0]
-        if self.fuse_steps > 1:
-            toks, self._keys = self.engine.decode_fused(
-                self._last_tok, remaining, self._keys, self.fuse_steps)
-            return np.asarray(toks), None        # [K, S]
-        live_mask = np.zeros(self.slots, bool)
-        live_mask[live] = True
-        toks, self._keys = self.engine.decode(
-            self._last_tok, self.engine.cache.cursors, self._keys,
-            live=live_mask)
-        self.engine.cache.advance(live_mask)
-        return self._read_block(toks)[None], None      # [1, S]
+    def _owed(self) -> dict:
+        """``{slot: request}`` of the slots owed a token that no
+        dispatched block holds yet: the live set of the next dispatch,
+        known without reading a token because a request ends on
+        ``max_new_tokens`` alone (the device's ``remaining > 0``)."""
+        pending = self._unread[2] if self._unread is not None else {}
+        return {s: r for s, r in enumerate(self._slot_req)
+                if r is not None and r.max_new_tokens - len(r.tokens)
+                > (pending.get(s) is r)}
 
-    def _read_block(self, toks):
-        """The dispatch's token block on the host. A model with routed
-        experts hands its ``[layers, experts]`` load over in the same
-        read-back; it is booked here: ``moe_expert_load`` (``stats()``),
-        the counter ``serve_moe_routed_pairs_total``, the gauge
+    def _dispatch(self, live: dict):
+        """ONE decode dispatch for the live set, from the loop state on
+        the device: nothing is sent. Returns the block as dispatched,
+        ``(tokens, routing, live)`` with device arrays — tokens [S] plain,
+        [K, S] fused, [K, S, G+2] speculative."""
+        if self.engine.spec:
+            return self.engine.decode_spec(self.fuse_steps), None, live
+        if self.fuse_steps > 1:
+            return self.engine.decode_fused(self.fuse_steps), None, live
+        return self.engine.decode() + (live,)
+
+    def _read_block(self, toks, routing):
+        """One program's tokens on the host — the loop's one sanctioned
+        readback — with the routing that came with them: ``(tokens,
+        rows)``. A model with routed experts hands its ``[layers,
+        experts]`` load over in the same read-back; it is booked here:
+        ``moe_expert_load`` (``stats()``), the counter
+        ``serve_moe_routed_pairs_total``, the gauge
         ``serve_moe_max_expert_share`` (the busiest expert's share of its
         layer's pairs in this dispatch) and ``experts_touched`` on the
         span open now, ``serve.decode`` or ``serve.prefill`` (the (layer,
         expert) cells that received a token). The rows' experts and
-        weights come in the same array (``engine._stack_routing``);
-        ``record_routing`` keeps them for the caller (``_routing``)."""
-        if self.engine.moe_routing is None:
-            return np.asarray(toks)
+        weights come in the same array (``engine._stack_routing``):
+        ``rows`` = ``(experts, weights)``, None for a dense model."""
+        if routing is None:
+            return np.asarray(toks), None
         import jax
 
-        toks, packed = jax.device_get((toks, self.engine.moe_routing))
+        toks, packed = jax.device_get((toks, routing))
         load, *rows = unpack_routing(packed, self.model.num_experts,
                                      self.model.experts_per_token)
-        if self.record_routing:
-            self._routing = rows
         self.moe_expert_load += load
         pairs = int(load.sum())
         if pairs:
@@ -510,52 +525,89 @@ class DecodeServer:
         span = tracer().current()
         if span is not None:
             span.attrs["experts_touched"] = int(np.count_nonzero(load))
-        return toks
+        return toks, rows
 
     def _sweep_expired(self) -> None:
         """The retirement loop's deadline check: an in-flight request
         past its deadline frees its slot NOW (shed, ``in_flight``), and
         a canceled hedge loser retires quietly — both before admission,
-        so the freed slots take new work this very boundary."""
+        so the freed slots take new work this very boundary. The slots
+        stop decoding on the device (``release_slot``), and a token of
+        theirs in the unread block is dropped when it is read."""
         now = self.clock()
         for slot in self._live_slots():
             req = self._slot_req[slot]
             if req.canceled:
                 req.state = "canceled"
-                self._slot_req[slot] = None
                 self._reg.counter("serve_requests_total").inc(
                     event="canceled")
             elif req.expired(now):
-                self._slot_req[slot] = None
                 self._shed(req, where="in_flight", reason="deadline",
                            now=now)
+            else:
+                continue
+            self._slot_req[slot] = None
+            self.engine.release_slot(slot)
 
     def step(self) -> bool:
         """One scheduler iteration: shed expired/canceled slots, admit
-        at the fusion boundary, then one decode dispatch (1, K, or K
-        speculative rounds of tokens). Returns False when nothing was
-        live (the caller may idle)."""
+        at the fusion boundary, one decode dispatch (1, K, or K
+        speculative rounds of tokens), then read and book a token block
+        — on the plain path the one dispatched a step EARLIER, so the
+        chip runs this step's dispatch meanwhile. Returns False when
+        nothing was dispatched or read (the caller may idle)."""
         with tracer().span("serve.step") as sp:
             self._sweep_expired()
             sp.attrs["admitted"] = self._admit()
-            live = self._live_slots()
+            live = self._owed()
             self._reg.gauge("serve_queue_depth").set(len(self.queue))
-            self._reg.gauge("serve_slot_occupancy").set(
-                len(live) / self.slots)
-            if not live:
+            self._reg.gauge("serve_slot_occupancy").set(self.occupancy())
+            if not live and self._unread is None:
                 return False
             sp.attrs["live"] = len(live)
-            with tracer().span("serve.decode", live=len(live),
-                               kind=self._decode_kind):
-                toks, counts = self._dispatch(live)
-            with tracer().span("serve.emit") as emit:
-                emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
-                    live, toks, counts)
+            self._decode(live)
             return True
 
-    def _emit(self, live: List[int], toks, counts) -> Tuple[int, int]:
-        """Book one dispatch's token block: per live slot the tokens it
-        takes, TPOT observations, retirement. Returns ``(tokens emitted,
+    def _decode(self, live: dict) -> None:
+        """The ``serve.decode`` and ``serve.emit`` phases: dispatch a
+        block for ``live`` (none when empty), then read and book the
+        block that was unread — or, on the fused and speculative paths,
+        the one just dispatched."""
+        unread, self._unread = self._unread, None
+        ahead = bool(live) and unread is not None
+        with tracer().span("serve.decode", live=len(live),
+                           kind=self._decode_kind, ahead=int(ahead)):
+            if live:
+                self._unread = self._dispatch(live)
+                if ahead:
+                    self.decode_ahead += 1
+                    self._reg.counter("serve_decode_ahead_total").inc()
+                if self._decode_kind != "plain":
+                    unread, self._unread = self._unread, None
+            if unread is None:      # the first dispatch after idling
+                return
+            toks, rows = self._read_block(*unread[:2])
+        counts = None
+        if self.engine.spec:                       # [K, S, G+2]
+            toks, counts = toks[:, :, 1:], toks[:, :, 0]
+        elif toks.ndim == 1:                       # plain: [S] -> [1, S]
+            toks = toks[None]
+        with tracer().span("serve.emit") as emit:
+            emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
+                unread[2], toks, counts, rows)
+
+    def flush(self) -> None:
+        """Read and book the block the host has not read yet (no-op with
+        none): afterwards every dispatched token is on its request and
+        the host's slot table agrees with the device's loop state."""
+        if self._unread is not None:
+            self._decode({})
+
+    def _emit(self, live: dict, toks, counts, rows) -> Tuple[int, int]:
+        """Book one dispatch's token block: per slot that was live in it
+        the tokens it takes, TPOT observations, retirement; ``rows`` is
+        the same block's routing. A slot whose request was swept while
+        the block was unread takes nothing. Returns ``(tokens emitted,
         requests retired)``."""
         now = self.clock()
         self.steps += 1
@@ -565,8 +617,9 @@ class DecodeServer:
                                    buckets=_LATENCY_BUCKETS)
         emitted_total = retired = 0
         proposed0, accepted0 = self.spec_proposed, self.spec_accepted
-        for slot in live:
-            req = self._slot_req[slot]
+        for slot, req in live.items():
+            if self._slot_req[slot] is not req:
+                continue
             rem = req.max_new_tokens - len(req.tokens)
             got: List[int] = []
             if counts is None:
@@ -586,7 +639,7 @@ class DecodeServer:
             req.tokens.extend(got)
             if req.routing is not None:  # the row that emitted this token
                 req.routing.append(tuple(a[:, slot:slot + 1]
-                                         for a in self._routing))
+                                         for a in rows))
             emitted_total += len(got)
             # with fusion the K tokens land together: spread the
             # dispatch interval evenly so TPOT keeps one observation
@@ -595,7 +648,6 @@ class DecodeServer:
                 1, len(got))
             for _ in got:
                 tpot.observe(interval)
-            self._last_tok[slot] = got[-1]
             self._last_tok_s[slot] = now
             if len(req.tokens) >= req.max_new_tokens:
                 self._retire(slot, now)
@@ -622,13 +674,16 @@ class DecodeServer:
             taken += 1
             if max_steps is not None and taken >= max_steps:
                 break
+        self.flush()
         return taken
 
     # ------------------------------------------------------------------
     def stats(self) -> dict:
         """Artifact-ready snapshot: compile counts, pool footprint,
         request/dispatch totals, and the fast-path headline ratios
-        (dispatches/token, accepted-tokens/dispatch)."""
+        (dispatches/token, accepted-tokens/dispatch). Reads the unread
+        block first (``flush``), so the counts are of every dispatch."""
+        self.flush()
         pool_bytes = self.engine.cache.nbytes
         per_slot = self.engine.cache.per_slot_nbytes
         if self.engine.draft_cache is not None:
@@ -656,6 +711,12 @@ class DecodeServer:
             # so the per-chip footprint is kv_pool_bytes / kv_shards
             "kv_shards": self.engine.cache.n_shard,
             "decode_dispatches": self.steps,
+            # plain-path dispatches issued while the previous block was
+            # unread, of all dispatches: the share of decode steps the
+            # chip did not wait for the host (0 on the first step after
+            # the server was empty, and on the fused/speculative paths)
+            "decode_ahead_share": (round(self.decode_ahead / self.steps, 4)
+                                   if self.steps else None),
             "decode_tokens": self.decode_tokens,
             "dispatches_per_token": (
                 round(self.steps / self.decode_tokens, 4)

@@ -206,19 +206,21 @@ class TestServeSpans:
     @pytest.fixture()
     def served(self, fresh_tracer):
         """One request of 3 tokens through a 2-slot server whose clock is
-        the tracer's: step 1 admits and decodes, step 2 decodes and
-        retires, step 3 finds nothing."""
+        the tracer's. The plain loop reads one step behind: step 1 admits
+        and dispatches block 1, step 2 dispatches block 2 and books block
+        1, step 3 owes nothing, books block 2 and retires, step 4 finds
+        nothing."""
         t, clock = fresh_tracer
         server = DecodeServer(_tiny_lm(), slots=2, max_len=96,
                               fuse_steps=1, clock=clock)
         req = server.submit(np.arange(1, 20, dtype=np.int32), 3)
-        progressed = [server.step(), server.step(), server.step()]
+        progressed = [server.step() for _ in range(4)]
         return t, server, req, progressed
 
     def test_one_request_is_queued_prefilled_and_retired_under_one_id(
             self, served):
         t, _server, req, progressed = served
-        assert progressed == [True, True, False]
+        assert progressed == [True, True, True, False]
         mine = [s for s in t.spans() if s.attrs.get("request") == req.id]
         by_start = sorted(mine, key=lambda s: s.start_s)
         assert [s.name for s in by_start] == ["serve.queued",
@@ -241,32 +243,37 @@ class TestServeSpans:
         t, _server, _req, _ = served
         spans = t.spans()
         steps = [s for s in spans if s.name == "serve.step"]
-        assert len(steps) == 3
+        assert len(steps) == 4
 
         def children(step):
             return sorted((s for s in spans if s.parent_id == step.span_id),
                           key=lambda s: s.start_s)
 
+        # the first dispatch after idling has no block behind it to book
         assert [s.name for s in children(steps[0])] == [
-            "serve.admit", "serve.decode", "serve.emit"]
-        # nothing waits at the second boundary: no serve.admit at all
-        assert [s.name for s in children(steps[1])] == [
-            "serve.decode", "serve.emit"]
-        assert children(steps[2]) == []
+            "serve.admit", "serve.decode"]
+        # nothing waits at the later boundaries: no serve.admit at all
+        for step in steps[1:3]:
+            assert [s.name for s in children(step)] == [
+                "serve.decode", "serve.emit"]
+        assert children(steps[3]) == []
         admit = children(steps[0])[0]
         assert admit.attrs["n"] == 1
         assert {s.name for s in spans if s.parent_id == admit.span_id} == {
             "serve.queued", "serve.prefill"}
         assert steps[0].attrs == {"admitted": 1, "live": 1}
         assert steps[1].attrs == {"admitted": 0, "live": 1}
-        assert steps[2].attrs == {"admitted": 0}
+        assert steps[2].attrs == {"admitted": 0, "live": 0}
+        assert steps[3].attrs == {"admitted": 0}
 
     def test_decode_and_emit_attrs(self, served):
         t, server, _req, _ = served
         decodes = [s for s in t.spans() if s.name == "serve.decode"]
         emits = [s for s in t.spans() if s.name == "serve.emit"]
         assert [d.attrs for d in decodes] == [
-            {"live": 1, "kind": "plain"}] * 2
+            {"live": 1, "kind": "plain", "ahead": 0},
+            {"live": 1, "kind": "plain", "ahead": 1},
+            {"live": 0, "kind": "plain", "ahead": 0}]
         assert [e.attrs for e in emits] == [
             {"tokens": 1, "retired": 0}, {"tokens": 1, "retired": 1}]
         assert server.steps == 2 and server.decode_tokens == 2
@@ -277,7 +284,7 @@ class TestServeSpans:
         """At most 4 spans a step and 3 a request (ISSUE 23)."""
         t, _server, _req, _ = served
         names = [s.name for s in t.spans()]
-        assert len(names) == 3 + 1 + 2 * 2 + 3
+        assert len(names) == 3 + 1 + 3 + 2 + 4
         assert names.count("serve.admit") == 1
 
     def test_fused_kind_and_mirror_carry_the_request(self, fresh_tracer,
@@ -288,7 +295,8 @@ class TestServeSpans:
         req = server.submit(np.arange(1, 9, dtype=np.int32), 4)
         server.drain()
         entered = {n: kw for a, n, kw in annotations if a == "enter"}
-        assert entered["dl4j.serve.decode"] == {"live": 1, "kind": "fused"}
+        assert entered["dl4j.serve.decode"] == {"live": 1, "kind": "fused",
+                                                "ahead": 0}
         assert entered["dl4j.serve.prefill"]["request"] == req.id
         assert set(entered["dl4j.serve.prefill"]) == {
             "request", "slot", "prompt_len", "bucket", "queue_wait_us"}

@@ -232,8 +232,9 @@ class TestContinuousBatching:
             srv.submit(p, m)
         srv.drain()
         warm = srv.engine.program_builds
+        # decode, two prefill rungs, and the loop state's admission write
         assert srv.engine.compile_counts() == {
-            "decode": 1, "prefill_buckets": [16, 32], "total": 3}
+            "decode": 1, "prefill_buckets": [16, 32], "total": 4}
         assert metrics().counter("serve_program_builds_total").value(
             kind="prefill") == before + 2
         # steady state: same rung menu, different lengths/counts
@@ -930,7 +931,9 @@ class TestInPlacePool:
         elif kind == "decode_fused":
             fn = functools.partial(eng._serve_decode_fused_impl, lm,
                                    sampler, 2)
-            args, donate = (lm.params, kv, vec, vec, vec, keys), (1, 2)
+            loop = {"cursors": vec, "tok": vec, "remaining": vec,
+                    "keys": keys}
+            args, donate = (lm.params, kv, loop), (1,)
         else:
             fn = functools.partial(eng._serve_verify_impl, lm)
             pos = jnp.zeros((slots, 3), jnp.int32)
@@ -991,3 +994,331 @@ class TestInPlacePool:
             assert (np.asarray(new_kv["k_scale"])[:, 0] > 0).all()
         elif scale:
             assert (np.asarray(new_kv["k_scale"]) == scale).all()
+
+
+# ---------------------------------------------------------------------------
+# the plain decode loop: state on the device, read one step behind
+# ---------------------------------------------------------------------------
+class SyncLoop:
+    """The straightforward loop the pipelined server is held to: FIFO
+    admission into free slots, then ONE decode dispatch per step from HOST
+    arrays (last tokens, cursors, keys, the live mask) through the one-step
+    program body, one blocking read, the cursors advanced by the host —
+    nothing but the pool kept on the device. Every request keeps its
+    tokens, its routing rows and the key its stream held after each
+    token."""
+
+    def __init__(self, lm, slots, max_len, **engine_kw):
+        import functools
+        from collections import deque
+
+        import jax
+        from deeplearning4j_tpu.serving import engine as eng
+
+        self.lm, self.slots = lm, slots
+        self.engine = eng.DecodeEngine(lm, slots, max_len=max_len,
+                                       **engine_kw)
+        self.run = jax.jit(functools.partial(
+            eng._serve_decode_impl, lm, self.engine._sample_row))
+        self.tok = np.zeros(slots, np.int32)
+        self.cursors = np.zeros(slots, np.int32)
+        self.keys = np.zeros((slots, 2), np.uint32)
+        self.req = [None] * slots
+        self.queue = deque()
+
+    def submit(self, prompt, max_new, seed=0):
+        from types import SimpleNamespace
+
+        req = SimpleNamespace(prompt=prompt, max_new=max_new, seed=seed,
+                              tokens=[], keys=[], routing=[])
+        self.queue.append(req)
+        return req
+
+    def _rows(self, packed):
+        from deeplearning4j_tpu.serving.engine import unpack_routing
+
+        return unpack_routing(np.asarray(packed), self.lm.num_experts,
+                              self.lm.experts_per_token)[1:]
+
+    def _took(self, slot, req, tok, key, rows):
+        req.tokens.append(int(tok))
+        req.keys.append(np.asarray(key))
+        if rows is not None:
+            req.routing.append(rows)
+        self.tok[slot], self.keys[slot] = tok, key
+        self.req[slot] = req if len(req.tokens) < req.max_new else None
+
+    def step(self):
+        import jax
+        import jax.numpy as jnp
+
+        for slot in range(self.slots):
+            if self.req[slot] is None and self.queue:
+                req = self.queue.popleft()
+                tok, key, packed = self.engine.prefill(
+                    req.prompt, slot, jax.random.PRNGKey(req.seed))
+                n = len(req.prompt)
+                self.cursors[slot] = n
+                self._took(slot, req, tok, key, None if packed is None else
+                           tuple(a[:, :n] for a in self._rows(packed)))
+        live = np.array([r is not None for r in self.req])
+        if not live.any():
+            return bool(self.queue)
+        args = [self.lm.params, self.engine.cache.state,
+                jnp.asarray(self.tok), jnp.asarray(self.cursors),
+                jnp.asarray(self.keys)]
+        if self.lm.num_experts:
+            args.append(jnp.asarray(live))
+        toks, keys, state, *packed = self.run(*args)
+        self.engine.cache.install(state)
+        toks, keys = np.asarray(toks), np.asarray(keys)
+        rows = self._rows(packed[0]) if packed else None
+        for slot in np.flatnonzero(live):
+            self._took(slot, self.req[slot], toks[slot], keys[slot],
+                       None if rows is None else
+                       tuple(a[:, slot:slot + 1] for a in rows))
+            self.cursors[slot] += 1
+        return True
+
+    def drain(self):
+        while self.step():
+            pass
+
+
+class ManualClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def _routed_lm():
+    """OLMoE's block, tiny: RMSNorm, QK-norm, untied head, RoPE, 4 SwiGLU
+    experts with 2 a token (float32: a row depends on that row alone, bit
+    for bit, whatever the batch holds)."""
+    return TransformerLM(vocab_size=61, d_model=32, num_heads=4,
+                         num_layers=2, d_ff=16, max_len=32,
+                         pos_encoding="rope", attn_impl="xla",
+                         norm="rmsnorm", qk_norm=True, num_experts=4,
+                         experts_per_token=2, tie_embeddings=False,
+                         seed=3).init()
+
+
+def _executions(fn):
+    """Device program executions ``fn()`` launches, counted in a
+    ``jax.profiler`` trace of the CPU client (one ``PjRtCpuExecutable::
+    Execute`` event a launch)."""
+    import glob
+    import tempfile
+
+    import jax
+
+    with tempfile.TemporaryDirectory() as tmp:
+        jax.profiler.start_trace(tmp)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
+                          recursive=True)
+        planes = jax.profiler.ProfileData.from_file(path).planes
+        return sum(ev.name == "PjRtCpuExecutable::Execute"
+                   for plane in planes for line in plane.lines
+                   for ev in line.events)
+
+
+class TestPipelinedLoop:
+    SLOTS, MAX_LEN, BUCKETS = 3, 32, (8, 16, 32)
+    # (prompt length, max_new_tokens): ends at admission (1), after one
+    # decode step (2), exactly at max_len (20 + 12), and more requests
+    # than slots, so a slot is re-used the step after the host sees it free
+    EARLY = [(5, 9), (11, 1), (7, 2), (20, 12), (3, 6)]
+    LATE = [(9, 5), (4, 1), (6, 8)]     # submitted mid-stream
+
+    def _server(self, lm, sampled, **kw):
+        sampling = dict(temperature=0.8, top_k=7) if sampled else {}
+        kw.setdefault("record_routing", bool(lm.num_experts))
+        return DecodeServer(lm, slots=self.SLOTS, max_len=self.MAX_LEN,
+                            buckets=self.BUCKETS, **sampling, **kw)
+
+    def _reference(self, lm, sampled):
+        sampling = dict(temperature=0.8, top_k=7) if sampled else {}
+        return SyncLoop(lm, self.SLOTS, self.MAX_LEN,
+                        buckets=self.BUCKETS, **sampling)
+
+    @staticmethod
+    def _same_routing(req, want):
+        got = [np.concatenate(x, axis=1) for x in zip(*req.routing)]
+        ref = [np.concatenate(x, axis=1) for x in zip(*want.routing)]
+        assert got[0].shape[1] == len(req.prompt) + len(req.tokens) - 1
+        for g, w in zip(got, ref):
+            assert np.array_equal(g, w)
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("model", ["dense", "routed"])
+    def test_equals_the_synchronous_loop(self, rng, model, sampled):
+        """Token for token, key for key and routing row for routing row
+        what the synchronous loop gives the same requests in the same
+        admission order, with admissions mid-stream."""
+        lm = _lm("rope", max_len=32) if model == "dense" else _routed_lm()
+        work = [(p, m, seed) for seed, (p, m) in enumerate(
+            zip(_prompts(rng, [n for n, _ in self.EARLY + self.LATE]),
+                [m for _, m in self.EARLY + self.LATE]))]
+        ref = self._reference(lm, sampled)
+        want = [ref.submit(p, m, seed) for p, m, seed in work]
+        ref.drain()
+
+        srv = self._server(lm, sampled)
+        reqs = [srv.submit(p, m, seed=seed)
+                for p, m, seed in work[:len(self.EARLY)]]
+        for _ in range(4):
+            srv.step()
+        # mid-stream, with a block unread: the device's keys are those of
+        # every DISPATCHED token, one ahead of the tokens the host holds
+        assert srv._unread is not None
+        for slot, req in srv._owed().items():
+            _, _, key = srv.engine.slot_state(slot)
+            assert np.array_equal(key, want[reqs.index(req)].keys[
+                len(req.tokens)])
+        reqs += [srv.submit(p, m, seed=seed)
+                 for p, m, seed in work[len(self.EARLY):]]
+        srv.drain()
+        assert srv._unread is None and not srv.busy()
+        assert len({r.slot for r in reqs}) < len(reqs)     # slots re-used
+        for req, ref_req in zip(reqs, want):
+            assert req.state == "finished"
+            assert req.tokens == ref_req.tokens
+            if lm.num_experts:
+                self._same_routing(req, ref_req)
+        # a slot's key froze with its last request's last token
+        last = {r.slot: i for i, r in enumerate(reqs)}
+        for slot, i in last.items():
+            cursor, tok, key = srv.engine.slot_state(slot)
+            assert np.array_equal(key, want[i].keys[-1])
+            assert tok == reqs[i].tokens[-1]
+            assert cursor == len(reqs[i].prompt) + len(reqs[i].tokens) - 1
+        assert srv.steps == srv.stats()["decode_dispatches"]
+
+    @pytest.mark.parametrize("model", ["dense", "routed"])
+    def test_deadline_and_cancel_with_a_block_in_flight(self, rng, model):
+        """A request shed on its deadline, and one canceled, while a block
+        holding their next token is unread: the token is dropped, the
+        slots stop decoding and are re-used, the others never notice."""
+        lm = _lm("rope", max_len=32) if model == "dense" else _routed_lm()
+        prompts = _prompts(rng, (5, 9, 7, 6, 4))
+        ref = self._reference(lm, False)
+        want = [ref.submit(p, 8, s) for s, p in enumerate(prompts)]
+        ref.drain()
+
+        clock = ManualClock()
+        srv = self._server(lm, False, clock=clock)
+        keep = srv.submit(prompts[0], 8, seed=0)
+        late = srv.submit(prompts[1], 8, seed=1, deadline_s=5.0)
+        loser = srv.submit(prompts[2], 8, seed=2)
+        srv.step()
+        srv.step()
+        assert set(srv._unread[2]) == {0, 1, 2}
+        held = [len(r.tokens) for r in (late, loser)]
+        clock.t = 10.0
+        loser.canceled = True
+        more = [srv.submit(p, 8, seed=s)
+                for s, p in enumerate(prompts[3:], start=3)]
+        srv.drain()
+        assert late.state == "shed" and loser.state == "canceled"
+        assert [len(late.tokens), len(loser.tokens)] == held
+        assert late.tokens == want[1].tokens[:held[0]]
+        assert srv.expired_in_flight == 1
+        assert {r.slot for r in more} == {1, 2}
+        for req, ref_req in zip([keep] + more, [want[0]] + want[3:]):
+            assert req.state == "finished"
+            assert req.tokens == ref_req.tokens
+            if lm.num_experts:
+                self._same_routing(req, ref_req)
+
+    def test_a_swept_slot_stops_decoding_on_the_device(self, rng):
+        """The lone request is canceled with its next token unread:
+        ``busy()`` holds until that block is read (and dropped), and the
+        device owes the slot nothing more."""
+        srv = self._server(_lm("rope", max_len=32), False)
+        req = srv.submit(_prompts(rng, (5,))[0], 8)
+        srv.step()
+        req.canceled = True
+        assert srv.step() and srv._unread is None       # swept, then read
+        assert req.state == "canceled" and len(req.tokens) == 1
+        assert not srv.busy() and not srv.step()
+        assert int(np.asarray(srv.engine.cache.loop["remaining"]).sum()) == 0
+        assert srv.steps == 1
+
+    def test_flush_points(self, rng):
+        """``stats()``, ``drain()`` and ``flush()`` read the unread block
+        first; ``busy()`` is true while there is one."""
+        srv = self._server(_lm("rope", max_len=32), False)
+        req = srv.submit(_prompts(rng, (5,))[0], 6)
+        srv.step()
+        srv.step()
+        assert srv._unread is not None and srv.busy()
+        assert (srv.steps, len(req.tokens)) == (1, 2)
+        assert srv.stats()["steps"] == 2 and len(req.tokens) == 3
+        assert srv._unread is None
+        srv.step()
+        srv.flush()
+        assert srv._unread is None and len(req.tokens) == 4
+        srv.flush()                                     # nothing to read
+        assert srv.steps == 3
+        assert srv.drain(max_steps=1) == 1
+        assert srv._unread is None and len(req.tokens) == 5
+        srv.drain()
+        assert req.state == "finished" and len(req.tokens) == 6
+        assert srv.steps == 5
+
+    def test_steady_step_sends_nothing_and_launches_one_program(self, rng):
+        """Between two decode steps with no admission nothing travels
+        host -> device — explicit puts (``jnp.asarray``) included, which
+        is what the loop made before its state lived on the device — and
+        exactly one device program is launched."""
+        import jax
+
+        srv = self._server(_lm("rope", max_len=32), False)
+        srv.submit(_prompts(rng, (5,))[0], 20)
+        for _ in range(3):
+            srv.step()
+        if _executions(lambda: srv.engine.decode()) != 1:
+            pytest.skip("this jax's CPU trace does not show launches")
+        srv.step()          # books the extra block's token too
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            assert _executions(srv.step) == 1
+            srv.step()
+
+    def test_decode_ahead_share(self, rng):
+        """0 for a lone 2-token request (its one dispatch had no block
+        before it), towards 1 in a long run; the counter and the span
+        attribute say the same."""
+        lm = _lm("rope", max_len=64)
+        ahead0 = metrics().counter("serve_decode_ahead_total").value()
+        srv = DecodeServer(lm, slots=2, max_len=64)
+        srv.submit(_prompts(rng, (5,))[0], 2)
+        srv.drain()
+        assert srv.stats()["decode_ahead_share"] == 0.0
+        assert srv.stats()["decode_dispatches"] == 1
+        tr = SpanTracer()
+        set_tracer(tr)
+        try:
+            srv = DecodeServer(lm, slots=2, max_len=64)
+            srv.submit(_prompts(rng, (5,))[0], 41)
+            srv.drain()
+        finally:
+            set_tracer(None)
+        assert srv.stats()["decode_ahead_share"] == round(39 / 40, 4)
+        assert metrics().counter(
+            "serve_decode_ahead_total").value() == ahead0 + 39
+        decode = [sp for sp in tr.spans() if sp.name == "serve.decode"]
+        # 40 dispatches, the first not ahead; one more span reads the last
+        assert [sp.attrs["ahead"] for sp in decode] == [0] + [1] * 39 + [0]
+        assert [sp.attrs["live"] for sp in decode] == [1] * 40 + [0]
+        # the fused path reads synchronously: never ahead
+        fused = DecodeServer(lm, slots=2, max_len=64, fuse_steps=4)
+        fused.submit(_prompts(rng, (5,))[0], 9)
+        fused.drain()
+        assert fused.stats()["decode_ahead_share"] == 0.0

@@ -461,7 +461,7 @@ class TestHandoff:
         src = DecodeServer(lm, slots=2, max_len=64)
         dst = DecodeServer(lm, slots=2, max_len=64)
         prompt = np.arange(1, 9, dtype=np.int32)
-        tok, key = src.engine.prefill(prompt, 0, jax.random.PRNGKey(0))
+        tok, key, _ = src.engine.prefill(prompt, 0, jax.random.PRNGKey(0))
         slabs = export_slot(src.engine, 0)
         handoff = SlotHandoff(slabs=slabs, cursor=len(prompt),
                               key=np.asarray(key), first_token=int(tok),
@@ -524,7 +524,7 @@ class TestHandoff:
 
         src = DecodeServer(lm, slots=2, max_len=64)
         prompt = np.arange(1, 5, dtype=np.int32)
-        tok, key = src.engine.prefill(prompt, 0, jax.random.PRNGKey(0))
+        tok, key, _ = src.engine.prefill(prompt, 0, jax.random.PRNGKey(0))
         slabs = export_slot(src.engine, 0)
 
         def handoff(**kw):

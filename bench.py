@@ -478,7 +478,7 @@ def bench_resnet18():
         net._train_step,
         (net.params, net.updater_state, net.net_state,
          jnp.asarray(0, jnp.int32), jnp.asarray(1.0, jnp.float32),
-         x, y, None, None, net._rng, None),
+         ((x,), (y,), None, None), net._rng),
         "resnet18_train_step")
     stepwise, fused = _fit_throughput(net, ds, batch, steps=10)
     sps = max(stepwise, fused)

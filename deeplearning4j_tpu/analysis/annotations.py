@@ -18,8 +18,9 @@ cannot import (fixture snippets, gated backends).
 ``HOT_PATH_REGISTRY`` is the second prong: function names that are hot
 by convention, so pre-annotation code (and code we must not churn) is
 covered without edits.  Names are matched bare, module-independent —
-every ``_step_impl`` in the tree is a hot root, which is exactly right
-for the MLN/CG twin implementations.
+every ``_micro_loss`` in the tree is a hot root, which is exactly right
+for the one function MultiLayerNetwork and ComputationGraph each supply
+to the shared ``nn/train_step.py``.
 """
 
 from __future__ import annotations
@@ -39,8 +40,9 @@ def traced(fn: F) -> F:
 
 
 # Functions that are hot roots by NAME, wherever they are defined — the
-# fused-step twins on MultiLayerNetwork/ComputationGraph, the chunk
-# program factory (its nested ``run`` is hot by containment), the
+# shared optimizer step and its grads stages, each network class's
+# micro-batch loss, the chunk program factory (its nested ``run`` is hot
+# by containment; ``_epoch_run_fn`` is the classes' delegate to it), the
 # device_eval kernels, and the traced helpers they lean on. Keep this
 # list in sync with docs/static_analysis.md.
 #
@@ -55,14 +57,14 @@ def traced(fn: F) -> F:
 # ledger_chunk_start/ledger_chunk_done/ledger_run_end/flight_record) —
 # chunk-boundary-only, never inside a traced program.
 HOT_PATH_REGISTRY = frozenset({
-    # nn/multilayer.py + nn/graph.py fused-step surface
-    "_step_impl",
-    "_accum_step_impl",
-    "_guarded_step_impl",
-    "_telemetry_step_impl",
-    "_loss_grads",
-    "_accum_loss_grads",
+    # nn/train_step.py — the one optimizer step and the programs that
+    # scan it; nn/multilayer.py + nn/graph.py supply _micro_loss
+    "optimizer_step",
+    "loss_grads",
+    "accum_grads",
+    "epoch_run_fn",
     "_epoch_run_fn",
+    "_micro_loss",
     # perf/epoch_cache.py — runs traced inside the chunk program
     "epoch_schedule",
     # perf/device_eval.py kernels (jitted inside the eval step)

@@ -20,7 +20,8 @@ fused pipeline (PRs 3–6) silently relies on:
    (single-device programs must contain none at all).
 4. **Outputs match the program key.** The trip history is present iff
    the sentinel is compiled in; the ``[E, N, 4]`` metrics history iff
-   telemetry is; shapes/dtypes as documented in ``_epoch_run_fn``.
+   telemetry is; shapes/dtypes as documented in
+   ``nn/train_step.py``'s ``epoch_run_fn``.
 
 ``check_network_contracts(net, cache)`` runs all four against every
 cached program; tier-1 wires it over FF/RNN/graph x {plain, accum,
@@ -32,7 +33,7 @@ real buffers.
 from __future__ import annotations
 
 import re
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = [
     "ContractViolation",
@@ -176,16 +177,6 @@ def _specs_of(tree):
                                        jnp.result_type(a)), tree)
 
 
-def _cache_fields(cache) -> Tuple[Any, Any, Any, Any]:
-    """(features, labels, features_mask(s), labels_mask(s)) for either
-    cache class — MLN's single arrays or CG's per-position tuples."""
-    if hasattr(cache, "features_masks"):  # DeviceMultiDataSetCache
-        return (cache.features, cache.labels, cache.features_masks,
-                cache.labels_masks)
-    return (cache.features, cache.labels, cache.features_mask,
-            cache.labels_mask)
-
-
 def fused_program_specs(net, cache, epochs: int = 2):
     """``jax.ShapeDtypeStruct`` argument specs matching the fused chunk
     program's signature ``(params, updater, net_state, iteration0,
@@ -194,7 +185,7 @@ def fused_program_specs(net, cache, epochs: int = 2):
     import jax
     import jax.numpy as jnp
 
-    xs, ys, fms, lms = _cache_fields(cache)
+    xs, ys, fms, lms = cache.stacks
     rng = net._rng
     key_spec = jax.ShapeDtypeStruct((epochs,) + tuple(jnp.shape(rng)),
                                     jnp.result_type(rng))

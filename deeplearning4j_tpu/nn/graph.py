@@ -56,18 +56,22 @@ from deeplearning4j_tpu.perf.bucketing import (
     pad_axis0,
     padded_label_mask,
 )
-from deeplearning4j_tpu.monitor import fused_metrics_stride, record_counter
+from deeplearning4j_tpu.monitor import record_counter
 from deeplearning4j_tpu.perf.device_eval import confusion_update
 from deeplearning4j_tpu.perf.epoch_cache import (
     DeviceMultiDataSetCache,
     accum_steps_default,
-    drive_epoch_chunks,
-    effective_accum_steps,
-    elastic_reshard,
-    epoch_schedule,
-    stream_epochs,
 )
 from deeplearning4j_tpu.analysis.annotations import traced
+from deeplearning4j_tpu.nn.train_step import (
+    epoch_run_fn,
+    epoch_train_step,
+    fit_epochs,
+    jit_step,
+    multi_step_fn,
+    step_state,
+    tbptt_fn,
+)
 
 
 def _slice_mds_time(mds: MultiDataSet, start: int, end: int) -> MultiDataSet:
@@ -90,6 +94,19 @@ def _slice_mds_time(mds: MultiDataSet, start: int, end: int) -> MultiDataSet:
         None if mds.labels_masks is None
         else [cut_mask(m) for m in mds.labels_masks],
     )
+
+
+def _batch_of(mds: MultiDataSet):
+    """A MultiDataSet as the train programs' batch pytree ``(inputs,
+    labels, feature_masks, label_masks)`` on the device: tuples per
+    input / output position, ``None`` for an absent mask."""
+    def masks(ms):
+        return None if ms is None else tuple(
+            None if m is None else jnp.asarray(m) for m in ms)
+
+    return (tuple(jnp.asarray(f) for f in mds.features),
+            tuple(jnp.asarray(l) for l in mds.labels),
+            masks(mds.features_masks), masks(mds.labels_masks))
 
 
 class ComputationGraph:
@@ -294,26 +311,22 @@ class ComputationGraph:
         return total, (new_state, new_rnn)
 
     # ------------------------------------------------------------------
-    def _lr_scale(self, iteration, lr_scale_host=None):
-        """Effective LR multiplier for ``iteration`` (policy scale times
-        the host ``halve_lr`` knob when given). Shared by the updater
-        apply and the telemetry pack's lr-scale column."""
+    def _lr_scale(self, iteration, lr_scale_host):
+        """Effective LR multiplier for ``iteration``: the schedule's
+        policy scale times the host scale (``halve_lr`` knob). Shared by
+        the updater apply and the telemetry pack's lr-scale column."""
         gc = self.conf.global_conf
-        scale = lr_policy_scale(
+        return lr_policy_scale(
             gc.lr_policy, iteration, gc.lr_policy_decay_rate,
             gc.lr_policy_steps, gc.lr_policy_power, gc.lr_schedule,
-            base_lr=gc.learning_rate)
-        if lr_scale_host is not None:
-            scale = scale * lr_scale_host
-        return scale
+            base_lr=gc.learning_rate) * lr_scale_host
 
     def _apply_updaters(self, params, updater_state, grads, iteration,
-                        lr_scale_host=None):
-        """LR schedule + updater math + parameter update — the tail
-        every optimizer-step variant (plain, accumulated, guarded)
-        shares. ``lr_scale_host`` (a traced scalar, or None = 1) is the
-        host LR multiplier the ``halve_lr`` divergence policy adjusts.
-        ONE flattened sweep per (spec, lr, dtype) leaf group instead of
+                        lr_scale_host):
+        """LR schedule + updater math + parameter update — the apply
+        stage of ``train_step.optimizer_step``. ``lr_scale_host`` (a
+        traced scalar) is the host LR multiplier the ``halve_lr``
+        divergence policy adjusts. ONE flattened sweep per (spec, lr, dtype) leaf group instead of
         a per-vertex Python loop (``grouped_apply_updaters``; bitwise
         the per-layer math); heterogeneously-sharded state (TP/FSDP
         placements) takes the per-layer apply — a concat over mixed
@@ -329,240 +342,35 @@ class ComputationGraph:
                         iteration + 1)
 
     @traced
-    def _loss_grads(self, params, net_state, inputs, labels,
-                    feature_masks, label_masks, rng, rnn_state=None):
-        """Training loss + gradients (pure; caller wraps the dtype policy
-        scope). Shared by the plain step and the sentinel-guarded step,
-        which needs the grads BEFORE deciding whether to apply them."""
-        def loss_fn(p):
-            return self._loss_and_state(
-                p, net_state, inputs, labels, feature_masks,
-                label_masks, rng, train=True, rnn_state=rnn_state)
-
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
-
-    @traced
-    def _step_impl(self, params, updater_state, net_state, iteration,
-                   inputs, labels, feature_masks, label_masks, rng,
-                   rnn_state):
-        """One optimizer step (pure; shared by the per-batch jitted step
-        and the fused TBPTT scan body)."""
-        with dtypes_mod.policy_scope(self._policy):
-            # master-weights policy: ONE bf16 copy for forward/backward,
-            # grads upcast ONCE, updater applies to the f32 masters
-            fwd_params = self._policy.compute_copy(params)
-            (loss, (new_net_state, new_rnn)), grads = self._loss_grads(
-                fwd_params, net_state, inputs, labels, feature_masks,
-                label_masks, rng, rnn_state)
-            grads = self._policy.master_grads(grads)
-            new_params, new_updater = self._apply_updaters(
-                params, updater_state, grads, iteration)
-        return new_params, new_updater, new_net_state, loss, new_rnn
-
-    @traced
-    def _accum_loss_grads(self, params, net_state, inputs, labels,
-                          feature_masks, label_masks, rng,
-                          accum_steps: int):
-        """Accumulated-microbatch loss + summed gradients (pure; caller
-        wraps the dtype policy scope and applies the updater). Returns
-        ``(grads, loss, new_net_state)``."""
-        k = accum_steps
-        micro = inputs[0].shape[0] // k
-
-        def split(a):
-            # strided (row i -> microbatch i % k): shard-local under
-            # a batch-sharded mesh (see MLN._accum_step_impl)
-            if a is None:
-                return None
-            return jnp.moveaxis(
-                a.reshape((micro, k) + a.shape[1:]), 1, 0)
-
-        d_full = tuple(jnp.maximum(jnp.sum(m), 1.0)
-                       for m in label_masks)
-        seq = {"x": tuple(split(a) for a in inputs),
-               "y": tuple(split(a) for a in labels),
-               "lm": tuple(split(a) for a in label_masks),
-               "rng": jax.random.split(rng, k)}
-        if feature_masks is not None:
-            seq["fm"] = tuple(split(a) for a in feature_masks)
-
-        def micro_loss(p, nst_in, xm, ym, fmm, lmm, r):
-            outs, st, _ = self._forward(
-                p, nst_in, xm, train=True, rng=r,
-                feature_masks=fmm)
-            total = 0.0
-            for i, out_name in enumerate(self.conf.outputs):
-                lc = self.conf.layers.get(out_name)
-                if lc is None or not hasattr(lc, "loss_function"):
-                    continue
-                core = compute_loss(
-                    lc.loss_function, outs[i], ym[i], lmm[i])
-                d_mb = jnp.maximum(jnp.sum(lmm[i]), 1.0)
-                total = total + core * (d_mb / d_full[i])
-            for name, impl in self.layer_impls.items():
-                total = total + impl.l1_l2_penalty(p[name]) / k
-            return total, st
-
-        def body(carry, inp):
-            gsum, lsum, nst_in = carry
-            # grads wrt params only; net_state threads through the
-            # carry so no microbatch's state update is dropped.
-            # Accumulation buffers carry the PARAM dtype (bf16 micro-
-            # batch grads upcast into the f32 sum — see MLN counterpart)
-            (lval, st), g = jax.value_and_grad(
-                micro_loss, has_aux=True)(
-                params, nst_in, inp["x"], inp["y"], inp.get("fm"),
-                inp["lm"], inp["rng"])
-            gsum = jax.tree_util.tree_map(
-                lambda s, gg: s + gg.astype(s.dtype), gsum, g)
-            return (gsum, lsum + lval, st), None
-
-        zeros = self._policy.grad_zeros(params)
-        (grads, loss, new_net_state), _ = jax.lax.scan(
-            body, (zeros, jnp.zeros((), jnp.float32), net_state), seq)
-        return grads, loss, new_net_state
-
-    @traced
-    def _accum_step_impl(self, params, updater_state, net_state, iteration,
-                         inputs, labels, feature_masks, label_masks, rng,
-                         accum_steps: int):
-        """One optimizer step over the full batch via ``accum_steps``
-        accumulated microbatches (the ComputationGraph counterpart of
-        MultiLayerNetwork._accum_step_impl): every output head's
-        microbatch loss is its masked SUM over the FULL batch's per-head
-        mask denominator (plus 1/K of the penalty), so the summed
-        gradients equal the unaccumulated step up to f32 summation
-        order. One updater apply."""
-        with dtypes_mod.policy_scope(self._policy):
-            grads, loss, new_net_state = self._accum_loss_grads(
-                self._policy.compute_copy(params), net_state, inputs,
-                labels, feature_masks, label_masks, rng, accum_steps)
-            new_params, new_updater = self._apply_updaters(
-                params, updater_state, grads, iteration)
-        return new_params, new_updater, new_net_state, loss, None
-
-    @traced
-    def _guarded_step_impl(self, params, updater_state, net_state,
-                           iteration, lr_scale_host, inputs, labels,
-                           feature_masks, label_masks, rng,
-                           accum_steps: int):
-        """Sentinel-checked optimizer step for the fused epoch program
-        (see MultiLayerNetwork._guarded_step_impl): non-finite loss or
-        gradients skip the updater apply via ``lax.cond`` (params/
-        updater/net state carried unchanged) and raise the trip flag.
-        Returns ``(params, updater, net_state, loss, tripped)``."""
-        from deeplearning4j_tpu.resilience.guard import tree_all_finite
-
-        with dtypes_mod.policy_scope(self._policy):
-            fwd_params = self._policy.compute_copy(params)
-            if accum_steps > 1:
-                grads, loss, nst2 = self._accum_loss_grads(
-                    fwd_params, net_state, inputs, labels, feature_masks,
-                    label_masks, rng, accum_steps)
-            else:
-                (loss, (nst2, _)), grads = self._loss_grads(
-                    fwd_params, net_state, inputs, labels, feature_masks,
-                    label_masks, rng)
-            # sentinel reads the f32 (master) grads post-upcast
-            grads = self._policy.master_grads(grads)
-            ok = jnp.isfinite(loss) & tree_all_finite(grads)
-
-            def apply(_):
-                p2, u2 = self._apply_updaters(
-                    params, updater_state, grads, iteration,
-                    lr_scale_host)
-                return p2, u2, nst2
-
-            def skip(_):
-                return params, updater_state, net_state
-
-            new_params, new_updater, new_nst = jax.lax.cond(
-                ok, apply, skip, None)
-        return new_params, new_updater, new_nst, loss, ~ok
-
-    @traced
-    def _telemetry_step_impl(self, params, updater_state, net_state,
-                             iteration, lr_scale_host, inputs, labels,
-                             feature_masks, label_masks, rng,
-                             accum_steps: int, guard: bool,
-                             metrics_stride: int):
-        """Fused-path step with the in-program metrics pack (see
-        MultiLayerNetwork._telemetry_step_impl): branch-for-branch the
-        same math as the plain/accumulated/guarded step — the unguarded
-        apply omits ``lr_scale_host`` exactly like ``_step_impl``, so
-        telemetry-on params stay bitwise-identical to telemetry-off —
-        plus the ``[4]`` f32 diagnostics vector. Returns ``(params,
-        updater, net_state, loss, tripped-or-None, metrics)``."""
-        from deeplearning4j_tpu.monitor.pack import step_metrics
-        from deeplearning4j_tpu.resilience.guard import tree_all_finite
-
-        with dtypes_mod.policy_scope(self._policy):
-            fwd_params = self._policy.compute_copy(params)
-            if accum_steps > 1:
-                grads, loss, nst2 = self._accum_loss_grads(
-                    fwd_params, net_state, inputs, labels, feature_masks,
-                    label_masks, rng, accum_steps)
-            else:
-                (loss, (nst2, _)), grads = self._loss_grads(
-                    fwd_params, net_state, inputs, labels, feature_masks,
-                    label_masks, rng)
-            # telemetry norms + sentinel read the f32 (master) grads
-            grads = self._policy.master_grads(grads)
-            if guard:
-                ok = jnp.isfinite(loss) & tree_all_finite(grads)
-
-                def apply(_):
-                    p2, u2 = self._apply_updaters(
-                        params, updater_state, grads, iteration,
-                        lr_scale_host)
-                    return p2, u2, nst2
-
-                def skip(_):
-                    return params, updater_state, net_state
-
-                new_params, new_updater, new_nst = jax.lax.cond(
-                    ok, apply, skip, None)
-                tripped = ~ok
-            else:
-                new_params, new_updater = self._apply_updaters(
-                    params, updater_state, grads, iteration)
-                new_nst, tripped = nst2, None
-            # report the scale actually APPLIED: the unguarded apply
-            # omits lr_scale_host (bitwise parity with _step_impl), so
-            # the lr_scale column must omit it too
-            m = step_metrics(params, new_params, grads,
-                             self._lr_scale(
-                                 iteration,
-                                 lr_scale_host if guard else None),
-                             iteration, metrics_stride)
-        return new_params, new_updater, new_nst, loss, tripped, m
+    def _micro_loss(self, params, net_state, batch, rng, d_full, k: int):
+        """One micro-batch's share of the FULL batch's training loss (see
+        MultiLayerNetwork._micro_loss): every output head's masked sum
+        over the full batch's per-head mask denominator ``d_full[i]``,
+        plus 1/k of the penalty. Returns ``(loss, new_net_state)``."""
+        inputs, labels, feature_masks, label_masks = batch
+        outs, new_state, _ = self._forward(
+            params, net_state, inputs, train=True, rng=rng,
+            feature_masks=feature_masks)
+        total = 0.0
+        for i, out_name in enumerate(self.conf.outputs):
+            lc = self.conf.layers.get(out_name)
+            if lc is None or not hasattr(lc, "loss_function"):
+                continue
+            core = compute_loss(
+                lc.loss_function, outs[i], labels[i], label_masks[i])
+            d_mb = jnp.maximum(jnp.sum(label_masks[i]), 1.0)
+            total = total + core * (d_mb / d_full[i])
+        for name, impl in self.layer_impls.items():
+            total = total + impl.l1_l2_penalty(params[name]) / k
+        return total, new_state
 
     @functools.cached_property
     def _train_step(self):
-        return jax.jit(self._step_impl, donate_argnums=(0, 1, 2))
+        return jit_step(self)
 
     @functools.cached_property
     def _multi_train_step(self):
-        """K optimizer steps fused into ONE XLA program via ``lax.scan``
-        (the ComputationGraph counterpart of
-        MultiLayerNetwork._multi_train_step): the batch transfers once and
-        there is a single host dispatch per K steps."""
-
-        def multi(params, updater_state, net_state, iteration0, inputs,
-                  labels, feature_masks, label_masks, rngs, rnn_state):
-            def body(carry, rng):
-                params, upd, nst, rnn, it = carry
-                p2, u2, s2, loss, rnn2 = self._step_impl(
-                    params, upd, nst, it, inputs, labels, feature_masks,
-                    label_masks, rng, rnn)
-                return (p2, u2, s2, rnn2, it + 1), loss
-
-            carry0 = (params, updater_state, net_state, rnn_state,
-                      iteration0)
-            (p, u, s, rnn, _), losses = jax.lax.scan(body, carry0, rngs)
-            return p, u, s, losses[-1]
-
-        return jax.jit(multi, donate_argnums=(0, 1, 2))
+        return jax.jit(multi_step_fn(self), donate_argnums=(0, 1, 2))
 
     def fit_steps(self, data, n_steps: int):
         """``fit(data)`` called ``n_steps`` times, fused into one XLA
@@ -583,20 +391,9 @@ class ComputationGraph:
         total = n_steps * max(1, gc.iterations)
         keys = jax.random.split(self._rng, total + 1)
         self._rng = keys[0]
-        (self.params, self.updater_state, self.net_state, loss) = (
-            self._multi_train_step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                tuple(jnp.asarray(f) for f in data.features),
-                tuple(jnp.asarray(l) for l in data.labels),
-                None if data.features_masks is None else tuple(
-                    None if m is None else jnp.asarray(m)
-                    for m in data.features_masks),
-                None if data.labels_masks is None else tuple(
-                    None if m is None else jnp.asarray(m)
-                    for m in data.labels_masks),
-                keys[1:], None,
-            ))
+        (self.params, self.updater_state, self.net_state, _, loss) = (
+            self._multi_train_step(*step_state(self), _batch_of(data),
+                                   keys[1:], None))
         self._score = loss
         self._train_dispatches += 1
         record_counter("train_dispatches_total", model="ComputationGraph",
@@ -610,99 +407,21 @@ class ComputationGraph:
     # whole-epoch fusion (the ComputationGraph counterpart of
     # MultiLayerNetwork.fit_epochs — see perf/epoch_cache.py)
     # ------------------------------------------------------------------
-    @traced
     def _epoch_run_fn(self, shuffle: bool, accum_steps: int = 1,
                       guard: bool = False, metrics_stride: int = 0):
-        """The PURE chunk program: E epochs x N batches scanned over the
-        HBM-resident ``[N, B, ...]`` stacks (tuples per input/output
-        position); per-epoch device-side reshuffle via ``epoch_schedule``
-        (the permutation runs over the unsharded batch-index axis — on a
-        mesh the gathers stay shard-local). ``lr_scale_host`` is the host
-        LR multiplier (a traced scalar — the halve_lr divergence policy
-        adjusts it between chunks without recompiling); the unguarded
-        step ignores it (it is 1.0 unless a guard policy changed it).
-        ``guard=True`` routes each step through the numeric sentinel;
-        ``metrics_stride > 0`` compiles the in-program metrics pack in.
-        Outputs, in order: ``(params, updater, net_state, [E, N] hist[,
-        [E, N] trips][, [E, N, 4] metrics])`` — trips iff guarded,
-        metrics iff the pack is compiled in. Shared by the single-device
-        jit and ``ParallelWrapper``'s SPMD jit."""
-
-        def run(params, updater_state, net_state, iteration0,
-                lr_scale_host, xs, ys, fms, lms, epoch_keys):
-            n = xs[0].shape[0]
-
-            def epoch_body(carry, ekey):
-                params, upd, nst, it = carry
-                order, step_keys = epoch_schedule(ekey, n, shuffle)
-
-                def batch_body(c2, inp):
-                    params, upd, nst, it = c2
-                    i, rng = inp
-                    batch = (tuple(x[i] for x in xs),
-                             tuple(y[i] for y in ys),
-                             None if fms is None
-                             else tuple(m[i] for m in fms),
-                             tuple(m[i] for m in lms), rng)
-                    if metrics_stride:
-                        p2, u2, s2, loss, tripped, m = (
-                            self._telemetry_step_impl(
-                                params, upd, nst, it, lr_scale_host,
-                                *batch, accum_steps, guard,
-                                metrics_stride))
-                        out = (loss, tripped, m) if guard else (loss, m)
-                        return (p2, u2, s2, it + 1), out
-                    if guard:
-                        p2, u2, s2, loss, tripped = self._guarded_step_impl(
-                            params, upd, nst, it, lr_scale_host, *batch,
-                            accum_steps)
-                        return (p2, u2, s2, it + 1), (loss, tripped)
-                    args = (params, upd, nst, it) + batch
-                    if accum_steps > 1:
-                        p2, u2, s2, loss, _ = self._accum_step_impl(
-                            *args, accum_steps)
-                    else:
-                        p2, u2, s2, loss, _ = self._step_impl(*args, None)
-                    return (p2, u2, s2, it + 1), loss
-
-                (params, upd, nst, it), losses = jax.lax.scan(
-                    batch_body, (params, upd, nst, it), (order, step_keys))
-                return (params, upd, nst, it), losses
-
-            carry0 = (params, updater_state, net_state, iteration0)
-            (p, u, s, _), hist = jax.lax.scan(epoch_body, carry0, epoch_keys)
-            if guard and metrics_stride:
-                losses, trips, mets = hist
-                return p, u, s, losses, trips, mets
-            if guard:
-                losses, trips = hist
-                return p, u, s, losses, trips
-            if metrics_stride:
-                losses, mets = hist
-                return p, u, s, losses, mets
-            return p, u, s, hist
-
-        return run
+        """The PURE chunk program ``run(params, updater_state, net_state,
+        iteration0, lr_scale_host, xs, ys, fms, lms, epoch_keys)`` over
+        this graph — stacks are tuples per input/output position
+        (``train_step.epoch_run_fn``)."""
+        return epoch_run_fn(self, shuffle, accum_steps, guard,
+                            metrics_stride)
 
     def _epoch_train_step(self, shuffle: bool, accum_steps: int = 1,
                           guard: bool = False, metrics_stride: int = 0):
-        """Jitted fused epoch program (one entry per (shuffle, accum,
-        guard, metrics_stride)); params/updater/net state donated,
-        dataset stacks resident. Entries are :class:`ProfiledProgram`s —
-        pass-through with ``DL4J_PROFILE`` off, cost/memory-profiled
-        once per signature with it on (monitor/profile.py)."""
-        from deeplearning4j_tpu.monitor.profile import ProfiledProgram
-
-        key = (shuffle, accum_steps, guard, metrics_stride)
-        fn = self._epoch_steps.get(key)
-        if fn is None:
-            fn = ProfiledProgram(
-                jax.jit(self._epoch_run_fn(shuffle, accum_steps, guard,
-                                           metrics_stride),
-                        donate_argnums=(0, 1, 2)),
-                name="ComputationGraph", key=key)
-            self._epoch_steps[key] = fn
-        return fn
+        """The jitted, donating chunk program for this key, traced once
+        and cached in ``_epoch_steps`` (``train_step.epoch_train_step``)."""
+        return epoch_train_step(self, shuffle, accum_steps, guard,
+                                metrics_stride)
 
     def fused_epochs_supported(self) -> bool:
         """True when this configuration can run the fused epoch program.
@@ -772,76 +491,12 @@ class ComputationGraph:
         Falls back to the per-step loop for TBPTT and ``iterations >
         1``; over-budget datasets stream with N-deep async device
         prefetch."""
-        from deeplearning4j_tpu.compile_cache import ensure_compile_cache
-        from deeplearning4j_tpu.resilience.guard import nan_guard_policy
-
-        ensure_compile_cache()
-        self._ensure_init()
-        if num_epochs <= 0:
-            return None
-        if accum_steps is None:
-            accum_steps = accum_steps_default()
-        if not self.fused_epochs_supported():
-            if isinstance(data, DeviceMultiDataSetCache):
-                raise ValueError(
-                    "this configuration needs the per-step fit loop "
-                    "(TBPTT / iterations > 1) — pass the original "
-                    "iterator, not a DeviceMultiDataSetCache")
-            for _ in range(num_epochs):
-                self.fit(data)
-            return None
-        cache = data if isinstance(data, DeviceMultiDataSetCache) else (
-            DeviceMultiDataSetCache.build(data, budget_mb=cache_mb,
-                                          mesh=mesh,
-                                          accum_steps=accum_steps))
-        if cache is None:
-            stream_epochs(self, data, num_epochs)
-            return None
-        accum = effective_accum_steps(accum_steps, cache.batch)
-        if cache.mesh is not None:
-            self._place_on_mesh(cache.mesh)
-        guard = nan_guard_policy() if guard is None else guard
-        guarded = guard != "off"
-        stride = fused_metrics_stride(telemetry)
-
-        def launch(epoch_keys):
-            # resolved per launch: a topology reshard clears the program
-            # cache (see MultiLayerNetwork.fit_epochs)
-            step = self._epoch_train_step(shuffle, accum, guarded, stride)
-            out = step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                jnp.asarray(self._lr_scale_host, jnp.float32),
-                cache.features, cache.labels, cache.features_masks,
-                cache.labels_masks, epoch_keys)
-            (self.params, self.updater_state, self.net_state) = out[:3]
-            hist = out[3]
-            trips = out[4] if guarded else None
-            mets = out[-1] if stride else None
-            return hist, trips, mets
-
-        def replay_step(params, upd, nst, it, i, rng):
-            # per-step replay for DL4J_NAN_GUARD=raise localization —
-            # accumulation split included, matching the fused run's
-            # per-microbatch rng stream
-            args = (params, upd, nst, jnp.asarray(it, jnp.int32),
-                    tuple(x[i] for x in cache.features),
-                    tuple(y[i] for y in cache.labels),
-                    None if cache.features_masks is None
-                    else tuple(m[i] for m in cache.features_masks),
-                    tuple(m[i] for m in cache.labels_masks), rng)
-            if accum > 1:
-                p, u, s, loss, _ = self._accum_step_impl(*args, accum)
-            else:
-                p, u, s, loss, _ = self._train_step(*args, None)
-            return p, u, s, loss
-
-        return drive_epoch_chunks(self, cache, num_epochs, chunk_epochs,
-                                  launch, shuffle=shuffle, guard=guard,
-                                  replay_step=replay_step,
-                                  on_chunk=on_chunk,
-                                  reshard=lambda m: elastic_reshard(
-                                      self, cache, m))
+        return fit_epochs(
+            self, data, num_epochs, DeviceMultiDataSetCache,
+            "TBPTT / iterations > 1", shuffle=shuffle,
+            chunk_epochs=chunk_epochs, cache_mb=cache_mb, mesh=mesh,
+            accum_steps=accum_steps, guard=guard, telemetry=telemetry,
+            on_chunk=on_chunk)
 
     @functools.cached_property
     def _output_fn(self):
@@ -892,17 +547,9 @@ class ComputationGraph:
         record_counter("train_dispatches_total", model="ComputationGraph",
                        path="per_step")
         self._rng, rng = jax.random.split(self._rng)
-        inputs = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fms = (None if mds.features_masks is None else tuple(
-            None if m is None else jnp.asarray(m) for m in mds.features_masks))
-        lms = (None if mds.labels_masks is None else tuple(
-            None if m is None else jnp.asarray(m) for m in mds.labels_masks))
-        (self.params, self.updater_state, self.net_state, loss,
-         new_rnn) = self._train_step(
-            self.params, self.updater_state, self.net_state,
-            jnp.asarray(self.iteration_count, jnp.int32),
-            inputs, labels, fms, lms, rng, rnn_state)
+        (self.params, self.updater_state, self.net_state, loss, new_rnn,
+         _, _) = self._train_step(*step_state(self), _batch_of(mds), rng,
+                                  rnn_state)
         self._score = loss  # device scalar; no per-step sync
         self.iteration_count += 1
         for listener in self.listeners:
@@ -915,58 +562,7 @@ class ComputationGraph:
     # ------------------------------------------------------------------
     @functools.cached_property
     def _tbptt_train_step(self):
-        """Fused TBPTT over the DAG: ``lax.scan`` over full windows in ONE
-        XLA program, rnn carry threaded with stop-gradient truncation at
-        boundaries (see MultiLayerNetwork._tbptt_train_step; reference
-        walks windows host-side — ComputationGraph.java:489-534).
-        Temporal ([b, t, ...]) arrays and [b, t] masks are windowed; static
-        inputs (e.g. an image conditioning a caption LSTM) are closed over
-        whole and reused every window."""
-        window = self.conf.tbptt_fwd_length
-
-        def tbptt(params, updater_state, net_state, iteration0, inputs,
-                  labels, fms, lms, rngs, rnn_state0):
-            t = max(f.shape[1] for f in inputs if f.ndim == 3)
-            n_win = t // window
-
-            def to_windows(a, temporal):
-                if a is None or not temporal:
-                    return None
-                b = a.shape[0]
-                shaped = a.reshape((b, n_win, window) + a.shape[2:])
-                return jnp.moveaxis(shaped, 1, 0)
-
-            in_w = tuple(to_windows(f, f.ndim == 3) for f in inputs)
-            lb_w = tuple(to_windows(l, l.ndim == 3) for l in labels)
-            fm_w = (None if fms is None
-                    else tuple(to_windows(m, True) for m in fms))
-            lm_w = (None if lms is None
-                    else tuple(to_windows(m, True) for m in lms))
-
-            def pick(windowed, whole):
-                return tuple(
-                    w if w is not None else s
-                    for w, s in zip(windowed, whole))
-
-            def body(carry, inp):
-                params, upd, nst, rnn, it = carry
-                iw, lw, fw, lmw, rng = inp
-                p2, u2, nst2, loss, rnn2 = self._step_impl(
-                    params, upd, nst, it, pick(iw, inputs),
-                    pick(lw, labels),
-                    None if fw is None else pick(fw, fms),
-                    None if lmw is None else pick(lmw, lms),
-                    rng, rnn)
-                rnn2 = jax.tree_util.tree_map(jax.lax.stop_gradient, rnn2)
-                return (p2, u2, nst2, rnn2, it + 1), loss
-
-            carry0 = (params, updater_state, net_state, rnn_state0,
-                      iteration0)
-            (p, u, s, rnn, _), losses = jax.lax.scan(
-                body, carry0, (in_w, lb_w, fm_w, lm_w, rngs))
-            return p, u, s, rnn, losses[-1]
-
-        return jax.jit(tbptt, donate_argnums=(0, 1, 2))
+        return jax.jit(tbptt_fn(self), donate_argnums=(0, 1, 2))
 
     def _fit_tbptt(self, mds: MultiDataSet):
         from deeplearning4j_tpu.nn.conf.enums import LearningRatePolicy
@@ -990,17 +586,7 @@ class ComputationGraph:
             self._rng = keys[0]
             (self.params, self.updater_state, self.net_state, rnn_state,
              loss) = self._tbptt_train_step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                tuple(jnp.asarray(f) for f in head.features),
-                tuple(jnp.asarray(l) for l in head.labels),
-                None if head.features_masks is None else tuple(
-                    None if m is None else jnp.asarray(m)
-                    for m in head.features_masks),
-                None if head.labels_masks is None else tuple(
-                    None if m is None else jnp.asarray(m)
-                    for m in head.labels_masks),
-                keys[1:], rnn_state)
+                *step_state(self), _batch_of(head), keys[1:], rnn_state)
             self._score = loss
             self.iteration_count += n_full
             start = n_full * window

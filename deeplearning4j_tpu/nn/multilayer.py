@@ -51,11 +51,6 @@ from deeplearning4j_tpu.perf.bucketing import (
 from deeplearning4j_tpu.perf.epoch_cache import (
     DeviceDataSetCache,
     accum_steps_default,
-    drive_epoch_chunks,
-    effective_accum_steps,
-    elastic_reshard,
-    epoch_schedule,
-    stream_epochs,
 )
 from deeplearning4j_tpu.perf.device_eval import (
     RegressionStats,
@@ -64,7 +59,16 @@ from deeplearning4j_tpu.perf.device_eval import (
     regression_update,
 )
 from deeplearning4j_tpu.analysis.annotations import traced
-from deeplearning4j_tpu.monitor import fused_metrics_stride, record_counter
+from deeplearning4j_tpu.monitor import record_counter
+from deeplearning4j_tpu.nn.train_step import (
+    epoch_run_fn,
+    epoch_train_step,
+    fit_epochs,
+    jit_step,
+    multi_step_fn,
+    step_state,
+    tbptt_fn,
+)
 
 _RECURRENT_CONFS = (L.GravesLSTM, L.GravesBidirectionalLSTM, L.GRU, L.LSTM)
 _PRETRAIN_CONFS = (L.RBM, L.AutoEncoder, L.RecursiveAutoEncoder)
@@ -224,9 +228,8 @@ class MultiLayerNetwork:
 
     def _apply_updaters(self, params, updater_state, grads, iteration,
                         lr_scale_host):
-        """LR schedule + updater math + parameter update — the tail
-        every optimizer-step variant (plain, accumulated, guarded)
-        shares. ONE flattened sweep per (spec, lr, dtype) leaf group
+        """LR schedule + updater math + parameter update — the apply
+        stage of ``train_step.optimizer_step``. ONE flattened sweep per (spec, lr, dtype) leaf group
         instead of a per-layer Python loop, so the traced optimizer tail
         is a fused region whose updater-math op count does not scale
         with depth (``grouped_apply_updaters``; bitwise the per-layer
@@ -247,241 +250,31 @@ class MultiLayerNetwork:
                         iteration + 1)
 
     @traced
-    def _loss_grads(self, params, net_state, x, y, feature_mask,
-                    label_mask, rng, rnn_state=None):
-        """Training loss + gradients (pure; caller wraps the dtype
-        policy scope). Shared by the plain step and the sentinel-guarded
-        step, which needs the grads BEFORE deciding whether to apply
-        them."""
-        def loss_fn(p):
-            return self._loss_and_state(
-                p, net_state, x, y, feature_mask, label_mask, rng,
-                train=True, rnn_state=rnn_state,
-            )
-
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
-
-    @traced
-    def _step_impl(self, params, updater_state, net_state, iteration,
-                   lr_scale_host, x, y, feature_mask, label_mask, rng,
-                   rnn_state):
-        with dtypes_mod.policy_scope(self._policy):
-            # master-weights policy: ONE bf16 copy for forward/backward,
-            # grads upcast ONCE, updater applies to the f32 masters
-            # (identity casts under the single-dtype policies)
-            fwd_params = self._policy.compute_copy(params)
-            (loss, (new_net_state, new_rnn)), grads = self._loss_grads(
-                fwd_params, net_state, x, y, feature_mask, label_mask,
-                rng, rnn_state)
-            grads = self._policy.master_grads(grads)
-            new_params, new_updater = self._apply_updaters(
-                params, updater_state, grads, iteration, lr_scale_host)
-        return new_params, new_updater, new_net_state, new_rnn, loss
-
-    @traced
-    def _accum_loss_grads(self, params, net_state, x, y, feature_mask,
-                          label_mask, rng, accum_steps: int):
-        """Accumulated-microbatch loss + summed gradients (pure; caller
-        wraps the dtype policy scope and applies the updater). Returns
-        ``(grads, loss, new_net_state)``."""
-        k = accum_steps
-        micro = x.shape[0] // k
-
-        def split(a):
-            # STRIDED split (row i -> microbatch i % k): under a
-            # batch-sharded mesh every microbatch then spans all
-            # shards evenly, so the slice stays shard-local (a
-            # contiguous split would pull each microbatch from a
-            # subset of the shards and force a resharding exchange)
-            if a is None:
-                return None
-            return jnp.moveaxis(
-                a.reshape((micro, k) + a.shape[1:]), 1, 0)
-
-        d_full = jnp.maximum(jnp.sum(label_mask), 1.0)
-        seq = {"x": split(x), "y": split(y), "lm": split(label_mask),
-               "rng": jax.random.split(rng, k)}
-        if feature_mask is not None:
-            seq["fm"] = split(feature_mask)
-
-        def micro_loss(p, nst_in, xm, ym, fmm, lmm, r):
-            out, st, _, _ = self._forward(
-                p, nst_in, xm, train=True, rng=r, feature_mask=fmm)
-            core = compute_loss(
-                self._output_conf.loss_function, out, ym, lmm)
-            d_mb = jnp.maximum(jnp.sum(lmm), 1.0)
-            pen = 0.0
-            for i, impl in enumerate(self.layers):
-                pen = pen + impl.l1_l2_penalty(p[str(i)])
-            return core * (d_mb / d_full) + pen / k, st
-
-        def body(carry, inp):
-            gsum, lsum, nst_in = carry
-            # grads wrt params only (argnum 0); net_state threads
-            # through the carry so NO microbatch's update is dropped.
-            # Accumulation buffers carry the PARAM dtype: bf16
-            # microbatch grads (master-weights policy) upcast into the
-            # f32 sum instead of summing in bf16
-            (lval, st), g = jax.value_and_grad(
-                micro_loss, has_aux=True)(
-                params, nst_in, inp["x"], inp["y"], inp.get("fm"),
-                inp["lm"], inp["rng"])
-            gsum = jax.tree_util.tree_map(
-                lambda s, gg: s + gg.astype(s.dtype), gsum, g)
-            return (gsum, lsum + lval, st), None
-
-        zeros = self._policy.grad_zeros(params)
-        (grads, loss, new_net_state), _ = jax.lax.scan(
-            body, (zeros, jnp.zeros((), jnp.float32), net_state), seq)
-        return grads, loss, new_net_state
-
-    @traced
-    def _accum_step_impl(self, params, updater_state, net_state, iteration,
-                         lr_scale_host, x, y, feature_mask, label_mask,
-                         rng, accum_steps: int):
-        """One optimizer step over the full batch via ``accum_steps``
-        accumulated microbatches: an inner ``lax.scan`` computes each
-        microbatch's share of the FULL-batch masked-mean loss (its masked
-        sum over the full batch's mask denominator, plus 1/K of the L1/L2
-        penalty), sums the gradients, and applies the updater ONCE. By
-        linearity this is the unaccumulated update up to f32 summation
-        order, while the live activation working set shrinks by K.
-        Caveats (documented in docs/training_pipeline.md): dropout draws
-        per microbatch, and train-mode batchnorm statistics chain K
-        per-microbatch updates instead of one full-batch update."""
-        with dtypes_mod.policy_scope(self._policy):
-            grads, loss, new_net_state = self._accum_loss_grads(
-                self._policy.compute_copy(params), net_state, x, y,
-                feature_mask, label_mask, rng, accum_steps)
-            new_params, new_updater = self._apply_updaters(
-                params, updater_state, grads, iteration, lr_scale_host)
-        return new_params, new_updater, new_net_state, None, loss
-
-    @traced
-    def _guarded_step_impl(self, params, updater_state, net_state,
-                           iteration, lr_scale_host, x, y, feature_mask,
-                           label_mask, rng, accum_steps: int):
-        """Sentinel-checked optimizer step for the fused epoch program:
-        compute loss + gradients, trip when the loss or ANY gradient
-        element is non-finite, and ``lax.cond`` between the updater apply
-        and identity — a tripped step carries params/updater/net state
-        through unchanged, containing a poisoned batch to exactly one
-        skipped update instead of E*N poisoned steps. Returns ``(params,
-        updater, net_state, loss, tripped)``; the iteration counter
-        advances either way so LR schedules stay aligned with an
-        uninterrupted run. The raw (possibly non-finite) loss is recorded
-        in the history — the host-side ``DL4J_NAN_GUARD`` policy reads
-        the trip flags, not the losses (see resilience/guard.py)."""
-        from deeplearning4j_tpu.resilience.guard import tree_all_finite
-
-        with dtypes_mod.policy_scope(self._policy):
-            fwd_params = self._policy.compute_copy(params)
-            if accum_steps > 1:
-                grads, loss, nst2 = self._accum_loss_grads(
-                    fwd_params, net_state, x, y, feature_mask, label_mask,
-                    rng, accum_steps)
-            else:
-                (loss, (nst2, _)), grads = self._loss_grads(
-                    fwd_params, net_state, x, y, feature_mask, label_mask,
-                    rng)
-            # sentinel reads the f32 grads (post-upcast): a bf16 overflow
-            # to inf is preserved by the widening cast
-            grads = self._policy.master_grads(grads)
-            ok = jnp.isfinite(loss) & tree_all_finite(grads)
-
-            def apply(_):
-                p2, u2 = self._apply_updaters(
-                    params, updater_state, grads, iteration,
-                    lr_scale_host)
-                return p2, u2, nst2
-
-            def skip(_):
-                return params, updater_state, net_state
-
-            new_params, new_updater, new_nst = jax.lax.cond(
-                ok, apply, skip, None)
-        return new_params, new_updater, new_nst, loss, ~ok
-
-    @traced
-    def _telemetry_step_impl(self, params, updater_state, net_state,
-                             iteration, lr_scale_host, x, y, feature_mask,
-                             label_mask, rng, accum_steps: int,
-                             guard: bool, metrics_stride: int):
-        """Fused-path step with the in-program metrics pack: the exact
-        math of the plain/accumulated/guarded step (branch for branch, so
-        telemetry-on params stay bitwise-identical to telemetry-off),
-        plus a ``[4]`` f32 diagnostics vector per step — grad global-norm,
-        applied-update global-norm, param global-norm, effective lr scale
-        (``monitor.pack.step_metrics``). Returns ``(params, updater,
-        net_state, loss, tripped-or-None, metrics)``."""
-        from deeplearning4j_tpu.monitor.pack import step_metrics
-        from deeplearning4j_tpu.resilience.guard import tree_all_finite
-
-        with dtypes_mod.policy_scope(self._policy):
-            fwd_params = self._policy.compute_copy(params)
-            if accum_steps > 1:
-                grads, loss, nst2 = self._accum_loss_grads(
-                    fwd_params, net_state, x, y, feature_mask, label_mask,
-                    rng, accum_steps)
-            else:
-                (loss, (nst2, _)), grads = self._loss_grads(
-                    fwd_params, net_state, x, y, feature_mask, label_mask,
-                    rng)
-            # telemetry norms + sentinel read the f32 (master) grads
-            grads = self._policy.master_grads(grads)
-            if guard:
-                ok = jnp.isfinite(loss) & tree_all_finite(grads)
-
-                def apply(_):
-                    p2, u2 = self._apply_updaters(
-                        params, updater_state, grads, iteration,
-                        lr_scale_host)
-                    return p2, u2, nst2
-
-                def skip(_):
-                    return params, updater_state, net_state
-
-                new_params, new_updater, new_nst = jax.lax.cond(
-                    ok, apply, skip, None)
-                tripped = ~ok
-            else:
-                new_params, new_updater = self._apply_updaters(
-                    params, updater_state, grads, iteration,
-                    lr_scale_host)
-                new_nst, tripped = nst2, None
-            m = step_metrics(params, new_params, grads,
-                             self._lr_scale(iteration, lr_scale_host),
-                             iteration, metrics_stride)
-        return new_params, new_updater, new_nst, loss, tripped, m
+    def _micro_loss(self, params, net_state, batch, rng, d_full, k: int):
+        """One micro-batch's share of the FULL batch's training loss, for
+        the accumulation scan (``train_step.accum_grads``): its masked
+        sum over the full batch's mask denominator ``d_full``, plus 1/k
+        of the L1/L2 penalty — the k shares sum to ``_loss_and_state``'s
+        value. Returns ``(loss, new_net_state)``."""
+        x, y, feature_mask, label_mask = batch
+        out, new_state, _, _ = self._forward(
+            params, net_state, x, train=True, rng=rng,
+            feature_mask=feature_mask)
+        core = compute_loss(
+            self._output_conf.loss_function, out, y, label_mask)
+        d_mb = jnp.maximum(jnp.sum(label_mask), 1.0)
+        pen = 0.0
+        for i, impl in enumerate(self.layers):
+            pen = pen + impl.l1_l2_penalty(params[str(i)])
+        return core * (d_mb / d_full) + pen / k, new_state
 
     @functools.cached_property
     def _train_step(self):
-        return jax.jit(self._step_impl, donate_argnums=(0, 1, 2))
+        return jit_step(self)
 
     @functools.cached_property
     def _multi_train_step(self):
-        """K SGD steps fused into ONE XLA program via ``lax.scan`` — the
-        batch transfers once and there is a single host dispatch per K
-        steps, eliminating per-step launch overhead for small models (the
-        equivalent of the reference's `iterations(n)` inner loop, but
-        compiled)."""
-
-        def multi(params, updater_state, net_state, iteration0,
-                  lr_scale_host, x, y, feature_mask, label_mask, rngs,
-                  rnn_state):
-            def body(carry, rng):
-                params, upd, nst, rnn, it = carry
-                p2, u2, s2, rnn2, loss = self._step_impl(
-                    params, upd, nst, it, lr_scale_host, x, y,
-                    feature_mask, label_mask, rng, rnn)
-                return (p2, u2, s2, rnn2, it + 1), loss
-
-            carry0 = (params, updater_state, net_state, rnn_state,
-                      iteration0)
-            (p, u, s, rnn, _), losses = jax.lax.scan(body, carry0, rngs)
-            return p, u, s, rnn, losses[-1]
-
-        return jax.jit(multi, donate_argnums=(0, 1, 2))
+        return jax.jit(multi_step_fn(self), donate_argnums=(0, 1, 2))
 
     @functools.cached_property
     def _score_fn(self):
@@ -549,7 +342,7 @@ class MultiLayerNetwork:
     def fit_steps(self, ds, n_steps: int):
         """``fit(ds)`` called ``n_steps`` times, fused: the batch transfers
         once and all ``n_steps · conf.iterations`` SGD iterations run as ONE
-        XLA program (see ``_multi_train_step``). Listeners fire once, after
+        XLA program (``train_step.multi_step_fn``). Listeners fire once, after
         the fused block, with the final score. Falls back to a plain ``fit``
         loop for non-SGD optimizers, TBPTT, pretraining, and the
         score-reactive LR policy (which needs a host decision per step)."""
@@ -569,15 +362,8 @@ class MultiLayerNetwork:
         keys = jax.random.split(self._rng, total + 1)
         self._rng = keys[0]
         (self.params, self.updater_state, self.net_state, _, loss) = (
-            self._multi_train_step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                jnp.asarray(self._lr_scale_host, jnp.float32),
-                _dev(ds.features), _dev(ds.labels),
-                _dev(ds.features_mask), _dev(ds.labels_mask),
-                keys[1:], None,
-            )
-        )
+            self._multi_train_step(*step_state(self), _batch_of(ds),
+                                   keys[1:], None))
         self._score = loss
         self._last_input = ds.features
         self._train_dispatches += 1
@@ -593,98 +379,20 @@ class MultiLayerNetwork:
     # HBM-resident dataset cache (the epoch-level generalization of
     # fit_steps' single-batch fusion — see perf/epoch_cache.py)
     # ------------------------------------------------------------------
-    @traced
     def _epoch_run_fn(self, shuffle: bool, accum_steps: int = 1,
                       guard: bool = False, metrics_stride: int = 0):
-        """The PURE chunk program: chunk_epochs x n_batches optimizer steps
-        — outer ``lax.scan`` over epoch keys (each epoch derives a
-        device-side ``jax.random.permutation`` batch order + per-batch step
-        keys via ``epoch_schedule``; the permutation runs over the
-        UNSHARDED batch-index axis, so on a mesh the gathers stay
-        shard-local and no resharding collective is emitted), inner scan
-        gathering batches from the resident ``[N, B, ...]`` stacks.
-        ``accum_steps > 1`` routes each batch through the microbatched
-        accumulation step. ``guard=True`` routes each step through the
-        numeric sentinel (``_guarded_step_impl``); ``metrics_stride > 0``
-        compiles the in-program metrics pack in (``_telemetry_step_impl``
-        — an extra ``[E, N, 4]`` diagnostics history). Outputs, in order:
-        ``(params, updater, net_state, [E, N] hist[, [E, N] trips][,
-        [E, N, 4] metrics])`` — trips present iff guarded, metrics
-        present iff the pack is compiled in. Shared verbatim by the
-        single-device jit and ``ParallelWrapper``'s SPMD jit (which pins
-        out_shardings)."""
-
-        def run(params, updater_state, net_state, iteration0, lr_scale_host,
-                xs, ys, fms, lms, epoch_keys):
-            n = xs.shape[0]
-
-            def epoch_body(carry, ekey):
-                params, upd, nst, it = carry
-                order, step_keys = epoch_schedule(ekey, n, shuffle)
-
-                def batch_body(c2, inp):
-                    params, upd, nst, it = c2
-                    i, rng = inp
-                    args = (params, upd, nst, it, lr_scale_host,
-                            xs[i], ys[i],
-                            None if fms is None else fms[i], lms[i], rng)
-                    if metrics_stride:
-                        p2, u2, s2, loss, tripped, m = (
-                            self._telemetry_step_impl(
-                                *args, accum_steps, guard, metrics_stride))
-                        out = (loss, tripped, m) if guard else (loss, m)
-                        return (p2, u2, s2, it + 1), out
-                    if guard:
-                        p2, u2, s2, loss, tripped = self._guarded_step_impl(
-                            *args, accum_steps)
-                        return (p2, u2, s2, it + 1), (loss, tripped)
-                    if accum_steps > 1:
-                        p2, u2, s2, _, loss = self._accum_step_impl(
-                            *args, accum_steps)
-                    else:
-                        p2, u2, s2, _, loss = self._step_impl(*args, None)
-                    return (p2, u2, s2, it + 1), loss
-
-                (params, upd, nst, it), losses = jax.lax.scan(
-                    batch_body, (params, upd, nst, it), (order, step_keys))
-                return (params, upd, nst, it), losses
-
-            carry0 = (params, updater_state, net_state, iteration0)
-            (p, u, s, _), hist = jax.lax.scan(epoch_body, carry0, epoch_keys)
-            if guard and metrics_stride:
-                losses, trips, mets = hist
-                return p, u, s, losses, trips, mets
-            if guard:
-                losses, trips = hist
-                return p, u, s, losses, trips
-            if metrics_stride:
-                losses, mets = hist
-                return p, u, s, losses, mets
-            return p, u, s, hist
-
-        return run
+        """The PURE chunk program ``run(params, updater_state, net_state,
+        iteration0, lr_scale_host, xs, ys, fms, lms, epoch_keys)`` over
+        this network (``train_step.epoch_run_fn``)."""
+        return epoch_run_fn(self, shuffle, accum_steps, guard,
+                            metrics_stride)
 
     def _epoch_train_step(self, shuffle: bool, accum_steps: int = 1,
                           guard: bool = False, metrics_stride: int = 0):
-        """Jitted fused epoch program (one entry per (shuffle, accum,
-        guard, metrics_stride)); params/updater/net state are donated; the
-        dataset stacks are NOT (they stay in HBM across chunks). Cached
-        entries are :class:`ProfiledProgram`s: with ``DL4J_PROFILE`` off
-        every call passes through to the jit function untouched; on, each
-        program's cost/memory analysis is captured once per signature
-        (monitor/profile.py)."""
-        from deeplearning4j_tpu.monitor.profile import ProfiledProgram
-
-        key = (shuffle, accum_steps, guard, metrics_stride)
-        fn = self._epoch_steps.get(key)
-        if fn is None:
-            fn = ProfiledProgram(
-                jax.jit(self._epoch_run_fn(shuffle, accum_steps, guard,
-                                           metrics_stride),
-                        donate_argnums=(0, 1, 2)),
-                name="MultiLayerNetwork", key=key)
-            self._epoch_steps[key] = fn
-        return fn
+        """The jitted, donating chunk program for this key, traced once
+        and cached in ``_epoch_steps`` (``train_step.epoch_train_step``)."""
+        return epoch_train_step(self, shuffle, accum_steps, guard,
+                                metrics_stride)
 
     def fused_epochs_supported(self) -> bool:
         """True when this configuration can run the fused epoch program —
@@ -807,96 +515,24 @@ class MultiLayerNetwork:
         run the plain per-step loop; datasets over the HBM budget
         (``DL4J_DEVICE_CACHE_MB``) stream through an N-deep async device
         prefetch instead (``DL4J_PREFETCH_DEPTH``)."""
-        from deeplearning4j_tpu.compile_cache import ensure_compile_cache
-        from deeplearning4j_tpu.resilience.guard import nan_guard_policy
-
-        ensure_compile_cache()
         self._ensure_init()
-        if num_epochs <= 0:
-            return None
         if not self.conf.backprop and not self.conf.pretrain:
             return None  # fit() trains nothing in this configuration
-        if accum_steps is None:
-            accum_steps = accum_steps_default()
-        if not self.fused_epochs_supported():
-            if isinstance(data, DeviceDataSetCache):
-                raise ValueError(
-                    "this configuration needs the per-step fit loop "
-                    "(non-SGD solver / TBPTT / pretraining / SCORE policy) "
-                    "— pass the original iterator, not a DeviceDataSetCache")
-            for _ in range(num_epochs):
-                self.fit(data)
-            return None
-        cache = data if isinstance(data, DeviceDataSetCache) else (
-            DeviceDataSetCache.build(data, budget_mb=cache_mb, mesh=mesh,
-                                     accum_steps=accum_steps))
-        if cache is None:
-            stream_epochs(self, data, num_epochs)
-            return None
-        accum = effective_accum_steps(accum_steps, cache.batch)
-        if cache.mesh is not None:
-            self._place_on_mesh(cache.mesh)
-        guard = nan_guard_policy() if guard is None else guard
-        guarded = guard != "off"
-        stride = fused_metrics_stride(telemetry)
-
-        def launch(epoch_keys):
-            # resolved per launch: an elastic TOPOLOGY reshard clears the
-            # program cache (the flat-vs-per-layer updater-apply choice is
-            # baked in at trace time from the live placements, so a stale
-            # trace would miscompile under the new shardings)
-            step = self._epoch_train_step(shuffle, accum, guarded, stride)
-            out = step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                jnp.asarray(self._lr_scale_host, jnp.float32),
-                cache.features, cache.labels, cache.features_mask,
-                cache.labels_mask, epoch_keys)
-            (self.params, self.updater_state, self.net_state) = out[:3]
-            hist = out[3]
-            trips = out[4] if guarded else None
-            mets = out[-1] if stride else None
-            return hist, trips, mets
-
-        def replay_step(params, upd, nst, it, i, rng):
-            # per-step replay for DL4J_NAN_GUARD=raise localization: the
-            # same step math on the same cache slice with the same key —
-            # including the accumulation split, whose per-microbatch rng
-            # draws the fused run consumed
-            args = (params, upd, nst, jnp.asarray(it, jnp.int32),
-                    jnp.asarray(self._lr_scale_host, jnp.float32),
-                    cache.features[i], cache.labels[i],
-                    None if cache.features_mask is None
-                    else cache.features_mask[i],
-                    cache.labels_mask[i], rng)
-            if accum > 1:
-                p, u, s, _, loss = self._accum_step_impl(*args, accum)
-            else:
-                p, u, s, _, loss = self._train_step(*args, None)
-            return p, u, s, loss
-
-        return drive_epoch_chunks(self, cache, num_epochs, chunk_epochs,
-                                  launch, shuffle=shuffle, guard=guard,
-                                  replay_step=replay_step,
-                                  on_chunk=on_chunk,
-                                  reshard=lambda m: elastic_reshard(
-                                      self, cache, m))
+        return fit_epochs(
+            self, data, num_epochs, DeviceDataSetCache,
+            "non-SGD solver / TBPTT / pretraining / SCORE policy",
+            shuffle=shuffle, chunk_epochs=chunk_epochs, cache_mb=cache_mb,
+            mesh=mesh, accum_steps=accum_steps, guard=guard,
+            telemetry=telemetry, on_chunk=on_chunk)
 
     def _sgd_step(self, ds, rnn_state=None):
         self._train_dispatches += 1
         record_counter("train_dispatches_total", model="MultiLayerNetwork",
                        path="per_step")
         self._rng, rng = jax.random.split(self._rng)
-        (self.params, self.updater_state, self.net_state, new_rnn, loss) = (
-            self._train_step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                jnp.asarray(self._lr_scale_host, jnp.float32),
-                _dev(ds.features), _dev(ds.labels),
-                _dev(ds.features_mask), _dev(ds.labels_mask),
-                rng, rnn_state,
-            )
-        )
+        (self.params, self.updater_state, self.net_state, loss, new_rnn,
+         _, _) = self._train_step(*step_state(self), _batch_of(ds), rng,
+                                  rnn_state)
         self._score = loss  # device scalar; no sync (see score_value)
         self._last_input = ds.features  # host ref for UI activation listeners
         return new_rnn
@@ -923,49 +559,7 @@ class MultiLayerNetwork:
     # ------------------------------------------------------------------
     @functools.cached_property
     def _tbptt_train_step(self):
-        """ALL full TBPTT windows of a batch fused into ONE XLA program:
-        ``lax.scan`` over windows, each window one SGD step with the rnn
-        carry threaded through and ``stop_gradient`` applied at window
-        boundaries (truncation). The sequence transfers to the device once
-        and there is a single host dispatch per batch instead of one per
-        window (reference walks windows host-side —
-        MultiLayerNetwork.java:1150)."""
-        window = self.conf.tbptt_fwd_length
-
-        def tbptt(params, updater_state, net_state, iteration0,
-                  lr_scale_host, x, y, feature_mask, label_mask, rngs,
-                  rnn_state0):
-            b, t = x.shape[0], x.shape[1]
-            n_win = t // window
-
-            def to_windows(a):
-                # 2D labels stay whole per window (DataSet.slice_time
-                # semantics); masks [b, t] and temporal [b, t, f] window
-                if a is None or (a is y and a.ndim == 2):
-                    return None
-                # [b, t, ...] -> [n_win, b, window, ...]
-                shaped = a.reshape((b, n_win, window) + a.shape[2:])
-                return jnp.moveaxis(shaped, 1, 0)
-
-            xs = (to_windows(x), to_windows(y), to_windows(feature_mask),
-                  to_windows(label_mask), rngs)
-
-            def body(carry, inp):
-                params, upd, nst, rnn, it = carry
-                xx, yy, fm, lm, rng = inp
-                yy = y if yy is None else yy
-                p2, u2, s2, rnn2, loss = self._step_impl(
-                    params, upd, nst, it, lr_scale_host, xx, yy, fm, lm,
-                    rng, rnn)
-                rnn2 = jax.tree_util.tree_map(jax.lax.stop_gradient, rnn2)
-                return (p2, u2, s2, rnn2, it + 1), loss
-
-            carry0 = (params, updater_state, net_state, rnn_state0,
-                      iteration0)
-            (p, u, s, rnn, _), losses = jax.lax.scan(body, carry0, xs)
-            return p, u, s, rnn, losses[-1]
-
-        return jax.jit(tbptt, donate_argnums=(0, 1, 2))
+        return jax.jit(tbptt_fn(self), donate_argnums=(0, 1, 2))
 
     def _fit_tbptt(self, ds):
         gc = self.conf.global_conf
@@ -988,18 +582,9 @@ class MultiLayerNetwork:
             self._rng = keys[0]
             (self.params, self.updater_state, self.net_state, rnn_state,
              loss) = self._tbptt_train_step(
-                self.params, self.updater_state, self.net_state,
-                jnp.asarray(self.iteration_count, jnp.int32),
-                jnp.asarray(self._lr_scale_host, jnp.float32),
-                _dev(ds.features[:, :n_full * window]),
-                _dev(ds.labels[:, :n_full * window]
-                     if ds.labels is not None and ds.labels.ndim == 3
-                     else ds.labels),
-                _dev(None if ds.features_mask is None
-                     else ds.features_mask[:, :n_full * window]),
-                _dev(None if ds.labels_mask is None
-                     else ds.labels_mask[:, :n_full * window]),
-                keys[1:], rnn_state)
+                *step_state(self),
+                _batch_of(ds.slice_time(0, n_full * window)), keys[1:],
+                rnn_state)
             self._score = loss
             self._last_input = ds.features
             self.iteration_count += n_full
@@ -1369,6 +954,13 @@ def _dev(x):
     if x is None:
         return None
     return jnp.asarray(x)
+
+
+def _batch_of(ds):
+    """A DataSet as the train programs' batch pytree ``(features, labels,
+    feature_mask, label_mask)`` on the device."""
+    return (_dev(ds.features), _dev(ds.labels), _dev(ds.features_mask),
+            _dev(ds.labels_mask))
 
 
 def _is_temporal(x) -> bool:

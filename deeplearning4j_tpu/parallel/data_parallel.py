@@ -36,6 +36,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.datasets.dataset import DataSet
+from deeplearning4j_tpu.nn.train_step import (
+    jit_step, run_fused_epochs, step_state)
 from deeplearning4j_tpu.nn.updater import apply_updater, lr_policy_scale
 
 logger = logging.getLogger(__name__)
@@ -120,13 +122,14 @@ class ParallelWrapper:
     @functools.cached_property
     def _fsdp_train_step(self):  # dl4j-lint: disable=adhoc-out-shardings -- shardings sourced from the registry (with_fsdp); only the jit pin lives here
         """The network's step re-jitted with out_shardings pinned to the
-        registry's FSDP specs so donated updates keep state sharded
+        registry's FSDP specs (``optimizer_step``'s result order: params
+        and updater state first) so donated updates keep state sharded
         across steps."""
-        return jax.jit(
-            self.network._step_impl,
+        return jit_step(
+            self.network,
             donate_argnums=(0, 1, 2) if self._donate else (),
-            out_shardings=(self._param_shardings, self._upd_shardings,
-                           None, None, None))
+            out_shardings=(self._param_shardings, self._upd_shardings)
+            + (None,) * 5)
 
     def _shard_batch(self, arr):
         from deeplearning4j_tpu.parallel.sharding_registry import (
@@ -180,10 +183,10 @@ class ParallelWrapper:
         """Configs whose per-batch semantics the sharded one-step path
         preserves exactly — the same exclusion list as
         MultiLayerNetwork.fit_steps (multilayer.py). Only
-        MultiLayerNetwork speaks the sharded step protocol
-        (_train_step(lr_scale)/_sgd_step/_lr_scale_host); every other
-        model (e.g. ComputationGraph off the CLI) delegates to its own
-        fit path rather than crashing mid-mesh-setup."""
+        MultiLayerNetwork has the per-batch host protocol around the
+        shared step (_sgd_step/_post_iteration, DataSet batches); every
+        other model (e.g. ComputationGraph off the CLI) delegates to its
+        own fit path rather than crashing mid-mesh-setup."""
         from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 
         if not isinstance(self.network, MultiLayerNetwork):
@@ -222,14 +225,10 @@ class ParallelWrapper:
         step = self._fsdp_train_step if self.fsdp else net._train_step
         with self.mesh:
             net._rng, rng = jax.random.split(net._rng)
-            (net.params, net.updater_state, net.net_state, _, loss) = step(
-                net.params, net.updater_state, net.net_state,
-                jnp.asarray(net.iteration_count, jnp.int32),
-                jnp.asarray(net._lr_scale_host, jnp.float32),
-                self._shard_batch(ds.features), self._shard_batch(ds.labels),
-                self._shard_batch(ds.features_mask), self._shard_batch(ds.labels_mask),
-                rng, None,
-            )
+            batch = tuple(self._shard_batch(a) for a in (
+                ds.features, ds.labels, ds.features_mask, ds.labels_mask))
+            (net.params, net.updater_state, net.net_state, loss,
+             *_) = step(*step_state(net), batch, rng)
         net.score_value = float(loss)
         net._post_iteration()
 
@@ -311,12 +310,9 @@ class ParallelWrapper:
         single-device fit for ComputationGraph, which does not speak the
         per-batch sharded-step protocol)."""
         from deeplearning4j_tpu.compile_cache import ensure_compile_cache
-        from deeplearning4j_tpu.monitor import fused_metrics_stride
         from deeplearning4j_tpu.perf.epoch_cache import (
             DeviceDataSetCache, DeviceMultiDataSetCache,
-            accum_steps_default, drive_epoch_chunks, effective_accum_steps,
-            stream_epochs)
-        from deeplearning4j_tpu.resilience.guard import nan_guard_policy
+            accum_steps_default, stream_epochs)
 
         ensure_compile_cache()
         net = self.network
@@ -363,76 +359,11 @@ class ParallelWrapper:
                     "DL4J_CACHE_DTYPE=bfloat16, or increase accum_steps")
             stream_epochs(self, data, num_epochs)
             return None
-        accum = effective_accum_steps(accum_steps, cache.batch)
-        multi = isinstance(cache, DeviceMultiDataSetCache)
-        guard = nan_guard_policy() if guard is None else guard
-        guarded = guard != "off"
-        stride = fused_metrics_stride(telemetry)
-
-        def launch(epoch_keys):
-            # resolved per launch, not per run: a mid-run elastic
-            # reshard clears the program cache and this must pick up
-            # the program re-pinned to the NEW mesh
-            step = self._epoch_program(shuffle, accum, guarded, stride)
-            with self.mesh:
-                if multi:
-                    out = step(
-                        net.params, net.updater_state, net.net_state,
-                        jnp.asarray(net.iteration_count, jnp.int32),
-                        jnp.asarray(net._lr_scale_host, jnp.float32),
-                        cache.features, cache.labels, cache.features_masks,
-                        cache.labels_masks, epoch_keys)
-                else:
-                    out = step(
-                        net.params, net.updater_state, net.net_state,
-                        jnp.asarray(net.iteration_count, jnp.int32),
-                        jnp.asarray(net._lr_scale_host, jnp.float32),
-                        cache.features, cache.labels, cache.features_mask,
-                        cache.labels_mask, epoch_keys)
-            (net.params, net.updater_state, net.net_state) = out[:3]
-            hist = out[3]
-            trips = out[4] if guarded else None
-            mets = out[-1] if stride else None
-            return hist, trips, mets
-
-        def replay_step(params, upd, nst, it, i, rng):
-            # DL4J_NAN_GUARD=raise localization replays through the
-            # network's own per-step math — accumulation split included
-            # (same per-microbatch rng stream as the fused run) — on the
-            # replicated layout; fine as a pre-raise diagnostic even
-            # under FSDP, where it temporarily re-replicates the state
-            # it is about to abort with
-            with self.mesh:
-                if multi:
-                    args = (params, upd, nst, jnp.asarray(it, jnp.int32),
-                            tuple(x[i] for x in cache.features),
-                            tuple(y[i] for y in cache.labels),
-                            None if cache.features_masks is None
-                            else tuple(m[i] for m in cache.features_masks),
-                            tuple(m[i] for m in cache.labels_masks), rng)
-                    if accum > 1:
-                        p, u, s, loss, _ = net._accum_step_impl(*args,
-                                                                accum)
-                    else:
-                        p, u, s, loss, _ = net._train_step(*args, None)
-                else:
-                    args = (params, upd, nst, jnp.asarray(it, jnp.int32),
-                            jnp.asarray(net._lr_scale_host, jnp.float32),
-                            cache.features[i], cache.labels[i],
-                            None if cache.features_mask is None
-                            else cache.features_mask[i],
-                            cache.labels_mask[i], rng)
-                    if accum > 1:
-                        p, u, s, _, loss = net._accum_step_impl(*args,
-                                                                accum)
-                    else:
-                        p, u, s, _, loss = net._train_step(*args, None)
-            return p, u, s, loss
-
-        return drive_epoch_chunks(
-            net, cache, num_epochs, chunk_epochs, launch,
-            shuffle=shuffle, guard=guard, replay_step=replay_step,
-            on_chunk=on_chunk,
+        return run_fused_epochs(
+            net, cache, num_epochs, chunk_epochs, self._epoch_program,
+            shuffle=shuffle, accum_steps=accum_steps, guard=guard,
+            telemetry=telemetry, on_chunk=on_chunk,
+            mesh=lambda: self.mesh,
             reshard=lambda new_mesh: self._apply_reshard(new_mesh, cache))
 
     def output(self, x):

@@ -243,6 +243,13 @@ class DeviceDataSetCache:
         self.mesh = mesh                  # None = single-device placement
         self.n_shard = n_shard            # data-axis shards holding the stacks
 
+    @property
+    def stacks(self):
+        """The train programs' batch pytree, stacked: ``(features,
+        labels, feature mask, label mask)``, each ``[N, B, ...]``."""
+        return (self.features, self.labels, self.features_mask,
+                self.labels_mask)
+
     def respec(self, mesh) -> "DeviceDataSetCache":
         """Re-place the resident stacks for a DIFFERENT mesh in-process
         (the elastic mid-run reshard path): each stack gathers to host
@@ -380,6 +387,12 @@ class DeviceMultiDataSetCache:
         self.nbytes = nbytes
         self.mesh = mesh
         self.n_shard = n_shard
+
+    @property
+    def stacks(self):
+        """Per-position twin of :attr:`DeviceDataSetCache.stacks`."""
+        return (self.features, self.labels, self.features_masks,
+                self.labels_masks)
 
     def respec(self, mesh) -> "DeviceMultiDataSetCache":
         """Per-position twin of :meth:`DeviceDataSetCache.respec`."""

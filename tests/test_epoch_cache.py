@@ -93,12 +93,13 @@ def _rnn_data(n=24, t=7, seed=0, label_mask=False):
     return DataSet(x, y, None, lm)
 
 
-def _reference_epochs_mln(net, cache, epochs, shuffle=True):
+def _reference_epochs(net, cache, epochs, shuffle=True):
     """The per-step train program (the exact jitted step ``fit`` uses)
     driven host-side on the fused path's RNG stream: chunk keys split off
     ``net._rng`` the same way, each epoch key expanded through
     ``epoch_schedule`` eagerly. This IS the per-step fit loop on identical
-    keys — the comparison the bitwise suite is named for."""
+    keys — the comparison the bitwise suite is named for. One body for
+    both network classes: the batch is ``cache.stacks`` indexed."""
     keys = jax.random.split(net._rng, epochs + 1)
     net._rng = keys[0]
     it = net.iteration_count
@@ -108,16 +109,14 @@ def _reference_epochs_mln(net, cache, epochs, shuffle=True):
         order = np.asarray(order)
         row = []
         for j in range(cache.n_batches):
-            i = int(order[j])
-            (net.params, net.updater_state, net.net_state, _, loss) = (
-                net._train_step(
-                    net.params, net.updater_state, net.net_state,
-                    jnp.asarray(it, jnp.int32),
-                    jnp.asarray(net._lr_scale_host, jnp.float32),
-                    cache.features[i], cache.labels[i],
-                    None if cache.features_mask is None
-                    else cache.features_mask[i],
-                    cache.labels_mask[i], skeys[j], None))
+            batch = jax.tree_util.tree_map(lambda a: a[int(order[j])],
+                                           cache.stacks)
+            (net.params, net.updater_state, net.net_state, loss,
+             *_) = net._train_step(
+                net.params, net.updater_state, net.net_state,
+                jnp.asarray(it, jnp.int32),
+                jnp.asarray(net._lr_scale_host, jnp.float32),
+                batch, skeys[j])
             it += 1
             row.append(np.asarray(loss))
         history.append(row)
@@ -125,32 +124,6 @@ def _reference_epochs_mln(net, cache, epochs, shuffle=True):
     return np.asarray(history)
 
 
-def _reference_epochs_graph(net, cache, epochs, shuffle=True):
-    keys = jax.random.split(net._rng, epochs + 1)
-    net._rng = keys[0]
-    it = net.iteration_count
-    history = []
-    for ekey in keys[1:]:
-        order, skeys = epoch_schedule(ekey, cache.n_batches, shuffle)
-        order = np.asarray(order)
-        row = []
-        for j in range(cache.n_batches):
-            i = int(order[j])
-            (net.params, net.updater_state, net.net_state, loss, _) = (
-                net._train_step(
-                    net.params, net.updater_state, net.net_state,
-                    jnp.asarray(it, jnp.int32),
-                    tuple(x[i] for x in cache.features),
-                    tuple(y[i] for y in cache.labels),
-                    None if cache.features_masks is None
-                    else tuple(m[i] for m in cache.features_masks),
-                    tuple(m[i] for m in cache.labels_masks),
-                    skeys[j], None))
-            it += 1
-            row.append(np.asarray(loss))
-        history.append(row)
-    net.iteration_count = it
-    return np.asarray(history)
 
 
 class TestDeviceDataSetCache:
@@ -221,7 +194,7 @@ class TestBitwiseEquivalenceMLN:
         cache = DeviceDataSetCache.build(
             ListDataSetIterator(data, batch_size=32))
         hist = fused.fit_epochs(cache, 3)
-        ref_hist = _reference_epochs_mln(ref, cache, 3)
+        ref_hist = _reference_epochs(ref, cache, 3)
         np.testing.assert_array_equal(np.asarray(hist), ref_hist)
         np.testing.assert_array_equal(fused.get_flat_params(),
                                       ref.get_flat_params())
@@ -235,7 +208,7 @@ class TestBitwiseEquivalenceMLN:
             ListDataSetIterator(data, batch_size=6))  # 6/6/3 → bucket 8
         assert cache.batch == 8
         hist = fused.fit_epochs(cache, 2)
-        ref_hist = _reference_epochs_mln(ref, cache, 2)
+        ref_hist = _reference_epochs(ref, cache, 2)
         np.testing.assert_array_equal(np.asarray(hist), ref_hist)
         np.testing.assert_array_equal(fused.get_flat_params(),
                                       ref.get_flat_params())
@@ -246,7 +219,7 @@ class TestBitwiseEquivalenceMLN:
         cache = DeviceDataSetCache.build(
             ListDataSetIterator(data, batch_size=32))
         hist = fused.fit_epochs(cache, 2, shuffle=False)
-        ref_hist = _reference_epochs_mln(ref, cache, 2, shuffle=False)
+        ref_hist = _reference_epochs(ref, cache, 2, shuffle=False)
         np.testing.assert_array_equal(np.asarray(hist), ref_hist)
         np.testing.assert_array_equal(fused.get_flat_params(),
                                       ref.get_flat_params())
@@ -261,7 +234,7 @@ class TestBitwiseEquivalenceGraph:
         cache = DeviceMultiDataSetCache.build(
             ListDataSetIterator(data, batch_size=32))
         hist = fused.fit_epochs(cache, 3)
-        ref_hist = _reference_epochs_graph(ref, cache, 3)
+        ref_hist = _reference_epochs(ref, cache, 3)
         np.testing.assert_array_equal(np.asarray(hist), ref_hist)
         for k, v in ref.get_param_table().items():
             np.testing.assert_array_equal(fused.get_param_table()[k], v)

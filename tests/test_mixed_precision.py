@@ -185,12 +185,12 @@ class TestFusedEpochMixed:
             row = []
             for j in range(cache.n_batches):
                 i = int(np.asarray(order)[j])
-                (ref.params, ref.updater_state, ref.net_state, _,
-                 loss) = ref._train_step(
+                (ref.params, ref.updater_state, ref.net_state, loss,
+                 *_) = ref._train_step(
                     ref.params, ref.updater_state, ref.net_state,
                     jnp.asarray(it, jnp.int32), jnp.asarray(1.0),
-                    cache.features[i], cache.labels[i], None,
-                    cache.labels_mask[i], skeys[j], None)
+                    (cache.features[i], cache.labels[i], None,
+                     cache.labels_mask[i]), skeys[j])
                 it += 1
                 row.append(np.asarray(loss))
             ref_hist.append(row)

@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration, Updater
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.nn.train_step import loss_grads
 from deeplearning4j_tpu.ops.losses import LossFunction
 from deeplearning4j_tpu.perf.epoch_cache import (
     DeviceDataSetCache,
@@ -412,13 +413,13 @@ class TestFusedTelemetryParity:
             order = np.asarray(order)
             for j in range(cache.n_batches):
                 i = int(order[j])
-                (ref.params, ref.updater_state, ref.net_state, _, _) = (
+                (ref.params, ref.updater_state, ref.net_state, *_) = (
                     ref._train_step(
                         ref.params, ref.updater_state, ref.net_state,
                         jnp.asarray(it, jnp.int32),
                         jnp.asarray(1.0, jnp.float32),
-                        cache.features[i], cache.labels[i], None,
-                        cache.labels_mask[i], skeys[j], None))
+                        (cache.features[i], cache.labels[i], None,
+                         cache.labels_mask[i]), skeys[j]))
                 it += 1
         assert _leaves_equal(fused.params, ref.params)
         assert np.isfinite(np.asarray(hist)).all()
@@ -490,10 +491,10 @@ class TestMetricsPackValues:
             order = np.asarray(order)
             for j in range(cache.n_batches):
                 i = int(order[j])
-                (_, (nst2, _)), grads = ref._loss_grads(
-                    ref.params, ref.net_state, cache.features[i],
-                    cache.labels[i], None, cache.labels_mask[i],
-                    skeys[j])
+                (_, (nst2, _)), grads = loss_grads(
+                    ref, ref.params, ref.net_state,
+                    (cache.features[i], cache.labels[i], None,
+                     cache.labels_mask[i]), skeys[j])
                 it_arr = jnp.asarray(it, jnp.int32)
                 one = jnp.asarray(1.0, jnp.float32)
                 new_params, new_upd = ref._apply_updaters(

@@ -176,7 +176,8 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
     one layer of a ``decode = (slots, t_max, window)`` KV pool in place,
     against ``grouped_query_attention`` over that layer's slab, for each
     ``(query heads, kv heads)`` of ``decode_heads`` (StarCoder2's 2 kv
-    heads under a window; OLMoE's 16, full causal). Then the routed
+    heads under a window; OLMoE's 16, full causal), and again with every
+    other slot holding no request (``live``). Then the routed
     experts (``moe = (hidden, expert width, experts, per token, token
     counts)``: OLMoE's widths, a decode step's 32 rows and a 4,096-token
     prefill, so both of ``routed_ffn``'s forms) against a masked loop over
@@ -254,6 +255,18 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
         tag = f"decode_t{t_max}_kv{hkv}" + ("" if win is None
                                              else f"_w{win}")
         errors[tag] = _rel_err(got, want)
+        # every other slot holds no request: its keys (NaN) are not read
+        # and its rows are zeros; the live rows do not change by a bit
+        dead = jnp.arange(slots) % 2 == 1
+        nan = jnp.where(dead[None, :, None, None, None], jnp.nan, 0)
+        some = jax.jit(lambda q, k, v, p, win=win: pool_decode_attention(
+            q, k, v, 1, p, window=win, live=~dead,
+            interpret=flash_default_interpret() if interpret is None
+            else interpret))(q, pool_k + nan.astype(pool_k.dtype),
+                             pool_v + nan.astype(pool_v.dtype), positions)
+        assert bool(jnp.all(jnp.where(dead[:, None, None, None],
+                                      some == 0, some == got))), (
+            f"{tag}: live rows moved or dead rows were read")
     errors.update(_moe_errors(*moe, dtype=jnp.dtype(dtype)))
     bad = {k: e for k, e in errors.items() if not e <= tol}
     assert not bad, f"kernels off the XLA op beyond {tol}: {bad}"

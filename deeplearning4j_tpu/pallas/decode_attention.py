@@ -6,10 +6,25 @@ not hand a convolution a *view* of one layer of that pool: every
 ``pool[layer]`` feeding the attention dots is first copied out as a
 ``[S, T_max, Hkv, Dh]`` slab (a static slice, a dynamic slice and a
 read-only pool all compile to the same copy). This kernel is the read
-that needs no slab: its block index maps address ``(layer, slot, key
-block)`` of the pool itself, so the only pool bytes that move are the key
-blocks a slot's mask can admit — blocks past the slot's cursor, or before
-its sliding window, are neither fetched nor computed.
+that needs no slab, and what it moves follows the keys that are attended,
+not the size of the pool: its work list is the key blocks of the slots
+that hold a request (``live``), each slot's own ``lo..hi`` — the blocks
+its mask can admit, from its sliding window's start to its cursor
+(``key_block_span``). A slot that holds no request — finished and not yet
+reassigned, or never used — contributes nothing: none of its blocks is
+fetched or multiplied, whatever its frozen cursor says, and its output
+rows are zeros. Blocks past a live slot's cursor, or before its window,
+are not in the list either.
+
+There is no grid. One invocation a layer walks the flat list of ``(slot,
+block)`` items in a loop whose trip count is a scalar operand: K and V
+stay in HBM (``pl.ANY``) and each item's two blocks are copied into one
+of two VMEM buffers (``pltpu.make_async_copy``), the next item's copy in
+flight — across slot boundaries too — while this one is multiplied. The
+online-softmax state restarts at a slot's first block and the slot's
+rows are written at its last. The list itself (``_work_list``: a
+cumulative sum and one comparison over ``[S x blocks, S]``) is a few
+small XLA ops a step, the same for every layer.
 
 The pool is passed as ``[L, S, T_max * Hkv, Dh]``: position-major,
 kv-head-minor rows, which is the pool's own byte order (a free reshape),
@@ -17,7 +32,15 @@ with ``Dh`` on the lanes. Row ``r`` is position ``r // Hkv`` of kv head
 ``r % Hkv``; a query head sees the rows of its own kv head only, so the
 grouped attention becomes one masked ``[Q*H, rows]`` product per block —
 ``Hkv`` times the MXU work of the per-head form, on a kernel that waits
-for memory.
+for memory. The queries of all slots (``[S, Q*H, Dh]``) and the output
+sit in VMEM whole.
+
+A block is 512 KiB of K (``pool_block_rows``: 2,048 rows in bf16 — 1,024
+positions at 2 kv heads, 128 at 16), chosen on the chip (TPU v5e, PR 28):
+with no grid step to pay for, a 256 KiB block over-fetches less but costs
+more items (0.54 against 0.49 ms for 20 live slots of 32 at 16 kv heads),
+a 1 MiB block fetches more than short contexts hold (0.073 against 0.058
+ms for 4 live slots of 64 at 2 kv heads).
 
 ``interpret=True`` runs the same kernel through the Pallas interpreter
 (CPU tests).
@@ -30,15 +53,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.pallas.flash_attention import _LANES, MASK_VALUE
 
-__all__ = ["pool_decode_attention", "pool_block_rows"]
+__all__ = ["pool_decode_attention", "pool_block_rows", "key_block_span"]
 
-_BLOCK_BYTES = 1 << 20   # one K (or V) block in VMEM; x2 arrays x2 buffers
+_BLOCK_BYTES = 1 << 19   # one K (or V) block in VMEM; x2 arrays x2 buffers
 
 
 def pool_block_rows(pool_shape, dtype) -> Optional[int]:
@@ -56,24 +80,86 @@ def pool_block_rows(pool_shape, dtype) -> Optional[int]:
     return block
 
 
-def _kernel(lo_ref, hi_ref, q_ref, qpos_ref, qhead_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, scale, block, hkv, window):
-    s = pl.program_id(0)
-    j = pl.program_id(1)
+def key_block_span(newest, oldest, *, block, hkv, window, t_max):
+    """``(lo, hi)``: the first and last key block (``block`` rows of
+    ``T_max * Hkv``) a slot's queries can admit, from the newest and the
+    oldest of their positions — for a decode step both are the slot's
+    cursor. Positions are clipped into the pool (a frozen slot may sit
+    past its end); a sliding window drops the blocks before ``oldest -
+    window + 1``. Plain arithmetic over numpy or jax integers: the
+    kernel's wrapper computes its work list with it and the server's
+    host counters (``kv_blocks``) count with it."""
+    xp = jnp if isinstance(newest, jax.Array) else np
+    newest = xp.clip(newest, 0, t_max - 1)
+    oldest = (xp.zeros_like(newest) if window is None
+              else xp.clip(oldest - (window - 1), 0, newest))
+    return oldest * hkv // block, (newest * hkv + hkv - 1) // block
 
-    @pl.when(j == 0)
+
+def _work_list(positions, live, *, block, hkv, window, t_max):
+    """The kernel's scalar operands from ``positions [S, Q]`` and ``live
+    [S]`` (or ``None``): ``(n, slot_of, lo, hi, first)``. Work item ``w
+    < n[0]`` is key block ``lo[s] + w - first[s]`` of slot ``s =
+    slot_of[w]``: every live slot's blocks ``lo[s]..hi[s]``, slot after
+    slot; a slot that is not live has none."""
+    lo, hi = key_block_span(
+        jnp.max(positions, axis=1), jnp.min(positions, axis=1),
+        block=block, hkv=hkv, window=window, t_max=t_max)
+    count = hi - lo + 1
+    if live is not None:
+        count = jnp.where(live, count, 0)
+    ends = jnp.cumsum(count)
+    items = positions.shape[0] * (t_max * hkv // block)
+    slot_of = jnp.sum(jnp.arange(items)[:, None] >= ends[None, :], axis=1,
+                      dtype=jnp.int32)
+    return ends[-1:], slot_of, lo, hi, ends - count
+
+
+def _kernel(n_ref, slot_ref, lo_ref, hi_ref, first_ref, pos_ref,
+            q_ref, qhead_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *,
+            layer, scale, block, hkv, window, heads, queries):
+    # dead slots, and rows the work list never reaches, read zeros
+    o_ref[...] = jnp.zeros_like(o_ref)
+    n = n_ref[0]
+
+    def block_of(w):
+        s = slot_ref[w]
+        return s, lo_ref[s] + (w - first_ref[s])
+
+    def copies(s, blk, buf):
+        rows = pl.ds(pl.multiple_of(blk * block, block), block)
+        return [pltpu.make_async_copy(hbm.at[layer, s, rows], vm.at[buf],
+                                      sem.at[i, buf])
+                for i, (hbm, vm) in enumerate(((k_hbm, k_buf),
+                                               (v_hbm, v_buf)))]
+
+    @pl.when(n > 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for c in copies(*block_of(0), 0):
+            c.start()
 
-    blk = lo_ref[s] + j
+    def item(w, carry):
+        buf = w & 1
 
-    @pl.when(blk <= hi_ref[s])
-    def _():
-        q = q_ref[0]                                        # [M, D]
-        k = k_ref[0, 0].astype(q.dtype)                     # [block, D]
-        v = v_ref[0, 0].astype(q.dtype)
+        @pl.when(w + 1 < n)         # the next block flies while this one
+        def _():                    # is multiplied
+            for c in copies(*block_of(w + 1), 1 - buf):
+                c.start()
+
+        s, blk = block_of(w)
+        for c in copies(s, blk, buf):
+            c.wait()
+
+        @pl.when(blk == lo_ref[s])
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        q = q_ref[s]                                        # [M, D]
+        k = k_buf[buf].astype(q.dtype)                      # [block, D]
+        v = v_buf[buf].astype(q.dtype)
         logits = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [M, block]
@@ -82,7 +168,13 @@ def _kernel(lo_ref, hi_ref, q_ref, qpos_ref, qhead_ref, k_ref, v_ref, o_ref,
             t, head = row // hkv, row % hkv
         else:           # a power of two: shifts, not vector division
             t, head = row >> (hkv.bit_length() - 1), row & (hkv - 1)
-        qpos = qpos_ref[0]                                  # [M, 1]
+        # per product row: its query's position (-1 on pad rows: nothing
+        # is admitted); rows are query-major, head-minor
+        mrow = lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0)
+        qpos = jnp.full_like(mrow, -1)
+        for i in range(queries):
+            qpos = jnp.where((mrow >= i * heads) & (mrow < (i + 1) * heads),
+                             pos_ref[s * queries + i], qpos)
         keep = (head == qhead_ref[...]) & (t <= qpos)
         if window is not None:
             keep &= t > qpos - window
@@ -99,17 +191,21 @@ def _kernel(lo_ref, hi_ref, q_ref, qpos_ref, qhead_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        l = l_ref[...][:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
-            o_ref.dtype)
+        @pl.when(blk == hi_ref[s])
+        def _():
+            l = l_ref[...][:, :1]
+            o_ref[s] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+                o_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, n, item, 0)
 
 
 def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
                           window: Optional[int] = None,
                           block_rows: Optional[int] = None,
-                          interpret: bool = False):
+                          interpret: bool = False, live=None):
     """Attention of ``q [S, Q, H, Dh]`` at absolute ``positions [S, Q]``
     against layer ``layer`` of the ``[L, S, T_max, Hkv, Dh]`` pools: query
     ``(s, i)`` attends keys ``t <= positions[s, i]`` of slot ``s`` (and
@@ -117,7 +213,9 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
     ``q.dtype`` — the mathematics of ``grouped_query_attention`` over
     ``pool[layer]`` under the same mask, as a blockwise online softmax.
     The pools may store another float dtype; blocks are cast to
-    ``q.dtype`` in VMEM."""
+    ``q.dtype`` in VMEM. ``live [S]`` (bool) names the slots that hold a
+    request: nothing of the others is fetched or multiplied and their
+    rows are zeros. ``None``: every slot is live."""
     s_, nq, h, dh = q.shape
     n_layers, _, t_max, hkv, _ = pool_k.shape
     block = block_rows or pool_block_rows(pool_k.shape, pool_k.dtype)
@@ -127,62 +225,36 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
             "does not fit the decode kernel's blocks")
     m = nq * h
     m_pad = -(-m // 16) * 16            # whole bf16 sublane tiles
-    n_blocks = t_max * hkv // block
-
     positions = positions.astype(jnp.int32)
-    # the key blocks any query of the slot can see: rows of positions
-    # (oldest admitted .. newest), clipped into the pool for frozen slots
-    newest = jnp.clip(jnp.max(positions, axis=1), 0, t_max - 1)
-    oldest = jnp.min(positions, axis=1)
-    oldest = (jnp.zeros_like(oldest) if window is None
-              else oldest - (window - 1))
-    oldest = jnp.clip(oldest, 0, newest)
-    lo = oldest * hkv // block
-    hi = (newest * hkv + hkv - 1) // block
-
     qf = jnp.pad(q.reshape(s_, m, dh), ((0, 0), (0, m_pad - m), (0, 0)))
-    # per product row: the query's position (-1 on pad rows: nothing is
-    # admitted) and the kv head its query head reads
-    qpos = jnp.pad(jnp.repeat(positions, h, axis=1),
-                   ((0, 0), (0, m_pad - m)), constant_values=-1)[..., None]
+    # per product row: the kv head its query head reads
     qhead = jnp.pad(jnp.tile(jnp.arange(h, dtype=jnp.int32) // (h // hkv),
                              nq), (0, m_pad - m))[:, None]
 
-    def kv_index(s, j, lo_ref, hi_ref):
-        # past the slot's last block the index repeats, so nothing is fetched
-        return (layer, s, jnp.minimum(lo_ref[s] + j, hi_ref[s]), 0)
-
-    def row_index(s, j, lo_ref, hi_ref):
-        return (s, 0, 0)
-
     kernel = functools.partial(
-        _kernel, scale=float(1.0 / (dh ** 0.5)), block=block, hkv=hkv,
-        window=window)
-    kv_spec = pl.BlockSpec((1, 1, block, dh), kv_index)
+        _kernel, layer=layer, scale=float(1.0 / (dh ** 0.5)), block=block,
+        hkv=hkv, window=window, heads=h, queries=nq)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(s_, n_blocks),
-            in_specs=[
-                pl.BlockSpec((1, m_pad, dh), row_index),
-                pl.BlockSpec((1, m_pad, 1), row_index),
-                pl.BlockSpec((m_pad, 1), lambda s, j, lo_ref, hi_ref: (0, 0)),
-                kv_spec,
-                kv_spec,
-            ],
-            out_specs=pl.BlockSpec((1, m_pad, dh), row_index),
+            num_scalar_prefetch=6,
+            grid=(),
+            in_specs=[vmem, vmem, hbm, hbm],
+            out_specs=vmem,
             scratch_shapes=[
+                pltpu.VMEM((2, block, dh), pool_k.dtype),
+                pltpu.VMEM((2, block, dh), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((m_pad, dh), jnp.float32),
                 pltpu.VMEM((m_pad, _LANES), jnp.float32),
                 pltpu.VMEM((m_pad, _LANES), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((s_, m_pad, dh), q.dtype),
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            # slots are independent; the key blocks carry the softmax state
-            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lo, hi, qf, qpos, qhead,
+    )(*_work_list(positions, live, block=block, hkv=hkv, window=window,
+                  t_max=t_max), positions.reshape(-1), qf, qhead,
       pool_k.reshape(n_layers, s_, t_max * hkv, dh),
       pool_v.reshape(n_layers, s_, t_max * hkv, dh))
     return out[:, :m].reshape(s_, nq, h, dh)

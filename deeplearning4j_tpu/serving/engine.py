@@ -48,8 +48,9 @@ fused programs, and the pool a program returns is the buffer that was
 donated to it — no slab is copied out to be written and none is stacked
 back. Where a TPU is attached the layer's keys are read from the pool
 itself by a Pallas kernel (``pallas/decode_attention.py``), because
-XLA:TPU copies ``pool[layer]`` out before a dot may read it; elsewhere
-the XLA op attends to that view.
+XLA:TPU copies ``pool[layer]`` out before a dot may read it — and only
+the key blocks of the slots that owe a token (``remaining > 0``) are
+read; elsewhere the XLA op attends to that view.
 
 All program bodies are ``@traced`` hot roots
 (``analysis/annotations.HOT_PATH_REGISTRY``) so dl4j-lint's host-sync
@@ -234,7 +235,7 @@ def unpack_routing(packed, num_experts: int, experts_per_token: int):
 
 
 @traced
-def _pool_attention(model, pool, positions, pool_kernel):
+def _pool_attention(model, pool, positions, pool_kernel, live=None):
     """``li -> attention(q, kk, vv)`` for a decode-family forward over
     the slot pool: layer ``li`` scatters its new K/V rows at
     ``positions [S, Q]`` straight into the carried pool
@@ -250,9 +251,12 @@ def _pool_attention(model, pool, positions, pool_kernel):
     before any dot may use it, so where the Pallas kernel applies
     (``pool_kernel``: a TPU is attached unless the caller says otherwise;
     an unquantized pool whose head size fills the lanes)
-    ``pool_decode_attention`` reads the layer's live key blocks from the
-    pool itself. Everywhere else — the CPU, int8 pools, a pool sharded
-    over a mesh — the XLA op attends to the ``pool[li]`` view."""
+    ``pool_decode_attention`` reads from the pool itself the key blocks
+    of the slots that hold a request (``live [S]``, bool; ``None``: all
+    of them) — a slot that holds none is neither fetched nor multiplied
+    and its rows are zeros. Everywhere else — the CPU, int8 pools, a pool
+    sharded over a mesh — the XLA op attends to the ``pool[li]`` view,
+    every slot's."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops.attention import grouped_query_attention
     from deeplearning4j_tpu.pallas import decode_attention as kernel
@@ -267,9 +271,9 @@ def _pool_attention(model, pool, positions, pool_kernel):
         block = kernel.pool_block_rows(pool["k"].shape, pool["k"].dtype)
     if block is None:
         keys = jnp.arange(pool["k"].shape[2])
-        live = keys <= positions[:, :, None]               # [S, Q, T]
+        mask = keys <= positions[:, :, None]               # [S, Q, T]
         if window is not None:
-            live &= keys > positions[:, :, None] - window
+            mask &= keys > positions[:, :, None] - window
 
     def layer(li):
         def attn(q, kk, vv):
@@ -282,12 +286,13 @@ def _pool_attention(model, pool, positions, pool_kernel):
             if block is not None:
                 return kernel.pool_decode_attention(
                     q, pool["k"], pool["v"], li, positions, window=window,
-                    block_rows=block, interpret=flash_default_interpret())
+                    block_rows=block, interpret=flash_default_interpret(),
+                    live=live)
             views = (dequant_slab(
                 pool[name][li],
                 pool[name + "_scale"][li] if name + "_scale" in pool
                 else None, dtype) for name in ("k", "v"))
-            return grouped_query_attention(q, *views, mask=live)
+            return grouped_query_attention(q, *views, mask=mask)
         return attn
 
     return layer
@@ -304,10 +309,11 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     donated, it is the same buffer. Free
     slots ride along computing garbage no one reads — their rows are
     masked out of nothing (rows are independent) and their pool writes
-    land at frozen cursors the admission prefill overwrites. Routed
-    experts: ``live [S]`` (bool) names the slots that hold a request —
-    the others choose no expert and count in no load — and ``moe_info``
-    receives each layer's routing (``TransformerLM._block``)."""
+    land at frozen cursors the admission prefill overwrites. ``live
+    [S]`` (bool) names the slots that hold a request: the pool kernel
+    reads none of the others' keys, and they choose no routed expert and
+    count in no load. ``moe_info`` receives each layer's routing
+    (``TransformerLM._block``)."""
     import jax.numpy as jnp
 
     h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
@@ -316,7 +322,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     h = model.policy.cast_compute(h)[:, None, :]           # [S, 1, D]
     new_kv = dict(kv)
     cached_attention = _pool_attention(
-        model, new_kv, positions[:, None], pool_kernel)
+        model, new_kv, positions[:, None], pool_kernel, live)
     for li, blk in enumerate(params["blocks"]):
         h, _, _ = model._block(
             blk, h, attention=cached_attention(li),
@@ -351,11 +357,12 @@ def _serve_decode_loop_impl(model, sample_row, params, kv, loop, *,
                             pool_kernel=None):
     """The plain decode program (``fuse_steps=1``): the PR-10 step on
     the device's own loop state. ``loop`` is ``SlotKVCache.loop``; a
-    slot is live while it owes a token (``remaining > 0`` — the routed
-    experts' ``live`` mask), consumes ``tok`` at its cursor and takes the
-    sampled token; ``advance_loop`` moves the state on. Returns ``(loop,
-    pool)`` and, for a model with routed experts, ``(loop, pool,
-    routing)``: the token block of the step is ``loop["tok"]``."""
+    slot is live while it owes a token (``remaining > 0`` — the pool
+    read's and the routed experts' ``live`` mask), consumes ``tok`` at
+    its cursor and takes the sampled token; ``advance_loop`` moves the
+    state on. Returns ``(loop, pool)`` and, for a model with routed
+    experts, ``(loop, pool, routing)``: the token block of the step is
+    ``loop["tok"]``."""
     out = _serve_decode_impl(
         model, sample_row, params, kv, loop["tok"], loop["cursors"],
         loop["keys"], loop["remaining"] > 0, pool_kernel=pool_kernel)
@@ -385,7 +392,7 @@ def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv, loop,
 
 
 @traced
-def _serve_verify_impl(model, params, kv, toks, positions, *,
+def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
                        pool_kernel=None):
     """Multi-token target forward for the speculative verify: consume
     ``toks [S, Q]`` at per-row ``positions [S, Q]`` against the slot
@@ -393,7 +400,8 @@ def _serve_verify_impl(model, params, kv, toks, positions, *,
     accepted prefix becomes permanent; rejected tails sit beyond the
     rewound cursor, masked until overwritten). Per-query masks keep
     causality at ragged per-slot offsets: query q attends pool keys
-    ``<= positions[s, q]``. Returns ``(logits [S, Q, V], new_kv)``."""
+    ``<= positions[s, q]``; the pool kernel reads the ``live [S]`` slots'
+    keys only. Returns ``(logits [S, Q, V], new_kv)``."""
     import jax.numpy as jnp
 
     h = jnp.take(params["embed"], toks, axis=0)            # [S, Q, D]
@@ -401,7 +409,8 @@ def _serve_verify_impl(model, params, kv, toks, positions, *,
         h = h + params["pos"][positions]
     h = model.policy.cast_compute(h)
     new_kv = dict(kv)
-    cached_attention = _pool_attention(model, new_kv, positions, pool_kernel)
+    cached_attention = _pool_attention(model, new_kv, positions, pool_kernel,
+                                       live)
     for li, blk in enumerate(params["blocks"]):
         h, _, _ = model._block(blk, h, attention=cached_attention(li),
                                positions=positions)
@@ -456,7 +465,7 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
             dkv, dtok, dkeys = dc
             logits, dkv = _decode_step_body(
                 draft_model, draft_params, dkv, dtok, cursors + i,
-                pool_kernel=pool_kernel)
+                pool_kernel=pool_kernel, live=act)
             if greedy:
                 prop = jnp.argmax(logits, axis=-1).astype(i32)
                 qdist = logits  # unused; placeholder keeps the scan pytree
@@ -479,7 +488,7 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
         # ---- verify: one multi-token target forward over tok + d_1..d_G
         vtoks = jnp.concatenate([tok[:, None], d], axis=1)  # [S, G+1]
         vpos = cursors[:, None] + jnp.arange(gamma + 1)[None, :]
-        logits, kv = _serve_verify_impl(model, params, kv, vtoks, vpos,
+        logits, kv = _serve_verify_impl(model, params, kv, vtoks, vpos, act,
                                         pool_kernel=pool_kernel)
 
         # ---- accept / resample

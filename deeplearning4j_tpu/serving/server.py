@@ -52,11 +52,15 @@ Observability: queue depth / occupancy gauges, token + dispatch
 counters (``serve_decode_steps_total`` counts DISPATCHES — with fusion
 one dispatch covers up to K·(G+1) tokens; ``stats()`` derives
 dispatches/token and accepted-tokens/dispatch, the fast-path headline
-metrics), speculative proposed/accepted counters, TTFT/TPOT/latency
-histograms (``monitor/registry``), and the spans below (``monitor/trace``
-— forwarded to the flight recorder when one is live and, like every span,
-a ``dl4j.<name>`` event on the device trace's clock in any
-``jax.profiler`` trace taken while the server runs):
+metrics), the pool read's key-block counters
+(``serve_decode_kv_blocks_total`` against
+``serve_decode_kv_blocks_pool_total``: what the live slots' keys cost
+of what every slot's cursor would), speculative proposed/accepted
+counters, TTFT/TPOT/latency histograms (``monitor/registry``), and the
+spans below (``monitor/trace`` — forwarded to the flight recorder when
+one is live and, like every span, a ``dl4j.<name>`` event on the device
+trace's clock in any ``jax.profiler`` trace taken while the server
+runs):
 
 - ``serve.step`` (``live``, ``admitted``) — one scheduler iteration;
   parent of the three phases:
@@ -69,10 +73,13 @@ a ``dl4j.<name>`` event on the device trace's clock in any
   ``first_token_s``), or ``serve.handoff.install``.
 - ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
   ``spec``, ``ahead`` = 1 when the dispatch was issued while the
-  previous block was unread) — the decode dispatch (``live`` slots; 0:
-  none was owed a token), then the read-back of the block dispatched a
-  step earlier (plain) or just now (fused, spec). ``experts_touched`` is
-  of the block READ.
+  previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
+  blocks a layer the pool kernel fetches for the slots dispatched, and
+  what a read of every slot's cursor, live or frozen, would fetch —
+  counted from the host's slot table, ``_book_kv_blocks``) — the decode
+  dispatch (``live`` slots; 0: none was owed a token), then the
+  read-back of the block dispatched a step earlier (plain) or just now
+  (fused, spec). ``experts_touched`` is of the block READ.
 - ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop
   over the block read, histograms, retirement.
 - ``serve.request`` (``request``, ``tokens``, ``slot``) — ``submit_s`` →
@@ -93,6 +100,8 @@ from typing import Callable, Deque, List, Optional, Sequence, Tuple
 import numpy as np
 
 from deeplearning4j_tpu.monitor import metrics, tracer
+from deeplearning4j_tpu.pallas.decode_attention import (
+    key_block_span, pool_block_rows)
 from deeplearning4j_tpu.serving.engine import DecodeEngine, unpack_routing
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
@@ -147,6 +156,17 @@ class DecodeServer:
             max_queue if max_queue is not None else serve_max_queue())
         self.clock = clock
         self._slot_req: List[Optional[ServeRequest]] = [None] * self.slots
+        # the host's copy of the device's cursors (written where the
+        # device's are: admission, release, a dispatch's own advance) and
+        # the pool kernel's rows a key block — None where the pool has no
+        # kernel read (int8, a mesh, a head size off the lanes): what
+        # ``kv_blocks`` is counted from
+        self._cursors = np.zeros(self.slots, np.int64)
+        pool = self.engine.cache
+        self._kv_block = (None if pool.quantized or mesh is not None
+                          else pool_block_rows(pool.k.shape, pool.k.dtype))
+        self.kv_blocks = 0
+        self.kv_blocks_pool = 0
         self._last_tok_s = np.zeros(self.slots, np.float64)
         # the dispatched block the host has not read: ``(tokens, routing,
         # {slot: request})`` — device arrays and the slots live in it
@@ -370,6 +390,7 @@ class DecodeServer:
             last_tok, cursor, key = install(self.engine, slot)
             self.engine.admit_slot(slot, last_tok, cursor,
                                    req.max_new_tokens - len(req.tokens), key)
+            self._cursors[slot] = cursor
         now = self.clock()
         req.state = "running"
         req.handoff = True
@@ -438,6 +459,7 @@ class DecodeServer:
                 # outputs, queued on the device before the host waits
                 self.engine.admit_slot(slot, tok, prompt_len,
                                        req.max_new_tokens - 1, key)
+                self._cursors[slot] = prompt_len
                 tok, rows = self._read_block(tok, routing)
                 now = self.clock()
                 req.state = "running"
@@ -488,12 +510,43 @@ class DecodeServer:
         """ONE decode dispatch for the live set, from the loop state on
         the device: nothing is sent. Returns the block as dispatched,
         ``(tokens, routing, live)`` with device arrays — tokens [S] plain,
-        [K, S] fused, [K, S, G+2] speculative."""
+        [K, S] fused, [K, S, G+2] speculative. The host's cursors move on
+        as the program moves the device's (a speculative round's count is
+        the device's to say: booked when its block is read, ``_emit``)."""
         if self.engine.spec:
             return self.engine.decode_spec(self.fuse_steps), None, live
+        for slot, req in live.items():
+            self._cursors[slot] += min(
+                self.fuse_steps, req.max_new_tokens - len(req.tokens))
         if self.fuse_steps > 1:
             return self.engine.decode_fused(self.fuse_steps), None, live
         return self.engine.decode() + (live,)
+
+    def _book_kv_blocks(self, live: dict) -> dict:
+        """Count what the dispatch for ``live`` reads of the pool, in the
+        kernel's key blocks a layer at the cursors it starts from
+        (``key_block_span``, the function the kernel's work list comes
+        from): ``kv_blocks`` of the live slots, ``kv_blocks_pool`` of
+        every slot, frozen cursors included — into the server's totals
+        and two registry counters, and returned as the ``serve.decode``
+        span's attrs (none where the pool has no kernel read, or nothing
+        is dispatched). No device read."""
+        attrs = {}
+        if live and self._kv_block is not None:
+            _, _, t_max, hkv, _ = self.engine.cache.k.shape
+            lo, hi = key_block_span(
+                self._cursors, self._cursors, block=self._kv_block,
+                hkv=hkv, window=self.model.attn_window, t_max=t_max)
+            blocks = hi - lo + 1
+            attrs = {"kv_blocks": int(blocks[list(live)].sum()),
+                     "kv_blocks_pool": int(blocks.sum())}
+            self.kv_blocks += attrs["kv_blocks"]
+            self.kv_blocks_pool += attrs["kv_blocks_pool"]
+            self._reg.counter("serve_decode_kv_blocks_total").inc(
+                attrs["kv_blocks"])
+            self._reg.counter("serve_decode_kv_blocks_pool_total").inc(
+                attrs["kv_blocks_pool"])
+        return attrs
 
     def _read_block(self, toks, routing):
         """One program's tokens on the host — the loop's one sanctioned
@@ -548,6 +601,7 @@ class DecodeServer:
                 continue
             self._slot_req[slot] = None
             self.engine.release_slot(slot)
+            self._cursors[slot] = 0
 
     def step(self) -> bool:
         """One scheduler iteration: shed expired/canceled slots, admit
@@ -576,7 +630,8 @@ class DecodeServer:
         unread, self._unread = self._unread, None
         ahead = bool(live) and unread is not None
         with tracer().span("serve.decode", live=len(live),
-                           kind=self._decode_kind, ahead=int(ahead)):
+                           kind=self._decode_kind, ahead=int(ahead),
+                           **self._book_kv_blocks(live)):
             if live:
                 self._unread = self._dispatch(live)
                 if ahead:
@@ -630,6 +685,7 @@ class DecodeServer:
                     c = int(counts[r, slot])
                     if c <= 0:
                         continue
+                    self._cursors[slot] += c
                     take = min(c, rem - len(got))
                     got.extend(int(t) for t in toks[r, slot, :take])
                     self.spec_proposed += self.engine.spec_tokens
@@ -717,6 +773,14 @@ class DecodeServer:
             # the server was empty, and on the fused/speculative paths)
             "decode_ahead_share": (round(self.decode_ahead / self.steps, 4)
                                    if self.steps else None),
+            # key blocks a layer the pool kernel fetched for the slots
+            # dispatched, of what reading every slot's cursor (live or
+            # frozen) would have fetched — None where the pool has no
+            # kernel read
+            "kv_blocks": self.kv_blocks,
+            "kv_blocks_pool": self.kv_blocks_pool,
+            "kv_blocks_share": (round(self.kv_blocks / self.kv_blocks_pool, 4)
+                                if self.kv_blocks_pool else None),
             "decode_tokens": self.decode_tokens,
             "dispatches_per_token": (
                 round(self.steps / self.decode_tokens, 4)

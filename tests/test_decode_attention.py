@@ -17,7 +17,7 @@ import pytest
 from deeplearning4j_tpu.models.transformer import TransformerLM
 from deeplearning4j_tpu.ops.attention import grouped_query_attention
 from deeplearning4j_tpu.pallas.decode_attention import (
-    pool_block_rows, pool_decode_attention)
+    _work_list, key_block_span, pool_block_rows, pool_decode_attention)
 from deeplearning4j_tpu.serving import SlotKVCache
 from deeplearning4j_tpu.serving import engine as eng
 
@@ -39,16 +39,23 @@ class TestPoolDecodeAttention:
         (3, 2, 96, 2, 4, 2, 40),        # verify under a window
         (2, 2, 64, 4, 4, 1, None),      # MHA: one query head a kv head
         (2, 2, 48, 3, 6, 1, None),      # kv heads not a power of two
+        (2, 3, 32, 16, 16, 1, None),    # 16 kv heads, full causal (OLMoE)
+        (2, 3, 32, 16, 16, 2, None),    # ... under a verify
     ]
 
+    @pytest.mark.parametrize("live", ["all", "some"])
     @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
                                            ("bfloat16", 2e-2)])
     @pytest.mark.parametrize("shape", SHAPES)
-    def test_matches_the_xla_op_over_the_slab(self, rng, shape, dtype, tol):
+    def test_matches_the_xla_op_over_the_slab(self, rng, shape, dtype, tol,
+                                              live):
         """The kernel over layer ``li`` of the pool is
         ``grouped_query_attention`` over ``pool[li]`` under the same
         mask: slot 0 sits at position 0 (one key), the last slot at the
-        pool's end, the rest anywhere."""
+        pool's end, the rest anywhere. ``live`` = some: the middle slots
+        hold no request — their keys, NaN here, are neither fetched nor
+        multiplied and their rows are exact zeros, while the live rows
+        are bit for bit what the kernel gives with every slot live."""
         n_layers, s_, t_max, hkv, h, nq, window = shape
         dh = 128
         pool_k, pool_v = (
@@ -62,13 +69,125 @@ class TestPoolDecodeAttention:
         li = n_layers - 1
         want = grouped_query_attention(
             q, pool_k[li], pool_v[li], mask=_mask(positions, t_max, window))
-        got = pool_decode_attention(
-            q, pool_k, pool_v, li, positions, window=window, block_rows=16,
+        kernel = functools.partial(
+            pool_decode_attention, window=window, block_rows=16,
             interpret=True)
+        got = kernel(q, pool_k, pool_v, li, positions)
+        if live == "some":
+            dead = np.arange(s_)[1:-1]
+            every, got = got, kernel(
+                q, pool_k.at[:, dead].set(jnp.nan),
+                pool_v.at[:, dead].set(jnp.nan), li, positions,
+                live=jnp.asarray(np.isin(np.arange(s_), dead, invert=True)))
+            assert not np.asarray(got[dead], np.float32).any()
+            keep = np.setdiff1d(np.arange(s_), dead)
+            assert np.array_equal(np.asarray(got[keep], np.float32),
+                                  np.asarray(every[keep], np.float32))
+            want = want.at[dead].set(0)
         assert got.shape == want.shape and got.dtype == want.dtype
         err = np.abs(np.asarray(got, np.float32)
                      - np.asarray(want, np.float32)).max()
         assert err < tol, err
+
+    # (kv heads, window): StarCoder2's and OLMoE's; 16 rows a block
+    HEADS = [(2, 24), (16, None)]
+
+    @pytest.mark.parametrize("hkv,window", HEADS)
+    def test_no_slot_live_and_every_slot_live(self, rng, hkv, window):
+        """No live slot: nothing is copied (the pool is NaN), every row
+        is zero. ``live`` all true is ``live=None`` bit for bit."""
+        s_, t_max, h = 3, 64, 2 * hkv if hkv == 2 else hkv
+        pool_k, pool_v = (
+            jnp.asarray(rng.normal(size=(2, s_, t_max, hkv, 128)),
+                        jnp.float32) for _ in range(2))
+        q = jnp.asarray(rng.normal(size=(s_, 1, h, 128)), jnp.float32)
+        positions = jnp.asarray([[5], [40], [63]], jnp.int32)
+        kernel = functools.partial(
+            pool_decode_attention, window=window, block_rows=16,
+            interpret=True)
+        none = kernel(q, pool_k * jnp.nan, pool_v * jnp.nan, 1, positions,
+                      live=jnp.zeros(s_, bool))
+        assert none.shape == q.shape and not np.asarray(none).any()
+        assert np.array_equal(
+            np.asarray(kernel(q, pool_k, pool_v, 1, positions)),
+            np.asarray(kernel(q, pool_k, pool_v, 1, positions,
+                              live=jnp.ones(s_, bool))))
+
+    @pytest.mark.parametrize("hkv,window", HEADS)
+    @pytest.mark.parametrize("cursors", [
+        (16, 31, 63),       # a block's first and last position; T_max - 1
+        (0, 15, 32),        # one key; a full first block; one key of a third
+        (63, 63, 0),
+    ])
+    def test_cursors_on_block_edges(self, rng, hkv, window, cursors):
+        """Live rows against the XLA op with the cursors on the edges of
+        a 16-position block (16 rows a block at one kv head's worth of
+        rows: ``block_rows = 16 * hkv``), the slot between them frozen —
+        dead, its keys NaN — at a cursor beyond the window."""
+        t_max, h = 64, 2 * hkv if hkv == 2 else hkv
+        cursors = (cursors[0], 50, cursors[1], cursors[2])
+        s_ = len(cursors)
+        live = np.asarray([True, False, True, True])
+        pool_k, pool_v = (
+            jnp.asarray(rng.normal(size=(2, s_, t_max, hkv, 128)),
+                        jnp.float32).at[:, 1].set(jnp.nan)
+            for _ in range(2))
+        q = jnp.asarray(rng.normal(size=(s_, 1, h, 128)), jnp.float32)
+        positions = jnp.asarray(cursors, jnp.int32)[:, None]
+        got = pool_decode_attention(
+            q, pool_k, pool_v, 0, positions, window=window,
+            block_rows=16 * hkv, interpret=True, live=jnp.asarray(live))
+        want = grouped_query_attention(
+            q[live], pool_k[0][live], pool_v[0][live],
+            mask=_mask(positions[live], t_max, window))
+        assert not np.asarray(got[1]).any()
+        np.testing.assert_allclose(np.asarray(got[live]), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("hkv,window", HEADS + [(3, None), (2, None)])
+    def test_work_list_is_the_live_slots_spans(self, rng, hkv, window):
+        """The span function the host counts with (numpy) and the
+        ``lo``/``hi`` the kernel prefetches (jax) agree, and the work
+        list is every live slot's blocks ``lo..hi`` once, in order."""
+        s_, t_max, block = 9, 96, 16
+        cursors = rng.integers(0, t_max + 8, size=s_)    # some past the end
+        cursors[:3] = 0, t_max - 1, 16
+        live = rng.random(s_) < 0.6
+        live[:3] = True
+        kw = dict(block=block, hkv=hkv, window=window, t_max=t_max)
+        lo, hi = key_block_span(cursors, cursors, **kw)
+        assert isinstance(lo, np.ndarray) and (0 <= lo).all()
+        assert (lo <= hi).all() and (hi < t_max * hkv // block).all()
+        n, slot_of, klo, khi, first = (np.asarray(a) for a in _work_list(
+            jnp.asarray(cursors, jnp.int32)[:, None], jnp.asarray(live),
+            **kw))
+        assert np.array_equal(klo, lo) and np.array_equal(khi, hi)
+        items = [(s, b) for s in range(s_) if live[s]
+                 for b in range(lo[s], hi[s] + 1)]
+        assert n[0] == len(items) == (hi - lo + 1)[live].sum()
+        w = np.arange(n[0])
+        assert [(s, lo[s] + i - first[s])
+                for i, s in zip(w, slot_of[:n[0]])] == items
+        # a verify's span runs from its oldest query's window to its newest
+        vlo, vhi = key_block_span(cursors + 3, cursors, **kw)
+        assert np.array_equal(vlo, lo) and (vhi >= hi).all()
+
+    def test_served_trace_reads_no_more_than_the_pool(self, rng):
+        """A server's ``kv_blocks`` (the slots dispatched) against
+        ``kv_blocks_pool`` (every slot's cursor, frozen ones included):
+        equal while every slot holds a request, less once one has left
+        (a slot that never held one counts its block at cursor 0)."""
+        from deeplearning4j_tpu.serving import DecodeServer
+
+        srv = DecodeServer(_lm128(), slots=2, max_len=96)
+        for n, new in ((40, 3), (9, 12)):
+            srv.submit(rng.integers(1, 61, n).astype(np.int32), new)
+        srv.step()
+        assert srv.kv_blocks == srv.kv_blocks_pool == 2   # one block a slot
+        srv.drain()
+        st = srv.stats()
+        assert 0 < st["kv_blocks"] < st["kv_blocks_pool"]
+        assert st["kv_blocks_share"] < 1
 
     def test_pool_stored_in_another_dtype(self, rng):
         """A float32 pool under bf16 queries: blocks are cast in VMEM,
@@ -88,9 +207,11 @@ class TestPoolDecodeAttention:
                       - np.asarray(want, np.float32)).max() < 2e-2
 
     def test_block_rows(self):
-        # the benchmark's pool: 1 MiB blocks of 4,096 rows (2,048 positions)
-        assert pool_block_rows((4, 64, 16384, 2, 128), jnp.bfloat16) == 4096
-        assert pool_block_rows((4, 64, 16384, 2, 128), jnp.float32) == 2048
+        # the benchmark's pools: 512 KiB blocks of 2,048 rows — 1,024
+        # positions at StarCoder2's 2 kv heads, 128 at OLMoE's 16
+        assert pool_block_rows((4, 64, 16384, 2, 128), jnp.bfloat16) == 2048
+        assert pool_block_rows((4, 32, 4096, 16, 128), jnp.bfloat16) == 2048
+        assert pool_block_rows((4, 64, 16384, 2, 128), jnp.float32) == 1024
         # a small pool is one block; a head size off the lanes has no kernel
         assert pool_block_rows((2, 4, 96, 1, 128), jnp.float32) == 96
         assert pool_block_rows((2, 4, 96, 2, 64), jnp.float32) is None

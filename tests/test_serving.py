@@ -15,6 +15,7 @@ The load-bearing claims:
 3. ``generate_beam(beam_size=1)`` is greedy ``generate``.
 """
 
+import functools
 import importlib.util
 import json
 import os
@@ -1322,3 +1323,98 @@ class TestPipelinedLoop:
         fused.submit(_prompts(rng, (5,))[0], 9)
         fused.drain()
         assert fused.stats()["decode_ahead_share"] == 0.0
+
+
+class TestKernelRead:
+    """The pool read by the Pallas kernel (interpreted here) under a
+    server: its work list is the live slots' own key blocks."""
+
+    SLOTS, MAX_LEN, BUCKETS, BLOCK = 2, 96, (16, 32, 64, 96), 16
+
+    def _server(self, monkeypatch, kernel, **kw):
+        """A server over 128-wide heads whose decode programs read the
+        pool by the kernel (``kernel``) or by the XLA op, with key blocks
+        of 16 positions so that a 96-position slot has six."""
+        from deeplearning4j_tpu.pallas import decode_attention
+        from deeplearning4j_tpu.serving import engine as eng
+
+        monkeypatch.setattr(decode_attention, "_BLOCK_BYTES",
+                            self.BLOCK * 128 * 4)
+        lm = _lm("rope", d_model=256, num_heads=2, num_kv_heads=1)
+        srv = DecodeServer(lm, slots=self.SLOTS, max_len=self.MAX_LEN,
+                           buckets=self.BUCKETS, **kw)
+        # the decode programs are built at the first dispatch
+        srv.engine._decode_jit = lambda donate, impl, *bound: (
+            eng.DecodeEngine._decode_jit(
+                srv.engine, donate,
+                functools.partial(impl, pool_kernel=kernel), *bound))
+        return srv
+
+    def _serve(self, rng_seed, srv, poison=False):
+        """A long request and a short one; when the long one has left, a
+        shorter one takes its slot. ``poison``: before that, the slot's
+        key blocks past the newcomer's reach are overwritten with NaN —
+        a read of the previous tenant's keys would show in the tokens."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(rng_seed)
+        long_, stay, short = _prompts(rng, (70, 20, 10))
+        a = srv.submit(long_, 4)
+        b = srv.submit(stay, 40)
+        while a.state != "finished":
+            srv.step()
+        srv.flush()
+        if poison:
+            pool = srv.engine.cache
+            reach = 2 * self.BLOCK      # prompt 10 + 6 tokens: blocks 0, 1
+            pool.k = pool.k.at[:, a.slot, reach:].set(jnp.nan)
+            pool.v = pool.v.at[:, a.slot, reach:].set(jnp.nan)
+        c = srv.submit(short, 6)
+        srv.drain()
+        assert c.slot == a.slot and b.state == c.state == "finished"
+        return [list(r.tokens) for r in (a, b, c)]
+
+    @pytest.mark.parametrize("kind", ["plain", "fused"])
+    def test_tokens_before_and_after_a_slot_changes_tenant(
+            self, monkeypatch, kind):
+        kw = {"fuse_steps": 3} if kind == "fused" else {}
+        want = self._serve(5, self._server(monkeypatch, False, **kw))
+        srv = self._server(monkeypatch, True, **kw)
+        assert self._serve(5, srv, poison=True) == want
+        # the host's cursors are the device's, and what the kernel read
+        # is less than every slot's span: the long tenant's frozen cursor
+        # counted in the pool's while its slot was free
+        assert np.array_equal(srv._cursors,
+                              np.asarray(srv.engine.cache.loop["cursors"]))
+        st = srv.stats()
+        assert 0 < st["kv_blocks"] < st["kv_blocks_pool"]
+        assert st["kv_blocks_share"] == round(
+            st["kv_blocks"] / st["kv_blocks_pool"], 4)
+
+    def test_kv_blocks_on_the_span_and_the_counters(self, monkeypatch):
+        reg = metrics()
+        read0 = reg.counter("serve_decode_kv_blocks_total").value()
+        pool0 = reg.counter("serve_decode_kv_blocks_pool_total").value()
+        tr = SpanTracer()
+        set_tracer(tr)
+        try:
+            srv = self._server(monkeypatch, False)
+            self._serve(7, srv)
+        finally:
+            set_tracer(None)
+        decode = [sp.attrs for sp in tr.spans()
+                  if sp.name == "serve.decode" and sp.attrs["live"]]
+        assert all(0 < a["kv_blocks"] <= a["kv_blocks_pool"] for a in decode)
+        assert sum(a["kv_blocks"] for a in decode) == srv.kv_blocks == (
+            reg.counter("serve_decode_kv_blocks_total").value() - read0)
+        assert sum(a["kv_blocks_pool"] for a in decode) == (
+            srv.kv_blocks_pool) == reg.counter(
+                "serve_decode_kv_blocks_pool_total").value() - pool0
+        # the first dispatch: prompts 70 and 20 -> cursors 70 and 20 ->
+        # blocks 0..4 and 0..1
+        assert (decode[0]["kv_blocks"], decode[0]["kv_blocks_pool"]) == (7, 7)
+        # a pool without the kernel's blocks (32-wide heads) counts none
+        plain = DecodeServer(_lm("rope"), slots=2, max_len=64)
+        plain.submit(np.arange(1, 6, dtype=np.int32), 3)
+        plain.drain()
+        assert plain.stats()["kv_blocks_share"] is None

@@ -1,0 +1,237 @@
+"""Kimi Delta Attention (KDA, arXiv:2510.26692 section 3): the linear-attention
+mixer of a hybrid block, as ``TransformerLM._block`` runs it.
+
+Per head, with a recurrent state ``S`` [dk, dv] in float32, zero at a
+request's start (``x`` is the block's normed input):
+
+    q~, k~, v~ = x Wq, x Wk, x Wv
+    q, k, v    = silu(conv(q~)), silu(conv(k~)), silu(conv(v~))
+                 conv: depthwise causal convolution over time, no bias
+    q, k       = q / ||q||, k / ||k||  per head;  q = q dk^-1/2
+    g_t        = lower * sigmoid(exp(A_log) * (x Wa + dt_bias))   in (lower, 0)
+    beta_t     = sigmoid(x Wb)                                    one a head
+    S'         = diag(exp(g_t)) S_{t-1}
+    S_t        = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t        = S_t^T q_t
+    y          = concat_h(rmsnorm(o_t) * sigmoid(x Wg)_h) Wo
+
+Two forms of the same recurrence:
+
+- ``kda_scan`` (prefill, training): chunks of ``CHUNK`` positions. Inside
+  a chunk the delta rule is one unit-lower-triangular system (the WY form of
+  the paper's section 3.2), solved by the inverse's finite series; across
+  chunks the state is a ``lax.scan``
+  carry. The decay between two positions of a chunk is taken as
+  ``exp(G_t - G_i)`` of the cumulated log-decays themselves, never as a
+  quotient ``exp(G_t) / exp(G_i)``: with ``lower = -5`` a chunk's
+  cumulated decay leaves float32's range.
+- ``kda_step`` (decode): the recurrence itself, one token a row.
+
+A row that holds no token (``live`` false: a prompt's pad tail, a slot
+that owes nothing) takes ``beta = 0`` and ``g = 0`` and so leaves the state
+as it was, and the convolution tail is that of the last real positions.
+A pad tail is inert for attention by causality; for a recurrence it has to
+be made so, here.
+
+Scopes ``kda.proj``, ``kda.scan`` and ``kda.step`` name the parts in a
+device trace.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+__all__ = ["CHUNK", "init_kda", "kda_mixer", "kda_scan", "kda_step"]
+
+CHUNK = 64
+_HI = lax.Precision.HIGHEST
+_EPS = 1e-6
+
+
+def init_kda(key, d_model: int, num_heads: int, head_dim: int, conv: int,
+             dtype) -> Dict[str, Any]:
+    """Glorot-normal projections ``wq/wk/wv/wa`` [D, H dk], ``wo``
+    [H dk, D], ``wb/wg`` [D, H]; convolution taps ``conv_q/k/v``
+    [conv, H dk] (normal / sqrt(conv)); ``a_log`` [H] zero, ``dt_bias``
+    [H dk] zero, the output norm's gain ``o_norm.g`` [dk] one."""
+    ks = jax.random.split(key, 10)
+    d, c = d_model, num_heads * head_dim
+
+    def glorot(k, fan_in, fan_out):
+        scale = jnp.sqrt(2.0 / (fan_in + fan_out)).astype(dtype)
+        return jax.random.normal(k, (fan_in, fan_out), dtype) * scale
+
+    def taps(k):
+        return jax.random.normal(k, (conv, c), dtype) * (conv ** -0.5)
+
+    return {"wq": glorot(ks[0], d, c), "wk": glorot(ks[1], d, c),
+            "wv": glorot(ks[2], d, c), "wa": glorot(ks[3], d, c),
+            "wb": glorot(ks[4], d, num_heads),
+            "wg": glorot(ks[5], d, num_heads),
+            "wo": glorot(ks[6], c, d),
+            "conv_q": taps(ks[7]), "conv_k": taps(ks[8]),
+            "conv_v": taps(ks[9]),
+            "a_log": jnp.zeros((num_heads,), dtype),
+            "dt_bias": jnp.zeros((c,), dtype),
+            "o_norm": {"g": jnp.ones((head_dim,), dtype)}}
+
+
+def _l2norm(x):
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _EPS)
+
+
+def _unit_lower_inverse(n, eye, mm):
+    """``(I + n)^-1`` for strictly lower triangular ``n`` [..., c, c]: n is
+    nilpotent, so the series ``sum_j (-n)^j`` ends at ``c - 1`` and factors
+    as ``(I - n)(I + n^2)(I + n^4)...``: a dozen small matmuls on the MXU
+    where a triangular solve is a sequential custom call on the TPU (its 64
+    rows one after another, the largest single op of a prefill; PERF.md
+    section 6, PR 29)."""
+    inv, power, reach = eye - n, n, 2
+    while reach < n.shape[-1]:
+        power = mm("...ij,...jk->...ik", power, power)
+        inv = mm("...ij,...jk->...ik", inv, eye + power)
+        reach *= 2
+    return inv
+
+
+def kda_scan(q, k, v, g, beta, state):
+    """The chunked recurrence. ``q, k`` [b, t, H, dk], ``v`` [b, t, H, dv],
+    ``g`` [b, t, H, dk] (log-decay, <= 0), ``beta`` [b, t, H], all float32;
+    ``state`` [b, H, dk, dv]. Returns ``(o [b, t, H, dv], state)``."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(CHUNK, t)
+    pad = -t % c
+    if pad:     # beta = 0 and g = 0: the tail moves no state
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (
+            a.ndim - 2)) for a in (q, k, v, g, beta))
+    n = (t + pad) // c
+
+    def chunks(a):      # [b, n c, H, ...] -> [n, b, H, c, ...]
+        a = a.reshape((b, n, c) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 3, 2)
+
+    keep = jnp.tril(jnp.ones((c, c), bool))
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    eye = jnp.eye(c, dtype=jnp.float32)
+
+    def mm(spec, x, y):
+        return jnp.einsum(spec, x, y, precision=_HI)
+
+    def step(s, xs):
+        qc, kc, vc, gc, bc = xs                 # [b, H, c, dk] ... [b, H, c]
+        cum = jnp.cumsum(gc, axis=2)            # G_t
+        # decay from position i to position t >= i, per channel
+        rel = jnp.where(keep[:, :, None],
+                        cum[:, :, :, None, :] - cum[:, :, None, :, :],
+                        -jnp.inf)
+        kd = kc[:, :, None, :, :] * jnp.exp(rel)            # [b,H,t,i,dk]
+        a = jnp.sum(kc[:, :, :, None, :] * kd, axis=-1)     # k_t . k_i
+        qk = jnp.sum(qc[:, :, :, None, :] * kd, axis=-1)    # q_t . k_i
+        into = jnp.exp(cum)
+        rhs = bc[..., None] * (vc - mm("bhtk,bhkv->bhtv", kc * into, s))
+        w = mm("bhti,bhiv->bhtv", _unit_lower_inverse(
+            bc[..., None] * jnp.where(strict, a, 0.0), eye, mm), rhs)
+        o = mm("bhtk,bhkv->bhtv", qc * into, s) + mm(
+            "bhti,bhiv->bhtv", qk, w)
+        last = cum[:, :, -1:, :]
+        s = jnp.swapaxes(jnp.exp(last), 2, 3) * s + mm(
+            "bhik,bhiv->bhkv", kc * jnp.exp(last - cum), w)
+        return s, o
+
+    state, o = lax.scan(step, state,
+                        tuple(chunks(a) for a in (q, k, v, g, beta)))
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)          # [b, n, c, H, dv]
+    return o.reshape(b, n * c, h, dv)[:, :t], state
+
+
+def kda_step(q, k, v, g, beta, state):
+    """The recurrence for one position a row: ``q, k, g`` [b, H, dk], ``v``
+    [b, H, dv], ``beta`` [b, H], float32; ``state`` [b, H, dk, dv].
+    Returns ``(o [b, H, dv], state)``."""
+    # one pass over the state gives both S'^T k and S'^T q (S' = diag(a) S,
+    # so S'^T x = S^T (a * x)); o = S_t^T q = S'^T q + beta (k . q) u
+    decay = jnp.exp(g)
+    sk, sq = jnp.moveaxis(jnp.einsum(
+        "bhkv,bhjk->bhjv", state, jnp.stack([decay * k, decay * q], axis=2),
+        precision=_HI), 2, 0)
+    u = beta[..., None] * (v - sk)
+    o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * u
+    return o, decay[..., None] * state + k[..., None] * u[:, :, None, :]
+
+
+def _conv(rows, taps):
+    """Depthwise causal convolution with ``taps`` [K, C] over ``rows``
+    [b, K-1 + t, C]: the K-1 positions before the first output, then the t
+    positions themselves. Returns [b, t, C]."""
+    width = taps.shape[0]
+    t = rows.shape[1] - (width - 1)
+    return sum(rows[:, j:j + t] * taps[j] for j in range(width))
+
+
+def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
+              cast: Callable = lambda w: w, live=None,
+              state=None) -> Tuple[Any, Any, Any]:
+    """The mixer on ``x`` [b, t, D] with the block's ``kda`` parameters
+    ``p`` (``init_kda``). ``live`` [b, t] (bool, default all) marks the
+    rows that hold a token; within a row they are a prefix. ``state`` =
+    ``(S [b, H, dk, dv] float32, tail [b, K-1, 3 H dk])`` is what the
+    positions before ``x`` left (default: a request's start, zeros); with a
+    state and ``t == 1`` the recurrence runs as ``kda_step``.
+
+    Returns ``(y [b, t, D] in x.dtype, S, tail)``: the state and the
+    convolution tail as of each row's last live position."""
+    b, t, _ = x.shape
+    h = num_heads
+    f32 = jnp.float32
+    with jax.named_scope("kda.proj"):
+        qkv = jnp.concatenate(
+            [x @ cast(p[n]) for n in ("wq", "wk", "wv")], axis=-1)
+        width = p["conv_q"].shape[0]
+        taps = jnp.concatenate(
+            [p[n] for n in ("conv_q", "conv_k", "conv_v")], axis=-1)
+        if state is None:
+            s0 = None
+            tail = jnp.zeros((b, width - 1, qkv.shape[-1]), qkv.dtype)
+        else:
+            s0, tail = state
+        rows = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+        mixed = jax.nn.silu(_conv(rows.astype(f32), taps.astype(f32)))
+        q, k, v = (a.reshape(b, t, h, -1) for a in jnp.split(mixed, 3, -1))
+        dk = q.shape[-1]
+        q = _l2norm(q) * dk ** -0.5
+        k = _l2norm(k)
+        a = (x @ cast(p["wa"])).astype(f32) + p["dt_bias"].astype(f32)
+        g = lower * jax.nn.sigmoid(
+            jnp.exp(p["a_log"].astype(f32))[:, None] * a.reshape(b, t, h, dk))
+        beta = jax.nn.sigmoid((x @ cast(p["wb"])).astype(f32))   # [b, t, H]
+        gate = jax.nn.sigmoid((x @ cast(p["wg"])).astype(f32))
+        if live is not None:
+            g = jnp.where(live[:, :, None, None], g, 0.0)
+            beta = jnp.where(live[:, :, None], beta, 0.0)
+        # the tail as of the last live position: the K-1 rows that end there
+        n_live = (jnp.full((b,), t) if live is None
+                  else jnp.sum(live, axis=1))
+        idx = n_live[:, None] + jnp.arange(width - 1)[None, :]
+        new_tail = jnp.take_along_axis(rows, idx[:, :, None], axis=1)
+        if s0 is None:
+            s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
+    if state is not None and t == 1:
+        with jax.named_scope("kda.step"):
+            o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            s0)
+            o = o[:, None]
+    else:
+        with jax.named_scope("kda.scan"):
+            o, s = kda_scan(q, k, v, g, beta, s0)
+    with jax.named_scope("kda.proj"):
+        o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + _EPS) \
+            * p["o_norm"]["g"].astype(f32)
+        o = (o * gate[..., None]).astype(x.dtype).reshape(b, t, -1)
+        y = o @ cast(p["wo"])
+    return y, s, new_tail.astype(tail.dtype)

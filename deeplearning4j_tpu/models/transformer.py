@@ -18,7 +18,7 @@ from __future__ import annotations
 import functools
 import logging
 import os
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 import jax
@@ -29,6 +29,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+from deeplearning4j_tpu.models import kda as kda_mod
+from deeplearning4j_tpu.models import mla as mla_mod
 from deeplearning4j_tpu.models import routed_experts
 from deeplearning4j_tpu.monitor import tracer
 from deeplearning4j_tpu.ops.attention import (
@@ -47,13 +49,21 @@ from deeplearning4j_tpu.pallas.flash_attention import (
 logger = logging.getLogger(__name__)
 
 
-def _rope(x, positions, base: float = 10000.0):
+def _rope(x, positions, base: float = 10000.0, interleaved: bool = False):
     """Rotary position embedding on [b, t, h, d] at absolute ``positions``
     (may be traced): [t] shared across the batch (training/prefill), or
     [b, t] per-row (the serving decode step, where every slot sits at its
     own position). Angles in f32, result in x's dtype. Rotation is
     applied to q/k BEFORE attention, so it composes unchanged with the
-    XLA, Pallas-flash, and ring paths."""
+    XLA, Pallas-flash, and ring paths. ``base`` and the pairing are the
+    model's (``rope_theta``, ``rope_interleaved``): a pair is dimensions
+    ``(i, i + d/2)``, or ``(2i, 2i + 1)`` when interleaved."""
+    if interleaved:     # bring each pair to (i, i + d/2), rotate, put back
+        shape = x.shape
+        halves = jnp.swapaxes(x.reshape(shape[:-1] + (-1, 2)), -1, -2)
+        out = _rope(halves.reshape(shape), positions, base)
+        return jnp.swapaxes(out.reshape(shape[:-1] + (2, -1)), -1, -2
+                            ).reshape(shape)
     d = x.shape[-1]
     half = d // 2
     freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -83,7 +93,7 @@ def _layernorm(x, g, b, eps=1e-5):
     return (y * g.astype(st) + b.astype(st)).astype(x.dtype)
 
 
-def _rmsnorm(x, g, eps=1e-5):
+def _rmsnorm(x, g, eps: float = 1e-5):
     # weight only; statistics in >=f32, result in x's dtype (as _layernorm)
     st = jnp.promote_types(x.dtype, jnp.float32)
     xs = x.astype(st)
@@ -103,7 +113,15 @@ class TransformerLM:
                  norm: str = "layernorm", qk_norm: bool = False,
                  num_experts: int = 0, experts_per_token: int = 0,
                  norm_topk_prob: bool = False,
-                 tie_embeddings: bool = True):
+                 tie_embeddings: bool = True,
+                 rope_theta: float = 10000.0, rope_interleaved: bool = False,
+                 norm_eps: float = 1e-5,
+                 mixers: Optional[Sequence[str]] = None,
+                 ffns: Optional[Sequence[str]] = None,
+                 glu_width: Optional[int] = None,
+                 kda: Optional[Dict[str, Any]] = None,
+                 mla: Optional[Dict[str, Any]] = None,
+                 moe: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -124,6 +142,46 @@ class TransformerLM:
             raise ValueError(
                 f"experts_per_token={experts_per_token} must be in "
                 f"[1, num_experts={num_experts}]")
+        # The block, described per layer (defaults: every layer the block
+        # above). mixers[i]: "attn" (GQA/MHA with RoPE or learned positions,
+        # above) | "kda" (Kimi Delta Attention, models/kda.py; ``kda`` =
+        # {head_dim, conv, lower}: a recurrent [H, dk, dk] state and a
+        # convolution tail instead of keys and values) | "mla" (latent
+        # attention, models/mla.py; ``mla`` = {kv_lora_rank,
+        # qk_nope_head_dim, qk_rope_head_dim, v_head_dim}: one latent row a
+        # position). ffns[i]: "mlp" (the biased GELU MLP) | "glu" (a dense
+        # SwiGLU of width ``glu_width``, no bias) | "moe" (routed experts of
+        # width ``d_ff``). ``moe`` describes a router and a share other
+        # than OLMoE's (models/routed_experts.py): {n_group, topk_group,
+        # scale, bias, shared_width, first, held}: group-limited sigmoid
+        # routing over ``num_experts``, of which ``held`` starting at
+        # ``first`` live here, and a shared expert. ``rope_theta`` /
+        # ``rope_interleaved`` / ``norm_eps``: RoPE's base and pairing, the
+        # norms' epsilon.
+        kinds = ("attn", "kda", "mla"), ("mlp", "glu", "moe")
+        self.mixers = tuple(mixers) if mixers is not None else (
+            "attn",) * num_layers
+        self.ffns = tuple(ffns) if ffns is not None else (
+            "moe" if num_experts else "mlp",) * num_layers
+        for name, got, ok in (("mixers", self.mixers, kinds[0]),
+                              ("ffns", self.ffns, kinds[1])):
+            if len(got) != num_layers or set(got) - set(ok):
+                raise ValueError(f"{name}={got!r} must name one of {ok} "
+                                 f"for each of the {num_layers} layers")
+        for kind, sizes, used in (("kda", kda, self.mixers),
+                                  ("mla", mla, self.mixers),
+                                  ("glu", glu_width, self.ffns),
+                                  ("moe", num_experts, self.ffns)):
+            if kind in used and not sizes:
+                raise ValueError(f"a {kind!r} layer needs its sizes "
+                                 "(kda=, mla=, glu_width=, num_experts=)")
+        self.kda = dict(kda) if kda else None
+        self.mla = dict(mla) if mla else None
+        self.moe = dict(moe) if moe else None
+        self.glu_width = glu_width
+        self.rope_theta = float(rope_theta)
+        self.rope_interleaved = bool(rope_interleaved)
+        self.norm_eps = float(norm_eps)
         self.norm = norm
         self.qk_norm = bool(qk_norm)
         self.num_experts = int(num_experts)
@@ -226,23 +284,36 @@ class TransformerLM:
                 jax.random.fold_in(keys[0], 1), (V, D), dt) * 0.02
         for i in range(self.num_layers):
             k = keys[2 + 6 * i:2 + 6 * (i + 1)]
-            blk = {
-                "ln1": norm(),
-                "attn": {
+            blk = {"ln1": norm(), "ln2": norm()}
+            if self.mixers[i] == "kda":
+                blk["kda"] = kda_mod.init_kda(
+                    k[0], D, self.num_heads, self.kda["head_dim"],
+                    self.kda["conv"], dt)
+            elif self.mixers[i] == "mla":
+                blk["mla"] = mla_mod.init_mla(k[0], D, self.num_heads,
+                                              self.mla, dt)
+            else:
+                blk["attn"] = {
                     "wq": dense(k[0], D, D),
                     "wk": dense(k[1], D, self.num_kv_heads * Dh),
                     "wv": dense(k[2], D, self.num_kv_heads * Dh),
                     "wo": dense(k[3], D, D),
-                },
-                "ln2": norm(),
-            }
-            if self.qk_norm:
-                blk["attn"]["q_norm"] = {"g": jnp.ones((D,), dt)}
-                blk["attn"]["k_norm"] = {
-                    "g": jnp.ones((self.num_kv_heads * Dh,), dt)}
-            if self.num_experts:
+                }
+                if self.qk_norm:
+                    blk["attn"]["q_norm"] = {"g": jnp.ones((D,), dt)}
+                    blk["attn"]["k_norm"] = {
+                        "g": jnp.ones((self.num_kv_heads * Dh,), dt)}
+            if self.ffns[i] == "moe":
+                m = self.moe or {}
                 blk["moe"] = routed_experts.init_experts(
-                    k[4], D, F, self.num_experts, dt)
+                    k[4], D, F, self.num_experts, dt, held=m.get("held"),
+                    bias=bool(m.get("bias")),
+                    shared_width=int(m.get("shared_width", 0)))
+            elif self.ffns[i] == "glu":
+                G = self.glu_width
+                blk["glu"] = {"w1": dense(k[4], D, G),
+                              "w3": dense(jax.random.fold_in(k[4], 1), D, G),
+                              "w2": dense(k[5], G, D)}
             else:
                 blk["mlp"] = {
                     "w1": dense(k[4], D, F), "b1": jnp.zeros((F,), dt),
@@ -301,9 +372,10 @@ class TransformerLM:
     def _block(self, blk, h, *, mesh: Optional[Mesh] = None,
                sequence_parallel: bool = False, attention=None,
                positions=None, train: bool = False, live=None,
-               moe_info: Optional[list] = None):
+               moe_info: Optional[list] = None, state=None):
         """One pre-norm block on ``h`` [b, t, D], as the model describes
-        it (norm kind, QK-norm, dense MLP or routed experts — chosen here,
+        that layer (``blk``'s own keys say which mixer and which
+        feed-forward it is; norm kind, QK-norm — chosen here,
         at trace time, for training, prefill and decode alike). Returns
         ``(h, k, v)``
         with k/v in [b, t, H, Dh] — ``forward`` discards them (XLA DCE),
@@ -315,14 +387,36 @@ class TransformerLM:
         [t] (default 0..t-1; the decode step passes its cache slot) or
         [b, t] per-row (the serving decode, one position per slot).
 
-        Routed experts only: ``live`` [b, t] (bool) marks the rows that
-        hold a token (a prompt's pad tail and free slots do not), and a
-        list passed as ``moe_info`` receives this layer's routing
+        ``live`` [b, t] (bool) marks the rows that hold a token (a
+        prompt's pad tail and free slots do not): the others reach no
+        routed expert and move no recurrent state. A list passed as
+        ``moe_info`` receives a routed layer's routing
         (``routed_experts.routed_ffn``'s ``info``: chosen experts, their
-        weights, the live load per expert)."""
+        weights, the live load per expert held).
+
+        The other mixers leave other things behind. A ``kda`` layer
+        returns ``(h, S, tail)``, its recurrent state and convolution tail
+        as of each row's last live position, and continues from ``state``
+        = ``(S, tail)`` (default: a request's start). An ``mla`` layer
+        returns ``(h, latent, None)``, each position's latent row
+        [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
+        attends a cache of such rows instead of the block's own."""
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
         x = self._norm(h, blk["ln1"])
+        if "kda" in blk or "mla" in blk:
+            if sequence_parallel:
+                raise NotImplementedError(
+                    "sequence parallelism is written for 'attn' layers only")
+            if "kda" in blk:
+                y, k, v = kda_mod.kda_mixer(
+                    x, blk["kda"], num_heads=self.num_heads,
+                    lower=self.kda["lower"], cast=policy.cast_compute,
+                    live=live, state=state)
+            else:
+                y, k = self._mla(blk["mla"], x, attention, positions, train)
+                v = None
+            return self._ffn(blk, h + y, live, moe_info), k, v
         q = x @ policy.cast_compute(blk["attn"]["wq"])
         if self.qk_norm:
             q = _rmsnorm(q, blk["attn"]["q_norm"]["g"])
@@ -336,8 +430,8 @@ class TransformerLM:
         if self.pos_encoding == "rope":
             if positions is None:
                 positions = jnp.arange(t)
-            q = _rope(q, positions)
-            k = _rope(k, positions)
+            q = _rope(q, positions, self.rope_theta, self.rope_interleaved)
+            k = _rope(k, positions, self.rope_theta, self.rope_interleaved)
         # the returned k/v stay at num_kv_heads (what the KV cache
         # stores); attention sees them repeated per query-head group
         if attention is not None:
@@ -365,28 +459,99 @@ class TransformerLM:
             o = grouped_query_attention(q, k, v, causal=True,
                                         window=self.attn_window)
         h = h + o.reshape(b, t, -1) @ policy.cast_compute(blk["attn"]["wo"])
+        return self._ffn(blk, h, live, moe_info), k, v
+
+    def _ffn(self, blk, h, live, moe_info):
+        """The block's second half on the residual stream ``h`` [b, t, D]:
+        the norm and the layer's feed-forward (``blk`` holds ``moe``,
+        ``glu`` or ``mlp``)."""
+        policy = self.policy
+        b, t = h.shape[0], h.shape[1]
         x = self._norm(h, blk["ln2"])
-        if self.num_experts:
+        if "moe" in blk:
+            m = self.moe
             y, info = routed_experts.routed_ffn(
                 x.reshape(b * t, -1), blk["moe"],
                 experts_per_token=self.experts_per_token,
                 norm_topk_prob=self.norm_topk_prob,
                 cast=policy.cast_compute,
-                live=None if live is None else live.reshape(b * t))
+                live=None if live is None else live.reshape(b * t),
+                groups=m and (m["n_group"], m["topk_group"], m["scale"]),
+                first=m["first"] if m else 0)
             if moe_info is not None:
                 moe_info.append(info)
-            return h + y.reshape(b, t, -1), k, v
+            return h + y.reshape(b, t, -1)
+        if "glu" in blk:
+            g = blk["glu"]
+            x = (jax.nn.silu(x @ policy.cast_compute(g["w1"]))
+                 * (x @ policy.cast_compute(g["w3"])))
+            return h + x @ policy.cast_compute(g["w2"])
         x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
                         + policy.cast_compute(blk["mlp"]["b1"]))
-        h = (h + x @ policy.cast_compute(blk["mlp"]["w2"])
-             + policy.cast_compute(blk["mlp"]["b2"]))
-        return h, k, v
+        return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
+                + policy.cast_compute(blk["mlp"]["b2"]))
+
+    def _mla(self, p, x, attention, positions, train):
+        """The latent-attention mixer on the normed ``x`` [b, t, D]:
+        ``(y [b, t, D], latent [b, t, r + dr])``. Without ``attention``
+        the positions attend each other causally, keys and values expanded
+        from the latents: the XLA op, or the flash kernel where
+        ``_attn_impl`` says so. The kernel takes one head size, so q and k
+        (dn + dr = 192 wide) and v (128) are padded with zeros to the next
+        multiple of 128: the scores and the first dv columns of the result
+        are unchanged."""
+        cast = self.policy.cast_compute
+        t = x.shape[1]
+        if positions is None:
+            positions = jnp.arange(t)
+        q_nope, q_rope, latent, gate = mla_mod.mla_project(
+            x, p, num_heads=self.num_heads, dims=self.mla, cast=cast,
+            rope=lambda a: _rope(a, positions, self.rope_theta,
+                                 self.rope_interleaved),
+            rmsnorm=lambda a, g: _rmsnorm(a, g, self.norm_eps))
+
+        def causal(q, k, v, scale):
+            if self._attn_impl(t, train=train) != "flash":
+                return dot_product_attention(q, k, v, causal=True,
+                                             scale=scale)
+            d = -(-q.shape[-1] // 128) * 128
+
+            def pad(a):
+                return jnp.pad(a, ((0, 0),) * 3 + ((0, d - a.shape[-1]),))
+
+            return flash_attention(pad(q), pad(k), pad(v), causal=True,
+                                   scale=scale)[..., :v.shape[-1]]
+
+        if attention is not None:
+            o = attention(q_nope, q_rope, latent)
+        else:
+            o = mla_mod.attend_full(q_nope, q_rope, latent, p, dims=self.mla,
+                                    attention=causal, cast=cast)
+        return mla_mod.mla_output(o, gate, p, cast), latent
 
     def _norm(self, x, p):
         """The model's norm (``norm=``) with the parameters ``p``."""
         if self.norm == "rmsnorm":
-            return _rmsnorm(x, p["g"])
-        return _layernorm(x, p["g"], p["b"])
+            return _rmsnorm(x, p["g"], self.norm_eps)
+        return _layernorm(x, p["g"], p["b"], self.norm_eps)
+
+    @property
+    def hybrid(self) -> bool:
+        """True when some layer's mixer is not key/value attention."""
+        return any(m != "attn" for m in self.mixers)
+
+    def layers_of(self, kind: str):
+        """The indices of the layers whose mixer or feed-forward is
+        ``kind``, in order: a layer's place in its kind's own state
+        (``serving/kv_cache.py``) is its place in this list."""
+        return [i for i, pair in enumerate(zip(self.mixers, self.ffns))
+                if kind in pair]
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts a layer holds here (all of them unless ``moe``
+        names a share)."""
+        return int(self.moe["held"]) if self.moe else self.num_experts
 
     def _repeat_kv(self, x):
         """[b, t, Hkv, d] → [b, t, H, d] by repeating each kv head over
@@ -405,6 +570,9 @@ class TransformerLM:
         bodies are traced apart)."""
         if moe_info is not None and (self.remat or self.scan_layers):
             raise ValueError("moe_info needs remat=False, scan_layers=False")
+        if self.scan_layers and (len(set(self.mixers)) > 1
+                                 or len(set(self.ffns)) > 1):
+            raise ValueError("scan_layers needs every layer the same block")
         policy = self.policy
         b, t = tokens.shape
         h = jnp.take(params["embed"], tokens, axis=0)
@@ -573,6 +741,12 @@ class TransformerLM:
             "experts_per_token": self.experts_per_token,
             "norm_topk_prob": self.norm_topk_prob,
             "tie_embeddings": self.tie_embeddings,
+            "rope_theta": self.rope_theta,
+            "rope_interleaved": self.rope_interleaved,
+            "norm_eps": self.norm_eps,
+            "mixers": list(self.mixers), "ffns": list(self.ffns),
+            "glu_width": self.glu_width, "kda": self.kda, "mla": self.mla,
+            "moe": self.moe,
         }
 
     def _ensure_init(self):
@@ -617,6 +791,11 @@ class TransformerLM:
         """One parallel forward over the prompt capturing per-layer K/V.
         Returns ``(h_last [b, D], cache)`` with cache entries padded out
         to ``prompt_len + max_new_tokens`` positions."""
+        if self.hybrid:
+            raise NotImplementedError(
+                "generate() and generate_beam() carry key/value caches only: "
+                "a 'kda' layer's recurrent state and an 'mla' layer's latent "
+                "rows are held by serving.DecodeServer's slot cache")
         policy = self.policy
         cdt = policy.compute_dtype
         prompt_len = prompt.shape[1]
@@ -876,22 +1055,40 @@ class TransformerLM:
                     else {"g": P()})
 
         blocks = []
-        for _ in range(self.num_layers):
-            blk = {
-                "ln1": norm(),
-                "attn": {"wq": col, "wk": kv_col, "wv": kv_col, "wo": row},
-                "ln2": norm(),
-            }
-            if self.qk_norm:
-                blk["attn"]["q_norm"] = {"g": P()}
-                blk["attn"]["k_norm"] = {"g": P()}
-            if self.num_experts:
-                # every chip holds all experts, each split on its width
-                # like the dense MLP; the router is replicated
+        for mixer, ffn in zip(self.mixers, self.ffns):
+            blk = {"ln1": norm(), "ln2": norm()}
+            if mixer == "attn":
+                blk["attn"] = {"wq": col, "wk": kv_col, "wv": kv_col,
+                               "wo": row}
+                if self.qk_norm:
+                    blk["attn"]["q_norm"] = {"g": P()}
+                    blk["attn"]["k_norm"] = {"g": P()}
+            elif mixer == "kda":
+                # no Megatron split is written for the two other mixers:
+                # every chip holds them whole
+                blk["kda"] = {n: P() for n in (
+                    "wq", "wk", "wv", "wa", "wb", "wg", "wo", "conv_q",
+                    "conv_k", "conv_v", "a_log", "dt_bias")}
+                blk["kda"]["o_norm"] = {"g": P()}
+            else:
+                blk["mla"] = {n: P() for n in ("wq", "wdkv", "wukv", "wo",
+                                               "wg")}
+                blk["mla"]["kv_norm"] = {"g": P()}
+            if ffn == "moe":
+                # every chip holds all experts (of this share), each
+                # split on its width like the dense MLP; the router is
+                # replicated
                 blk["moe"] = {"router": P(),
                               "w_gate": P(None, None, MODEL_AXIS),
                               "w_up": P(None, None, MODEL_AXIS),
                               "w_down": P(None, MODEL_AXIS, None)}
+                if self.moe and self.moe.get("bias"):
+                    blk["moe"]["bias"] = P()
+                if self.moe and self.moe.get("shared_width"):
+                    blk["moe"]["shared"] = {"w_gate": col, "w_up": col,
+                                            "w_down": row}
+            elif ffn == "glu":
+                blk["glu"] = {"w1": col, "w3": col, "w2": row}
             else:
                 blk["mlp"] = {"w1": col, "b1": P(MODEL_AXIS), "w2": row,
                               "b2": P()}

@@ -162,16 +162,37 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         h = h + params["pos"][:p][None]
     h = policy.cast_compute(h)
     ks, vs = [], []
-    # the pad tail holds no token: it takes no part in routed experts
-    live = (jnp.arange(p) < prompt_len)[None] if model.num_experts else None
+    left = {"latent": [], "kda": [], "conv": []}    # the other layer kinds
+    # the pad tail holds no token: it takes no part in routed experts and
+    # moves no recurrent state (a 'kda' layer's state is as of prompt_len)
+    live = ((jnp.arange(p) < prompt_len)[None]
+            if model.num_experts or model.hybrid else None)
     moe_info: list = []
     for blk in params["blocks"]:
         h, kk, vv = model._block(blk, h, live=live, moe_info=moe_info)
-        ks.append(kk.astype(cdt))
-        vs.append(vv.astype(cdt))
-    kcat = jnp.stack(ks)                     # [L, 1, P, Hkv, Dh]
-    vcat = jnp.stack(vs)
-    if quantized:
+        if "kda" in blk:
+            left["kda"].append(kk)
+            left["conv"].append(vv)
+        elif "mla" in blk:
+            left["latent"].append(kk)
+        else:
+            ks.append(kk.astype(cdt))
+            vs.append(vv.astype(cdt))
+    # a 'kda' layer's [1, H, dk, dk] state and [1, K-1, C] tail and an
+    # 'mla' layer's [1, P, r + dr] rows, each into the slot's place in its
+    # layer's own array: the slot's old state is overwritten whole
+    def into(pool, new):
+        if new.shape[-1] < pool.shape[-1]:      # a latent row's zero lanes
+            new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + (
+                (0, pool.shape[-1] - new.shape[-1]),))
+        return lax.dynamic_update_slice(
+            pool, new.astype(pool.dtype), (slot,) + (0,) * (pool.ndim - 1))
+
+    new_kv = {name: [into(pool, new) for pool, new in zip(kv[name], rows)]
+              for name, rows in left.items() if rows}
+    kcat = jnp.stack(ks) if ks else None     # [L, 1, P, Hkv, Dh]
+    vcat = jnp.stack(vs) if ks else None
+    if ks and quantized:
         real = (jnp.arange(p) < prompt_len)[None, None, :, None, None]
 
         def quant(cat, pool, scale):
@@ -188,15 +209,13 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
 
         pool_k, k_scale = quant(kcat, kv["k"], kv["k_scale"])
         pool_v, v_scale = quant(vcat, kv["v"], kv["v_scale"])
-        new_kv = {"k": pool_k, "v": pool_v,
-                  "k_scale": k_scale, "v_scale": v_scale}
-    else:
-        new_kv = {
-            "k": lax.dynamic_update_slice(
+        new_kv.update(k=pool_k, v=pool_v, k_scale=k_scale, v_scale=v_scale)
+    elif ks:
+        new_kv.update(
+            k=lax.dynamic_update_slice(
                 kv["k"], kcat.astype(kv["k"].dtype), (0, slot, 0, 0, 0)),
-            "v": lax.dynamic_update_slice(
-                kv["v"], vcat.astype(kv["v"].dtype), (0, slot, 0, 0, 0)),
-        }
+            v=lax.dynamic_update_slice(
+                kv["v"], vcat.astype(kv["v"].dtype), (0, slot, 0, 0, 0)))
     h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
     tok, key = sample_row(model._unembed(params, h_last), key)
     if model.num_experts:
@@ -264,6 +283,8 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     window = model.attn_window
     dtype = model.policy.compute_dtype
     rows = jnp.arange(positions.shape[0])
+    if "k" not in pool:         # no layer of this model keeps keys
+        return None
     if pool_kernel is None:
         pool_kernel = not flash_default_interpret()
     block = None
@@ -298,6 +319,36 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     return layer
 
 
+def _latent_attention(model, pool, positions):
+    """``(j, p) -> attention(q_nope, q_rope, latent)`` for a decode-family
+    forward of an ``mla`` layer (the model's j-th, parameters ``p``) over
+    its cached latent rows: the new rows land at ``positions [S, Q]`` of
+    ``pool["latent"][j]`` (``[S, T_max, latent_row_width]``, rebound in ``pool`` as
+    ``_pool_attention`` rebinds the K/V pools), then query ``(s, i)``
+    attends slot ``s``'s rows ``<= positions[s, i]`` in the absorbed form
+    (``models/mla.attend_latent``)."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models import mla
+
+    rows = jnp.arange(positions.shape[0])
+
+    def layer(j, p):
+        def attn(q_nope, q_rope, latent):
+            cache = pool["latent"][j]
+            latent = jnp.pad(latent, ((0, 0), (0, 0), (
+                0, cache.shape[-1] - latent.shape[-1])))
+            cache = cache.at[rows[:, None], positions].set(
+                latent.astype(cache.dtype))
+            pool["latent"][j] = cache
+            mask = jnp.arange(cache.shape[1]) <= positions[:, :, None]
+            return mla.attend_latent(q_nope, q_rope, cache, mask, p,
+                                     dims=model.mla,
+                                     cast=model.policy.cast_compute)
+        return attn
+
+    return layer
+
+
 def _decode_step_body(model, params, kv, tok, positions, *,
                       pool_kernel=None, live=None, moe_info=None):
     """ONE decode forward for all S slots: consume ``tok[s]`` at
@@ -313,21 +364,41 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     [S]`` (bool) names the slots that hold a request: the pool kernel
     reads none of the others' keys, and they choose no routed expert and
     count in no load. ``moe_info`` receives each layer's routing
-    (``TransformerLM._block``)."""
+    (``TransformerLM._block``).
+
+    Each layer meets its own kind of state, at its place among the layers
+    of its kind: an ``attn`` layer the K/V pools, an ``mla`` layer its
+    latent rows (``_latent_attention``), a ``kda`` layer its recurrent
+    matrix and convolution tail, which it takes and hands back advanced
+    for the live slots and untouched for the others."""
     import jax.numpy as jnp
 
     h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
     if model.pos_encoding == "learned":
         h = h + params["pos"][positions]
     h = model.policy.cast_compute(h)[:, None, :]           # [S, 1, D]
-    new_kv = dict(kv)
+    new_kv = {k: list(v) if isinstance(v, list) else v
+              for k, v in kv.items()}
     cached_attention = _pool_attention(
         model, new_kv, positions[:, None], pool_kernel, live)
-    for li, blk in enumerate(params["blocks"]):
-        h, _, _ = model._block(
-            blk, h, attention=cached_attention(li),
-            positions=positions[:, None], moe_info=moe_info,
-            live=None if live is None else live[:, None])
+    latent_attention = _latent_attention(model, new_kv, positions[:, None])
+    seen = {"attn": 0, "mla": 0, "kda": 0}
+    for blk in params["blocks"]:
+        kind = next(k for k in seen if k in blk)
+        j = seen[kind]
+        seen[kind] += 1
+        kw = {}
+        if kind == "kda":
+            kw["state"] = (new_kv["kda"][j], new_kv["conv"][j])
+        elif kind == "mla":
+            kw["attention"] = latent_attention(j, blk["mla"])
+        else:
+            kw["attention"] = cached_attention(j)
+        h, a, b = model._block(
+            blk, h, positions=positions[:, None], moe_info=moe_info,
+            live=None if live is None else live[:, None], **kw)
+        if kind == "kda":
+            new_kv["kda"][j], new_kv["conv"][j] = a, b
     logits = model._unembed(params, h[:, 0])               # [S, V]
     return logits, new_kv
 
@@ -613,6 +684,12 @@ class DecodeEngine:
         self.program_builds = 0
 
         # ---- speculative-decoding configuration
+        if model.hybrid and (draft_model is not None or draft_layers):
+            raise ValueError(
+                "speculative decoding is not written for a model with "
+                "'kda' or 'mla' layers: a rejected draft token would have "
+                "to be taken back out of the recurrent state, which keeps "
+                "no history to rewind to")
         if draft_model is not None and draft_layers:
             raise ValueError(
                 "pass draft_model= OR draft_layers=, not both")
@@ -671,6 +748,7 @@ class DecodeEngine:
 
         cfg = dict(model.get_config())
         cfg["num_layers"] = n
+        cfg["mixers"], cfg["ffns"] = cfg["mixers"][:n], cfg["ffns"][:n]
         draft = TransformerLM(**cfg)
         draft.params = {k: v for k, v in model.params.items()
                         if k != "blocks"}

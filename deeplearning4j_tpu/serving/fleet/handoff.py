@@ -90,6 +90,16 @@ class SlotHandoff:
         return int(sum(s.nbytes for s in self.slabs.values()))
 
 
+def _kv_only(engine) -> None:
+    """A slab is K/V rows (and their scales): the hand-off does not carry
+    a 'kda' layer's recurrent state or an 'mla' layer's latent rows."""
+    if engine.model.hybrid:
+        raise ValueError(
+            "the fleet hand-off carries K/V slabs only: a model with 'kda' "
+            "or 'mla' layers keeps recurrent state and latent rows that "
+            "export_slot / install_slot do not move")
+
+
 def export_slot(engine, slot: int) -> Dict[str, np.ndarray]:
     """Pull one slot's pool state to host numpy (the handoff's wire
     format). The readback is sanctioned here — once per request at the
@@ -97,6 +107,7 @@ def export_slot(engine, slot: int) -> Dict[str, np.ndarray]:
     import jax
     import jax.numpy as jnp
 
+    _kv_only(engine)
     run = engine._program(
         ("handoff_export", engine.slots),
         lambda: jax.jit(_slot_export_impl))
@@ -114,6 +125,7 @@ def install_slot(engine, slot: int, handoff: SlotHandoff):
     import jax
     import jax.numpy as jnp
 
+    _kv_only(engine)
     if handoff.kv_dtype != engine.kv_dtype:
         raise ValueError(
             f"handoff kv_dtype={handoff.kv_dtype!r} != target pool "

@@ -39,6 +39,25 @@ the host writes them only where a request enters or leaves a slot
 (``slot_admit``: after a prefill or a hand-off, and with nothing owed on
 a deadline or a cancel).
 
+State by layer kind (``TransformerLM(mixers=...)``): what a position or a
+request leaves behind depends on the layer's mixer, and one slot holds all
+of it side by side:
+
+- ``attn`` layers: the K/V pools above, ``[L_attn, S, T_max, Hkv, Dh]``;
+- ``mla`` layers: latent rows, one ``[S, T_max, r + dr]`` array a layer
+  (``latent``; each row padded with zeros to whole 128-lane tiles,
+  ``latent_row_width``), written at the cursor and masked like keys;
+- ``kda`` layers: a recurrent matrix ``[S, H, dk, dk]`` in float32
+  (``kda``) and a convolution tail ``[S, K - 1, 3 H dk]`` (``conv``) a
+  layer. They have no time axis: a prefill writes them **as of the
+  prompt's length** (pad rows move no state, ``models/kda.py``), a decode
+  step replaces a live slot's and leaves a frozen slot's as they are, and
+  the next prefill into the slot overwrites them whole.
+
+The new kinds are lists of per-layer arrays (no layer axis to slice a slab
+out of). ``pool_layout`` is the one description of all of it: the arrays
+are built from it and ``kv_pool_nbytes`` sums it.
+
 Quantized pool (``DL4J_SERVE_KV_DTYPE`` / ``kv_dtype=``): the pool is
 the dominant HBM term at high slot counts, so the store dtype is a
 capacity lever — ``float32``, ``bfloat16``, or ``int8``. int8 keeps
@@ -52,6 +71,7 @@ exceeds the slot-head's scale requantizes that row in-program
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional
 
@@ -61,6 +81,7 @@ __all__ = [
     "SlotKVCache",
     "resolve_kv_dtype",
     "kv_pool_nbytes",
+    "pool_layout",
     "max_slots_in_budget",
     "dequant_slab",
     "requant_write_slab",
@@ -98,21 +119,59 @@ def _elem_bytes(name: str) -> int:
 
 def _pool_dims(model, slots: int, max_len: int):
     dh = model.d_model // model.num_heads
-    return (model.num_layers, slots, max_len, model.num_kv_heads, dh)
+    return (len(model.layers_of("attn")), slots, max_len,
+            model.num_kv_heads, dh)
+
+
+def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
+    """``{kind: [(shape, dtype name), ...]}`` of every array a slot pool
+    of this model holds, by what it is: ``kv`` (the K and the V pool, and
+    their int8 scales), ``latent`` (one array an ``mla`` layer),
+    ``recurrent`` and ``conv`` (one each a ``kda`` layer). A kind the
+    model has no layer of is an empty list."""
+    out = {"kv": [], "latent": [], "recurrent": [], "conv": []}
+    dims = _pool_dims(model, slots, max_len)
+    if dims[0]:
+        out["kv"] += [(dims, kv_dtype)] * 2
+        if kv_dtype == "int8":
+            out["kv"] += [(dims[:2] + (dims[3],), "float32")] * 2
+    if model.mla:
+        out["latent"] = [((slots, max_len, latent_row_width(model)),
+                          kv_dtype)] * len(model.layers_of("mla"))
+    if model.kda:
+        n, dk = len(model.layers_of("kda")), model.kda["head_dim"]
+        out["recurrent"] = [((slots, model.num_heads, dk, dk),
+                             "float32")] * n
+        out["conv"] = [((slots, model.kda["conv"] - 1,
+                         3 * model.num_heads * dk), kv_dtype)] * n
+    return out
+
+
+def latent_row_width(model) -> int:
+    """Lanes a cached latent row takes: its ``r + dr`` numbers, then zeros
+    up to the next multiple of 128. With rows of 576 XLA:TPU copies the
+    whole array twice a decode step (once before the scatter, once into
+    the layout its dot wants: 4.8 ms of a 26 ms step at 64 slots x 10,240;
+    PERF.md section 6, PR 29); with rows of 640 it does neither."""
+    width = model.mla["kv_lora_rank"] + model.mla["qk_rope_head_dim"]
+    return -(-width // 128) * 128
+
+
+def _layout_nbytes(arrays) -> int:
+    return sum(math.prod(shape) * _elem_bytes(dtype)
+               for shape, dtype in arrays)
 
 
 def kv_pool_nbytes(model, slots: int, max_len: Optional[int] = None,
                    kv_dtype: Optional[str] = None) -> int:
-    """Analytic device footprint of the K/V pool pair (+ int8 scale
-    sidecars) — the serving term of the HBM budget model. Matches
-    ``SlotKVCache.nbytes`` exactly (asserted in tests)."""
+    """Analytic device footprint of a slot pool: the K/V pool pair
+    (+ int8 scale sidecars), the latent rows and the recurrent state with
+    its convolution tails, whichever the model's layers keep — the
+    serving term of the HBM budget model. Matches ``SlotKVCache.nbytes``
+    exactly (asserted in tests)."""
     name = resolve_kv_dtype(kv_dtype, model)
-    ll, ss, tt, hkv, dh = _pool_dims(model, slots,
-                                     int(max_len or model.max_len))
-    total = 2 * ll * ss * tt * hkv * dh * _elem_bytes(name)
-    if name == "int8":
-        total += 2 * ll * ss * hkv * 4  # f32 per-(layer, slot, head) scales
-    return total
+    layout = pool_layout(model, slots, int(max_len or model.max_len), name)
+    return sum(_layout_nbytes(arrays) for arrays in layout.values())
 
 
 def max_slots_in_budget(model, max_len: int, budget_bytes: int,
@@ -238,8 +297,10 @@ def slot_admit(loop, at, tok, key):
 
 
 class SlotKVCache:
-    """``[L, S, T_max, Hkv, Dh]`` K/V pools + the decode loop's device
-    per-slot state (cursors, last tokens, tokens owed, RNG keys)."""
+    """``[L, S, T_max, Hkv, Dh]`` K/V pools, the other layer kinds' state
+    (latent rows, recurrent matrices, convolution tails: ``pool_layout``)
+    + the decode loop's device per-slot state (cursors, last tokens,
+    tokens owed, RNG keys)."""
 
     # validate_cache_budget (monitor/memory.py) prices any cache as
     # nbytes/n_shard vs measured per-device bytes; the slot pool is
@@ -267,8 +328,21 @@ class SlotKVCache:
                 f"position table ({model.max_len}); use "
                 "pos_encoding='rope' to serve past it")
         self.kv_dtype = resolve_kv_dtype(kv_dtype, model)
+        if model.hybrid and (self.kv_dtype == "int8"
+                             or registry is not None):
+            raise ValueError(
+                "a model with 'kda' or 'mla' layers is served from an "
+                "unquantized pool on one chip: the int8 codec and the "
+                "mesh's head split are written for K/V pools only, not for "
+                "latent rows or recurrent state")
+        layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype)
+        self.latent, self.kda, self.conv = (
+            [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
+            for kind in ("latent", "recurrent", "conv"))
         shape = _pool_dims(model, self.slots, self.max_len)
-        if self.kv_dtype == "int8":
+        if not shape[0]:        # no layer keeps keys and values
+            self.k = self.v = self.k_scale = self.v_scale = None
+        elif self.kv_dtype == "int8":
             self.k = jnp.zeros(shape, jnp.int8)
             self.v = jnp.zeros(shape, jnp.int8)
             self.k_scale = jnp.zeros(shape[:2] + (shape[3],), jnp.float32)
@@ -327,30 +401,43 @@ class SlotKVCache:
         ``{k, v}`` plus the int8 scale sidecars when quantized. The
         engine's programs write into these buffers and hand them back
         (``install``); once donated, the arrays returned here are dead."""
-        st = {"k": self.k, "v": self.v}
+        st = {} if self.k is None else {"k": self.k, "v": self.v}
         if self.k_scale is not None:
             st["k_scale"] = self.k_scale
             st["v_scale"] = self.v_scale
+        for name in ("latent", "kda", "conv"):
+            if getattr(self, name):
+                st[name] = list(getattr(self, name))
         return st
 
     def install(self, state: dict) -> None:
         """Install the pool state a jitted program returned: the
         buffers ``state`` donated to it, updated in place — the same
         device memory, not a copy of it."""
-        self.k = state["k"]
-        self.v = state["v"]
+        self.k = state.get("k")
+        self.v = state.get("v")
         self.k_scale = state.get("k_scale")
         self.v_scale = state.get("v_scale")
+        for name in ("latent", "kda", "conv"):
+            setattr(self, name, list(state.get(name, ())))
 
     @property
     def nbytes(self) -> int:
         """Device footprint of the pool state (capacity planning: the
         serving analogue of the epoch cache's HBM budget). Includes the
         int8 scale sidecars."""
-        total = int(self.k.nbytes) + int(self.v.nbytes)
-        if self.k_scale is not None:
-            total += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
-        return total
+        return sum(self.nbytes_by_kind.values())
+
+    @property
+    def nbytes_by_kind(self) -> dict:
+        """``nbytes`` apart: ``kv`` (K/V pools and their scales),
+        ``latent``, ``recurrent``, ``conv`` (``pool_layout``'s kinds)."""
+        kv = [a for a in (self.k, self.v, self.k_scale, self.v_scale)
+              if a is not None]
+        return {kind: sum(int(a.nbytes) for a in arrays)
+                for kind, arrays in (("kv", kv), ("latent", self.latent),
+                                     ("recurrent", self.kda),
+                                     ("conv", self.conv))}
 
     @property
     def per_slot_nbytes(self) -> int:

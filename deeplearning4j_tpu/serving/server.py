@@ -164,6 +164,7 @@ class DecodeServer:
         self._cursors = np.zeros(self.slots, np.int64)
         pool = self.engine.cache
         self._kv_block = (None if pool.quantized or mesh is not None
+                          or pool.k is None
                           else pool_block_rows(pool.k.shape, pool.k.dtype))
         self.kv_blocks = 0
         self.kv_blocks_pool = 0
@@ -190,12 +191,17 @@ class DecodeServer:
         self.slot_dispatches = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
-        # routed experts: (token, expert) pairs every expert of every
-        # layer has received from live rows, prefills and plain decode
-        # steps alike ([L, E]; an empty array for a dense model)
+        # routed experts: (token, expert) pairs every expert held here, of
+        # every layer that has experts, has received from live rows,
+        # prefills and plain decode steps alike ([Lmoe, held]; an empty
+        # array for a dense model); the live rows those layers saw; and of
+        # the decode blocks read, how many there were and the (layer,
+        # expert) cells they reached
         self.moe_expert_load = np.zeros(
-            (model.num_layers if model.num_experts else 0,
-             model.num_experts), np.int64)
+            (len(model.layers_of("moe")), model.experts_held), np.int64)
+        self.moe_rows = 0
+        self.moe_decode_blocks = 0
+        self.moe_decode_touched = 0
         self._decode_kind = ("spec" if self.engine.spec else
                              "fused" if self.fuse_steps > 1 else "plain")
         # record_routing: every request keeps the experts that served
@@ -460,7 +466,7 @@ class DecodeServer:
                 self.engine.admit_slot(slot, tok, prompt_len,
                                        req.max_new_tokens - 1, key)
                 self._cursors[slot] = prompt_len
-                tok, rows = self._read_block(tok, routing)
+                tok, rows = self._read_block(tok, routing, prompt_len)
                 now = self.clock()
                 req.state = "running"
                 req.slot = slot
@@ -548,7 +554,7 @@ class DecodeServer:
                 attrs["kv_blocks_pool"])
         return attrs
 
-    def _read_block(self, toks, routing):
+    def _read_block(self, toks, routing, live_rows: int, decode=False):
         """One program's tokens on the host — the loop's one sanctioned
         readback — with the routing that came with them: ``(tokens,
         rows)``. A model with routed experts hands its ``[layers,
@@ -560,15 +566,21 @@ class DecodeServer:
         span open now, ``serve.decode`` or ``serve.prefill`` (the (layer,
         expert) cells that received a token). The rows' experts and
         weights come in the same array (``engine._stack_routing``):
-        ``rows`` = ``(experts, weights)``, None for a dense model."""
+        ``rows`` = ``(experts, weights)``, None for a dense model.
+        ``live_rows`` is how many rows of the program held a token (a
+        prompt's length, a decode block's live slots): ``moe_rows``."""
         if routing is None:
             return np.asarray(toks), None
         import jax
 
         toks, packed = jax.device_get((toks, routing))
-        load, *rows = unpack_routing(packed, self.model.num_experts,
+        load, *rows = unpack_routing(packed, self.model.experts_held,
                                      self.model.experts_per_token)
         self.moe_expert_load += load
+        self.moe_rows += live_rows
+        if decode:
+            self.moe_decode_blocks += 1
+            self.moe_decode_touched += int(np.count_nonzero(load))
         pairs = int(load.sum())
         if pairs:
             self._reg.counter("serve_moe_routed_pairs_total").inc(pairs)
@@ -641,7 +653,8 @@ class DecodeServer:
                     unread, self._unread = self._unread, None
             if unread is None:      # the first dispatch after idling
                 return
-            toks, rows = self._read_block(*unread[:2])
+            toks, rows = self._read_block(*unread[:2], len(unread[2]),
+                                          decode=True)
         counts = None
         if self.engine.spec:                       # [K, S, G+2]
             toks, counts = toks[:, :, 1:], toks[:, :, 0]
@@ -759,6 +772,13 @@ class DecodeServer:
             "fuse_steps": self.fuse_steps,
             "kv_dtype": self.engine.kv_dtype,
             "kv_pool_bytes": pool_bytes,
+            # the target pool's bytes by what they are: K/V rows, latent
+            # rows, recurrent matrices, convolution tails
+            "state_bytes": self.engine.cache.nbytes_by_kind,
+            # slots a decode dispatch served, mean
+            "live_slots_per_step": (
+                round(self.slot_dispatches / self.steps, 4)
+                if self.steps else None),
             # what one concurrent request costs in pool HBM — includes
             # the draft pool's share when speculative (kv_per_slot_bytes
             # * slots == kv_pool_bytes holds in every configuration)
@@ -802,6 +822,20 @@ class DecodeServer:
         }
         if self.model.num_experts:
             out["moe_expert_load"] = self.moe_expert_load.tolist()
+            out["moe_rows"] = self.moe_rows
+            # moe_rows: the live rows the expert layers saw (prompt tokens
+            # and decode slots); (layer, held expert) cells a decode block
+            # reached, mean; and the (token, expert) pairs that landed on an
+            # expert held
+            # here, per live row and layer (k when every expert is here;
+            # k x held / num_experts in expectation for a share)
+            out["moe_experts_touched_per_step"] = (
+                round(self.moe_decode_touched / self.moe_decode_blocks, 4)
+                if self.moe_decode_blocks else None)
+            layer_rows = self.moe_rows * len(self.moe_expert_load)
+            out["moe_pairs_here_per_token"] = (
+                round(float(self.moe_expert_load.sum()) / layer_rows, 4)
+                if layer_rows else None)
         if self.engine.spec:
             out["spec_tokens"] = self.engine.spec_tokens
             out["spec_proposed"] = self.spec_proposed

@@ -1,0 +1,220 @@
+"""Learned sparse attention over latent rows (DeepSeek sparse attention, the
+lightning indexer of DeepSeek-V3.2-Exp, as GLM-5.2's ``glm_moe_dsa`` layers
+use it): a small indexer scores every cached position for a query, the
+``topk`` best are selected, and the latent-attention mixer (``models/mla.py``)
+attends those rows and no others.
+
+    q^I_t   = c_q W^I_q                     (hI x dI)   c_q: the compressed query
+    k^I_s   = layernorm(x_s W^I_k)          (dI)        one key for all hI heads
+    q^I, k^I: RoPE on the first ``rope_dim`` of the dI dimensions
+    w_t     = x_t W^I_w * hI^-1/2 * dI^-1/2 (hI)
+    I(t, s) = sum_j w_{t,j} relu(q^I_{t,j} . k^I_s)
+    S_t     = the topk positions s <= t of largest I(t, s); all while t < topk
+
+A layer's indexer is ``"full"`` (it has the parameters above, computes
+``S_t`` and hands it on) or ``"shared"`` (no indexer parameters: it attends
+the ``S_t`` of the nearest ``"full"`` layer before it). What a position
+leaves behind in a ``"full"`` layer, beside its latent row, is its index key
+``k^I_s`` (``serving/kv_cache.py``'s ``index`` rows).
+
+The selection is **exact** (ties to the lower position): an approximate
+top-k below recall 1 would be another model. It has two forms, chosen from
+the shapes at trace time, and the attention over ``S_t`` (the absorbed form of
+``mla.attend_latent``) follows the form it is handed:
+
+- **positions** ``(idx [b, 1, k], valid)``, from ``lax.top_k`` (on a TPU a
+  sort of the whole row: 2.9 ms for 16 x 32,768): the query gathers its k
+  rows and attends them, k rows whatever the context holds. One query a row:
+  a decode step.
+- **a mask** ``[b, q, T]``, from the k-th largest score found by bisection on
+  the scores' bits (32 passes of compare-and-count, no sort): the queries
+  attend all T keys under it as dense products. Several queries a row: a
+  prefill block, where 18 MFLOP a key and 128 queries on the MXU are cheaper
+  than a sort a query and a gather of 128 x 2,048 rows of 1,280 B (XLA:TPU
+  moves 11 ns a row; PERF.md section 6, PR 31).
+
+Queries are taken ``ATTEND_BLOCK`` at a time, so that a prefill block's scores
+``[q, hI, T]`` and logits ``[H, q, T]`` stay under a gigabyte and a half.
+Scopes ``dsa.index`` (projections, scores, selection) and ``mla.attend``
+(gather and attention).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from deeplearning4j_tpu.models import mla
+
+__all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
+           "index_scores", "select", "kth_largest_mask", "selected_positions",
+           "attend_selected"]
+
+# queries whose scores and attention logits are alive at once: at GLM-5.2's
+# sizes against 28,672 keys, [128, 32, T] float32 index scores are 470 MB
+# and [64, 128, T] float32 logits 940 MB (1.45 GiB of temporaries a block
+# program, compile-only, PERF.md section 4)
+ATTEND_BLOCK = 128
+
+
+def init_indexer(key, d_model: int, q_rank: int, dims: Dict[str, int],
+                 dtype) -> Dict[str, Any]:
+    """Glorot-normal ``wq`` [rq, hI dI], ``wk`` [D, dI], ``ww`` [D, hI];
+    ``k_norm.{g, b}`` [dI] (a LayerNorm)."""
+    ks = jax.random.split(key, 3)
+    h, d = dims["n_heads"], dims["head_dim"]
+
+    def glorot(k, fan_in, fan_out):
+        scale = jnp.sqrt(2.0 / (fan_in + fan_out)).astype(dtype)
+        return jax.random.normal(k, (fan_in, fan_out), dtype) * scale
+
+    return {"wq": glorot(ks[0], q_rank, h * d),
+            "wk": glorot(ks[1], d_model, d),
+            "k_norm": {"g": jnp.ones((d,), dtype),
+                       "b": jnp.zeros((d,), dtype)},
+            "ww": glorot(ks[2], d_model, h)}
+
+
+def index_project(x, c_q, p, *, dims: Dict[str, int], rope, layernorm,
+                  cast: Callable = lambda w: w):
+    """``x`` [b, t, D], ``c_q`` [b, t, rq] -> ``(q^I [b, t, hI, dI], k^I
+    [b, t, dI], w [b, t, hI] float32)``, queries and key after RoPE on
+    their first ``rope_dim`` dimensions. ``rope(a [b, t, h, rope_dim])`` and
+    ``layernorm(a, g, b)`` are the model's."""
+    b, t, _ = x.shape
+    h, d, rd = dims["n_heads"], dims["head_dim"], dims["rope_dim"]
+    with jax.named_scope("dsa.index"):
+        q = (c_q @ cast(p["wq"])).reshape(b, t, h, d)
+        q = jnp.concatenate([rope(q[..., :rd]), q[..., rd:]], axis=-1)
+        k = layernorm(x @ cast(p["wk"]), p["k_norm"]["g"], p["k_norm"]["b"])
+        k = jnp.concatenate(
+            [rope(k[:, :, None, :rd])[:, :, 0], k[..., rd:]], axis=-1)
+        w = (x @ cast(p["ww"])).astype(jnp.float32) * (h ** -0.5 * d ** -0.5)
+    return q, k, w
+
+
+def _by_query_blocks(fn, *args):
+    """``fn`` on ``args`` ([b, q, ...] each) ``ATTEND_BLOCK`` queries at a
+    time, where q is a larger multiple of it; the results joined on q."""
+    b, q = args[0].shape[:2]
+    if q <= ATTEND_BLOCK or q % ATTEND_BLOCK:
+        return fn(*args)
+    n = q // ATTEND_BLOCK
+
+    def split(a):       # [b, q, ...] -> [n, b, block, ...]
+        return jnp.moveaxis(
+            a.reshape((b, n, ATTEND_BLOCK) + a.shape[2:]), 1, 0)
+
+    def join(a):
+        a = jnp.moveaxis(a, 0, 1)
+        return a.reshape((b, q) + a.shape[3:])
+
+    out = lax.map(lambda xs: fn(*xs), tuple(split(a) for a in args))
+    return jax.tree_util.tree_map(join, out)
+
+
+def index_scores(iq, iw, keys):
+    """``I(t, s)`` [b, q, T] float32 of queries ``iq`` [b, q, hI, dI] with
+    head weights ``iw`` [b, q, hI] against index keys ``keys`` [b, T, dI]."""
+    s = jnp.einsum("bqhd,btd->bqht", iq.astype(keys.dtype), keys,
+                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqht,bqh->bqt", jax.nn.relu(s), iw)
+    # one zero: a shut relu times a negative weight is -0.0, which a sort's
+    # total order and the bits put below +0.0
+    return jnp.where(s == 0, 0.0, s)
+
+
+def kth_largest_mask(scores, k: int):
+    """``[..., T]`` bool: the k largest of each row of ``scores`` (float32;
+    -inf marks what may not be chosen and is never set), equal scores to the
+    lower index; a row with fewer than k finite scores has them all. No
+    sort: the k-th largest value is found one bit at a time, 32 counts of
+    the row against a candidate, on the scores' bits in an order-preserving
+    unsigned form."""
+    bits = lax.bitcast_convert_type(scores, jnp.int32)
+    keys = jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31))
+    keys = lax.bitcast_convert_type(keys, jnp.uint32)   # larger = larger score
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(keys >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = keys > kth[..., None]
+    ties = (keys == kth[..., None])
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    ties &= jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room
+    return (above | ties) & (scores > -jnp.inf)
+
+
+def select(iq, iw, keys, q_pos, topk: int):
+    """The selection ``S_t`` of each query, the positions ``s <=
+    q_pos[b, q]`` of ``keys`` [b, T, dI] with the k = min(topk, T) largest
+    index scores, in one of two forms (the module's docstring):
+
+    - ``(idx [b, q, k] int32, valid [b, q, k] bool)``, best first; a query
+      with fewer than k positions behind it selects them all, and ``valid``
+      is false on the rest of its row (whose ``idx`` are then positions it
+      may not see). One query a row.
+    - ``mask [b, q, T]`` bool. Several queries a row."""
+    t = keys.shape[1]
+    k = min(int(topk), t)
+    as_mask = iq.shape[1] > 1
+
+    def block(iq, iw, q_pos):
+        scores = index_scores(iq, iw, keys)
+        scores = jnp.where(jnp.arange(t) <= q_pos[..., None], scores,
+                           -jnp.inf)
+        if as_mask:
+            return kth_largest_mask(scores, k)
+        best, idx = lax.top_k(scores, k)
+        return idx.astype(jnp.int32), best > -jnp.inf
+
+    with jax.named_scope("dsa.index"):
+        return _by_query_blocks(block, iq, iw, q_pos)
+
+
+def selected_positions(selection, k: int):
+    """A selection of either form as positions ``[b, q, k]`` int32, -1 where
+    a query selected fewer than k (order: best first from the positions
+    form, any from a mask). For a record of it: a mask's costs a sort a
+    query."""
+    if isinstance(selection, tuple):
+        idx, valid = selection
+        return jnp.where(valid, idx, -1)
+    found, idx = lax.top_k(selection.astype(jnp.int32), k)
+    return jnp.where(found > 0, idx.astype(jnp.int32), -1)
+
+
+def attend_selected(q_nope, q_rope, rows, selection, p, *, dims,
+                    cast: Callable = lambda w: w):
+    """Latent attention of each query over its own selected rows:
+    ``q_nope`` [b, q, H, dn], ``q_rope`` [b, q, H, dr], ``rows`` [b, T, >=
+    r + dr] (cached latent rows), ``selection`` = ``select``'s, in either
+    form. Returns ``o`` [b, q, H, dv]. From positions: ``mla.attend_latent``
+    with every query a batch row of its own, its keys the k rows gathered
+    for it. From a mask: ``mla.attend_latent`` over all T rows under it."""
+    b = rows.shape[0]
+
+    def gathered(q_nope, q_rope, idx, valid):
+        n, k = idx.shape[1], idx.shape[2]
+        with jax.named_scope("mla.attend"):
+            picked = rows[jnp.arange(b)[:, None, None], idx]  # [b, n, k, W]
+        o = mla.attend_latent(
+            q_nope.reshape((b * n, 1) + q_nope.shape[2:]),
+            q_rope.reshape((b * n, 1) + q_rope.shape[2:]),
+            picked.reshape(b * n, k, -1), valid.reshape(b * n, 1, k), p,
+            dims=dims, cast=cast)
+        return o.reshape((b, n) + o.shape[2:])
+
+    def masked(q_nope, q_rope, mask):
+        return mla.attend_latent(q_nope, q_rope, rows, mask, p, dims=dims,
+                                 cast=cast)
+
+    if isinstance(selection, tuple):
+        return _by_query_blocks(gathered, q_nope, q_rope, *selection)
+    return _by_query_blocks(masked, q_nope, q_rope, selection)

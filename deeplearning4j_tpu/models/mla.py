@@ -1,14 +1,21 @@
 """Multi-head latent attention (MLA, DeepSeek-V2, arXiv:2405.04434 section
-2.1) without query compression: the full-attention mixer of a hybrid block,
-as ``TransformerLM._block`` runs it.
+2.1): the full-attention mixer of a hybrid block, as ``TransformerLM._block``
+runs it.
 
-    [q_nope (H x dn) ; q_rope (H x dr)] = x Wq
+    [q_nope (H x dn) ; q_rope (H x dr)] = x Wq                 or, with query
+    c_q = rmsnorm(x Wqa) ;  [q_nope ; q_rope] = c_q Wqb        compression
     [c~ (r) ; k_r~ (dr)]               = x Wdkv ;  c = rmsnorm(c~)
     [k_nope (H x dn) ; v (H x dv)]     = c Wukv
     q_rope, k_r = rope(.)              k_r is one key shared by all heads
     p_h(t, s)   = softmax_{s<=t}((q_nope_h . k_nope_h + q_rope_h . k_r)
                                  / sqrt(dn + dr))
     y           = concat_h((sum_s p_h v_h) * sigmoid(x Wg)_h) Wo
+
+The model's description says which (``TransformerLM(mla=)``): a
+``q_lora_rank`` gives the compressed query (``wq_a``, ``q_norm``, ``wq_b`` in
+place of ``wq``; Ling has none, GLM-5.2 2,048), ``gate: False`` leaves the
+head-wise output gate ``wg`` out (Ling has one, GLM-5.2 none). The functions
+read it off the parameters they are given. ``dv`` need not equal ``dn``.
 
 What a position leaves behind is its latent row ``[c ; k_r]`` after the
 norm and after RoPE: ``r + dr`` numbers whatever the number of heads.
@@ -28,15 +35,18 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 
-__all__ = ["init_mla", "mla_project", "attend_full", "attend_latent",
-           "mla_output"]
+__all__ = ["init_mla", "compress_query", "mla_project", "attend_full",
+           "attend_latent", "mla_output"]
 
 
 def init_mla(key, d_model: int, num_heads: int, dims: Dict[str, int],
              dtype) -> Dict[str, Any]:
     """Glorot-normal ``wq`` [D, H (dn + dr)], ``wdkv`` [D, r + dr], ``wukv``
-    [r, H (dn + dv)], ``wo`` [H dv, D], ``wg`` [D, H]; ``kv_norm.g`` [r]."""
-    ks = jax.random.split(key, 5)
+    [r, H (dn + dv)], ``wo`` [H dv, D], ``wg`` [D, H]; ``kv_norm.g`` [r].
+    With ``dims["q_lora_rank"]`` = rq: ``wq_a`` [D, rq], ``q_norm.g`` [rq],
+    ``wq_b`` [rq, H (dn + dr)] instead of ``wq``; with ``dims["gate"]``
+    false no ``wg``."""
+    ks = jax.random.split(key, 6)
     r, dn, dr, dv = (dims[n] for n in ("kv_lora_rank", "qk_nope_head_dim",
                                        "qk_rope_head_dim", "v_head_dim"))
 
@@ -45,31 +55,52 @@ def init_mla(key, d_model: int, num_heads: int, dims: Dict[str, int],
         return jax.random.normal(k, (fan_in, fan_out), dtype) * scale
 
     h = num_heads
-    return {"wq": glorot(ks[0], d_model, h * (dn + dr)),
-            "wdkv": glorot(ks[1], d_model, r + dr),
-            "kv_norm": {"g": jnp.ones((r,), dtype)},
-            "wukv": glorot(ks[2], r, h * (dn + dv)),
-            "wo": glorot(ks[3], h * dv, d_model),
-            "wg": glorot(ks[4], d_model, h)}
+    p = {"wdkv": glorot(ks[1], d_model, r + dr),
+         "kv_norm": {"g": jnp.ones((r,), dtype)},
+         "wukv": glorot(ks[2], r, h * (dn + dv)),
+         "wo": glorot(ks[3], h * dv, d_model)}
+    rq = dims.get("q_lora_rank")
+    if rq:
+        p.update(wq_a=glorot(ks[0], d_model, rq),
+                 q_norm={"g": jnp.ones((rq,), dtype)},
+                 wq_b=glorot(ks[5], rq, h * (dn + dr)))
+    else:
+        p["wq"] = glorot(ks[0], d_model, h * (dn + dr))
+    if dims.get("gate", True):
+        p["wg"] = glorot(ks[4], d_model, h)
+    return p
+
+
+def compress_query(x, p, *, rmsnorm, cast: Callable = lambda w: w):
+    """``c_q = rmsnorm(x Wqa)`` [b, t, rq], the compressed query that the
+    query heads (and a lightning indexer's, ``models/dsa.py``) are made
+    from; ``None`` for parameters without query compression."""
+    if "wq_a" not in p:
+        return None
+    with jax.named_scope("mla.proj"):
+        return rmsnorm(x @ cast(p["wq_a"]), p["q_norm"]["g"])
 
 
 def mla_project(x, p, *, num_heads: int, dims: Dict[str, int], rope,
-                rmsnorm, cast: Callable = lambda w: w):
+                rmsnorm, cast: Callable = lambda w: w, c_q=None):
     """``x`` [b, t, D] -> ``(q_nope [b, t, H, dn], q_rope [b, t, H, dr],
-    latent [b, t, r + dr], gate [b, t, H])``: queries after RoPE, the
-    position's latent row ``[c ; k_r]`` after norm and RoPE, the head-wise
-    output gate. ``rope(a [b, t, h, dr])`` and ``rmsnorm(a, g)`` are the
-    model's."""
+    latent [b, t, r + dr], gate [b, t, H] or None)``: queries after RoPE
+    (from ``c_q`` = ``compress_query``'s where the parameters compress the
+    query), the position's latent row ``[c ; k_r]`` after norm and RoPE,
+    the head-wise output gate where there is one. ``rope(a [b, t, h, dr])``
+    and ``rmsnorm(a, g)`` are the model's."""
     b, t, _ = x.shape
     r, dn, dr = (dims[n] for n in ("kv_lora_rank", "qk_nope_head_dim",
                                    "qk_rope_head_dim"))
     with jax.named_scope("mla.proj"):
-        q = (x @ cast(p["wq"])).reshape(b, t, num_heads, dn + dr)
+        q = (x @ cast(p["wq"]) if c_q is None else c_q @ cast(p["wq_b"]))
+        q = q.reshape(b, t, num_heads, dn + dr)
         q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
         down = x @ cast(p["wdkv"])
         c = rmsnorm(down[..., :r], p["kv_norm"]["g"])
         k_r = rope(down[..., r:][:, :, None, :])[:, :, 0]
-        gate = jax.nn.sigmoid((x @ cast(p["wg"])).astype(jnp.float32))
+        gate = (jax.nn.sigmoid((x @ cast(p["wg"])).astype(jnp.float32))
+                if "wg" in p else None)
     return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1), gate
 
 
@@ -121,8 +152,10 @@ def attend_latent(q_nope, q_rope, rows, mask, p, *, dims,
 
 
 def mla_output(o, gate, p, cast: Callable = lambda w: w):
-    """``o`` [b, t, H, dv] gated a head and projected back to ``D``."""
+    """``o`` [b, t, H, dv] gated a head (``gate`` None: no gate) and
+    projected back to ``D``."""
     b, t = o.shape[:2]
     with jax.named_scope("mla.proj"):
-        o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
+        if gate is not None:
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         return o.reshape(b, t, -1) @ cast(p["wo"])

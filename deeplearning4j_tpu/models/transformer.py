@@ -29,6 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
+from deeplearning4j_tpu.models import dsa as dsa_mod
 from deeplearning4j_tpu.models import kda as kda_mod
 from deeplearning4j_tpu.models import mla as mla_mod
 from deeplearning4j_tpu.models import routed_experts
@@ -121,7 +122,9 @@ class TransformerLM:
                  glu_width: Optional[int] = None,
                  kda: Optional[Dict[str, Any]] = None,
                  mla: Optional[Dict[str, Any]] = None,
-                 moe: Optional[Dict[str, Any]] = None):
+                 moe: Optional[Dict[str, Any]] = None,
+                 indexers: Optional[Sequence[Optional[str]]] = None,
+                 dsa: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -157,7 +160,14 @@ class TransformerLM:
         # routing over ``num_experts``, of which ``held`` starting at
         # ``first`` live here, and a shared expert. ``rope_theta`` /
         # ``rope_interleaved`` / ``norm_eps``: RoPE's base and pairing, the
-        # norms' epsilon.
+        # norms' epsilon. ``mla`` may also give ``q_lora_rank`` (the query
+        # is compressed: ``wq_a``, a norm, ``wq_b``) and ``gate: False`` (no
+        # head-wise output gate). indexers[i], for an "mla" layer of a model
+        # with learned sparse attention (models/dsa.py; ``dsa`` = {n_heads,
+        # head_dim, topk, rope_dim}): "full" (the layer has a lightning
+        # indexer, selects ``topk`` positions a query and attends them) |
+        # "shared" (it attends the selection of the nearest "full" layer
+        # before it) | None (it attends every position).
         kinds = ("attn", "kda", "mla"), ("mlp", "glu", "moe")
         self.mixers = tuple(mixers) if mixers is not None else (
             "attn",) * num_layers
@@ -175,6 +185,25 @@ class TransformerLM:
             if kind in used and not sizes:
                 raise ValueError(f"a {kind!r} layer needs its sizes "
                                  "(kda=, mla=, glu_width=, num_experts=)")
+        self.indexers = tuple(indexers) if indexers is not None else (
+            None,) * num_layers
+        if len(self.indexers) != num_layers or dsa is None and any(
+                self.indexers):
+            raise ValueError(f"indexers={indexers!r} must name None, 'full' "
+                             f"or 'shared' for each of the {num_layers} "
+                             "layers, with the indexer's sizes in dsa=")
+        for i, kind in enumerate(self.indexers):
+            if kind not in (None, "full", "shared") or kind and (
+                    self.mixers[i] != "mla" or not (mla or {}).get(
+                        "q_lora_rank")):
+                raise ValueError(
+                    f"indexers[{i}]={kind!r}: an indexer is 'full' or "
+                    "'shared' and belongs to an 'mla' layer with a "
+                    "compressed query (mla['q_lora_rank'])")
+            if kind == "shared" and "full" not in self.indexers[:i]:
+                raise ValueError(f"indexers[{i}]='shared' has no 'full' "
+                                 "layer before it to take a selection from")
+        self.dsa = dict(dsa) if dsa else None
         self.kda = dict(kda) if kda else None
         self.mla = dict(mla) if mla else None
         self.moe = dict(moe) if moe else None
@@ -292,6 +321,9 @@ class TransformerLM:
             elif self.mixers[i] == "mla":
                 blk["mla"] = mla_mod.init_mla(k[0], D, self.num_heads,
                                               self.mla, dt)
+                if self.indexers[i] == "full":
+                    blk["mla"]["indexer"] = dsa_mod.init_indexer(
+                        k[1], D, self.mla["q_lora_rank"], self.dsa, dt)
             else:
                 blk["attn"] = {
                     "wq": dense(k[0], D, D),
@@ -372,7 +404,8 @@ class TransformerLM:
     def _block(self, blk, h, *, mesh: Optional[Mesh] = None,
                sequence_parallel: bool = False, attention=None,
                positions=None, train: bool = False, live=None,
-               moe_info: Optional[list] = None, state=None):
+               moe_info: Optional[list] = None, state=None,
+               indexer=None, selection=None):
         """One pre-norm block on ``h`` [b, t, D], as the model describes
         that layer (``blk``'s own keys say which mixer and which
         feed-forward it is; norm kind, QK-norm — chosen here,
@@ -400,7 +433,15 @@ class TransformerLM:
         = ``(S, tail)`` (default: a request's start). An ``mla`` layer
         returns ``(h, latent, None)``, each position's latent row
         [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
-        attends a cache of such rows instead of the block's own."""
+        attends a cache of such rows instead of the block's own.
+
+        In a model with learned sparse attention (``dsa``) an ``mla`` layer
+        returns ``(h, latent, selection)``: a layer with an indexer selects
+        (``dsa.select``: against its own index keys, or through
+        ``indexer(q^I, k^I, w) -> selection`` against a cache of them), a
+        layer without one takes ``selection``, the last selection made
+        before it, and hands it on; ``attention`` then takes the selection
+        as its fourth argument."""
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
         x = self._norm(h, blk["ln1"])
@@ -414,8 +455,8 @@ class TransformerLM:
                     lower=self.kda["lower"], cast=policy.cast_compute,
                     live=live, state=state)
             else:
-                y, k = self._mla(blk["mla"], x, attention, positions, train)
-                v = None
+                y, k, v = self._mla(blk["mla"], x, attention, positions,
+                                    train, indexer, selection)
             return self._ffn(blk, h + y, live, moe_info, train), k, v
         q = x @ policy.cast_compute(blk["attn"]["wq"])
         if self.qk_norm:
@@ -491,9 +532,12 @@ class TransformerLM:
         return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
                 + policy.cast_compute(blk["mlp"]["b2"]))
 
-    def _mla(self, p, x, attention, positions, train):
+    def _mla(self, p, x, attention, positions, train, indexer=None,
+             selection=None):
         """The latent-attention mixer on the normed ``x`` [b, t, D]:
-        ``(y [b, t, D], latent [b, t, r + dr])``. Without ``attention``
+        ``(y [b, t, D], latent [b, t, r + dr], selection)``; ``selection``
+        is None unless the model has learned sparse attention, where it is
+        what this layer attended (``_block``). Without ``attention``
         the positions attend each other causally, keys and values expanded
         from the latents: the XLA op, or the flash kernel where
         ``_attn_impl`` says so. The kernel takes one head size, so q and k
@@ -504,11 +548,35 @@ class TransformerLM:
         t = x.shape[1]
         if positions is None:
             positions = jnp.arange(t)
+
+        def rope(a):
+            return _rope(a, positions, self.rope_theta, self.rope_interleaved)
+
+        def rmsnorm(a, g):
+            return _rmsnorm(a, g, self.norm_eps)
+
+        c_q = mla_mod.compress_query(x, p, rmsnorm=rmsnorm, cast=cast)
         q_nope, q_rope, latent, gate = mla_mod.mla_project(
             x, p, num_heads=self.num_heads, dims=self.mla, cast=cast,
-            rope=lambda a: _rope(a, positions, self.rope_theta,
-                                 self.rope_interleaved),
-            rmsnorm=lambda a, g: _rmsnorm(a, g, self.norm_eps))
+            rope=rope, rmsnorm=rmsnorm, c_q=c_q)
+        if "indexer" in p:
+            iq, ik, iw = dsa_mod.index_project(
+                x, c_q, p["indexer"], dims=self.dsa, rope=rope, cast=cast,
+                layernorm=lambda a, g, b: _layernorm(a, g, b, self.norm_eps))
+            if indexer is not None:
+                selection = indexer(iq, ik, iw)
+            else:
+                pos = jnp.broadcast_to(positions, x.shape[:2])
+                selection = dsa_mod.select(iq, iw, ik, pos,
+                                           self.dsa["topk"])
+        if selection is not None:
+            # each query attends its selected rows, absorbed: the block's
+            # own latents unless ``attention`` holds a cache of them
+            o = (attention(q_nope, q_rope, latent, selection)
+                 if attention is not None else dsa_mod.attend_selected(
+                     q_nope, q_rope, latent, selection, p, dims=self.mla,
+                     cast=cast))
+            return mla_mod.mla_output(o, gate, p, cast), latent, selection
 
         def causal(q, k, v, scale):
             if self._attn_impl(t, train=train) != "flash":
@@ -527,7 +595,7 @@ class TransformerLM:
         else:
             o = mla_mod.attend_full(q_nope, q_rope, latent, p, dims=self.mla,
                                     attention=causal, cast=cast)
-        return mla_mod.mla_output(o, gate, p, cast), latent
+        return mla_mod.mla_output(o, gate, p, cast), latent, None
 
     def _norm(self, x, p):
         """The model's norm (``norm=``) with the parameters ``p``."""
@@ -570,8 +638,8 @@ class TransformerLM:
         bodies are traced apart)."""
         if moe_info is not None and (self.remat or self.scan_layers):
             raise ValueError("moe_info needs remat=False, scan_layers=False")
-        if self.scan_layers and (len(set(self.mixers)) > 1
-                                 or len(set(self.ffns)) > 1):
+        if self.scan_layers and max(map(len, map(set, (
+                self.mixers, self.ffns, self.indexers)))) > 1:
             raise ValueError("scan_layers needs every layer the same block")
         policy = self.policy
         b, t = tokens.shape
@@ -580,10 +648,11 @@ class TransformerLM:
             h = h + params["pos"][:t][None]
         h = policy.cast_compute(h)
 
-        def block_fn(blk, h):
+        def block_fn(blk, h, selection=None):
             return self._block(blk, h, mesh=mesh,
                                sequence_parallel=sequence_parallel,
-                               train=train, moe_info=moe_info)[0]
+                               train=train, moe_info=moe_info,
+                               selection=selection)
 
         if self.remat:
             block_fn = jax.checkpoint(block_fn)
@@ -595,11 +664,16 @@ class TransformerLM:
             # because XLA schedules the scan body independently)
             stacked = jax.tree_util.tree_map(
                 lambda *xs: jnp.stack(xs), *params["blocks"])
-            h, _ = lax.scan(lambda c, blk: (block_fn(blk, c), None),
+            h, _ = lax.scan(lambda c, blk: (block_fn(blk, c)[0], None),
                             h, stacked)
         else:
+            # a selection of key positions is the one value that goes from
+            # a layer to the layers after it beside the residual stream
+            selection = None
             for blk in params["blocks"]:
-                h = block_fn(blk, h)
+                h, _, left = block_fn(blk, h, selection)
+                if self.dsa and "mla" in blk:
+                    selection = left
         return policy.cast_output(self._unembed(params, h))
 
     @traced
@@ -746,7 +820,8 @@ class TransformerLM:
             "norm_eps": self.norm_eps,
             "mixers": list(self.mixers), "ffns": list(self.ffns),
             "glu_width": self.glu_width, "kda": self.kda, "mla": self.mla,
-            "moe": self.moe,
+            "moe": self.moe, "indexers": list(self.indexers),
+            "dsa": self.dsa,
         }
 
     def _ensure_init(self):
@@ -794,8 +869,9 @@ class TransformerLM:
         if self.hybrid:
             raise NotImplementedError(
                 "generate() and generate_beam() carry key/value caches only: "
-                "a 'kda' layer's recurrent state and an 'mla' layer's latent "
-                "rows are held by serving.DecodeServer's slot cache")
+                "a 'kda' layer's recurrent state, an 'mla' layer's latent "
+                "rows and an indexer's keys are held by "
+                "serving.DecodeServer's slot cache")
         policy = self.policy
         cdt = policy.compute_dtype
         prompt_len = prompt.shape[1]
@@ -1055,7 +1131,8 @@ class TransformerLM:
                     else {"g": P()})
 
         blocks = []
-        for mixer, ffn in zip(self.mixers, self.ffns):
+        for mixer, ffn, indexer in zip(self.mixers, self.ffns,
+                                       self.indexers):
             blk = {"ln1": norm(), "ln2": norm()}
             if mixer == "attn":
                 blk["attn"] = {"wq": col, "wk": kv_col, "wv": kv_col,
@@ -1071,9 +1148,18 @@ class TransformerLM:
                     "conv_k", "conv_v", "a_log", "dt_bias")}
                 blk["kda"]["o_norm"] = {"g": P()}
             else:
-                blk["mla"] = {n: P() for n in ("wq", "wdkv", "wukv", "wo",
-                                               "wg")}
+                names = ["wdkv", "wukv", "wo"] + (
+                    ["wq_a", "wq_b"] if self.mla.get("q_lora_rank")
+                    else ["wq"]) + (["wg"] if self.mla.get("gate", True)
+                                    else [])
+                blk["mla"] = {n: P() for n in names}
                 blk["mla"]["kv_norm"] = {"g": P()}
+                if self.mla.get("q_lora_rank"):
+                    blk["mla"]["q_norm"] = {"g": P()}
+                if indexer == "full":
+                    blk["mla"]["indexer"] = {
+                        "wq": P(), "wk": P(), "ww": P(),
+                        "k_norm": {"g": P(), "b": P()}}
             if ffn == "moe":
                 # every chip holds all experts (of this share), each
                 # split on its width like the dense MLP; the router is

@@ -64,11 +64,16 @@ _VMEM_BYTES = 64 << 20
 def _rows_per_block(rows: int, row_bytes: int) -> Optional[int]:
     """The most rows of a ``[rows, width]`` matrix a block may hold: a
     whole number of lane tiles (the rows are the other product's lanes)
-    that divides ``rows``."""
+    that divides ``rows``. Where one lane tile of rows is already more than
+    ``_BLOCK_BYTES`` (a ``[2048, 6144]`` float32 down projection: 3 MiB),
+    that one tile is the block, up to twice ``_BLOCK_BYTES``."""
     best = None
     for block in range(_LANES, rows + 1, _LANES):
         if rows % block == 0 and block * row_bytes <= _BLOCK_BYTES:
             best = block
+    if (best is None and rows % _LANES == 0
+            and _LANES * row_bytes <= 2 * _BLOCK_BYTES):
+        best = _LANES
     return best
 
 

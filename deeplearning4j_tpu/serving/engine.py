@@ -8,6 +8,12 @@ compiles outside it:
   per-layer K/V into one slot of the ``[L, S, T_max, Hkv, Dh]`` pool and
   sampling the request's first token from position ``prompt_len - 1``.
   One compile per prompt-ladder rung (``perf/bucketing.prompt_bucket``).
+  A model with learned sparse attention (``TransformerLM(indexers=)``)
+  prefills in blocks instead (``_serve_prefill_block_impl``): the rung's
+  program takes ``PREFILL_BLOCK`` positions through every layer against
+  the rows the blocks before it wrote, and the host runs it once for each
+  block the prompt has — one pass over a 28k-token prompt would need its
+  q, k and v at 64 heads of 256 (3 x 940 MB) beside the weights.
 - ``("decode", S)`` — ONE step for ALL S slots at their own positions:
   scatter the consumed tokens' K/V at each slot's cursor, attend each row
   against its own masked cache history (GQA-aware — the pool stores
@@ -321,38 +327,255 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     return layer
 
 
-def _latent_attention(model, pool, positions):
-    """``(j, p) -> attention(q_nope, q_rope, latent)`` for a decode-family
-    forward of an ``mla`` layer (the model's j-th, parameters ``p``) over
-    its cached latent rows: the new rows land at ``positions [S, Q]`` of
-    ``pool["latent"][j]`` (``[S, T_max, latent_row_width]``, rebound in ``pool`` as
-    ``_pool_attention`` rebinds the K/V pools), then query ``(s, i)``
-    attends slot ``s``'s rows ``<= positions[s, i]`` in the absorbed form
-    (``models/mla.attend_latent``)."""
+def _write_rows(cache, new, rows, positions, slot):
+    """``new`` [b, Q, w] into ``cache`` [S, T_max, W >= w] (zeros in the
+    lanes past w): one row a slot and query at ``positions [S, Q]`` (a
+    decode step: ``rows`` = every slot), or, with ``slot``, the Q
+    consecutive rows of that one slot from ``positions[0, 0]`` on (a
+    prefill block)."""
     import jax.numpy as jnp
-    from deeplearning4j_tpu.models import mla
+    from jax import lax
+
+    new = jnp.pad(new, ((0, 0), (0, 0), (0, cache.shape[-1] - new.shape[-1]))
+                  ).astype(cache.dtype)
+    if slot is None:
+        return cache.at[rows[:, None], positions].set(new)
+    return lax.dynamic_update_slice(cache, new, (slot, positions[0, 0], 0))
+
+
+def _slot_rows(cache, slot, keys):
+    """What the queries may read of ``cache`` [S, T_max, W]: all of it (a
+    decode step: every slot its own rows), or the first ``keys`` rows of
+    ``slot`` as [1, keys, W] (a prefill block: no position of the rung lies
+    beyond them)."""
+    from jax import lax
+
+    if slot is None:
+        return cache
+    return lax.dynamic_slice(cache, (slot, 0, 0), (1, keys, cache.shape[2]))
+
+
+def _latent_attention(model, pool, positions, slot=None, keys=None):
+    """``(j, p) -> attention(q_nope, q_rope, latent[, selection])`` for a
+    decode-family forward of an ``mla`` layer (the model's j-th, parameters
+    ``p``) over its cached latent rows: the new rows land at ``positions
+    [S, Q]`` of ``pool["latent"][j]`` (``[S, T_max, latent_row_width]``,
+    rebound in ``pool`` as ``_pool_attention`` rebinds the K/V pools), then
+    query ``(s, i)`` attends slot ``s``'s rows ``<= positions[s, i]`` in the
+    absorbed form (``models/mla.attend_latent``) — or, handed a
+    ``selection`` (``models/dsa.select``'s), the rows it names and no
+    others (``dsa.attend_selected``). ``slot`` and ``keys``: the forward is
+    a prefill block of that one slot (``_write_rows``, ``_slot_rows``)."""
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models import dsa, mla
 
     rows = jnp.arange(positions.shape[0])
+    cast = model.policy.cast_compute
 
     def layer(j, p):
-        def attn(q_nope, q_rope, latent):
-            cache = pool["latent"][j]
-            latent = jnp.pad(latent, ((0, 0), (0, 0), (
-                0, cache.shape[-1] - latent.shape[-1])))
-            cache = cache.at[rows[:, None], positions].set(
-                latent.astype(cache.dtype))
+        def attn(q_nope, q_rope, latent, selection=None):
+            cache = _write_rows(pool["latent"][j], latent, rows, positions,
+                                slot)
             pool["latent"][j] = cache
-            mask = jnp.arange(cache.shape[1]) <= positions[:, :, None]
-            return mla.attend_latent(q_nope, q_rope, cache, mask, p,
-                                     dims=model.mla,
-                                     cast=model.policy.cast_compute)
+            view = _slot_rows(cache, slot, keys)
+            if selection is not None:
+                return dsa.attend_selected(q_nope, q_rope, view, selection,
+                                           p, dims=model.mla, cast=cast)
+            mask = jnp.arange(view.shape[1]) <= positions[:, :, None]
+            return mla.attend_latent(q_nope, q_rope, view, mask, p,
+                                     dims=model.mla, cast=cast)
         return attn
 
     return layer
 
 
+def _index_selection(model, pool, positions, slot=None, keys=None):
+    """``j -> indexer(q^I, k^I, w)`` for a decode-family forward of the
+    model's j-th layer with a lightning indexer, over its cached index
+    keys: the new keys land at ``positions [S, Q]`` of ``pool["index"][j]``
+    (``[S, T_max, dI]``) as the latent rows do, then each query scores its
+    slot's keys ``<= positions[s, i]`` and selects (``models/dsa.select``).
+    ``slot`` and ``keys`` as ``_latent_attention``'s."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models import dsa
+
+    rows = jnp.arange(positions.shape[0])
+
+    def layer(j):
+        def indexer(iq, ik, iw):
+            with jax.named_scope("dsa.index"):
+                cache = _write_rows(pool["index"][j], ik, rows, positions,
+                                    slot)
+            pool["index"][j] = cache
+            return dsa.select(iq, iw, _slot_rows(cache, slot, keys),
+                              positions, model.dsa["topk"])
+        return indexer
+
+    return layer
+
+
+def _latent_layers(model, params, pool, positions, slot=None, keys=None):
+    """Per block of ``params`` the keywords ``TransformerLM._block`` takes
+    for an ``mla`` layer served from the pool (``attention`` and, for a
+    layer with an indexer, ``indexer``; None for another kind of layer)."""
+    attention = _latent_attention(model, pool, positions, slot, keys)
+    indexer = _index_selection(model, pool, positions, slot, keys)
+    out, j, jf = [], 0, 0
+    for blk in params["blocks"]:
+        if "mla" not in blk:
+            out.append(None)
+            continue
+        kw = {"attention": attention(j, blk["mla"])}
+        j += 1
+        if "indexer" in blk["mla"]:
+            kw["indexer"] = indexer(jf)
+            jf += 1
+        out.append(kw)
+    return out
+
+
+def _stack_selection(selections, k, at=None):
+    """The selections of the layers with an indexer (``dsa.select``'s,
+    either form) as ONE int32 array ``[layers, b, Q, k]`` of positions, -1
+    where a row selected fewer than k (``dsa.selected_positions``); with
+    ``at`` (traced), of that one query of the first row alone: ``[layers,
+    k]``."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.models import dsa
+
+    def cut(a):
+        return a if at is None else jnp.take(a[:1], at[None], axis=1)
+
+    out = jnp.stack([dsa.selected_positions(
+        jax.tree_util.tree_map(cut, sel), k) for sel in selections])
+    return out if at is None else out[:, 0, 0]
+
+
+# positions a prefill block of a model with learned sparse attention takes
+# through the layers at once. Every block reads the weights once more
+# (10.7 GB as stored at GLM-5.2's cut: 13 ms) and computes 2.6 GFLOP a
+# position (13 ms at the chip's peak per 1,024), and from 1,025 rows the
+# routed experts take their sorted form, which multiplies only the (token,
+# expert) pairs that landed here (``routed_experts.DENSE_MAX_TOKENS``).
+PREFILL_BLOCK = 2048
+
+
+def prefill_block_count(prompt_len: int, bucket: int) -> int:
+    """Blocks a prompt on the rung ``bucket`` is prefilled in
+    (``_serve_prefill_block_impl``): ``PREFILL_BLOCK`` positions each, or
+    the whole rung where it is no multiple of that."""
+    c = PREFILL_BLOCK if bucket % PREFILL_BLOCK == 0 else bucket
+    return -(-prompt_len // c)
+
+
+def prefill_carry_layout(model, bucket: int) -> dict:
+    """``{name: (shape, dtype name, fill)}`` of what the blocks of one
+    prefill hand on beside the pool (``_serve_prefill_block_impl``)."""
+    import jax.numpy as jnp
+
+    n_moe, k = len(model.layers_of("moe")), model.experts_per_token
+    topk = min(model.dsa["topk"], bucket)
+    return {
+        "h_last": ((model.d_model,),
+                   jnp.dtype(model.policy.compute_dtype).name, 0),
+        "sel_last": ((model.indexers.count("full"), topk), "int32", -1),
+        "load": ((n_moe, model.experts_held), "int32", 0),
+        "read": ((n_moe,), "int32", 0),
+        "chosen": ((n_moe, bucket, k), "int32", 0),
+        "weights": ((n_moe, bucket, k), "float32", 0)}
+
+
+@traced
+def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
+                              prompt_len, slot, key, i):
+    """``_serve_prefill_impl`` for a model with learned sparse attention,
+    whose prefill cannot be one pass (the module's docstring): block ``i``
+    of the bucket-padded prompt ([1, P]) through every layer. The host runs
+    ``prefill_block_count`` of them one after another (``DecodeEngine.
+    prefill``), so the rung's pad blocks are never run, and one block's
+    temporaries are all the program holds beside weights and pool. (As one
+    program with a loop over the blocks, XLA hoists the loop-invariant
+    bf16 copies of every float32 weight out of the loop: 5.3 GB live at
+    once, 18.9 GB in all at GLM-5.2's cut.)
+
+    A block is the decode-family forward at Q = its positions: it writes
+    its latent rows and index keys into the slot, then every query scores
+    the slot's index keys up to its own position, selects, and attends the
+    selected rows (``_latent_layers``). The pad tail of the last block is
+    inert by causality and writes rows beyond the cursor, which the decode
+    steps overwrite before any query reads them.
+
+    ``carry`` (``prefill_carry_layout``; block 0 resets it) hands on the
+    routing of the blocks so far and, from the block that holds position
+    ``prompt_len - 1``, its hidden state and selections. Returns ``(token,
+    key, pool, carry, routing, selection)``, of which the last block's
+    token, key, routing and selection are the prefill's: ``routing`` as
+    ``_stack_routing`` packs it (rows = the P positions, those of blocks
+    not run zero; None without routed experts), ``selection`` [layers with
+    an indexer, k] int32, the positions that the prompt's last token
+    selected (-1: fewer than k)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    if set(model.mixers) != {"mla"}:
+        raise NotImplementedError(
+            "prefill in query blocks is written for a stack of 'mla' "
+            f"layers; this model's are {model.mixers}")
+    policy = model.policy
+    p = prompt.shape[1]
+    c = p // prefill_block_count(p, p)
+    layout = prefill_carry_layout(model, p)
+    carry = {name: jnp.where(i == 0, jnp.full_like(a, layout[name][2]), a)
+             for name, a in carry.items()}
+    pool = {name: list(v) for name, v in kv.items()}
+    start = i * c
+    positions = (start + jnp.arange(c))[None]                   # [1, c]
+    toks = lax.dynamic_slice(prompt, (0, start), (1, c))
+    h = policy.cast_compute(jnp.take(params["embed"], toks, axis=0))
+    live = positions < prompt_len
+    moe_info: list = []
+    selections, selection = [], None
+    for blk, kw in zip(params["blocks"], _latent_layers(
+            model, params, pool, positions, slot, p)):
+        h, _, selection = model._block(
+            blk, h, positions=positions, live=live, moe_info=moe_info,
+            selection=selection, **kw)
+        if "indexer" in kw:
+            selections.append(selection)
+    at = prompt_len - 1 - start
+    here = (at >= 0) & (at < c)
+    at = jnp.clip(at, 0, c - 1)
+    new = {"h_last": jnp.where(here, jnp.take(h[0], at, axis=0),
+                               carry["h_last"]),
+           "sel_last": jnp.where(
+               here, _stack_selection(selections, layout["sel_last"][0][1],
+                                      at), carry["sel_last"])}
+    routing = None
+    if moe_info:
+        new["load"] = carry["load"] + jnp.stack(
+            [m["load"] for m in moe_info])
+        new["read"] = jnp.maximum(carry["read"], jnp.stack(
+            [m["read"] for m in moe_info]))
+        for name, got in (("chosen", "experts"), ("weights", "weights")):
+            new[name] = lax.dynamic_update_slice(
+                carry[name], jnp.stack([m[got] for m in moe_info]),
+                (0, start, 0))
+        n_moe = len(moe_info)
+        routing = jnp.concatenate(
+            [new["load"], new["chosen"].reshape(n_moe, -1),
+             lax.bitcast_convert_type(new["weights"], jnp.int32).reshape(
+                 n_moe, -1), new["read"][:, None]], axis=1)
+    new = {**carry, **new}
+    tok, key = sample_row(model._unembed(params, new["h_last"]), key)
+    return tok, key, pool, new, routing, new["sel_last"]
+
+
 def _decode_step_body(model, params, kv, tok, positions, *,
-                      pool_kernel=None, live=None, moe_info=None):
+                      pool_kernel=None, live=None, moe_info=None,
+                      selections=None):
     """ONE decode forward for all S slots: consume ``tok[s]`` at
     ``positions[s]``, write its (de/re)quantized K/V at that cursor,
     attend keys ``<= positions[s]`` (``_pool_attention``).
@@ -372,7 +595,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     of its kind: an ``attn`` layer the K/V pools, an ``mla`` layer its
     latent rows (``_latent_attention``), a ``kda`` layer its recurrent
     matrix and convolution tail, which it takes and hands back advanced
-    for the live slots and untouched for the others."""
+    for the live slots and untouched for the others. In a model with
+    learned sparse attention a layer with an indexer also meets its index
+    keys, selects, and its selection goes to the layers after it
+    (``_latent_layers``); ``selections`` receives each such layer's."""
     import jax.numpy as jnp
 
     h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
@@ -383,24 +609,28 @@ def _decode_step_body(model, params, kv, tok, positions, *,
               for k, v in kv.items()}
     cached_attention = _pool_attention(
         model, new_kv, positions[:, None], pool_kernel, live)
-    latent_attention = _latent_attention(model, new_kv, positions[:, None])
+    latent = _latent_layers(model, params, new_kv, positions[:, None])
     seen = {"attn": 0, "mla": 0, "kda": 0}
-    for blk in params["blocks"]:
+    selection = None
+    for blk, kw in zip(params["blocks"], latent):
         kind = next(k for k in seen if k in blk)
         j = seen[kind]
         seen[kind] += 1
-        kw = {}
         if kind == "kda":
-            kw["state"] = (new_kv["kda"][j], new_kv["conv"][j])
+            kw = {"state": (new_kv["kda"][j], new_kv["conv"][j])}
         elif kind == "mla":
-            kw["attention"] = latent_attention(j, blk["mla"])
+            kw = dict(kw, selection=selection)
         else:
-            kw["attention"] = cached_attention(j)
+            kw = {"attention": cached_attention(j)}
         h, a, b = model._block(
             blk, h, positions=positions[:, None], moe_info=moe_info,
             live=None if live is None else live[:, None], **kw)
         if kind == "kda":
             new_kv["kda"][j], new_kv["conv"][j] = a, b
+        elif kind == "mla" and model.dsa:
+            selection = b
+            if selections is not None and "indexer" in kw:
+                selections.append(b)
     logits = model._unembed(params, h[:, 0])               # [S, V]
     return logits, new_kv
 
@@ -412,16 +642,25 @@ def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
     sampling. One host dispatch per token — the ``fuse_steps=1`` path.
     Returns ``(tokens, keys, pool)``, and for a model with routed experts
     ``(tokens, keys, pool, routing)`` (``_stack_routing``, rows = the S
-    slots)."""
+    slots), and for one with learned sparse attention a fifth value, the
+    step's selections ``[layers with an indexer, S, k]`` int32
+    (``_stack_selection``)."""
     import jax
 
     moe_info: list = []
+    selections: list = []
     logits, new_kv = _decode_step_body(model, params, kv, tok, positions,
                                        pool_kernel=pool_kernel, live=live,
-                                       moe_info=moe_info)
+                                       moe_info=moe_info,
+                                       selections=selections)
     toks, keys = jax.vmap(sample_row)(logits, keys)
+    routing = _stack_routing(moe_info) if model.num_experts else None
+    if model.dsa:
+        k = min(model.dsa["topk"], new_kv["latent"][0].shape[1])
+        return (toks, keys, new_kv, routing,
+                _stack_selection(selections, k)[:, :, 0])
     if model.num_experts:
-        return toks, keys, new_kv, _stack_routing(moe_info)
+        return toks, keys, new_kv, routing
     return toks, keys, new_kv
 
 
@@ -434,7 +673,8 @@ def _serve_decode_loop_impl(model, sample_row, params, kv, loop, *,
     read's and the routed experts' ``live`` mask), consumes ``tok`` at
     its cursor and takes the sampled token; ``advance_loop`` moves the
     state on. Returns ``(loop, pool)`` and, for a model with routed
-    experts, ``(loop, pool, routing)``: the token block of the step is
+    experts, ``(loop, pool, routing)`` (with learned sparse attention,
+    ``(loop, pool, routing, selection)``): the token block of the step is
     ``loop["tok"]``."""
     out = _serve_decode_impl(
         model, sample_row, params, kv, loop["tok"], loop["cursors"],
@@ -626,6 +866,16 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
     return blocks, loop, draft_keys, kv, draft_kv
 
 
+def _record(extra):
+    """What a serving program returned beside tokens, keys and pool (and a
+    prefill block's carry), as the
+    engine hands it on: None (a dense model), the routing array
+    (``_stack_routing``), or ``(routing, selection)`` for a model with
+    learned sparse attention (``_stack_selection``)."""
+    return None if not extra else extra[0] if len(extra) == 1 else tuple(
+        extra)
+
+
 class DecodeEngine:
     """Owns the slot pool(s) + the per-signature program cache.
 
@@ -684,6 +934,7 @@ class DecodeEngine:
         self._sample_row = _row_sampler(self.temperature, top_k)
         self._programs: Dict[tuple, object] = {}
         self.program_builds = 0
+        self._prefill_carry: Dict[int, list] = {}   # prefill_blocks
 
         # ---- speculative-decoding configuration
         if model.hybrid and (draft_model is not None or draft_layers):
@@ -691,7 +942,8 @@ class DecodeEngine:
                 "speculative decoding is not written for a model with "
                 "'kda' or 'mla' layers: a rejected draft token would have "
                 "to be taken back out of the recurrent state, which keeps "
-                "no history to rewind to")
+                "no history to rewind to, and the verify forward knows "
+                "neither latent rows nor an indexer's keys")
         if draft_model is not None and draft_layers:
             raise ValueError(
                 "pass draft_model= OR draft_layers=, not both")
@@ -750,7 +1002,8 @@ class DecodeEngine:
 
         cfg = dict(model.get_config())
         cfg["num_layers"] = n
-        cfg["mixers"], cfg["ffns"] = cfg["mixers"][:n], cfg["ffns"][:n]
+        for per_layer in ("mixers", "ffns", "indexers"):
+            cfg[per_layer] = cfg[per_layer][:n]
         draft = TransformerLM(**cfg)
         draft.params = {k: v for k, v in model.params.items()
                         if k != "blocks"}
@@ -811,21 +1064,75 @@ class DecodeEngine:
             return jax.jit(fn, donate_argnums=(1,))
 
         run = self._program((kind, int(padded.shape[0])), build)
-        tok, key, state, *routing = run(
+        tok, key, state, *record = run(
             model.params, cache.state, jnp.asarray(padded)[None],
             jnp.asarray(plen, jnp.int32), jnp.asarray(slot, jnp.int32), key)
         cache.install(state)
-        return tok, key, routing[0] if routing else None
+        return tok, key, _record(record)
+
+    def prefill_blocks(self, prompt, slot: int, key):
+        """``prefill`` for a model with learned sparse attention, one block
+        a ``next``: a generator that runs the rung's block program
+        (``_serve_prefill_block_impl``) once for each block the prompt has,
+        pool and carry donated from one to the next, and yields None after
+        every block but the last and ``prefill``'s triple after that one.
+        Between two blocks the caller may dispatch decode steps
+        (``DecodeServer`` does: one block a scheduler step): the slot is
+        frozen meanwhile with
+        its cursor at ``prompt_len``, so the row a frozen slot writes in
+        every step lands where no query of the prompt reads and where the
+        slot's own first decode step writes before it reads. The carry's
+        buffers come from the rung's free list and go back to it when the
+        generator ends or is closed (a request that left mid-prefill)."""
+        import jax
+        import jax.numpy as jnp
+
+        model, cache = self.model, self.cache
+        prompt = np.asarray(prompt, np.int32)
+        if prompt.ndim != 1:
+            raise ValueError(f"prompt must be [t] (got {prompt.shape})")
+        bucket = self.prompt_bucket(int(prompt.shape[0]))
+        padded, plen = pad_prompt(prompt, bucket)
+
+        def build():
+            fn = functools.partial(_serve_prefill_block_impl, model,
+                                   self._sample_row)
+            return jax.jit(fn, donate_argnums=(1, 2))
+
+        run = self._program(("prefill", bucket), build)
+        free = self._prefill_carry.setdefault(bucket, [])
+        carry = free.pop() if free else {
+            name: jnp.full(shape, fill, jnp.dtype(dt))
+            for name, (shape, dt, fill) in prefill_carry_layout(
+                model, bucket).items()}
+        self.admit_slot(slot, 0, int(plen), 0, key)
+        tokens = jnp.asarray(padded)[None]
+        plen_, slot_ = jnp.asarray(plen, jnp.int32), jnp.asarray(
+            slot, jnp.int32)
+        last = prefill_block_count(int(plen), bucket) - 1
+        try:
+            for i in range(last + 1):
+                tok, new_key, state, carry, *record = run(
+                    model.params, cache.state, carry, tokens, plen_, slot_,
+                    key, np.int32(i))
+                cache.install(state)
+                yield (tok, new_key, _record(record)) if i == last else None
+        finally:
+            free.append(carry)
 
     def prefill(self, prompt, slot: int, key):
         """One prompt ([t] int) into ``slot``'s pool rows: bucket-pad, run
         the prefill program (plus the draft-pool prefill when speculative
         decoding is on). Returns ``(first_token, new_key, routing)``
         (device values; ``routing`` is ``_stack_routing``'s array, None
-        for a dense model). The slot decodes once ``admit_slot`` has
-        written its loop state."""
+        for a dense model, and ``(routing, selection)`` for a model with
+        learned sparse attention: ``_record``). The slot decodes once
+        ``admit_slot`` has written its loop state."""
         import jax
 
+        if self.model.dsa:      # every block, back to back
+            *_, out = self.prefill_blocks(prompt, slot, key)
+            return out
         prompt = np.asarray(prompt, np.int32)
         if prompt.ndim != 1:
             raise ValueError(f"prompt must be [t] (got {prompt.shape})")
@@ -880,17 +1187,17 @@ class DecodeEngine:
         """One batched step (the ``fuse_steps=1`` / PR-10 path) from the
         loop state on the device to the loop state on the device: no
         argument comes from the host. Returns ``(tokens [S], routing)``
-        (device; ``routing`` None for a dense model): a live slot's
+        (device; ``routing`` as ``prefill``'s): a live slot's
         token is the one it just sampled, a frozen slot's its last."""
         def build():
             return self._decode_jit(
                 (1,), _serve_decode_loop_impl, self.model, self._sample_row)
 
         run = self._program(("decode", self.slots), build)
-        self.cache.loop, state, *routing = run(
+        self.cache.loop, state, *record = run(
             self.model.params, self.cache.state, self.cache.loop)
         self.cache.install(state)
-        return self.cache.loop["tok"], routing[0] if routing else None
+        return self.cache.loop["tok"], _record(record)
 
     def decode_fused(self, k_steps: int):
         """K decode steps as ONE dispatch: returns the ``[K, S]`` token

@@ -47,6 +47,10 @@ of it side by side:
 - ``mla`` layers: latent rows, one ``[S, T_max, r + dr]`` array a layer
   (``latent``; each row padded with zeros to whole 128-lane tiles,
   ``latent_row_width``), written at the cursor and masked like keys;
+- ``mla`` layers with a lightning indexer (``TransformerLM(indexers=)``'s
+  ``"full"`` layers, ``models/dsa.py``): beside their latent rows, index
+  keys, one ``[S, T_max, dI]`` array a layer (``index``), written and
+  masked as the latent rows are; a ``"shared"`` layer keeps none;
 - ``kda`` layers: a recurrent matrix ``[S, H, dk, dk]`` in float32
   (``kda``) and a convolution tail ``[S, K - 1, 3 H dk]`` (``conv``) a
   layer. They have no time axis: a prefill writes them **as of the
@@ -127,9 +131,10 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
     """``{kind: [(shape, dtype name), ...]}`` of every array a slot pool
     of this model holds, by what it is: ``kv`` (the K and the V pool, and
     their int8 scales), ``latent`` (one array an ``mla`` layer),
+    ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
     ``recurrent`` and ``conv`` (one each a ``kda`` layer). A kind the
     model has no layer of is an empty list."""
-    out = {"kv": [], "latent": [], "recurrent": [], "conv": []}
+    out = {"kv": [], "latent": [], "index": [], "recurrent": [], "conv": []}
     dims = _pool_dims(model, slots, max_len)
     if dims[0]:
         out["kv"] += [(dims, kv_dtype)] * 2
@@ -138,6 +143,9 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
     if model.mla:
         out["latent"] = [((slots, max_len, latent_row_width(model)),
                           kv_dtype)] * len(model.layers_of("mla"))
+    if model.dsa:
+        out["index"] = [((slots, max_len, model.dsa["head_dim"]),
+                         kv_dtype)] * model.indexers.count("full")
     if model.kda:
         n, dk = len(model.layers_of("kda")), model.kda["head_dim"]
         out["recurrent"] = [((slots, model.num_heads, dk, dk),
@@ -298,7 +306,8 @@ def slot_admit(loop, at, tok, key):
 
 class SlotKVCache:
     """``[L, S, T_max, Hkv, Dh]`` K/V pools, the other layer kinds' state
-    (latent rows, recurrent matrices, convolution tails: ``pool_layout``)
+    (latent rows, index keys, recurrent matrices, convolution tails:
+    ``pool_layout``)
     + the decode loop's device per-slot state (cursors, last tokens,
     tokens owed, RNG keys)."""
 
@@ -334,11 +343,11 @@ class SlotKVCache:
                 "a model with 'kda' or 'mla' layers is served from an "
                 "unquantized pool on one chip: the int8 codec and the "
                 "mesh's head split are written for K/V pools only, not for "
-                "latent rows or recurrent state")
+                "latent rows, an indexer's keys or recurrent state")
         layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype)
-        self.latent, self.kda, self.conv = (
+        self.latent, self.index, self.kda, self.conv = (
             [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
-            for kind in ("latent", "recurrent", "conv"))
+            for kind in ("latent", "index", "recurrent", "conv"))
         shape = _pool_dims(model, self.slots, self.max_len)
         if not shape[0]:        # no layer keeps keys and values
             self.k = self.v = self.k_scale = self.v_scale = None
@@ -405,7 +414,7 @@ class SlotKVCache:
         if self.k_scale is not None:
             st["k_scale"] = self.k_scale
             st["v_scale"] = self.v_scale
-        for name in ("latent", "kda", "conv"):
+        for name in ("latent", "index", "kda", "conv"):
             if getattr(self, name):
                 st[name] = list(getattr(self, name))
         return st
@@ -418,7 +427,7 @@ class SlotKVCache:
         self.v = state.get("v")
         self.k_scale = state.get("k_scale")
         self.v_scale = state.get("v_scale")
-        for name in ("latent", "kda", "conv"):
+        for name in ("latent", "index", "kda", "conv"):
             setattr(self, name, list(state.get(name, ())))
 
     @property
@@ -431,13 +440,16 @@ class SlotKVCache:
     @property
     def nbytes_by_kind(self) -> dict:
         """``nbytes`` apart: ``kv`` (K/V pools and their scales),
-        ``latent``, ``recurrent``, ``conv`` (``pool_layout``'s kinds)."""
+        ``latent``, ``index``, ``recurrent``, ``conv`` (``pool_layout``'s
+        kinds)."""
         kv = [a for a in (self.k, self.v, self.k_scale, self.v_scale)
               if a is not None]
+        kinds = [("kv", kv), ("latent", self.latent),
+                 ("recurrent", self.kda), ("conv", self.conv)]
+        if self.index:      # only a model with an indexer names the kind
+            kinds.insert(2, ("index", self.index))
         return {kind: sum(int(a.nbytes) for a in arrays)
-                for kind, arrays in (("kv", kv), ("latent", self.latent),
-                                     ("recurrent", self.kda),
-                                     ("conv", self.conv))}
+                for kind, arrays in kinds}
 
     @property
     def per_slot_nbytes(self) -> int:

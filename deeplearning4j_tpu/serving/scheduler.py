@@ -346,6 +346,12 @@ class ServeRequest:   # field-wise eq would compare prompt arrays
     # emitted a token, in order: position p of ``output[:-1]`` was served
     # by row p of their concatenation along axis 1
     routing: Optional[list] = None
+    # the same server over a model with learned sparse attention: one
+    # ``[layers with an indexer, 1, k]`` int32 array (-1: fewer than k
+    # selected) for the prompt's last position and one for each decode
+    # step that emitted a token: row j of their concatenation along axis 1
+    # is the key positions that the query which emitted token j attended
+    selection: Optional[list] = None
 
     def expired(self, now: float) -> bool:
         return self.deadline_s is not None and now > self.deadline_s

@@ -16,6 +16,12 @@ The loop, per ``step()`` (a step IS a fusion boundary):
    ONLY here: with ``fuse_steps=K`` a request arriving mid-scan waits for
    the dispatch in flight to finish (the admission-boundary trade —
    bounded added TTFT, in exchange for K tokens per dispatch).
+   A model with learned sparse attention is prefilled in blocks
+   (``engine.prefill_blocks``), and its admission is spread over steps:
+   a request takes its slot at once, ONE block of one prompt runs a step
+   (``serve.prefill_block``; the prompts part-way in take turns), and
+   the last block is the request's ``serve.prefill`` (``_admit_blocks``).
+   The live slots decode between the blocks.
 3. **decode** — if any slot is owed a token, run ONE decode dispatch:
    the plain single-step program (``fuse_steps=1``, the PR-10 step), the
    K-step fused program, or K speculative rounds when a draft is
@@ -70,7 +76,11 @@ runs):
   → popped from the queue; recorded when it ends) and ``serve.prefill``
   (``request``, ``slot``, ``prompt_len``, ``bucket``, ``queue_wait_us``:
   key, pad, prefill dispatch, cursor, the first token's read-back, up to
-  ``first_token_s``), or ``serve.handoff.install``.
+  ``first_token_s``), or ``serve.handoff.install``. Learned sparse
+  attention: ``serve.prefill`` is the prompt's last block (``blocks``:
+  how many it had) and each block before it a ``serve.prefill_block``
+  (``request``, ``slot``, ``block``, ``blocks``) in an earlier step's
+  ``serve.admit``.
 - ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
   ``spec``, ``ahead`` = 1 when the dispatch was issued while the
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
@@ -95,7 +105,7 @@ times; that is the tracer's clock unless one of the two was injected.
 from __future__ import annotations
 
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -103,7 +113,8 @@ import numpy as np
 from deeplearning4j_tpu.monitor import metrics, tracer
 from deeplearning4j_tpu.pallas.decode_attention import (
     key_block_span, pool_block_rows)
-from deeplearning4j_tpu.serving.engine import DecodeEngine, unpack_routing
+from deeplearning4j_tpu.serving.engine import (
+    DecodeEngine, prefill_block_count, unpack_routing)
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
     criticality_rank, serve_deadline_s, serve_draft_layers,
@@ -169,6 +180,17 @@ class DecodeServer:
                           else pool_block_rows(pool.k.shape, pool.k.dtype))
         self.kv_blocks = 0
         self.kv_blocks_pool = 0
+        # learned sparse attention: latent rows the dispatched slots held
+        # below their cursors, and rows their queries attended (at most
+        # ``topk`` each), summed over decode steps, live slots and 'mla'
+        # layers; and the blocks the prefill programs ran
+        self.keys_cached = 0
+        self.keys_attended = 0
+        self.prefill_blocks = 0
+        # {slot: [engine.prefill_blocks generator, its blocks, queue wait in
+        # us, blocks run]} of the requests whose prompt is part-way into
+        # its slot, the next to run first (``_admit_blocks``)
+        self._prefilling: "OrderedDict[int, list]" = OrderedDict()
         self._last_tok_s = np.zeros(self.slots, np.float64)
         # the dispatched block the host has not read: ``(tokens, routing,
         # {slot: request})`` — device arrays and the slots live in it
@@ -413,11 +435,35 @@ class DecodeServer:
 
     def _admit(self) -> int:
         free = self._free_slots()
-        if not free or not (self._handoffs or len(self.queue)):
+        if not self._prefilling and (
+                not free or not (self._handoffs or len(self.queue))):
             return 0
         with tracer().span("serve.admit") as sp:
-            sp.attrs["n"] = admitted = self._admit_into(free)
+            sp.attrs["n"] = admitted = (
+                self._admit_blocks(free) if self.model.dsa
+                else self._admit_into(free))
         return admitted
+
+    def _pop_request(self):
+        """The next queued request that is still wanted, its wait recorded
+        (``serve.queued``), with that wait in microseconds: ``(request,
+        waited)``, or None with an empty queue. Pops past corpses: an
+        expired request sheds HERE — before its prefill burns the slot —
+        and a canceled hedge loser vanishes without a trace in the
+        finished ledger."""
+        req = self.queue.pop()
+        while req is not None:
+            now = self.clock()
+            if req.canceled:
+                req.state = "canceled"
+            elif req.expired(now):
+                self._shed(req, where="queue", reason="deadline", now=now)
+            else:
+                tracer().record("serve.queued", req.submit_s, now,
+                                request=req.id, criticality=req.criticality)
+                return req, int(1e6 * (now - req.submit_s))
+            req = self.queue.pop()
+        return None
 
     def _admit_into(self, free: List[int]) -> int:
         import jax
@@ -431,63 +477,111 @@ class DecodeServer:
                 self._admit_handoff(slot)
                 admitted += 1
                 continue
-            # pop past corpses: an expired request sheds HERE — before
-            # its prefill burns the slot — and a canceled hedge loser
-            # vanishes without a trace in the finished ledger
-            req = self.queue.pop()
-            while req is not None:
-                now = self.clock()
-                if req.canceled:
-                    req.state = "canceled"
-                elif req.expired(now):
-                    self._shed(req, where="queue", reason="deadline",
-                               now=now)
-                else:
-                    break
-                req = self.queue.pop()
-            if req is None:
+            popped = self._pop_request()
+            if popped is None:
                 break
+            req, waited = popped
             prompt_len = int(req.prompt.shape[0])
-            tracer().record("serve.queued", req.submit_s, now,
-                            request=req.id, criticality=req.criticality)
             with tracer().span(
                     "serve.prefill", request=req.id, slot=slot,
                     prompt_len=prompt_len,
                     bucket=self.engine.prompt_bucket(prompt_len),
-                    queue_wait_us=int(1e6 * (now - req.submit_s))):
+                    queue_wait_us=waited):
                 key = jax.random.PRNGKey(req.seed)
                 if self.engine.spec:
                     # an independent per-slot draft stream (only the
                     # sampled speculative path consumes it)
                     self.engine.draft_keys = self.engine.draft_keys.at[
                         slot].set(jax.random.fold_in(key, 0x5bec))
-                tok, key, routing = self.engine.prefill(req.prompt, slot,
-                                                        key)
-                # the slot's loop state straight from the program's
-                # outputs, queued on the device before the host waits
-                self.engine.admit_slot(slot, tok, prompt_len,
-                                       req.max_new_tokens - 1, key)
-                self._cursors[slot] = prompt_len
-                tok, rows = self._read_block(tok, routing, prompt_len)
-                now = self.clock()
-                req.state = "running"
-                req.slot = slot
-                req.first_token_s = now
-            if self.record_routing:
-                req.routing = [tuple(a[:, :prompt_len].copy()
-                                     for a in rows)]
-            req.tokens.append(int(tok))
-            self._slot_req[slot] = req
-            self._last_tok_s[slot] = now
-            if req.ttft_s is not None:
-                self._reg.histogram("serve_ttft_seconds",
-                                    buckets=_LATENCY_BUCKETS
-                                    ).observe(req.ttft_s)
-            self._reg.counter("serve_tokens_total").inc()
+                self._first_token(req, slot, *self.engine.prefill(
+                    req.prompt, slot, key))
+            self._enter(req, slot)
             admitted += 1
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(slot, now)
         return admitted
+
+    def _admit_blocks(self, free: List[int]) -> int:
+        """Admission for a model with learned sparse attention, whose
+        prefill is a row of block programs (``engine.prefill_blocks``):
+        every free slot takes a queued request, and then ONE block runs —
+        of the request that has waited longest for one — before the step's
+        decode dispatch, so a 28k-token prompt holds the live slots back a
+        block at a time and not for all of its fourteen, and a short prompt
+        behind it shares the chip with it instead of waiting it out.
+        Returns how many requests took their first token."""
+        import jax
+
+        for slot in free:
+            popped = self._pop_request()
+            if popped is None:
+                break
+            req, waited = popped
+            prompt_len = int(req.prompt.shape[0])
+            blocks = prefill_block_count(
+                prompt_len, self.engine.prompt_bucket(prompt_len))
+            self.prefill_blocks += blocks
+            req.state, req.slot = "running", slot
+            self._slot_req[slot] = req
+            self._prefilling[slot] = [
+                self.engine.prefill_blocks(
+                    req.prompt, slot, jax.random.PRNGKey(req.seed)),
+                blocks, waited, 0]
+        if not self._prefilling:
+            return 0
+        slot, state = next(iter(self._prefilling.items()))
+        run, blocks, waited, done = state
+        req = self._slot_req[slot]
+        if done + 1 < blocks:
+            with tracer().span("serve.prefill_block", request=req.id,
+                               slot=slot, block=done, blocks=blocks):
+                next(run)
+            state[3] += 1
+            self._prefilling.move_to_end(slot)
+            return 0
+        # the prompt's last block is the request's ``serve.prefill``
+        prompt_len = int(req.prompt.shape[0])
+        del self._prefilling[slot]
+        with tracer().span(
+                "serve.prefill", request=req.id, slot=slot,
+                prompt_len=prompt_len,
+                bucket=self.engine.prompt_bucket(prompt_len),
+                queue_wait_us=waited, blocks=blocks):
+            self._first_token(req, slot, *next(run))
+        run.close()
+        self._enter(req, slot)
+        return 1
+
+    def _first_token(self, req: ServeRequest, slot: int, tok, key,
+                     routing) -> None:
+        """What a finished prefill hands to the slot and the request,
+        inside its ``serve.prefill`` span: the loop state, the first token
+        read back, the routing and selection where they are recorded."""
+        prompt_len = int(req.prompt.shape[0])
+        # the slot's loop state straight from the program's outputs,
+        # queued on the device before the host waits
+        self.engine.admit_slot(slot, tok, prompt_len,
+                               req.max_new_tokens - 1, key)
+        self._cursors[slot] = prompt_len
+        tok, rows, selection = self._read_block(tok, routing, prompt_len)
+        req.state = "running"
+        req.slot = slot
+        req.first_token_s = self.clock()
+        if self.record_routing:
+            req.routing = [tuple(a[:, :prompt_len].copy() for a in rows)]
+            if selection is not None:   # of the prompt's last position
+                req.selection = [selection[:, None]]
+        req.tokens.append(int(tok))
+
+    def _enter(self, req: ServeRequest, slot: int) -> None:
+        """The request holds ``slot`` and its first token: booked."""
+        self._slot_req[slot] = req
+        self._last_tok_s[slot] = req.first_token_s
+        if req.ttft_s is not None:
+            self._reg.histogram("serve_ttft_seconds",
+                                buckets=_LATENCY_BUCKETS
+                                ).observe(req.ttft_s)
+        self._reg.counter("serve_tokens_total").inc()
+        if len(req.tokens) >= req.max_new_tokens:
+            self._retire(slot, req.first_token_s)
 
     def _retire(self, slot: int, now: float) -> None:
         req = self._slot_req[slot]
@@ -511,8 +605,8 @@ class DecodeServer:
         ``max_new_tokens`` alone (the device's ``remaining > 0``)."""
         pending = self._unread[2] if self._unread is not None else {}
         return {s: r for s, r in enumerate(self._slot_req)
-                if r is not None and r.max_new_tokens - len(r.tokens)
-                > (pending.get(s) is r)}
+                if r is not None and s not in self._prefilling
+                and r.max_new_tokens - len(r.tokens) > (pending.get(s) is r)}
 
     def _dispatch(self, live: dict):
         """ONE decode dispatch for the live set, from the loop state on
@@ -538,8 +632,21 @@ class DecodeServer:
         every slot, frozen cursors included — into the server's totals
         and two registry counters, and returned as the ``serve.decode``
         span's attrs (none where the pool has no kernel read, or nothing
-        is dispatched). No device read."""
+        is dispatched). For a model with learned sparse attention the
+        attrs are ``keys_cached`` and ``keys_attended`` instead: the latent
+        rows the live slots hold up to their cursors and the rows their
+        queries attend, over the 'mla' layers. No device read."""
         attrs = {}
+        if live and self.model.dsa:
+            # a query at cursor c has c + 1 rows behind it (its own among
+            # them) in every 'mla' layer and attends min(c + 1, topk)
+            layers = len(self.model.layers_of("mla"))
+            cached = self._cursors[list(live)] + 1
+            attrs = {"keys_cached": int(cached.sum()) * layers,
+                     "keys_attended": int(np.minimum(
+                         cached, self.model.dsa["topk"]).sum()) * layers}
+            self.keys_cached += attrs["keys_cached"]
+            self.keys_attended += attrs["keys_attended"]
         if live and self._kv_block is not None:
             _, _, t_max, hkv, _ = self.engine.cache.k.shape
             lo, hi = key_block_span(
@@ -573,12 +680,24 @@ class DecodeServer:
         weights come in the same array (``engine._stack_routing``):
         ``rows`` = ``(experts, weights)``, None for a dense model.
         ``live_rows`` is how many rows of the program held a token (a
-        prompt's length, a decode block's live slots): ``moe_rows``."""
-        if routing is None:
-            return np.asarray(toks), None
+        prompt's length, a decode block's live slots): ``moe_rows``.
+
+        A model with learned sparse attention hands over ``(routing,
+        selection)`` (``engine._record``); the third value returned is the
+        selection on the host where ``record_routing`` keeps it (a quarter
+        of a megabyte a decode step at 16 slots, 2 layers, 2,048 keys:
+        otherwise it stays on the device), else None."""
         import jax
 
-        toks, packed = jax.device_get((toks, routing))
+        selection = None
+        if isinstance(routing, tuple):
+            routing, selection = routing
+            if not self.record_routing:
+                selection = None
+        if routing is None:
+            toks, selection = jax.device_get((toks, selection))
+            return np.asarray(toks), None, selection
+        toks, packed, selection = jax.device_get((toks, routing, selection))
         load, *rows, read = unpack_routing(packed, self.model.experts_held,
                                            self.model.experts_per_token)
         touched, read = int(np.count_nonzero(load)), int(read.sum())
@@ -598,7 +717,7 @@ class DecodeServer:
         if span is not None:
             span.attrs["experts_touched"] = touched
             span.attrs["experts_read"] = read
-        return toks, rows
+        return toks, rows, selection
 
     def _sweep_expired(self) -> None:
         """The retirement loop's deadline check: an in-flight request
@@ -620,6 +739,8 @@ class DecodeServer:
             else:
                 continue
             self._slot_req[slot] = None
+            if slot in self._prefilling:    # its carry goes back
+                self._prefilling.pop(slot)[0].close()
             self.engine.release_slot(slot)
             self._cursors[slot] = 0
 
@@ -629,7 +750,8 @@ class DecodeServer:
         speculative rounds of tokens), then read and book a token block
         — on the plain path the one dispatched a step EARLIER, so the
         chip runs this step's dispatch meanwhile. Returns False when
-        nothing was dispatched or read (the caller may idle)."""
+        nothing was dispatched or read and no prompt is part-way through
+        its prefill blocks (the caller may idle)."""
         with tracer().span("serve.step") as sp:
             self._sweep_expired()
             sp.attrs["admitted"] = self._admit()
@@ -637,7 +759,7 @@ class DecodeServer:
             self._reg.gauge("serve_queue_depth").set(len(self.queue))
             self._reg.gauge("serve_slot_occupancy").set(self.occupancy())
             if not live and self._unread is None:
-                return False
+                return bool(self._prefilling)   # a prefill block ran
             sp.attrs["live"] = len(live)
             self._decode(live)
             return True
@@ -661,8 +783,8 @@ class DecodeServer:
                     unread, self._unread = self._unread, None
             if unread is None:      # the first dispatch after idling
                 return
-            toks, rows = self._read_block(*unread[:2], len(unread[2]),
-                                          decode=True)
+            toks, rows, selection = self._read_block(
+                *unread[:2], len(unread[2]), decode=True)
         counts = None
         if self.engine.spec:                       # [K, S, G+2]
             toks, counts = toks[:, :, 1:], toks[:, :, 0]
@@ -670,7 +792,7 @@ class DecodeServer:
             toks = toks[None]
         with tracer().span("serve.emit") as emit:
             emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
-                unread[2], toks, counts, rows)
+                unread[2], toks, counts, rows, selection)
 
     def flush(self) -> None:
         """Read and book the block the host has not read yet (no-op with
@@ -679,12 +801,13 @@ class DecodeServer:
         if self._unread is not None:
             self._decode({})
 
-    def _emit(self, live: dict, toks, counts, rows) -> Tuple[int, int]:
+    def _emit(self, live: dict, toks, counts, rows,
+              selection=None) -> Tuple[int, int]:
         """Book one dispatch's token block: per slot that was live in it
         the tokens it takes, TPOT observations, retirement; ``rows`` is
-        the same block's routing. A slot whose request was swept while
-        the block was unread takes nothing. Returns ``(tokens emitted,
-        requests retired)``."""
+        the same block's routing and ``selection`` its key selections. A
+        slot whose request was swept while the block was unread takes
+        nothing. Returns ``(tokens emitted, requests retired)``."""
         now = self.clock()
         self.steps += 1
         self.slot_dispatches += len(live)
@@ -717,6 +840,8 @@ class DecodeServer:
             if req.routing is not None:  # the row that emitted this token
                 req.routing.append(tuple(a[:, slot:slot + 1]
                                          for a in rows))
+                if selection is not None:
+                    req.selection.append(selection[:, slot:slot + 1])
             emitted_total += len(got)
             # with fusion the K tokens land together: spread the
             # dispatch interval evenly so TPOT keeps one observation
@@ -781,7 +906,7 @@ class DecodeServer:
             "kv_dtype": self.engine.kv_dtype,
             "kv_pool_bytes": pool_bytes,
             # the target pool's bytes by what they are: K/V rows, latent
-            # rows, recurrent matrices, convolution tails
+            # rows, an indexer's keys, recurrent matrices, convolution tails
             "state_bytes": self.engine.cache.nbytes_by_kind,
             # slots a decode dispatch served, mean
             "live_slots_per_step": (
@@ -828,6 +953,13 @@ class DecodeServer:
             "speculative": self.engine.spec,
             "compiles": self.engine.compile_counts(),
         }
+        if self.model.dsa:
+            out["keys_cached"] = self.keys_cached
+            out["keys_attended"] = self.keys_attended
+            out["keys_attended_share"] = (
+                round(self.keys_attended / self.keys_cached, 4)
+                if self.keys_cached else None)
+            out["prefill_blocks"] = self.prefill_blocks
         if self.model.num_experts:
             out["moe_expert_load"] = self.moe_expert_load.tolist()
             out["moe_rows"] = self.moe_rows

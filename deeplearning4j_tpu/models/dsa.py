@@ -22,16 +22,20 @@ top-k below recall 1 would be another model. It has two forms, chosen from
 the shapes at trace time, and the attention over ``S_t`` (the absorbed form of
 ``mla.attend_latent``) follows the form it is handed:
 
-- **positions** ``(idx [b, 1, k], valid)``, from ``lax.top_k`` (on a TPU a
-  sort of the whole row: 2.9 ms for 16 x 32,768): the query gathers its k
-  rows and attends them, k rows whatever the context holds. One query a row:
-  a decode step.
 - **a mask** ``[b, q, T]``, from the k-th largest score found by bisection on
   the scores' bits (32 passes of compare-and-count, no sort): the queries
   attend all T keys under it as dense products. Several queries a row: a
   prefill block, where 18 MFLOP a key and 128 queries on the MXU are cheaper
-  than a sort a query and a gather of 128 x 2,048 rows of 1,280 B (XLA:TPU
-  moves 11 ns a row; PERF.md section 6, PR 31).
+  than a gather of 128 x 2,048 rows of 1,280 B (XLA:TPU moves 11 ns a row;
+  PERF.md section 6, PR 31).
+- **positions** ``(idx [b, 1, k], valid)``, ascending: the same mask, its set
+  bits packed into k positions (``mask_positions``: counts in tiles of 128
+  lanes, a prefix over the tiles, a one-hot product that fetches each
+  position's tile; no sort, no gather, no scatter). The query gathers its k
+  rows and attends them, k rows whatever the context holds. One query a row:
+  a decode step. Threshold and packing take 0.16 ms for 16 x 32,768 scores
+  (a top-k primitive at k = 2,048 is a sort of the whole row on a TPU: 2.9 ms;
+  PERF.md section 6, PR 32).
 
 Queries are taken ``ATTEND_BLOCK`` at a time, so that a prefill block's scores
 ``[q, hI, T]`` and logits ``[H, q, T]`` stay under a gigabyte and a half.
@@ -50,8 +54,8 @@ from jax import lax
 from deeplearning4j_tpu.models import mla
 
 __all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
-           "index_scores", "select", "kth_largest_mask", "selected_positions",
-           "attend_selected"]
+           "index_scores", "select", "kth_largest_mask", "mask_positions",
+           "selected_positions", "attend_selected"]
 
 # queries whose scores and attention logits are alive at once: at GLM-5.2's
 # sizes against 28,672 keys, [128, 32, T] float32 index scores are 470 MB
@@ -127,13 +131,15 @@ def index_scores(iq, iw, keys):
     return jnp.where(s == 0, 0.0, s)
 
 
-def kth_largest_mask(scores, k: int):
+def kth_largest_mask(scores, k: int, unroll=None):
     """``[..., T]`` bool: the k largest of each row of ``scores`` (float32;
     -inf marks what may not be chosen and is never set), equal scores to the
     lower index; a row with fewer than k finite scores has them all. No
     sort: the k-th largest value is found one bit at a time, 32 counts of
     the row against a candidate, on the scores' bits in an order-preserving
-    unsigned form."""
+    unsigned form. ``unroll``: ``lax.fori_loop``'s (a decode step's one
+    query a row has the 32 counts in a line: 0.06 ms less for 16 x 32,768
+    scores than 32 trips of a loop on a TPU)."""
     bits = lax.bitcast_convert_type(scores, jnp.int32)
     keys = jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31))
     keys = lax.bitcast_convert_type(keys, jnp.uint32)   # larger = larger score
@@ -143,7 +149,8 @@ def kth_largest_mask(scores, k: int):
         enough = jnp.sum(keys >= cand[..., None], axis=-1) >= k
         return jnp.where(enough, cand, kth)
 
-    kth = lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32),
+                        unroll=unroll)
     above = keys > kth[..., None]
     ties = (keys == kth[..., None])
     room = k - jnp.sum(above, axis=-1, keepdims=True)
@@ -151,16 +158,52 @@ def kth_largest_mask(scores, k: int):
     return (above | ties) & (scores > -jnp.inf)
 
 
+LANES = 128     # a tile of ``mask_positions``: the lanes of a vector register
+
+
+def mask_positions(mask, k: int):
+    """``(idx [..., k] int32, valid [..., k] bool)``: the positions of the
+    set bits of each row of ``mask`` [..., T] in ascending order, the first
+    k of them; ``valid`` is false from the row's count on, and ``idx`` there
+    is some position below T. No sort, no gather, no scatter, no loop over
+    the keys: the row in tiles of 128 lanes, the set bits counted along each
+    tile (a product with a triangle of ones: counts to 128 are exact in
+    bfloat16) and over the tiles before it; output r lies in the tile whose
+    running total first passes r (a compare against every tile's), that
+    tile's running counts come by a one-hot product, and its lane is where
+    they first pass what r has left."""
+    t = mask.shape[-1]
+    n = -(-t // LANES)
+    tiles = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, n * LANES - t)])
+    tiles = tiles.reshape(mask.shape[:-1] + (n, LANES)).astype(jnp.bfloat16)
+    running = jnp.einsum(
+        "...nl,lm->...nm", tiles,
+        jnp.triu(jnp.ones((LANES, LANES), tiles.dtype)),
+        preferred_element_type=jnp.float32).astype(tiles.dtype)
+    count = running[..., -1].astype(jnp.int32)                # [..., n]
+    upto = jnp.cumsum(count, axis=-1)           # set bits to a tile's end
+    r = jnp.arange(k, dtype=jnp.int32)
+    passed = upto[..., None, :] <= r[:, None]                 # [..., k, n]
+    tile = jnp.sum(passed, axis=-1, dtype=jnp.int32)
+    left = r - jnp.sum(jnp.where(passed, count[..., None, :], 0), axis=-1)
+    mine = jnp.einsum(
+        "...kn,...nl->...kl", (tile[..., None] == jnp.arange(n)).astype(
+            running.dtype), running, preferred_element_type=jnp.float32)
+    lane = jnp.sum(mine <= left[..., None].astype(jnp.float32), axis=-1,
+                   dtype=jnp.int32)
+    return (jnp.minimum(tile * LANES + lane, t - 1), r < upto[..., -1:])
+
+
 def select(iq, iw, keys, q_pos, topk: int):
     """The selection ``S_t`` of each query, the positions ``s <=
     q_pos[b, q]`` of ``keys`` [b, T, dI] with the k = min(topk, T) largest
     index scores, in one of two forms (the module's docstring):
 
-    - ``(idx [b, q, k] int32, valid [b, q, k] bool)``, best first; a query
-      with fewer than k positions behind it selects them all, and ``valid``
-      is false on the rest of its row (whose ``idx`` are then positions it
-      may not see). One query a row.
-    - ``mask [b, q, T]`` bool. Several queries a row."""
+    - ``mask [b, q, T]`` bool. Several queries a row.
+    - ``(idx [b, q, k] int32, valid [b, q, k] bool)``, the mask's positions
+      in ascending order; a query with fewer than k positions behind it
+      selects them all, and ``valid`` is false on the rest of its row
+      (whose ``idx`` are then positions it may not see). One query a row."""
     t = keys.shape[1]
     k = min(int(topk), t)
     as_mask = iq.shape[1] > 1
@@ -171,23 +214,20 @@ def select(iq, iw, keys, q_pos, topk: int):
                            -jnp.inf)
         if as_mask:
             return kth_largest_mask(scores, k)
-        best, idx = lax.top_k(scores, k)
-        return idx.astype(jnp.int32), best > -jnp.inf
+        return mask_positions(kth_largest_mask(scores, k, unroll=True), k)
 
     with jax.named_scope("dsa.index"):
         return _by_query_blocks(block, iq, iw, q_pos)
 
 
 def selected_positions(selection, k: int):
-    """A selection of either form as positions ``[b, q, k]`` int32, -1 where
-    a query selected fewer than k (order: best first from the positions
-    form, any from a mask). For a record of it: a mask's costs a sort a
-    query."""
-    if isinstance(selection, tuple):
-        idx, valid = selection
-        return jnp.where(valid, idx, -1)
-    found, idx = lax.top_k(selection.astype(jnp.int32), k)
-    return jnp.where(found > 0, idx.astype(jnp.int32), -1)
+    """A selection of either form as positions ``[b, q, k]`` int32 in
+    ascending order, -1 where a query selected fewer than k. For a record of
+    it: a mask is packed as ``select`` packs one (``mask_positions``)."""
+    if not isinstance(selection, tuple):
+        selection = mask_positions(selection, k)
+    idx, valid = selection
+    return jnp.where(valid, idx, -1)
 
 
 def attend_selected(q_nope, q_rope, rows, selection, p, *, dims,

@@ -147,12 +147,12 @@ def test_forward_is_the_reference(t):
 @pytest.mark.parametrize("form", ["positions", "mask"])
 def test_the_selection_is_exact_and_ties_go_to_the_lower_position(
         form, monkeypatch):
-    """``dsa.select`` against a sort, in both its forms (positions from
-    ``lax.top_k``: one query a row; a mask from the k-th largest score's
-    bisection: several): the k best positions s <= t; a query with fewer
-    than k behind it selects them all; equal scores (exact zeros where
-    every head's relu is shut, -0.0 where its weight is negative) go to the
-    lower position, as the reference's stable sort gives them."""
+    """``dsa.select`` against a sort, in both its forms (a mask from the
+    k-th largest score's bisection: several queries a row; that mask packed
+    into ascending positions: one): the k best positions s <= t; a query
+    with fewer than k behind it selects them all; equal scores (exact zeros
+    where every head's relu is shut, -0.0 where its weight is negative) go to
+    the lower position, as the reference's stable sort gives them."""
     rng = np.random.default_rng(0)
     iq = jnp.asarray(rng.normal(size=(2, 12, 4, 16)), jnp.float32)
     keys = jnp.asarray(rng.normal(size=(2, 12, 16)), jnp.float32)
@@ -176,7 +176,7 @@ def test_the_selection_is_exact_and_ties_go_to_the_lower_position(
             have = [x for x in got[b, t].tolist() if x >= 0]
             assert sorted(have) == sorted(want.tolist())
             if form == "positions":
-                assert have == want.tolist()        # best first
+                assert have == sorted(have)         # ascending
     assert sorted(got[0, 10].tolist()) == [0, 1, 2, 3, 4]
 
 
@@ -197,6 +197,91 @@ def test_kth_largest_mask_is_a_sort(k):
         want[order] = True
         want &= row > -np.inf
         np.testing.assert_array_equal(mask, want)
+
+
+def _bits(case, t, k):
+    """A row of T bits for ``test_mask_positions_is_flatnonzero``."""
+    rng = np.random.default_rng(t + k)
+    row = np.zeros(t, bool)
+    if case == "some":                  # about k / 2 of them
+        row[rng.choice(t, max(1, min(t, k) // 2), replace=False)] = True
+    elif case == "more":                # more than k: the first k count
+        row[rng.choice(t, min(t, 2 * k), replace=False)] = True
+    elif case == "all":
+        row[:] = True
+    elif case == "run":                 # k in a run across a tile's edge
+        row[t // 2 - 3:t // 2 - 3 + k] = True
+    elif case == "spread":              # k, evenly
+        row[::max(1, t // k)][:k] = True
+    elif case == "ends":                # the first and the last position
+        row[[0, t - 1]] = True
+    return row                          # "none": no bit
+
+
+@pytest.mark.parametrize("t, k", [(12, 1), (12, 5), (100, 5), (128, 64),
+                                  (129, 64), (1000, 64), (4096, 2048),
+                                  (32768, 2048)])
+@pytest.mark.parametrize("case", ["some", "more", "all", "none", "run",
+                                  "spread", "ends"])
+def test_mask_positions_is_flatnonzero(case, t, k):
+    """The packing against ``np.flatnonzero``: the first k set bits of a row
+    in ascending order, ``valid`` false from the row's count on and every
+    ``idx`` a position of the row; rows with fewer than k bits, none, all, T
+    no multiple of the tile, k bits in one run and spread evenly. Two rows
+    at once: the case's and its reverse."""
+    rows = np.stack([_bits(case, t, k), _bits(case, t, k)[::-1]])
+    idx, valid = dsa.mask_positions(jnp.asarray(rows)[:, None], k)
+    assert idx.shape == valid.shape == (2, 1, k) and idx.dtype == jnp.int32
+    idx, valid = np.asarray(idx)[:, 0], np.asarray(valid)[:, 0]
+    assert ((idx >= 0) & (idx < t)).all()
+    for row, i, v in zip(rows, idx, valid):
+        want = np.flatnonzero(row)[:k]
+        assert v.tolist() == [True] * len(want) + [False] * (k - len(want))
+        assert i[v].tolist() == want.tolist()
+
+
+def _sorts(jaxpr, found=None):
+    """Every ``sort`` / ``top_k`` equation of a jaxpr and of the jaxprs inside
+    it: (primitive, the length of the axis it orders, its name stack)."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if "sort" in name or "top_k" in name:
+            axis = eqn.params.get("dimension", -1)
+            found.append((name, eqn.invars[0].aval.shape[axis],
+                          str(eqn.source_info.name_stack)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _sorts(sub, found)
+    return found
+
+
+def test_no_program_of_the_model_sorts_its_keys():
+    """Neither the decode step nor a prefill block holds a ``sort`` or a
+    ``top_k`` over the cached keys, under ``dsa.index`` or outside it (the
+    record of a block's last selection): the selection is a bisection and a
+    packing. What the programs do order is the router's scores, over the
+    experts and their groups (at most 32 here, 48 keys a slot), under
+    ``moe.route``."""
+    lm = _lm()
+    engine = eng.DecodeEngine(lm, 2, max_len=48, buckets=(16,))
+    sample = eng._row_sampler(0.0, None)
+    state = engine.cache.state
+    decode = jax.make_jaxpr(lambda *a: eng._serve_decode_impl(
+        lm, sample, *a))(
+            lm.params, state, jnp.zeros((2,), jnp.int32),
+            jnp.array([20, 30], jnp.int32),
+            jax.random.split(jax.random.PRNGKey(0), 2))
+    carry = {name: jnp.full(shape, fill, jnp.dtype(dt)) for name, (
+        shape, dt, fill) in eng.prefill_carry_layout(lm, 16).items()}
+    prefill = jax.make_jaxpr(lambda *a: eng._serve_prefill_block_impl(
+        lm, sample, *a))(
+            lm.params, state, carry, jnp.zeros((1, 16), jnp.int32),
+            jnp.int32(9), jnp.int32(0), jax.random.PRNGKey(0), jnp.int32(0))
+    for program in (decode, prefill):
+        sorts = _sorts(program.jaxpr)
+        assert sorts, "the router's top_k went: is this the model's program?"
+        for name, axis, scope in sorts:
+            assert "moe.route" in scope and axis <= E, (name, axis, scope)
 
 
 def test_a_shared_layer_attends_the_preceding_full_layers_set():

@@ -52,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.models import mla
+from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
            "index_scores", "select", "kth_largest_mask", "mask_positions",
@@ -90,7 +91,7 @@ def index_project(x, c_q, p, *, dims: Dict[str, int], rope, layernorm,
     ``layernorm(a, g, b)`` are the model's."""
     b, t, _ = x.shape
     h, d, rd = dims["n_heads"], dims["head_dim"], dims["rope_dim"]
-    with jax.named_scope("dsa.index"):
+    with scope("dsa.index"):
         q = (c_q @ cast(p["wq"])).reshape(b, t, h, d)
         q = jnp.concatenate([rope(q[..., :rd]), q[..., rd:]], axis=-1)
         k = layernorm(x @ cast(p["wk"]), p["k_norm"]["g"], p["k_norm"]["b"])
@@ -216,7 +217,7 @@ def select(iq, iw, keys, q_pos, topk: int):
             return kth_largest_mask(scores, k)
         return mask_positions(kth_largest_mask(scores, k, unroll=True), k)
 
-    with jax.named_scope("dsa.index"):
+    with scope("dsa.index"):
         return _by_query_blocks(block, iq, iw, q_pos)
 
 
@@ -242,7 +243,7 @@ def attend_selected(q_nope, q_rope, rows, selection, p, *, dims,
 
     def gathered(q_nope, q_rope, idx, valid):
         n, k = idx.shape[1], idx.shape[2]
-        with jax.named_scope("mla.attend"):
+        with scope("mla.attend"):
             picked = rows[jnp.arange(b)[:, None, None], idx]  # [b, n, k, W]
         o = mla.attend_latent(
             q_nope.reshape((b * n, 1) + q_nope.shape[2:]),

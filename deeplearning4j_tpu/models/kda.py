@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.scopes import scope
+
 __all__ = ["CHUNK", "init_kda", "kda_mixer", "kda_scan", "kda_step"]
 
 CHUNK = 64
@@ -189,7 +191,7 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
     b, t, _ = x.shape
     h = num_heads
     f32 = jnp.float32
-    with jax.named_scope("kda.proj"):
+    with scope("kda.proj"):
         qkv = jnp.concatenate(
             [x @ cast(p[n]) for n in ("wq", "wk", "wv")], axis=-1)
         width = p["conv_q"].shape[0]
@@ -222,14 +224,14 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
         if s0 is None:
             s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
     if state is not None and t == 1:
-        with jax.named_scope("kda.step"):
+        with scope("kda.step"):
             o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                             s0)
             o = o[:, None]
     else:
-        with jax.named_scope("kda.scan"):
+        with scope("kda.scan"):
             o, s = kda_scan(q, k, v, g, beta, s0)
-    with jax.named_scope("kda.proj"):
+    with scope("kda.proj"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + _EPS) \
             * p["o_norm"]["g"].astype(f32)
         o = (o * gate[..., None]).astype(x.dtype).reshape(b, t, -1)

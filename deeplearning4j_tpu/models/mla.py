@@ -35,6 +35,8 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.scopes import scope
+
 __all__ = ["init_mla", "compress_query", "mla_project", "attend_full",
            "attend_latent", "mla_output"]
 
@@ -77,7 +79,7 @@ def compress_query(x, p, *, rmsnorm, cast: Callable = lambda w: w):
     from; ``None`` for parameters without query compression."""
     if "wq_a" not in p:
         return None
-    with jax.named_scope("mla.proj"):
+    with scope("mla.proj"):
         return rmsnorm(x @ cast(p["wq_a"]), p["q_norm"]["g"])
 
 
@@ -92,7 +94,7 @@ def mla_project(x, p, *, num_heads: int, dims: Dict[str, int], rope,
     b, t, _ = x.shape
     r, dn, dr = (dims[n] for n in ("kv_lora_rank", "qk_nope_head_dim",
                                    "qk_rope_head_dim"))
-    with jax.named_scope("mla.proj"):
+    with scope("mla.proj"):
         q = (x @ cast(p["wq"]) if c_q is None else c_q @ cast(p["wq_b"]))
         q = q.reshape(b, t, num_heads, dn + dr)
         q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
@@ -112,13 +114,13 @@ def attend_full(q_nope, q_rope, latent, p, *, dims, attention,
     ``o`` [b, t, H, dv]."""
     b, t, h, dn = q_nope.shape
     r, dv = dims["kv_lora_rank"], dims["v_head_dim"]
-    with jax.named_scope("mla.proj"):
+    with scope("mla.proj"):
         up = (latent[..., :r] @ cast(p["wukv"])).reshape(b, t, h, dn + dv)
         k_r = jnp.broadcast_to(latent[:, :, None, r:],
                                (b, t, h, latent.shape[-1] - r))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([up[..., :dn], k_r.astype(up.dtype)], axis=-1)
-    with jax.named_scope("mla.attend"):
+    with scope("mla.attend"):
         return attention(q, k, up[..., dn:], q.shape[-1] ** -0.5)
 
 
@@ -133,7 +135,7 @@ def attend_latent(q_nope, q_rope, rows, mask, p, *, dims,
     minor axis would be a copy of the cache."""
     r, dn = dims["kv_lora_rank"], q_nope.shape[-1]
     h = q_nope.shape[2]
-    with jax.named_scope("mla.attend"):
+    with scope("mla.attend"):
         w = cast(p["wukv"]).reshape(r, h, -1)
         wuk, wuv = w[..., :dn], w[..., dn:]
         q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, wuk)
@@ -155,7 +157,7 @@ def mla_output(o, gate, p, cast: Callable = lambda w: w):
     """``o`` [b, t, H, dv] gated a head (``gate`` None: no gate) and
     projected back to ``D``."""
     b, t = o.shape[:2]
-    with jax.named_scope("mla.proj"):
+    with scope("mla.proj"):
         if gate is not None:
             o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         return o.reshape(b, t, -1) @ cast(p["wo"])

@@ -70,6 +70,7 @@ from jax import lax
 
 from deeplearning4j_tpu.pallas import reached_experts as reached_kernel
 from deeplearning4j_tpu.pallas.flash_attention import flash_default_interpret
+from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["DENSE_MAX_TOKENS", "REACHED_MAX_PAIRS_PER_EXPERT",
            "init_experts", "route", "routed_ffn"]
@@ -271,7 +272,7 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
     held = p["w_gate"].shape[0]
     if live is None:
         live = jnp.ones((n,), bool)
-    with jax.named_scope("moe.route"):
+    with scope("moe.route"):
         weights, experts = route(x, p["router"], experts_per_token,
                                  norm_topk_prob, bias=p.get("bias"),
                                  groups=groups)
@@ -281,7 +282,7 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
             live[:, None, None]
             & (local[..., None] == jnp.arange(held)),
             axis=(0, 1), dtype=jnp.int32)
-    with jax.named_scope("moe.experts"):
+    with scope("moe.experts"):
         stored = (p["w_gate"], p["w_up"], p["w_down"])
         blocks = _reached_blocks(x, experts, stored[0], p["router"].shape[1],
                                  train)
@@ -299,7 +300,7 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
         else:
             y = _grouped_experts(x, weights, local, live, *map(cast, stored))
     if "shared" in p:
-        with jax.named_scope("moe.shared"):
+        with scope("moe.shared"):
             sh = p["shared"]
             hidden = (jax.nn.silu(x @ cast(sh["w_gate"]))
                       * (x @ cast(sh["w_up"])))

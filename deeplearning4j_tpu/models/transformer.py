@@ -46,6 +46,7 @@ from deeplearning4j_tpu.parallel.mesh import (
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention
 from deeplearning4j_tpu.pallas.flash_attention import (
     flash_attention, flash_default_interpret)
+from deeplearning4j_tpu.scopes import scope
 
 logger = logging.getLogger(__name__)
 
@@ -458,21 +459,27 @@ class TransformerLM:
                 y, k, v = self._mla(blk["mla"], x, attention, positions,
                                     train, indexer, selection)
             return self._ffn(blk, h + y, live, moe_info, train), k, v
-        q = x @ policy.cast_compute(blk["attn"]["wq"])
-        if self.qk_norm:
-            q = _rmsnorm(q, blk["attn"]["q_norm"]["g"])
-        q = q.reshape(b, t, self.num_heads, -1)
-        k = x @ policy.cast_compute(blk["attn"]["wk"])
-        if self.qk_norm:
-            k = _rmsnorm(k, blk["attn"]["k_norm"]["g"])
-        k = k.reshape(b, t, self.num_kv_heads, -1)
-        v = (x @ policy.cast_compute(blk["attn"]["wv"])).reshape(
-            b, t, self.num_kv_heads, -1)
-        if self.pos_encoding == "rope":
-            if positions is None:
-                positions = jnp.arange(t)
-            q = _rope(q, positions, self.rope_theta, self.rope_interleaved)
-            k = _rope(k, positions, self.rope_theta, self.rope_interleaved)
+        # ``attn.proj`` is closed wherever the attention core is called and
+        # opened again for the output projection: a scope open round a
+        # Pallas kernel would rename it in the trace (``scopes.py``)
+        with scope("attn.proj"):
+            q = x @ policy.cast_compute(blk["attn"]["wq"])
+            if self.qk_norm:
+                q = _rmsnorm(q, blk["attn"]["q_norm"]["g"])
+            q = q.reshape(b, t, self.num_heads, -1)
+            k = x @ policy.cast_compute(blk["attn"]["wk"])
+            if self.qk_norm:
+                k = _rmsnorm(k, blk["attn"]["k_norm"]["g"])
+            k = k.reshape(b, t, self.num_kv_heads, -1)
+            v = (x @ policy.cast_compute(blk["attn"]["wv"])).reshape(
+                b, t, self.num_kv_heads, -1)
+            if self.pos_encoding == "rope":
+                if positions is None:
+                    positions = jnp.arange(t)
+                q = _rope(q, positions, self.rope_theta,
+                          self.rope_interleaved)
+                k = _rope(k, positions, self.rope_theta,
+                          self.rope_interleaved)
         # the returned k/v stay at num_kv_heads (what the KV cache
         # stores); attention sees them repeated per query-head group
         if attention is not None:
@@ -499,7 +506,9 @@ class TransformerLM:
             # when H == Hkv)
             o = grouped_query_attention(q, k, v, causal=True,
                                         window=self.attn_window)
-        h = h + o.reshape(b, t, -1) @ policy.cast_compute(blk["attn"]["wo"])
+        with scope("attn.proj"):
+            h = h + o.reshape(b, t, -1) @ policy.cast_compute(
+                blk["attn"]["wo"])
         return self._ffn(blk, h, live, moe_info, train), k, v
 
     def _ffn(self, blk, h, live, moe_info, train=False):
@@ -522,15 +531,16 @@ class TransformerLM:
             if moe_info is not None:
                 moe_info.append(info)
             return h + y.reshape(b, t, -1)
-        if "glu" in blk:
-            g = blk["glu"]
-            x = (jax.nn.silu(x @ policy.cast_compute(g["w1"]))
-                 * (x @ policy.cast_compute(g["w3"])))
-            return h + x @ policy.cast_compute(g["w2"])
-        x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
-                        + policy.cast_compute(blk["mlp"]["b1"]))
-        return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
-                + policy.cast_compute(blk["mlp"]["b2"]))
+        with scope("ffn.dense"):
+            if "glu" in blk:
+                g = blk["glu"]
+                x = (jax.nn.silu(x @ policy.cast_compute(g["w1"]))
+                     * (x @ policy.cast_compute(g["w3"])))
+                return h + x @ policy.cast_compute(g["w2"])
+            x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
+                            + policy.cast_compute(blk["mlp"]["b1"]))
+            return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
+                    + policy.cast_compute(blk["mlp"]["b2"]))
 
     def _mla(self, p, x, attention, positions, train, indexer=None,
              selection=None):
@@ -625,7 +635,8 @@ class TransformerLM:
         """[b, t, Hkv, d] → [b, t, H, d] by repeating each kv head over
         its query-head group (no-op when H == Hkv)."""
         rep = self.num_heads // self.num_kv_heads
-        return x if rep == 1 else jnp.repeat(x, rep, axis=2)
+        with scope("attn.proj"):
+            return x if rep == 1 else jnp.repeat(x, rep, axis=2)
 
     def forward(self, params, tokens, *, mesh: Optional[Mesh] = None,
                 sequence_parallel: bool = False, train: bool = False,
@@ -643,10 +654,11 @@ class TransformerLM:
             raise ValueError("scan_layers needs every layer the same block")
         policy = self.policy
         b, t = tokens.shape
-        h = jnp.take(params["embed"], tokens, axis=0)
-        if self.pos_encoding == "learned":
-            h = h + params["pos"][:t][None]
-        h = policy.cast_compute(h)
+        with scope("lm.embed"):
+            h = jnp.take(params["embed"], tokens, axis=0)
+            if self.pos_encoding == "learned":
+                h = h + params["pos"][:t][None]
+            h = policy.cast_compute(h)
 
         def block_fn(blk, h, selection=None):
             return self._block(blk, h, mesh=mesh,
@@ -674,7 +686,9 @@ class TransformerLM:
                 h, _, left = block_fn(blk, h, selection)
                 if self.dsa and "mla" in blk:
                     selection = left
-        return policy.cast_output(self._unembed(params, h))
+        logits = self._unembed(params, h)
+        with scope("lm.head"):
+            return policy.cast_output(logits)
 
     @traced
     def loss(self, params, tokens, *, mesh=None, sequence_parallel=False,
@@ -683,11 +697,12 @@ class TransformerLM:
         logits = self.forward(params, tokens, mesh=mesh,
                               sequence_parallel=sequence_parallel,
                               train=train)
-        targets = tokens[:, 1:]
-        logits = logits[:, :-1]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll)
+        with scope("lm.head"):
+            targets = tokens[:, 1:]
+            logits = logits[:, :-1]
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.mean(nll)
 
     # ------------------------------------------------------------------
     @traced
@@ -704,13 +719,15 @@ class TransformerLM:
         b1, b2, eps = 0.9, 0.999, 1e-8
 
         def step(params, opt_state, tokens, step_count):
-            fwd_params = self.policy.compute_copy(params)
+            with scope("opt.cast"):
+                fwd_params = self.policy.compute_copy(params)
             loss, grads = jax.value_and_grad(
                 lambda p: self.loss(p, tokens, mesh=mesh,
                                     sequence_parallel=sequence_parallel,
                                     train=True)
             )(fwd_params)
-            grads = self.policy.master_grads(grads)
+            with scope("opt.cast"):
+                grads = self.policy.master_grads(grads)
             t = step_count.astype(jnp.float32) + 1.0
 
             def upd(p, g, s):
@@ -724,7 +741,9 @@ class TransformerLM:
             flat_p, treedef = jax.tree_util.tree_flatten(params)
             flat_s = treedef.flatten_up_to(opt_state)
             flat_g = treedef.flatten_up_to(grads)
-            out = [upd(p, g, s) for p, g, s in zip(flat_p, flat_g, flat_s)]
+            with scope("opt.update"):
+                out = [upd(p, g, s)
+                       for p, g, s in zip(flat_p, flat_g, flat_s)]
             new_params = jax.tree_util.tree_unflatten(treedef, [o[0] for o in out])
             new_state = jax.tree_util.tree_unflatten(treedef, [o[1] for o in out])
             return new_params, new_state, loss
@@ -855,12 +874,13 @@ class TransformerLM:
         accumulation — one of the largest matmuls in the step, so a
         plain f32 matmul here would cost MXU rate."""
         policy = self.policy
-        hf = self._norm(h, params["ln_f"])
-        head = params["embed" if self.tie_embeddings else "head"]
-        return lax.dot_general(
-            policy.cast_compute(hf), policy.cast_compute(head),
-            (((hf.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with scope("lm.head"):
+            hf = self._norm(h, params["ln_f"])
+            head = params["embed" if self.tie_embeddings else "head"]
+            return lax.dot_general(
+                policy.cast_compute(hf), policy.cast_compute(head),
+                (((hf.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     def _prefill(self, params, prompt, max_new_tokens: int):
         """One parallel forward over the prompt capturing per-layer K/V.
@@ -875,10 +895,11 @@ class TransformerLM:
         policy = self.policy
         cdt = policy.compute_dtype
         prompt_len = prompt.shape[1]
-        h = jnp.take(params["embed"], prompt, axis=0)
-        if self.pos_encoding == "learned":
-            h = h + params["pos"][:prompt_len][None]
-        h = policy.cast_compute(h)
+        with scope("lm.embed"):
+            h = jnp.take(params["embed"], prompt, axis=0)
+            if self.pos_encoding == "learned":
+                h = h + params["pos"][:prompt_len][None]
+            h = policy.cast_compute(h)
         cache = []
         pad_t = ((0, 0), (0, max_new_tokens), (0, 0), (0, 0))
         for blk in params["blocks"]:
@@ -894,10 +915,11 @@ class TransformerLM:
         policy = self.policy
         cdt = policy.compute_dtype
         B = tok.shape[0]
-        h = jnp.take(params["embed"], tok, axis=0)
-        if self.pos_encoding == "learned":
-            h = h + params["pos"][t]
-        h = policy.cast_compute(h)[:, None, :]              # [B, 1, D]
+        with scope("lm.embed"):
+            h = jnp.take(params["embed"], tok, axis=0)
+            if self.pos_encoding == "learned":
+                h = h + params["pos"][t]
+            h = policy.cast_compute(h)[:, None, :]          # [B, 1, D]
         live = jnp.arange(total) <= t                       # [total]
         if self.attn_window is not None:
             live &= jnp.arange(total) > t - self.attn_window

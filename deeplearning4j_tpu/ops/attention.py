@@ -21,6 +21,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.scopes import scope
+
 NEG_INF = -1e30
 
 
@@ -79,23 +81,24 @@ def dot_product_attention(
         raise ValueError("window requires causal=True and window >= 1")
     d = q.shape[-1]
     scale = scale if scale is not None else float(1.0 / np.sqrt(d))
-    # bf16 inputs feed the MXU; logits accumulate in f32
-    # (preferred_element_type) so the softmax runs at full precision
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if bias is not None:
-        logits = logits + bias
-    if causal:
-        logits = jnp.where(causal_band_mask(q.shape[1], k.shape[1],
-                                            window=window),
-                           logits, NEG_INF)
-    if mask is not None:
-        logits = jnp.where(_lift_mask(mask, 4), logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1)
-    # cast probabilities back to the value dtype: the PV contraction runs
-    # on the MXU at the bf16 rate with f32 accumulation
-    return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(v.dtype)
+    with scope("attn.core"):
+        # bf16 inputs feed the MXU; logits accumulate in f32
+        # (preferred_element_type) so the softmax runs at full precision
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if bias is not None:
+            logits = logits + bias
+        if causal:
+            logits = jnp.where(causal_band_mask(q.shape[1], k.shape[1],
+                                                window=window),
+                               logits, NEG_INF)
+        if mask is not None:
+            logits = jnp.where(_lift_mask(mask, 4), logits, NEG_INF)
+        weights = jax.nn.softmax(logits, axis=-1)
+        # cast probabilities back to the value dtype: the PV contraction
+        # runs on the MXU at the bf16 rate with f32 accumulation
+        return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(v.dtype)
 
 
 def grouped_query_attention(
@@ -125,18 +128,20 @@ def grouped_query_attention(
                          f"heads {hkv}")
     rep = H // hkv
     scale = scale if scale is not None else float(1.0 / np.sqrt(d))
-    qg = q.reshape(b, tq, hkv, rep, d)
-    logits = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        logits = jnp.where(causal_band_mask(tq, k.shape[1], window=window),
-                           logits, NEG_INF)
-    if mask is not None:
-        logits = jnp.where(_lift_mask(mask, 5), logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bhrqk,bkhd->bqhrd", weights.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32).astype(v.dtype)
-    return o.reshape(b, tq, H, d)
+    with scope("attn.core"):
+        qg = q.reshape(b, tq, hkv, rep, d)
+        logits = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k,
+                            preferred_element_type=jnp.float32) * scale
+        if causal:
+            logits = jnp.where(
+                causal_band_mask(tq, k.shape[1], window=window),
+                logits, NEG_INF)
+        if mask is not None:
+            logits = jnp.where(_lift_mask(mask, 5), logits, NEG_INF)
+        weights = jax.nn.softmax(logits, axis=-1)
+        o = jnp.einsum("bhrqk,bkhd->bqhrd", weights.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32).astype(v.dtype)
+        return o.reshape(b, tq, H, d)
 
 
 def multi_head_attention(

@@ -59,6 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.pallas.flash_attention import _LANES, MASK_VALUE
+from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["pool_decode_attention", "pool_block_rows", "key_block_span"]
 
@@ -225,11 +226,22 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
             "does not fit the decode kernel's blocks")
     m = nq * h
     m_pad = -(-m // 16) * 16            # whole bf16 sublane tiles
-    positions = positions.astype(jnp.int32)
-    qf = jnp.pad(q.reshape(s_, m, dh), ((0, 0), (0, m_pad - m), (0, 0)))
-    # per product row: the kv head its query head reads
-    qhead = jnp.pad(jnp.tile(jnp.arange(h, dtype=jnp.int32) // (h // hkv),
-                             nq), (0, m_pad - m))[:, None]
+    # the operands' layout and the work list are XLA ops of the pool's side
+    # of the step (``kv.write``); the scope closes before the kernel, which
+    # keeps the name the trace's readers know (``scopes.py``)
+    with scope("kv.write"):
+        positions = positions.astype(jnp.int32)
+        qf = jnp.pad(q.reshape(s_, m, dh),
+                     ((0, 0), (0, m_pad - m), (0, 0)))
+        # per product row: the kv head its query head reads
+        qhead = jnp.pad(jnp.tile(
+            jnp.arange(h, dtype=jnp.int32) // (h // hkv), nq),
+            (0, m_pad - m))[:, None]
+        operands = (*_work_list(positions, live, block=block, hkv=hkv,
+                                window=window, t_max=t_max),
+                    positions.reshape(-1), qf, qhead,
+                    pool_k.reshape(n_layers, s_, t_max * hkv, dh),
+                    pool_v.reshape(n_layers, s_, t_max * hkv, dh))
 
     kernel = functools.partial(
         _kernel, layer=layer, scale=float(1.0 / (dh ** 0.5)), block=block,
@@ -253,8 +265,6 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
             ]),
         out_shape=jax.ShapeDtypeStruct((s_, m_pad, dh), q.dtype),
         interpret=interpret,
-    )(*_work_list(positions, live, block=block, hkv=hkv, window=window,
-                  t_max=t_max), positions.reshape(-1), qf, qhead,
-      pool_k.reshape(n_layers, s_, t_max * hkv, dh),
-      pool_v.reshape(n_layers, s_, t_max * hkv, dh))
-    return out[:, :m].reshape(s_, nq, h, dh)
+    )(*operands)
+    with scope("kv.write"):
+        return out[:, :m].reshape(s_, nq, h, dh)

@@ -1,0 +1,68 @@
+"""The scope vocabulary: every ``jax.named_scope`` the model and serving code
+opens, listed once.
+
+A scope is metadata on the lowered program: it reaches a device trace as the
+``tf_op`` stat of XLA's own ops (``jit(step)/transpose(jvp(ffn.dense))/
+dot_general``) and changes nothing the compiler sees, so there is nothing to
+switch off. The readers under ``benchmarks/layer_metrics/`` sum device time
+by these names; an op whose ``tf_op`` holds none of them is what
+``unscoped_ms_per_step`` / ``unscoped_ms_per_decode_step`` report.
+
+The one thing a scope does rename is a Mosaic custom call lowered inside it:
+the kernel's instruction takes the name stack as its name
+(``moe.experts...mosaic``; the flash kernels are ``jvp__`` /
+``transpose_jvp___`` because nothing is open round them). So **no scope of
+this list is open where ``attention(q, k, v)``, ``flash_attention``,
+``ring_attention`` or ``grouped_query_attention`` is called from the block,
+nor round the decode-attention kernel's ``pallas_call``**: those kernels
+keep the names the accepted readers know (``tests/test_scopes.py`` holds
+this). ``attn.core`` is opened inside the XLA attention op itself
+(``ops/attention.py``), which holds no kernel. The routed experts' kernel
+sits under ``moe.experts`` since PR 30 and is read by that name.
+
+Norms between the halves of a block stay bare on purpose: XLA fuses the next
+norm's statistics into the fusion that ends the previous matmul, and which op
+of a multi-output fusion gives it its ``tf_op`` is XLA's choice (PERF.md
+section 5 says where each straddling fusion landed on the chip).
+"""
+
+from __future__ import annotations
+
+import jax
+
+# name -> what is under it (docs/observability.md has the table with the
+# metrics that read each)
+SCOPES = {
+    "lm.embed": "token gather, learned positions, cast into the compute dtype",
+    "attn.proj": "wq/wk/wv, QK-norm, head reshapes, RoPE, the kv-head repeat; "
+                 "reopened for wo and its residual add",
+    "attn.core": "the XLA attention op (scores, mask, softmax, values) where "
+                 "no kernel applies",
+    "kv.write": "a decode-family step's new rows into the slot pool or a "
+                "latent cache, and the decode kernel's work list and operand "
+                "layout (not the kernel)",
+    "ffn.dense": "the dense feed-forward: glu or mlp matmuls, activation, "
+                 "biases, residual add",
+    "lm.head": "final norm, unembedding, output cast; the loss's log-softmax "
+               "and mean in training, the sampler in serving",
+    "opt.cast": "the step's compute-dtype copy of the parameters and the "
+                "gradients' cast back to the master dtype",
+    "opt.update": "the per-leaf Adam update",
+    "moe.route": "router logits, top-k, the load per expert",
+    "moe.experts": "the routed experts (dense, sorted or reached form)",
+    "moe.shared": "the shared expert",
+    "kda.proj": "KDA projections, convolutions, gates, output",
+    "kda.step": "KDA's one-position recurrence (decode)",
+    "kda.scan": "KDA's chunked recurrence (prefill, training)",
+    "mla.proj": "MLA query/latent projections, norms, RoPE, output",
+    "mla.attend": "MLA attention over latent rows or expanded keys",
+    "dsa.index": "the lightning indexer: index keys, scores, selection",
+}
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of the vocabulary."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is no scope of scopes.py: "
+                         f"{sorted(SCOPES)}")
+    return jax.named_scope(name)

@@ -88,6 +88,7 @@ from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.pallas.flash_attention import flash_default_interpret
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
+from deeplearning4j_tpu.scopes import scope
 from deeplearning4j_tpu.serving.kv_cache import (
     SlotKVCache, advance_loop, dequant_slab, slot_admit, write_pool_rows)
 
@@ -163,10 +164,11 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     policy = model.policy
     cdt = policy.compute_dtype
     p = prompt.shape[1]
-    h = jnp.take(params["embed"], prompt, axis=0)
-    if model.pos_encoding == "learned":
-        h = h + params["pos"][:p][None]
-    h = policy.cast_compute(h)
+    with scope("lm.embed"):
+        h = jnp.take(params["embed"], prompt, axis=0)
+        if model.pos_encoding == "learned":
+            h = h + params["pos"][:p][None]
+        h = policy.cast_compute(h)
     ks, vs = [], []
     left = {"latent": [], "kda": [], "conv": []}    # the other layer kinds
     # the pad tail holds no token: it takes no part in routed experts and
@@ -223,7 +225,9 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
             v=lax.dynamic_update_slice(
                 kv["v"], vcat.astype(kv["v"].dtype), (0, slot, 0, 0, 0)))
     h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
-    tok, key = sample_row(model._unembed(params, h_last), key)
+    logits = model._unembed(params, h_last)
+    with scope("lm.head"):
+        tok, key = sample_row(logits, key)
     if model.num_experts:
         return tok, key, new_kv, _stack_routing(moe_info)
     return tok, key, new_kv
@@ -306,12 +310,13 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
 
     def layer(li):
         def attn(q, kk, vv):
-            for name, new in (("k", kk), ("v", vv)):
-                pool[name], scale = write_pool_rows(
-                    pool[name], pool.get(name + "_scale"), li, new, rows,
-                    positions)
-                if scale is not None:
-                    pool[name + "_scale"] = scale
+            with scope("kv.write"):
+                for name, new in (("k", kk), ("v", vv)):
+                    pool[name], scale = write_pool_rows(
+                        pool[name], pool.get(name + "_scale"), li, new, rows,
+                        positions)
+                    if scale is not None:
+                        pool[name + "_scale"] = scale
             if block is not None:
                 return kernel.pool_decode_attention(
                     q, pool["k"], pool["v"], li, positions, window=window,
@@ -374,8 +379,9 @@ def _latent_attention(model, pool, positions, slot=None, keys=None):
 
     def layer(j, p):
         def attn(q_nope, q_rope, latent, selection=None):
-            cache = _write_rows(pool["latent"][j], latent, rows, positions,
-                                slot)
+            with scope("kv.write"):
+                cache = _write_rows(pool["latent"][j], latent, rows,
+                                    positions, slot)
             pool["latent"][j] = cache
             view = _slot_rows(cache, slot, keys)
             if selection is not None:
@@ -396,7 +402,6 @@ def _index_selection(model, pool, positions, slot=None, keys=None):
     (``[S, T_max, dI]``) as the latent rows do, then each query scores its
     slot's keys ``<= positions[s, i]`` and selects (``models/dsa.select``).
     ``slot`` and ``keys`` as ``_latent_attention``'s."""
-    import jax
     import jax.numpy as jnp
     from deeplearning4j_tpu.models import dsa
 
@@ -404,7 +409,7 @@ def _index_selection(model, pool, positions, slot=None, keys=None):
 
     def layer(j):
         def indexer(iq, ik, iw):
-            with jax.named_scope("dsa.index"):
+            with scope("dsa.index"):
                 cache = _write_rows(pool["index"][j], ik, rows, positions,
                                     slot)
             pool["index"][j] = cache
@@ -534,7 +539,8 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     start = i * c
     positions = (start + jnp.arange(c))[None]                   # [1, c]
     toks = lax.dynamic_slice(prompt, (0, start), (1, c))
-    h = policy.cast_compute(jnp.take(params["embed"], toks, axis=0))
+    with scope("lm.embed"):
+        h = policy.cast_compute(jnp.take(params["embed"], toks, axis=0))
     live = positions < prompt_len
     moe_info: list = []
     selections, selection = [], None
@@ -569,7 +575,9 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
              lax.bitcast_convert_type(new["weights"], jnp.int32).reshape(
                  n_moe, -1), new["read"][:, None]], axis=1)
     new = {**carry, **new}
-    tok, key = sample_row(model._unembed(params, new["h_last"]), key)
+    logits = model._unembed(params, new["h_last"])
+    with scope("lm.head"):
+        tok, key = sample_row(logits, key)
     return tok, key, pool, new, routing, new["sel_last"]
 
 
@@ -601,10 +609,11 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     (``_latent_layers``); ``selections`` receives each such layer's."""
     import jax.numpy as jnp
 
-    h = jnp.take(params["embed"], tok, axis=0)             # [S, D]
-    if model.pos_encoding == "learned":
-        h = h + params["pos"][positions]
-    h = model.policy.cast_compute(h)[:, None, :]           # [S, 1, D]
+    with scope("lm.embed"):
+        h = jnp.take(params["embed"], tok, axis=0)         # [S, D]
+        if model.pos_encoding == "learned":
+            h = h + params["pos"][positions]
+        h = model.policy.cast_compute(h)[:, None, :]       # [S, 1, D]
     new_kv = {k: list(v) if isinstance(v, list) else v
               for k, v in kv.items()}
     cached_attention = _pool_attention(
@@ -653,7 +662,8 @@ def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
                                        pool_kernel=pool_kernel, live=live,
                                        moe_info=moe_info,
                                        selections=selections)
-    toks, keys = jax.vmap(sample_row)(logits, keys)
+    with scope("lm.head"):
+        toks, keys = jax.vmap(sample_row)(logits, keys)
     routing = _stack_routing(moe_info) if model.num_experts else None
     if model.dsa:
         k = min(model.dsa["topk"], new_kv["latent"][0].shape[1])
@@ -717,10 +727,11 @@ def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
     keys only. Returns ``(logits [S, Q, V], new_kv)``."""
     import jax.numpy as jnp
 
-    h = jnp.take(params["embed"], toks, axis=0)            # [S, Q, D]
-    if model.pos_encoding == "learned":
-        h = h + params["pos"][positions]
-    h = model.policy.cast_compute(h)
+    with scope("lm.embed"):
+        h = jnp.take(params["embed"], toks, axis=0)        # [S, Q, D]
+        if model.pos_encoding == "learned":
+            h = h + params["pos"][positions]
+        h = model.policy.cast_compute(h)
     new_kv = dict(kv)
     cached_attention = _pool_attention(model, new_kv, positions, pool_kernel,
                                        live)

@@ -35,7 +35,12 @@ the shapes at trace time, and the attention over ``S_t`` (the absorbed form of
   rows and attends them, k rows whatever the context holds. One query a row:
   a decode step. Threshold and packing take 0.16 ms for 16 x 32,768 scores
   (a top-k primitive at k = 2,048 is a sort of the whole row on a TPU: 2.9 ms;
-  PERF.md section 6, PR 32).
+  PERF.md section 6, PR 32). Its two reads of a cache follow a **work list
+  of the live rows** (``live_slots``): a loop takes one row a trip, scores
+  its index keys or gathers its k latent rows, and has as many trips as rows
+  owe a token, so a slot that holds no request costs neither 8 MB of index
+  keys nor 2,048 rows at 11 ns each (PERF.md section 6, PR 34). Threshold,
+  packing and the attention over the gathered rows stay one op over all rows.
 
 Queries are taken ``ATTEND_BLOCK`` at a time, so that a prefill block's scores
 ``[q, hI, T]`` and logits ``[H, q, T]`` stay under a gigabyte and a half.
@@ -49,14 +54,15 @@ from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning4j_tpu.models import mla
 from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
-           "index_scores", "select", "kth_largest_mask", "mask_positions",
-           "selected_positions", "attend_selected"]
+           "index_scores", "live_slots", "select", "kth_largest_mask",
+           "mask_positions", "selected_positions", "attend_selected"]
 
 # queries whose scores and attention logits are alive at once: at GLM-5.2's
 # sizes against 28,672 keys, [128, 32, T] float32 index scores are 470 MB
@@ -119,6 +125,22 @@ def _by_query_blocks(fn, *args):
 
     out = lax.map(lambda xs: fn(*xs), tuple(split(a) for a in args))
     return jax.tree_util.tree_map(join, out)
+
+
+def live_slots(live, slots: int):
+    """The work list of the one-query form's reads: ``(order [slots] int32,
+    count)``, the rows that owe a token (``live`` [slots] bool; None: all of
+    them) first, in ascending order, and how many they are; ``order`` from
+    ``count`` on is ``slots``, no row. Plain arithmetic over numpy or jax
+    values: ``select`` and ``attend_selected`` loop over it, and the
+    server's host counter (``rows_gathered``) counts with it."""
+    if live is None:
+        live = np.ones(slots, bool)
+    xp = jnp if isinstance(live, jax.Array) else np
+    ends = xp.cumsum(xp.asarray(live, dtype=xp.int32))
+    order = xp.sum(xp.arange(slots)[:, None] >= ends[None, :], axis=1,
+                   dtype=xp.int32)
+    return order, ends[-1]
 
 
 def index_scores(iq, iw, keys):
@@ -204,20 +226,37 @@ def select(iq, iw, keys, q_pos, topk: int):
     - ``(idx [b, q, k] int32, valid [b, q, k] bool)``, the mask's positions
       in ascending order; a query with fewer than k positions behind it
       selects them all, and ``valid`` is false on the rest of its row
-      (whose ``idx`` are then positions it may not see). One query a row."""
-    t = keys.shape[1]
+      (whose ``idx`` are then positions it may not see). One query a row.
+      A row whose query stands before every key (``q_pos < 0``: a slot that
+      owes no token, ``serving/engine._index_selection``) has nothing to
+      select and its keys are not read: the scores are taken a row a trip
+      of a loop over the others (``live_slots``)."""
+    b, t = keys.shape[:2]
     k = min(int(topk), t)
-    as_mask = iq.shape[1] > 1
+
+    def behind(scores, q_pos):
+        return jnp.where(jnp.arange(t) <= q_pos[..., None], scores, -jnp.inf)
 
     def block(iq, iw, q_pos):
-        scores = index_scores(iq, iw, keys)
-        scores = jnp.where(jnp.arange(t) <= q_pos[..., None], scores,
-                           -jnp.inf)
-        if as_mask:
-            return kth_largest_mask(scores, k)
-        return mask_positions(kth_largest_mask(scores, k, unroll=True), k)
+        return kth_largest_mask(behind(index_scores(iq, iw, keys), q_pos), k)
+
+    def one(iq, iw, q_pos):
+        order, count = live_slots(q_pos[:, 0] >= 0, b)
+
+        def trip(i, scores):
+            s = order[i]
+            return lax.dynamic_update_slice_in_dim(scores, index_scores(*(
+                lax.dynamic_slice_in_dim(a, s, 1) for a in (iq, iw, keys))),
+                s, 0)
+
+        scores = lax.fori_loop(0, count, trip,
+                               jnp.full((b, 1, t), -jnp.inf, jnp.float32))
+        return mask_positions(
+            kth_largest_mask(behind(scores, q_pos), k, unroll=True), k)
 
     with scope("dsa.index"):
+        if iq.shape[1] == 1:
+            return one(iq, iw, q_pos)
         return _by_query_blocks(block, iq, iw, q_pos)
 
 
@@ -232,19 +271,31 @@ def selected_positions(selection, k: int):
 
 
 def attend_selected(q_nope, q_rope, rows, selection, p, *, dims,
-                    cast: Callable = lambda w: w):
+                    cast: Callable = lambda w: w, live=None):
     """Latent attention of each query over its own selected rows:
     ``q_nope`` [b, q, H, dn], ``q_rope`` [b, q, H, dr], ``rows`` [b, T, >=
     r + dr] (cached latent rows), ``selection`` = ``select``'s, in either
     form. Returns ``o`` [b, q, H, dv]. From positions: ``mla.attend_latent``
     with every query a batch row of its own, its keys the k rows gathered
-    for it. From a mask: ``mla.attend_latent`` over all T rows under it."""
+    for it — gathered from ``rows`` itself a row of the batch a trip, for
+    the rows that owe a token (``live`` [b] bool; None: all; ``live_slots``);
+    the others attend zeros and no row of theirs is fetched. From a mask:
+    ``mla.attend_latent`` over all T rows under it."""
     b = rows.shape[0]
 
     def gathered(q_nope, q_rope, idx, valid):
         n, k = idx.shape[1], idx.shape[2]
+        order, count = live_slots(live, b)
+
+        def trip(i, picked):
+            s = jnp.asarray(order)[i]
+            at = lax.dynamic_index_in_dim(idx, s, keepdims=False)   # [n, k]
+            return lax.dynamic_update_slice_in_dim(
+                picked, rows[s, at][None], s, 0)
+
         with scope("mla.attend"):
-            picked = rows[jnp.arange(b)[:, None, None], idx]  # [b, n, k, W]
+            picked = lax.fori_loop(0, count, trip, jnp.zeros(
+                (b, n, k, rows.shape[-1]), rows.dtype))
         o = mla.attend_latent(
             q_nope.reshape((b * n, 1) + q_nope.shape[2:]),
             q_rope.reshape((b * n, 1) + q_rope.shape[2:]),

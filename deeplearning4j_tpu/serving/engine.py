@@ -360,7 +360,8 @@ def _slot_rows(cache, slot, keys):
     return lax.dynamic_slice(cache, (slot, 0, 0), (1, keys, cache.shape[2]))
 
 
-def _latent_attention(model, pool, positions, slot=None, keys=None):
+def _latent_attention(model, pool, positions, slot=None, keys=None,
+                      live=None):
     """``(j, p) -> attention(q_nope, q_rope, latent[, selection])`` for a
     decode-family forward of an ``mla`` layer (the model's j-th, parameters
     ``p``) over its cached latent rows: the new rows land at ``positions
@@ -369,8 +370,11 @@ def _latent_attention(model, pool, positions, slot=None, keys=None):
     query ``(s, i)`` attends slot ``s``'s rows ``<= positions[s, i]`` in the
     absorbed form (``models/mla.attend_latent``) — or, handed a
     ``selection`` (``models/dsa.select``'s), the rows it names and no
-    others (``dsa.attend_selected``). ``slot`` and ``keys``: the forward is
-    a prefill block of that one slot (``_write_rows``, ``_slot_rows``)."""
+    others (``dsa.attend_selected``), which are fetched for the slots that
+    owe a token alone (``live [S]``, bool; None: all of them — the work list
+    of ``dsa.live_slots``); the others' outputs are finite and read by
+    nobody. ``slot`` and ``keys``: the forward is a prefill block of that one
+    slot (``_write_rows``, ``_slot_rows``)."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.models import dsa, mla
 
@@ -386,7 +390,8 @@ def _latent_attention(model, pool, positions, slot=None, keys=None):
             view = _slot_rows(cache, slot, keys)
             if selection is not None:
                 return dsa.attend_selected(q_nope, q_rope, view, selection,
-                                           p, dims=model.mla, cast=cast)
+                                           p, dims=model.mla, cast=cast,
+                                           live=live)
             mask = jnp.arange(view.shape[1]) <= positions[:, :, None]
             return mla.attend_latent(q_nope, q_rope, view, mask, p,
                                      dims=model.mla, cast=cast)
@@ -395,13 +400,17 @@ def _latent_attention(model, pool, positions, slot=None, keys=None):
     return layer
 
 
-def _index_selection(model, pool, positions, slot=None, keys=None):
+def _index_selection(model, pool, positions, slot=None, keys=None,
+                     live=None):
     """``j -> indexer(q^I, k^I, w)`` for a decode-family forward of the
     model's j-th layer with a lightning indexer, over its cached index
     keys: the new keys land at ``positions [S, Q]`` of ``pool["index"][j]``
     (``[S, T_max, dI]``) as the latent rows do, then each query scores its
     slot's keys ``<= positions[s, i]`` and selects (``models/dsa.select``).
-    ``slot`` and ``keys`` as ``_latent_attention``'s."""
+    A slot that owes no token (``live [S]`` false) queries from position -1:
+    no key lies behind it, so its selection is empty and ``dsa.select``
+    reads none of its keys. ``slot`` and ``keys`` as
+    ``_latent_attention``'s."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.models import dsa
 
@@ -412,20 +421,23 @@ def _index_selection(model, pool, positions, slot=None, keys=None):
             with scope("dsa.index"):
                 cache = _write_rows(pool["index"][j], ik, rows, positions,
                                     slot)
+                q_pos = positions if live is None else jnp.where(
+                    live[:, None], positions, -1)
             pool["index"][j] = cache
             return dsa.select(iq, iw, _slot_rows(cache, slot, keys),
-                              positions, model.dsa["topk"])
+                              q_pos, model.dsa["topk"])
         return indexer
 
     return layer
 
 
-def _latent_layers(model, params, pool, positions, slot=None, keys=None):
+def _latent_layers(model, params, pool, positions, slot=None, keys=None,
+                   live=None):
     """Per block of ``params`` the keywords ``TransformerLM._block`` takes
     for an ``mla`` layer served from the pool (``attention`` and, for a
     layer with an indexer, ``indexer``; None for another kind of layer)."""
-    attention = _latent_attention(model, pool, positions, slot, keys)
-    indexer = _index_selection(model, pool, positions, slot, keys)
+    attention = _latent_attention(model, pool, positions, slot, keys, live)
+    indexer = _index_selection(model, pool, positions, slot, keys, live)
     out, j, jf = [], 0, 0
     for blk in params["blocks"]:
         if "mla" not in blk:
@@ -595,8 +607,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     masked out of nothing (rows are independent) and their pool writes
     land at frozen cursors the admission prefill overwrites. ``live
     [S]`` (bool) names the slots that hold a request: the pool kernel
-    reads none of the others' keys, and they choose no routed expert and
-    count in no load. ``moe_info`` receives each layer's routing
+    reads none of the others' keys, a model with learned sparse attention
+    neither their index keys nor their selected latent rows
+    (``_latent_layers``), and they choose no routed expert and count in no
+    load. ``moe_info`` receives each layer's routing
     (``TransformerLM._block``).
 
     Each layer meets its own kind of state, at its place among the layers
@@ -618,7 +632,8 @@ def _decode_step_body(model, params, kv, tok, positions, *,
               for k, v in kv.items()}
     cached_attention = _pool_attention(
         model, new_kv, positions[:, None], pool_kernel, live)
-    latent = _latent_layers(model, params, new_kv, positions[:, None])
+    latent = _latent_layers(model, params, new_kv, positions[:, None],
+                            live=live)
     seen = {"attn": 0, "mla": 0, "kda": 0}
     selection = None
     for blk, kw in zip(params["blocks"], latent):

@@ -86,7 +86,9 @@ runs):
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
   blocks a layer the pool kernel fetches for the slots dispatched, and
   what a read of every slot's cursor, live or frozen, would fetch —
-  counted from the host's slot table, ``_book_kv_blocks``) — the decode
+  counted from the host's slot table, ``_book_kv_blocks``; for a model with
+  learned sparse attention ``keys_cached``, ``keys_attended`` and
+  ``rows_gathered`` instead) — the decode
   dispatch (``live`` slots; 0: none was owed a token), then the
   read-back of the block dispatched a step earlier (plain) or just now
   (fused, spec). ``experts_touched`` and ``experts_read`` are of the
@@ -110,6 +112,7 @@ from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from deeplearning4j_tpu.models.dsa import live_slots
 from deeplearning4j_tpu.monitor import metrics, tracer
 from deeplearning4j_tpu.pallas.decode_attention import (
     key_block_span, pool_block_rows)
@@ -186,6 +189,7 @@ class DecodeServer:
         # layers; and the blocks the prefill programs ran
         self.keys_cached = 0
         self.keys_attended = 0
+        self.rows_gathered = 0
         self.prefill_blocks = 0
         # {slot: [engine.prefill_blocks generator, its blocks, queue wait in
         # us, blocks run]} of the requests whose prompt is part-way into
@@ -633,20 +637,27 @@ class DecodeServer:
         and two registry counters, and returned as the ``serve.decode``
         span's attrs (none where the pool has no kernel read, or nothing
         is dispatched). For a model with learned sparse attention the
-        attrs are ``keys_cached`` and ``keys_attended`` instead: the latent
-        rows the live slots hold up to their cursors and the rows their
-        queries attend, over the 'mla' layers. No device read."""
+        attrs are ``keys_cached``, ``keys_attended`` and ``rows_gathered``
+        instead: the latent rows the live slots hold up to their cursors,
+        the rows their queries attend, and the rows the step's gathers fetch
+        (``index_topk`` a trip of the program's own work list,
+        ``dsa.live_slots``), over the 'mla' layers. No device read."""
         attrs = {}
         if live and self.model.dsa:
             # a query at cursor c has c + 1 rows behind it (its own among
             # them) in every 'mla' layer and attends min(c + 1, topk)
             layers = len(self.model.layers_of("mla"))
             cached = self._cursors[list(live)] + 1
+            topk = min(self.model.dsa["topk"], self.max_len)
+            owing = np.isin(np.arange(self.slots), list(live))
             attrs = {"keys_cached": int(cached.sum()) * layers,
                      "keys_attended": int(np.minimum(
-                         cached, self.model.dsa["topk"]).sum()) * layers}
+                         cached, topk).sum()) * layers,
+                     "rows_gathered": int(live_slots(owing, self.slots)[1])
+                     * topk * layers}
             self.keys_cached += attrs["keys_cached"]
             self.keys_attended += attrs["keys_attended"]
+            self.rows_gathered += attrs["rows_gathered"]
         if live and self._kv_block is not None:
             _, _, t_max, hkv, _ = self.engine.cache.k.shape
             lo, hi = key_block_span(
@@ -956,6 +967,7 @@ class DecodeServer:
         if self.model.dsa:
             out["keys_cached"] = self.keys_cached
             out["keys_attended"] = self.keys_attended
+            out["rows_gathered"] = self.rows_gathered
             out["keys_attended_share"] = (
                 round(self.keys_attended / self.keys_cached, 4)
                 if self.keys_cached else None)

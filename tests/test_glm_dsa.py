@@ -240,19 +240,21 @@ def test_mask_positions_is_flatnonzero(case, t, k):
         assert i[v].tolist() == want.tolist()
 
 
-def _sorts(jaxpr, found=None):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _sorts(jaxpr):
     """Every ``sort`` / ``top_k`` equation of a jaxpr and of the jaxprs inside
     it: (primitive, the length of the axis it orders, its name stack)."""
-    found = [] if found is None else found
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if "sort" in name or "top_k" in name:
-            axis = eqn.params.get("dimension", -1)
-            found.append((name, eqn.invars[0].aval.shape[axis],
-                          str(eqn.source_info.name_stack)))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _sorts(sub, found)
-    return found
+    return [(eqn.primitive.name,
+             eqn.invars[0].aval.shape[eqn.params.get("dimension", -1)],
+             str(eqn.source_info.name_stack)) for eqn in _eqns(jaxpr)
+            if "sort" in eqn.primitive.name or "top_k" in eqn.primitive.name]
 
 
 def test_no_program_of_the_model_sorts_its_keys():
@@ -666,6 +668,153 @@ def test_pool_bytes_count_the_index_keys():
     assert cache.nbytes == sum(want.values()) \
         == kv_pool_nbytes(lm, slots, t, "bfloat16")
     assert [a.shape for a in cache.index] == [(slots, t, 16)] * 2
+
+
+# ---- (d2) a decode step reads the slots that owe a token ---------------------
+LIVE = {"none": None, "one": [False, False, True, False],
+        "some": [True, False, True, False], "all": [True] * 4,
+        "nobody": [False] * 4}
+
+
+@pytest.mark.parametrize("xp", ["numpy", "jax"])
+@pytest.mark.parametrize("case", list(LIVE))
+def test_live_slots_is_the_live_slots_first_and_their_count(case, xp):
+    """The work list of the one-query form's reads: the slots that owe a
+    token in ascending order, then no slot (``slots``); their count; all of
+    them for ``live=None``. numpy on the host (the server's
+    ``rows_gathered``), jax values in a program, the same arithmetic."""
+    live = LIVE[case]
+    want = list(range(4)) if live is None else np.flatnonzero(live).tolist()
+    if live is not None:
+        live = (np if xp == "numpy" else jnp).asarray(live)
+    order, count = dsa.live_slots(live, 4)
+    if xp == "jax" and live is not None:
+        assert isinstance(order, jax.Array)
+        order, count = jax.jit(lambda m: dsa.live_slots(m, 4))(live)
+    assert int(count) == len(want)
+    assert np.asarray(order).tolist() == want + [4] * (4 - len(want))
+    assert np.asarray(order).dtype == np.int32
+
+
+def _pool_of_noise(lm, slots, t_max, seed=0):
+    """A pool whose every row holds something: what a dead slot leaves
+    behind must not matter, and a row that moved shows."""
+    state = SlotKVCache(lm, slots, t_max, "float32").state
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+    return {name: [jax.random.normal(next(keys), a.shape, a.dtype)
+                   for a in arrays] for name, arrays in state.items()}
+
+
+def _decode_step(lm, kv, tok, positions, live):
+    selections = []
+    logits, new = eng._decode_step_body(
+        lm, lm.params, kv, tok, positions, live=live, selections=selections)
+    k = min(TOPK, kv["latent"][0].shape[1])
+    return logits, new, eng._stack_selection(selections, k)[:, :, 0]
+
+
+@pytest.mark.parametrize("context", ["below_topk", "above_topk"])
+@pytest.mark.parametrize("case", ["none", "one", "some", "all"])
+def test_a_decode_step_over_the_live_slots_is_the_step_over_all(case,
+                                                                context):
+    """``_decode_step_body`` with a live mask against the same step with
+    none (every slot scored and gathered): the live slots select the same
+    positions, exactly, and their logits are the same; the others' logits
+    are finite and their record is empty; both forms write the cursor's
+    row in every slot and layer, the same for a live slot, and no other row
+    of the pool moves. Cursors below ``index_topk`` (a query selects all it has) and
+    above it."""
+    lm = _lm()
+    slots, t_max = 4, 48
+    kv = _pool_of_noise(lm, slots, t_max)
+    positions = jnp.asarray([3, 6, 0, 5] if context == "below_topk"
+                            else [20, 37, 9, 47], jnp.int32)
+    tok = jnp.asarray(_tokens(slots, seed=5))
+    live = None if LIVE[case] is None else jnp.asarray(LIVE[case])
+    step = jax.jit(lambda kv, live: _decode_step(lm, kv, tok, positions,
+                                                 live))
+    want_logits, want_kv, want_sel = step(kv, None)
+    logits, new, sel = step(kv, live)
+    owing = np.ones(slots, bool) if live is None else np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(sel)[:, owing],
+                                  np.asarray(want_sel)[:, owing])
+    np.testing.assert_allclose(np.asarray(logits)[owing],
+                               np.asarray(want_logits)[owing], atol=TOL)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert (np.asarray(sel)[:, ~owing] == -1).all()
+    # a live slot's record names min(cursor + 1, topk) positions behind it
+    for s in np.flatnonzero(owing):
+        named = np.asarray(sel)[:, s]
+        assert ((named >= 0).sum(-1) == min(int(positions[s]) + 1,
+                                            TOPK)).all()
+        assert (named <= int(positions[s])).all()
+    at = (np.arange(slots), np.asarray(positions))
+    for name in ("latent", "index"):
+        for before, a, b in zip(kv[name], new[name], want_kv[name]):
+            before, a, b = (np.array(x) for x in (before, a, b))
+            # (a slot that owes nothing writes what nobody reads)
+            np.testing.assert_allclose(a[at][owing], b[at][owing], atol=TOL)
+            assert (a[at] != before[at]).any(-1).all()    # the cursor's row
+            a[at] = before[at]
+            np.testing.assert_array_equal(a, before)      # and no other
+
+
+def test_the_decode_program_gathers_a_slot_at_a_time():
+    """No gather of the decode program fetches latent rows for every slot
+    at once: the selected rows come ``index_topk`` a trip of a loop over the
+    live slots, one such gather a layer, and the program holds one ``while``
+    for each of them and for each layer that scores."""
+    lm = _lm()
+    slots = 3
+    engine = eng.DecodeEngine(lm, slots, max_len=48, buckets=(16,))
+    sample = eng._row_sampler(0.0, None)
+    program = jax.make_jaxpr(lambda *a: eng._serve_decode_loop_impl(
+        lm, sample, *a))(lm.params, engine.cache.state, engine.cache.loop)
+    width = engine.cache.latent[0].shape[-1]
+    rows = [eqn.outvars[0].aval.shape for eqn in _eqns(program.jaxpr)
+            if eqn.primitive.name == "gather"
+            and eqn.outvars[0].aval.shape[-1] == width]
+    assert rows == [(1, TOPK, width)] * 5
+    text = str(program)
+    assert text.count("while[") == 5 + 2
+
+
+@pytest.mark.parametrize("fuse_steps", [2, 4])
+def test_the_fused_program_is_the_plain_loop(fuse_steps, small_blocks):
+    """Requests that start and end at different steps, so that the live
+    mask changes between the steps of one fused program (a slot that owes
+    nothing more freezes mid-scan) and a freed slot is taken again: the
+    fused-K program emits the plain loop's tokens."""
+    lm = _lm()
+    lengths = [(21, 9), (5, 3), (12, 14), (9, 6), (30, 5)]
+    _, plain = _served(lm, lengths, fuse_steps=1)
+    _, fused = _served(lm, lengths, fuse_steps=fuse_steps)
+    assert [r.tokens for r in fused] == [r.tokens for r in plain]
+
+
+def test_rows_gathered_counts_the_work_list(small_blocks):
+    """``serve.decode`` carries ``rows_gathered`` = the live slots x
+    ``index_topk`` x the five 'mla' layers, whatever their cursors (a slot
+    below ``index_topk`` attends fewer rows than its trip gathers);
+    ``stats()`` carries the total."""
+    lm = _lm()
+    spans = []
+    program_trace.add_sink(spans.append)
+    try:
+        server, reqs = _served(lm, [(4, 6), (21, 3), (13, 9)], slots=3)
+    finally:
+        program_trace.remove_sink(spans.append)
+    decode = [s["attrs"] for s in spans if s["name"] == "serve.decode"
+              and s["attrs"]["live"]]
+    assert {d["live"] for d in decode} >= {1, 2}
+    for d in decode:
+        assert d["rows_gathered"] == d["live"] * TOPK * 5
+        assert d["keys_attended"] <= d["rows_gathered"]
+    # the 4-token prompt's first steps have fewer than 8 rows behind them
+    assert any(d["keys_attended"] < d["rows_gathered"] for d in decode)
+    st = server.stats()
+    assert st["rows_gathered"] == sum(d["rows_gathered"] for d in decode)
+    assert st["rows_gathered"] == server.slot_dispatches * TOPK * 5
 
 
 # ---- (e) spans and counters --------------------------------------------------

@@ -385,3 +385,31 @@ def test_span_attr_readers():
     assert live_slots_per_step.compute(bare, None, {}, {}) == 4.0
     none = xplane.Trace({}, [xplane.Event("dl4j.train.step", 0.0, 1.0, {})])
     assert live_slots_per_step.compute(none, None, {}, {}) is None
+
+
+@pytest.mark.parametrize("program", ["a work list of live slots",
+                                     "every slot gathered", "before PR 34",
+                                     "no span"])
+def test_rows_gathered_per_attended(program):
+    """``dsa_rows_gathered_per_attended`` sums ``rows_gathered`` over
+    ``keys_attended`` of the ``serve.decode`` spans that carry both: 1 for
+    two slots past ``index_topk`` (8) in 5 layers, more while a cursor is
+    below it, slots / live for a program that gathers for all 4 slots;
+    nothing where the program books no ``rows_gathered``."""
+    from benchmarks.layer_metrics import dsa_rows_gathered_per_attended as m
+
+    def span(**stats):
+        return xplane.Event("dl4j.serve.decode", 0.0, 10.0,
+                            {k: str(v) for k, v in stats.items()})
+
+    host = {"a work list of live slots": [
+                span(live=2, keys_attended=80, rows_gathered=80),
+                span(live=2, keys_attended=60, rows_gathered=80),
+                span(live=0)],
+            "every slot gathered": [
+                span(live=2, keys_attended=80, rows_gathered=160)],
+            "before PR 34": [span(live=2, keys_attended=80, keys_cached=400)],
+            "no span": [xplane.Event("dl4j.train.step", 0.0, 1.0, {})]}
+    want = {"a work list of live slots": 160 / 140, "every slot gathered": 2.0}
+    got = m.compute(xplane.Trace({}, host[program]), None, {}, {})
+    assert got == (pytest.approx(want[program]) if program in want else None)

@@ -64,11 +64,7 @@ __all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
            "index_scores", "live_slots", "select", "kth_largest_mask",
            "mask_positions", "selected_positions", "attend_selected"]
 
-# queries whose scores and attention logits are alive at once: at GLM-5.2's
-# sizes against 28,672 keys, [128, 32, T] float32 index scores are 470 MB
-# and [64, 128, T] float32 logits 940 MB (1.45 GiB of temporaries a block
-# program, compile-only, PERF.md section 4)
-ATTEND_BLOCK = 128
+ATTEND_BLOCK = mla.QUERY_BLOCK     # queries alive at once
 
 
 def init_indexer(key, d_model: int, q_rank: int, dims: Dict[str, int],
@@ -108,23 +104,8 @@ def index_project(x, c_q, p, *, dims: Dict[str, int], rope, layernorm,
 
 
 def _by_query_blocks(fn, *args):
-    """``fn`` on ``args`` ([b, q, ...] each) ``ATTEND_BLOCK`` queries at a
-    time, where q is a larger multiple of it; the results joined on q."""
-    b, q = args[0].shape[:2]
-    if q <= ATTEND_BLOCK or q % ATTEND_BLOCK:
-        return fn(*args)
-    n = q // ATTEND_BLOCK
-
-    def split(a):       # [b, q, ...] -> [n, b, block, ...]
-        return jnp.moveaxis(
-            a.reshape((b, n, ATTEND_BLOCK) + a.shape[2:]), 1, 0)
-
-    def join(a):
-        a = jnp.moveaxis(a, 0, 1)
-        return a.reshape((b, q) + a.shape[3:])
-
-    out = lax.map(lambda xs: fn(*xs), tuple(split(a) for a in args))
-    return jax.tree_util.tree_map(join, out)
+    """``mla.by_query_blocks``, ``ATTEND_BLOCK`` queries at a time."""
+    return mla.by_query_blocks(fn, *args, block=ATTEND_BLOCK)
 
 
 def live_slots(live, slots: int):

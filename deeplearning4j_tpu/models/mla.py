@@ -30,15 +30,52 @@ by all query heads. Scopes ``mla.proj`` and ``mla.attend``.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from deeplearning4j_tpu.scopes import scope
 
-__all__ = ["init_mla", "compress_query", "mla_project", "attend_full",
-           "attend_latent", "mla_output"]
+__all__ = ["QUERY_BLOCK", "init_mla", "compress_query", "mla_project",
+           "attend_full", "attend_latent", "mla_output", "softmax_scale",
+           "by_query_blocks"]
+
+# queries whose scores and attention logits are alive at once: at GLM-5.2's
+# sizes against 28,672 keys, [128, 32, T] float32 index scores are 470 MB
+# and [64, 128, T] float32 logits 940 MB (1.45 GiB of temporaries a block
+# program, compile-only, PERF.md section 4)
+QUERY_BLOCK = 128
+
+
+def softmax_scale(dims: Dict[str, Any]) -> float:
+    """``(dn + dr)^-1/2``, times ``dims["softmax_mult"]`` where the model
+    scales its rotary frequencies (YaRN's ``mscale_all_dim``, squared:
+    ``TransformerLM(rope_scaling=)``)."""
+    return ((dims["qk_nope_head_dim"] + dims["qk_rope_head_dim"]) ** -0.5
+            * float(dims.get("softmax_mult", 1.0)))
+
+
+def by_query_blocks(fn, *args, block: Optional[int] = None):
+    """``fn`` on ``args`` ([b, q, ...] each) ``block`` (default
+    ``QUERY_BLOCK``, read at the call) queries at a time, where q is a larger
+    multiple of it; the results joined on q."""
+    block = block or QUERY_BLOCK
+    b, q = args[0].shape[:2]
+    if q <= block or q % block:
+        return fn(*args)
+    n = q // block
+
+    def split(a):       # [b, q, ...] -> [n, b, block, ...]
+        return jnp.moveaxis(a.reshape((b, n, block) + a.shape[2:]), 1, 0)
+
+    def join(a):
+        a = jnp.moveaxis(a, 0, 1)
+        return a.reshape((b, q) + a.shape[3:])
+
+    out = lax.map(lambda xs: fn(*xs), tuple(split(a) for a in args))
+    return jax.tree_util.tree_map(join, out)
 
 
 def init_mla(key, d_model: int, num_heads: int, dims: Dict[str, int],
@@ -121,7 +158,7 @@ def attend_full(q_nope, q_rope, latent, p, *, dims, attention,
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([up[..., :dn], k_r.astype(up.dtype)], axis=-1)
     with scope("mla.attend"):
-        return attention(q, k, up[..., dn:], q.shape[-1] ** -0.5)
+        return attention(q, k, up[..., dn:], softmax_scale(dims))
 
 
 def attend_latent(q_nope, q_rope, rows, mask, p, *, dims,
@@ -143,9 +180,9 @@ def attend_latent(q_nope, q_rope, rows, mask, p, *, dims,
             [q_lat.astype(rows.dtype), q_rope.astype(rows.dtype)], axis=-1)
         q_cat = jnp.pad(q_cat, ((0, 0),) * 3 + (
             (0, rows.shape[-1] - q_cat.shape[-1]),))
-        scale = (dn + q_rope.shape[-1]) ** -0.5
         logits = jnp.einsum("bqhc,btc->bhqt", q_cat, rows,
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32
+                            ) * softmax_scale(dims)
         logits = jnp.where(mask[:, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
         o_lat = jnp.einsum("bhqt,btr->bqhr", probs.astype(rows.dtype),

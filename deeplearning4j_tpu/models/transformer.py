@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 import os
 from typing import Any, Dict, Optional, Sequence
 
@@ -51,7 +52,45 @@ from deeplearning4j_tpu.scopes import scope
 logger = logging.getLogger(__name__)
 
 
-def _rope(x, positions, base: float = 10000.0, interleaved: bool = False):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1`` (1 for a
+    factor of at most 1 or an ``mscale`` of 0)."""
+    if factor <= 1.0 or not mscale:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(d: int, base: float, scaling: Optional[Dict[str, Any]]):
+    """The ``d / 2`` rotary frequencies ``f_i = base^(-2i/d)`` as a numpy
+    float64 array, scaled by YaRN (arXiv:2309.00071, "NTK-by-parts") where
+    ``scaling`` = ``{factor, original_max_position_embeddings, beta_fast,
+    beta_slow}`` says so: a dimension that turns more than ``beta_fast``
+    times within the original context keeps its frequency, one that turns
+    less than ``beta_slow`` times has it divided by ``factor``, and a linear
+    ramp over the dimension's index joins the two:
+
+        corr(n) = d ln(L / (2 pi n)) / (2 ln base)
+        low, high = floor(corr(beta_fast)), ceil(corr(beta_slow))  in [0, d-1]
+        ramp_i = clip((i - low) / (high - low), 0, 1)
+        f'_i   = f_i (1 - ramp_i) + (f_i / factor) ramp_i"""
+    half = d // 2
+    freqs = np.power(base, -np.arange(half, dtype=np.float64) / half)
+    if not scaling:
+        return freqs
+    length = scaling["original_max_position_embeddings"]
+
+    def corr(turns):
+        return d * math.log(length / (2 * math.pi * turns)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(corr(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(corr(scaling.get("beta_slow", 1))), d - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return freqs * (1 - ramp) + freqs / scaling["factor"] * ramp
+
+
+def _rope(x, positions, base: float = 10000.0, interleaved: bool = False,
+          scaling: Optional[Dict[str, Any]] = None):
     """Rotary position embedding on [b, t, h, d] at absolute ``positions``
     (may be traced): [t] shared across the batch (training/prefill), or
     [b, t] per-row (the serving decode step, where every slot sits at its
@@ -59,21 +98,32 @@ def _rope(x, positions, base: float = 10000.0, interleaved: bool = False):
     applied to q/k BEFORE attention, so it composes unchanged with the
     XLA, Pallas-flash, and ring paths. ``base`` and the pairing are the
     model's (``rope_theta``, ``rope_interleaved``): a pair is dimensions
-    ``(i, i + d/2)``, or ``(2i, 2i + 1)`` when interleaved."""
+    ``(i, i + d/2)``, or ``(2i, 2i + 1)`` when interleaved. ``scaling``
+    (``rope_scaling``): YaRN's frequencies (``rope_frequencies``), and cos
+    and sin times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)``, which is 1 where the two are equal."""
     if interleaved:     # bring each pair to (i, i + d/2), rotate, put back
         shape = x.shape
         halves = jnp.swapaxes(x.reshape(shape[:-1] + (-1, 2)), -1, -2)
-        out = _rope(halves.reshape(shape), positions, base)
+        out = _rope(halves.reshape(shape), positions, base, scaling=scaling)
         return jnp.swapaxes(out.reshape(shape[:-1] + (2, -1)), -1, -2
                             ).reshape(shape)
     d = x.shape[-1]
     half = d // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling:
+        freqs = jnp.asarray(rope_frequencies(d, base, scaling), jnp.float32)
+    else:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions.astype(jnp.float32)[..., None] * freqs
     if positions.ndim == 1:       # [t, half] -> broadcast over batch
         angles = angles[None]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling:
+        amp = yarn_mscale(scaling["factor"], scaling.get("mscale", 1)) / (
+            yarn_mscale(scaling["factor"], scaling.get("mscale_all_dim", 0)))
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     out = jnp.concatenate(
@@ -125,7 +175,9 @@ class TransformerLM:
                  mla: Optional[Dict[str, Any]] = None,
                  moe: Optional[Dict[str, Any]] = None,
                  indexers: Optional[Sequence[Optional[str]]] = None,
-                 dsa: Optional[Dict[str, Any]] = None):
+                 dsa: Optional[Dict[str, Any]] = None,
+                 rope_scaling: Optional[Dict[str, Any]] = None,
+                 mtp: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -169,6 +221,19 @@ class TransformerLM:
         # indexer, selects ``topk`` positions a query and attends them) |
         # "shared" (it attends the selection of the nearest "full" layer
         # before it) | None (it attends every position).
+        # ``rope_scaling`` = {factor, original_max_position_embeddings,
+        # beta_fast, beta_slow, mscale, mscale_all_dim}: YaRN on every RoPE
+        # of the model (``rope_frequencies``); with ``mscale_all_dim`` an
+        # 'mla' layer's softmax scale is multiplied by
+        # ``yarn_mscale(factor, mscale_all_dim)`` squared, as the
+        # ``deepseek_v3`` modelling code does. ``mtp`` = {loss_weight}: the
+        # model has a multi-token-prediction module of depth 1 (DeepSeek-V3,
+        # arXiv:2412.19437 section 2.2; ``mtp_logits``): one more block, of
+        # the last layer's kind, that reads the model's final-normed hidden
+        # state at position i beside the embedding of token i + 1 and
+        # predicts token i + 2 through the model's own embedding and head.
+        # ``loss`` adds its cross entropy times ``loss_weight``, and
+        # ``serving.DecodeServer`` drafts from it (speculative rounds).
         kinds = ("attn", "kda", "mla"), ("mlp", "glu", "moe")
         self.mixers = tuple(mixers) if mixers is not None else (
             "attn",) * num_layers
@@ -207,6 +272,25 @@ class TransformerLM:
         self.dsa = dict(dsa) if dsa else None
         self.kda = dict(kda) if kda else None
         self.mla = dict(mla) if mla else None
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        kind = (rope_scaling or {}).get("rope_type", "yarn")
+        if kind != "yarn" or rope_scaling and pos_encoding != "rope":
+            raise ValueError(f"rope_scaling: rope_type {kind!r} with "
+                             f"pos_encoding={pos_encoding!r}; YaRN over RoPE "
+                             "is the one scaling written")
+        if self.rope_scaling and self.mla:
+            # read by ``models/mla.softmax_scale``; set, not multiplied, so a
+            # model rebuilt from ``get_config`` carries it once
+            self.mla["softmax_mult"] = yarn_mscale(
+                self.rope_scaling["factor"],
+                self.rope_scaling.get("mscale_all_dim", 0)) ** 2
+        self.mtp = dict(mtp) if mtp else None
+        if self.mtp and (pos_encoding != "rope" or self.mixers[-1] != "mla"
+                         or self.indexers[-1]):
+            raise ValueError(
+                "mtp= is written for a model with RoPE whose last layer is "
+                "'mla' without an indexer: the module's block is one more "
+                "layer of that kind")
         self.moe = dict(moe) if moe else None
         self.glu_width = glu_width
         self.rope_theta = float(rope_theta)
@@ -312,8 +396,9 @@ class TransformerLM:
         if not self.tie_embeddings:
             params["head"] = jax.random.normal(
                 jax.random.fold_in(keys[0], 1), (V, D), dt) * 0.02
-        for i in range(self.num_layers):
-            k = keys[2 + 6 * i:2 + 6 * (i + 1)]
+
+        def block(k, i):
+            """Layer ``i``'s block from the six keys ``k``."""
             blk = {"ln1": norm(), "ln2": norm()}
             if self.mixers[i] == "kda":
                 blk["kda"] = kda_mod.init_kda(
@@ -352,7 +437,23 @@ class TransformerLM:
                     "w1": dense(k[4], D, F), "b1": jnp.zeros((F,), dt),
                     "w2": dense(k[5], F, D), "b2": jnp.zeros((D,), dt),
                 }
-            params["blocks"].append(blk)
+            return blk
+
+        params["blocks"] = [block(keys[2 + 6 * i:2 + 6 * (i + 1)], i)
+                            for i in range(self.num_layers)]
+        if self.mtp:
+            # the module (``mtp_logits``): the norms of the token's embedding
+            # and of the hidden state, ``proj`` [2 D, D] (``M``: rows 0..D-1
+            # meet the embedding, the others the hidden state), one more
+            # block of the last layer's kind, the norm before the shared head
+            # (keys[1] is the learned positions' key: a model with a module
+            # has RoPE and never draws from it)
+            km = jax.random.split(jax.random.fold_in(keys[1], 0x3170), 7)
+            params["mtp"] = {
+                "enorm": norm(), "hnorm": norm(),
+                "proj": dense(km[6], 2 * D, D),
+                "block": block(km[:6], self.num_layers - 1),
+                "norm": norm()}
         self.params = params
         self.opt_state = jax.tree_util.tree_map(
             lambda p: {"m": jnp.zeros_like(p), "v": jnp.zeros_like(p)}, params)
@@ -477,9 +578,9 @@ class TransformerLM:
                 if positions is None:
                     positions = jnp.arange(t)
                 q = _rope(q, positions, self.rope_theta,
-                          self.rope_interleaved)
+                          self.rope_interleaved, self.rope_scaling)
                 k = _rope(k, positions, self.rope_theta,
-                          self.rope_interleaved)
+                          self.rope_interleaved, self.rope_scaling)
         # the returned k/v stay at num_kv_heads (what the KV cache
         # stores); attention sees them repeated per query-head group
         if attention is not None:
@@ -560,7 +661,8 @@ class TransformerLM:
             positions = jnp.arange(t)
 
         def rope(a):
-            return _rope(a, positions, self.rope_theta, self.rope_interleaved)
+            return _rope(a, positions, self.rope_theta, self.rope_interleaved,
+                         self.rope_scaling)
 
         def rmsnorm(a, g):
             return _rmsnorm(a, g, self.norm_eps)
@@ -625,6 +727,14 @@ class TransformerLM:
         return [i for i, pair in enumerate(zip(self.mixers, self.ffns))
                 if kind in pair]
 
+    def n_layers(self, kind: str) -> int:
+        """How many blocks of ``kind`` keep state or route here: the layers
+        and, in a model with one, the multi-token-prediction module's block
+        (of the last layer's kind), which comes after them in its kind's
+        state and in a serving program's routing."""
+        last = (self.mixers[-1], self.ffns[-1])
+        return len(self.layers_of(kind)) + bool(self.mtp and kind in last)
+
     @property
     def experts_held(self) -> int:
         """Routed experts a layer holds here (all of them unless ``moe``
@@ -647,6 +757,17 @@ class TransformerLM:
         ``moe_info`` receives each layer's routing (``_block``; rows are
         the b·t tokens; not under ``remat`` or ``scan_layers``, whose
         bodies are traced apart)."""
+        h = self._hidden(params, tokens, mesh=mesh,
+                         sequence_parallel=sequence_parallel, train=train,
+                         moe_info=moe_info)
+        logits = self._unembed(params, h)
+        with scope("lm.head"):
+            return self.policy.cast_output(logits)
+
+    def _hidden(self, params, tokens, *, mesh=None, sequence_parallel=False,
+                train=False, moe_info=None):
+        """``forward`` up to the last block: [b, t, D], before the final
+        norm."""
         if moe_info is not None and (self.remat or self.scan_layers):
             raise ValueError("moe_info needs remat=False, scan_layers=False")
         if self.scan_layers and max(map(len, map(set, (
@@ -686,23 +807,70 @@ class TransformerLM:
                 h, _, left = block_fn(blk, h, selection)
                 if self.dsa and "mla" in blk:
                     selection = left
-        logits = self._unembed(params, h)
-        with scope("lm.head"):
-            return policy.cast_output(logits)
+        return h
+
+    def mtp_input(self, params, g, tokens):
+        """What the module's block reads (DeepSeek-V3 eq. 21): ``h'_i =
+        [rmsnorm_e(Emb(t_{i+1})) ; rmsnorm_h(g_i)] M`` for ``g`` [..., D],
+        the model's hidden states after its final norm, and ``tokens``
+        [...], the tokens one position on. Scopes ``mtp.embed`` and
+        ``mtp.proj``."""
+        policy, m = self.policy, params["mtp"]
+        with scope("mtp.embed"):
+            e = policy.cast_compute(jnp.take(params["embed"], tokens, axis=0))
+        with scope("mtp.proj"):
+            both = jnp.concatenate([self._norm(e, m["enorm"]),
+                                    self._norm(g, m["hnorm"])], axis=-1)
+            return both @ policy.cast_compute(m["proj"])
+
+    def mtp_block(self, params, x, **kw):
+        """The module's own block on ``mtp_input``'s ``x`` [b, t, D], as
+        ``_block`` runs a layer (``kw``: its keywords), every scope inside
+        it under ``mtp``. Returns ``_block``'s triple."""
+        with scope("mtp"):
+            return self._block(params["mtp"]["block"], x, **kw)
+
+    def mtp_logits(self, params, g, tokens, **kw):
+        """The module's logits [b, t, V]: at position i, from ``g[:, i]`` and
+        ``tokens[:, i]`` = token i + 1, the distribution of token i + 2,
+        through the model's own embedding and head."""
+        h, _, _ = self.mtp_block(params, self.mtp_input(params, g, tokens),
+                                 **kw)
+        return self.mtp_head(params, h)
+
+    def mtp_head(self, params, h):
+        """The module's own norm and the model's head on the module's block
+        output ``h`` [..., D] -> float32 logits [..., V]."""
+        with scope("mtp"):
+            return self._unembed(params, h, params["mtp"]["norm"])
 
     @traced
     def loss(self, params, tokens, *, mesh=None, sequence_parallel=False,
              train: bool = False):
-        """Next-token cross entropy (mean over positions)."""
-        logits = self.forward(params, tokens, mesh=mesh,
-                              sequence_parallel=sequence_parallel,
-                              train=train)
-        with scope("lm.head"):
-            targets = tokens[:, 1:]
-            logits = logits[:, :-1]
+        """Next-token cross entropy (mean over positions); with a
+        multi-token-prediction module, plus ``mtp["loss_weight"]`` times the
+        module's cross entropy against the tokens two positions on
+        (DeepSeek-V3 eq. 24-25, depth 1)."""
+        h = self._hidden(params, tokens, mesh=mesh,
+                         sequence_parallel=sequence_parallel, train=train)
+
+        def mean_nll(logits, targets):
             logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-            return jnp.mean(nll)
+            return jnp.mean(-jnp.take_along_axis(
+                logp, targets[..., None], axis=-1))
+
+        logits = self._unembed(params, h)
+        with scope("lm.head"):
+            logits = self.policy.cast_output(logits)
+            targets = tokens[:, 1:]     # the parent's order, op for op
+            total = mean_nll(logits[:, :-1], targets)
+        if self.mtp and tokens.shape[1] > 2:
+            g = self._norm(h, params["ln_f"])[:, :-2]
+            extra = self.mtp_logits(params, g, tokens[:, 1:-1], train=train)
+            with scope("lm.head"):
+                total = total + self.mtp["loss_weight"] * mean_nll(
+                    extra, tokens[:, 2:])
+        return total
 
     # ------------------------------------------------------------------
     @traced
@@ -840,7 +1008,8 @@ class TransformerLM:
             "mixers": list(self.mixers), "ffns": list(self.ffns),
             "glu_width": self.glu_width, "kda": self.kda, "mla": self.mla,
             "moe": self.moe, "indexers": list(self.indexers),
-            "dsa": self.dsa,
+            "dsa": self.dsa, "rope_scaling": self.rope_scaling,
+            "mtp": self.mtp,
         }
 
     def _ensure_init(self):
@@ -867,15 +1036,17 @@ class TransformerLM:
     # ------------------------------------------------------------------
     # autoregressive decoding (KV cache)
     # ------------------------------------------------------------------
-    def _unembed(self, params, h):
-        """Final norm + unembedding (the embedding itself when tied,
+    def _unembed(self, params, h, ln=None):
+        """Final norm (``ln``: another norm's parameters, the
+        multi-token-prediction module's) + unembedding (the embedding
+        itself when tied,
         else the ``head`` leaf) on [..., D] hidden → [..., V] f32 logits.
         The matmul runs with compute-dtype (bf16) operands and f32
         accumulation — one of the largest matmuls in the step, so a
         plain f32 matmul here would cost MXU rate."""
         policy = self.policy
         with scope("lm.head"):
-            hf = self._norm(h, params["ln_f"])
+            hf = self._norm(h, params["ln_f"] if ln is None else ln)
             head = params["embed" if self.tie_embeddings else "head"]
             return lax.dot_general(
                 policy.cast_compute(hf), policy.cast_compute(head),
@@ -1207,6 +1378,9 @@ class TransformerLM:
             specs["pos"] = P()
         if not self.tie_embeddings:
             specs["head"] = embed
+        if self.mtp:
+            specs["mtp"] = {"enorm": norm(), "hnorm": norm(), "proj": P(),
+                            "block": blocks[-1], "norm": norm()}
         return specs
 
     def shard_params(self, mesh: Mesh, specs: Optional[Dict[str, Any]] = None):

@@ -57,6 +57,14 @@ SCOPES = {
     "mla.proj": "MLA query/latent projections, norms, RoPE, output",
     "mla.attend": "MLA attention over latent rows or expanded keys",
     "dsa.index": "the lightning indexer: index keys, scores, selection",
+    "mtp": "the multi-token-prediction module's own block and its pass "
+           "through the shared head; the block's scopes are open inside it "
+           "(mtp/mla.proj, mtp/moe.experts, ...)",
+    "mtp.embed": "the module's token gather: the embedding of the token one "
+                 "position on",
+    "mtp.proj": "the module's two input norms and M, [2 D, D]",
+    "spec.accept": "a speculative round's accept / resample rule, its "
+                   "emitted block and the loop state's advance",
 }
 
 

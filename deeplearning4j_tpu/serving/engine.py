@@ -393,8 +393,12 @@ def _latent_attention(model, pool, positions, slot=None, keys=None,
                                            p, dims=model.mla, cast=cast,
                                            live=live)
             mask = jnp.arange(view.shape[1]) <= positions[:, :, None]
-            return mla.attend_latent(q_nope, q_rope, view, mask, p,
-                                     dims=model.mla, cast=cast)
+            # a prefill block's queries mla.QUERY_BLOCK at a time: 2,048 of
+            # them against 4,096 rows at 64 heads are 2 GiB of scores
+            return mla.by_query_blocks(
+                lambda qn, qr, m: mla.attend_latent(
+                    qn, qr, view, m, p, dims=model.mla, cast=cast),
+                q_nope, q_rope, mask)
         return attn
 
     return layer
@@ -492,8 +496,8 @@ def prefill_carry_layout(model, bucket: int) -> dict:
     prefill hand on beside the pool (``_serve_prefill_block_impl``)."""
     import jax.numpy as jnp
 
-    n_moe, k = len(model.layers_of("moe")), model.experts_per_token
-    topk = min(model.dsa["topk"], bucket)
+    n_moe, k = model.n_layers("moe"), model.experts_per_token
+    topk = min(model.dsa["topk"], bucket) if model.dsa else 0
     return {
         "h_last": ((model.d_model,),
                    jnp.dtype(model.policy.compute_dtype).name, 0),
@@ -524,16 +528,22 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     inert by causality and writes rows beyond the cursor, which the decode
     steps overwrite before any query reads them.
 
+    A model that drafts from its own multi-token-prediction module
+    (``TransformerLM(mtp=)``, no indexer: every query attends the slot's rows
+    up to its own) is prefilled the same way, and its module runs over the
+    block too, into its own layer of latent rows (the last), and proposes the
+    first round's draft.
+
     ``carry`` (``prefill_carry_layout``; block 0 resets it) hands on the
     routing of the blocks so far and, from the block that holds position
     ``prompt_len - 1``, its hidden state and selections. Returns ``(token,
-    key, pool, carry, routing, selection)``, of which the last block's
+    key, pool, carry, routing, selection)`` (for a model with a module
+    ``(token, key, pool, carry, routing, draft)``), of which the last block's
     token, key, routing and selection are the prefill's: ``routing`` as
     ``_stack_routing`` packs it (rows = the P positions, those of blocks
     not run zero; None without routed experts), ``selection`` [layers with
     an indexer, k] int32, the positions that the prompt's last token
     selected (-1: fewer than k)."""
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -567,10 +577,32 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     here = (at >= 0) & (at < c)
     at = jnp.clip(at, 0, c - 1)
     new = {"h_last": jnp.where(here, jnp.take(h[0], at, axis=0),
-                               carry["h_last"]),
-           "sel_last": jnp.where(
-               here, _stack_selection(selections, layout["sel_last"][0][1],
-                                      at), carry["sel_last"])}
+                               carry["h_last"])}
+    if model.dsa:
+        new["sel_last"] = jnp.where(
+            here, _stack_selection(selections, layout["sel_last"][0][1], at),
+            carry["sel_last"])
+    logits = model._unembed(params, new["h_last"])
+    with scope("lm.head"):
+        tok, key = sample_row(logits, key)
+    draft = ()
+    if model.mtp:
+        # the module one position behind: its row at position i reads the
+        # hidden state there and token i + 1, the prompt's next or, at the
+        # prompt's last position, the token just sampled; its argmax there
+        # is the first round's draft
+        nxt = lax.dynamic_slice(jnp.pad(prompt, ((0, 0), (0, 1))),
+                                (0, start + 1), (1, c))
+        nxt = jnp.where(positions == prompt_len - 1, tok, nxt)
+        attention = _latent_attention(model, pool, positions, slot, p)(
+            len(model.layers_of("mla")), params["mtp"]["block"]["mla"])
+        hm, _, _ = model.mtp_block(
+            params, model.mtp_input(params, model._norm(h, params["ln_f"]),
+                                    nxt),
+            positions=positions, live=live, moe_info=moe_info,
+            attention=attention)
+        draft = (jnp.argmax(model.mtp_head(
+            params, jnp.take(hm[0], at, axis=0))).astype(jnp.int32),)
     routing = None
     if moe_info:
         new["load"] = carry["load"] + jnp.stack(
@@ -587,10 +619,9 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
              lax.bitcast_convert_type(new["weights"], jnp.int32).reshape(
                  n_moe, -1), new["read"][:, None]], axis=1)
     new = {**carry, **new}
-    logits = model._unembed(params, new["h_last"])
-    with scope("lm.head"):
-        tok, key = sample_row(logits, key)
-    return tok, key, pool, new, routing, new["sel_last"]
+    if model.dsa:
+        return tok, key, pool, new, routing, new["sel_last"]
+    return (tok, key, pool, new, routing) + draft
 
 
 def _decode_step_body(model, params, kv, tok, positions, *,
@@ -731,15 +762,20 @@ def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv, loop,
 
 @traced
 def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
-                       pool_kernel=None):
+                       pool_kernel=None, moe_info=None, hidden=False):
     """Multi-token target forward for the speculative verify: consume
     ``toks [S, Q]`` at per-row ``positions [S, Q]`` against the slot
-    pool, scatter-writing every candidate's K/V at its position (the
+    pool, scatter-writing every candidate's K/V (an ``mla`` layer's: its
+    latent row, ``_latent_layers``) at its position (the
     accepted prefix becomes permanent; rejected tails sit beyond the
     rewound cursor, masked until overwritten). Per-query masks keep
     causality at ragged per-slot offsets: query q attends pool keys
     ``<= positions[s, q]``; the pool kernel reads the ``live [S]`` slots'
-    keys only. Returns ``(logits [S, Q, V], new_kv)``."""
+    keys only. Returns ``(logits [S, Q, V], new_kv)``; with ``hidden``
+    also the last block's output [S, Q, D], before the final norm. A list
+    passed as ``moe_info`` receives each layer's routing (rows = the S Q
+    candidates), and the rows of a slot that is not ``live`` then choose
+    no routed expert."""
     import jax.numpy as jnp
 
     with scope("lm.embed"):
@@ -747,14 +783,105 @@ def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
         if model.pos_encoding == "learned":
             h = h + params["pos"][positions]
         h = model.policy.cast_compute(h)
-    new_kv = dict(kv)
+    new_kv = {k: list(v) if isinstance(v, list) else v
+              for k, v in kv.items()}
     cached_attention = _pool_attention(model, new_kv, positions, pool_kernel,
                                        live)
-    for li, blk in enumerate(params["blocks"]):
-        h, _, _ = model._block(blk, h, attention=cached_attention(li),
-                               positions=positions)
+    rows = (None if live is None or moe_info is None
+            else jnp.broadcast_to(live[:, None], positions.shape))
+    li = 0
+    for blk, kw in zip(params["blocks"], _latent_layers(
+            model, params, new_kv, positions, live=live)):
+        if kw is None:
+            kw = {"attention": cached_attention(li)}
+            li += 1
+        h, _, _ = model._block(blk, h, positions=positions, live=rows,
+                               moe_info=moe_info, **kw)
     logits = model._unembed(params, h)                     # [S, Q, V]
-    return logits, new_kv
+    return (logits, new_kv, h) if hidden else (logits, new_kv)
+
+
+def _accept_round(act, logits, d, q, keys, gamma, greedy, sample_filtered):
+    """A speculative round's accept / resample rule, for either kind of
+    draft: ``logits [S, G + 1, V]`` the target's at the candidates'
+    positions, ``d [S, G]`` the proposals, ``q [S, G, V]`` the distributions
+    they were drawn from (unused when greedy; one-hot for a proposal that
+    was an argmax), ``keys`` the slots' RNG streams, ``act [S]`` the slots
+    that owe a token. Greedy: accept the longest prefix where the target's
+    argmax equals the proposal, then take the target's own next token.
+    Sampled: accept ``d_i`` with probability ``min(1, p(d_i)/q(d_i))``, on
+    the first rejection resample from ``norm(max(p - q, 0))``, after full
+    acceptance sample the bonus from ``p``. Returns ``(count [S], corr [S],
+    block [S, G + 2], keys)``: tokens emitted (accepted + 1; 0 for a frozen
+    slot), the last of them, and ``[count, e_1..e_{G+1}]``."""
+    import jax
+    import jax.numpy as jnp
+
+    i32 = jnp.int32
+    with scope("spec.accept"):
+        if greedy:
+            t = jnp.argmax(logits, axis=-1).astype(i32)    # [S, G+1]
+            accept = t[:, :gamma] == d                     # [S, G]
+            a = jnp.sum(jnp.cumprod(accept.astype(i32), axis=1), axis=1)
+            corr = jnp.take_along_axis(t, a[:, None], axis=1)[:, 0]
+        else:
+            p = jax.nn.softmax(sample_filtered(logits), axis=-1)
+            p_d = jnp.take_along_axis(
+                p[:, :gamma], d[..., None], axis=-1)[..., 0]
+            q_d = jnp.take_along_axis(q, d[..., None], axis=-1)[..., 0]
+
+            def consume(key):
+                key, su = jax.random.split(key)
+                u = jax.random.uniform(su, (gamma,))
+                key, sc = jax.random.split(key)
+                return key, u, sc
+
+            keys, us, subs = jax.vmap(consume)(keys)
+            # u < min(1, p/q)  <=>  u*q < p  (q=0 proposals never drawn)
+            accept = us * q_d < p_d
+            a = jnp.sum(jnp.cumprod(accept.astype(i32), axis=1), axis=1)
+            p_a = jnp.take_along_axis(
+                p, a[:, None, None], axis=1)[:, 0]         # [S, V]
+            q_pad = jnp.concatenate(
+                [q, jnp.zeros_like(q[:, :1])], axis=1)
+            q_a = jnp.take_along_axis(
+                q_pad, a[:, None, None], axis=1)[:, 0]
+            res = jnp.maximum(p_a - q_a, 0.0)
+            has_res = jnp.sum(res, axis=-1, keepdims=True) > 0
+            res = jnp.where(has_res, res, p_a)
+            corr = jax.vmap(
+                lambda s_, r: jax.random.categorical(
+                    s_, jnp.log(jnp.maximum(r, 1e-38))))(subs, res)
+            corr = corr.astype(i32)
+
+        count = jnp.where(act, a + 1, 0).astype(i32)
+        idx = jnp.arange(gamma + 1)[None, :]
+        d_pad = jnp.concatenate(
+            [d, jnp.zeros_like(d[:, :1])], axis=1)         # [S, G+1]
+        emit = jnp.where(idx < a[:, None], d_pad,
+                         jnp.where(idx == a[:, None], corr[:, None], 0))
+        block = jnp.concatenate([count[:, None], emit], axis=1)
+    return count, corr, block, keys
+
+
+def _advance_rounds(loop, act, count, corr, keys, **more):
+    """A round's effect on the loop state: a slot that owed a token takes
+    the round's last token, moves its cursor on by ``count`` and owes as
+    many fewer (floored at zero: the host truncates the last round's
+    tokens by its own bookkeeping); ``more``: further per-slot values a
+    live slot takes (a self-draft's next proposal)."""
+    import jax.numpy as jnp
+
+    with scope("spec.accept"):
+        new = {"cursors": jnp.where(act, loop["cursors"] + count,
+                                    loop["cursors"]),
+               "tok": jnp.where(act, corr, loop["tok"]),
+               "remaining": jnp.where(
+                   act, jnp.maximum(loop["remaining"] - count, 0),
+                   loop["remaining"]),
+               "keys": keys}
+        new.update({k: jnp.where(act, v, loop[k]) for k, v in more.items()})
+    return new
 
 
 @traced
@@ -796,8 +923,9 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
     i32 = jnp.int32
 
     def round_body(carry, _):
-        kv, draft_kv, cursors, tok, remaining, keys, draft_keys = carry
-        act = remaining > 0
+        kv, draft_kv, loop, draft_keys = carry
+        cursors, tok = loop["cursors"], loop["tok"]
+        act = loop["remaining"] > 0
 
         # ---- draft: propose gamma candidates, write gamma+1 K/V
         def dstep(dc, i):
@@ -831,65 +959,90 @@ def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
                                         pool_kernel=pool_kernel)
 
         # ---- accept / resample
-        if greedy:
-            t = jnp.argmax(logits, axis=-1).astype(i32)    # [S, G+1]
-            accept = t[:, :gamma] == d                     # [S, G]
-            a = jnp.sum(jnp.cumprod(accept.astype(i32), axis=1), axis=1)
-            corr = jnp.take_along_axis(t, a[:, None], axis=1)[:, 0]
-        else:
-            p = jax.nn.softmax(sample_filtered(logits), axis=-1)
-            q = jnp.swapaxes(qdists[:gamma], 0, 1)         # [S, G, V]
-            p_d = jnp.take_along_axis(
-                p[:, :gamma], d[..., None], axis=-1)[..., 0]
-            q_d = jnp.take_along_axis(q, d[..., None], axis=-1)[..., 0]
-
-            def consume(key):
-                key, su = jax.random.split(key)
-                u = jax.random.uniform(su, (gamma,))
-                key, sc = jax.random.split(key)
-                return key, u, sc
-
-            keys, us, subs = jax.vmap(consume)(keys)
-            # u < min(1, p/q)  <=>  u*q < p  (q=0 proposals never drawn)
-            accept = us * q_d < p_d
-            a = jnp.sum(jnp.cumprod(accept.astype(i32), axis=1), axis=1)
-            p_a = jnp.take_along_axis(
-                p, a[:, None, None], axis=1)[:, 0]         # [S, V]
-            q_pad = jnp.concatenate(
-                [q, jnp.zeros_like(q[:, :1])], axis=1)
-            q_a = jnp.take_along_axis(
-                q_pad, a[:, None, None], axis=1)[:, 0]
-            res = jnp.maximum(p_a - q_a, 0.0)
-            has_res = jnp.sum(res, axis=-1, keepdims=True) > 0
-            res = jnp.where(has_res, res, p_a)
-            corr = jax.vmap(
-                lambda s_, r: jax.random.categorical(
-                    s_, jnp.log(jnp.maximum(r, 1e-38))))(subs, res)
-            corr = corr.astype(i32)
-
-        count = jnp.where(act, a + 1, 0).astype(i32)
-        idx = jnp.arange(gamma + 1)[None, :]
-        d_pad = jnp.concatenate(
-            [d, jnp.zeros_like(d[:, :1])], axis=1)         # [S, G+1]
-        emit = jnp.where(idx < a[:, None], d_pad,
-                         jnp.where(idx == a[:, None], corr[:, None], 0))
-        block = jnp.concatenate([count[:, None], emit], axis=1)
-
-        tok = jnp.where(act, corr, tok)
-        cursors = jnp.where(act, cursors + count, cursors)
-        remaining = jnp.where(act, jnp.maximum(remaining - count, 0),
-                              remaining)
-        return (kv, draft_kv, cursors, tok, remaining, keys,
+        q = None if greedy else jnp.swapaxes(qdists[:gamma], 0, 1)
+        count, corr, block, keys = _accept_round(
+            act, logits, d, q, loop["keys"], gamma, greedy, sample_filtered)
+        return (kv, draft_kv, _advance_rounds(loop, act, count, corr, keys),
                 draft_keys), block
 
-    (kv, draft_kv, cursors, tok, remaining, keys, draft_keys), blocks = (
-        lax.scan(round_body,
-                 (kv, draft_kv, loop["cursors"], loop["tok"],
-                  loop["remaining"], loop["keys"], draft_keys), None,
-                 length=k_rounds))
-    loop = {"cursors": cursors, "tok": tok, "remaining": remaining,
-            "keys": keys}
+    (kv, draft_kv, loop, draft_keys), blocks = lax.scan(
+        round_body, (kv, draft_kv, loop, draft_keys), None, length=k_rounds)
     return blocks, loop, draft_keys, kv, draft_kv
+
+
+@traced
+def _serve_mtp_impl(model, sample_filtered, greedy, k_rounds, params, kv,
+                    loop, *, pool_kernel=None):
+    """K speculative rounds drafted from the model's own
+    multi-token-prediction module (``TransformerLM(mtp=)``; DeepSeek-V3,
+    arXiv:2412.19437 section 2.2), as ONE program. A slot carries ``tok`` at
+    cursor ``c`` and ``draft``, the module's proposal for ``c + 1``. Per
+    round and live slot:
+
+    1. **verify** — the target over ``[tok, draft]`` at ``[c, c + 1]``
+       (``_serve_verify_impl``: latent rows written at both), logits and
+       hidden states for both.
+    2. **accept** — ``_accept_round`` with one proposal (``_serve_spec_impl``'s
+       rule; the proposal was an argmax, so ``q`` is one-hot): on acceptance
+       the round emits the draft and the target's token after it, cursor
+       ``c + 2``; else the target's own token, cursor ``c + 1``, and row
+       ``c + 1`` stays beyond the cursor, masked until overwritten.
+    3. **draft** — the module over the positions just made permanent (two;
+       the second holds no token after a rejection) with the emitted tokens'
+       embeddings beside the verify's final-normed hidden states, its latent
+       rows written at those positions of its own layer (the pool's last);
+       its argmax at the last permanent position is the next round's draft.
+
+    No second pool and no second cursor: the module reads position i of the
+    target's hidden states and token i + 1, so its rows sit at the target's
+    positions, and nothing of a round but the draft goes to the next.
+    Returns ``(blocks, loop, pool, routing)``: ``blocks [K, S, 4]``, per
+    round and slot ``[count, e_1, e_2, draft verified]``; ``routing`` (None
+    without routed experts) ``[K, L + 1, ...]``, each round's
+    ``_stack_routing`` over the S x 2 candidates, the layers' and then the
+    module's (whose row at a position is the token one further on)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    i32 = jnp.int32
+    module = len(model.layers_of("mla"))    # the module's latent layer
+
+    def round_body(carry, _):
+        kv, loop = carry
+        act = loop["remaining"] > 0
+        d = loop["draft"]
+        vtoks = jnp.stack([loop["tok"], d], axis=1)         # [S, 2]
+        vpos = loop["cursors"][:, None] + jnp.arange(2)[None, :]
+        moe_info: list = []
+        logits, kv, h = _serve_verify_impl(
+            model, params, kv, vtoks, vpos, act, pool_kernel=pool_kernel,
+            moe_info=moe_info, hidden=True)
+        q = None if greedy else jax.nn.one_hot(d, logits.shape[-1])[:, None]
+        count, corr, block, keys = _accept_round(
+            act, logits, d[:, None], q, loop["keys"], 1, greedy,
+            sample_filtered)
+        # ---- draft: the module at the positions that are now permanent
+        held = jnp.arange(2)[None, :] < count[:, None]      # [S, 2]
+        hm, _, _ = model.mtp_block(
+            params, model.mtp_input(params, model._norm(h, params["ln_f"]),
+                                    block[:, 1:]),
+            positions=vpos, live=held, moe_info=moe_info,
+            attention=_latent_attention(model, kv, vpos, live=act)(
+                module, params["mtp"]["block"]["mla"]))
+        last = jnp.take_along_axis(
+            hm, jnp.maximum(count - 1, 0)[:, None, None], axis=1)[:, 0]
+        nxt = jnp.argmax(model.mtp_head(params, last), axis=-1).astype(i32)
+        loop = _advance_rounds(loop, act, count, corr, keys, draft=nxt)
+        # None is an empty tree: a scan stacks nothing for it
+        routing = _stack_routing(moe_info) if moe_info else None
+        return (kv, loop), (jnp.concatenate([block, d[:, None]], axis=1),
+                            routing)
+
+    # no loop round a single round
+    (kv, loop), (blocks, routing) = lax.scan(
+        round_body, (kv, loop), None, length=k_rounds, unroll=k_rounds == 1)
+    return blocks, loop, kv, routing
 
 
 def _record(extra):
@@ -961,19 +1114,33 @@ class DecodeEngine:
         self._programs: Dict[tuple, object] = {}
         self.program_builds = 0
         self._prefill_carry: Dict[int, list] = {}   # prefill_blocks
+        # {slot: the first round's draft} between a model with a module's
+        # prefill and its admit_slot
+        self._first_draft: Dict[int, object] = {}
 
         # ---- speculative-decoding configuration
-        if model.hybrid and (draft_model is not None or draft_layers):
+        if (model.kda or model.dsa) and (
+                draft_model is not None or draft_layers or model.mtp):
             raise ValueError(
-                "speculative decoding is not written for a model with "
-                "'kda' or 'mla' layers: a rejected draft token would have "
-                "to be taken back out of the recurrent state, which keeps "
-                "no history to rewind to, and the verify forward knows "
-                "neither latent rows nor an indexer's keys")
+                "speculative decoding is not written for a model with 'kda' "
+                "layers or an indexer: a rejected draft token would have to "
+                "be taken back out of the recurrent state, which keeps no "
+                "history to rewind to, and the verify forward knows no "
+                "indexer's keys: it neither writes them nor selects")
+        if model.mtp and (draft_model is not None or draft_layers):
+            raise ValueError(
+                "this model drafts from its own multi-token-prediction "
+                "module (mtp=): pass no draft_model= or draft_layers=")
+        if model.mtp and set(model.mixers) != {"mla"}:
+            raise NotImplementedError(
+                "speculative rounds drafted from a multi-token-prediction "
+                "module are written for a stack of 'mla' layers (prefill in "
+                f"blocks); this model's are {model.mixers}")
         if draft_model is not None and draft_layers:
             raise ValueError(
                 "pass draft_model= OR draft_layers=, not both")
-        self.spec_tokens = int(spec_tokens)
+        # a model's own module proposes one token a round
+        self.spec_tokens = 1 if model.mtp else int(spec_tokens)
         if self.spec_tokens < 1:
             raise ValueError(f"spec_tokens={spec_tokens} must be >= 1")
         self.draft_model = None
@@ -1017,7 +1184,17 @@ class DecodeEngine:
 
     @property
     def spec(self) -> bool:
-        return self.draft_model is not None
+        """True when a decode dispatch is speculative rounds: a draft model
+        with its own pool, or the model's own multi-token-prediction
+        module."""
+        return self.draft_model is not None or bool(self.model.mtp)
+
+    @property
+    def block_prefill(self) -> bool:
+        """True when a prompt is prefilled in blocks (``prefill_blocks``):
+        learned sparse attention, or a model that drafts from its own
+        module."""
+        return bool(self.model.dsa or self.model.mtp)
 
     @staticmethod
     def _shallow_draft(model, n: int):
@@ -1141,6 +1318,8 @@ class DecodeEngine:
                 tok, new_key, state, carry, *record = run(
                     model.params, cache.state, carry, tokens, plen_, slot_,
                     key, np.int32(i))
+                if model.mtp:
+                    self._first_draft[slot] = record.pop()
                 cache.install(state)
                 yield (tok, new_key, _record(record)) if i == last else None
         finally:
@@ -1156,7 +1335,7 @@ class DecodeEngine:
         ``admit_slot`` has written its loop state."""
         import jax
 
-        if self.model.dsa:      # every block, back to back
+        if self.block_prefill:      # every block, back to back
             *_, out = self.prefill_blocks(prompt, slot, key)
             return out
         prompt = np.asarray(prompt, np.int32)
@@ -1188,7 +1367,10 @@ class DecodeEngine:
         run = self._program(("slot_admit",), lambda: jax.jit(slot_admit))
         self.cache.loop = run(
             self.cache.loop, np.asarray([slot, cursor, remaining], np.int32),
-            jnp.asarray(tok, jnp.int32), key)
+            jnp.asarray(tok, jnp.int32), key,
+            # one signature: always a draft where the loop state has one
+            self._first_draft.pop(slot, np.int32(0)) if self.model.mtp
+            else None)
 
     def release_slot(self, slot: int) -> None:
         """The request in ``slot`` leaves before its last token: the
@@ -1240,11 +1422,14 @@ class DecodeEngine:
         return toks
 
     def decode_spec(self, k_rounds: int):
-        """K speculative rounds as ONE dispatch: returns the
-        ``[K, S, spec_tokens + 2]`` block (per round and slot:
-        ``[count, tokens...]``); both pools, the loop state and the
-        draft's RNG streams (``draft_keys``) advance in place."""
+        """K speculative rounds as ONE dispatch: returns ``(blocks,
+        routing)``, the ``[K, S, spec_tokens + 2]`` block (per round and
+        slot: ``[count, tokens...]``) and None; both pools, the loop state
+        and the draft's RNG streams (``draft_keys``) advance in place. A
+        model that drafts from its own module: ``_decode_mtp``."""
         greedy = self.temperature == 0.0
+        if self.model.mtp:
+            return self._decode_mtp(k_rounds, greedy)
 
         def build():
             return self._decode_jit(
@@ -1262,4 +1447,19 @@ class DecodeEngine:
             self.draft_keys)
         self.cache.install(state)
         self.draft_cache.install(dstate)
-        return blocks
+        return blocks, None
+
+    def _decode_mtp(self, k_rounds: int, greedy: bool):
+        """``decode_spec`` for a model that drafts from its own module
+        (``_serve_mtp_impl``): ``(blocks [K, S, 4], routing)``, one pool."""
+        def build():
+            return self._decode_jit(
+                (1,), _serve_mtp_impl, self.model,
+                None if greedy else _filtered_logits_fn(
+                    self.temperature, self.top_k), greedy, k_rounds)
+
+        run = self._program(("decode_spec", self.slots, k_rounds, 1), build)
+        blocks, self.cache.loop, state, routing = run(
+            self.model.params, self.cache.state, self.cache.loop)
+        self.cache.install(state)
+        return blocks, routing

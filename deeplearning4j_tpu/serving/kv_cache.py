@@ -141,8 +141,10 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
         if kv_dtype == "int8":
             out["kv"] += [(dims[:2] + (dims[3],), "float32")] * 2
     if model.mla:
+        # a multi-token-prediction module's block keeps one more layer of
+        # rows, the last of the list (``TransformerLM.n_layers``)
         out["latent"] = [((slots, max_len, latent_row_width(model)),
-                          kv_dtype)] * len(model.layers_of("mla"))
+                          kv_dtype)] * model.n_layers("mla")
     if model.dsa:
         out["index"] = [((slots, max_len, model.dsa["head_dim"]),
                          kv_dtype)] * model.indexers.count("full")
@@ -283,7 +285,8 @@ def advance_loop(loop, ntok, nkeys):
     import jax.numpy as jnp
 
     act = loop["remaining"] > 0
-    return {"cursors": jnp.where(act, loop["cursors"] + 1, loop["cursors"]),
+    return {**loop,
+            "cursors": jnp.where(act, loop["cursors"] + 1, loop["cursors"]),
             "tok": jnp.where(act, ntok, loop["tok"]),
             "remaining": jnp.where(act, loop["remaining"] - 1,
                                    loop["remaining"]),
@@ -291,17 +294,23 @@ def advance_loop(loop, ntok, nkeys):
 
 
 @traced
-def slot_admit(loop, at, tok, key):
+def slot_admit(loop, at, tok, key, draft=None):
     """A request enters a slot: ``at`` = ``[slot, cursor, remaining]``
     (int32, one transfer), ``tok`` its last emitted token (the next
-    step's input), ``key`` its RNG stream. A request that leaves before
-    its last token (deadline, cancel) is the same write with nothing
-    owed: the slot freezes."""
+    step's input), ``key`` its RNG stream, ``draft`` (a model that drafts
+    from its own multi-token-prediction module: the loop state has
+    ``draft``) the token proposed for the position after ``tok``'s. A
+    request that leaves before its last token (deadline, cancel) is the
+    same write with nothing owed: the slot freezes."""
     slot = at[0]
-    return {"cursors": loop["cursors"].at[slot].set(at[1]),
-            "tok": loop["tok"].at[slot].set(tok),
-            "remaining": loop["remaining"].at[slot].set(at[2]),
-            "keys": loop["keys"].at[slot].set(key)}
+    new = {"cursors": loop["cursors"].at[slot].set(at[1]),
+           "tok": loop["tok"].at[slot].set(tok),
+           "remaining": loop["remaining"].at[slot].set(at[2]),
+           "keys": loop["keys"].at[slot].set(key)}
+    if "draft" in loop:
+        new["draft"] = loop["draft"].at[slot].set(
+            0 if draft is None else draft)
+    return new
 
 
 class SlotKVCache:
@@ -377,6 +386,10 @@ class SlotKVCache:
             "tok": jnp.zeros(self.slots, jnp.int32),
             "remaining": jnp.zeros(self.slots, jnp.int32),
             "keys": jnp.zeros((self.slots,) + key.shape, key.dtype)}
+        if model.mtp:
+            # the module's proposal for position cursor + 1, made at the end
+            # of the round (or the prefill) before: what a round verifies
+            self.loop["draft"] = jnp.zeros(self.slots, jnp.int32)
         self.registry = registry
         if registry is not None:
             from jax.sharding import PartitionSpec as P

@@ -352,6 +352,12 @@ class ServeRequest:   # field-wise eq would compare prompt arrays
     # step that emitted a token: row j of their concatenation along axis 1
     # is the key positions that the query which emitted token j attended
     selection: Optional[list] = None
+    # the same server over a model that drafts from its own
+    # multi-token-prediction module: ``(position, token)`` of every draft a
+    # round of this request verified, the position the one it was proposed
+    # for; ``routing`` then holds a round's ``count`` rows (n = 1 or 2),
+    # the module's layer last
+    drafts: Optional[list] = None
 
     def expired(self, now: float) -> bool:
         return self.deadline_s is not None and now > self.deadline_s

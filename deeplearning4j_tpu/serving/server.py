@@ -218,6 +218,11 @@ class DecodeServer:
         self.slot_dispatches = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # rounds the live slots took and tokens those rounds yielded, as the
+        # device counted them (a request's last round may yield a token
+        # more than it is owed)
+        self.spec_rounds = 0
+        self.spec_emitted = 0
         # routed experts: (token, expert) pairs every expert held here, of
         # every layer that has experts, has received from live rows,
         # prefills and plain decode steps alike ([Lmoe, held]; an empty
@@ -225,7 +230,7 @@ class DecodeServer:
         # the decode blocks read, how many there were and the (layer,
         # expert) cells they reached and their programs fetched
         self.moe_expert_load = np.zeros(
-            (len(model.layers_of("moe")), model.experts_held), np.int64)
+            (model.n_layers("moe"), model.experts_held), np.int64)
         self.moe_rows = 0
         self.moe_decode_blocks = 0
         self.moe_decode_touched = 0
@@ -239,12 +244,18 @@ class DecodeServer:
         # bf16 flip near-ties of the router. They reach the host with
         # the load whatever this says (one array, 9 KiB a step at 32
         # slots, 4 layers, 8 of 64 experts a token); this keeps them.
+        # A model that drafts from its own module (``mtp=``) records its
+        # rounds too: the rows of the positions a round made permanent, the
+        # module's layer last, and every draft a round verified with the
+        # position it was proposed for (``ServeRequest.drafts``).
         self.record_routing = bool(record_routing)
-        if self.record_routing and not (model.num_experts
-                                        and self._decode_kind == "plain"):
+        if self.record_routing and not (model.num_experts and (
+                self._decode_kind == "plain"
+                or model.mtp and self.fuse_steps == 1)):
             raise ValueError(
                 "record_routing needs a model with routed experts and "
-                "the plain decode step (fuse_steps=1, no draft model)")
+                "the plain decode step or one round a dispatch of a model's "
+                "own module (fuse_steps=1, no draft model)")
         self._reg = metrics()
 
     # ------------------------------------------------------------------
@@ -444,7 +455,7 @@ class DecodeServer:
             return 0
         with tracer().span("serve.admit") as sp:
             sp.attrs["n"] = admitted = (
-                self._admit_blocks(free) if self.model.dsa
+                self._admit_blocks(free) if self.engine.block_prefill
                 else self._admit_into(free))
         return admitted
 
@@ -504,7 +515,8 @@ class DecodeServer:
         return admitted
 
     def _admit_blocks(self, free: List[int]) -> int:
-        """Admission for a model with learned sparse attention, whose
+        """Admission for a model with learned sparse attention or one that
+        drafts from its own module, whose
         prefill is a row of block programs (``engine.prefill_blocks``):
         every free slot takes a queued request, and then ONE block runs —
         of the request that has waited longest for one — before the step's
@@ -573,6 +585,8 @@ class DecodeServer:
             req.routing = [tuple(a[:, :prompt_len].copy() for a in rows)]
             if selection is not None:   # of the prompt's last position
                 req.selection = [selection[:, None]]
+            if self.model.mtp:
+                req.drafts = []
         req.tokens.append(int(tok))
 
     def _enter(self, req: ServeRequest, slot: int) -> None:
@@ -620,7 +634,7 @@ class DecodeServer:
         as the program moves the device's (a speculative round's count is
         the device's to say: booked when its block is read, ``_emit``)."""
         if self.engine.spec:
-            return self.engine.decode_spec(self.fuse_steps), None, live
+            return self.engine.decode_spec(self.fuse_steps) + (live,)
         for slot, req in live.items():
             self._cursors[slot] += min(
                 self.fuse_steps, req.max_new_tokens - len(req.tokens))
@@ -691,7 +705,8 @@ class DecodeServer:
         weights come in the same array (``engine._stack_routing``):
         ``rows`` = ``(experts, weights)``, None for a dense model.
         ``live_rows`` is how many rows of the program held a token (a
-        prompt's length, a decode block's live slots): ``moe_rows``.
+        prompt's length, a decode block's live slots, twice that for a round
+        that verifies two candidates a slot): ``moe_rows``.
 
         A model with learned sparse attention hands over ``(routing,
         selection)`` (``engine._record``); the third value returned is the
@@ -709,21 +724,24 @@ class DecodeServer:
             toks, selection = jax.device_get((toks, selection))
             return np.asarray(toks), None, selection
         toks, packed, selection = jax.device_get((toks, routing, selection))
-        load, *rows, read = unpack_routing(packed, self.model.experts_held,
-                                           self.model.experts_per_token)
-        touched, read = int(np.count_nonzero(load)), int(read.sum())
-        self.moe_expert_load += load
-        self.moe_rows += live_rows
-        if decode:
-            self.moe_decode_blocks += 1
-            self.moe_decode_touched += touched
-            self.moe_decode_read += read
-        pairs = int(load.sum())
-        if pairs:
-            self._reg.counter("serve_moe_routed_pairs_total").inc(pairs)
-            self._reg.gauge("serve_moe_max_expert_share").set(
-                float((load.max(axis=1) / np.maximum(
-                    load.sum(axis=1), 1)).max()))
+        # speculative rounds hand over one array a round ([K, L, ...]):
+        # each is booked as a block of its own, the span holds the last
+        for one in (packed if packed.ndim == 3 else packed[None]):
+            load, *rows, read = unpack_routing(
+                one, self.model.experts_held, self.model.experts_per_token)
+            touched, read = int(np.count_nonzero(load)), int(read.sum())
+            self.moe_expert_load += load
+            self.moe_rows += live_rows
+            if decode:
+                self.moe_decode_blocks += 1
+                self.moe_decode_touched += touched
+                self.moe_decode_read += read
+            pairs = int(load.sum())
+            if pairs:
+                self._reg.counter("serve_moe_routed_pairs_total").inc(pairs)
+                self._reg.gauge("serve_moe_max_expert_share").set(
+                    float((load.max(axis=1) / np.maximum(
+                        load.sum(axis=1), 1)).max()))
         span = tracer().current()
         if span is not None:
             span.attrs["experts_touched"] = touched
@@ -784,7 +802,7 @@ class DecodeServer:
         ahead = bool(live) and unread is not None
         with tracer().span("serve.decode", live=len(live),
                            kind=self._decode_kind, ahead=int(ahead),
-                           **self._book_kv_blocks(live)):
+                           **self._book_kv_blocks(live)) as sp:
             if live:
                 self._unread = self._dispatch(live)
                 if ahead:
@@ -794,16 +812,28 @@ class DecodeServer:
                     unread, self._unread = self._unread, None
             if unread is None:      # the first dispatch after idling
                 return
+            candidates = 1 + bool(self.model.mtp)   # rows a slot and layer
             toks, rows, selection = self._read_block(
-                *unread[:2], len(unread[2]), decode=True)
-        counts = None
-        if self.engine.spec:                       # [K, S, G+2]
-            toks, counts = toks[:, :, 1:], toks[:, :, 0]
-        elif toks.ndim == 1:                       # plain: [S] -> [1, S]
-            toks = toks[None]
+                *unread[:2], candidates * len(unread[2]), decode=True)
+            counts = drafts = None
+            if self.engine.spec:                   # [K, S, G+2]
+                if self.model.mtp:  # [K, S, G+3]: the draft verified, last
+                    toks, drafts = toks[:, :, :-1], toks[:, :, -1]
+                toks, counts = toks[:, :, 1:], toks[:, :, 0]
+                # what the device says of the rounds (no further read)
+                c = counts[:, list(unread[2])]
+                rounds = int(np.count_nonzero(c > 0))
+                sp.attrs.update(
+                    rounds=rounds, proposed=rounds * self.engine.spec_tokens,
+                    accepted=int(np.maximum(c - 1, 0).sum()),
+                    emitted=int(c.sum()))
+                self.spec_rounds += rounds
+                self.spec_emitted += sp.attrs["emitted"]
+            elif toks.ndim == 1:                   # plain: [S] -> [1, S]
+                toks = toks[None]
         with tracer().span("serve.emit") as emit:
             emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
-                unread[2], toks, counts, rows, selection)
+                unread[2], toks, counts, rows, selection, drafts)
 
     def flush(self) -> None:
         """Read and book the block the host has not read yet (no-op with
@@ -813,10 +843,12 @@ class DecodeServer:
             self._decode({})
 
     def _emit(self, live: dict, toks, counts, rows,
-              selection=None) -> Tuple[int, int]:
+              selection=None, drafts=None) -> Tuple[int, int]:
         """Book one dispatch's token block: per slot that was live in it
         the tokens it takes, TPOT observations, retirement; ``rows`` is
-        the same block's routing and ``selection`` its key selections. A
+        the same block's routing, ``selection`` its key selections and
+        ``drafts`` [K, S] the drafts its rounds verified (a model's own
+        module). A
         slot whose request was swept while the block was unread takes
         nothing. Returns ``(tokens emitted, requests retired)``."""
         now = self.clock()
@@ -840,15 +872,25 @@ class DecodeServer:
                     c = int(counts[r, slot])
                     if c <= 0:
                         continue
-                    self._cursors[slot] += c
                     take = min(c, rem - len(got))
+                    if req.drafts is not None:
+                        # the round verified a draft for the position after
+                        # its cursor's, and made ``take`` positions permanent:
+                        # their rows of the S x 2 candidates', every layer's
+                        req.drafts.append((int(self._cursors[slot]) + 1,
+                                           int(drafts[r, slot])))
+                        req.routing.append(tuple(
+                            a.reshape(a.shape[0], self.slots, 2, -1)[
+                                :, slot, :take] for a in rows))
+                    self._cursors[slot] += c
                     got.extend(int(t) for t in toks[r, slot, :take])
                     self.spec_proposed += self.engine.spec_tokens
                     self.spec_accepted += c - 1
                     if len(got) >= rem:
                         break
             req.tokens.extend(got)
-            if req.routing is not None:  # the row that emitted this token
+            if req.routing is not None and counts is None:
+                # the row that emitted this token
                 req.routing.append(tuple(a[:, slot:slot + 1]
                                          for a in rows))
                 if selection is not None:
@@ -998,4 +1040,6 @@ class DecodeServer:
             out["spec_accept_rate"] = (
                 round(self.spec_accepted / self.spec_proposed, 4)
                 if self.spec_proposed else None)
+            out["spec_rounds"] = self.spec_rounds
+            out["spec_emitted"] = self.spec_emitted
         return out

@@ -34,18 +34,32 @@ The loop's state — per slot the cursor, the last token, the tokens still
 owed and the RNG key — lives ON THE DEVICE (``SlotKVCache.loop``): each
 decode program takes it and returns it advanced, and the host writes it
 only where a request enters or leaves a slot. A request ends on
-``max_new_tokens`` alone, so the host knows which slots a dispatch
-serves without reading a token. The plain path therefore runs ONE STEP
-AHEAD of the host: step 3 dispatches block *n + 1* from the device's
-state before step 4 reads block *n*, the one dispatched a step earlier
-(``_unread``), and the chip computes *n + 1* while the host books *n*.
-A slot the host learns is free one read later is admitted one step
-later. With nothing unread (the first step after the server was empty)
-the order is the old one by itself; ``busy()`` stays true while a block
-is unread, and ``drain()``, ``stats()``, ``flush()`` and the fleet's
-drain-time export read it first. The fused and speculative paths keep
-their synchronous read (a speculative round's token count is the
-device's to say).
+``max_new_tokens`` alone, and the device freezes a slot that owes
+nothing more (``remaining``), so nothing a decode program computes waits
+for the host to have read a token. Every kind of block therefore runs
+ONE DISPATCH AHEAD of the host: step 3 dispatches block *n + 1* from
+the device's state before step 4 reads block *n*, the one dispatched a
+step earlier (``_unread``), and the chip computes *n + 1* while the host
+books *n*. What the host chooses without that read is the live set of
+*n + 1* (``_owed``): the slots that MAY still owe a token once block *n*
+is read, counting for it the fewest tokens it can hold for a slot
+(``_still_owed``: 1 of a plain step, ``min(K, owed)`` of K fused steps —
+both exact — and as many of K rounds, each of which yields one token at
+least and ``G + 1`` at most). With rounds the live set is thus a
+superset of the truth by the slots that finished on an accepted draft:
+the device gives those a count of 0, and a dispatch in which every row
+comes back 0 (a lone request's tail) is counted, ``stats()
+["empty_dispatches"]``. Reading behind costs a slot nothing: a slot is
+free the moment the block that is CERTAIN to end its request is
+dispatched (``_vacate``: it owes no more than the fewest tokens the
+block can hold for it), the next step's admission takes it, and the
+request, off the slot table, books its last tokens and retires when that
+block is read. Only a request that ends on an accepted draft, which the
+host cannot foresee, holds its slot one round longer. With nothing
+unread (the first step after the server was empty) the order is
+dispatch, then read in the next step; ``busy()`` stays true while a
+block is unread, and ``drain()``, ``stats()``, ``flush()`` and the
+fleet's drain-time export read it first.
 
 The host sees one token-block readback per dispatch ([S] at K=1,
 [K, S] fused, [K, S, G+2] speculative; a model with routed experts
@@ -89,10 +103,11 @@ runs):
   counted from the host's slot table, ``_book_kv_blocks``; for a model with
   learned sparse attention ``keys_cached``, ``keys_attended`` and
   ``rows_gathered`` instead) — the decode
-  dispatch (``live`` slots; 0: none was owed a token), then the
-  read-back of the block dispatched a step earlier (plain) or just now
-  (fused, spec). ``experts_touched`` and ``experts_read`` are of the
-  block READ.
+  dispatch (``live`` slots; 0: none may be owed a token), then the
+  read-back of the block dispatched a step earlier, whatever its kind.
+  ``experts_touched`` and ``experts_read``, and a round's ``rounds``,
+  ``proposed``, ``accepted`` and ``emitted`` (the device's counts), are
+  of the block READ.
 - ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop
   over the block read, histograms, retirement.
 - ``serve.request`` (``request``, ``tokens``, ``slot``) — ``submit_s`` →
@@ -197,8 +212,9 @@ class DecodeServer:
         self._prefilling: "OrderedDict[int, list]" = OrderedDict()
         self._last_tok_s = np.zeros(self.slots, np.float64)
         # the dispatched block the host has not read: ``(tokens, routing,
-        # {slot: request})`` — device arrays and the slots live in it
-        self._unread: Optional[Tuple[object, object, dict]] = None
+        # {slot: request}, {slot: last token's instant})`` — device arrays,
+        # the slots live in it and those of them freed at the dispatch
+        self._unread: Optional[Tuple[object, object, dict, dict]] = None
         # externally-prefilled requests waiting for a free slot: each
         # entry carries an ``install(engine, slot) -> (last_tok, cursor,
         # key)`` that lands the handed-off KV slab into the slot
@@ -214,6 +230,9 @@ class DecodeServer:
         self.expired_in_flight = 0
         self.steps = 0
         self.decode_ahead = 0
+        # dispatches whose block gave no slot a token, by the device's
+        # own counts: what dispatching on "may owe" costs (rounds only)
+        self.empty_dispatches = 0
         self.decode_tokens = 0
         self.slot_dispatches = 0
         self.spec_proposed = 0
@@ -446,7 +465,7 @@ class DecodeServer:
         # already covers every emitted token, so a request that arrived
         # complete just retires
         if len(req.tokens) >= req.max_new_tokens:
-            self._retire(slot, now)
+            self._retire(slot, req, now)
 
     def _admit(self) -> int:
         free = self._free_slots()
@@ -599,13 +618,13 @@ class DecodeServer:
                                 ).observe(req.ttft_s)
         self._reg.counter("serve_tokens_total").inc()
         if len(req.tokens) >= req.max_new_tokens:
-            self._retire(slot, req.first_token_s)
+            self._retire(slot, req, req.first_token_s)
 
-    def _retire(self, slot: int, now: float) -> None:
-        req = self._slot_req[slot]
+    def _retire(self, slot: int, req: ServeRequest, now: float) -> None:
         req.state = "finished"
         req.finish_s = now
-        self._slot_req[slot] = None
+        if self._slot_req[slot] is req:     # else it left at its dispatch
+            self._slot_req[slot] = None
         self.finished.append(req)
         self._reg.counter("serve_requests_total").inc(event="finished")
         if req.latency_s is not None:
@@ -616,15 +635,29 @@ class DecodeServer:
                             request=req.id, tokens=len(req.tokens),
                             slot=slot)
 
+    def _still_owed(self, slot: int, req: ServeRequest) -> int:
+        """The most tokens ``req`` in ``slot`` can still be owed once the
+        unread block is read: what the host has counted it owed, less the
+        FEWEST tokens that block can hold for it — ``min(fuse_steps,
+        owed)``: one of a plain step, exactly that of K fused steps, at
+        least that of K rounds (a live round yields a token or more).
+        ``_owed`` and the fused dispatch's cursors both count from here."""
+        owed = req.max_new_tokens - len(req.tokens)
+        if self._unread is not None and self._unread[2].get(slot) is req:
+            owed -= min(self.fuse_steps, owed)
+        return owed
+
     def _owed(self) -> dict:
-        """``{slot: request}`` of the slots owed a token that no
+        """``{slot: request}`` of the slots that may be owed a token no
         dispatched block holds yet: the live set of the next dispatch,
         known without reading a token because a request ends on
-        ``max_new_tokens`` alone (the device's ``remaining > 0``)."""
-        pending = self._unread[2] if self._unread is not None else {}
+        ``max_new_tokens`` alone (the device's ``remaining > 0``). Exact
+        for plain and fused steps; with rounds a superset by the slots
+        whose unread rounds accepted a draft past their end, which the
+        device has frozen already (their rows come back with count 0)."""
         return {s: r for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._prefilling
-                and r.max_new_tokens - len(r.tokens) > (pending.get(s) is r)}
+                and self._still_owed(s, r) > 0}
 
     def _dispatch(self, live: dict):
         """ONE decode dispatch for the live set, from the loop state on
@@ -632,15 +665,37 @@ class DecodeServer:
         ``(tokens, routing, live)`` with device arrays — tokens [S] plain,
         [K, S] fused, [K, S, G+2] speculative. The host's cursors move on
         as the program moves the device's (a speculative round's count is
-        the device's to say: booked when its block is read, ``_emit``)."""
+        the device's to say: booked when its block is read, ``_emit``).
+        Called with the block before it still in ``_unread``: the fused
+        steps a slot takes are counted past that block's."""
         if self.engine.spec:
             return self.engine.decode_spec(self.fuse_steps) + (live,)
         for slot, req in live.items():
-            self._cursors[slot] += min(
-                self.fuse_steps, req.max_new_tokens - len(req.tokens))
+            self._cursors[slot] += min(self.fuse_steps,
+                                       self._still_owed(slot, req))
         if self.fuse_steps > 1:
             return self.engine.decode_fused(self.fuse_steps), None, live
         return self.engine.decode() + (live,)
+
+    def _vacate(self) -> None:
+        """Free the slots whose requests the block just dispatched is
+        CERTAIN to end — it holds at least ``min(fuse_steps, owed)`` tokens
+        for a slot, and these are owed no more than that — so the next
+        step's admission takes them, as it did when the block was read in
+        the step that dispatched it. The request is off the slot table
+        (no sweep reaches it: the device has finished it) and stays in the
+        unread block, which books its last tokens and retires it when it
+        is read; ``left`` keeps what ``_emit`` needs of the slot and a new
+        tenant overwrites, the instant of its last token."""
+        if self._unread is None:
+            return
+        _, _, live, left = self._unread
+        for slot, req in live.items():
+            # every block before this one is booked: ``tokens`` is current
+            if (req.max_new_tokens - len(req.tokens) <= self.fuse_steps
+                    and self._slot_req[slot] is req):
+                self._slot_req[slot] = None
+                left[slot] = self._last_tok_s[slot]
 
     def _book_kv_blocks(self, live: dict) -> dict:
         """Count what the dispatch for ``live`` reads of the pool, in the
@@ -777,8 +832,10 @@ class DecodeServer:
         """One scheduler iteration: shed expired/canceled slots, admit
         at the fusion boundary, one decode dispatch (1, K, or K
         speculative rounds of tokens), then read and book a token block
-        — on the plain path the one dispatched a step EARLIER, so the
-        chip runs this step's dispatch meanwhile. Returns False when
+        — the one dispatched a step EARLIER, whatever its kind, so the
+        chip runs this step's dispatch meanwhile — and the slots this
+        step's dispatch is certain to finish are free for the next step's
+        admission (``_vacate``). Returns False when
         nothing was dispatched or read and no prompt is part-way through
         its prefill blocks (the caller may idle)."""
         with tracer().span("serve.step") as sp:
@@ -791,25 +848,25 @@ class DecodeServer:
                 return bool(self._prefilling)   # a prefill block ran
             sp.attrs["live"] = len(live)
             self._decode(live)
+            self._vacate()
             return True
 
     def _decode(self, live: dict) -> None:
         """The ``serve.decode`` and ``serve.emit`` phases: dispatch a
         block for ``live`` (none when empty), then read and book the
-        block that was unread — or, on the fused and speculative paths,
-        the one just dispatched."""
-        unread, self._unread = self._unread, None
+        block that was unread, the one dispatched a step earlier — the
+        same order for a plain step, fused steps and rounds."""
+        unread = self._unread
         ahead = bool(live) and unread is not None
         with tracer().span("serve.decode", live=len(live),
                            kind=self._decode_kind, ahead=int(ahead),
                            **self._book_kv_blocks(live)) as sp:
-            if live:
-                self._unread = self._dispatch(live)
-                if ahead:
-                    self.decode_ahead += 1
-                    self._reg.counter("serve_decode_ahead_total").inc()
-                if self._decode_kind != "plain":
-                    unread, self._unread = self._unread, None
+            # dispatched while ``_unread`` is still the block before it;
+            # ``_vacate`` fills the block's last part
+            self._unread = self._dispatch(live) + ({},) if live else None
+            if ahead:
+                self.decode_ahead += 1
+                self._reg.counter("serve_decode_ahead_total").inc()
             if unread is None:      # the first dispatch after idling
                 return
             candidates = 1 + bool(self.model.mtp)   # rows a slot and layer
@@ -829,11 +886,14 @@ class DecodeServer:
                     emitted=int(c.sum()))
                 self.spec_rounds += rounds
                 self.spec_emitted += sp.attrs["emitted"]
+                # every slot of the live set had finished on a draft that
+                # an unread round accepted: a dispatch for nothing
+                self.empty_dispatches += rounds == 0
             elif toks.ndim == 1:                   # plain: [S] -> [1, S]
                 toks = toks[None]
         with tracer().span("serve.emit") as emit:
             emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
-                unread[2], toks, counts, rows, selection, drafts)
+                *unread[2:], toks, counts, rows, selection, drafts)
 
     def flush(self) -> None:
         """Read and book the block the host has not read yet (no-op with
@@ -842,7 +902,7 @@ class DecodeServer:
         if self._unread is not None:
             self._decode({})
 
-    def _emit(self, live: dict, toks, counts, rows,
+    def _emit(self, live: dict, left: dict, toks, counts, rows,
               selection=None, drafts=None) -> Tuple[int, int]:
         """Book one dispatch's token block: per slot that was live in it
         the tokens it takes, TPOT observations, retirement; ``rows`` is
@@ -850,7 +910,10 @@ class DecodeServer:
         ``drafts`` [K, S] the drafts its rounds verified (a model's own
         module). A
         slot whose request was swept while the block was unread takes
-        nothing. Returns ``(tokens emitted, requests retired)``."""
+        nothing; one whose request left it at the dispatch (``left``,
+        ``_vacate``) books it all the same, and touches nothing of the
+        slot, which may hold its next tenant. Returns ``(tokens emitted,
+        requests retired)``."""
         now = self.clock()
         self.steps += 1
         self.slot_dispatches += len(live)
@@ -860,7 +923,8 @@ class DecodeServer:
         emitted_total = retired = 0
         proposed0, accepted0 = self.spec_proposed, self.spec_accepted
         for slot, req in live.items():
-            if self._slot_req[slot] is not req:
+            tenant = self._slot_req[slot]
+            if tenant is not req and slot not in left:
                 continue
             rem = req.max_new_tokens - len(req.tokens)
             got: List[int] = []
@@ -875,14 +939,19 @@ class DecodeServer:
                     take = min(c, rem - len(got))
                     if req.drafts is not None:
                         # the round verified a draft for the position after
-                        # its cursor's, and made ``take`` positions permanent:
-                        # their rows of the S x 2 candidates', every layer's
-                        req.drafts.append((int(self._cursors[slot]) + 1,
-                                           int(drafts[r, slot])))
+                        # its cursor's (the tokens so far end on the cursor),
+                        # and made ``take`` positions permanent: their rows
+                        # of the S x 2 candidates', every layer's
+                        req.drafts.append((
+                            req.prompt.shape[0] + len(req.tokens) + len(got),
+                            int(drafts[r, slot])))
                         req.routing.append(tuple(
                             a.reshape(a.shape[0], self.slots, 2, -1)[
                                 :, slot, :take] for a in rows))
-                    self._cursors[slot] += c
+                    # the slot's cursor is this request's to move while
+                    # it holds the slot, or left it and no tenant has come
+                    if tenant is req or tenant is None:
+                        self._cursors[slot] += c
                     got.extend(int(t) for t in toks[r, slot, :take])
                     self.spec_proposed += self.engine.spec_tokens
                     self.spec_accepted += c - 1
@@ -899,13 +968,14 @@ class DecodeServer:
             # with fusion the K tokens land together: spread the
             # dispatch interval evenly so TPOT keeps one observation
             # per token and sums to the true wall span
-            interval = (now - self._last_tok_s[slot]) / max(
-                1, len(got))
+            last = left[slot] if slot in left else self._last_tok_s[slot]
+            interval = (now - last) / max(1, len(got))
             for _ in got:
                 tpot.observe(interval)
-            self._last_tok_s[slot] = now
+            if tenant is req:
+                self._last_tok_s[slot] = now
             if len(req.tokens) >= req.max_new_tokens:
-                self._retire(slot, now)
+                self._retire(slot, req, now)
                 retired += 1
         self.decode_tokens += emitted_total
         self._reg.counter("serve_tokens_total").inc(emitted_total)
@@ -961,7 +1031,8 @@ class DecodeServer:
             # the target pool's bytes by what they are: K/V rows, latent
             # rows, an indexer's keys, recurrent matrices, convolution tails
             "state_bytes": self.engine.cache.nbytes_by_kind,
-            # slots a decode dispatch served, mean
+            # slots a decode dispatch served, mean (with rounds: the slots
+            # that MAY owe a token, ``_owed``'s superset)
             "live_slots_per_step": (
                 round(self.slot_dispatches / self.steps, 4)
                 if self.steps else None),
@@ -973,12 +1044,16 @@ class DecodeServer:
             # so the per-chip footprint is kv_pool_bytes / kv_shards
             "kv_shards": self.engine.cache.n_shard,
             "decode_dispatches": self.steps,
-            # plain-path dispatches issued while the previous block was
-            # unread, of all dispatches: the share of decode steps the
-            # chip did not wait for the host (0 on the first step after
-            # the server was empty, and on the fused/speculative paths)
+            # dispatches issued while the previous block was unread, of
+            # all dispatches: the share of decode steps the chip did not
+            # wait for the host (0 on the first step after the server was
+            # empty), on every kind of block
             "decode_ahead_share": (round(self.decode_ahead / self.steps, 4)
                                    if self.steps else None),
+            # dispatches of rounds in which every slot of the live set had
+            # ended on a draft accepted in the block before (count 0 in
+            # every row): the cost of dispatching on "may owe a token"
+            "empty_dispatches": self.empty_dispatches,
             # key blocks a layer the pool kernel fetched for the slots
             # dispatched, of what reading every slot's cursor (live or
             # frozen) would have fetched — None where the pool has no

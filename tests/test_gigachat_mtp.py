@@ -443,6 +443,69 @@ def test_a_trained_module_is_accepted_and_changes_no_token(trained,
     assert st["spec_emitted"] > st["decode_tokens"]
 
 
+def test_a_request_that_ends_on_an_accepted_draft(trained, small_blocks):
+    """Rounds are read one dispatch behind, so the host picks a dispatch's
+    slots by what the unread round holds at least, one token. A lone request
+    owed four tokens gets them from two rounds of two; the host, which has
+    counted 4 - 2 - 1 > 0, dispatches a third, the device has frozen the slot
+    and the block comes back with a count of 0: the request retires a step
+    later than in the synchronous order (a ``flush()`` after every step), and
+    ``empty_dispatches`` counts the round for nothing. Beside a request that
+    goes on, the same slot-round is no dispatch of its own."""
+    def serve(news, sync, slots=1):
+        server = DecodeServer(trained, slots=slots, max_len=128,
+                              buckets=(16, 32), fuse_steps=1)
+        reqs = [server.submit(_stream(9 + 2 * i, 2 + i), k)
+                for i, k in enumerate(news)]
+        done_at = {}
+
+        def run():
+            step = 0
+            while server.busy():
+                server.step()
+                if sync:
+                    server.flush()
+                step += 1
+                for r in reqs:
+                    if r.state == "finished":
+                        done_at.setdefault(r.id, step)
+
+        _, spans = _rounds(run)
+        blocks = [s["attrs"] for s in spans if s["name"] == "serve.decode"
+                  and "rounds" in s["attrs"]]
+        return server, reqs, blocks, [done_at[r.id] for r in reqs]
+
+    sync, want, blocks, (then,) = serve([5], True)
+    assert [b["emitted"] for b in blocks] == [2, 2]
+    assert sync.stats()["empty_dispatches"] == 0
+    assert sync.stats()["decode_ahead_share"] == 0.0
+
+    server, reqs, blocks, done = serve([5], False)
+    assert reqs[0].tokens == want[0].tokens
+    assert done == [then + 1]
+    assert [(b["rounds"], b["emitted"]) for b in blocks] == [
+        (1, 2), (1, 2), (0, 0)]
+    st = server.stats()
+    assert (st["steps"], st["empty_dispatches"]) == (3, 1)
+    assert (server.slot_dispatches, st["spec_rounds"]) == (3, 2)
+    assert st["decode_ahead_share"] == round(2 / 3, 4)
+    # cursor: the prompt's 9 and four tokens, whatever the third round read
+    assert server.engine.slot_state(0)[0] == server._cursors[0] == 13
+
+    # owed three tokens, the host's count says so: 3 - 2 - 1 = 0
+    server, reqs, blocks, _ = serve([4], False)
+    assert server.stats()["empty_dispatches"] == 0 and len(blocks) == 2
+
+    # beside a longer request the third round of the first is a row of a
+    # block that others fill: one slot-round more, no dispatch more
+    server, reqs, blocks, _ = serve([5, 12], False, slots=2)
+    _, want, _, _ = serve([5, 12], True, slots=2)
+    assert [r.tokens for r in reqs] == [r.tokens for r in want]
+    st = server.stats()
+    assert st["empty_dispatches"] == 0
+    assert server.slot_dispatches > st["spec_rounds"]
+
+
 def test_a_rejection_after_an_acceptance(trained, small_blocks):
     """The trained model with noise on the module's ``M`` (the target is
     untouched): some drafts now miss. A round that rejects yields the

@@ -295,8 +295,13 @@ class TestServeSpans:
         req = server.submit(np.arange(1, 9, dtype=np.int32), 4)
         server.drain()
         entered = {n: kw for a, n, kw in annotations if a == "enter"}
-        assert entered["dl4j.serve.decode"] == {"live": 1, "kind": "fused",
-                                                "ahead": 0}
+        # three tokens after the prompt's: two fused dispatches, the second
+        # ahead of the first's read, and a span that only reads
+        assert [kw for a, n, kw in annotations
+                if a == "enter" and n == "dl4j.serve.decode"] == [
+            {"live": 1, "kind": "fused", "ahead": 0},
+            {"live": 1, "kind": "fused", "ahead": 1},
+            {"live": 0, "kind": "fused", "ahead": 0}]
         assert entered["dl4j.serve.prefill"]["request"] == req.id
         assert set(entered["dl4j.serve.prefill"]) == {
             "request", "slot", "prompt_len", "bucket", "queue_wait_us"}

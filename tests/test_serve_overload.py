@@ -104,10 +104,12 @@ class TestDeadlineSheds:
         v2 = server.try_submit(_prompt(5), 4, deadline_s=0.5)
         assert v1.admitted and v2.admitted
         # expiry is observed at the pop — run the slot dry so admission
-        # reaches the corpse rather than burning a prefill on it
+        # reaches the corpse rather than burning a prefill on it (the slot
+        # is free once v1's last block is dispatched, a step before v1 is
+        # read finished: the clock moves first)
+        t["now"] = 1.0
         while v1.request.state != "finished":
             server.step()
-        t["now"] = 1.0
         server.step()
         assert v2.request.state == "shed"
         assert v2.request.shed_reason == "deadline"

@@ -716,8 +716,8 @@ class TestQuantizedKV:
 class TestSpeculativeDecode:
     def test_full_self_draft_accepts_everything(self, rng):
         """draft_layers == num_layers makes the draft the target: every
-        proposal verifies, tokens/slot-dispatch hits spec_tokens + 1,
-        and the stream is the target's greedy stream."""
+        proposal verifies, a round yields spec_tokens + 1 tokens, and the
+        stream is the target's greedy stream."""
         lm = _lm()
         p = _prompts(rng, (5,))[0]
         srv = DecodeServer(lm, slots=1, max_len=96, draft_layers=2,
@@ -728,8 +728,13 @@ class TestSpeculativeDecode:
             req.output, np.asarray(lm.generate(p[None], 13))[0])
         st = srv.stats()
         assert st["spec_accept_rate"] == 1.0
-        assert st["tokens_per_slot_dispatch"] == 4.0
-        assert srv.steps == 3
+        assert st["spec_emitted"] == 4 * st["spec_rounds"] == 12
+        # read one dispatch behind, the host dispatches a fourth time on
+        # "may owe a token" (12 - 4 - 4 less the one token the unread
+        # round holds at least): the device had frozen the slot, and the
+        # block says so
+        assert (srv.steps, st["empty_dispatches"]) == (4, 1)
+        assert st["tokens_per_slot_dispatch"] == 3.0
 
     @pytest.mark.parametrize("pos_encoding", ["learned", "rope"])
     def test_shallow_draft_greedy_token_identity(self, rng, pos_encoding):
@@ -1129,6 +1134,31 @@ def _executions(fn):
                    for ev in line.events)
 
 
+def _module_lm():
+    """Two latent-attention layers, routed experts in the second, and a
+    multi-token-prediction module the server drafts from (float32;
+    ``tests/test_gigachat_mtp.py`` holds the model to its reference)."""
+    return TransformerLM(
+        vocab_size=61, d_model=32, num_heads=4, num_layers=2, d_ff=16,
+        max_len=32, pos_encoding="rope", norm="rmsnorm",
+        tie_embeddings=False, seed=3, num_experts=8, experts_per_token=2,
+        norm_topk_prob=True, mixers=("mla",) * 2, ffns=("glu", "moe"),
+        glu_width=32, mtp={"loss_weight": 0.3},
+        mla={"q_lora_rank": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+             "qk_rope_head_dim": 8, "v_head_dim": 12, "gate": False}).init()
+
+
+# every kind of decode block: the model, what the server is built with, and
+# the tokens one dispatch can hold for a slot at most
+KINDS = {
+    "plain": (_routed_lm, {}, 1),
+    "fused": (lambda: _lm("rope", max_len=32), {"fuse_steps": 4}, 4),
+    "draft": (lambda: _lm("rope", max_len=32),
+              {"draft_layers": 1, "spec_tokens": 2}, 3),
+    "module": (_module_lm, {}, 2),
+}
+
+
 class TestPipelinedLoop:
     SLOTS, MAX_LEN, BUCKETS = 3, 32, (8, 16, 32)
     # (prompt length, max_new_tokens): ends at admission (1), after one
@@ -1238,6 +1268,187 @@ class TestPipelinedLoop:
             if lm.num_experts:
                 self._same_routing(req, ref_req)
 
+    @staticmethod
+    def _run(srv, submit, sync):
+        """Step ``srv`` dry, the requests of ``submit[i]`` entering before
+        step i; ``sync``: with a ``flush()`` after every step, which is the
+        synchronous order (a block is read in the step that dispatched it).
+        Returns the requests and the ``serve.decode`` spans' attrs."""
+        tr = SpanTracer()
+        set_tracer(tr)
+        try:
+            reqs, i = [], 0
+            while srv.busy() or i < len(submit):
+                if i < len(submit):
+                    reqs += [srv.submit(p, m, seed=seed)
+                             for p, m, seed in submit[i]]
+                srv.step()
+                if sync:
+                    srv.flush()
+                i += 1
+        finally:
+            set_tracer(None)
+        return reqs, [sp.attrs for sp in tr.spans()
+                      if sp.name == "serve.decode"]
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_every_kind_is_read_one_dispatch_behind(self, rng, kind,
+                                                    sampled):
+        """A plain step, K fused steps, rounds of a separate draft and
+        rounds of the model's own module: dispatched while the block before
+        is unread, a server gives every request the tokens, the drafts and
+        the routing rows of the synchronous order, with admissions
+        mid-stream, slots re-used and requests that end inside a block."""
+        make, kw, most = KINDS[kind]
+        lm = make()
+        # the rounds verify up to ``spec_tokens`` positions past the end
+        slack = kw.get("spec_tokens", 1 if kind == "module" else 0)
+        work = [(p, min(m, self.MAX_LEN - len(p) - slack), seed)
+                for seed, (p, m) in enumerate(zip(
+                    _prompts(rng, [n for n, _ in self.EARLY + self.LATE]),
+                    [m for _, m in self.EARLY + self.LATE]))]
+        submit = [work[:len(self.EARLY)], [], [], [], work[len(self.EARLY):]]
+        want, sync = self._run(self._server(lm, sampled, **kw), submit, True)
+        srv = self._server(lm, sampled, **kw)
+        reqs, spans = self._run(srv, submit, False)
+        assert not any(sp["ahead"] for sp in sync) and sum(
+            sp["ahead"] for sp in spans) >= len(spans) - 4
+        assert len({r.slot for r in reqs}) < len(reqs)     # slots re-used
+        for req, ref_req in zip(reqs, want):
+            assert req.state == "finished"
+            assert len(req.tokens) == req.max_new_tokens
+            assert req.tokens == ref_req.tokens
+            assert req.drafts == ref_req.drafts
+            if lm.num_experts:
+                self._same_routing(req, ref_req)
+        assert (kind == "module") == (reqs[0].drafts is not None)
+        # the host's cursors are the device's once every block is read,
+        # and the slots a dispatch served are no fewer than the rows that
+        # yielded a token
+        assert np.array_equal(srv._cursors,
+                              np.asarray(srv.engine.cache.loop["cursors"]))
+        st = srv.stats()
+        assert st["decode_tokens"] == sum(m - 1 for _, m, _ in work)
+        assert st["decode_tokens"] <= most * srv.slot_dispatches
+        if not srv.engine.spec:
+            assert st["empty_dispatches"] == 0
+
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_ahead_of_a_lone_request(self, rng, kind):
+        """``ahead`` reads 0, 1, 1, ..., 0 on every kind: the first
+        dispatch had no block before it, and the last span only reads."""
+        make, kw, most = KINDS[kind]
+        srv = self._server(make(), False, **kw)
+        _, spans = self._run(srv, [[(_prompts(rng, (5,))[0], 21, 0)]], False)
+        n = srv.stats()["decode_dispatches"]
+        assert n >= -(-20 // most) and len(spans) == n + 1
+        assert [sp["ahead"] for sp in spans] == [0] + [1] * (n - 1) + [0]
+        assert [sp["live"] for sp in spans] == [1] * n + [0]
+        assert [sp["kind"] for sp in spans] == [srv._decode_kind] * (n + 1)
+        assert srv.stats()["decode_ahead_share"] == round((n - 1) / n, 4)
+
+    @pytest.mark.parametrize("kind", [k for k in KINDS if k != "plain"])
+    def test_sweep_and_cancel_with_fused_steps_or_rounds_unread(self, rng,
+                                                                 kind):
+        """``test_deadline_and_cancel_with_a_block_in_flight`` for the
+        blocks that hold more than a token a slot: the rows of a request
+        shed on its deadline and of one canceled, in a block that is
+        unread, are dropped; the requests admitted into their slots before
+        that block is read take none of its tokens."""
+        make, kw, most = KINDS[kind]
+        lm = make()
+        prompts = _prompts(rng, (5, 9, 7, 6, 4))
+        ref = self._server(lm, False, **kw)
+        want = [ref.submit(p, 14, seed=s) for s, p in enumerate(prompts)]
+        ref.drain()
+
+        clock = ManualClock()
+        srv = self._server(lm, False, clock=clock, **kw)
+        keep = srv.submit(prompts[0], 14, seed=0)
+        late = srv.submit(prompts[1], 14, seed=1, deadline_s=5.0)
+        loser = srv.submit(prompts[2], 14, seed=2)
+        # the module's prompts enter a block a step, one after another
+        while not (srv._unread and set(srv._unread[2]) == {0, 1, 2}):
+            srv.step()
+        srv.step()
+        assert set(srv._unread[2]) == {0, 1, 2}
+        held = [len(r.tokens) for r in (late, loser)]
+        assert all(1 < n < 14 for n in held)
+        clock.t = 10.0
+        loser.canceled = True
+        more = [srv.submit(p, 14, seed=s)
+                for s, p in enumerate(prompts[3:], start=3)]
+        srv.step()
+        # swept and re-admitted in one step, the old tenants' block unread
+        # until the end of it (the module's second prompt a step later)
+        assert srv._slot_req[1] is more[0]
+        srv.drain()
+        assert late.state == "shed" and loser.state == "canceled"
+        assert [len(late.tokens), len(loser.tokens)] == held
+        assert late.tokens == want[1].tokens[:held[0]]
+        assert loser.tokens == want[2].tokens[:held[1]]
+        assert srv.expired_in_flight == 1
+        assert {r.slot for r in more} == {1, 2}
+        for req, ref_req in zip([keep] + more, [want[0]] + want[3:]):
+            assert req.state == "finished"
+            assert req.tokens == ref_req.tokens
+            assert req.drafts == ref_req.drafts
+        for req in [keep] + more:    # the swept slots' new tenants too
+            assert srv.engine.slot_state(req.slot)[0] == srv._cursors[
+                req.slot] == len(req.prompt) + len(req.tokens) - 1
+
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_a_slot_is_free_once_its_last_block_is_dispatched(self, rng,
+                                                              kind):
+        """Reading a block one dispatch behind costs a request's slot
+        nothing: the slot is free when the block that is certain to end
+        its request is dispatched, so the next tenant enters at the step
+        it enters in the synchronous order, while that block is unread;
+        the request that left books its last tokens, its TPOT from its
+        own clock, and retires one read later."""
+        make, kw, most = KINDS[kind]
+        lm = make()
+        pa, pb = _prompts(rng, (5, 7))
+        tpot = metrics().histogram("serve_tpot_seconds")
+
+        def run(sync):
+            clock = ManualClock()
+            srv = DecodeServer(lm, slots=1, max_len=self.MAX_LEN,
+                               buckets=self.BUCKETS, clock=clock, **kw)
+            a = srv.submit(pa, 1 + 2 * most, seed=0)
+            b = srv.submit(pb, 4, seed=1)
+            while srv.busy():
+                clock.t += 1.0
+                srv.step()
+                if sync:
+                    srv.flush()
+                if a.state == "running" and srv._slot_req[0] is None:
+                    assert 0 in srv._unread[3] and srv.free_slot_count()
+                    assert len(a.tokens) < a.max_new_tokens
+            # the slot's cursor is its last tenant's (a draft accepted
+            # past the end moves it further than the tokens taken)
+            assert int(srv._cursors[0]) == srv.engine.slot_state(0)[0] \
+                >= len(pb) + len(b.tokens) - 1
+            return a, b
+
+        spent = tpot.value()["sum"]
+        (a0, b0), (a, b) = run(True), run(False)
+        spent = tpot.value()["sum"] - spent
+        # the next tenant enters in the step that reads the last block of
+        # the one before it: the step after it retired, read synchronously
+        assert b0.first_token_s == a0.finish_s + 1.0
+        assert b.first_token_s == a.finish_s
+        for req, want in ((a, a0), (b, b0)):
+            assert req.state == "finished" and req.tokens == want.tokens
+            assert req.drafts == want.drafts
+            assert req.first_token_s == want.first_token_s
+            assert req.finish_s == want.finish_s + 1.0
+        # an observation a token, summing to each request's decode span
+        assert spent == pytest.approx(sum(
+            r.finish_s - r.first_token_s for r in (a0, b0, a, b)))
+
     def test_a_swept_slot_stops_decoding_on_the_device(self, rng):
         """The lone request is canceled with its next token unread:
         ``busy()`` holds until that block is read (and dropped), and the
@@ -1274,18 +1485,24 @@ class TestPipelinedLoop:
         assert req.state == "finished" and len(req.tokens) == 6
         assert srv.steps == 5
 
-    def test_steady_step_sends_nothing_and_launches_one_program(self, rng):
-        """Between two decode steps with no admission nothing travels
-        host -> device — explicit puts (``jnp.asarray``) included, which
-        is what the loop made before its state lived on the device — and
-        exactly one device program is launched."""
+    @pytest.mark.parametrize("kind", ["plain", "module"])
+    def test_steady_step_sends_nothing_and_launches_one_program(self, rng,
+                                                                kind):
+        """Between two decode steps — or two rounds drafted from the
+        model's own module — with no admission nothing travels host ->
+        device — explicit puts (``jnp.asarray``) included, which is what
+        the loop made before its state lived on the device — and exactly
+        one device program is launched."""
         import jax
 
-        srv = self._server(_lm("rope", max_len=32), False)
+        srv = self._server(_lm("rope", max_len=32) if kind == "plain"
+                           else _module_lm(), False)
         srv.submit(_prompts(rng, (5,))[0], 20)
         for _ in range(3):
             srv.step()
-        if _executions(lambda: srv.engine.decode()) != 1:
+        probe = (srv.engine.decode if kind == "plain"
+                 else lambda: srv.engine.decode_spec(1))
+        if _executions(probe) != 1:
             pytest.skip("this jax's CPU trace does not show launches")
         srv.step()          # books the extra block's token too
         with jax.transfer_guard_host_to_device("disallow_explicit"):
@@ -1318,11 +1535,13 @@ class TestPipelinedLoop:
         # 40 dispatches, the first not ahead; one more span reads the last
         assert [sp.attrs["ahead"] for sp in decode] == [0] + [1] * 39 + [0]
         assert [sp.attrs["live"] for sp in decode] == [1] * 40 + [0]
-        # the fused path reads synchronously: never ahead
+        # the fused path is read one dispatch behind too: two dispatches
+        # of four steps for eight tokens, the second ahead
         fused = DecodeServer(lm, slots=2, max_len=64, fuse_steps=4)
         fused.submit(_prompts(rng, (5,))[0], 9)
         fused.drain()
-        assert fused.stats()["decode_ahead_share"] == 0.0
+        assert fused.stats()["decode_dispatches"] == 2
+        assert fused.stats()["decode_ahead_share"] == 0.5
 
 
 class TestKernelRead:

@@ -287,7 +287,9 @@ class TestFailover:
         """The dryrun smoke's arithmetic, asserted here too: K=4 fused,
         A needs 9 (prefill 1 + 4 on r0 before the kill, then re-prefill
         emits 1 + 3 fused on r1), B needs 5 (prefill 1 + 4 fused) — one
-        shared dispatch on the survivor covers both."""
+        shared dispatch on the survivor covers both. A fused block is
+        read one dispatch behind: r0's is held once ``flush()`` has read
+        it, and a kill before that would have lost its four tokens."""
         lm = _lm()
         reps = [_replica(f"f{i}", slots=2, fuse_steps=4)
                 for i in range(2)]
@@ -298,6 +300,8 @@ class TestFailover:
         fb = router.submit(prompt + 1, 5)
         assert fa.replica_id == "f0" and fb.replica_id == "f1"
         reps[0].step_once()
+        assert len(fa.tokens) == 1
+        reps[0].server.flush()
         assert len(fa.tokens) == 5
         controller.evict("f0", reason="test-kill")
         while reps[1].busy():
@@ -306,6 +310,56 @@ class TestFailover:
         assert reps[0].server.steps == 1 and reps[1].server.steps == 1
         assert np.array_equal(fa.output, _ref(lm, prompt, 9))
         assert np.array_equal(fb.output, _ref(lm, prompt + 1, 5))
+
+    @pytest.mark.parametrize("kind", ["fused", "module"])
+    def test_a_kill_with_a_block_unread_costs_recompute_not_tokens(self,
+                                                                   kind):
+        """The same kill WITHOUT the ``flush()``: f0 dies with its one
+        dispatched block unread — K=4 fused steps, or a round drafted
+        from the model's own module — and that block's tokens die with
+        it. A holds its prefill's token alone, re-prefills from prompt+1
+        on the survivor (emitting 1) and decodes the 7 it still lacks
+        there, beside B's 4: two fused dispatches where the flushed kill
+        needed one. Both streams are the reference's, token for token."""
+        if kind == "fused":
+            lm, kw = _lm(), {"fuse_steps": 4}
+        else:
+            lm, kw = _lm(
+                "module", num_kv_heads=None, d_ff=16, norm="rmsnorm",
+                tie_embeddings=False, num_experts=8, experts_per_token=2,
+                norm_topk_prob=True, mixers=("mla",) * 2,
+                ffns=("glu", "moe"), glu_width=32,
+                mtp={"loss_weight": 0.3},
+                mla={"q_lora_rank": 16, "kv_lora_rank": 16,
+                     "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+                     "v_head_dim": 12, "gate": False}), {}
+        reps = [_replica(f"f{i}", lm, slots=2, **kw) for i in range(2)]
+        router = FleetRouter(reps)
+        controller = FleetController(router, None, evict_timeout_s=5.0)
+        prompt = np.arange(1, 9, dtype=np.int32)
+        fa = router.submit(prompt, 9)
+        fb = router.submit(prompt + 1, 5)
+        assert fa.replica_id == "f0" and fb.replica_id == "f1"
+        while reps[0].server._unread is None:   # prefill, one dispatch
+            reps[0].step_once()
+        assert len(fa.tokens) == 1 and set(reps[0].server._unread[2]) == {0}
+        controller.evict("f0", reason="test-kill")
+        assert fa.emitted == fa.tokens and len(fa.tokens) == 1
+        while reps[1].busy():
+            reps[1].step_once()
+        assert fa.finished and fb.finished
+        # the unfailed run (``generate`` holds no latent rows: a server's)
+        whole = DecodeServer(lm, slots=2, max_len=64, **kw)
+        want = [whole.submit(prompt, 9), whole.submit(prompt + 1, 5)]
+        whole.drain()
+        assert np.array_equal(fa.output, want[0].output)
+        assert np.array_equal(fb.output, want[1].output)
+        if kind == "fused":
+            assert np.array_equal(fa.output, _ref(lm, prompt, 9))
+        assert reps[0].server.steps == 0        # its block was never read
+        assert reps[1].server.decode_tokens == 7 + 4
+        if kind == "fused":
+            assert reps[1].server.steps == 2
 
     def test_fully_emitted_requeue_completes_without_survivor_work(self):
         """A max_new=1 split request whose handoff never installed: the

@@ -35,6 +35,15 @@ be made so, here.
 
 Scopes ``kda.proj``, ``kda.scan`` and ``kda.step`` name the parts in a
 device trace.
+
+The decay may also be **one number a head** (Gated DeltaNet, arXiv:2412.06464:
+``S' = exp(g_t) S_{t-1}``, ``models/gdn.py``): ``g`` then has a last axis of
+1 where KDA's has ``dk``. Both forms take it so. It is the same recurrence,
+the same chunk loop, inverse and state update; only the products inside a
+chunk differ: a scalar decay leaves ``k_t . k_i`` and ``q_t . k_i`` plain
+matmuls, each times ``exp(G_t - G_i)``, a ``[c, c]`` matrix a head, where a
+per-channel decay has to enter every term of the dot product (``[c, c, dk]``
+on the VPU).
 """
 
 from __future__ import annotations
@@ -47,7 +56,8 @@ from jax import lax
 
 from deeplearning4j_tpu.scopes import scope
 
-__all__ = ["CHUNK", "init_kda", "kda_mixer", "kda_scan", "kda_step"]
+__all__ = ["CHUNK", "init_kda", "kda_mixer", "kda_scan", "kda_step",
+           "conv_rows", "live_tail", "mask_dead", "recur", "l2norm"]
 
 CHUNK = 64
 _HI = lax.Precision.HIGHEST
@@ -82,7 +92,7 @@ def init_kda(key, d_model: int, num_heads: int, head_dim: int, conv: int,
             "o_norm": {"g": jnp.ones((head_dim,), dtype)}}
 
 
-def _l2norm(x):
+def l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _EPS)
 
 
@@ -103,8 +113,9 @@ def _unit_lower_inverse(n, eye, mm):
 
 def kda_scan(q, k, v, g, beta, state):
     """The chunked recurrence. ``q, k`` [b, t, H, dk], ``v`` [b, t, H, dv],
-    ``g`` [b, t, H, dk] (log-decay, <= 0), ``beta`` [b, t, H], all float32;
-    ``state`` [b, H, dk, dv]. Returns ``(o [b, t, H, dv], state)``."""
+    ``g`` [b, t, H, dk] (log-decay, <= 0; [b, t, H, 1]: one decay a head),
+    ``beta`` [b, t, H], all float32; ``state`` [b, H, dk, dv]. Returns
+    ``(o [b, t, H, dv], state)``."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     c = min(CHUNK, t)
@@ -128,13 +139,21 @@ def kda_scan(q, k, v, g, beta, state):
     def step(s, xs):
         qc, kc, vc, gc, bc = xs                 # [b, H, c, dk] ... [b, H, c]
         cum = jnp.cumsum(gc, axis=2)            # G_t
-        # decay from position i to position t >= i, per channel
-        rel = jnp.where(keep[:, :, None],
-                        cum[:, :, :, None, :] - cum[:, :, None, :, :],
-                        -jnp.inf)
-        kd = kc[:, :, None, :, :] * jnp.exp(rel)            # [b,H,t,i,dk]
-        a = jnp.sum(kc[:, :, :, None, :] * kd, axis=-1)     # k_t . k_i
-        qk = jnp.sum(qc[:, :, :, None, :] * kd, axis=-1)    # q_t . k_i
+        if gc.shape[-1] == 1:
+            # one decay a head: exp(G_t - G_i) is a [c, c] matrix and the
+            # dot products are the MXU's
+            decay = jnp.exp(jnp.where(
+                keep, cum - jnp.swapaxes(cum, 2, 3), -jnp.inf))
+            a = mm("bhtk,bhik->bhti", kc, kc) * decay       # k_t . k_i
+            qk = mm("bhtk,bhik->bhti", qc, kc) * decay      # q_t . k_i
+        else:
+            # decay from position i to position t >= i, per channel
+            rel = jnp.where(keep[:, :, None],
+                            cum[:, :, :, None, :] - cum[:, :, None, :, :],
+                            -jnp.inf)
+            kd = kc[:, :, None, :, :] * jnp.exp(rel)        # [b,H,t,i,dk]
+            a = jnp.sum(kc[:, :, :, None, :] * kd, axis=-1)   # k_t . k_i
+            qk = jnp.sum(qc[:, :, :, None, :] * kd, axis=-1)  # q_t . k_i
         into = jnp.exp(cum)
         rhs = bc[..., None] * (vc - mm("bhtk,bhkv->bhtv", kc * into, s))
         w = mm("bhti,bhiv->bhtv", _unit_lower_inverse(
@@ -153,9 +172,9 @@ def kda_scan(q, k, v, g, beta, state):
 
 
 def kda_step(q, k, v, g, beta, state):
-    """The recurrence for one position a row: ``q, k, g`` [b, H, dk], ``v``
-    [b, H, dv], ``beta`` [b, H], float32; ``state`` [b, H, dk, dv].
-    Returns ``(o [b, H, dv], state)``."""
+    """The recurrence for one position a row: ``q, k, g`` [b, H, dk] (``g``
+    [b, H, 1]: one decay a head), ``v`` [b, H, dv], ``beta`` [b, H], float32;
+    ``state`` [b, H, dk, dv]. Returns ``(o [b, H, dv], state)``."""
     # one pass over the state gives both S'^T k and S'^T q (S' = diag(a) S,
     # so S'^T x = S^T (a * x)); o = S_t^T q = S'^T q + beta (k . q) u
     decay = jnp.exp(g)
@@ -174,6 +193,58 @@ def _conv(rows, taps):
     width = taps.shape[0]
     t = rows.shape[1] - (width - 1)
     return sum(rows[:, j:j + t] * taps[j] for j in range(width))
+
+
+def conv_rows(qkv, taps, state):
+    """The short convolution of a delta-rule mixer: ``qkv`` [b, t, C] (the
+    projections side by side) after the ``K - 1`` rows the positions before
+    left, through ``taps`` [K, C] and SiLU. ``state`` = ``(S, tail [b, K-1,
+    C])`` or None (a request's start: a tail of zeros, no matrix yet).
+    Returns ``(S or None, tail, rows [b, K-1 + t, C], mixed [b, t, C]
+    float32)``."""
+    b = qkv.shape[0]
+    if state is None:
+        s0 = None
+        tail = jnp.zeros((b, taps.shape[0] - 1, qkv.shape[-1]), qkv.dtype)
+    else:
+        s0, tail = state
+    rows = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+    mixed = jax.nn.silu(_conv(rows.astype(jnp.float32),
+                              taps.astype(jnp.float32)))
+    return s0, tail, rows, mixed
+
+
+def mask_dead(g, beta, live):
+    """The rule for a row that holds no token: ``beta = 0`` and ``g = 0``
+    (``g`` [b, t, H, .], ``beta`` [b, t, H], ``live`` [b, t] or None)."""
+    if live is None:
+        return g, beta
+    return (jnp.where(live[:, :, None, None], g, 0.0),
+            jnp.where(live[:, :, None], beta, 0.0))
+
+
+def live_tail(rows, live, width: int):
+    """The convolution tail as of each row's last live position: the
+    ``width - 1`` rows of ``rows`` [b, K-1 + t, C] that end there."""
+    b, t = rows.shape[0], rows.shape[1] - (width - 1)
+    n_live = (jnp.full((b,), t) if live is None
+              else jnp.sum(live, axis=1))
+    idx = n_live[:, None] + jnp.arange(width - 1)[None, :]
+    return jnp.take_along_axis(rows, idx[:, :, None], axis=1)
+
+
+def recur(q, k, v, g, beta, state, s0, names):
+    """``kda_step`` on a carried state and one position, else ``kda_scan``,
+    under the mixer's own scope names ``(step, scan)``. ``q, k, v, g, beta``
+    [b, t, H, .]; ``state``: what the mixer was handed; ``s0`` the matrix to
+    start from."""
+    if state is not None and q.shape[1] == 1:
+        with scope(names[0]):
+            o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            s0)
+            return o[:, None], s
+    with scope(names[1]):
+        return kda_scan(q, k, v, g, beta, s0)
 
 
 def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
@@ -197,40 +268,21 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
         width = p["conv_q"].shape[0]
         taps = jnp.concatenate(
             [p[n] for n in ("conv_q", "conv_k", "conv_v")], axis=-1)
-        if state is None:
-            s0 = None
-            tail = jnp.zeros((b, width - 1, qkv.shape[-1]), qkv.dtype)
-        else:
-            s0, tail = state
-        rows = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
-        mixed = jax.nn.silu(_conv(rows.astype(f32), taps.astype(f32)))
+        s0, tail, rows, mixed = conv_rows(qkv, taps, state)
         q, k, v = (a.reshape(b, t, h, -1) for a in jnp.split(mixed, 3, -1))
         dk = q.shape[-1]
-        q = _l2norm(q) * dk ** -0.5
-        k = _l2norm(k)
+        q = l2norm(q) * dk ** -0.5
+        k = l2norm(k)
         a = (x @ cast(p["wa"])).astype(f32) + p["dt_bias"].astype(f32)
         g = lower * jax.nn.sigmoid(
             jnp.exp(p["a_log"].astype(f32))[:, None] * a.reshape(b, t, h, dk))
         beta = jax.nn.sigmoid((x @ cast(p["wb"])).astype(f32))   # [b, t, H]
         gate = jax.nn.sigmoid((x @ cast(p["wg"])).astype(f32))
-        if live is not None:
-            g = jnp.where(live[:, :, None, None], g, 0.0)
-            beta = jnp.where(live[:, :, None], beta, 0.0)
-        # the tail as of the last live position: the K-1 rows that end there
-        n_live = (jnp.full((b,), t) if live is None
-                  else jnp.sum(live, axis=1))
-        idx = n_live[:, None] + jnp.arange(width - 1)[None, :]
-        new_tail = jnp.take_along_axis(rows, idx[:, :, None], axis=1)
+        g, beta = mask_dead(g, beta, live)
+        new_tail = live_tail(rows, live, width)
         if s0 is None:
             s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
-    if state is not None and t == 1:
-        with scope("kda.step"):
-            o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            s0)
-            o = o[:, None]
-    else:
-        with scope("kda.scan"):
-            o, s = kda_scan(q, k, v, g, beta, s0)
+    o, s = recur(q, k, v, g, beta, state, s0, ("kda.step", "kda.scan"))
     with scope("kda.proj"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + _EPS) \
             * p["o_norm"]["g"].astype(f32)

@@ -54,7 +54,18 @@ A layer may describe its router and its share (``TransformerLM``'s ``moe=``):
   chosen experts it holds and leaves out what the others would add. Nothing
   stands in for the absent chips. ``info["load"]`` is over the held experts.
 - **a shared expert**: one more SwiGLU expert that every token passes
-  through with weight 1 (``p["shared"]``), every chip alike.
+  through with weight 1 (``p["shared"]``), every chip alike; with
+  ``p["shared"]["gate"]`` [D] its output is first multiplied by
+  ``sigmoid(x . gate)``, one number a token (``qwen3_next``).
+
+The share goes with either router: the softmax router of the head of this
+page keeps its E outputs and its k a token just the same.
+
+A prompt of more than ``2 ROW_BLOCK`` rows takes the sorted form
+``ROW_BLOCK`` rows at a time: the form gathers one row of ``D`` numbers for
+every (token, expert) pair, held here or not, and at ten pairs a token a
+28,672-token prompt's would be 2.3 GB in float32 for one layer's output
+alone.
 
 Scopes ``moe.route``, ``moe.experts`` and ``moe.shared`` name the parts in a
 device trace.
@@ -105,16 +116,21 @@ DENSE_MAX_TOKENS = 1024
 # layer takes 0.72 ms where the dense form took 2.05.
 REACHED_MAX_PAIRS_PER_EXPERT = 2
 
+# Rows a pass of the sorted form takes of a prompt longer than twice this
+# (the module's docstring); the ladders' long rungs are multiples of it.
+ROW_BLOCK = 4096
+
 
 def init_experts(key, d_model: int, d_ff: int, num_experts: int, dtype, *,
                  held: Optional[int] = None, bias: bool = False,
-                 shared_width: int = 0):
+                 shared_width: int = 0, shared_gate: bool = False):
     """Glorot-normal router and stacked expert matrices: ``router``
     [D, E], ``w_gate`` / ``w_up`` [held, D, F], ``w_down`` [held, F, D]
     (``held`` defaults to E: every expert is here). ``bias``: an expert
     bias ``bias`` [E] for choosing (normal x 0.01: small, and not zero, so
     that a seeded model exercises it). ``shared_width`` > 0: a shared
-    expert ``shared.{w_gate, w_up, w_down}`` of that width."""
+    expert ``shared.{w_gate, w_up, w_down}`` of that width, and with
+    ``shared_gate`` its gate vector ``shared.gate`` [D]."""
     kr, kg, ku, kd = jax.random.split(key, 4)
 
     def glorot(k, shape, fan_in, fan_out):
@@ -136,6 +152,9 @@ def init_experts(key, d_model: int, d_ff: int, num_experts: int, dtype, *,
         p["shared"] = {"w_gate": glorot(ks[0], (d, w), d, w),
                        "w_up": glorot(ks[1], (d, w), d, w),
                        "w_down": glorot(ks[2], (w, d), w, d)}
+        if shared_gate:
+            p["shared"]["gate"] = glorot(
+                jax.random.fold_in(ks[0], 1), (d,), d, 1)
     return p
 
 
@@ -297,6 +316,12 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
             read = jnp.sum(load > 0, dtype=jnp.int32)
         elif n <= DENSE_MAX_TOKENS:
             y = _dense_experts(x, weights, local, *map(cast, stored))
+        elif n > 2 * ROW_BLOCK and n % ROW_BLOCK == 0:
+            matrices = tuple(map(cast, stored))
+            y = lax.map(
+                lambda rows: _grouped_experts(*rows, *matrices),
+                tuple(a.reshape((-1, ROW_BLOCK) + a.shape[1:])
+                      for a in (x, weights, local, live))).reshape(n, -1)
         else:
             y = _grouped_experts(x, weights, local, live, *map(cast, stored))
     if "shared" in p:
@@ -304,7 +329,13 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
             sh = p["shared"]
             hidden = (jax.nn.silu(x @ cast(sh["w_gate"]))
                       * (x @ cast(sh["w_up"])))
-            y = y + jnp.where(live[:, None], hidden @ cast(sh["w_down"]),
-                              0.0)
+            keep = live[:, None]
+            out = hidden @ cast(sh["w_down"])
+            if "gate" in sh:
+                out = out * jax.nn.sigmoid(jnp.dot(
+                    x.astype(jnp.float32), sh["gate"].astype(jnp.float32),
+                    precision=lax.Precision.HIGHEST))[:, None].astype(
+                        out.dtype)
+            y = y + jnp.where(keep, out, 0.0)
     return y.astype(x.dtype), {"experts": experts, "weights": weights,
                                "load": load, "read": read}

@@ -31,6 +31,7 @@ from deeplearning4j_tpu import dtypes as dtypes_mod
 from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.models import dsa as dsa_mod
+from deeplearning4j_tpu.models import gdn as gdn_mod
 from deeplearning4j_tpu.models import kda as kda_mod
 from deeplearning4j_tpu.models import mla as mla_mod
 from deeplearning4j_tpu.models import routed_experts
@@ -177,7 +178,9 @@ class TransformerLM:
                  indexers: Optional[Sequence[Optional[str]]] = None,
                  dsa: Optional[Dict[str, Any]] = None,
                  rope_scaling: Optional[Dict[str, Any]] = None,
-                 mtp: Optional[Dict[str, Any]] = None):
+                 mtp: Optional[Dict[str, Any]] = None,
+                 gdn: Optional[Dict[str, Any]] = None,
+                 attn: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -234,7 +237,20 @@ class TransformerLM:
         # predicts token i + 2 through the model's own embedding and head.
         # ``loss`` adds its cross entropy times ``loss_weight``, and
         # ``serving.DecodeServer`` drafts from it (speculative rounds).
-        kinds = ("attn", "kda", "mla"), ("mlp", "glu", "moe")
+        # mixers[i] may also be "gdn" (Gated DeltaNet, models/gdn.py; ``gdn``
+        # = {key_heads, value_heads, head_dim, conv}: a recurrent [Hv, dk,
+        # dk] state and a convolution tail, one decay a head). ``attn``
+        # describes an "attn" layer whose sizes are its own: {head_dim (not
+        # d_model // num_heads), rotary_dim (RoPE on the first so many
+        # dimensions of a head; default all), head_norm (an RMSNorm over
+        # each head of q and of k, gain [head_dim], instead of ``qk_norm``'s
+        # over the whole projection), gate (``wq`` is twice as wide: per
+        # head, head_dim of query then head_dim of gate, and the heads'
+        # output is multiplied by sigmoid(gate) before ``wo``)}. ``moe``
+        # without ``n_group`` keeps the softmax router over ``num_experts``
+        # and names the share alone ({first, held}); ``shared_gate``: the
+        # shared expert's output times sigmoid(x . w), one number a token.
+        kinds = ("attn", "kda", "mla", "gdn"), ("mlp", "glu", "moe")
         self.mixers = tuple(mixers) if mixers is not None else (
             "attn",) * num_layers
         self.ffns = tuple(ffns) if ffns is not None else (
@@ -246,11 +262,12 @@ class TransformerLM:
                                  f"for each of the {num_layers} layers")
         for kind, sizes, used in (("kda", kda, self.mixers),
                                   ("mla", mla, self.mixers),
+                                  ("gdn", gdn, self.mixers),
                                   ("glu", glu_width, self.ffns),
                                   ("moe", num_experts, self.ffns)):
             if kind in used and not sizes:
-                raise ValueError(f"a {kind!r} layer needs its sizes "
-                                 "(kda=, mla=, glu_width=, num_experts=)")
+                raise ValueError(f"a {kind!r} layer needs its sizes (kda=, "
+                                 "mla=, gdn=, glu_width=, num_experts=)")
         self.indexers = tuple(indexers) if indexers is not None else (
             None,) * num_layers
         if len(self.indexers) != num_layers or dsa is None and any(
@@ -272,6 +289,15 @@ class TransformerLM:
         self.dsa = dict(dsa) if dsa else None
         self.kda = dict(kda) if kda else None
         self.mla = dict(mla) if mla else None
+        self.gdn = dict(gdn) if gdn else None
+        if self.gdn and self.gdn["value_heads"] % self.gdn["key_heads"]:
+            raise ValueError(
+                f"gdn: value_heads={self.gdn['value_heads']} must be a "
+                f"multiple of key_heads={self.gdn['key_heads']}")
+        self.attn = dict(attn) if attn else None
+        self.head_dim = int((attn or {}).get("head_dim",
+                                             d_model // num_heads))
+        self.rotary_dim = int((attn or {}).get("rotary_dim", self.head_dim))
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         kind = (rope_scaling or {}).get("rope_type", "yarn")
         if kind != "yarn" or rope_scaling and pos_encoding != "rope":
@@ -310,11 +336,13 @@ class TransformerLM:
         # max_len); "rope": rotary embedding on q/k — relative positions,
         # the modern long-context choice
         assert pos_encoding in ("learned", "rope")
-        if pos_encoding == "rope" and (d_model // num_heads) % 2:
+        if pos_encoding == "rope" and (
+                self.rotary_dim % 2 or self.rotary_dim > self.head_dim):
             raise ValueError(
-                f"RoPE needs an even head_dim (got "
-                f"{d_model // num_heads}: d_model={d_model} / "
-                f"num_heads={num_heads}); the rotation pairs dimensions")
+                f"RoPE needs an even head_dim, or an even rotary_dim within "
+                f"it (got {self.rotary_dim} of head_dim {self.head_dim}: "
+                f"d_model={d_model} / num_heads={num_heads} unless attn= "
+                "says otherwise); the rotation pairs dimensions")
         self.pos_encoding = pos_encoding
         # GQA/MQA: fewer key/value heads than query heads — KV cache and
         # wk/wv params shrink by num_heads/num_kv_heads; K/V are repeated
@@ -372,7 +400,7 @@ class TransformerLM:
     def init(self) -> "TransformerLM":
         key = jax.random.PRNGKey(self.seed)
         D, F, V, L = self.d_model, self.d_ff, self.vocab_size, self.max_len
-        Dh = D // self.num_heads
+        Dh = self.head_dim
         dt = self.policy.param_dtype
 
         def dense(key, fan_in, fan_out):
@@ -404,6 +432,8 @@ class TransformerLM:
                 blk["kda"] = kda_mod.init_kda(
                     k[0], D, self.num_heads, self.kda["head_dim"],
                     self.kda["conv"], dt)
+            elif self.mixers[i] == "gdn":
+                blk["gdn"] = gdn_mod.init_gdn(k[0], D, self.gdn, dt)
             elif self.mixers[i] == "mla":
                 blk["mla"] = mla_mod.init_mla(k[0], D, self.num_heads,
                                               self.mla, dt)
@@ -411,13 +441,18 @@ class TransformerLM:
                     blk["mla"]["indexer"] = dsa_mod.init_indexer(
                         k[1], D, self.mla["q_lora_rank"], self.dsa, dt)
             else:
+                a = self.attn or {}
+                width = self.num_heads * Dh
                 blk["attn"] = {
-                    "wq": dense(k[0], D, D),
+                    "wq": dense(k[0], D, width * (2 if a.get("gate") else 1)),
                     "wk": dense(k[1], D, self.num_kv_heads * Dh),
                     "wv": dense(k[2], D, self.num_kv_heads * Dh),
-                    "wo": dense(k[3], D, D),
+                    "wo": dense(k[3], width, D),
                 }
-                if self.qk_norm:
+                if a.get("head_norm"):
+                    blk["attn"]["q_norm"] = {"g": jnp.ones((Dh,), dt)}
+                    blk["attn"]["k_norm"] = {"g": jnp.ones((Dh,), dt)}
+                elif self.qk_norm:
                     blk["attn"]["q_norm"] = {"g": jnp.ones((D,), dt)}
                     blk["attn"]["k_norm"] = {
                         "g": jnp.ones((self.num_kv_heads * Dh,), dt)}
@@ -426,7 +461,8 @@ class TransformerLM:
                 blk["moe"] = routed_experts.init_experts(
                     k[4], D, F, self.num_experts, dt, held=m.get("held"),
                     bias=bool(m.get("bias")),
-                    shared_width=int(m.get("shared_width", 0)))
+                    shared_width=int(m.get("shared_width", 0)),
+                    shared_gate=bool(m.get("shared_gate")))
             elif self.ffns[i] == "glu":
                 G = self.glu_width
                 blk["glu"] = {"w1": dense(k[4], D, G),
@@ -465,8 +501,7 @@ class TransformerLM:
         flash block shapes put head_dim on the minor (lane) axis, so a
         sublane-aligned head_dim >= half a lane tile keeps the MXU fed
         without pathological padding."""
-        head_dim = self.d_model // self.num_heads
-        return head_dim >= 64 and head_dim % 8 == 0
+        return self.head_dim >= 64 and self.head_dim % 8 == 0
 
     def _attn_impl(self, t: Optional[int] = None, *,
                    train: bool = False) -> str:
@@ -498,7 +533,7 @@ class TransformerLM:
         if train:
             return "flash" if self._head_dim_tiles() else "xla"
         seq = t if t is not None else self.max_len
-        if seq >= 4096 and self.d_model // self.num_heads >= 64:
+        if seq >= 4096 and self.head_dim >= 64:
             return "flash"
         return "xla"
 
@@ -529,11 +564,11 @@ class TransformerLM:
         (``routed_experts.routed_ffn``'s ``info``: chosen experts, their
         weights, the live load per expert held).
 
-        The other mixers leave other things behind. A ``kda`` layer
-        returns ``(h, S, tail)``, its recurrent state and convolution tail
-        as of each row's last live position, and continues from ``state``
-        = ``(S, tail)`` (default: a request's start). An ``mla`` layer
-        returns ``(h, latent, None)``, each position's latent row
+        The other mixers leave other things behind. A ``kda`` or ``gdn``
+        layer returns ``(h, S, tail)``, its recurrent state and convolution
+        tail as of each row's last live position, and continues from
+        ``state`` = ``(S, tail)`` (default: a request's start). An ``mla``
+        layer returns ``(h, latent, None)``, each position's latent row
         [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
         attends a cache of such rows instead of the block's own.
 
@@ -547,7 +582,7 @@ class TransformerLM:
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
         x = self._norm(h, blk["ln1"])
-        if "kda" in blk or "mla" in blk:
+        if "kda" in blk or "mla" in blk or "gdn" in blk:
             if sequence_parallel:
                 raise NotImplementedError(
                     "sequence parallelism is written for 'attn' layers only")
@@ -556,6 +591,10 @@ class TransformerLM:
                     x, blk["kda"], num_heads=self.num_heads,
                     lower=self.kda["lower"], cast=policy.cast_compute,
                     live=live, state=state)
+            elif "gdn" in blk:
+                y, k, v = gdn_mod.gdn_mixer(
+                    x, blk["gdn"], dims=self.gdn, eps=self.norm_eps,
+                    cast=policy.cast_compute, live=live, state=state)
             else:
                 y, k, v = self._mla(blk["mla"], x, attention, positions,
                                     train, indexer, selection)
@@ -563,24 +602,29 @@ class TransformerLM:
         # ``attn.proj`` is closed wherever the attention core is called and
         # opened again for the output projection: a scope open round a
         # Pallas kernel would rename it in the trace (``scopes.py``)
+        sizes = self.attn or {}
+        per_head = bool(sizes.get("head_norm"))
+        gate = None
         with scope("attn.proj"):
             q = x @ policy.cast_compute(blk["attn"]["wq"])
-            if self.qk_norm:
+            if self.qk_norm and not per_head:
                 q = _rmsnorm(q, blk["attn"]["q_norm"]["g"])
             q = q.reshape(b, t, self.num_heads, -1)
+            if sizes.get("gate"):   # per head: [query ; gate] in wq's columns
+                q, gate = jnp.split(q, 2, axis=-1)
             k = x @ policy.cast_compute(blk["attn"]["wk"])
-            if self.qk_norm:
+            if self.qk_norm and not per_head:
                 k = _rmsnorm(k, blk["attn"]["k_norm"]["g"])
             k = k.reshape(b, t, self.num_kv_heads, -1)
+            if per_head:
+                q = _rmsnorm(q, blk["attn"]["q_norm"]["g"], self.norm_eps)
+                k = _rmsnorm(k, blk["attn"]["k_norm"]["g"], self.norm_eps)
             v = (x @ policy.cast_compute(blk["attn"]["wv"])).reshape(
                 b, t, self.num_kv_heads, -1)
             if self.pos_encoding == "rope":
                 if positions is None:
                     positions = jnp.arange(t)
-                q = _rope(q, positions, self.rope_theta,
-                          self.rope_interleaved, self.rope_scaling)
-                k = _rope(k, positions, self.rope_theta,
-                          self.rope_interleaved, self.rope_scaling)
+                q, k = (self._rope_head(a, positions) for a in (q, k))
         # the returned k/v stay at num_kv_heads (what the KV cache
         # stores); attention sees them repeated per query-head group
         if attention is not None:
@@ -608,9 +652,21 @@ class TransformerLM:
             o = grouped_query_attention(q, k, v, causal=True,
                                         window=self.attn_window)
         with scope("attn.proj"):
+            if gate is not None:
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(x.dtype)
             h = h + o.reshape(b, t, -1) @ policy.cast_compute(
                 blk["attn"]["wo"])
         return self._ffn(blk, h, live, moe_info, train), k, v
+
+    def _rope_head(self, x, positions):
+        """RoPE on the first ``rotary_dim`` dimensions of each head of ``x``
+        [b, t, H, Dh] (all of them unless ``attn=`` says fewer)."""
+        r = self.rotary_dim
+        turned = _rope(x[..., :r], positions, self.rope_theta,
+                       self.rope_interleaved, self.rope_scaling)
+        return turned if r == x.shape[-1] else jnp.concatenate(
+            [turned, x[..., r:]], axis=-1)
 
     def _ffn(self, blk, h, live, moe_info, train=False):
         """The block's second half on the residual stream ``h`` [b, t, D]:
@@ -627,7 +683,8 @@ class TransformerLM:
                 norm_topk_prob=self.norm_topk_prob,
                 cast=policy.cast_compute,
                 live=None if live is None else live.reshape(b * t),
-                groups=m and (m["n_group"], m["topk_group"], m["scale"]),
+                groups=(m["n_group"], m["topk_group"], m["scale"])
+                if m and "n_group" in m else None,
                 first=m["first"] if m else 0, train=train)
             if moe_info is not None:
                 moe_info.append(info)
@@ -1009,7 +1066,7 @@ class TransformerLM:
             "glu_width": self.glu_width, "kda": self.kda, "mla": self.mla,
             "moe": self.moe, "indexers": list(self.indexers),
             "dsa": self.dsa, "rope_scaling": self.rope_scaling,
-            "mtp": self.mtp,
+            "mtp": self.mtp, "gdn": self.gdn, "attn": self.attn,
         }
 
     def _ensure_init(self):
@@ -1060,8 +1117,8 @@ class TransformerLM:
         if self.hybrid:
             raise NotImplementedError(
                 "generate() and generate_beam() carry key/value caches only: "
-                "a 'kda' layer's recurrent state, an 'mla' layer's latent "
-                "rows and an indexer's keys are held by "
+                "a 'kda' or 'gdn' layer's recurrent state, an 'mla' layer's "
+                "latent rows and an indexer's keys are held by "
                 "serving.DecodeServer's slot cache")
         policy = self.policy
         cdt = policy.compute_dtype
@@ -1330,16 +1387,20 @@ class TransformerLM:
             if mixer == "attn":
                 blk["attn"] = {"wq": col, "wk": kv_col, "wv": kv_col,
                                "wo": row}
-                if self.qk_norm:
+                if self.qk_norm or (self.attn or {}).get("head_norm"):
                     blk["attn"]["q_norm"] = {"g": P()}
                     blk["attn"]["k_norm"] = {"g": P()}
             elif mixer == "kda":
-                # no Megatron split is written for the two other mixers:
+                # no Megatron split is written for the three other mixers:
                 # every chip holds them whole
                 blk["kda"] = {n: P() for n in (
                     "wq", "wk", "wv", "wa", "wb", "wg", "wo", "conv_q",
                     "conv_k", "conv_v", "a_log", "dt_bias")}
                 blk["kda"]["o_norm"] = {"g": P()}
+            elif mixer == "gdn":
+                blk["gdn"] = {n: P() for n in (
+                    "w_qkvz", "w_ba", "wo", "conv", "a_log", "dt_bias")}
+                blk["gdn"]["o_norm"] = {"g": P()}
             else:
                 names = ["wdkv", "wukv", "wo"] + (
                     ["wq_a", "wq_b"] if self.mla.get("q_lora_rank")
@@ -1366,6 +1427,8 @@ class TransformerLM:
                 if self.moe and self.moe.get("shared_width"):
                     blk["moe"]["shared"] = {"w_gate": col, "w_up": col,
                                             "w_down": row}
+                    if self.moe.get("shared_gate"):
+                        blk["moe"]["shared"]["gate"] = P()
             elif ffn == "glu":
                 blk["glu"] = {"w1": col, "w3": col, "w2": row}
             else:

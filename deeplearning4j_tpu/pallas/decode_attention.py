@@ -206,9 +206,13 @@ def _kernel(n_ref, slot_ref, lo_ref, hi_ref, first_ref, pos_ref,
 def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
                           window: Optional[int] = None,
                           block_rows: Optional[int] = None,
-                          interpret: bool = False, live=None):
+                          interpret: bool = False, live=None,
+                          hkv: Optional[int] = None):
     """Attention of ``q [S, Q, H, Dh]`` at absolute ``positions [S, Q]``
-    against layer ``layer`` of the ``[L, S, T_max, Hkv, Dh]`` pools: query
+    against layer ``layer`` of the ``[L, S, T_max, Hkv, Dh]`` pools (or
+    their rows ``[L, S, T_max Hkv, Dh]`` with ``hkv`` kv heads, the shape a
+    pool of heads wider than a lane tile is stored in:
+    ``serving/kv_cache.pool_shape``): query
     ``(s, i)`` attends keys ``t <= positions[s, i]`` of slot ``s`` (and
     ``t > positions[s, i] - window``). Returns ``[S, Q, H, Dh]`` in
     ``q.dtype`` — the mathematics of ``grouped_query_attention`` over
@@ -218,8 +222,12 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
     request: nothing of the others is fetched or multiplied and their
     rows are zeros. ``None``: every slot is live."""
     s_, nq, h, dh = q.shape
-    n_layers, _, t_max, hkv, _ = pool_k.shape
-    block = block_rows or pool_block_rows(pool_k.shape, pool_k.dtype)
+    if pool_k.ndim == 4:
+        n_layers, t_max = pool_k.shape[0], pool_k.shape[2] // hkv
+    else:
+        n_layers, _, t_max, hkv, _ = pool_k.shape
+    block = block_rows or pool_block_rows(
+        (n_layers, s_, t_max, hkv, dh), pool_k.dtype)
     if block is None or (t_max * hkv) % block or h % hkv:
         raise ValueError(
             f"pool {pool_k.shape} ({pool_k.dtype}) with {h} query heads "

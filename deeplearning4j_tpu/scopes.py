@@ -35,7 +35,7 @@ import jax
 SCOPES = {
     "lm.embed": "token gather, learned positions, cast into the compute dtype",
     "attn.proj": "wq/wk/wv, QK-norm, head reshapes, RoPE, the kv-head repeat; "
-                 "reopened for wo and its residual add",
+                 "reopened for the output gate, wo and its residual add",
     "attn.core": "the XLA attention op (scores, mask, softmax, values) where "
                  "no kernel applies",
     "kv.write": "a decode-family step's new rows into the slot pool or a "
@@ -54,6 +54,10 @@ SCOPES = {
     "kda.proj": "KDA projections, convolutions, gates, output",
     "kda.step": "KDA's one-position recurrence (decode)",
     "kda.scan": "KDA's chunked recurrence (prefill, training)",
+    "gdn.proj": "Gated DeltaNet projections, convolution, gates, output norm "
+                "and output projection",
+    "gdn.step": "Gated DeltaNet's one-position recurrence (decode)",
+    "gdn.scan": "Gated DeltaNet's chunked recurrence (prefill, training)",
     "mla.proj": "MLA query/latent projections, norms, RoPE, output",
     "mla.attend": "MLA attention over latent rows or expanded keys",
     "dsa.index": "the lightning indexer: index keys, scores, selection",

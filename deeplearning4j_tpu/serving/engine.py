@@ -172,13 +172,14 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     ks, vs = [], []
     left = {"latent": [], "kda": [], "conv": []}    # the other layer kinds
     # the pad tail holds no token: it takes no part in routed experts and
-    # moves no recurrent state (a 'kda' layer's state is as of prompt_len)
+    # moves no recurrent state (a 'kda' or 'gdn' layer's state is as of
+    # prompt_len)
     live = ((jnp.arange(p) < prompt_len)[None]
             if model.num_experts or model.hybrid else None)
     moe_info: list = []
     for blk in params["blocks"]:
         h, kk, vv = model._block(blk, h, live=live, moe_info=moe_info)
-        if "kda" in blk:
+        if "kda" in blk or "gdn" in blk:
             left["kda"].append(kk)
             left["conv"].append(vv)
         elif "mla" in blk:
@@ -186,8 +187,8 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         else:
             ks.append(kk.astype(cdt))
             vs.append(vv.astype(cdt))
-    # a 'kda' layer's [1, H, dk, dk] state and [1, K-1, C] tail and an
-    # 'mla' layer's [1, P, r + dr] rows, each into the slot's place in its
+    # a 'kda' or 'gdn' layer's [1, H, dk, dk] state and [1, K-1, C] tail and
+    # an 'mla' layer's [1, P, r + dr] rows, each into the slot's place in its
     # layer's own array: the slot's old state is overwritten whole
     def into(pool, new):
         if new.shape[-1] < pool.shape[-1]:      # a latent row's zero lanes
@@ -219,11 +220,14 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         pool_v, v_scale = quant(vcat, kv["v"], kv["v_scale"])
         new_kv.update(k=pool_k, v=pool_v, k_scale=k_scale, v_scale=v_scale)
     elif ks:
-        new_kv.update(
-            k=lax.dynamic_update_slice(
-                kv["k"], kcat.astype(kv["k"].dtype), (0, slot, 0, 0, 0)),
-            v=lax.dynamic_update_slice(
-                kv["v"], vcat.astype(kv["v"].dtype), (0, slot, 0, 0, 0)))
+        def put(pool, cat):
+            cat = cat.astype(pool.dtype)
+            if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
+                cat = cat.reshape(cat.shape[:2] + (-1, cat.shape[-1]))
+            return lax.dynamic_update_slice(
+                pool, cat, (0, slot) + (0,) * (pool.ndim - 2))
+
+        new_kv.update(k=put(kv["k"], kcat), v=put(kv["v"], vcat))
     h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
     logits = model._unembed(params, h_last)
     with scope("lm.head"):
@@ -299,11 +303,17 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
         return None
     if pool_kernel is None:
         pool_kernel = not flash_default_interpret()
+    # the pools' logical axes: rows of wide heads are stored flat
+    # (``kv_cache.pool_shape``)
+    hkv = model.num_kv_heads
+    dims = pool["k"].shape if pool["k"].ndim == 5 else (
+        pool["k"].shape[:2] + (pool["k"].shape[2] // hkv, hkv,
+                               pool["k"].shape[3]))
     block = None
     if pool_kernel and "k_scale" not in pool:
-        block = kernel.pool_block_rows(pool["k"].shape, pool["k"].dtype)
+        block = kernel.pool_block_rows(dims, pool["k"].dtype)
     if block is None:
-        keys = jnp.arange(pool["k"].shape[2])
+        keys = jnp.arange(dims[2])
         mask = keys <= positions[:, :, None]               # [S, Q, T]
         if window is not None:
             mask &= keys > positions[:, :, None] - window
@@ -321,9 +331,10 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
                 return kernel.pool_decode_attention(
                     q, pool["k"], pool["v"], li, positions, window=window,
                     block_rows=block, interpret=flash_default_interpret(),
-                    live=live)
+                    live=live, hkv=hkv)
             views = (dequant_slab(
-                pool[name][li],
+                pool[name][li] if pool[name].ndim == 5
+                else pool[name][li].reshape(dims[1:]),
                 pool[name + "_scale"][li] if name + "_scale" in pool
                 else None, dtype) for name in ("k", "v"))
             return grouped_query_attention(q, *views, mask=mask)
@@ -646,9 +657,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
 
     Each layer meets its own kind of state, at its place among the layers
     of its kind: an ``attn`` layer the K/V pools, an ``mla`` layer its
-    latent rows (``_latent_attention``), a ``kda`` layer its recurrent
-    matrix and convolution tail, which it takes and hands back advanced
-    for the live slots and untouched for the others. In a model with
+    latent rows (``_latent_attention``), a ``kda`` or ``gdn`` layer its
+    recurrent matrix and convolution tail (one list, in the layers' order),
+    which it takes and hands back advanced for the live slots and untouched
+    for the others. In a model with
     learned sparse attention a layer with an indexer also meets its index
     keys, selects, and its selection goes to the layers after it
     (``_latent_layers``); ``selections`` receives each such layer's."""
@@ -668,7 +680,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     seen = {"attn": 0, "mla": 0, "kda": 0}
     selection = None
     for blk, kw in zip(params["blocks"], latent):
-        kind = next(k for k in seen if k in blk)
+        kind = "kda" if "gdn" in blk else next(k for k in seen if k in blk)
         j = seen[kind]
         seen[kind] += 1
         if kind == "kda":
@@ -1119,14 +1131,14 @@ class DecodeEngine:
         self._first_draft: Dict[int, object] = {}
 
         # ---- speculative-decoding configuration
-        if (model.kda or model.dsa) and (
+        if (model.kda or model.gdn or model.dsa) and (
                 draft_model is not None or draft_layers or model.mtp):
             raise ValueError(
                 "speculative decoding is not written for a model with 'kda' "
-                "layers or an indexer: a rejected draft token would have to "
-                "be taken back out of the recurrent state, which keeps no "
-                "history to rewind to, and the verify forward knows no "
-                "indexer's keys: it neither writes them nor selects")
+                "or 'gdn' layers or an indexer: a rejected draft token would "
+                "have to be taken back out of the recurrent state, which "
+                "keeps no history to rewind to, and the verify forward knows "
+                "no indexer's keys: it neither writes them nor selects")
         if model.mtp and (draft_model is not None or draft_layers):
             raise ValueError(
                 "this model drafts from its own multi-token-prediction "

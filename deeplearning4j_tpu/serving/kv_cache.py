@@ -43,7 +43,9 @@ State by layer kind (``TransformerLM(mixers=...)``): what a position or a
 request leaves behind depends on the layer's mixer, and one slot holds all
 of it side by side:
 
-- ``attn`` layers: the K/V pools above, ``[L_attn, S, T_max, Hkv, Dh]``;
+- ``attn`` layers: the K/V pools above, ``[L_attn, S, T_max, Hkv, Dh]``
+  (heads wider than one lane tile: stored ``[L_attn, S, T_max Hkv, Dh]``,
+  the same rows in the same order; ``pool_shape``);
 - ``mla`` layers: latent rows, one ``[S, T_max, r + dr]`` array a layer
   (``latent``; each row padded with zeros to whole 128-lane tiles,
   ``latent_row_width``), written at the cursor and masked like keys;
@@ -51,9 +53,11 @@ of it side by side:
   ``"full"`` layers, ``models/dsa.py``): beside their latent rows, index
   keys, one ``[S, T_max, dI]`` array a layer (``index``), written and
   masked as the latent rows are; a ``"shared"`` layer keeps none;
-- ``kda`` layers: a recurrent matrix ``[S, H, dk, dk]`` in float32
-  (``kda``) and a convolution tail ``[S, K - 1, 3 H dk]`` (``conv``) a
-  layer. They have no time axis: a prefill writes them **as of the
+- ``kda`` and ``gdn`` layers (the delta-rule mixers): a recurrent matrix
+  in float32 (``kda``: ``[S, H, dk, dk]``, a ``gdn`` layer's ``[S, Hv, dk,
+  dk]``) and a convolution tail (``conv``: ``[S, K - 1, 3 H dk]``, a
+  ``gdn`` layer's ``[S, K - 1, 2 Hk dk + Hv dk]``) a layer, in the layers'
+  own order. They have no time axis: a prefill writes them **as of the
   prompt's length** (pad rows move no state, ``models/kda.py``), a decode
   step replaces a live slot's and leaves a frozen slot's as they are, and
   the next prefill into the slot overwrites them whole.
@@ -86,6 +90,7 @@ __all__ = [
     "resolve_kv_dtype",
     "kv_pool_nbytes",
     "pool_layout",
+    "pool_shape",
     "max_slots_in_budget",
     "dequant_slab",
     "requant_write_slab",
@@ -122,22 +127,52 @@ def _elem_bytes(name: str) -> int:
 
 
 def _pool_dims(model, slots: int, max_len: int):
-    dh = model.d_model // model.num_heads
     return (len(model.layers_of("attn")), slots, max_len,
-            model.num_kv_heads, dh)
+            model.num_kv_heads, model.head_dim)
 
 
-def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
+def pool_shape(dims, kv_dtype: str, sharded: bool = False):
+    """The shape a K (or V) pool of the logical ``dims`` = ``(L, S, T_max,
+    Hkv, Dh)`` is stored in. XLA:TPU tiles the two minor axes ``[Hkv, Dh]``
+    by ``(Hkv, 128)`` when there are few kv heads; with ``Dh`` of one lane
+    tile that is the byte order of the ``[T_max Hkv, Dh]`` rows the decode
+    kernel reads (``pallas/decode_attention.py``: a free reshape), with a
+    wider head it is another order and the reshape copies the pool (2 x 2
+    GiB a decode step at 64 slots x 32,768 x 2 heads of 256; PERF.md section
+    6, PR 39). So a pool of wider heads is stored as those rows, ``(L, S,
+    T_max Hkv, Dh)``: row ``t Hkv + h`` is position ``t`` of kv head ``h``.
+    The int8 codec and a mesh's head split keep the five axes they are
+    written for (neither reads through the kernel)."""
+    n, s, t, hkv, dh = dims
+    if dh > 128 and dh % 128 == 0 and kv_dtype != "int8" and not sharded:
+        return (n, s, t * hkv, dh)
+    return tuple(dims)
+
+
+def _recurrent_dims(model, kind: str):
+    """``(heads, dk, tail width)`` of a ``kda`` or ``gdn`` layer's state."""
+    if kind == "kda":
+        h, dk = model.num_heads, model.kda["head_dim"]
+        return h, dk, 3 * h * dk
+    from deeplearning4j_tpu.models.gdn import gdn_widths
+
+    ck, cv = gdn_widths(model.gdn)
+    return model.gdn["value_heads"], model.gdn["head_dim"], 2 * ck + cv
+
+
+def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
+                sharded: bool = False) -> dict:
     """``{kind: [(shape, dtype name), ...]}`` of every array a slot pool
     of this model holds, by what it is: ``kv`` (the K and the V pool, and
     their int8 scales), ``latent`` (one array an ``mla`` layer),
     ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
-    ``recurrent`` and ``conv`` (one each a ``kda`` layer). A kind the
-    model has no layer of is an empty list."""
+    ``recurrent`` and ``conv`` (one each a ``kda`` or ``gdn`` layer, in the
+    layers' order). A kind the model has no layer of is an empty list.
+    ``sharded``: the pool lies over a mesh (``pool_shape``)."""
     out = {"kv": [], "latent": [], "index": [], "recurrent": [], "conv": []}
     dims = _pool_dims(model, slots, max_len)
     if dims[0]:
-        out["kv"] += [(dims, kv_dtype)] * 2
+        out["kv"] += [(pool_shape(dims, kv_dtype, sharded), kv_dtype)] * 2
         if kv_dtype == "int8":
             out["kv"] += [(dims[:2] + (dims[3],), "float32")] * 2
     if model.mla:
@@ -148,12 +183,12 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str) -> dict:
     if model.dsa:
         out["index"] = [((slots, max_len, model.dsa["head_dim"]),
                          kv_dtype)] * model.indexers.count("full")
-    if model.kda:
-        n, dk = len(model.layers_of("kda")), model.kda["head_dim"]
-        out["recurrent"] = [((slots, model.num_heads, dk, dk),
-                             "float32")] * n
-        out["conv"] = [((slots, model.kda["conv"] - 1,
-                         3 * model.num_heads * dk), kv_dtype)] * n
+    for kind in model.mixers:
+        if kind in ("kda", "gdn"):
+            h, dk, width = _recurrent_dims(model, kind)
+            taps = (model.kda if kind == "kda" else model.gdn)["conv"]
+            out["recurrent"].append(((slots, h, dk, dk), "float32"))
+            out["conv"].append(((slots, taps - 1, width), kv_dtype))
     return out
 
 
@@ -213,7 +248,8 @@ def dequant_slab(slab, scale, dtype):
 def write_pool_rows(pool, scale, layer, values, rows, positions):
     """Write ``values [S, q, Hkv, Dh]`` at ``(rows [S], positions
     [S, q])`` of layer ``layer`` (a Python int) into the whole
-    ``[L, S, T, Hkv, Dh]`` pool; returns ``(pool, scale)`` with ``scale``
+    ``[L, S, T, Hkv, Dh]`` pool (or its ``[L, S, T Hkv, Dh]`` rows:
+    ``pool_shape``); returns ``(pool, scale)`` with ``scale``
     the ``[L, S, Hkv]`` sidecar (``None`` when unquantized).
 
     The one write the decode-family programs make. It scatters into the
@@ -232,6 +268,11 @@ def write_pool_rows(pool, scale, layer, values, rows, positions):
     written."""
     import jax.numpy as jnp
 
+    if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
+        hkv = values.shape[2]
+        at = (layer, rows[:, None, None],
+              positions[:, :, None] * hkv + jnp.arange(hkv))
+        return pool.at[at].set(values.astype(pool.dtype)), None
     at = (layer, rows[:, None], positions)
     if scale is None:
         return pool.at[at].set(values.astype(pool.dtype)), None
@@ -349,15 +390,21 @@ class SlotKVCache:
         if model.hybrid and (self.kv_dtype == "int8"
                              or registry is not None):
             raise ValueError(
-                "a model with 'kda' or 'mla' layers is served from an "
-                "unquantized pool on one chip: the int8 codec and the "
-                "mesh's head split are written for K/V pools only, not for "
-                "latent rows, an indexer's keys or recurrent state")
-        layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype)
+                "a model with 'kda', 'gdn' or 'mla' layers is served from "
+                "an unquantized pool on one chip: the int8 codec and the "
+                "mesh's head split are written for a model whose every layer "
+                "keeps K/V rows, not for latent rows, an indexer's keys or "
+                "recurrent state beside them")
+        layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype,
+                             sharded=registry is not None)
         self.latent, self.index, self.kda, self.conv = (
             [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
             for kind in ("latent", "index", "recurrent", "conv"))
-        shape = _pool_dims(model, self.slots, self.max_len)
+        # the pools' logical axes (L, S, T_max, Hkv, Dh), whichever shape
+        # they are stored in
+        self.pool_dims = _pool_dims(model, self.slots, self.max_len)
+        shape = pool_shape(self.pool_dims, self.kv_dtype,
+                           registry is not None)
         if not shape[0]:        # no layer keeps keys and values
             self.k = self.v = self.k_scale = self.v_scale = None
         elif self.kv_dtype == "int8":

@@ -100,8 +100,11 @@ runs):
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
   blocks a layer the pool kernel fetches for the slots dispatched, and
   what a read of every slot's cursor, live or frozen, would fetch —
-  counted from the host's slot table, ``_book_kv_blocks``; for a model with
-  learned sparse attention ``keys_cached``, ``keys_attended`` and
+  counted from the host's slot table, ``_book_kv_blocks``, as are
+  ``kv_rows``, the K/V rows the live slots hold up to their cursors over the
+  'attn' layers, and, for a model with 'kda' or 'gdn' layers,
+  ``state_slots``, the slots whose recurrent state the step moves; for a
+  model with learned sparse attention ``keys_cached``, ``keys_attended`` and
   ``rows_gathered`` instead) — the decode
   dispatch (``live`` slots; 0: none may be owed a token), then the
   read-back of the block dispatched a step earlier, whatever its kind.
@@ -195,9 +198,12 @@ class DecodeServer:
         pool = self.engine.cache
         self._kv_block = (None if pool.quantized or mesh is not None
                           or pool.k is None
-                          else pool_block_rows(pool.k.shape, pool.k.dtype))
+                          else pool_block_rows(pool.pool_dims, pool.k.dtype))
         self.kv_blocks = 0
         self.kv_blocks_pool = 0
+        self.kv_rows = 0
+        self.state_slots = 0
+        self._recurrent = bool(model.kda or model.gdn)
         # learned sparse attention: latent rows the dispatched slots held
         # below their cursors, and rows their queries attended (at most
         # ``topk`` each), summed over decode steps, live slots and 'mla'
@@ -705,9 +711,12 @@ class DecodeServer:
         every slot, frozen cursors included — into the server's totals
         and two registry counters, and returned as the ``serve.decode``
         span's attrs (none where the pool has no kernel read, or nothing
-        is dispatched). For a model with learned sparse attention the
-        attrs are ``keys_cached``, ``keys_attended`` and ``rows_gathered``
-        instead: the latent rows the live slots hold up to their cursors,
+        is dispatched); beside them ``kv_rows`` (a pool of K/V rows: the
+        rows the live slots hold up to their cursors, over the 'attn'
+        layers) and ``state_slots`` (a model with 'kda' or 'gdn' layers: the
+        live slots, whose recurrent state the step moves). For a model with
+        learned sparse attention the attrs are ``keys_cached``,
+        ``keys_attended`` and ``rows_gathered`` instead: the latent rows the live slots hold up to their cursors,
         the rows their queries attend, and the rows the step's gathers fetch
         (``index_topk`` a trip of the program's own work list,
         ``dsa.live_slots``), over the 'mla' layers. No device read."""
@@ -727,14 +736,25 @@ class DecodeServer:
             self.keys_cached += attrs["keys_cached"]
             self.keys_attended += attrs["keys_attended"]
             self.rows_gathered += attrs["rows_gathered"]
+        if live and self.engine.cache.k is not None:
+            # a query at cursor c attends c + 1 rows (its own among them)
+            # of every 'attn' layer, its window's at most
+            layers, _, t_max = self.engine.cache.pool_dims[:3]
+            held = np.minimum(self._cursors[list(live)] + 1,
+                              self.model.attn_window or t_max)
+            attrs["kv_rows"] = int(held.sum()) * layers
+            self.kv_rows += attrs["kv_rows"]
+        if live and self._recurrent:
+            attrs["state_slots"] = len(live)
+            self.state_slots += len(live)
         if live and self._kv_block is not None:
-            _, _, t_max, hkv, _ = self.engine.cache.k.shape
+            _, _, t_max, hkv, _ = self.engine.cache.pool_dims
             lo, hi = key_block_span(
                 self._cursors, self._cursors, block=self._kv_block,
                 hkv=hkv, window=self.model.attn_window, t_max=t_max)
             blocks = hi - lo + 1
-            attrs = {"kv_blocks": int(blocks[list(live)].sum()),
-                     "kv_blocks_pool": int(blocks.sum())}
+            attrs.update(kv_blocks=int(blocks[list(live)].sum()),
+                         kv_blocks_pool=int(blocks.sum()))
             self.kv_blocks += attrs["kv_blocks"]
             self.kv_blocks_pool += attrs["kv_blocks_pool"]
             self._reg.counter("serve_decode_kv_blocks_total").inc(
@@ -1062,6 +1082,11 @@ class DecodeServer:
             "kv_blocks_pool": self.kv_blocks_pool,
             "kv_blocks_share": (round(self.kv_blocks / self.kv_blocks_pool, 4)
                                 if self.kv_blocks_pool else None),
+            # K/V rows the dispatched slots held up to their cursors, over
+            # the 'attn' layers, and slots whose recurrent state a step
+            # moved, summed over the decode dispatches
+            "kv_rows": self.kv_rows,
+            "state_slots": self.state_slots,
             "decode_tokens": self.decode_tokens,
             "dispatches_per_token": (
                 round(self.steps / self.decode_tokens, 4)

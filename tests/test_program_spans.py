@@ -271,8 +271,8 @@ class TestServeSpans:
         decodes = [s for s in t.spans() if s.name == "serve.decode"]
         emits = [s for s in t.spans() if s.name == "serve.emit"]
         assert [d.attrs for d in decodes] == [
-            {"live": 1, "kind": "plain", "ahead": 0},
-            {"live": 1, "kind": "plain", "ahead": 1},
+            {"live": 1, "kind": "plain", "ahead": 0, "kv_rows": 40},
+            {"live": 1, "kind": "plain", "ahead": 1, "kv_rows": 42},
             {"live": 0, "kind": "plain", "ahead": 0}]
         assert [e.attrs for e in emits] == [
             {"tokens": 1, "retired": 0}, {"tokens": 1, "retired": 1}]
@@ -299,8 +299,8 @@ class TestServeSpans:
         # ahead of the first's read, and a span that only reads
         assert [kw for a, n, kw in annotations
                 if a == "enter" and n == "dl4j.serve.decode"] == [
-            {"live": 1, "kind": "fused", "ahead": 0},
-            {"live": 1, "kind": "fused", "ahead": 1},
+            {"live": 1, "kind": "fused", "ahead": 0, "kv_rows": 18},
+            {"live": 1, "kind": "fused", "ahead": 1, "kv_rows": 22},
             {"live": 0, "kind": "fused", "ahead": 0}]
         assert entered["dl4j.serve.prefill"]["request"] == req.id
         assert set(entered["dl4j.serve.prefill"]) == {

@@ -65,7 +65,25 @@ def _hybrid():
              "shared_width": 16, "first": 0, "held": 4}).init()
 
 
-MODELS = {"dense": _dense, "olmoe": _olmoe, "hybrid": _hybrid}
+def _qwen3next(**attn):
+    """Gated DeltaNet layers beside a gated attention layer with its own
+    head size, softmax-routed experts over a share, a gated shared expert."""
+    return TransformerLM(
+        vocab_size=128, d_model=32, num_heads=2, num_kv_heads=1, num_layers=3,
+        d_ff=16, max_len=MAX_LEN, pos_encoding="rope",
+        dtype_policy="float32", attn_impl="xla", norm="rmsnorm",
+        num_experts=8, experts_per_token=2, norm_topk_prob=True,
+        tie_embeddings=False, seed=2, mixers=("gdn", "gdn", "attn"),
+        ffns=("moe",) * 3,
+        gdn={"key_heads": 1, "value_heads": 2, "head_dim": 16, "conv": 4},
+        attn={"head_dim": 32, "rotary_dim": 8, "head_norm": True,
+              "gate": True, **attn},
+        moe={"shared_width": 16, "shared_gate": True, "first": 0,
+             "held": 4}).init()
+
+
+MODELS = {"dense": _dense, "olmoe": _olmoe, "hybrid": _hybrid,
+          "qwen3next": _qwen3next}
 
 
 def _lower(lm, program, **kw):
@@ -239,6 +257,32 @@ def test_no_scope_is_open_round_the_decode_kernel(monkeypatch):
     text = _lower(lm, "decode", pool_kernel=True).as_text(debug_info=True)
     assert len(seen) == lm.num_layers and not any(seen), seen
     assert "kv.write/scatter" in text and "kv.write/jit(cumsum)" in text
+
+
+def test_a_recurrence_beside_the_pool_kernel_keeps_both_names(monkeypatch):
+    """The mixture's decode program: ``gdn.proj`` and ``gdn.step`` name the
+    recurrent layers (``gdn.scan`` the prefill's), nothing of ``kda.*``
+    does, and the pool kernel of the one attention layer is called with no
+    scope open."""
+    seen = []
+    real = decode_attention.pl.pallas_call
+
+    def pallas_call(*a, **kw):
+        call = real(*a, **kw)
+
+        def run(*operands):
+            seen.append(_open_names())
+            return call(*operands)
+        return run
+
+    monkeypatch.setattr(decode_attention.pl, "pallas_call", pallas_call)
+    lm = _qwen3next(head_dim=128)
+    text = _lower(lm, "decode", pool_kernel=True).as_text(debug_info=True)
+    assert len(seen) == 1 and not any(seen), seen
+    assert "gdn.proj/" in text and "gdn.step/" in text
+    assert not re.search(r"gdn\.scan/|kda\.(proj|step|scan)/", text)
+    prefill = _lower(lm, "prefill").as_text(debug_info=True)
+    assert "gdn.scan/" in prefill and "gdn.step/" not in prefill
 
 
 # ---- (c) a scope is metadata ------------------------------------------------

@@ -32,6 +32,7 @@ any failed phase makes the exit code non-zero.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -181,8 +182,11 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
     experts (``moe = (hidden, expert width, experts, per token, token
     counts)``: OLMoE's widths; 8 rows, a decode step's 32 and a 4,096-token
     prefill, so all three of ``routed_ffn``'s forms) against a masked loop over
-    the experts. Both sides of that check feed the MXU bf16 operands and
-    accumulate in float32; they differ in the order of the sum over
+    the experts, and past the dense form once more with a quarter of a
+    four times wider router's experts held here, so that the sorted form
+    runs in passes (``_moe_share_error``). Both sides of that check feed
+    the MXU bf16 operands and accumulate in float32; they differ in the
+    order of the sum over
     experts and in where the weighted hidden state is rounded to bf16
     (2^-9 an element), which ``tol`` holds with room and a wrong expert,
     weight or dropped token does not."""
@@ -294,27 +298,68 @@ def _moe_errors(d_model, d_ff, n_experts, per_token, token_counts, *, dtype):
         return jnp.dot(a, cast(b), preferred_element_type=jnp.float32)
 
     # the weights are arguments: a jitted closure would bake 1.6 GB of
-    # constants into each program
-    @jax.jit
-    def loop(x, p):
+    # constants into each program; ``first``: the router's index of the
+    # first expert held
+    @functools.partial(jax.jit, static_argnames="first")
+    def loop(x, p, first=0):
         w, e = routed_experts.route(x, p["router"], per_token)
 
         def one(i, acc):
-            wi = jnp.sum(jnp.where(e == i, w, 0.0), -1, keepdims=True)
+            wi = jnp.sum(jnp.where(e - first == i, w, 0.0), -1, keepdims=True)
             hid = jax.nn.silu(dot(x, p["w_gate"][i])) * dot(x, p["w_up"][i])
             return acc + wi * dot(hid.astype(dtype), p["w_down"][i])
 
-        return lax.fori_loop(0, n_experts, one,
+        return lax.fori_loop(0, p["w_gate"].shape[0], one,
                              jnp.zeros(x.shape, jnp.float32))
 
-    ffn = jax.jit(lambda x, p: routed_experts.routed_ffn(
-        x, p, experts_per_token=per_token, cast=cast)[0])
+    ffn = jax.jit(lambda x, p, first=0: routed_experts.routed_ffn(
+        x, p, experts_per_token=per_token, cast=cast, first=first),
+        static_argnames="first")
     errors = {}
     for n in token_counts:
         x = jax.random.normal(jax.random.fold_in(key, n), (n, d_model),
                               jnp.float32).astype(dtype)
-        errors[f"moe_n{n}"] = _rel_err(ffn(x, p), loop(x, p).astype(dtype))
+        errors[f"moe_n{n}"] = _rel_err(ffn(x, p)[0], loop(x, p).astype(dtype))
+        if n > routed_experts.DENSE_MAX_TOKENS:
+            errors[f"moe_share_n{n}"] = _moe_share_error(
+                x, p, per_token, ffn, loop)
     return errors
+
+
+def _moe_share_error(x, p, per_token, ffn, loop, share=4, keep=64):
+    """The sorted form where this chip holds one in ``share`` of the
+    router's experts (the second run of them), so that it runs in passes
+    over the pairs held here: against the masked loop over the held experts
+    (``_moe_errors``' two programs), and the first ``keep`` rows bit for bit
+    whatever the others choose -- re-drawn, or all following the row that
+    chose most experts held here, which takes several passes."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import routed_experts
+
+    n, d = x.shape
+    held = p["w_gate"].shape[0]
+    p = dict(p, router=jax.random.normal(
+        jax.random.PRNGKey(share), (d, share * held), jnp.float32) * 0.05)
+    assert routed_experts._pass_rows(
+        n, per_token, held, share * held, False) < n * per_token
+    base, info = ffn(x, p, first=held)
+    local = info["experts"] - held
+    leader = jnp.argmax(jnp.sum((local >= 0) & (local < held), axis=1))
+    redrawn = jax.random.normal(jax.random.PRNGKey(keep), x.shape,
+                                jnp.float32).astype(x.dtype)
+    runs = [int(info["run"])]
+    for others in (redrawn, jnp.broadcast_to(x[leader], x.shape)):
+        some, info = ffn(x.at[keep:].set(others[keep:]), p, first=held)
+        assert bool(jnp.all(some[:keep] == base[:keep])), (
+            "sorted experts in passes: a row's bits moved with the other "
+            "rows' routing")
+        runs.append(int(info["run"]))
+    assert runs[2] > runs[0] > 0, f"the crowd took no more passes: {runs}"
+    assert bool(jnp.all(ffn(x, p, first=held)[0] == base)), (
+        "not the same bits twice")
+    return _rel_err(base, loop(x, p, first=held).astype(x.dtype))
 
 
 # ---------------------------------------------------------------------------

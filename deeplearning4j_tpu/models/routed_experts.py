@@ -34,10 +34,19 @@ The experts take one of three forms, chosen from shapes at trace time
   which both forms read, and the extra products hide under that read; no
   sort, no gather, no second copy of the weights.
 - many tokens (a long prompt, a training batch): the (token, expert)
-  pairs are sorted by expert and the three projections are grouped
-  matmuls (``jax.lax.ragged_dot``: each row meets only its expert's
-  weights), then each token gathers its k results back. Rows that hold no
-  token (``live`` false) sort behind the last group and reach no expert.
+  pairs are sorted by expert, those whose expert is held here first, and
+  the three projections are grouped matmuls (``jax.lax.ragged_dot``: each
+  row meets only its expert's weights) over the pairs held here alone,
+  ``_pass_rows`` sorted rows at a time: a pass gathers its rows' tokens,
+  multiplies, and adds each result into its token's row. The size of a
+  pass comes from the shapes (what an even router sends to the experts
+  held, and a quarter more); how many passes run is what the block's
+  routing needs, none where no token chose an expert here -- one loop, one
+  program, no pair dropped. Pairs held elsewhere and rows that hold no
+  token (``live`` false) sort behind the last group and reach no pass.
+  Where one pass would take every pair anyway (every expert held here) or
+  the trace takes a gradient, it is one pass with no loop, and each token
+  gathers its k results back.
 
 A layer may describe its router and its share (``TransformerLM``'s ``moe=``):
 
@@ -61,11 +70,23 @@ A layer may describe its router and its share (``TransformerLM``'s ``moe=``):
 The share goes with either router: the softmax router of the head of this
 page keeps its E outputs and its k a token just the same.
 
+A row's sum over its experts has one order, fixed by the row's own choices
+whatever the other rows of the block chose, and repeats bit for bit: the
+dense form's contraction runs over the experts by index, the one-pass
+sorted form adds a row's k results in the router's order (largest weight
+first), and the passes add them by ascending expert index, as the dense
+form does -- a pass's scatter-add applies its rows in sorted order onto a
+row that starts at zero, one product at a time, so where the passes are
+cut does not show (``chip_smoke.py`` holds the chip to it).
+
 A prompt of more than ``2 ROW_BLOCK`` rows takes the sorted form
-``ROW_BLOCK`` rows at a time: the form gathers one row of ``D`` numbers for
-every (token, expert) pair, held here or not, and at ten pairs a token a
-28,672-token prompt's would be 2.3 GB in float32 for one layer's output
-alone.
+``ROW_BLOCK`` rows at a time: the sort, the loads and the passes' bound are
+a block's, so a 28,672-token prompt runs the program of a 4,096-token
+block seven times over (before the passes the form gathered one row of
+``D`` numbers for every (token, expert) pair, held here or not: at ten
+pairs a token that prompt's would have been 2.3 GB in float32 for one
+layer's output alone; a pass's rows are a quarter of a block's pairs at
+Qwen3-Next's share).
 
 Scopes ``moe.route``, ``moe.experts`` and ``moe.shared`` name the parts in a
 device trace.
@@ -73,6 +94,8 @@ device trace.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -116,9 +139,16 @@ DENSE_MAX_TOKENS = 1024
 # layer takes 0.72 ms where the dense form took 2.05.
 REACHED_MAX_PAIRS_PER_EXPERT = 2
 
-# Rows a pass of the sorted form takes of a prompt longer than twice this
+# Rows the sorted form takes at a time of a prompt longer than twice this
 # (the module's docstring); the ladders' long rungs are multiples of it.
 ROW_BLOCK = 4096
+
+# A pass of the sorted form (``_pass_rows``): what an even router sends to
+# the experts held here and a quarter more, in whole tiles of the grouped
+# matmul's rows. At Qwen3-Next's block (4,096 rows x 10, 128 of 512 held)
+# that is 12,800 rows of 40,960.
+PASS_MARGIN = 1.25
+PASS_TILE = 128
 
 
 def init_experts(key, d_model: int, d_ff: int, num_experts: int, dtype, *,
@@ -235,10 +265,61 @@ def _combine(weights, experts, held: int):
         axis=1)
 
 
-def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down):
-    """(token, expert) pairs sorted by expert, three grouped matmuls, each
-    token's k results gathered back and summed in top-k order (no
-    scatter-add: a row's sum has one order whatever its neighbours are)."""
+def _pass_rows(n: int, k: int, held: int, num_experts: int,
+               train: bool) -> int:
+    """Rows one pass of the sorted form takes of a block of ``n`` rows with
+    ``k`` pairs each, where ``held`` of the router's ``num_experts`` are
+    here: what an even router sends here, ``n k held / num_experts``, and
+    ``PASS_MARGIN`` more, in whole ``PASS_TILE``s -- or all ``n k`` pairs in
+    one pass where that is no fewer (every expert held, or nearly) and where
+    the trace takes a gradient (the passes are a ``while``, which has no
+    reverse mode). From shapes alone: a block has one program whatever its
+    tokens choose, and the router's skew shows in the number of passes."""
+    pairs = n * k
+    if train:
+        return pairs
+    tiles = math.ceil(PASS_MARGIN * pairs * held / num_experts / PASS_TILE)
+    return min(tiles * PASS_TILE, pairs)
+
+
+def _expert_rows(xs, sizes, w_gate, w_up, w_down):
+    """The three grouped matmuls on rows sorted by expert, ``sizes [held]``
+    of them each; float32. Rows past the last group are not the grouped
+    matmul's to define: zero."""
+    gate = lax.ragged_dot(xs, w_gate, sizes,
+                          preferred_element_type=jnp.float32)
+    up = lax.ragged_dot(xs, w_up, sizes,
+                        preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    out = lax.ragged_dot(hidden, w_down, sizes,
+                         preferred_element_type=jnp.float32)
+    in_group = jnp.arange(xs.shape[0]) < jnp.sum(sizes)
+    return jnp.where(in_group[:, None], out, 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("pass_rows",))
+def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down, *,
+                     pass_rows: int):
+    """(token, expert) pairs sorted by expert, the pairs whose expert is
+    held here first; those rows alone are gathered, meet the three grouped
+    matmuls and go back to their tokens, ``pass_rows`` sorted rows at a
+    time, in as many passes as the pairs held here need: ``(y [N, D]
+    float32, rows run int32)``. No pair is dropped and no pass is run for
+    pairs held elsewhere; no token choosing an expert here is no pass.
+
+    A pass adds its rows into ``y`` (a scatter-add, applied in the order of
+    the sorted rows), so a row's sum runs over its experts in ascending
+    order, as the dense form's contraction does, from zero, one product at
+    a time: it does not depend on where the passes were cut, hence not on
+    what the other rows chose, and repeats bit for bit.
+
+    ``pass_rows == N k`` (``_pass_rows``: every expert held, a trace that
+    takes a gradient) is one pass over every pair with no loop, and each
+    token gathers its k results back and sums them in top-k order.
+
+    A ``jit`` of its own: the layers of a program, and the rungs of a
+    ladder that share a block's shapes, trace and lower it once (a serving
+    process's warm start traces and lowers every program it loads)."""
     n, k = experts.shape
     n_experts = w_gate.shape[0]
     # rows without a token, and pairs whose expert is held elsewhere, go to
@@ -249,20 +330,35 @@ def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down):
     order = jnp.argsort(pair_expert, stable=True)           # [N*k]
     sizes = jnp.bincount(pair_expert, length=n_experts + 1)[
         :n_experts].astype(jnp.int32)
-    xs = jnp.take(x, order // k, axis=0)                    # [N*k, D]
-    gate = lax.ragged_dot(xs, w_gate, sizes,
-                          preferred_element_type=jnp.float32)
-    up = lax.ragged_dot(xs, w_up, sizes,
-                        preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    out = lax.ragged_dot(hidden, w_down, sizes,
-                         preferred_element_type=jnp.float32)
-    # rows past the last group are not the grouped matmul's to define
-    in_group = jnp.arange(n * k) < jnp.sum(sizes)
-    out = jnp.where(in_group[:, None], out, 0.0)
-    back = jnp.argsort(order)                               # pair -> row
-    out = jnp.take(out, back, axis=0).reshape(n, k, -1)
-    return jnp.sum(jnp.where(here, weights, 0.0)[..., None] * out, axis=1)
+    weights = jnp.where(here, weights, 0.0)
+    if pass_rows == n * k:
+        out = _expert_rows(jnp.take(x, order // k, axis=0), sizes,
+                           w_gate, w_up, w_down)
+        back = jnp.argsort(order)                           # pair -> row
+        out = jnp.take(out, back, axis=0).reshape(n, k, -1)
+        return (jnp.sum(weights[..., None] * out, axis=1),
+                jnp.int32(pass_rows))
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    passes = (ends[-1] + pass_rows - 1) // pass_rows
+    # the last pass may reach past the pairs: those rows lie in no group
+    order = jnp.pad(order, (0, -(n * k) % pass_rows))
+    pair_weight = weights.reshape(-1)
+
+    def one_pass(p, y):
+        lo = p * pass_rows
+        pairs = lax.dynamic_slice(order, (lo,), (pass_rows,))
+        rows = pairs // k
+        # this pass's share of each group
+        share = jnp.clip(jnp.minimum(ends, lo + pass_rows)
+                         - jnp.maximum(starts, lo), 0)
+        out = _expert_rows(jnp.take(x, rows, axis=0), share,
+                           w_gate, w_up, w_down)
+        return y.at[rows].add(jnp.take(pair_weight, pairs)[:, None] * out)
+
+    y = lax.fori_loop(0, passes, one_pass,
+                      jnp.zeros((n, w_down.shape[2]), jnp.float32))
+    return y, passes * pass_rows
 
 
 def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
@@ -286,7 +382,11 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
     float32, ``info["load"]`` ``[held]`` int32, the live (token, expert)
     pairs each expert held here received, and ``info["read"]`` int32, the
     held experts whose matrices this trace fetches: those with ``load >
-    0`` in the reached form, all of them in the other two."""
+    0`` in the reached form, all of them in the other two, and
+    ``info["run"]`` int32, the sorted rows the passes of the sorted form
+    took through the grouped matmuls (``_grouped_experts``; over
+    ``load.sum()`` it is the form's waste, 1 at best), 0 in the other
+    two."""
     n = x.shape[0]
     held = p["w_gate"].shape[0]
     if live is None:
@@ -305,7 +405,7 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
         stored = (p["w_gate"], p["w_up"], p["w_down"])
         blocks = _reached_blocks(x, experts, stored[0], p["router"].shape[1],
                                  train)
-        read = jnp.int32(held)
+        read, run = jnp.int32(held), jnp.int32(0)
         if blocks is not None:
             # the kernel rounds each fetched block as ``cast`` would
             rounded = jax.eval_shape(cast, stored[0]).dtype
@@ -316,14 +416,21 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
             read = jnp.sum(load > 0, dtype=jnp.int32)
         elif n <= DENSE_MAX_TOKENS:
             y = _dense_experts(x, weights, local, *map(cast, stored))
-        elif n > 2 * ROW_BLOCK and n % ROW_BLOCK == 0:
-            matrices = tuple(map(cast, stored))
-            y = lax.map(
-                lambda rows: _grouped_experts(*rows, *matrices),
-                tuple(a.reshape((-1, ROW_BLOCK) + a.shape[1:])
-                      for a in (x, weights, local, live))).reshape(n, -1)
         else:
-            y = _grouped_experts(x, weights, local, live, *map(cast, stored))
+            blocked = n > 2 * ROW_BLOCK and n % ROW_BLOCK == 0
+            sort = functools.partial(
+                _grouped_experts, pass_rows=_pass_rows(
+                    ROW_BLOCK if blocked else n, experts_per_token, held,
+                    p["router"].shape[1], train))
+            matrices = tuple(map(cast, stored))
+            if blocked:
+                y, run = lax.map(
+                    lambda rows: sort(*rows, *matrices),
+                    tuple(a.reshape((-1, ROW_BLOCK) + a.shape[1:])
+                          for a in (x, weights, local, live)))
+                y, run = y.reshape(n, -1), jnp.sum(run)
+            else:
+                y, run = sort(x, weights, local, live, *matrices)
     if "shared" in p:
         with scope("moe.shared"):
             sh = p["shared"]
@@ -338,4 +445,4 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
                         out.dtype)
             y = y + jnp.where(keep, out, 0.0)
     return y.astype(x.dtype), {"experts": experts, "weights": weights,
-                               "load": load, "read": read}
+                               "load": load, "read": read, "run": run}

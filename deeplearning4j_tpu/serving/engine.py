@@ -241,18 +241,20 @@ def _stack_routing(moe_info):
     """The layers' routing (``routed_experts.routed_ffn``'s ``info``, as
     ``TransformerLM._block`` collects it) as ONE int32 array a serving
     program returns beside its tokens, so that the host reads it in a
-    single transfer: ``[L, E + 2 N k + 1]``, per layer the load ``[E]`` (the
+    single transfer: ``[L, E + 2 N k + 2]``, per layer the load ``[E]`` (the
     live (token, expert) pairs each expert received), then the N rows'
     experts ``[N k]``, then the bits of their float32 weights ``[N k]``
     (0 on a row that holds no token), then how many experts' matrices the
-    layer fetched (``info["read"]``). ``unpack_routing`` is its inverse."""
+    layer fetched (``info["read"]``) and how many sorted rows its passes
+    took (``info["run"]``). ``unpack_routing`` is its inverse."""
     import jax.numpy as jnp
     from jax import lax
 
     def layer(info):
         weights = lax.bitcast_convert_type(info["weights"], jnp.int32)
         return jnp.concatenate([info["load"], info["experts"].reshape(-1),
-                                weights.reshape(-1), info["read"][None]])
+                                weights.reshape(-1), info["read"][None],
+                                info["run"][None]])
 
     return jnp.stack([layer(info) for info in moe_info])
 
@@ -260,13 +262,14 @@ def _stack_routing(moe_info):
 def unpack_routing(packed, num_experts: int, experts_per_token: int):
     """``_stack_routing``'s array on the host (numpy) -> ``(load [L, E]
     int32, experts [L, N, k] int32, weights [L, N, k] float32, read [L]
-    int32)``, views."""
+    int32, run [L] int32)``, views."""
     layers = packed.shape[0]
-    load, rows = packed[:, :num_experts], packed[:, num_experts:-1]
+    load, rows = packed[:, :num_experts], packed[:, num_experts:-2]
     half = rows.shape[1] // 2
     shape = (layers, -1, experts_per_token)
     return (load, rows[:, :half].reshape(shape),
-            rows[:, half:].view(np.float32).reshape(shape), packed[:, -1])
+            rows[:, half:].view(np.float32).reshape(shape), packed[:, -2],
+            packed[:, -1])
 
 
 @traced
@@ -515,6 +518,7 @@ def prefill_carry_layout(model, bucket: int) -> dict:
         "sel_last": ((model.indexers.count("full"), topk), "int32", -1),
         "load": ((n_moe, model.experts_held), "int32", 0),
         "read": ((n_moe,), "int32", 0),
+        "run": ((n_moe,), "int32", 0),
         "chosen": ((n_moe, bucket, k), "int32", 0),
         "weights": ((n_moe, bucket, k), "float32", 0)}
 
@@ -620,6 +624,7 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
             [m["load"] for m in moe_info])
         new["read"] = jnp.maximum(carry["read"], jnp.stack(
             [m["read"] for m in moe_info]))
+        new["run"] = carry["run"] + jnp.stack([m["run"] for m in moe_info])
         for name, got in (("chosen", "experts"), ("weights", "weights")):
             new[name] = lax.dynamic_update_slice(
                 carry[name], jnp.stack([m[got] for m in moe_info]),
@@ -628,7 +633,8 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
         routing = jnp.concatenate(
             [new["load"], new["chosen"].reshape(n_moe, -1),
              lax.bitcast_convert_type(new["weights"], jnp.int32).reshape(
-                 n_moe, -1), new["read"][:, None]], axis=1)
+                 n_moe, -1), new["read"][:, None], new["run"][:, None]],
+            axis=1)
     new = {**carry, **new}
     if model.dsa:
         return tok, key, pool, new, routing, new["sel_last"]

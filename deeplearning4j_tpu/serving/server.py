@@ -94,7 +94,9 @@ runs):
   attention: ``serve.prefill`` is the prompt's last block (``blocks``:
   how many it had) and each block before it a ``serve.prefill_block``
   (``request``, ``slot``, ``block``, ``blocks``) in an earlier step's
-  ``serve.admit``.
+  ``serve.admit``. Routed experts on a share: a ``serve.passes``
+  (``moe_rows_run``, ``moe_pairs_run``) inside the ``serve.prefill`` that
+  read a routing whose sorted form ran in passes (``_read_block``).
 - ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
   ``spec``, ``ahead`` = 1 when the dispatch was issued while the
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
@@ -253,10 +255,14 @@ class DecodeServer:
         # prefills and plain decode steps alike ([Lmoe, held]; an empty
         # array for a dense model); the live rows those layers saw; and of
         # the decode blocks read, how many there were and the (layer,
-        # expert) cells they reached and their programs fetched
+        # expert) cells they reached and their programs fetched; and of
+        # the layers that took the sorted form, the sorted rows their
+        # passes ran and the pairs held here those passes were for
         self.moe_expert_load = np.zeros(
             (model.n_layers("moe"), model.experts_held), np.int64)
         self.moe_rows = 0
+        self.moe_rows_run = 0
+        self.moe_pairs_run = 0
         self.moe_decode_blocks = 0
         self.moe_decode_touched = 0
         self.moe_decode_read = 0
@@ -776,7 +782,12 @@ class DecodeServer:
         (layer, expert) cells that received a token) and ``experts_read``
         (the cells whose matrices the program fetched: the same where it
         was traced in the reached form, layers x held where not;
-        ``models/routed_experts.py``). The rows' experts and
+        ``models/routed_experts.py``). Where a layer took the sorted form,
+        the sorted rows its passes ran and the pairs held here they ran
+        them for are booked too (``stats()``: ``moe_rows_run``,
+        ``moe_pairs_run``) and open a span of their own, ``serve.passes``,
+        with both as attrs: a profiler trace keeps only the attrs a span
+        is opened with (``monitor/trace.py``). The rows' experts and
         weights come in the same array (``engine._stack_routing``):
         ``rows`` = ``(experts, weights)``, None for a dense model.
         ``live_rows`` is how many rows of the program held a token (a
@@ -801,10 +812,13 @@ class DecodeServer:
         toks, packed, selection = jax.device_get((toks, routing, selection))
         # speculative rounds hand over one array a round ([K, L, ...]):
         # each is booked as a block of its own, the span holds the last
+        rows_run = pairs_run = 0
         for one in (packed if packed.ndim == 3 else packed[None]):
-            load, *rows, read = unpack_routing(
+            load, *rows, read, run = unpack_routing(
                 one, self.model.experts_held, self.model.experts_per_token)
             touched, read = int(np.count_nonzero(load)), int(read.sum())
+            rows_run += int(run.sum())
+            pairs_run += int(load[run > 0].sum())
             self.moe_expert_load += load
             self.moe_rows += live_rows
             if decode:
@@ -821,6 +835,12 @@ class DecodeServer:
         if span is not None:
             span.attrs["experts_touched"] = touched
             span.attrs["experts_read"] = read
+        if rows_run:
+            self.moe_rows_run += rows_run
+            self.moe_pairs_run += pairs_run
+            with tracer().span("serve.passes", moe_rows_run=rows_run,
+                               moe_pairs_run=pairs_run):
+                pass
         return toks, rows, selection
 
     def _sweep_expired(self) -> None:
@@ -1117,12 +1137,16 @@ class DecodeServer:
         if self.model.num_experts:
             out["moe_expert_load"] = self.moe_expert_load.tolist()
             out["moe_rows"] = self.moe_rows
+            out["moe_rows_run"] = self.moe_rows_run
+            out["moe_pairs_run"] = self.moe_pairs_run
             # moe_rows: the live rows the expert layers saw (prompt tokens
             # and decode slots); (layer, held expert) cells a decode block
             # reached, and cells whose matrices its program fetched, means;
             # and the (token, expert) pairs that landed on an expert held
             # here, per live row and layer (k when every expert is here;
-            # k x held / num_experts in expectation for a share)
+            # k x held / num_experts in expectation for a share);
+            # moe_rows_run over moe_pairs_run is what the sorted form's
+            # passes ran for each pair they served (1 at best)
             blocks = self.moe_decode_blocks
             out["moe_experts_touched_per_step"] = (
                 round(self.moe_decode_touched / blocks, 4) if blocks

@@ -65,7 +65,8 @@ def test_kernels_tiny():
     assert set(r["rel_err"]) == {
         f"{case}_{k}" for case in ("t128", "t256_w128")
         for k in ("fwd", "dq", "dk", "dv")} | {
-            "decode_t64_kv2_w24", "decode_t64_kv16", "moe_n8", "moe_n1032"}
+            "decode_t64_kv2_w24", "decode_t64_kv16", "moe_n8", "moe_n1032",
+            "moe_share_n1032"}
 
 
 def test_kernels_tolerance_is_enforced():

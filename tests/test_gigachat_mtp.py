@@ -604,6 +604,40 @@ def test_spans_and_counters(small_blocks):
     assert st["compiles"]["decode"] == 1
 
 
+def test_the_blocks_of_a_prompt_add_up_the_rows_their_passes_ran(
+        monkeypatch, small_blocks):
+    """A prompt prefilled in blocks long enough for the sorted experts: each
+    block runs whole passes over its own pairs held here, the carry adds
+    them up, and the last block's routing hands the sum to the server --
+    held to the routing it recorded (four expert layers, the module's among
+    them; 8 rows a block, 8 of 64 experts held)."""
+    from deeplearning4j_tpu.models import routed_experts
+
+    monkeypatch.setattr(routed_experts, "DENSE_MAX_TOKENS", 4)
+    monkeypatch.setattr(routed_experts, "PASS_TILE", 2)
+    lm = _lm()
+    pass_rows = routed_experts._pass_rows(8, K, HELD, E, False)
+    assert pass_rows < 8 * K
+    program_trace.tracer().clear()
+    server, (req,) = _served(lm, [(21, 3)], buckets=(16, 32),
+                             record_routing=True)
+    experts = np.asarray(req.routing[0][0])                 # [L, 21, k]
+    assert experts.shape[1:] == (21, K)
+    here = (experts >= 0) & (experts < HELD)
+    blocks = [here[:, i:i + 8].sum(axis=(1, 2)) for i in (0, 8, 16)]
+    rows = sum(-(-int(h) // pass_rows) * pass_rows
+               for block in blocks for h in block)
+    spans = program_trace.tracer().spans()
+    prefill, = [s for s in spans if s.name == "serve.prefill"]
+    passes, = [s for s in spans if s.name == "serve.passes"
+               and s.parent_id == prefill.span_id]
+    assert passes.attrs["moe_rows_run"] == rows > 0
+    assert passes.attrs["moe_pairs_run"] == int(here.sum()) <= rows
+    # the rounds' six rows are past the lowered threshold too
+    st = server.stats()
+    assert st["moe_rows_run"] >= rows and st["moe_pairs_run"] >= here.sum()
+
+
 def test_the_scopes_name_the_parts():
     """``mtp.embed``, ``mtp.proj``, the module's block under ``mtp`` and
     ``spec.accept`` reach the round program's HLO, the module's the prefill

@@ -1023,7 +1023,7 @@ def bench_serve():
     from deeplearning4j_tpu.compile_cache import compile_cache_stats
     from deeplearning4j_tpu.models.transformer import TransformerLM
     from deeplearning4j_tpu.serving import (
-        DecodeServer, max_slots_in_budget, poisson_schedule, run_open_loop)
+        DecodeServer, poisson_schedule, run_open_loop)
 
     lm = TransformerLM(vocab_size=512, d_model=128, num_heads=8,
                        num_kv_heads=4, num_layers=2, max_len=512,
@@ -1060,57 +1060,6 @@ def bench_serve():
          f"steady={builds_steady} "
          f"({'FLAT' if flat else 'NOT FLAT — recompiling per request?'})")
 
-    # ---- fast-path sweep: fuse_steps x kv_dtype x spec-decode -------
-    # the same request stream replayed against each serve config, so
-    # dispatches/token and accepted-tokens/dispatch compare apples to
-    # apples; TPOT differences isolate the dispatch-amortization win
-    def fast_config(name, **kw):
-        srv = DecodeServer(lm, slots=slots, max_len=256, **kw)
-        sched = poisson_schedule(
-            24, rate_rps=200.0, vocab_size=512,
-            prompt_lens=(8, 16, 24), max_new_tokens=(16,), seed=3)
-        rep = run_open_loop(srv, sched).summary()
-        st = srv.stats()
-        row = {
-            "tokens_per_sec": rep["tokens_per_sec"],
-            "tpot_mean_ms": rep["tpot_mean_ms"],
-            "dispatches_per_token": st["dispatches_per_token"],
-            # distinct name on purpose: this one includes slot-batching
-            # amortization (decode_tokens / dispatches across the whole
-            # batch); the gated top-level accepted_tokens_per_dispatch
-            # is the PER-SLOT figure below
-            "batch_tokens_per_dispatch":
-                st["accepted_tokens_per_dispatch"],
-            "tokens_per_slot_dispatch": st["tokens_per_slot_dispatch"],
-            "kv_pool_bytes": st["kv_pool_bytes"],
-            "kv_dtype": st["kv_dtype"],
-            "fuse_steps": st["fuse_steps"],
-        }
-        if st.get("spec_accept_rate") is not None:
-            row["spec_accept_rate"] = st["spec_accept_rate"]
-        _log(f"serve[{name}]: {row['tokens_per_sec']:,.0f} tok/s, "
-             f"disp/tok {row['dispatches_per_token']}, "
-             f"tok/slot-disp {row['tokens_per_slot_dispatch']}, "
-             f"TPOT {row['tpot_mean_ms']} ms")
-        return row
-
-    sweep = {
-        "fuse1": fast_config("fuse1", fuse_steps=1),
-        "fuse4": fast_config("fuse4", fuse_steps=4),
-        "fuse4_int8": fast_config("fuse4_int8", fuse_steps=4,
-                                  kv_dtype="int8"),
-        "spec_draft1": fast_config("spec_draft1", draft_layers=1,
-                                   spec_tokens=3),
-    }
-
-    # max concurrency the HBM budget buys per store dtype (analytic —
-    # the model validate_cache_budget checks against device bytes)
-    budget = 1 << 30  # 1 GiB of pool budget at max_len=256
-    max_slots = {dt: max_slots_in_budget(lm, 256, budget, dt)
-                 for dt in ("float32", "bfloat16", "int8")}
-    _log(f"serve: max slots in {budget >> 20} MiB pool budget: "
-         + ", ".join(f"{k}={v}" for k, v in max_slots.items()))
-
     return {**summary,
             "slots": slots,
             "kv_pool_bytes": stats["kv_pool_bytes"],
@@ -1118,20 +1067,7 @@ def bench_serve():
             "program_builds_warmup": builds_warm,
             "program_builds_steady": builds_steady,
             "compile_count_flat_after_warmup": bool(flat),
-            "compile_cache": compile_cache_stats(),
-            # fast-path headline metrics (tracked by bench_report.py:
-            # dispatches/token gates lower, accepted-tokens/dispatch
-            # and int8 max-slots gate higher)
-            "dispatches_per_token":
-                sweep["fuse4"]["dispatches_per_token"],
-            "tpot_fused_ms": sweep["fuse4"]["tpot_mean_ms"],
-            "accepted_tokens_per_dispatch":
-                sweep["spec_draft1"]["tokens_per_slot_dispatch"],
-            "spec_accept_rate": sweep["spec_draft1"].get(
-                "spec_accept_rate"),
-            "max_slots_in_budget": max_slots,
-            "max_slots_int8": max_slots["int8"],
-            "fast_path": sweep}
+            "compile_cache": compile_cache_stats()}
 
 
 def bench_serve_fleet():
@@ -1161,10 +1097,10 @@ def bench_serve_fleet():
     prompt_lens = (8, 16, 24)
 
     def build_replicas(n):
-        reps = [ServeReplica(f"r{i}", lm, slots=8, max_len=256,
-                             fuse_steps=4) for i in range(n)]
+        reps = [ServeReplica(f"r{i}", lm, slots=8, max_len=256)
+                for i in range(n)]
         for r in reps:
-            # warm every prompt-ladder rung + the fused decode program
+            # warm every prompt-ladder rung + the decode program
             # on the main thread, outside the measured virtual replay
             for plen in prompt_lens:
                 r.server.submit(np.arange(1, plen + 1, dtype=np.int32), 2)

@@ -79,14 +79,11 @@ HOT_PATH_REGISTRY = frozenset({
     # host sync here would serialize every online token behind a device
     # readback; the serve loop's ONE sanctioned readback is the
     # per-dispatch token block in serving/server.py, outside these
-    # roots). The fast-path roots: the K-step fused scan, the shared
-    # one-step forward it scans, and the speculative draft-round /
-    # multi-token-verify bodies.
+    # roots). Beside the plain step: the one-step forward it wraps and
+    # a round's multi-token-verify body.
     "_serve_prefill_impl",
     "_serve_decode_impl",
     "_serve_decode_loop_impl",
-    "_serve_decode_fused_impl",
-    "_serve_spec_impl",
     "_serve_verify_impl",
     "_decode_step_body",
     # serving/fleet/handoff.py — the prefill/decode-split slot movers:

@@ -148,15 +148,15 @@ def cache_resident_bytes(cache) -> Dict[str, int]:
     """Measured per-device bytes of a device cache's stacks. Walks the
     dataset-cache attributes (features/labels/masks; DataSet and
     MultiDataSet cache shapes both) AND the serving slot-pool attributes
-    (``k``/``v`` plus the int8 ``k_scale``/``v_scale`` sidecars), so
-    ``validate_cache_budget`` prices a quantized ``SlotKVCache`` —
+    (``k``/``v``), so
+    ``validate_cache_budget`` prices a ``SlotKVCache`` —
     predicted nbytes vs what the device actually holds — the same way
     it prices an epoch cache. Metadata-only, no transfer."""
     per_device: Dict[str, int] = {}
     arrays: List[Any] = []
     for attr in ("features", "labels", "features_mask", "labels_mask",
                  "features_masks", "labels_masks",
-                 "k", "v", "k_scale", "v_scale"):
+                 "k", "v"):
         val = getattr(cache, attr, None)
         if val is None:
             continue

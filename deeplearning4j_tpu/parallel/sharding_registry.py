@@ -274,8 +274,8 @@ class ShardingRegistry:
     leaves raise. The registry then answers every placement question the
     framework asks — param/updater shardings (``place_network``), batch
     placement (``batch_sharding``), fused-program ``out_shardings``
-    (``epoch_out_shardings``), serving KV-pool specs
-    (``kv_pool_spec``/``kv_scale_spec``), and the collective-axis
+    (``epoch_out_shardings``), the serving KV-pool spec
+    (``kv_pool_spec``), and the collective-axis
     declaration the contract checker enforces (``declared_axes``).
     """
 
@@ -511,13 +511,6 @@ class ShardingRegistry:
                 "KV pool TP fallback: %d kv heads do not tile the model "
                 "axis (size %d) — pool stays replicated", n_kv_heads, tp)
         return P()
-
-    def kv_scale_spec(self, n_kv_heads: int) -> P:
-        """int8 scale sidecar ``[L, S, Hkv]``: same head split."""
-        pool = self.kv_pool_spec(n_kv_heads)
-        if pool == P():
-            return P()
-        return P(None, None, MODEL_AXIS)
 
     # ------------------------------------------------------------------
     # contracts

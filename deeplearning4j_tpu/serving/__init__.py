@@ -10,14 +10,11 @@ serving/training split applied to the fused-program framework:
   write cursors, so S concurrent requests at different decode positions
   are ONE program's batch dimension.
 - :mod:`~deeplearning4j_tpu.serving.engine` — the jitted program set
-  (bucket-padded prefill, batched decode step, K-step fused decode,
-  speculative draft/verify rounds) built on the SAME
+  (bucket-padded prefill, and ONE decode block a model: the batched
+  decode step, or one speculative round drafted from the model's own
+  multi-token-prediction module) built on the SAME
   ``TransformerLM._block`` math as training; ``@traced`` hot roots for
-  dl4j-lint's host-sync rule. The fast path: ``fuse_steps=K`` turns K
-  tokens into one dispatch, ``kv_dtype="int8"`` shrinks the pool 4x,
-  and a draft model (``draft_layers=N`` shallow self-draft or a
-  provided ``TransformerLM``) makes accepted-tokens/dispatch the
-  headline metric.
+  dl4j-lint's host-sync rule.
 - :mod:`~deeplearning4j_tpu.serving.scheduler` — request model + bounded
   FIFO admission queue (``DL4J_SERVE_SLOTS``/``DL4J_SERVE_MAX_QUEUE``).
 - :mod:`~deeplearning4j_tpu.serving.server` — :class:`DecodeServer`,
@@ -54,11 +51,8 @@ from deeplearning4j_tpu.serving.scheduler import (  # noqa: F401
     criticality_rank,
     request_cost,
     serve_deadline_s,
-    serve_draft_layers,
     serve_evict_s,
-    serve_fuse_steps,
     serve_hedge_s,
-    serve_kv_dtype,
     serve_max_queue,
     serve_replicas,
     serve_retry_burst,
@@ -80,9 +74,8 @@ __all__ = [
     "ServeQueueFull", "ServeRequest", "SlotKVCache",
     "criticality_rank", "kv_pool_nbytes", "max_slots_in_budget",
     "poisson_schedule", "request_cost", "resolve_kv_dtype",
-    "run_open_loop", "serve_deadline_s", "serve_draft_layers",
-    "serve_evict_s", "serve_fuse_steps", "serve_hedge_s",
-    "serve_kv_dtype", "serve_max_queue", "serve_replicas",
+    "run_open_loop", "serve_deadline_s", "serve_evict_s",
+    "serve_hedge_s", "serve_max_queue", "serve_replicas",
     "serve_retry_burst", "serve_retry_ratio", "serve_role",
     "serve_slots",
 ]

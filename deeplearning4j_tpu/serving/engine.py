@@ -18,42 +18,40 @@ compiles outside it:
   scatter the consumed tokens' K/V at each slot's cursor, attend each row
   against its own masked cache history (GQA-aware — the pool stores
   ``num_kv_heads``), sample one token per row from per-slot RNG streams.
-  The ``fuse_steps=1`` path: one dispatch per token. Its inputs are the
-  pool and the loop state (``SlotKVCache.loop``: cursors, last tokens,
-  tokens owed, keys), all on the device, and it returns them advanced
+  One dispatch per token. Its inputs are the pool and the loop state
+  (``SlotKVCache.loop``: cursors, last tokens, tokens owed, keys), all
+  on the device, and it returns them advanced
   (``kv_cache.advance_loop``): step *n + 1* is dispatched from step
-  *n*'s outputs before the host has read a token of them.
-- ``("decode_fused", S, K)`` — K decode steps as one ``lax.scan``: the
-  single-step body runs K times in-program (per-slot cursors advance on
-  device, RNG streams split in-program, K/V scatters land per step) and
-  the host sees ONE dispatch + one ``[K, S]`` token block per K tokens.
-  Per-slot ``remaining`` counts freeze retired/short slots mid-scan: a
-  frozen slot's token/cursor/key carry unchanged while its rows ride
-  along computing garbage no one reads.
+  *n*'s outputs before the host has read a token of them. A slot that
+  owes nothing freezes itself (``remaining``): its token, cursor and key
+  carry unchanged while its rows ride along computing garbage no one
+  reads.
 - ``("slot_admit",)`` — the loop state's one write from the host
   (``kv_cache.slot_admit``): a request enters a slot after its prefill
   or hand-off, or leaves it before its last token (the same write with
   nothing owed). One small program.
-- ``("decode_spec", S, K, G)`` — speculative decoding: K rounds per
-  dispatch, each round drafting G tokens with the draft model (its own
-  slot pool, positions derived from the shared cursors), verifying all
-  G+1 candidates with ONE multi-token target forward
-  (``_serve_verify_impl``), and accept/resample-ing per the standard
-  speculative-sampling rule — greedy streams are token-identical to the
-  target model's greedy decode, sampled streams draw from the target
-  model's exact sampling distribution. Each round emits ``accepted + 1``
-  tokens per slot (the +1 is the target's correction/bonus token), so
-  accepted-tokens/dispatch — the headline serve metric — exceeds 1
-  whenever the draft agrees at all.
+- ``("decode_spec", S)`` — a model with a multi-token-prediction module
+  (``TransformerLM(mtp=)``) decodes in speculative rounds instead of
+  plain steps, one round a dispatch (``_serve_mtp_impl``): the target
+  verifies the token and the module's draft for the position after it
+  with ONE two-candidate forward (``_serve_verify_impl``), accepts or
+  resamples by the standard speculative-sampling rule
+  (``_accept_round``) — greedy streams are token-identical to the
+  model's greedy decode, sampled streams draw from its exact sampling
+  distribution — and the module drafts for the next round. A round emits
+  one token a slot, or two when the draft was accepted.
+
+An engine thus dispatches ONE kind of decode block for its model: the
+plain step, or the round of the model's own module.
 
 The decode-family programs update the donated pool IN PLACE
 (``_pool_attention``): every layer scatters its new rows straight into
 the ``[L, S, T_max, Hkv, Dh]`` arrays the program was given
-(``kv_cache.write_pool_rows``), the pool is the ``lax.scan`` carry of the
-fused programs, and the pool a program returns is the buffer that was
-donated to it — no slab is copied out to be written and none is stacked
-back. Where a TPU is attached the layer's keys are read from the pool
-itself by a Pallas kernel (``pallas/decode_attention.py``), because
+(``kv_cache.write_pool_rows``), and the pool a program returns is the
+buffer that was donated to it — no slab is copied out to be written and
+none is stacked back. Where a TPU is attached the layer's keys are read
+from the pool itself by a Pallas kernel
+(``pallas/decode_attention.py``), because
 XLA:TPU copies ``pool[layer]`` out before a dot may read it — and only
 the key blocks of the slots that owe a token (``remaining > 0``) are
 read; elsewhere the XLA op attends to that view.
@@ -68,12 +66,10 @@ in ``server.py``.
 Numerics contract (tests/test_serving.py): a slot's token sequence is
 IDENTICAL to ``TransformerLM.generate`` on the same prompt — greedy and
 sampled (each slot replays the exact ``sample``/``split`` chain of a
-single-request ``generate(seed=...)``), at every ``fuse_steps`` and
-under greedy speculative decoding. Slot rows are computationally
-independent (every op is row-wise; masked pad keys contribute exactly
-zero attention weight), so batching requests changes no request's
-tokens. Quantized pools (``kv_dtype="int8"``) trade bounded logit error
-(``<= absmax/127`` per K/V element) for 4x capacity.
+single-request ``generate(seed=...)``), and under greedy rounds. Slot
+rows are computationally independent (every op is row-wise; masked pad
+keys contribute exactly zero attention weight), so batching requests
+changes no request's tokens.
 """
 
 from __future__ import annotations
@@ -90,7 +86,7 @@ from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
 from deeplearning4j_tpu.scopes import scope
 from deeplearning4j_tpu.serving.kv_cache import (
-    SlotKVCache, advance_loop, dequant_slab, slot_admit, write_pool_rows)
+    SlotKVCache, advance_loop, slot_admit, write_pool_rows)
 
 __all__ = ["DecodeEngine"]
 
@@ -123,9 +119,8 @@ def _row_sampler(temperature: float, top_k: Optional[int]):
 
 def _filtered_logits_fn(temperature: float, top_k: Optional[int]):
     """Vectorized ``logits [..., V] -> filtered scaled logits`` — the
-    argument ``sample``'s categorical draws from, shared by the draft
-    proposal draw and the accept-ratio distributions so q(d) is exactly
-    the probability the draft sampled ``d`` with."""
+    argument ``sample``'s categorical draws from, shared by the row
+    sampler and a round's accept-ratio distributions."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -153,14 +148,15 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     sampled from) are the unpadded prefill's values. ``prompt_len`` and
     ``slot`` are traced: one compile per bucket, not per request.
 
-    Quantized pools: the slot's per-(layer, head) scales RESET here to
-    the prompt K/V absmax (pad positions masked out of the max — their
-    quantized garbage clips and sits beyond the cursor until real decode
-    writes requantize past it), so a recycled slot never inherits a
-    stale scale."""
+    ``quantized`` selects nothing and must be False: the tools under
+    ``benchmarks/`` bind it third (ROADMAP D12)."""
     import jax.numpy as jnp
     from jax import lax
 
+    if quantized is not False:
+        raise ValueError(
+            f"quantized={quantized!r}: the int8 pool left the serving path "
+            "(no kernel read, no cell: ISSUE 43); pass False")
     policy = model.policy
     cdt = policy.compute_dtype
     p = prompt.shape[1]
@@ -201,25 +197,7 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
               for name, rows in left.items() if rows}
     kcat = jnp.stack(ks) if ks else None     # [L, 1, P, Hkv, Dh]
     vcat = jnp.stack(vs) if ks else None
-    if ks and quantized:
-        real = (jnp.arange(p) < prompt_len)[None, None, :, None, None]
-
-        def quant(cat, pool, scale):
-            m = jnp.max(jnp.where(real, jnp.abs(cat.astype(jnp.float32)),
-                                  0.0), axis=(1, 2, 4))     # [L, Hkv]
-            denom = jnp.where(m > 0, m, 1.0)
-            q = jnp.clip(jnp.round(cat.astype(jnp.float32)
-                                   / denom[:, None, None, :, None]
-                                   * 127.0), -127, 127).astype(jnp.int8)
-            pool = lax.dynamic_update_slice(pool, q, (0, slot, 0, 0, 0))
-            scale = lax.dynamic_update_slice(
-                scale, m[:, None, :], (0, slot, 0))
-            return pool, scale
-
-        pool_k, k_scale = quant(kcat, kv["k"], kv["k_scale"])
-        pool_v, v_scale = quant(vcat, kv["v"], kv["v_scale"])
-        new_kv.update(k=pool_k, v=pool_v, k_scale=k_scale, v_scale=v_scale)
-    elif ks:
+    if ks:
         def put(pool, cat):
             cat = cat.astype(pool.dtype)
             if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
@@ -288,13 +266,13 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     The read has two forms. XLA:TPU copies ``pool[li]`` out as a slab
     before any dot may use it, so where the Pallas kernel applies
     (``pool_kernel``: a TPU is attached unless the caller says otherwise;
-    an unquantized pool whose head size fills the lanes)
+    a pool whose head size fills the lanes)
     ``pool_decode_attention`` reads from the pool itself the key blocks
     of the slots that hold a request (``live [S]``, bool; ``None``: all
     of them) — a slot that holds none is neither fetched nor multiplied
-    and its rows are zeros. Everywhere else — the CPU, int8 pools, a pool
-    sharded over a mesh — the XLA op attends to the ``pool[li]`` view,
-    every slot's."""
+    and its rows are zeros. Everywhere else — the CPU, a pool sharded
+    over a mesh — the XLA op attends to the ``pool[li]`` view, every
+    slot's."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops.attention import grouped_query_attention
     from deeplearning4j_tpu.pallas import decode_attention as kernel
@@ -313,7 +291,7 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
         pool["k"].shape[:2] + (pool["k"].shape[2] // hkv, hkv,
                                pool["k"].shape[3]))
     block = None
-    if pool_kernel and "k_scale" not in pool:
+    if pool_kernel:
         block = kernel.pool_block_rows(dims, pool["k"].dtype)
     if block is None:
         keys = jnp.arange(dims[2])
@@ -325,21 +303,16 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
         def attn(q, kk, vv):
             with scope("kv.write"):
                 for name, new in (("k", kk), ("v", vv)):
-                    pool[name], scale = write_pool_rows(
-                        pool[name], pool.get(name + "_scale"), li, new, rows,
-                        positions)
-                    if scale is not None:
-                        pool[name + "_scale"] = scale
+                    pool[name] = write_pool_rows(pool[name], li, new, rows,
+                                                 positions)
             if block is not None:
                 return kernel.pool_decode_attention(
                     q, pool["k"], pool["v"], li, positions, window=window,
                     block_rows=block, interpret=flash_default_interpret(),
                     live=live, hkv=hkv)
-            views = (dequant_slab(
-                pool[name][li] if pool[name].ndim == 5
-                else pool[name][li].reshape(dims[1:]),
-                pool[name + "_scale"][li] if name + "_scale" in pool
-                else None, dtype) for name in ("k", "v"))
+            views = ((pool[name][li] if pool[name].ndim == 5
+                      else pool[name][li].reshape(dims[1:])).astype(dtype)
+                     for name in ("k", "v"))
             return grouped_query_attention(q, *views, mask=mask)
         return attn
 
@@ -645,11 +618,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
                       pool_kernel=None, live=None, moe_info=None,
                       selections=None):
     """ONE decode forward for all S slots: consume ``tok[s]`` at
-    ``positions[s]``, write its (de/re)quantized K/V at that cursor,
+    ``positions[s]``, write its K/V at that cursor,
     attend keys ``<= positions[s]`` (``_pool_attention``).
     Returns ``(logits [S, V], new_kv)`` — sampling happens in the
-    callers so the draft path can keep the proposal distribution.
-    ``new_kv`` is ``kv`` with one row per slot and layer scattered in:
+    caller. ``new_kv`` is ``kv`` with one row per slot and layer scattered in:
     donated, it is the same buffer. Free
     slots ride along computing garbage no one reads — their rows are
     masked out of nothing (rows are independent) and their pool writes
@@ -712,7 +684,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
 def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
                        keys, live=None, *, pool_kernel=None):
     """The PR-10 single-step program: one batched forward + per-slot
-    sampling. One host dispatch per token — the ``fuse_steps=1`` path.
+    sampling. One host dispatch per token.
     Returns ``(tokens, keys, pool)``, and for a model with routed experts
     ``(tokens, keys, pool, routing)`` (``_stack_routing``, rows = the S
     slots), and for one with learned sparse attention a fifth value, the
@@ -741,8 +713,8 @@ def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
 @traced
 def _serve_decode_loop_impl(model, sample_row, params, kv, loop, *,
                             pool_kernel=None):
-    """The plain decode program (``fuse_steps=1``): the PR-10 step on
-    the device's own loop state. ``loop`` is ``SlotKVCache.loop``; a
+    """The plain decode program: the PR-10 step on the device's own
+    loop state. ``loop`` is ``SlotKVCache.loop``; a
     slot is live while it owes a token (``remaining > 0`` — the pool
     read's and the routed experts' ``live`` mask), consumes ``tok`` at
     its cursor and takes the sampled token; ``advance_loop`` moves the
@@ -757,31 +729,9 @@ def _serve_decode_loop_impl(model, sample_row, params, kv, loop, *,
 
 
 @traced
-def _serve_decode_fused_impl(model, sample_row, k_steps, params, kv, loop,
-                             *, pool_kernel=None):
-    """K decode steps as ONE ``lax.scan`` of the plain program's body:
-    sampling, per-slot RNG splits, K/V scatter writes and the loop state
-    all move in-program. A slot that owes nothing more mid-scan
-    self-freezes (``advance_loop``). Emits the ``[K, S]`` token block;
-    rows past a slot's remaining repeat its final token and the host
-    truncates by its own bookkeeping. Returns ``(toks, loop, pool)``."""
-    from jax import lax
-
-    def body(carry, _):
-        kv, loop = carry
-        loop, kv = _serve_decode_loop_impl(
-            model, sample_row, params, kv, loop,
-            pool_kernel=pool_kernel)[:2]
-        return (kv, loop), loop["tok"]
-
-    (kv, loop), toks = lax.scan(body, (kv, loop), None, length=k_steps)
-    return toks, loop, kv
-
-
-@traced
 def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
                        pool_kernel=None, moe_info=None, hidden=False):
-    """Multi-token target forward for the speculative verify: consume
+    """Multi-token target forward for a round's verify: consume
     ``toks [S, Q]`` at per-row ``positions [S, Q]`` against the slot
     pool, scatter-writing every candidate's K/V (an ``mla`` layer's: its
     latent row, ``_latent_layers``) at its position (the
@@ -820,8 +770,9 @@ def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
 
 
 def _accept_round(act, logits, d, q, keys, gamma, greedy, sample_filtered):
-    """A speculative round's accept / resample rule, for either kind of
-    draft: ``logits [S, G + 1, V]`` the target's at the candidates'
+    """A speculative round's accept / resample rule, general in the
+    number of proposals ``gamma`` (the model's own module proposes one):
+    ``logits [S, G + 1, V]`` the target's at the candidates'
     positions, ``d [S, G]`` the proposals, ``q [S, G, V]`` the distributions
     they were drawn from (unused when greedy; one-hot for a proposal that
     was an argmax), ``keys`` the slots' RNG streams, ``act [S]`` the slots
@@ -903,92 +854,6 @@ def _advance_rounds(loop, act, count, corr, keys, **more):
 
 
 @traced
-def _serve_spec_impl(model, draft_model, sample_filtered, gamma, greedy,
-                     k_rounds, params, draft_params, kv, draft_kv, loop,
-                     draft_keys, *, pool_kernel=None):
-    """K speculative rounds as ONE program. Per round and live slot:
-
-    1. **draft** — ``gamma + 1`` draft-model steps from the shared
-       cursors (step j consumes candidate j-1), proposing ``d_1..d_G``
-       and writing every candidate's draft K/V so the draft pool covers
-       the accepted prefix whatever the acceptance turns out to be (the
-       G+1-th step writes ``d_G``'s K/V; its proposal is discarded).
-    2. **verify** — ONE target forward over ``[tok, d_1..d_G]`` at
-       positions ``c..c+G`` (``_serve_verify_impl``), yielding target
-       distributions for every candidate plus the bonus position.
-    3. **accept/resample** — greedy: accept the longest prefix where the
-       target's argmax equals the proposal, then emit the target's own
-       next token (token-identity with unassisted greedy decode by
-       construction). Sampled: the standard speculative-sampling rule —
-       accept ``d_i`` with probability ``min(1, p(d_i)/q(d_i))``, on the
-       first rejection resample from ``norm(max(p - q, 0))``, after full
-       acceptance sample the bonus from ``p`` — which draws from the
-       target model's exact (temperature/top-k filtered) distribution.
-
-    ``loop`` is the target pool's loop state (``SlotKVCache.loop``);
-    cursors advance by ``accepted + 1`` and ``remaining`` falls by as
-    much (floored at zero: the host truncates the last round's tokens by
-    its own bookkeeping). The draft pool needs no cursor of its own
-    (positions derive from the shared cursors, and rejected candidates'
-    draft K/V sit beyond the rewound cursor exactly like the target
-    pool's). Emits ``[K, S, G + 2]`` blocks: per round, ``[count,
-    e_1..e_{G+1}]`` per slot (count = 0 for frozen slots). Returns
-    ``(blocks, loop, draft_keys, pool, draft_pool)``."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    i32 = jnp.int32
-
-    def round_body(carry, _):
-        kv, draft_kv, loop, draft_keys = carry
-        cursors, tok = loop["cursors"], loop["tok"]
-        act = loop["remaining"] > 0
-
-        # ---- draft: propose gamma candidates, write gamma+1 K/V
-        def dstep(dc, i):
-            dkv, dtok, dkeys = dc
-            logits, dkv = _decode_step_body(
-                draft_model, draft_params, dkv, dtok, cursors + i,
-                pool_kernel=pool_kernel, live=act)
-            if greedy:
-                prop = jnp.argmax(logits, axis=-1).astype(i32)
-                qdist = logits  # unused; placeholder keeps the scan pytree
-            else:
-                scaled = sample_filtered(logits)           # [S, V]
-                qdist = jax.nn.softmax(scaled, axis=-1)
-
-                def draw(key, lg):
-                    key, sub = jax.random.split(key)
-                    return key, jax.random.categorical(sub, lg)
-
-                dkeys, prop = jax.vmap(draw)(dkeys, scaled)
-                prop = prop.astype(i32)
-            return (dkv, prop, dkeys), (prop, qdist)
-
-        (draft_kv, _, draft_keys), (props, qdists) = lax.scan(
-            dstep, (draft_kv, tok, draft_keys), jnp.arange(gamma + 1))
-        d = jnp.swapaxes(props[:gamma], 0, 1)              # [S, G]
-
-        # ---- verify: one multi-token target forward over tok + d_1..d_G
-        vtoks = jnp.concatenate([tok[:, None], d], axis=1)  # [S, G+1]
-        vpos = cursors[:, None] + jnp.arange(gamma + 1)[None, :]
-        logits, kv = _serve_verify_impl(model, params, kv, vtoks, vpos, act,
-                                        pool_kernel=pool_kernel)
-
-        # ---- accept / resample
-        q = None if greedy else jnp.swapaxes(qdists[:gamma], 0, 1)
-        count, corr, block, keys = _accept_round(
-            act, logits, d, q, loop["keys"], gamma, greedy, sample_filtered)
-        return (kv, draft_kv, _advance_rounds(loop, act, count, corr, keys),
-                draft_keys), block
-
-    (kv, draft_kv, loop, draft_keys), blocks = lax.scan(
-        round_body, (kv, draft_kv, loop, draft_keys), None, length=k_rounds)
-    return blocks, loop, draft_keys, kv, draft_kv
-
-
-@traced
 def _serve_mtp_impl(model, sample_filtered, greedy, k_rounds, params, kv,
                     loop, *, pool_kernel=None):
     """K speculative rounds drafted from the model's own
@@ -1000,8 +865,9 @@ def _serve_mtp_impl(model, sample_filtered, greedy, k_rounds, params, kv,
     1. **verify** — the target over ``[tok, draft]`` at ``[c, c + 1]``
        (``_serve_verify_impl``: latent rows written at both), logits and
        hidden states for both.
-    2. **accept** — ``_accept_round`` with one proposal (``_serve_spec_impl``'s
-       rule; the proposal was an argmax, so ``q`` is one-hot): on acceptance
+    2. **accept** — ``_accept_round`` with one proposal (the rule of
+       speculative sampling; the proposal was an argmax, so ``q`` is
+       one-hot): on acceptance
        the round emits the draft and the target's token after it, cursor
        ``c + 2``; else the target's own token, cursor ``c + 1``, and row
        ``c + 1`` stays beyond the cursor, masked until overwritten.
@@ -1074,27 +940,27 @@ def _record(extra):
 
 
 class DecodeEngine:
-    """Owns the slot pool(s) + the per-signature program cache.
+    """Owns the slot pool + the per-signature program cache.
 
     ``temperature``/``top_k`` are server-level (baked into the compiled
     programs — a per-request sampling config would be a program
     signature per config, exactly the recompile hazard the server
     exists to avoid); per-request randomness rides in per-slot keys.
 
-    Speculative decoding: pass ``draft_layers=n`` for a shallow self-
-    draft (the target's first n blocks + its final norm/unembedding —
-    zero extra parameters) or ``draft_model=`` for an independently
-    trained draft ``TransformerLM`` (same vocab). Either builds a second
-    slot pool for the draft's K/V on the same slot machinery.
+    A model with a multi-token-prediction module (``TransformerLM(mtp=)``)
+    decodes in speculative rounds drafted from that module (``spec``); any
+    other in plain steps.
     """
+
+    # proposals a round verifies, and so the rows a request needs free past
+    # its last position: the module proposes one
+    spec_tokens = 1
 
     def __init__(self, model, slots: int, *,
                  max_len: Optional[int] = None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None,
-                 kv_dtype: Optional[str] = None,
-                 draft_model=None, draft_layers: int = 0,
-                 spec_tokens: int = 3, mesh=None):
+                 kv_dtype: Optional[str] = None, mesh=None):
         if temperature < 0.0:
             raise ValueError(f"temperature={temperature} must be >= 0")
         if top_k is not None and not 1 <= top_k <= model.vocab_size:
@@ -1136,76 +1002,24 @@ class DecodeEngine:
         # prefill and its admit_slot
         self._first_draft: Dict[int, object] = {}
 
-        # ---- speculative-decoding configuration
-        if (model.kda or model.gdn or model.dsa) and (
-                draft_model is not None or draft_layers or model.mtp):
+        if model.mtp and (model.kda or model.gdn or model.dsa):
             raise ValueError(
                 "speculative decoding is not written for a model with 'kda' "
                 "or 'gdn' layers or an indexer: a rejected draft token would "
                 "have to be taken back out of the recurrent state, which "
                 "keeps no history to rewind to, and the verify forward knows "
                 "no indexer's keys: it neither writes them nor selects")
-        if model.mtp and (draft_model is not None or draft_layers):
-            raise ValueError(
-                "this model drafts from its own multi-token-prediction "
-                "module (mtp=): pass no draft_model= or draft_layers=")
         if model.mtp and set(model.mixers) != {"mla"}:
             raise NotImplementedError(
                 "speculative rounds drafted from a multi-token-prediction "
                 "module are written for a stack of 'mla' layers (prefill in "
                 f"blocks); this model's are {model.mixers}")
-        if draft_model is not None and draft_layers:
-            raise ValueError(
-                "pass draft_model= OR draft_layers=, not both")
-        # a model's own module proposes one token a round
-        self.spec_tokens = 1 if model.mtp else int(spec_tokens)
-        if self.spec_tokens < 1:
-            raise ValueError(f"spec_tokens={spec_tokens} must be >= 1")
-        self.draft_model = None
-        if draft_layers:
-            if not 1 <= draft_layers <= model.num_layers:
-                raise ValueError(
-                    f"draft_layers={draft_layers} must be in "
-                    f"[1, num_layers={model.num_layers}]")
-            self.draft_model = self._shallow_draft(model, draft_layers)
-        elif draft_model is not None:
-            draft_model._ensure_init()
-            if draft_model.vocab_size != model.vocab_size:
-                raise ValueError(
-                    f"draft vocab {draft_model.vocab_size} != target "
-                    f"vocab {model.vocab_size}")
-            self.draft_model = draft_model
-        self.draft_cache = self.draft_keys = None
-        if self.draft_model is not None:
-            draft_reg = self.registry
-            if self.registry is not None and draft_model is not None:
-                # independent draft: its own registry (own layer count /
-                # head split); the shallow self-draft shares the target's
-                # already-placed buffers, so the target registry applies
-                from deeplearning4j_tpu.parallel.sharding_registry import (
-                    ShardingRegistry)
-
-                draft_reg = ShardingRegistry.for_transformer(
-                    self.draft_model, self.mesh)
-                self.draft_model.params = draft_reg.place(
-                    self.draft_model.params)
-            # same slot count/positions as the target pool (the
-            # SlotKVCache ctor re-validates learned-table capacity for
-            # the draft's own position table)
-            self.draft_cache = SlotKVCache(
-                self.draft_model, self.slots, self.max_len, kv_dtype,
-                registry=draft_reg)
-            # the draft's per-slot RNG streams (only the sampled
-            # speculative path consumes them); its pool needs no loop
-            # state of its own
-            self.draft_keys = self.draft_cache.loop["keys"]
 
     @property
     def spec(self) -> bool:
-        """True when a decode dispatch is speculative rounds: a draft model
-        with its own pool, or the model's own multi-token-prediction
-        module."""
-        return self.draft_model is not None or bool(self.model.mtp)
+        """True when a decode dispatch is a speculative round: the model
+        drafts from its own multi-token-prediction module."""
+        return bool(self.model.mtp)
 
     @property
     def block_prefill(self) -> bool:
@@ -1213,23 +1027,6 @@ class DecodeEngine:
         learned sparse attention, or a model that drafts from its own
         module."""
         return bool(self.model.dsa or self.model.mtp)
-
-    @staticmethod
-    def _shallow_draft(model, n: int):
-        """Self-draft by layer truncation: the target's first ``n``
-        blocks + its embedding/position/final-norm/unembedding, sharing
-        the target's parameter buffers (a view, not a copy)."""
-        from deeplearning4j_tpu.models.transformer import TransformerLM
-
-        cfg = dict(model.get_config())
-        cfg["num_layers"] = n
-        for per_layer in ("mixers", "ffns", "indexers"):
-            cfg[per_layer] = cfg[per_layer][:n]
-        draft = TransformerLM(**cfg)
-        draft.params = {k: v for k, v in model.params.items()
-                        if k != "blocks"}
-        draft.params["blocks"] = model.params["blocks"][:n]
-        return draft
 
     # ------------------------------------------------------------------
     def _program(self, sig: tuple, factory):
@@ -1248,8 +1045,8 @@ class DecodeEngine:
 
     def compile_counts(self) -> dict:
         """``{decode, prefill_buckets, total}`` — the warmup-flatness
-        evidence serving artifacts embed (``decode`` counts every
-        decode-family program: plain, fused, speculative)."""
+        evidence serving artifacts embed (``decode`` counts the
+        decode-family program: the plain step or the round)."""
         pre = sorted(s[1] for s in self._programs
                      if s[0].startswith("prefill"))
         return {"decode": sum(1 for s in self._programs
@@ -1274,22 +1071,6 @@ class DecodeEngine:
     # ------------------------------------------------------------------
     def prompt_bucket(self, n: int) -> int:
         return prompt_bucket(n, self.buckets, max_len=self.max_len)
-
-    def _prefill_one(self, kind, model, cache, padded, plen, slot, key):
-        import jax
-        import jax.numpy as jnp
-
-        def build():
-            fn = functools.partial(_serve_prefill_impl, model,
-                                   self._sample_row, cache.quantized)
-            return jax.jit(fn, donate_argnums=(1,))
-
-        run = self._program((kind, int(padded.shape[0])), build)
-        tok, key, state, *record = run(
-            model.params, cache.state, jnp.asarray(padded)[None],
-            jnp.asarray(plen, jnp.int32), jnp.asarray(slot, jnp.int32), key)
-        cache.install(state)
-        return tok, key, _record(record)
 
     def prefill_blocks(self, prompt, slot: int, key):
         """``prefill`` for a model with learned sparse attention, one block
@@ -1345,13 +1126,13 @@ class DecodeEngine:
 
     def prefill(self, prompt, slot: int, key):
         """One prompt ([t] int) into ``slot``'s pool rows: bucket-pad, run
-        the prefill program (plus the draft-pool prefill when speculative
-        decoding is on). Returns ``(first_token, new_key, routing)``
+        the prefill program. Returns ``(first_token, new_key, routing)``
         (device values; ``routing`` is ``_stack_routing``'s array, None
         for a dense model, and ``(routing, selection)`` for a model with
         learned sparse attention: ``_record``). The slot decodes once
         ``admit_slot`` has written its loop state."""
         import jax
+        import jax.numpy as jnp
 
         if self.block_prefill:      # every block, back to back
             *_, out = self.prefill_blocks(prompt, slot, key)
@@ -1361,16 +1142,18 @@ class DecodeEngine:
             raise ValueError(f"prompt must be [t] (got {prompt.shape})")
         bucket = self.prompt_bucket(int(prompt.shape[0]))
         padded, plen = pad_prompt(prompt, bucket)
-        tok, key, routing = self._prefill_one(
-            "prefill", self.model, self.cache, padded, plen, slot, key)
-        if self.spec:
-            # the draft pool must hold the prompt's K/V too; its sampled
-            # token (and the dummy key) are discarded — the served first
-            # token is the TARGET prefill's
-            self._prefill_one("prefill_draft", self.draft_model,
-                              self.draft_cache, padded, plen, slot,
-                              jax.random.PRNGKey(0))
-        return tok, key, routing
+
+        def build():
+            fn = functools.partial(_serve_prefill_impl, self.model,
+                                   self._sample_row, False)
+            return jax.jit(fn, donate_argnums=(1,))
+
+        run = self._program(("prefill", bucket), build)
+        tok, key, state, *record = run(
+            self.model.params, self.cache.state, jnp.asarray(padded)[None],
+            jnp.asarray(plen, jnp.int32), jnp.asarray(slot, jnp.int32), key)
+        self.cache.install(state)
+        return tok, key, _record(record)
 
     def admit_slot(self, slot: int, tok, cursor: int, remaining: int,
                    key) -> None:
@@ -1398,26 +1181,26 @@ class DecodeEngine:
         keys = self.cache.loop["keys"]
         self.admit_slot(slot, 0, 0, 0, np.zeros(keys.shape[1:], keys.dtype))
 
-    def _decode_jit(self, donate, impl, *bound):
+    def _decode_jit(self, impl, *bound):
         """The jitted decode-family program ``impl`` with its static
-        leading arguments bound and the pool arguments donated. A pool
+        leading arguments bound and its pool argument donated. A pool
         sharded over a mesh keeps the XLA read: GSPMD would gather the
         whole pool onto every chip for the kernel's custom call."""
         import jax
 
         kw = {} if self.mesh is None else {"pool_kernel": False}
         return jax.jit(functools.partial(impl, *bound, **kw),
-                       donate_argnums=donate)
+                       donate_argnums=(1,))
 
     def decode(self):
-        """One batched step (the ``fuse_steps=1`` / PR-10 path) from the
+        """One batched step (the PR-10 step) from the
         loop state on the device to the loop state on the device: no
         argument comes from the host. Returns ``(tokens [S], routing)``
         (device; ``routing`` as ``prefill``'s): a live slot's
         token is the one it just sampled, a frozen slot's its last."""
         def build():
             return self._decode_jit(
-                (1,), _serve_decode_loop_impl, self.model, self._sample_row)
+                _serve_decode_loop_impl, self.model, self._sample_row)
 
         run = self._program(("decode", self.slots), build)
         self.cache.loop, state, *record = run(
@@ -1425,58 +1208,20 @@ class DecodeEngine:
         self.cache.install(state)
         return self.cache.loop["tok"], _record(record)
 
-    def decode_fused(self, k_steps: int):
-        """K decode steps as ONE dispatch: returns the ``[K, S]`` token
-        block (device); pool and loop state advance in place."""
-        def build():
-            return self._decode_jit(
-                (1,), _serve_decode_fused_impl, self.model,
-                self._sample_row, k_steps)
-
-        run = self._program(("decode_fused", self.slots, k_steps), build)
-        toks, self.cache.loop, state = run(
-            self.model.params, self.cache.state, self.cache.loop)
-        self.cache.install(state)
-        return toks
-
-    def decode_spec(self, k_rounds: int):
-        """K speculative rounds as ONE dispatch: returns ``(blocks,
-        routing)``, the ``[K, S, spec_tokens + 2]`` block (per round and
-        slot: ``[count, tokens...]``) and None; both pools, the loop state
-        and the draft's RNG streams (``draft_keys``) advance in place. A
-        model that drafts from its own module: ``_decode_mtp``."""
+    def decode_spec(self):
+        """One speculative round drafted from the model's own module
+        (``_serve_mtp_impl``, called with one round a dispatch): returns
+        ``(blocks [1, S, 4], routing)`` (device; per slot ``[count, e_1,
+        e_2, draft verified]``); pool and loop state advance in place."""
         greedy = self.temperature == 0.0
-        if self.model.mtp:
-            return self._decode_mtp(k_rounds, greedy)
 
         def build():
             return self._decode_jit(
-                (2, 3), _serve_spec_impl, self.model, self.draft_model,
+                _serve_mtp_impl, self.model,
                 None if greedy else _filtered_logits_fn(
-                    self.temperature, self.top_k),
-                self.spec_tokens, greedy, k_rounds)
+                    self.temperature, self.top_k), greedy, 1)
 
-        run = self._program(
-            ("decode_spec", self.slots, k_rounds, self.spec_tokens),
-            build)
-        blocks, self.cache.loop, self.draft_keys, state, dstate = run(
-            self.model.params, self.draft_model.params,
-            self.cache.state, self.draft_cache.state, self.cache.loop,
-            self.draft_keys)
-        self.cache.install(state)
-        self.draft_cache.install(dstate)
-        return blocks, None
-
-    def _decode_mtp(self, k_rounds: int, greedy: bool):
-        """``decode_spec`` for a model that drafts from its own module
-        (``_serve_mtp_impl``): ``(blocks [K, S, 4], routing)``, one pool."""
-        def build():
-            return self._decode_jit(
-                (1,), _serve_mtp_impl, self.model,
-                None if greedy else _filtered_logits_fn(
-                    self.temperature, self.top_k), greedy, k_rounds)
-
-        run = self._program(("decode_spec", self.slots, k_rounds, 1), build)
+        run = self._program(("decode_spec", self.slots), build)
         blocks, self.cache.loop, state, routing = run(
             self.model.params, self.cache.state, self.cache.loop)
         self.cache.install(state)

@@ -1,7 +1,7 @@
 """Serve fleet: multi-replica routing, failover, prefill/decode split.
 
 PRs 10-11 built the single-replica online engine (slot-batched KV pool,
-continuous batching, fused K-step decode, speculative decoding); this
+continuous batching, speculative rounds of a model's own module); this
 package scales it OUT — ROADMAP item 3's fleet phase, the TensorFlow-
 paper serving/training split (arXiv 1605.08695) taken to fleet scale on
 the cluster primitives that already exist (``parallel/statetracker``,

@@ -40,10 +40,9 @@ __all__ = ["SlotHandoff", "export_slot", "export_live_slot",
 
 @traced
 def _slot_export_impl(state, slot):
-    """Gather one slot's K/V (+ int8 scale rows) out of the pool:
-    ``[L, S, T, Hkv, Dh]`` pools yield ``[L, T, Hkv, Dh]`` slabs,
-    ``[L, S, Hkv]`` scale sidecars yield ``[L, Hkv]`` rows. ``slot`` is
-    traced — one compiled program per engine, any slot."""
+    """Gather one slot's K/V out of the pool: ``[L, S, T, Hkv, Dh]``
+    pools yield ``[L, T, Hkv, Dh]`` slabs. ``slot`` is traced — one
+    compiled program per engine, any slot."""
     import jax.numpy as jnp
 
     return {name: jnp.take(pool, slot, axis=1)
@@ -74,7 +73,7 @@ class SlotHandoff:
     prefill, so TTFT is stamped prefill-side) and the compatibility
     fields the install validates against the target pool."""
 
-    slabs: Dict[str, np.ndarray]   # k/v [L, T, Hkv, Dh] (+ *_scale [L, Hkv])
+    slabs: Dict[str, np.ndarray]   # k/v [L, T, Hkv, Dh]
     # next write position: prompt_len for a prefill handoff,
     # prompt_len + emitted for a drain-time mid-stream migration
     cursor: int
@@ -91,7 +90,7 @@ class SlotHandoff:
 
 
 def _kv_only(engine) -> None:
-    """A slab is K/V rows (and their scales): the hand-off does not carry
+    """A slab is K/V rows: the hand-off does not carry
     a 'kda' layer's recurrent state or an 'mla' layer's latent rows."""
     if engine.model.hybrid:
         raise ValueError(

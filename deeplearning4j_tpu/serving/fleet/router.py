@@ -69,7 +69,7 @@ class FleetSaturated(RuntimeError):
     """Every alive replica's queue is at its bound."""
 
 
-@dataclass
+@dataclass(eq=False)  # identity, as ServeRequest: prompts are arrays
 class FleetRequest:
     """One request at fleet level: survives replica failover by
     stitching the tokens emitted before the death (``emitted``) to the
@@ -217,7 +217,7 @@ class FleetRouter:
                 "failover token-identity needs one fleet-wide config")
         self.greedy = temps.pop() == 0.0
         # pool config must be fleet-uniform too: a failover continuation
-        # or a handoff landing on a smaller/differently-quantized pool
+        # or a handoff landing on a smaller pool or one of another dtype
         # would raise mid-recovery (or mid-step, killing a healthy
         # replica) — reject the misconfiguration at construction
         for attr in ("max_len", "kv_dtype"):
@@ -232,9 +232,9 @@ class FleetRouter:
             if spec:
                 raise ValueError(
                     f"decode replicas {spec} run speculative decoding, "
-                    "which cannot accept handoffs (no draft-pool prompt "
-                    "K/V) — a split fleet needs non-speculative decode "
-                    "replicas")
+                    "which cannot accept handoffs (a hand-off carries no "
+                    "draft for a slot's first round) — a split fleet needs "
+                    "non-speculative decode replicas")
         self.clock = clock
         self.requests: List[FleetRequest] = []
         self._affinity: Dict[str, str] = {}

@@ -66,21 +66,14 @@ The new kinds are lists of per-layer arrays (no layer axis to slice a slab
 out of). ``pool_layout`` is the one description of all of it: the arrays
 are built from it and ``kv_pool_nbytes`` sums it.
 
-Quantized pool (``DL4J_SERVE_KV_DTYPE`` / ``kv_dtype=``): the pool is
-the dominant HBM term at high slot counts, so the store dtype is a
-capacity lever — ``float32``, ``bfloat16``, or ``int8``. int8 keeps
-per-(layer, slot, head) absmax scales beside the pool (f32 ``[L, S,
-Hkv]``, a ``1/(T_max·Dh)``-sized sidecar) and dequantizes inside the
-attention body; the pool shrinks 4x vs f32 and ``max_slots_in_budget``
-rises accordingly. Scales are running maxima: a write whose absmax
-exceeds the slot-head's scale requantizes that row in-program
-(``write_pool_rows``), so streamed decode writes never clip.
+Store dtype (``kv_dtype=``): ``float32`` or ``bfloat16``, by default the
+model's compute dtype. The pool is the dominant HBM term at high slot
+counts; ``max_slots_in_budget`` prices a slot at either width.
 """
 
 from __future__ import annotations
 
 import math
-import os
 from typing import Optional
 
 from deeplearning4j_tpu.analysis.annotations import traced
@@ -92,38 +85,31 @@ __all__ = [
     "pool_layout",
     "pool_shape",
     "max_slots_in_budget",
-    "dequant_slab",
-    "requant_write_slab",
     "write_pool_rows",
     "advance_loop",
     "slot_admit",
 ]
 
-_KV_DTYPES = ("float32", "bfloat16", "int8")
+_KV_DTYPES = ("float32", "bfloat16")
 _ALIASES = {"f32": "float32", "bf16": "bfloat16"}
 
 
 def resolve_kv_dtype(kv_dtype: Optional[str], model) -> str:
-    """Canonical store-dtype name for the pool: an explicit ``kv_dtype``
-    wins, else ``DL4J_SERVE_KV_DTYPE``, else the model's compute dtype
-    (the pre-quantization default — today's behavior, bitwise)."""
-    raw = kv_dtype
-    if raw is None:
-        raw = os.environ.get("DL4J_SERVE_KV_DTYPE", "").strip() or None
-    if raw is None:
+    """Canonical store-dtype name for the pool: an explicit ``kv_dtype``,
+    else the model's compute dtype."""
+    if kv_dtype is None:
         import jax.numpy as jnp
 
         return str(jnp.dtype(model.policy.compute_dtype))
-    name = _ALIASES.get(str(raw).lower(), str(raw).lower())
+    name = _ALIASES.get(str(kv_dtype).lower(), str(kv_dtype).lower())
     if name not in _KV_DTYPES:
         raise ValueError(
-            f"kv_dtype={raw!r} must be one of {_KV_DTYPES} "
-            "(DL4J_SERVE_KV_DTYPE)")
+            f"kv_dtype={kv_dtype!r} must be one of {_KV_DTYPES}")
     return name
 
 
 def _elem_bytes(name: str) -> int:
-    return {"float32": 4, "bfloat16": 2, "int8": 1}.get(name, 4)
+    return {"float32": 4, "bfloat16": 2}.get(name, 4)
 
 
 def _pool_dims(model, slots: int, max_len: int):
@@ -131,7 +117,7 @@ def _pool_dims(model, slots: int, max_len: int):
             model.num_kv_heads, model.head_dim)
 
 
-def pool_shape(dims, kv_dtype: str, sharded: bool = False):
+def pool_shape(dims, sharded: bool = False):
     """The shape a K (or V) pool of the logical ``dims`` = ``(L, S, T_max,
     Hkv, Dh)`` is stored in. XLA:TPU tiles the two minor axes ``[Hkv, Dh]``
     by ``(Hkv, 128)`` when there are few kv heads; with ``Dh`` of one lane
@@ -141,10 +127,10 @@ def pool_shape(dims, kv_dtype: str, sharded: bool = False):
     GiB a decode step at 64 slots x 32,768 x 2 heads of 256; PERF.md section
     6, PR 39). So a pool of wider heads is stored as those rows, ``(L, S,
     T_max Hkv, Dh)``: row ``t Hkv + h`` is position ``t`` of kv head ``h``.
-    The int8 codec and a mesh's head split keep the five axes they are
-    written for (neither reads through the kernel)."""
+    A mesh's head split keeps the five axes it is written for (it does not
+    read through the kernel)."""
     n, s, t, hkv, dh = dims
-    if dh > 128 and dh % 128 == 0 and kv_dtype != "int8" and not sharded:
+    if dh > 128 and dh % 128 == 0 and not sharded:
         return (n, s, t * hkv, dh)
     return tuple(dims)
 
@@ -163,8 +149,8 @@ def _recurrent_dims(model, kind: str):
 def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
                 sharded: bool = False) -> dict:
     """``{kind: [(shape, dtype name), ...]}`` of every array a slot pool
-    of this model holds, by what it is: ``kv`` (the K and the V pool, and
-    their int8 scales), ``latent`` (one array an ``mla`` layer),
+    of this model holds, by what it is: ``kv`` (the K and the V pool),
+    ``latent`` (one array an ``mla`` layer),
     ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
     ``recurrent`` and ``conv`` (one each a ``kda`` or ``gdn`` layer, in the
     layers' order). A kind the model has no layer of is an empty list.
@@ -172,9 +158,7 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
     out = {"kv": [], "latent": [], "index": [], "recurrent": [], "conv": []}
     dims = _pool_dims(model, slots, max_len)
     if dims[0]:
-        out["kv"] += [(pool_shape(dims, kv_dtype, sharded), kv_dtype)] * 2
-        if kv_dtype == "int8":
-            out["kv"] += [(dims[:2] + (dims[3],), "float32")] * 2
+        out["kv"] += [(pool_shape(dims, sharded), kv_dtype)] * 2
     if model.mla:
         # a multi-token-prediction module's block keeps one more layer of
         # rows, the last of the list (``TransformerLM.n_layers``)
@@ -209,8 +193,8 @@ def _layout_nbytes(arrays) -> int:
 
 def kv_pool_nbytes(model, slots: int, max_len: Optional[int] = None,
                    kv_dtype: Optional[str] = None) -> int:
-    """Analytic device footprint of a slot pool: the K/V pool pair
-    (+ int8 scale sidecars), the latent rows and the recurrent state with
+    """Analytic device footprint of a slot pool: the K/V pool pair, the
+    latent rows, an indexer's keys and the recurrent state with
     its convolution tails, whichever the model's layers keep — the
     serving term of the HBM budget model. Matches ``SlotKVCache.nbytes``
     exactly (asserted in tests)."""
@@ -222,94 +206,37 @@ def kv_pool_nbytes(model, slots: int, max_len: Optional[int] = None,
 def max_slots_in_budget(model, max_len: int, budget_bytes: int,
                         kv_dtype: Optional[str] = None) -> int:
     """How many concurrent slots an HBM budget can hold at ``max_len``
-    context — the capacity planning answer quantization multiplies
-    (int8 fits ~4x the slots of float32)."""
+    context — the capacity planning answer (bfloat16 fits twice the slots
+    of float32)."""
     per_slot = kv_pool_nbytes(model, 1, max_len, kv_dtype)
     return max(0, int(budget_bytes) // per_slot)
 
 
 # ---------------------------------------------------------------------------
-# int8 codec: traced helpers the engine's program bodies call
+# the one write the decode-family programs make
 # ---------------------------------------------------------------------------
 @traced
-def dequant_slab(slab, scale, dtype):
-    """Dequantize one layer's pool slab ``[S, T, Hkv, Dh]`` to ``dtype``
-    for the attention body. ``scale is None`` = unquantized store (the
-    slab IS the values; cast only if the store dtype differs)."""
-    import jax.numpy as jnp
-
-    if scale is None:
-        return slab if slab.dtype == dtype else slab.astype(dtype)
-    return (slab.astype(jnp.float32)
-            * (scale[:, None, :, None] / 127.0)).astype(dtype)
-
-
-@traced
-def write_pool_rows(pool, scale, layer, values, rows, positions):
+def write_pool_rows(pool, layer, values, rows, positions):
     """Write ``values [S, q, Hkv, Dh]`` at ``(rows [S], positions
     [S, q])`` of layer ``layer`` (a Python int) into the whole
     ``[L, S, T, Hkv, Dh]`` pool (or its ``[L, S, T Hkv, Dh]`` rows:
-    ``pool_shape``); returns ``(pool, scale)`` with ``scale``
-    the ``[L, S, Hkv]`` sidecar (``None`` when unquantized).
+    ``pool_shape``); returns the pool.
 
-    The one write the decode-family programs make. It scatters into the
-    pool itself — never into a copy of the layer's slab — so a program
-    whose pool argument is donated updates that buffer in place and the
-    only pool-sized value it produces is the buffer it was given.
-
-    Unquantized: a plain scatter in the store dtype. int8:
-    per-(slot, head) running-absmax scales — when a write's absmax
-    exceeds the stored scale, the slot-head's existing entries in this
-    layer are requantized to the grown scale in the same program (slots
-    whose scale did not grow multiply by exactly 1.0 — an
-    int8→f32→round→int8 identity), then the new values quantize and
-    scatter. Out-of-range scatter positions (frozen slots riding along
-    near ``T_max``) are dropped by XLA's scatter semantics, never
-    written."""
+    It scatters into the pool itself — never into a copy of the layer's
+    slab — so a program whose pool argument is donated updates that
+    buffer in place and the only pool-sized value it produces is the
+    buffer it was given: a plain scatter in the store dtype.
+    Out-of-range scatter positions (frozen slots riding along near
+    ``T_max``) are dropped by XLA's scatter semantics, never written."""
     import jax.numpy as jnp
 
     if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
         hkv = values.shape[2]
         at = (layer, rows[:, None, None],
               positions[:, :, None] * hkv + jnp.arange(hkv))
-        return pool.at[at].set(values.astype(pool.dtype)), None
-    at = (layer, rows[:, None], positions)
-    if scale is None:
-        return pool.at[at].set(values.astype(pool.dtype)), None
-    from jax import lax
-
-    vals = values.astype(jnp.float32)
-    m = jnp.max(jnp.abs(vals), axis=(1, 3))                 # [S, Hkv]
-    old = scale[layer]
-    new = jnp.maximum(old, m)
-    denom = jnp.where(new > 0, new, 1.0)
-    factor = jnp.where(new > 0, old / denom, 1.0)
-    # the requant pass rewrites the layer's whole slab, so gate it on any
-    # scale actually growing: in the steady state (absmax already seen)
-    # every factor is 1.0 and the identity rewrite would burn a full
-    # slab-read+write of bandwidth per layer per step for nothing —
-    # cond keeps the common case scatter-only on the pool
-    pool = lax.cond(
-        jnp.any(new > old),
-        lambda p: p.at[layer].set(
-            jnp.round(p[layer].astype(jnp.float32)
-                      * factor[:, None, :, None]).astype(jnp.int8)),
-        lambda p: p,
-        pool)
-    q = jnp.clip(jnp.round(vals / denom[:, None, :, None] * 127.0),
-                 -127, 127).astype(jnp.int8)
-    return pool.at[at].set(q), scale.at[layer].set(new)
-
-
-@traced
-def requant_write_slab(slab, scale, values, rows, positions):
-    """``write_pool_rows`` for ONE layer's slab ``[S, T, Hkv, Dh]`` and
-    its ``[S, Hkv]`` scale (``None`` = unquantized): the slab as a
-    one-layer pool. Returns ``(slab, scale)``."""
-    pool, scales = write_pool_rows(
-        slab[None], None if scale is None else scale[None], 0, values,
-        rows, positions)
-    return pool[0], None if scales is None else scales[0]
+    else:
+        at = (layer, rows[:, None], positions)
+    return pool.at[at].set(values.astype(pool.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -387,14 +314,12 @@ class SlotKVCache:
                 f"position table ({model.max_len}); use "
                 "pos_encoding='rope' to serve past it")
         self.kv_dtype = resolve_kv_dtype(kv_dtype, model)
-        if model.hybrid and (self.kv_dtype == "int8"
-                             or registry is not None):
+        if model.hybrid and registry is not None:
             raise ValueError(
-                "a model with 'kda', 'gdn' or 'mla' layers is served from "
-                "an unquantized pool on one chip: the int8 codec and the "
-                "mesh's head split are written for a model whose every layer "
-                "keeps K/V rows, not for latent rows, an indexer's keys or "
-                "recurrent state beside them")
+                "a model with 'kda', 'gdn' or 'mla' layers is served on one "
+                "chip: the mesh's head split is written for a model whose "
+                "every layer keeps K/V rows, not for latent rows, an "
+                "indexer's keys or recurrent state beside them")
         layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype,
                              sharded=registry is not None)
         self.latent, self.index, self.kda, self.conv = (
@@ -403,20 +328,10 @@ class SlotKVCache:
         # the pools' logical axes (L, S, T_max, Hkv, Dh), whichever shape
         # they are stored in
         self.pool_dims = _pool_dims(model, self.slots, self.max_len)
-        shape = pool_shape(self.pool_dims, self.kv_dtype,
-                           registry is not None)
-        if not shape[0]:        # no layer keeps keys and values
-            self.k = self.v = self.k_scale = self.v_scale = None
-        elif self.kv_dtype == "int8":
-            self.k = jnp.zeros(shape, jnp.int8)
-            self.v = jnp.zeros(shape, jnp.int8)
-            self.k_scale = jnp.zeros(shape[:2] + (shape[3],), jnp.float32)
-            self.v_scale = jnp.zeros(shape[:2] + (shape[3],), jnp.float32)
-        else:
-            self.k = jnp.zeros(shape, jnp.dtype(self.kv_dtype))
-            self.v = jnp.zeros(shape, jnp.dtype(self.kv_dtype))
-            self.k_scale = None
-            self.v_scale = None
+        self.k = self.v = None      # no layer keeps keys and values
+        if layout["kv"]:
+            self.k, self.v = (jnp.zeros(shape, jnp.dtype(dt))
+                              for shape, dt in layout["kv"])
         # the decode loop's per-slot state, DEVICE arrays the decode
         # programs take and return advanced (not donated: the token block
         # a program returns is its ``tok``, which the host reads one step
@@ -448,11 +363,6 @@ class SlotKVCache:
             pool = named(registry.mesh, pool_spec)
             self.k = jax.device_put(self.k, pool)
             self.v = jax.device_put(self.v, pool)
-            if self.k_scale is not None:
-                sc = named(registry.mesh,
-                           registry.kv_scale_spec(model.num_kv_heads))
-                self.k_scale = jax.device_put(self.k_scale, sc)
-                self.v_scale = jax.device_put(self.v_scale, sc)
             self.loop = jax.device_put(
                 self.loop, replicated_sharding(registry.mesh))
             if pool_spec != P():
@@ -461,19 +371,12 @@ class SlotKVCache:
                 self.n_shard = model_axis_size(registry.mesh)
 
     @property
-    def quantized(self) -> bool:
-        return self.k_scale is not None
-
-    @property
     def state(self) -> dict:
         """The pool pytree a jitted program consumes (and is donated):
-        ``{k, v}`` plus the int8 scale sidecars when quantized. The
+        ``{k, v}`` and the other layer kinds' lists. The
         engine's programs write into these buffers and hand them back
         (``install``); once donated, the arrays returned here are dead."""
         st = {} if self.k is None else {"k": self.k, "v": self.v}
-        if self.k_scale is not None:
-            st["k_scale"] = self.k_scale
-            st["v_scale"] = self.v_scale
         for name in ("latent", "index", "kda", "conv"):
             if getattr(self, name):
                 st[name] = list(getattr(self, name))
@@ -485,25 +388,20 @@ class SlotKVCache:
         device memory, not a copy of it."""
         self.k = state.get("k")
         self.v = state.get("v")
-        self.k_scale = state.get("k_scale")
-        self.v_scale = state.get("v_scale")
         for name in ("latent", "index", "kda", "conv"):
             setattr(self, name, list(state.get(name, ())))
 
     @property
     def nbytes(self) -> int:
         """Device footprint of the pool state (capacity planning: the
-        serving analogue of the epoch cache's HBM budget). Includes the
-        int8 scale sidecars."""
+        serving analogue of the epoch cache's HBM budget)."""
         return sum(self.nbytes_by_kind.values())
 
     @property
     def nbytes_by_kind(self) -> dict:
-        """``nbytes`` apart: ``kv`` (K/V pools and their scales),
-        ``latent``, ``index``, ``recurrent``, ``conv`` (``pool_layout``'s
-        kinds)."""
-        kv = [a for a in (self.k, self.v, self.k_scale, self.v_scale)
-              if a is not None]
+        """``nbytes`` apart: ``kv`` (the K/V pools), ``latent``, ``index``,
+        ``recurrent``, ``conv`` (``pool_layout``'s kinds)."""
+        kv = [a for a in (self.k, self.v) if a is not None]
         kinds = [("kv", kv), ("latent", self.latent),
                  ("recurrent", self.kda), ("conv", self.conv)]
         if self.index:      # only a model with an indexer names the kind
@@ -513,7 +411,5 @@ class SlotKVCache:
 
     @property
     def per_slot_nbytes(self) -> int:
-        """The pool bytes one concurrent request costs — what int8
-        shrinks ~4x vs float32 (max concurrency multiplies by the
-        inverse)."""
+        """The pool bytes one concurrent request costs."""
         return self.nbytes // self.slots

@@ -45,8 +45,7 @@ import numpy as np
 
 __all__ = ["ServeRequest", "ServeQueueFull", "RequestQueue",
            "AdmissionVerdict", "RetryBudget", "serve_slots",
-           "serve_max_queue", "serve_fuse_steps", "serve_kv_dtype",
-           "serve_draft_layers", "serve_replicas", "serve_role",
+           "serve_max_queue", "serve_replicas", "serve_role",
            "serve_evict_s", "serve_deadline_s", "serve_retry_ratio",
            "serve_retry_burst", "serve_hedge_s", "SERVE_ROLES",
            "CRITICALITIES", "criticality_rank", "request_cost"]
@@ -60,37 +59,6 @@ def serve_slots(default: int = 8) -> int:
     raw = os.environ.get("DL4J_SERVE_SLOTS", "")
     try:
         return max(1, int(raw)) if raw else default
-    except ValueError:
-        return default
-
-
-def serve_fuse_steps(default: int = 1) -> int:
-    """``DL4J_SERVE_FUSE_STEPS``: decode steps fused per dispatch (K).
-    1 (default) = one host dispatch per token, the PR-10 behavior,
-    bitwise; K > 1 runs K steps as one ``lax.scan`` program and admits
-    new requests only at fusion boundaries."""
-    raw = os.environ.get("DL4J_SERVE_FUSE_STEPS", "")
-    try:
-        return max(1, int(raw)) if raw else default
-    except ValueError:
-        return default
-
-
-def serve_kv_dtype(default=None):
-    """``DL4J_SERVE_KV_DTYPE``: the KV pool's store dtype
-    (``float32``/``bfloat16``/``int8``); unset = the model's compute
-    dtype (pre-quantization behavior). Validation happens in
-    ``kv_cache.resolve_kv_dtype`` (model-aware)."""
-    raw = os.environ.get("DL4J_SERVE_KV_DTYPE", "").strip()
-    return raw or default
-
-
-def serve_draft_layers(default: int = 0) -> int:
-    """``DL4J_SERVE_DRAFT_LAYERS``: speculative decoding via a shallow
-    self-draft of the target's first N layers. 0 (default) = off."""
-    raw = os.environ.get("DL4J_SERVE_DRAFT_LAYERS", "")
-    try:
-        return max(0, int(raw)) if raw else default
     except ValueError:
         return default
 

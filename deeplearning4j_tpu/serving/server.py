@@ -5,7 +5,7 @@ persistent server object that compiles its program set once, keeps all
 state device-resident (TensorFlow-paper serving/training split), and
 multiplexes S concurrent requests through ONE jitted decode dispatch.
 
-The loop, per ``step()`` (a step IS a fusion boundary):
+The loop, per ``step()``:
 
 1. **sweep** — an in-flight request past its deadline, or canceled,
    leaves its slot (its ``remaining`` on the device goes to zero).
@@ -13,19 +13,17 @@ The loop, per ``step()`` (a step IS a fusion boundary):
    the bucket-compiled prefill (``serve.prefill`` span), writes the
    slot's loop state (``engine.admit_slot``), records TTFT, and may
    retire immediately when ``max_new_tokens == 1``. Admission happens
-   ONLY here: with ``fuse_steps=K`` a request arriving mid-scan waits for
-   the dispatch in flight to finish (the admission-boundary trade —
-   bounded added TTFT, in exchange for K tokens per dispatch).
+   ONLY here.
    A model with learned sparse attention is prefilled in blocks
    (``engine.prefill_blocks``), and its admission is spread over steps:
    a request takes its slot at once, ONE block of one prompt runs a step
    (``serve.prefill_block``; the prompts part-way in take turns), and
    the last block is the request's ``serve.prefill`` (``_admit_blocks``).
    The live slots decode between the blocks.
-3. **decode** — if any slot is owed a token, run ONE decode dispatch:
-   the plain single-step program (``fuse_steps=1``, the PR-10 step), the
-   K-step fused program, or K speculative rounds when a draft is
-   configured.
+3. **decode** — if any slot is owed a token, run ONE decode dispatch,
+   of the one kind of block the model has: the plain single-step program
+   (the PR-10 step), or, for a model with a multi-token-prediction module
+   (``TransformerLM(mtp=)``), one speculative round drafted from it.
 4. **read and emit** — the token block reaches the host; every slot that
    was live in it appends its tokens; finished requests retire and free
    their slots.
@@ -36,23 +34,22 @@ decode program takes it and returns it advanced, and the host writes it
 only where a request enters or leaves a slot. A request ends on
 ``max_new_tokens`` alone, and the device freezes a slot that owes
 nothing more (``remaining``), so nothing a decode program computes waits
-for the host to have read a token. Every kind of block therefore runs
+for the host to have read a token. Either kind of block therefore runs
 ONE DISPATCH AHEAD of the host: step 3 dispatches block *n + 1* from
 the device's state before step 4 reads block *n*, the one dispatched a
 step earlier (``_unread``), and the chip computes *n + 1* while the host
 books *n*. What the host chooses without that read is the live set of
 *n + 1* (``_owed``): the slots that MAY still owe a token once block *n*
-is read, counting for it the fewest tokens it can hold for a slot
-(``_still_owed``: 1 of a plain step, ``min(K, owed)`` of K fused steps —
-both exact — and as many of K rounds, each of which yields one token at
-least and ``G + 1`` at most). With rounds the live set is thus a
-superset of the truth by the slots that finished on an accepted draft:
+is read, counting for it the one token it holds for a live slot at least
+(exact for a plain step; a round yields one token or two). With rounds
+the live set is thus a superset of the truth by the slots that finished
+on an accepted draft:
 the device gives those a count of 0, and a dispatch in which every row
 comes back 0 (a lone request's tail) is counted, ``stats()
 ["empty_dispatches"]``. Reading behind costs a slot nothing: a slot is
 free the moment the block that is CERTAIN to end its request is
-dispatched (``_vacate``: it owes no more than the fewest tokens the
-block can hold for it), the next step's admission takes it, and the
+dispatched (``_vacate``: it owes one token, which the block holds),
+the next step's admission takes it, and the
 request, off the slot table, books its last tokens and retires when that
 block is read. Only a request that ends on an accepted draft, which the
 host cannot foresee, holds its slot one round longer. With nothing
@@ -61,18 +58,17 @@ dispatch, then read in the next step; ``busy()`` stays true while a
 block is unread, and ``drain()``, ``stats()``, ``flush()`` and the
 fleet's drain-time export read it first.
 
-The host sees one token-block readback per dispatch ([S] at K=1,
-[K, S] fused, [K, S, G+2] speculative; a model with routed experts
+The host sees one token-block readback per dispatch ([S] of a plain
+step, [1, S, 4] of a round; a model with routed experts
 sends its routing in the same read, carried with ITS block) — that is
 the decode loop's entire host/device chatter: between two decode steps
 with no admission nothing travels host → device. Everything else
 (queue, slot table) is host bookkeeping the scheduler needs anyway.
 
 Observability: queue depth / occupancy gauges, token + dispatch
-counters (``serve_decode_steps_total`` counts DISPATCHES — with fusion
-one dispatch covers up to K·(G+1) tokens; ``stats()`` derives
-dispatches/token and accepted-tokens/dispatch, the fast-path headline
-metrics), the pool read's key-block counters
+counters (``serve_decode_steps_total`` counts DISPATCHES — a round
+holds up to two tokens a slot; ``stats()`` derives dispatches/token and
+tokens/dispatch), the pool read's key-block counters
 (``serve_decode_kv_blocks_total`` against
 ``serve_decode_kv_blocks_pool_total``: what the live slots' keys cost
 of what every slot's cursor would), speculative proposed/accepted
@@ -97,8 +93,8 @@ runs):
   ``serve.admit``. Routed experts on a share: a ``serve.passes``
   (``moe_rows_run``, ``moe_pairs_run``) inside the ``serve.prefill`` that
   read a routing whose sorted form ran in passes (``_read_block``).
-- ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``fused`` |
-  ``spec``, ``ahead`` = 1 when the dispatch was issued while the
+- ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``spec``,
+  ``ahead`` = 1 when the dispatch was issued while the
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
   blocks a layer the pool kernel fetches for the slots dispatched, and
   what a read of every slot's cursor, live or frozen, would fetch —
@@ -140,8 +136,7 @@ from deeplearning4j_tpu.serving.engine import (
     DecodeEngine, prefill_block_count, unpack_routing)
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
-    criticality_rank, serve_deadline_s, serve_draft_layers,
-    serve_fuse_steps, serve_kv_dtype, serve_max_queue, serve_slots)
+    criticality_rank, serve_deadline_s, serve_max_queue, serve_slots)
 
 __all__ = ["DecodeServer"]
 
@@ -161,14 +156,17 @@ class DecodeServer:
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None,
                  fuse_steps: Optional[int] = None,
-                 kv_dtype: Optional[str] = None,
-                 draft_model=None, draft_layers: Optional[int] = None,
-                 spec_tokens: int = 3, mesh=None,
+                 kv_dtype: Optional[str] = None, mesh=None,
                  clock=time.monotonic, record_routing: bool = False):
-        self.fuse_steps = (fuse_steps if fuse_steps is not None
-                           else serve_fuse_steps())
-        if self.fuse_steps < 1:
-            raise ValueError(f"fuse_steps={fuse_steps} must be >= 1")
+        # the drivers under benchmarks/ pass fuse_steps=1 (ROADMAP D12)
+        if fuse_steps not in (None, 1):
+            raise ValueError(
+                f"fuse_steps={fuse_steps}: a dispatch is one decode step "
+                "(or one round of a model's own module). K fused steps "
+                "bought the host's turn between two dispatches, which "
+                "reading a block one dispatch behind already hides, and "
+                "made an arriving request wait K steps for its slot "
+                "(ISSUE 43)")
         if mesh is None:
             from deeplearning4j_tpu.parallel.sharding_registry import (
                 mesh_from_env)
@@ -177,13 +175,7 @@ class DecodeServer:
         self.engine = DecodeEngine(
             model, slots if slots is not None else serve_slots(),
             max_len=max_len, temperature=temperature, top_k=top_k,
-            buckets=buckets,
-            kv_dtype=kv_dtype if kv_dtype is not None else serve_kv_dtype(),
-            draft_model=draft_model,
-            draft_layers=(draft_layers if draft_layers is not None
-                          else (0 if draft_model is not None
-                                else serve_draft_layers())),
-            spec_tokens=spec_tokens, mesh=mesh)
+            buckets=buckets, kv_dtype=kv_dtype, mesh=mesh)
         self.model = model
         self.slots = self.engine.slots
         self.max_len = self.engine.max_len
@@ -194,12 +186,11 @@ class DecodeServer:
         # the host's copy of the device's cursors (written where the
         # device's are: admission, release, a dispatch's own advance) and
         # the pool kernel's rows a key block — None where the pool has no
-        # kernel read (int8, a mesh, a head size off the lanes): what
+        # kernel read (a mesh, a head size off the lanes): what
         # ``kv_blocks`` is counted from
         self._cursors = np.zeros(self.slots, np.int64)
         pool = self.engine.cache
-        self._kv_block = (None if pool.quantized or mesh is not None
-                          or pool.k is None
+        self._kv_block = (None if mesh is not None or pool.k is None
                           else pool_block_rows(pool.pool_dims, pool.k.dtype))
         self.kv_blocks = 0
         self.kv_blocks_pool = 0
@@ -266,8 +257,7 @@ class DecodeServer:
         self.moe_decode_blocks = 0
         self.moe_decode_touched = 0
         self.moe_decode_read = 0
-        self._decode_kind = ("spec" if self.engine.spec else
-                             "fused" if self.fuse_steps > 1 else "plain")
+        self._decode_kind = "spec" if self.engine.spec else "plain"
         # record_routing: every request keeps the experts that served
         # each of its positions, and their weights, as the prefill and
         # decode programs chose them (``ServeRequest.routing``): what a
@@ -280,13 +270,9 @@ class DecodeServer:
         # module's layer last, and every draft a round verified with the
         # position it was proposed for (``ServeRequest.drafts``).
         self.record_routing = bool(record_routing)
-        if self.record_routing and not (model.num_experts and (
-                self._decode_kind == "plain"
-                or model.mtp and self.fuse_steps == 1)):
+        if self.record_routing and not model.num_experts:
             raise ValueError(
-                "record_routing needs a model with routed experts and "
-                "the plain decode step or one round a dispatch of a model's "
-                "own module (fuse_steps=1, no draft model)")
+                "record_routing needs a model with routed experts")
         self._reg = metrics()
 
     # ------------------------------------------------------------------
@@ -425,8 +411,8 @@ class DecodeServer:
         if self.engine.spec:
             raise ValueError(
                 "handoff into a speculative decode server is "
-                "unsupported: the draft pool holds no prompt K/V for "
-                "the handed-off slot")
+                "unsupported: a hand-off carries no draft for the "
+                "slot's first round")
         if not req.tokens:
             raise ValueError(
                 "admit_external needs a prefilled request (its first "
@@ -533,14 +519,8 @@ class DecodeServer:
                     prompt_len=prompt_len,
                     bucket=self.engine.prompt_bucket(prompt_len),
                     queue_wait_us=waited):
-                key = jax.random.PRNGKey(req.seed)
-                if self.engine.spec:
-                    # an independent per-slot draft stream (only the
-                    # sampled speculative path consumes it)
-                    self.engine.draft_keys = self.engine.draft_keys.at[
-                        slot].set(jax.random.fold_in(key, 0x5bec))
                 self._first_token(req, slot, *self.engine.prefill(
-                    req.prompt, slot, key))
+                    req.prompt, slot, jax.random.PRNGKey(req.seed)))
             self._enter(req, slot)
             admitted += 1
         return admitted
@@ -647,52 +627,39 @@ class DecodeServer:
                             request=req.id, tokens=len(req.tokens),
                             slot=slot)
 
-    def _still_owed(self, slot: int, req: ServeRequest) -> int:
-        """The most tokens ``req`` in ``slot`` can still be owed once the
-        unread block is read: what the host has counted it owed, less the
-        FEWEST tokens that block can hold for it — ``min(fuse_steps,
-        owed)``: one of a plain step, exactly that of K fused steps, at
-        least that of K rounds (a live round yields a token or more).
-        ``_owed`` and the fused dispatch's cursors both count from here."""
-        owed = req.max_new_tokens - len(req.tokens)
-        if self._unread is not None and self._unread[2].get(slot) is req:
-            owed -= min(self.fuse_steps, owed)
-        return owed
-
     def _owed(self) -> dict:
         """``{slot: request}`` of the slots that may be owed a token no
         dispatched block holds yet: the live set of the next dispatch,
         known without reading a token because a request ends on
-        ``max_new_tokens`` alone (the device's ``remaining > 0``). Exact
-        for plain and fused steps; with rounds a superset by the slots
+        ``max_new_tokens`` alone (the device's ``remaining > 0``). What
+        the host has counted a request owed, less the one token the unread
+        block holds for it at least: exact
+        for plain steps; with rounds, which yield one token or two, a
+        superset by the slots
         whose unread rounds accepted a draft past their end, which the
         device has frozen already (their rows come back with count 0)."""
+        unread = self._unread[2] if self._unread is not None else {}
         return {s: r for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._prefilling
-                and self._still_owed(s, r) > 0}
+                and r.max_new_tokens - len(r.tokens)
+                > (unread.get(s) is r)}
 
     def _dispatch(self, live: dict):
         """ONE decode dispatch for the live set, from the loop state on
         the device: nothing is sent. Returns the block as dispatched,
-        ``(tokens, routing, live)`` with device arrays — tokens [S] plain,
-        [K, S] fused, [K, S, G+2] speculative. The host's cursors move on
+        ``(tokens, routing, live)`` with device arrays — tokens [S] of a
+        plain step, [1, S, 4] of a round. The host's cursors move on
         as the program moves the device's (a speculative round's count is
-        the device's to say: booked when its block is read, ``_emit``).
-        Called with the block before it still in ``_unread``: the fused
-        steps a slot takes are counted past that block's."""
+        the device's to say: booked when its block is read, ``_emit``)."""
         if self.engine.spec:
-            return self.engine.decode_spec(self.fuse_steps) + (live,)
-        for slot, req in live.items():
-            self._cursors[slot] += min(self.fuse_steps,
-                                       self._still_owed(slot, req))
-        if self.fuse_steps > 1:
-            return self.engine.decode_fused(self.fuse_steps), None, live
+            return self.engine.decode_spec() + (live,)
+        self._cursors[list(live)] += 1
         return self.engine.decode() + (live,)
 
     def _vacate(self) -> None:
         """Free the slots whose requests the block just dispatched is
-        CERTAIN to end — it holds at least ``min(fuse_steps, owed)`` tokens
-        for a slot, and these are owed no more than that — so the next
+        CERTAIN to end — it holds a token for a slot at least, and these
+        are owed no more than that — so the next
         step's admission takes them, as it did when the block was read in
         the step that dispatched it. The request is off the slot table
         (no sweep reaches it: the device has finished it) and stays in the
@@ -704,7 +671,7 @@ class DecodeServer:
         _, _, live, left = self._unread
         for slot, req in live.items():
             # every block before this one is booked: ``tokens`` is current
-            if (req.max_new_tokens - len(req.tokens) <= self.fuse_steps
+            if (req.max_new_tokens - len(req.tokens) <= 1
                     and self._slot_req[slot] is req):
                 self._slot_req[slot] = None
                 left[slot] = self._last_tok_s[slot]
@@ -810,8 +777,7 @@ class DecodeServer:
             toks, selection = jax.device_get((toks, selection))
             return np.asarray(toks), None, selection
         toks, packed, selection = jax.device_get((toks, routing, selection))
-        # speculative rounds hand over one array a round ([K, L, ...]):
-        # each is booked as a block of its own, the span holds the last
+        # a speculative round hands over its array as [1, L, ...]
         rows_run = pairs_run = 0
         for one in (packed if packed.ndim == 3 else packed[None]):
             load, *rows, read, run = unpack_routing(
@@ -869,10 +835,10 @@ class DecodeServer:
             self._cursors[slot] = 0
 
     def step(self) -> bool:
-        """One scheduler iteration: shed expired/canceled slots, admit
-        at the fusion boundary, one decode dispatch (1, K, or K
-        speculative rounds of tokens), then read and book a token block
-        — the one dispatched a step EARLIER, whatever its kind, so the
+        """One scheduler iteration: shed expired/canceled slots, admit,
+        one decode dispatch (a plain step, or a round of the model's own
+        module), then read and book a token block
+        — the one dispatched a step EARLIER, so the
         chip runs this step's dispatch meanwhile — and the slots this
         step's dispatch is certain to finish are free for the next step's
         admission (``_vacate``). Returns False when
@@ -895,7 +861,7 @@ class DecodeServer:
         """The ``serve.decode`` and ``serve.emit`` phases: dispatch a
         block for ``live`` (none when empty), then read and book the
         block that was unread, the one dispatched a step earlier — the
-        same order for a plain step, fused steps and rounds."""
+        same order for a plain step and a round."""
         unread = self._unread
         ahead = bool(live) and unread is not None
         with tracer().span("serve.decode", live=len(live),
@@ -913,12 +879,13 @@ class DecodeServer:
             toks, rows, selection = self._read_block(
                 *unread[:2], candidates * len(unread[2]), decode=True)
             counts = drafts = None
-            if self.engine.spec:                   # [K, S, G+2]
-                if self.model.mtp:  # [K, S, G+3]: the draft verified, last
-                    toks, drafts = toks[:, :, :-1], toks[:, :, -1]
-                toks, counts = toks[:, :, 1:], toks[:, :, 0]
-                # what the device says of the rounds (no further read)
-                c = counts[:, list(unread[2])]
+            if self.engine.spec:
+                # the block's one round, [S, 4]: the tokens it yields, the
+                # two it may yield, the draft it verified
+                counts, toks, drafts = (toks[0, :, 0], toks[0, :, 1:3],
+                                        toks[0, :, 3])
+                # what the device says of the round (no further read)
+                c = counts[list(unread[2])]
                 rounds = int(np.count_nonzero(c > 0))
                 sp.attrs.update(
                     rounds=rounds, proposed=rounds * self.engine.spec_tokens,
@@ -929,8 +896,6 @@ class DecodeServer:
                 # every slot of the live set had finished on a draft that
                 # an unread round accepted: a dispatch for nothing
                 self.empty_dispatches += rounds == 0
-            elif toks.ndim == 1:                   # plain: [S] -> [1, S]
-                toks = toks[None]
         with tracer().span("serve.emit") as emit:
             emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
                 *unread[2:], toks, counts, rows, selection, drafts)
@@ -945,10 +910,11 @@ class DecodeServer:
     def _emit(self, live: dict, left: dict, toks, counts, rows,
               selection=None, drafts=None) -> Tuple[int, int]:
         """Book one dispatch's token block: per slot that was live in it
-        the tokens it takes, TPOT observations, retirement; ``rows`` is
+        the tokens it takes (``toks`` [S] of a plain step; [S, 2] of a
+        round, of which ``counts`` [S] says how many a slot yields), TPOT
+        observations, retirement; ``rows`` is
         the same block's routing, ``selection`` its key selections and
-        ``drafts`` [K, S] the drafts its rounds verified (a model's own
-        module). A
+        ``drafts`` [S] the drafts its round verified. A
         slot whose request was swept while the block was unread takes
         nothing; one whose request left it at the dispatch (``left``,
         ``_vacate``) books it all the same, and touches nothing of the
@@ -966,46 +932,39 @@ class DecodeServer:
             tenant = self._slot_req[slot]
             if tenant is not req and slot not in left:
                 continue
-            rem = req.max_new_tokens - len(req.tokens)
             got: List[int] = []
-            if counts is None:
-                for r in range(min(toks.shape[0], rem)):
-                    got.append(int(toks[r, slot]))
-            else:
-                for r in range(toks.shape[0]):
-                    c = int(counts[r, slot])
-                    if c <= 0:
-                        continue
-                    take = min(c, rem - len(got))
-                    if req.drafts is not None:
-                        # the round verified a draft for the position after
-                        # its cursor's (the tokens so far end on the cursor),
-                        # and made ``take`` positions permanent: their rows
-                        # of the S x 2 candidates', every layer's
-                        req.drafts.append((
-                            req.prompt.shape[0] + len(req.tokens) + len(got),
-                            int(drafts[r, slot])))
-                        req.routing.append(tuple(
-                            a.reshape(a.shape[0], self.slots, 2, -1)[
-                                :, slot, :take] for a in rows))
-                    # the slot's cursor is this request's to move while
-                    # it holds the slot, or left it and no tenant has come
-                    if tenant is req or tenant is None:
-                        self._cursors[slot] += c
-                    got.extend(int(t) for t in toks[r, slot, :take])
-                    self.spec_proposed += self.engine.spec_tokens
-                    self.spec_accepted += c - 1
-                    if len(got) >= rem:
-                        break
+            if counts is None:      # owed a token when it was dispatched
+                got.append(int(toks[slot]))
+                if req.routing is not None:
+                    # the row that emitted this token
+                    req.routing.append(tuple(a[:, slot:slot + 1]
+                                             for a in rows))
+                    if selection is not None:
+                        req.selection.append(selection[:, slot:slot + 1])
+            elif counts[slot] > 0:
+                c = int(counts[slot])
+                take = min(c, req.max_new_tokens - len(req.tokens))
+                if req.drafts is not None:
+                    # the round verified a draft for the position after
+                    # its cursor's (the tokens so far end on the cursor),
+                    # and made ``take`` positions permanent: their rows
+                    # of the S x 2 candidates', every layer's
+                    req.drafts.append((
+                        req.prompt.shape[0] + len(req.tokens),
+                        int(drafts[slot])))
+                    req.routing.append(tuple(
+                        a.reshape(a.shape[0], self.slots, 2, -1)[
+                            :, slot, :take] for a in rows))
+                # the slot's cursor is this request's to move while
+                # it holds the slot, or left it and no tenant has come
+                if tenant is req or tenant is None:
+                    self._cursors[slot] += c
+                got.extend(int(t) for t in toks[slot, :take])
+                self.spec_proposed += self.engine.spec_tokens
+                self.spec_accepted += c - 1
             req.tokens.extend(got)
-            if req.routing is not None and counts is None:
-                # the row that emitted this token
-                req.routing.append(tuple(a[:, slot:slot + 1]
-                                         for a in rows))
-                if selection is not None:
-                    req.selection.append(selection[:, slot:slot + 1])
             emitted_total += len(got)
-            # with fusion the K tokens land together: spread the
+            # a round's two tokens land together: spread the
             # dispatch interval evenly so TPOT keeps one observation
             # per token and sums to the true wall span
             last = left[slot] if slot in left else self._last_tok_s[slot]
@@ -1045,15 +1004,10 @@ class DecodeServer:
     # ------------------------------------------------------------------
     def stats(self) -> dict:
         """Artifact-ready snapshot: compile counts, pool footprint,
-        request/dispatch totals, and the fast-path headline ratios
-        (dispatches/token, accepted-tokens/dispatch). Reads the unread
+        request/dispatch totals and ratios (dispatches/token,
+        tokens/dispatch). Reads the unread
         block first (``flush``), so the counts are of every dispatch."""
         self.flush()
-        pool_bytes = self.engine.cache.nbytes
-        per_slot = self.engine.cache.per_slot_nbytes
-        if self.engine.draft_cache is not None:
-            pool_bytes += self.engine.draft_cache.nbytes
-            per_slot += self.engine.draft_cache.per_slot_nbytes
         out = {
             "slots": self.slots,
             "max_len": self.max_len,
@@ -1065,10 +1019,9 @@ class DecodeServer:
             "shed_by_class": dict(self.shed_by_class),
             "expired_in_queue": self.expired_in_queue,
             "expired_in_flight": self.expired_in_flight,
-            "fuse_steps": self.fuse_steps,
             "kv_dtype": self.engine.kv_dtype,
-            "kv_pool_bytes": pool_bytes,
-            # the target pool's bytes by what they are: K/V rows, latent
+            "kv_pool_bytes": self.engine.cache.nbytes,
+            # the pool's bytes by what they are: K/V rows, latent
             # rows, an indexer's keys, recurrent matrices, convolution tails
             "state_bytes": self.engine.cache.nbytes_by_kind,
             # slots a decode dispatch served, mean (with rounds: the slots
@@ -1076,10 +1029,9 @@ class DecodeServer:
             "live_slots_per_step": (
                 round(self.slot_dispatches / self.steps, 4)
                 if self.steps else None),
-            # what one concurrent request costs in pool HBM — includes
-            # the draft pool's share when speculative (kv_per_slot_bytes
-            # * slots == kv_pool_bytes holds in every configuration)
-            "kv_per_slot_bytes": per_slot,
+            # what one concurrent request costs in pool HBM
+            # (kv_per_slot_bytes * slots == kv_pool_bytes)
+            "kv_per_slot_bytes": self.engine.cache.per_slot_nbytes,
             # TP serving: the pool shards its head axis over ``model``,
             # so the per-chip footprint is kv_pool_bytes / kv_shards
             "kv_shards": self.engine.cache.n_shard,
@@ -1087,7 +1039,7 @@ class DecodeServer:
             # dispatches issued while the previous block was unread, of
             # all dispatches: the share of decode steps the chip did not
             # wait for the host (0 on the first step after the server was
-            # empty), on every kind of block
+            # empty), on either kind of block
             "decode_ahead_share": (round(self.decode_ahead / self.steps, 4)
                                    if self.steps else None),
             # dispatches of rounds in which every slot of the live set had
@@ -1111,15 +1063,12 @@ class DecodeServer:
             "dispatches_per_token": (
                 round(self.steps / self.decode_tokens, 4)
                 if self.decode_tokens else None),
-            # tokens one dispatch yields across the whole batch (slot
-            # batching amortizes on top of fusion/speculation) ...
+            # tokens one dispatch yields across the whole batch ...
             "accepted_tokens_per_dispatch": (
                 round(self.decode_tokens / self.steps, 4)
                 if self.steps else None),
-            # ... vs per live slot: exactly 1.0 on the unfused
-            # non-speculative path, > 1 ONLY through fusion (up to K)
-            # or accepted speculation (up to K*(spec_tokens+1)) — the
-            # isolated fast-path signal
+            # ... vs per live slot: exactly 1.0 of plain steps, more
+            # ONLY through accepted drafts (up to 2 with a module)
             "tokens_per_slot_dispatch": (
                 round(self.decode_tokens / self.slot_dispatches, 4)
                 if self.slot_dispatches else None),

@@ -89,18 +89,6 @@ TRACKED = [
      lambda r: _dig(r, "serve", "p99_latency_ms"), "lower"),
     ("serve_ttft_p50_ms",
      lambda r: _dig(r, "serve", "ttft_p50_ms"), "lower"),
-    # the PR-11 fast path: fused dispatch amortization (fewer host
-    # dispatches per token and a lower fused TPOT gate LOWER),
-    # speculative acceptance and quantized-pool concurrency gate HIGHER
-    ("serve_dispatches_per_token",
-     lambda r: _dig(r, "serve", "dispatches_per_token"), "lower"),
-    ("serve_tpot_fused_ms",
-     lambda r: _dig(r, "serve", "tpot_fused_ms"), "lower"),
-    ("serve_accepted_tokens_per_dispatch",
-     lambda r: _dig(r, "serve", "accepted_tokens_per_dispatch"),
-     "higher"),
-    ("serve_max_slots_int8",
-     lambda r: _dig(r, "serve", "max_slots_int8"), "higher"),
     # the serve fleet (PR 13): aggregate throughput and 1->2-replica
     # scaling gate higher; fleet latency percentiles and the
     # failover-recovery time gate lower

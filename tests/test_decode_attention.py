@@ -263,8 +263,8 @@ class TestEngineRead:
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
     def test_which_pools_the_kernel_reads(self, monkeypatch):
-        """The kernel is the read where a TPU is attached, for an
-        unquantized pool whose head size fills the lanes; int8 pools,
+        """The kernel is the read where a TPU is attached, for a
+        pool whose head size fills the lanes;
         other head sizes and (bound by the engine) pools sharded over a
         mesh keep the XLA read."""
         calls = []
@@ -290,7 +290,6 @@ class TestEngineRead:
         assert reads(lm, "float32", attached=True) == ["kernel"]
         assert reads(lm, "bfloat16", attached=True) == ["kernel"]
         assert reads(lm, "float32", attached=False) == ["xla"]
-        assert reads(lm, "int8", attached=True) == ["xla"]
         assert reads(lm, "float32", attached=True,
                      pool_kernel=False) == ["xla"]
         small = _lm128(d_model=32, num_heads=4, num_kv_heads=2)
@@ -302,12 +301,12 @@ class TestEngineRead:
 
         lm = _lm128(d_model=32, num_heads=4, num_kv_heads=2)
         one = DecodeEngine(lm, 2, max_len=96)._decode_jit(
-            (1,), eng._serve_decode_impl, lm, None)
+            eng._serve_decode_impl, lm, None)
         tp = DecodeEngine(
             _lm128(d_model=32, num_heads=4, num_kv_heads=2), 2, max_len=96,
             mesh=build_mesh(MeshSpec(data=1, model=2),
                             devices=jax.devices()[:2]))._decode_jit(
-            (1,), eng._serve_decode_impl, lm, None)
+            eng._serve_decode_impl, lm, None)
         assert one.__wrapped__.keywords == {}
         assert tp.__wrapped__.keywords == {"pool_kernel": False}
 

@@ -123,7 +123,7 @@ def small_blocks(monkeypatch):
 
 def _served(lm, lengths, slots=3, buckets=(16, 32, 64), prompts=None, **kw):
     server = DecodeServer(lm, slots=slots, max_len=128, buckets=buckets,
-                          fuse_steps=kw.pop("fuse_steps", 1), **kw)
+                          **kw)
     prompts = prompts or [_tokens(n, seed=n) for n, _ in lengths]
     reqs = [server.submit(p, k) for p, (_, k) in zip(prompts, lengths)]
     server.drain()
@@ -285,8 +285,7 @@ def test_round_logits_equal_the_reference(monkeypatch, small_blocks):
     position)."""
     lm = _lm()
     seen = _spy(monkeypatch)
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32))
     req = server.submit(_tokens(21), 8)
     server.drain()
     assert server.stats()["spec_accepted"] == 0
@@ -297,23 +296,18 @@ def test_round_logits_equal_the_reference(monkeypatch, small_blocks):
     np.testing.assert_allclose(got, np.asarray(extra)[-7:-1], atol=5e-5)
 
 
-@pytest.mark.parametrize("fuse_steps", [1, 3])
-def test_prefill_then_rounds_is_the_reference_forward(fuse_steps,
-                                                      small_blocks):
+def test_prefill_then_rounds_is_the_reference_forward(small_blocks):
     """Five requests over three slots (a slot is reused, slots freeze while
     others go on): every token is the reference's argmax over the whole
     sequence, every draft a round verified the reference module's, and the
     routing recorded through the round program the reference's own."""
     lm = _lm()
     lengths = [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)]
-    server, reqs = _served(lm, lengths, fuse_steps=fuse_steps,
-                           record_routing=fuse_steps == 1)
+    server, reqs = _served(lm, lengths, record_routing=True)
     for r in reqs:
         want, extra, routes = ref.forward(lm.params, _seq(r), _cfg())
         n = len(r.tokens)
         assert r.tokens == np.argmax(np.asarray(want)[-n:], -1).tolist()
-        if fuse_steps > 1:
-            continue
         module = np.argmax(np.asarray(extra), -1)
         judged = [(q, d) for q, d in r.drafts if q - 2 < len(module) - 1]
         assert len(judged) >= n - 2
@@ -347,13 +341,13 @@ def test_seeded_rounds_are_the_plain_greedy_decode(small_blocks):
 
 
 def test_sampled_rounds_draw_and_keep_their_streams(small_blocks):
-    """Temperature > 0 runs ``_serve_spec_impl``'s accept / resample rule
+    """Temperature > 0 runs ``_accept_round``'s accept / resample rule
     with a one-hot proposal distribution: the same seed gives the same
     tokens whatever else the batch holds, another seed other tokens."""
     lm = _lm()
     def run(others):
         server = DecodeServer(lm, slots=3, max_len=64, buckets=(16,),
-                              fuse_steps=1, temperature=0.8, top_k=20)
+                              temperature=0.8, top_k=20)
         req = server.submit(_tokens(9), 10, seed=5)
         more = [server.submit(_tokens(4 + i, seed=i), 6, seed=i)
                 for i in range(others)]
@@ -406,7 +400,7 @@ def trained():
 def _serve_counted(lm, prompts, news, slots=2):
     def run():
         server = DecodeServer(lm, slots=slots, max_len=128,
-                              buckets=(16, 32), fuse_steps=1)
+                              buckets=(16, 32))
         reqs = [server.submit(p, k) for p, k in zip(prompts, news)]
         server.drain()
         return server, reqs
@@ -428,7 +422,7 @@ def test_a_trained_module_is_accepted_and_changes_no_token(trained,
     news = [12, 2, 3, 9, 4]
     server, reqs, rounds = _serve_counted(trained, prompts, news)
     plain = DecodeServer(_plain(trained), slots=2, max_len=128,
-                         buckets=(16, 32), fuse_steps=1)
+                         buckets=(16, 32))
     want = [plain.submit(p, k) for p, k in zip(prompts, news)]
     plain.drain()
     assert [r.tokens for r in reqs] == [r.tokens for r in want]
@@ -454,7 +448,7 @@ def test_a_request_that_ends_on_an_accepted_draft(trained, small_blocks):
     goes on, the same slot-round is no dispatch of its own."""
     def serve(news, sync, slots=1):
         server = DecodeServer(trained, slots=slots, max_len=128,
-                              buckets=(16, 32), fuse_steps=1)
+                              buckets=(16, 32))
         reqs = [server.submit(_stream(9 + 2 * i, 2 + i), k)
                 for i, k in enumerate(news)]
         done_at = {}
@@ -518,7 +512,7 @@ def test_a_rejection_after_an_acceptance(trained, small_blocks):
     noisy.params = {**trained.params, "mtp": m}
     prompts = [_tokens(7, seed=s, vocab=16) for s in range(3)]
     plain = DecodeServer(_plain(trained), slots=1, max_len=128,
-                         buckets=(16, 32), fuse_steps=1)
+                         buckets=(16, 32))
     seen = []
     for p in prompts:
         _, (r,), rounds = _serve_counted(noisy, [p], [14], slots=1)
@@ -579,7 +573,7 @@ def test_pool_bytes_count_the_modules_layer():
     server = DecodeServer(lm, slots=4, max_len=64, buckets=(16,))
     st = server.stats()
     assert st["state_bytes"]["latent"] == cache.nbytes_by_kind["latent"]
-    assert server.engine.draft_cache is None and server.engine.spec
+    assert server.engine.spec and not hasattr(server.engine, "draft_cache")
 
 
 def test_spans_and_counters(small_blocks):
@@ -697,9 +691,8 @@ def test_a_module_that_cannot_be_built_is_refused(bad):
         TransformerLM(**kw)
 
 
-@pytest.mark.parametrize("what", ["generate", "beam", "int8", "mesh", "draft",
-                                  "draft_model", "handoff", "scan_layers",
-                                  "mixed", "kda", "routing_fused"])
+@pytest.mark.parametrize("what", ["generate", "beam", "mesh", "handoff",
+                                  "scan_layers", "mixed", "kda"])
 def test_paths_without_the_new_state_refuse_the_model(what):
     """What PR 31 listed for a stack of 'mla' layers still refuses by name,
     and so does speculative decoding where it is not written."""
@@ -711,9 +704,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
     elif what == "beam":
         with pytest.raises(NotImplementedError, match="latent"):
             lm.generate_beam(prompt, 3, beam_size=2)
-    elif what == "int8":
-        with pytest.raises(ValueError, match="latent rows"):
-            DecodeServer(lm, slots=1, max_len=32, kv_dtype="int8")
     elif what == "mesh":
         from deeplearning4j_tpu.parallel.sharding_registry import (
             ShardingRegistry)
@@ -724,12 +714,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
         with pytest.raises(ValueError, match="one chip"):
             SlotKVCache(lm, 1, 32, "bfloat16",
                         registry=ShardingRegistry.for_transformer(lm, mesh))
-    elif what == "draft":
-        with pytest.raises(ValueError, match="own multi-token-prediction"):
-            DecodeServer(lm, slots=1, max_len=32, draft_layers=1)
-    elif what == "draft_model":
-        with pytest.raises(ValueError, match="own multi-token-prediction"):
-            DecodeServer(lm, slots=1, max_len=32, draft_model=_plain(lm))
     elif what == "handoff":
         server = DecodeServer(lm, slots=1, max_len=32, buckets=(16,))
         with pytest.raises(ValueError, match="hand-off"):
@@ -744,25 +728,9 @@ def test_paths_without_the_new_state_refuse_the_model(what):
                                      mixers=("attn", "mla", "mla")))
         with pytest.raises(NotImplementedError, match="stack of 'mla'"):
             DecodeServer(mixed, slots=1, max_len=32)
-    elif what == "kda":
+    else:
         hybrid = TransformerLM(**dict(
             lm.get_config(), mixers=("kda", "mla", "mla"),
             kda={"head_dim": 16, "conv": 4, "lower": -5.0}))
         with pytest.raises(ValueError, match="speculative"):
             DecodeServer(hybrid, slots=1, max_len=32)
-    else:
-        with pytest.raises(ValueError, match="record_routing"):
-            DecodeServer(lm, slots=1, max_len=32, fuse_steps=2,
-                         record_routing=True)
-
-
-def test_a_plain_mla_stack_takes_a_separate_draft(small_blocks):
-    """The verify forward reads latent rows, so a stack of 'mla' layers
-    without a module may be drafted for by its own first layers (a second
-    pool): greedy tokens stay the plain decode's."""
-    lm = _plain(_lm())
-    lengths = [(5, 9), (16, 5), (9, 12)]
-    _, want = _served(lm, lengths)
-    server, got = _served(lm, lengths, draft_layers=2, spec_tokens=2)
-    assert [r.tokens for r in got] == [r.tokens for r in want]
-    assert server.stats()["speculative"]

@@ -107,7 +107,7 @@ def small_blocks(monkeypatch):
 
 def _served(lm, lengths, slots=3, buckets=(16, 32, 64), **kw):
     server = DecodeServer(lm, slots=slots, max_len=128, buckets=buckets,
-                          fuse_steps=kw.pop("fuse_steps", 1), **kw)
+                          **kw)
     reqs = [server.submit(_tokens(n, seed=n), k) for n, k in lengths]
     server.drain()
     return server, reqs
@@ -354,17 +354,13 @@ def test_contexts_below_index_topk_are_plain_mla():
 
 
 # ---- (b) prefill, then decode through the cache -----------------------------
-@pytest.mark.parametrize("fuse_steps", [1, 4])
-def test_prefill_then_decode_is_the_reference_forward(fuse_steps,
-                                                      small_blocks):
+def test_prefill_then_decode_is_the_reference_forward(small_blocks):
     """n prompt tokens through the prefill in blocks, then k tokens a step
     at a time through the slot cache (latent rows in every layer, index keys
     in the full ones), five requests over three slots: every token is the
-    reference's argmax over the whole sequence. The fused-K program carries
-    the same state."""
+    reference's argmax over the whole sequence."""
     lm = _lm()
-    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)],
-                      fuse_steps=fuse_steps)
+    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)])
     for r in reqs:
         want = np.asarray(ref.forward(lm.params, _seq(r), _cfg())[0])
         n = len(r.tokens)
@@ -377,8 +373,7 @@ def test_decode_logits_equal_the_reference(monkeypatch, small_blocks):
     reference's at position n + j."""
     lm = _lm()
     seen = _decode_logits(monkeypatch)
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32))
     req = server.submit(_tokens(21), 7)
     server.drain()
     want = np.asarray(ref.forward(lm.params, _seq(req), _cfg())[0])[-6:]
@@ -429,7 +424,7 @@ def test_bf16_server_stays_within_the_benchmarks_check(small_blocks):
     are this size's, not the cell's."""
     lm = _lm("bf16")
     server = DecodeServer(lm, slots=3, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1, record_routing=True)
+                          record_routing=True)
     req = server.submit(_tokens(30), 16)
     server.drain()
     toks = np.asarray(req.tokens, np.int32)
@@ -459,7 +454,7 @@ def test_the_reference_judges_a_handed_in_selection():
     the dense control gives other logits."""
     lm = _lm()
     server = DecodeServer(lm, slots=1, max_len=64, buckets=(32,),
-                          fuse_steps=1, record_routing=True)
+                          record_routing=True)
     req = server.submit(_tokens(20), 6)
     server.drain()
     seq = _seq(req)
@@ -559,8 +554,7 @@ def test_prefill_blocks_take_turns_with_decode_steps(small_blocks):
     takes its turns and has its first token first. Every request gives the
     tokens it gives alone."""
     lm = _lm()
-    server = DecodeServer(lm, slots=3, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=3, max_len=128, buckets=(16, 32, 64))
     first = server.submit(_tokens(12, seed=12), 30)
     while not first.tokens:
         server.step()
@@ -600,8 +594,7 @@ def test_a_slot_frozen_inside_the_next_prompt_spoils_no_row(small_blocks):
     new prompt (``engine.prefill_blocks`` moves the frozen cursor to the
     prompt's length first)."""
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 32, 64))
     before = server.submit(_tokens(11, seed=11), 9)
     other = server.submit(_tokens(6, seed=6), 40)
     while before.state != "finished":
@@ -618,8 +611,7 @@ def test_a_request_that_leaves_between_blocks_frees_its_slot(small_blocks):
     the rung's carry is back on its free list, and the next request in the
     slot gives the tokens it gives alone."""
     lm = _lm()
-    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64))
     gone = server.submit(_tokens(37, seed=37), 6)
     server.step()
     assert server.free_slot_count() == 0 and not gone.tokens
@@ -637,8 +629,7 @@ def test_a_slot_that_owes_nothing_keeps_its_rows():
     """A finished request's slot rides along in the next steps: its latent
     rows and index keys below its cursor stay as its last step left them."""
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,))
     short = server.submit(_tokens(5), 2)
     server.submit(_tokens(6, seed=1), 12)
     while short.state != "finished":
@@ -779,19 +770,6 @@ def test_the_decode_program_gathers_a_slot_at_a_time():
     assert text.count("while[") == 5 + 2
 
 
-@pytest.mark.parametrize("fuse_steps", [2, 4])
-def test_the_fused_program_is_the_plain_loop(fuse_steps, small_blocks):
-    """Requests that start and end at different steps, so that the live
-    mask changes between the steps of one fused program (a slot that owes
-    nothing more freezes mid-scan) and a freed slot is taken again: the
-    fused-K program emits the plain loop's tokens."""
-    lm = _lm()
-    lengths = [(21, 9), (5, 3), (12, 14), (9, 6), (30, 5)]
-    _, plain = _served(lm, lengths, fuse_steps=1)
-    _, fused = _served(lm, lengths, fuse_steps=fuse_steps)
-    assert [r.tokens for r in fused] == [r.tokens for r in plain]
-
-
 def test_rows_gathered_counts_the_work_list(small_blocks):
     """``serve.decode`` carries ``rows_gathered`` = the live slots x
     ``index_topk`` x the five 'mla' layers, whatever their cursors (a slot
@@ -903,8 +881,8 @@ def test_a_description_that_cannot_be_built_is_refused(bad):
         TransformerLM(**kw)
 
 
-@pytest.mark.parametrize("what", ["generate", "beam", "int8", "mesh", "draft",
-                                  "handoff", "scan_layers"])
+@pytest.mark.parametrize("what", ["generate", "beam", "mesh", "handoff",
+                                  "scan_layers"])
 def test_paths_without_the_new_state_refuse_the_model(what):
     """Every serving path that carries K/V only names what it lacks
     instead of decoding garbage."""
@@ -916,9 +894,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
     elif what == "beam":
         with pytest.raises(NotImplementedError, match="latent"):
             lm.generate_beam(prompt, 3, beam_size=2)
-    elif what == "int8":
-        with pytest.raises(ValueError, match="indexer's keys"):
-            DecodeServer(lm, slots=1, max_len=32, kv_dtype="int8")
     elif what == "mesh":
         from deeplearning4j_tpu.parallel.sharding_registry import (
             ShardingRegistry)
@@ -929,9 +904,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
         with pytest.raises(ValueError, match="one chip"):
             SlotKVCache(lm, 1, 32, "bfloat16",
                         registry=ShardingRegistry.for_transformer(lm, mesh))
-    elif what == "draft":
-        with pytest.raises(ValueError, match="indexer's keys"):
-            DecodeServer(lm, slots=1, max_len=32, draft_layers=1)
     elif what == "handoff":
         server = DecodeServer(lm, slots=1, max_len=32, buckets=(16,))
         with pytest.raises(ValueError, match="hand-off"):

@@ -132,8 +132,7 @@ def test_bucket_padded_prefill_leaves_the_unpadded_state(n):
     and the tail is the last three REAL positions (fewer than three: zeros
     in front)."""
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 64))
     toks = _tokens(n, seed=n)
     server.engine.prefill(toks, 1, jax.random.PRNGKey(0))
     states = ref.final_states(lm.params, toks, _cfg())
@@ -172,15 +171,13 @@ def _judge(lm, reqs, cfg, tol):
         assert gap.max() <= tol, (len(r.prompt), gap.max())
 
 
-@pytest.mark.parametrize("fuse_steps", [1, 4])
-def test_prefill_then_decode_is_the_reference_forward(fuse_steps):
+def test_prefill_then_decode_is_the_reference_forward():
     """n prompt tokens through the bucketed prefill, then k tokens one step
     at a time through the slot cache (latent rows, recurrent state, tails),
     five requests over three slots: every token is the reference's argmax
-    over the whole sequence. The fused-K program carries the same state."""
+    over the whole sequence."""
     lm = _lm()
-    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)],
-                      fuse_steps=fuse_steps)
+    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)])
     _judge(lm, reqs, _cfg(), 1e-5)
 
 
@@ -197,8 +194,7 @@ def test_decode_logits_equal_the_reference(monkeypatch):
         return logits, kv
 
     monkeypatch.setattr(eng, "_decode_step_body", spy)
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,))
     req = server.submit(_tokens(11), 6)
     server.drain()
     seq = np.concatenate([req.prompt, np.asarray(req.tokens, np.int32)])[:-1]
@@ -213,7 +209,7 @@ def test_bf16_server_stays_within_the_benchmark_tolerance():
     check does."""
     lm = _lm("bf16")
     server = DecodeServer(lm, slots=3, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1, record_routing=True)
+                          record_routing=True)
     req = server.submit(_tokens(30), 16)
     server.drain()
     toks = np.asarray(req.tokens, np.int32)
@@ -359,14 +355,12 @@ def test_a_reused_slot_gives_the_fresh_servers_tokens():
     gives the tokens it gives alone in a fresh server."""
     lm = _lm()
     lengths = [(40, 12), (7, 9), (21, 15)]
-    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64))
     reqs = [server.submit(_tokens(n, seed=n), k) for n, k in lengths]
     server.drain()
     assert [r.slot for r in reqs] == [0, 0, 0]
     for (n, k), r in zip(lengths, reqs):
-        fresh = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64),
-                             fuse_steps=1)
+        fresh = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64))
         alone = fresh.submit(_tokens(n, seed=n), k)
         fresh.drain()
         assert r.tokens == alone.tokens
@@ -376,8 +370,7 @@ def test_a_slot_that_owes_nothing_keeps_its_state():
     """A finished request's slot rides along in the next steps: its
     recurrent state and tail stay as its last step left them."""
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,))
     short = server.submit(_tokens(5), 2)
     server.submit(_tokens(6, seed=1), 12)
     while short.state != "finished":
@@ -416,7 +409,7 @@ def test_pool_bytes_count_every_kind(mixers):
 
 def test_stats_report_state_bytes_and_the_share():
     lm = _lm()
-    server, _ = _served(lm, [(5, 9), (16, 5), (37, 20)], fuse_steps=1,
+    server, _ = _served(lm, [(5, 9), (16, 5), (37, 20)],
                         record_routing=True)
     st = server.stats()
     assert st["state_bytes"] == server.engine.cache.nbytes_by_kind
@@ -441,10 +434,10 @@ def _lane_wide(policy):
 @pytest.mark.parametrize("policy", ["float32", "bf16"])
 def test_served_tokens_are_the_same_in_the_reached_form(policy, monkeypatch):
     lengths = [(5, 9), (16, 5), (37, 20), (9, 12)]
-    _, want = _served(_lane_wide(policy), lengths, fuse_steps=1)
+    _, want = _served(_lane_wide(policy), lengths)
     monkeypatch.setattr(routed_experts, "_kernel_backend",
                         lambda: "interpret")
-    server, got = _served(_lane_wide(policy), lengths, fuse_steps=1)
+    server, got = _served(_lane_wide(policy), lengths)
     assert [r.tokens for r in got] == [r.tokens for r in want]
     st = server.stats()
     assert st["moe_experts_read_per_step"] == \
@@ -465,7 +458,7 @@ def test_experts_read_says_which_form_a_program_took(monkeypatch):
 
     lengths = [(5, 9), (16, 5), (20, 7)]
     tracer().clear()
-    server, _ = _served(_lane_wide("float32"), lengths, fuse_steps=1)
+    server, _ = _served(_lane_wide("float32"), lengths)
     assert spans(server) and all(
         s.attrs["experts_read"] == 3 * HELD for s in spans(server))
     assert server.stats()["moe_experts_read_per_step"] == 3 * HELD
@@ -473,7 +466,7 @@ def test_experts_read_says_which_form_a_program_took(monkeypatch):
     tracer().clear()
     monkeypatch.setattr(routed_experts, "_kernel_backend",
                         lambda: "interpret")
-    server, _ = _served(_lane_wide("float32"), lengths, fuse_steps=1)
+    server, _ = _served(_lane_wide("float32"), lengths)
     by_name = {name: [s for s in spans(server) if s.name == name]
                for name in ("serve.decode", "serve.prefill")}
     assert by_name["serve.decode"] and by_name["serve.prefill"]
@@ -492,8 +485,7 @@ def test_an_attn_layer_among_the_others_serves():
     program against itself (``forward``), since the reference has no
     'attn' layer."""
     lm = _lm(mixers=("attn", "kda", "mla", "kda"))
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16, 32))
     reqs = [server.submit(_tokens(n, seed=n), 8) for n in (5, 20, 13)]
     server.drain()
     for r in reqs:
@@ -530,8 +522,8 @@ def test_get_config_rebuilds_the_model():
                 a, (dict, list))))
 
 
-@pytest.mark.parametrize("what", ["generate", "beam", "int8", "draft",
-                                  "handoff", "scan_layers"])
+@pytest.mark.parametrize("what", ["generate", "beam", "handoff",
+                                  "scan_layers"])
 def test_paths_without_the_new_state_refuse_the_model(what):
     """Every serving path that carries K/V only names what it lacks
     instead of decoding garbage."""
@@ -543,12 +535,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
     elif what == "beam":
         with pytest.raises(NotImplementedError, match="latent"):
             lm.generate_beam(prompt, 3, beam_size=2)
-    elif what == "int8":
-        with pytest.raises(ValueError, match="int8"):
-            DecodeServer(lm, slots=1, max_len=32, kv_dtype="int8")
-    elif what == "draft":
-        with pytest.raises(ValueError, match="speculative"):
-            DecodeServer(lm, slots=1, max_len=32, draft_layers=1)
     elif what == "handoff":
         server = DecodeServer(lm, slots=1, max_len=32, buckets=(16,))
         with pytest.raises(ValueError, match="hand-off"):

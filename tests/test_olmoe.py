@@ -419,7 +419,6 @@ def test_server_records_the_routing_that_served_each_position():
 @pytest.mark.parametrize("why, make", [
     ("dense model", lambda: dict(model=TransformerLM(
         V, d_model=D, num_heads=H, num_layers=1, max_len=64).init())),
-    ("fused steps", lambda: dict(model=_lm(), fuse_steps=2)),
 ])
 def test_record_routing_refuses_what_it_cannot_record(why, make):
     kw = make()

@@ -211,8 +211,7 @@ class TestServeSpans:
         1, step 3 owes nothing, books block 2 and retires, step 4 finds
         nothing."""
         t, clock = fresh_tracer
-        server = DecodeServer(_tiny_lm(), slots=2, max_len=96,
-                              fuse_steps=1, clock=clock)
+        server = DecodeServer(_tiny_lm(), slots=2, max_len=96, clock=clock)
         req = server.submit(np.arange(1, 20, dtype=np.int32), 3)
         progressed = [server.step() for _ in range(4)]
         return t, server, req, progressed
@@ -287,21 +286,20 @@ class TestServeSpans:
         assert len(names) == 3 + 1 + 3 + 2 + 4
         assert names.count("serve.admit") == 1
 
-    def test_fused_kind_and_mirror_carry_the_request(self, fresh_tracer,
-                                                     annotations):
+    def test_kind_and_mirror_carry_the_request(self, fresh_tracer,
+                                               annotations):
         t, clock = fresh_tracer
-        server = DecodeServer(_tiny_lm(), slots=2, max_len=96,
-                              fuse_steps=2, clock=clock)
-        req = server.submit(np.arange(1, 9, dtype=np.int32), 4)
+        server = DecodeServer(_tiny_lm(), slots=2, max_len=96, clock=clock)
+        req = server.submit(np.arange(1, 9, dtype=np.int32), 3)
         server.drain()
         entered = {n: kw for a, n, kw in annotations if a == "enter"}
-        # three tokens after the prompt's: two fused dispatches, the second
+        # two tokens after the prompt's: two dispatches, the second
         # ahead of the first's read, and a span that only reads
         assert [kw for a, n, kw in annotations
                 if a == "enter" and n == "dl4j.serve.decode"] == [
-            {"live": 1, "kind": "fused", "ahead": 0, "kv_rows": 18},
-            {"live": 1, "kind": "fused", "ahead": 1, "kv_rows": 22},
-            {"live": 0, "kind": "fused", "ahead": 0}]
+            {"live": 1, "kind": "plain", "ahead": 0, "kv_rows": 18},
+            {"live": 1, "kind": "plain", "ahead": 1, "kv_rows": 20},
+            {"live": 0, "kind": "plain", "ahead": 0}]
         assert entered["dl4j.serve.prefill"]["request"] == req.id
         assert set(entered["dl4j.serve.prefill"]) == {
             "request", "slot", "prompt_len", "bucket", "queue_wait_us"}

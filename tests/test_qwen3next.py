@@ -254,15 +254,13 @@ def _judge(lm, reqs, cfg, tol):
         assert gap.max() <= tol, (len(r.prompt), gap.max())
 
 
-@pytest.mark.parametrize("fuse_steps", [1, 4])
-def test_prefill_then_decode_is_the_reference_forward(fuse_steps):
+def test_prefill_then_decode_is_the_reference_forward():
     """n prompt tokens through the bucketed prefill, then k tokens one step
     at a time through the slot cache (K/V rows, recurrent state, tails),
     five requests over three slots: every token is the reference's argmax
-    over the whole sequence. The fused-K program carries the same state."""
+    over the whole sequence."""
     lm = _lm()
-    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)],
-                      fuse_steps=fuse_steps)
+    _, reqs = _served(lm, [(5, 9), (16, 5), (37, 20), (20, 7), (9, 12)])
     _judge(lm, reqs, _cfg(), 1e-5)
 
 
@@ -270,19 +268,18 @@ def test_wide_heads_are_served_from_a_pool_of_rows():
     """Heads of 256, wider than a lane tile: the pool is stored ``[L, S,
     T_max Hkv, Dh]`` (``kv_cache.pool_shape``), row ``t Hkv + h`` position t
     of kv head h, and prefill, decode writes and the read agree with the
-    reference; the int8 codec and a mesh keep the five axes."""
+    reference; a mesh keeps the five axes."""
     lm = _lm(attn={"head_dim": 256, "rotary_dim": 64, "head_norm": True,
                    "gate": True})
-    server, reqs = _served(lm, [(5, 9), (37, 12), (16, 5)], fuse_steps=1)
+    server, reqs = _served(lm, [(5, 9), (37, 12), (16, 5)])
     cache = server.engine.cache
     assert cache.k.shape == cache.v.shape == (1, 3, 128 * HKV, 256)
     assert cache.pool_dims == (1, 3, 128, HKV, 256)
     _judge(lm, reqs, _cfg(head_dim=256, rotary_dim=64), 1e-5)
-    assert pool_shape((1, 3, 128, HKV, 256), "int8") == (1, 3, 128, HKV, 256)
-    assert pool_shape((1, 3, 128, HKV, 256), "bfloat16", sharded=True) == (
+    assert pool_shape((1, 3, 128, HKV, 256)) == (1, 3, 128 * HKV, 256)
+    assert pool_shape((1, 3, 128, HKV, 256), sharded=True) == (
         1, 3, 128, HKV, 256)
-    assert pool_shape((4, 3, 128, HKV, 128), "bfloat16") == (
-        4, 3, 128, HKV, 128)
+    assert pool_shape((4, 3, 128, HKV, 128)) == (4, 3, 128, HKV, 128)
     assert server.stats()["kv_rows"] == sum(
         sum(range(n + 1, n + k)) for n, k in [(5, 9), (37, 12), (16, 5)])
 
@@ -301,8 +298,7 @@ def test_decode_logits_equal_the_reference(n, monkeypatch):
         return logits, kv
 
     monkeypatch.setattr(eng, "_decode_step_body", spy)
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,))
     req = server.submit(_tokens(n, seed=n), 6)
     server.drain()
     seq = np.concatenate([req.prompt, np.asarray(req.tokens, np.int32)])[:-1]
@@ -318,8 +314,7 @@ def test_bucket_padded_prefill_leaves_the_unpadded_state(n):
     untouched, and the K/V rows of the one attention layer are written up to
     the prompt's length."""
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 64))
     toks = _tokens(n, seed=n)
     server.engine.prefill(toks, 1, jax.random.PRNGKey(0))
     states = ref.final_states(lm.params, toks, _cfg())
@@ -339,7 +334,7 @@ def test_bf16_server_stays_within_the_benchmark_tolerance():
     check does."""
     lm = _lm("bf16", seed=4, d_model=256, d_ff=64)
     server = DecodeServer(lm, slots=3, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1, record_routing=True)
+                          record_routing=True)
     req = server.submit(_tokens(30, seed=30), 16)
     server.drain()
     toks = np.asarray(req.tokens, np.int32)
@@ -360,14 +355,12 @@ def test_bf16_server_stays_within_the_benchmark_tolerance():
 def test_a_reused_slot_gives_the_fresh_servers_tokens():
     lm = _lm()
     lengths = [(40, 12), (7, 9), (21, 15)]
-    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64))
     reqs = [server.submit(_tokens(n, seed=n), k) for n, k in lengths]
     server.drain()
     assert [r.slot for r in reqs] == [0, 0, 0]
     for (n, k), r in zip(lengths, reqs):
-        fresh = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64),
-                             fuse_steps=1)
+        fresh = DecodeServer(lm, slots=1, max_len=128, buckets=(16, 32, 64))
         alone = fresh.submit(_tokens(n, seed=n), k)
         fresh.drain()
         assert r.tokens == alone.tokens
@@ -375,8 +368,7 @@ def test_a_reused_slot_gives_the_fresh_servers_tokens():
 
 def test_a_slot_that_owes_nothing_keeps_its_state():
     lm = _lm()
-    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=64, buckets=(16,))
     short = server.submit(_tokens(5), 2)
     server.submit(_tokens(6, seed=1), 12)
     while short.state != "finished":
@@ -493,8 +485,7 @@ def test_a_kda_layer_and_a_gdn_layer_keep_state_side_by_side():
         (2, 4, 8, 8), (2, 4, 16, 16), (2, 4, 8, 8)]
     assert [a[0] for a in layout["conv"]] == [
         (2, 2, 96), (2, 3, 128), (2, 2, 96)]
-    server = DecodeServer(lm, slots=2, max_len=32, buckets=(16,),
-                          fuse_steps=1)
+    server = DecodeServer(lm, slots=2, max_len=32, buckets=(16,))
     req = server.submit(_tokens(9), 6)
     server.drain()
     seq = np.concatenate([req.prompt, np.asarray(req.tokens, np.int32)])
@@ -512,7 +503,7 @@ def test_stats_and_spans_carry_what_the_readers_divide_by():
 
     tracer().clear()
     lm = _lm()
-    server, reqs = _served(lm, [(5, 9), (16, 5), (37, 20)], fuse_steps=1,
+    server, reqs = _served(lm, [(5, 9), (16, 5), (37, 20)],
                            record_routing=True)
     spans = [s.attrs for s in tracer().spans()
              if s.name == "serve.decode" and s.attrs.get("live")]
@@ -600,8 +591,7 @@ def test_sizes_that_do_not_describe_a_layer_are_refused(bad):
         TransformerLM(**kw)
 
 
-@pytest.mark.parametrize("what", ["generate", "beam", "int8", "draft",
-                                  "draft_model", "mesh", "handoff",
+@pytest.mark.parametrize("what", ["generate", "beam", "mesh", "handoff",
                                   "scan_layers", "sequence_parallel"])
 def test_paths_without_the_new_state_refuse_the_model(what):
     """Every path that carries K/V only names what it lacks instead of
@@ -615,15 +605,6 @@ def test_paths_without_the_new_state_refuse_the_model(what):
     elif what == "beam":
         with pytest.raises(NotImplementedError, match="recurrent state"):
             lm.generate_beam(prompt, 3, beam_size=2)
-    elif what == "int8":
-        with pytest.raises(ValueError, match="int8 codec.*recurrent state"):
-            DecodeServer(lm, slots=1, max_len=32, kv_dtype="int8")
-    elif what == "draft":
-        with pytest.raises(ValueError, match="speculative.*'gdn'"):
-            DecodeServer(lm, slots=1, max_len=32, draft_layers=1)
-    elif what == "draft_model":
-        with pytest.raises(ValueError, match="no history to rewind"):
-            DecodeServer(lm, slots=1, max_len=32, draft_model=_lm(seed=4))
     elif what == "mesh":
         from deeplearning4j_tpu.parallel.mesh import build_mesh
 

@@ -296,7 +296,7 @@ def test_the_server_books_the_rows_run_on_the_prefill_span_and_in_stats():
         moe={"first": first, "held": held}).init()
     tracer().clear()
     server = DecodeServer(lm, slots=2, max_len=128, buckets=(16, 32, 64),
-                          fuse_steps=1, record_routing=True)
+                          record_routing=True)
     rng = np.random.default_rng(0)
     reqs = [server.submit(rng.integers(1, 256, n).astype(np.int32), 3)
             for n in (5, 20, 37, 64)]

@@ -97,7 +97,7 @@ def _lower(lm, program, **kw):
     cache = engine.cache
     if program == "prefill":
         fn = functools.partial(eng._serve_prefill_impl, lm,
-                               engine._sample_row, cache.quantized)
+                               engine._sample_row, False)
         return jax.jit(fn).lower(
             lm.params, cache.state, jnp.zeros((1, BUCKET), jnp.int32),
             jnp.asarray(5, jnp.int32), jnp.asarray(1, jnp.int32),

@@ -247,7 +247,7 @@ class TestRetryBudget:
             b.deposit("platinum")
 
     def test_dry_budget_parks_failover_with_evidence(self):
-        reps = [_replica(f"r{i}", fuse_steps=2) for i in range(2)]
+        reps = [_replica(f"r{i}") for i in range(2)]
         router = FleetRouter(reps)
         router.retry_budget = RetryBudget(ratio=0.0, burst=0.0)
         controller = FleetController(router, None, evict_timeout_s=5.0)
@@ -276,7 +276,7 @@ class TestRetryBudget:
                                   _ref(lm, fr.prompt, fr.max_new_tokens))
 
     def test_first_placement_is_free(self):
-        reps = [_replica("r0", fuse_steps=2)]
+        reps = [_replica("r0")]
         router = FleetRouter(reps)
         router.retry_budget = RetryBudget(ratio=0.0, burst=0.0)
         fr = router.try_submit(_prompt(), 4)
@@ -288,7 +288,7 @@ class TestRetryBudget:
 # ---------------------------------------------------------------------------
 class TestHedging:
     def _fleet(self, t):
-        reps = [_replica(f"r{i}", slots=1, max_queue=2, fuse_steps=2)
+        reps = [_replica(f"r{i}", slots=1, max_queue=2)
                 for i in range(2)]
         clock = lambda: t["now"]  # noqa: E731
         router = FleetRouter(reps, clock=clock)
@@ -392,7 +392,7 @@ class TestHedging:
 class TestDrain:
     def test_drain_migrates_live_and_queued_zero_recompute(self):
         lm = _lm()
-        reps = [_replica(f"r{i}", slots=2, max_queue=4, fuse_steps=2)
+        reps = [_replica(f"r{i}", slots=2, max_queue=4)
                 for i in range(2)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=5.0)
@@ -433,7 +433,7 @@ class TestDrain:
 
     def test_drain_drops_hedge_copies_not_primaries(self):
         t = {"now": 0.0}
-        reps = [_replica(f"r{i}", slots=1, max_queue=2, fuse_steps=2)
+        reps = [_replica(f"r{i}", slots=1, max_queue=2)
                 for i in range(2)]
         clock = lambda: t["now"]  # noqa: E731
         router = FleetRouter(reps, clock=clock)
@@ -491,10 +491,10 @@ class TestOverloadSoak:
     drain — the ``--serve-slo`` gate's assertions, read mechanically
     off the run report and the decision logs."""
 
-    PIN = 0.01                              # pinned per-step cost
+    PIN = 0.005                             # pinned per-step cost
 
     def _fleet(self):
-        reps = [_replica(f"r{i}", slots=2, max_queue=4, fuse_steps=2)
+        reps = [_replica(f"r{i}", slots=2, max_queue=4)
                 for i in range(3)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=50.0)
@@ -519,12 +519,13 @@ class TestOverloadSoak:
         assert base["finished"] == 30
         # uncontended TTFT on a virtual clock can round to zero (the
         # token lands in the same tick the request arrives); the
-        # physical floor is one pinned step
+        # physical floor is two pinned steps, the one in flight when a
+        # request arrives and the one that admits it
         base_ttft = max(base["ttft_p50_ms_by_class"]["interactive"],
-                        1000.0 * self.PIN)
+                        2 * 1000.0 * self.PIN)
 
         # the storm: ~3x capacity. Capacity ~ 3 replicas x 2 slots x
-        # (2 fused tokens / 0.01 s) / ~7 tokens-per-request ~ 170 rps;
+        # (1 token / 0.005 s) / ~7 tokens-per-request ~ 170 rps;
         # drive 500 rps with a 25/60/15 class mix and per-class
         # deadline budgets wide enough that interactive holds
         router, controller, driver = self._fleet()

@@ -27,10 +27,11 @@ from deeplearning4j_tpu.models.transformer import TransformerLM
 from deeplearning4j_tpu.monitor import metrics, set_tracer, SpanTracer
 from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
+from deeplearning4j_tpu.serving import kv_cache
 from deeplearning4j_tpu.serving import (
     DecodeServer, ServeQueueFull, SlotKVCache, kv_pool_nbytes,
     max_slots_in_budget, poisson_schedule, run_open_loop,
-    serve_draft_layers, serve_fuse_steps, serve_max_queue, serve_slots)
+    serve_max_queue, serve_slots)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -462,384 +463,206 @@ class TestBenchReportDirections:
 
 
 # ---------------------------------------------------------------------------
-# fused multi-token decode: ("decode_fused", S, K)
+# one decode block a model: the plain step's program, and the names that left
 # ---------------------------------------------------------------------------
-class TestFusedDecode:
-    @pytest.mark.parametrize("pos_encoding", ["learned", "rope"])
-    def test_fused_greedy_token_identical(self, rng, pos_encoding):
-        """K=4 fused decode over 2 slots with recycling across fusion
-        boundaries — token-for-token identical to the K=1 path (which
-        PR 10 pinned to ``generate``)."""
-        lm = _lm(pos_encoding)
-        prompts = _prompts(rng, (5, 11, 23))
-        max_new = [7, 4, 9]
-        refs = [np.asarray(lm.generate(p[None], m))[0]
-                for p, m in zip(prompts, max_new)]
-        srv = DecodeServer(lm, slots=2, max_len=96, fuse_steps=4)
-        reqs = [srv.submit(p, m) for p, m in zip(prompts, max_new)]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert req.state == "finished"
-            assert np.array_equal(req.output, ref)
-
-    def test_fused_dispatch_count_is_ceil(self, rng):
-        """The acceptance invariant: one request generating N tokens at
-        fuse_steps=K takes exactly ceil((N - prefill_token)/K) decode
-        dispatches, counter-asserted."""
-        lm = _lm()
-        p = _prompts(rng, (6,))[0]
-        for k, max_new in ((4, 10), (3, 10), (5, 6), (4, 5)):
-            srv = DecodeServer(lm, slots=1, max_len=96, fuse_steps=k)
-            reg = metrics()
-            d0 = reg.counter("serve_decode_steps_total").value()
-            req = srv.submit(p, max_new)
-            srv.drain()
-            want = -(-(max_new - 1) // k)   # ceil; 1 token from prefill
-            assert srv.steps == want, (k, max_new, srv.steps)
-            assert reg.counter("serve_decode_steps_total").value() \
-                == d0 + want
-            assert np.array_equal(
-                req.output, np.asarray(lm.generate(p[None], max_new))[0])
-
-    def test_fused_sampled_matches_single_step(self, rng):
-        """Per-slot RNG splits move in-program: the K=3 fused stream
-        emits the same sampled tokens as ``generate(seed=s)``."""
-        lm = _lm(num_kv_heads=4)
-        prompts = _prompts(rng, (5, 11))
-        refs = [np.asarray(lm.generate(
-            p[None], 6, temperature=0.7, top_k=13, seed=s))[0]
-            for s, p in enumerate(prompts)]
-        srv = DecodeServer(lm, slots=2, max_len=96, fuse_steps=3,
-                           temperature=0.7, top_k=13)
-        reqs = [srv.submit(p, 6, seed=s) for s, p in enumerate(prompts)]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert np.array_equal(req.output, ref)
-
-    def test_ragged_retirement_mid_scan(self, rng):
-        """A short request (2 tokens) rides a K=4 scan beside a long one
-        (9): the short slot self-freezes mid-scan (its remaining hits 0)
-        and both streams stay token-exact through the recycle that
-        follows."""
-        lm = _lm()
-        prompts = _prompts(rng, (4, 8, 6))
-        max_new = [2, 9, 5]
-        refs = [np.asarray(lm.generate(p[None], m))[0]
-                for p, m in zip(prompts, max_new)]
-        srv = DecodeServer(lm, slots=2, max_len=96, fuse_steps=4)
-        reqs = [srv.submit(p, m) for p, m in zip(prompts, max_new)]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert len(req.tokens) == req.max_new_tokens
-            assert np.array_equal(req.output, ref)
-
+class TestOneDecodeBlock:
     def test_fuse_steps_one_is_pr10_bitwise(self, rng):
-        """``DL4J_SERVE_FUSE_STEPS=1`` (the default) runs the identical
-        PR-10 single-step program — same ("decode", S) cache key, same
-        per-step dispatch cadence, same tokens."""
+        """The server runs the PR-10 single-step program — the ("decode",
+        S) cache key, one dispatch a token, ``generate``'s tokens — and no
+        other decode program; ``fuse_steps=1`` (what the benchmark's
+        drivers pass) builds the same server and any other value
+        raises."""
         lm = _lm()
         prompts = _prompts(rng, (5, 11))
         refs = [np.asarray(lm.generate(p[None], m))[0]
                 for p, m in zip(prompts, (6, 4))]
-        srv = DecodeServer(lm, slots=2, max_len=96)
-        assert srv.fuse_steps == 1
+        srv = DecodeServer(lm, slots=2, max_len=96, fuse_steps=1)
         reqs = [srv.submit(p, m) for p, m in zip(prompts, (6, 4))]
         srv.drain()
-        assert ("decode", 2) in srv.engine._programs
-        assert not any(s[0] in ("decode_fused", "decode_spec")
-                       for s in srv.engine._programs)
+        assert [s for s in srv.engine._programs if s[0].startswith(
+            "decode")] == [("decode", 2)]
         assert srv.steps == 5   # max(6,4)-1: one dispatch per token
         for req, ref in zip(reqs, refs):
             assert np.array_equal(req.output, ref)
-        assert srv.stats()["tokens_per_slot_dispatch"] == 1.0
+        st = srv.stats()
+        assert st["tokens_per_slot_dispatch"] == 1.0
+        assert "fuse_steps" not in st and not hasattr(srv, "fuse_steps")
+        with pytest.raises(ValueError, match="one decode step"):
+            DecodeServer(lm, slots=2, max_len=96, fuse_steps=2)
 
-    def test_fused_env_flag(self, rng, monkeypatch):
-        monkeypatch.setenv("DL4J_SERVE_FUSE_STEPS", "4")
-        assert serve_fuse_steps() == 4
+    @pytest.mark.parametrize("kw,error,variable", [
+        ({"fuse_steps": 2}, ValueError, "DL4J_SERVE_FUSE_STEPS"),
+        ({"kv_dtype": "int8"}, ValueError, "DL4J_SERVE_KV_DTYPE"),
+        ({"draft_layers": 1}, TypeError, "DL4J_SERVE_DRAFT_LAYERS"),
+    ], ids=["fuse_steps", "int8", "draft_layers"])
+    def test_the_removed_names_refuse(self, monkeypatch, kw, error,
+                                      variable):
+        """K fused steps, the int8 pool and a separate draft are not
+        options of the server or the engine: asking for one fails at
+        construction, and the variable that used to ask for it is read by
+        nobody."""
+        from deeplearning4j_tpu.serving import DecodeEngine
+
         lm = _lm()
+        with pytest.raises(error):
+            DecodeServer(lm, slots=1, max_len=96, **kw)
+        if "fuse_steps" not in kw:      # the engine never took it
+            with pytest.raises(error):
+                DecodeEngine(lm, 1, max_len=96, **kw)
+        monkeypatch.setenv(variable, str(list(kw.values())[0]))
         srv = DecodeServer(lm, slots=1, max_len=96)
-        assert srv.fuse_steps == 4
-        monkeypatch.setenv("DL4J_SERVE_FUSE_STEPS", "bogus")
-        assert serve_fuse_steps() == 1
-        monkeypatch.delenv("DL4J_SERVE_FUSE_STEPS")
-        assert serve_fuse_steps() == 1
-
-    def test_fused_compile_flat_after_warmup(self, rng):
-        """The fused program joins the bounded program set: a second
-        ragged wave at the same (S, K) adds ZERO programs."""
-        lm = _lm()
-        srv = DecodeServer(lm, slots=3, max_len=96, fuse_steps=4)
-        for p, m in zip(_prompts(rng, (5, 12, 30)), (4, 3, 5)):
-            srv.submit(p, m)
-        srv.drain()
-        warm = srv.engine.program_builds
-        assert ("decode_fused", 3, 4) in srv.engine._programs
-        for p, m in zip(_prompts(rng, (7, 16, 25, 9)), (2, 5, 3, 4)):
-            srv.submit(p, m)
-        srv.drain()
-        assert srv.engine.program_builds == warm
-
-    def test_admission_waits_for_fusion_boundary(self, rng):
-        """With fuse_steps=K a request submitted while a dispatch is in
-        flight joins at the next step() — the admission-boundary
-        semantics (queue drains only through _admit)."""
-        lm = _lm()
-        srv = DecodeServer(lm, slots=2, max_len=96, fuse_steps=4)
-        srv.submit(_prompts(rng, (5,))[0], 9)
-        srv.step()                     # dispatch in flight for req 1
-        late = srv.submit(_prompts(rng, (7,))[0], 3)
-        assert late.state == "queued"  # mid-flight: not admitted
-        srv.step()                     # boundary: admitted + decoded
-        assert late.state in ("running", "finished")
-        srv.drain()
-        assert np.array_equal(
-            late.output,
-            np.asarray(lm.generate(late.prompt[None], 3))[0])
+        assert (srv._decode_kind, srv.engine.spec, srv.engine.kv_dtype) == (
+            "plain", False, "float32")
 
 
 # ---------------------------------------------------------------------------
-# quantized KV pool (DL4J_SERVE_KV_DTYPE)
+# the pool's store dtype
 # ---------------------------------------------------------------------------
-class TestQuantizedKV:
-    def test_int8_pool_shrinks_4x(self):
-        lm = _lm()
-        f32 = SlotKVCache(lm, slots=4, max_len=96, kv_dtype="float32")
-        i8 = SlotKVCache(lm, slots=4, max_len=96, kv_dtype="int8")
-        ratio = f32.per_slot_nbytes / i8.per_slot_nbytes
-        assert 3.5 < ratio <= 4.0, ratio
-        assert kv_pool_nbytes(lm, 4, 96, "int8") == i8.nbytes
-        assert kv_pool_nbytes(lm, 4, 96, "float32") == f32.nbytes
-
-    def test_validate_cache_budget_prices_the_quantized_pool(self):
-        """PR 8's budget validator sees the pool + scale sidecars the
-        runtime actually allocated: predicted nbytes == measured device
-        bytes, and the int8 pool measures ~4x under float32."""
+class TestPoolDtype:
+    def test_validate_cache_budget_prices_the_pool(self):
+        """PR 8's budget validator sees the pool the runtime actually
+        allocated: predicted nbytes == measured device bytes, and the
+        bfloat16 pool measures half of float32."""
         from deeplearning4j_tpu.monitor.memory import validate_cache_budget
         lm = _lm()
         out = {}
-        for dt in ("float32", "int8"):
+        for dt in ("float32", "bfloat16"):
             cache = SlotKVCache(lm, slots=4, max_len=96, kv_dtype=dt)
             v = validate_cache_budget(cache)
             assert v["within_tolerance"], v
             assert v["predicted_per_shard_bytes"] \
-                == v["measured_per_device_bytes"] == cache.nbytes
+                == v["measured_per_device_bytes"] == cache.nbytes \
+                == kv_pool_nbytes(lm, 4, 96, dt)
             out[dt] = v["measured_per_device_bytes"]
-        assert 3.5 < out["float32"] / out["int8"] <= 4.0
+        assert out["float32"] == 2 * out["bfloat16"]
 
     def test_max_slots_in_budget_multiplies(self):
         lm = _lm()
         budget = 64 * 1024 * 1024
         n_f32 = max_slots_in_budget(lm, 96, budget, "float32")
-        n_i8 = max_slots_in_budget(lm, 96, budget, "int8")
-        assert n_i8 > 3 * n_f32
-        assert max_slots_in_budget(lm, 96, 0, "int8") == 0
+        n_bf16 = max_slots_in_budget(lm, 96, budget, "bfloat16")
+        assert n_bf16 in (2 * n_f32, 2 * n_f32 + 1) and n_f32 > 0
+        assert max_slots_in_budget(lm, 96, budget) == n_f32   # the model's
+        assert max_slots_in_budget(lm, 96, 0, "bfloat16") == 0
 
-    def test_kv_dtype_validation_and_env(self, monkeypatch):
+    def test_kv_dtype_validation(self, monkeypatch):
+        """Two float names and their aliases; ``"int8"`` is refused as
+        ``"int4"`` is; no variable names a default: unset, the pool stays
+        in the model's compute dtype."""
         lm = _lm()
-        with pytest.raises(ValueError):
-            SlotKVCache(lm, slots=1, kv_dtype="int4")
+        for bad in ("int4", "int8"):
+            with pytest.raises(ValueError, match="kv_dtype"):
+                SlotKVCache(lm, slots=1, kv_dtype=bad)
+        assert SlotKVCache(lm, slots=1, kv_dtype="bf16").kv_dtype \
+            == "bfloat16"
         monkeypatch.setenv("DL4J_SERVE_KV_DTYPE", "bf16")
-        assert SlotKVCache(lm, slots=1).kv_dtype == "bfloat16"
-        monkeypatch.delenv("DL4J_SERVE_KV_DTYPE")
-        # unset: the pool stays in the model's compute dtype (the
-        # pre-quantization default, bitwise)
         assert SlotKVCache(lm, slots=1).kv_dtype == "float32"
-
-    def test_int8_greedy_token_parity(self, rng):
-        """End-to-end: the int8-quantized pool reproduces the
-        full-precision greedy stream on the small test model (pinned
-        prompts — int8 is lossy by design; the logit-error test bounds
-        how lossy)."""
-        lm = _lm()
-        prompts = _prompts(rng, (5, 17))
-        max_new = [7, 6]
-        refs = [np.asarray(lm.generate(p[None], m))[0]
-                for p, m in zip(prompts, max_new)]
-        srv = DecodeServer(lm, slots=2, max_len=96, kv_dtype="int8")
-        reqs = [srv.submit(p, m) for p, m in zip(prompts, max_new)]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert np.array_equal(req.output, ref)
-        assert srv.stats()["kv_dtype"] == "int8"
-
-    def test_int8_fused_matches_single_step(self, rng):
-        """Quantization composes with fusion: K=3 int8 == K=1 int8
-        token-for-token (the requant/scatter sequence per slot is the
-        same op chain either way)."""
-        lm = _lm("rope")
-        prompts = _prompts(rng, (3, 9, 17, 5))
-        max_new = [5, 2, 6, 8]
-        a = DecodeServer(lm, slots=2, max_len=96, kv_dtype="int8")
-        b = DecodeServer(lm, slots=2, max_len=96, kv_dtype="int8",
-                         fuse_steps=3)
-        ra = [a.submit(p, m) for p, m in zip(prompts, max_new)]
-        a.drain()
-        rb = [b.submit(p, m) for p, m in zip(prompts, max_new)]
-        b.drain()
-        for x, y in zip(ra, rb):
-            assert np.array_equal(x.output, y.output)
-
-    def test_int8_roundtrip_logit_error_bound(self):
-        """The quantization error contract: a dequantized K/V element
-        sits within absmax/127 of the original (half a quantum after
-        rounding), including after a requantizing scale growth."""
-        import jax.numpy as jnp
-        from deeplearning4j_tpu.serving.kv_cache import (
-            dequant_slab, requant_write_slab)
-
-        rng = np.random.default_rng(7)
-        s_, t_, h_, d_ = 3, 8, 2, 4
-        slab = jnp.zeros((s_, t_, h_, d_), jnp.int8)
-        scale = jnp.zeros((s_, h_), jnp.float32)
-        rows = jnp.arange(s_)
-        vals1 = jnp.asarray(rng.normal(size=(s_, 4, h_, d_)), jnp.float32)
-        pos1 = jnp.tile(jnp.arange(4)[None], (s_, 1))
-        slab, scale = requant_write_slab(slab, scale, vals1, rows, pos1)
-        # second write with LARGER values: forces a requantization of
-        # the first write's entries under the grown scale
-        vals2 = 3.0 * jnp.asarray(
-            rng.normal(size=(s_, 4, h_, d_)), jnp.float32)
-        pos2 = pos1 + 4
-        slab, scale = requant_write_slab(slab, scale, vals2, rows, pos2)
-        deq = np.asarray(dequant_slab(slab, scale, jnp.float32))
-        bound = np.asarray(scale)[:, None, :, None] / 127.0 + 1e-7
-        err1 = np.abs(deq[:, :4] - np.asarray(vals1))
-        err2 = np.abs(deq[:, 4:] - np.asarray(vals2))
-        # the requantized first write pays one extra rounding: 2 quanta
-        assert (err1 <= 2 * bound).all(), err1.max()
-        assert (err2 <= bound).all(), err2.max()
+        srv = DecodeServer(lm, slots=1, max_len=96, kv_dtype="bfloat16")
+        assert srv.stats()["kv_dtype"] == "bfloat16"
+        assert srv.engine.cache.k.dtype == "bfloat16"
 
 
 # ---------------------------------------------------------------------------
-# speculative decoding (draft + verify inside the fused program)
+# six tiny models, one for each thing a slot can hold
 # ---------------------------------------------------------------------------
-class TestSpeculativeDecode:
-    def test_full_self_draft_accepts_everything(self, rng):
-        """draft_layers == num_layers makes the draft the target: every
-        proposal verifies, a round yields spec_tokens + 1 tokens, and the
-        stream is the target's greedy stream."""
-        lm = _lm()
-        p = _prompts(rng, (5,))[0]
-        srv = DecodeServer(lm, slots=1, max_len=96, draft_layers=2,
-                           spec_tokens=3)
-        req = srv.submit(p, 13)     # 12 decode tokens = 3 full rounds
-        srv.drain()
-        assert np.array_equal(
-            req.output, np.asarray(lm.generate(p[None], 13))[0])
-        st = srv.stats()
-        assert st["spec_accept_rate"] == 1.0
-        assert st["spec_emitted"] == 4 * st["spec_rounds"] == 12
-        # read one dispatch behind, the host dispatches a fourth time on
-        # "may owe a token" (12 - 4 - 4 less the one token the unread
-        # round holds at least): the device had frozen the slot, and the
-        # block says so
-        assert (srv.steps, st["empty_dispatches"]) == (4, 1)
-        assert st["tokens_per_slot_dispatch"] == 3.0
+def _routed_lm():
+    """OLMoE's block, tiny: RMSNorm, QK-norm, untied head, RoPE, 4 SwiGLU
+    experts with 2 a token (float32: a row depends on that row alone, bit
+    for bit, whatever the batch holds)."""
+    return TransformerLM(vocab_size=61, d_model=32, num_heads=4,
+                         num_layers=2, d_ff=16, max_len=32,
+                         pos_encoding="rope", attn_impl="xla",
+                         norm="rmsnorm", qk_norm=True, num_experts=4,
+                         experts_per_token=2, tie_embeddings=False,
+                         seed=3).init()
 
-    @pytest.mark.parametrize("pos_encoding", ["learned", "rope"])
-    def test_shallow_draft_greedy_token_identity(self, rng, pos_encoding):
-        """The speculative contract: whatever the draft proposes (here a
-        1-of-2-layer self-draft with a low accept rate), the emitted
-        stream is EXACTLY the target model's greedy stream — acceptance
-        only changes how many dispatches it takes."""
-        lm = _lm(pos_encoding)
-        prompts = _prompts(rng, (5, 11, 23))
-        max_new = [7, 4, 9]
-        refs = [np.asarray(lm.generate(p[None], m))[0]
-                for p, m in zip(prompts, max_new)]
-        srv = DecodeServer(lm, slots=2, max_len=96, draft_layers=1,
-                           spec_tokens=3)
-        reqs = [srv.submit(p, m) for p, m in zip(prompts, max_new)]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert np.array_equal(req.output, ref)
-        st = srv.stats()
-        assert st["speculative"] and st["spec_proposed"] > 0
 
-    def test_provided_draft_model(self, rng):
-        """An independently seeded draft TransformerLM rides the same
-        slot machinery (its own pool) and preserves target greedy
-        token identity."""
-        lm = _lm("rope")
-        draft = _lm("rope", num_layers=1, seed=9)
-        p = _prompts(rng, (9,))[0]
-        ref = np.asarray(lm.generate(p[None], 8))[0]
-        srv = DecodeServer(lm, slots=2, max_len=96, draft_model=draft,
-                           spec_tokens=2)
-        req = srv.submit(p, 8)
-        srv.drain()
-        assert np.array_equal(req.output, ref)
+_MLA = {"kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+        "v_head_dim": 8}
 
-    def test_spec_composes_with_fuse_steps(self, rng):
-        """K rounds per dispatch: fuse_steps=2 x spec_tokens=2 emits up
-        to 6 tokens per dispatch and stays target-greedy-exact."""
-        lm = _lm()
-        prompts = _prompts(rng, (3, 9, 17))
-        refs = [np.asarray(lm.generate(p[None], 9))[0] for p in prompts]
-        srv = DecodeServer(lm, slots=2, max_len=96, draft_layers=2,
-                           spec_tokens=2, fuse_steps=2)
-        reqs = [srv.submit(p, 9) for p in prompts]
-        srv.drain()
-        for req, ref in zip(reqs, refs):
-            assert np.array_equal(req.output, ref)
-        assert srv.stats()["tokens_per_slot_dispatch"] > 1.0
 
-    def test_sampled_spec_matches_target_distribution(self):
-        """Accept/resample correctness, statistically: the marginal of
-        a decode-phase token under speculative sampling stays within a
-        total-variation bound of the vanilla sampled server's (exact
-        per-token identity is NOT expected — the RNG consumption
-        differs; the DISTRIBUTION must not)."""
-        V = 13
-        lm = TransformerLM(vocab_size=V, d_model=16, num_heads=2,
-                           num_layers=2, max_len=32, seed=5).init()
-        prompt = np.array([1, 2, 3], np.int32)
-        n = 300
+def _module_lm():
+    """Two latent-attention layers, routed experts in the second, and a
+    multi-token-prediction module the server drafts from (float32;
+    ``tests/test_gigachat_mtp.py`` holds the model to its reference)."""
+    return TransformerLM(
+        vocab_size=61, d_model=32, num_heads=4, num_layers=2, d_ff=16,
+        max_len=32, pos_encoding="rope", norm="rmsnorm",
+        tie_embeddings=False, seed=3, num_experts=8, experts_per_token=2,
+        norm_topk_prob=True, mixers=("mla",) * 2, ffns=("glu", "moe"),
+        glu_width=32, mtp={"loss_weight": 0.3},
+        mla={"q_lora_rank": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+             "qk_rope_head_dim": 8, "v_head_dim": 12, "gate": False}).init()
 
-        def freqs(**kw):
-            srv = DecodeServer(lm, slots=1, max_len=32, temperature=0.9,
-                               **kw)
-            c = np.zeros(V)
-            for s in range(n):
-                req = srv.submit(prompt, 4, seed=s)
-                srv.drain()
-                c[req.tokens[2]] += 1
-            return c / n
 
-        ref = freqs()
-        spec = freqs(draft_layers=1, spec_tokens=2)
-        tv = 0.5 * np.abs(ref - spec).sum()
-        assert tv < 0.15, tv
+def _hybrid_lm():
+    """A Kimi Delta Attention layer under a latent-attention layer: a slot
+    holds a recurrent matrix, a convolution tail and latent rows, and its
+    prompt is prefilled whole (``tests/test_ling_hybrid.py`` holds the
+    model to its reference)."""
+    return TransformerLM(
+        vocab_size=61, d_model=32, num_heads=4, num_layers=2, d_ff=16,
+        max_len=32, pos_encoding="rope", attn_impl="xla", norm="rmsnorm",
+        tie_embeddings=False, seed=3, rope_interleaved=True,
+        mixers=("kda", "mla"), ffns=("glu", "glu"), glu_width=32,
+        kda={"head_dim": 8, "conv": 4, "lower": -5.0}, mla=_MLA).init()
 
-    def test_env_flag_and_validation(self, rng, monkeypatch):
-        monkeypatch.setenv("DL4J_SERVE_DRAFT_LAYERS", "1")
-        assert serve_draft_layers() == 1
-        lm = _lm()
-        srv = DecodeServer(lm, slots=1, max_len=96)
-        assert srv.engine.spec
-        assert srv.engine.draft_model.num_layers == 1
-        monkeypatch.delenv("DL4J_SERVE_DRAFT_LAYERS")
-        with pytest.raises(ValueError):
-            DecodeServer(lm, slots=1, max_len=96, draft_layers=3)
-        with pytest.raises(ValueError):
-            DecodeServer(lm, slots=1, max_len=96, draft_layers=1,
-                         spec_tokens=0)
-        with pytest.raises(ValueError):
-            # draft vocab mismatch
-            DecodeServer(lm, slots=1, max_len=96,
-                         draft_model=_lm(vocab_size=32))
 
-    def test_spec_capacity_needs_verify_slack(self, rng):
-        """The verify forward writes spec_tokens candidates past the
-        live cursor: submit() reserves that slack against max_len."""
-        lm = _lm()
-        srv = DecodeServer(lm, slots=1, max_len=32, draft_layers=1,
-                           spec_tokens=4)
-        with pytest.raises(ValueError):
-            srv.submit(_prompts(rng, (20,))[0], 9)   # 29 + 4 > 32
-        req = srv.submit(_prompts(rng, (20,))[0], 8)  # 28 + 4 == 32
-        srv.drain()
-        assert len(req.tokens) == 8
+def _sparse_lm():
+    """Latent attention over a lightning indexer's selection of 4 keys (the
+    second layer shares the first's), routed experts in the second layer: a
+    slot holds latent rows and index keys, and its prompt is prefilled in
+    blocks (``tests/test_glm_dsa.py`` holds the model to its reference)."""
+    return TransformerLM(
+        vocab_size=61, d_model=32, num_heads=4, num_layers=2, d_ff=16,
+        max_len=32, pos_encoding="rope", norm="rmsnorm",
+        tie_embeddings=False, seed=3, rope_interleaved=True, num_experts=4,
+        experts_per_token=2, norm_topk_prob=True, mixers=("mla",) * 2,
+        ffns=("glu", "moe"), glu_width=32,
+        mla=dict(_MLA, q_lora_rank=16, gate=False),
+        indexers=("full", "shared"),
+        dsa={"n_heads": 2, "head_dim": 8, "topk": 4, "rope_dim": 4}).init()
+
+
+def _gdn_lm(mixers=("gdn", "attn")):
+    """A Gated DeltaNet layer under a gated softmax-attention layer of two
+    kv heads 256 wide: a slot holds a recurrent state and a convolution
+    tail beside a K/V pool stored as rows (``kv_cache.pool_shape``;
+    ``tests/test_qwen3next.py`` holds the model to its reference)."""
+    return TransformerLM(
+        vocab_size=61, d_model=32, num_heads=4, num_kv_heads=2,
+        num_layers=len(mixers), d_ff=16, max_len=32, pos_encoding="rope",
+        attn_impl="xla", norm="rmsnorm", tie_embeddings=False, seed=3,
+        mixers=mixers, ffns=("glu",) * len(mixers), glu_width=32,
+        gdn={"key_heads": 2, "value_heads": 4, "head_dim": 8, "conv": 4},
+        attn={"head_dim": 256, "rotary_dim": 8, "head_norm": True,
+              "gate": True}).init()
+
+
+# what a slot holds and how its prompt is admitted: the model, the tokens one
+# dispatch can hold for a slot at most, and the positions of a prefill block
+# where the test sets them (None: the engine's, one block a rung here)
+KINDS = {
+    "dense": (lambda: _lm("rope", max_len=32), 1, None),
+    "routed": (_routed_lm, 1, None),
+    "module": (_module_lm, 2, None),
+    "hybrid": (_hybrid_lm, 1, None),
+    "sparse": (_sparse_lm, 1, 8),
+    "gdn": (_gdn_lm, 1, None),
+    # a seventh: K/V under learned positions and a sliding window of 8
+    "window": (lambda: _lm(max_len=32, attn_window=8), 1, None),
+}
+
+
+def _kind(monkeypatch, kind):
+    """``KINDS[kind]``'s model and most tokens a dispatch, with the
+    engine's prefill block set to the kind's."""
+    from deeplearning4j_tpu.serving import engine as eng
+
+    make, most, block = KINDS[kind]
+    if block is not None:
+        monkeypatch.setattr(eng, "PREFILL_BLOCK", block)
+    return make(), most
 
 
 # ---------------------------------------------------------------------------
@@ -848,16 +671,17 @@ class TestSpeculativeDecode:
 def _slab_step_reference(model, params, kv, toks, positions):
     """The decode-family forward as it was before PR 24, kept here as the
     reference: each layer's ``[S, T, Hkv, Dh]`` slab is sliced out of the
-    pool, ``requant_write_slab`` scatters into the copy, attention reads
-    it, and the slabs are stacked back into a fresh pool. ``toks`` and
-    ``positions`` are ``[S, Q]``. Returns ``(logits [S, Q, V], pool)``."""
+    pool, the new rows are scattered into the copy, attention reads it, and
+    the slabs are stacked back into a fresh pool (of the shape it came in:
+    five axes, or the rows of wide heads). ``toks`` and ``positions`` are
+    ``[S, Q]``. Returns ``(logits [S, Q, V], pool)``."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.ops.attention import grouped_query_attention
-    from deeplearning4j_tpu.serving.kv_cache import (
-        dequant_slab, requant_write_slab)
 
     cdt = model.policy.compute_dtype
-    t_max = kv["k"].shape[2]
+    slots, hkv = toks.shape[0], model.num_kv_heads
+    slab_shape = (slots, -1, hkv, kv["k"].shape[-1])
+    t_max = kv["k"][0].reshape(slab_shape).shape[1]
     h = jnp.take(params["embed"], toks, axis=0)
     if model.pos_encoding == "learned":
         h = h + params["pos"][positions]
@@ -866,21 +690,18 @@ def _slab_step_reference(model, params, kv, toks, positions):
     if model.attn_window is not None:
         live &= (jnp.arange(t_max)[None, None, :]
                  > positions[:, :, None] - model.attn_window)
-    rows = jnp.arange(toks.shape[0])
+    rows = jnp.arange(slots)
     out = {name: [] for name in kv}
 
     def cached_attention(li):
         def attn(q, kk, vv):
             slabs = []
             for name, new in (("k", kk), ("v", vv)):
-                scale = kv.get(name + "_scale")
-                slab, scale = requant_write_slab(
-                    kv[name][li], None if scale is None else scale[li],
-                    new, rows, positions)
-                out[name].append(slab)
-                if scale is not None:
-                    out[name + "_scale"].append(scale)
-                slabs.append(dequant_slab(slab, scale, cdt))
+                slab = kv[name][li].reshape(slab_shape)
+                slab = slab.at[rows[:, None], positions].set(
+                    new.astype(slab.dtype))
+                out[name].append(slab.reshape(kv[name].shape[1:]))
+                slabs.append(slab.astype(cdt))
             return grouped_query_attention(q, *slabs, mask=live)
         return attn
 
@@ -891,32 +712,40 @@ def _slab_step_reference(model, params, kv, toks, positions):
             {name: jnp.stack(slabs) for name, slabs in out.items()})
 
 
-def _random_pool(rng, lm, slots, max_len, kv_dtype, scale):
-    """A pool of seeded content (and, for int8, scales all ``scale``)."""
+def _random_pool(rng, lm, slots, max_len, kv_dtype):
+    """A pool of seeded content."""
     import jax.numpy as jnp
 
     cache = SlotKVCache(lm, slots, max_len, kv_dtype)
-    kv = {}
-    for name, arr in cache.state.items():
-        if name.endswith("_scale"):
-            kv[name] = jnp.full(arr.shape, scale, arr.dtype)
-        elif arr.dtype == jnp.int8:
-            kv[name] = jnp.asarray(
-                rng.integers(-127, 128, arr.shape), jnp.int8)
-        else:
-            kv[name] = jnp.asarray(rng.normal(size=arr.shape), arr.dtype)
-    return kv
+    return {name: jnp.asarray(rng.normal(size=arr.shape), arr.dtype)
+            for name, arr in cache.state.items()}
+
+
+# program x what the pool it takes holds: the model, by its kind
+ALIASED = [
+    ("decode", "dense"),        # K/V, five axes
+    ("decode", "gdn"),          # K/V as rows, a recurrent state, a tail
+    ("decode", "hybrid"),       # a recurrent matrix, a tail, latent rows
+    ("decode", "sparse"),       # latent rows, index keys
+    ("verify", "dense"),
+    ("verify", "module"),       # latent rows, the module's among them
+    ("round", "module"),
+    ("prefill_block", "sparse"),
+    ("prefill_block", "module"),
+]
 
 
 class TestInPlacePool:
     SLOTS, MAX_LEN = 3, 24
 
-    @pytest.mark.parametrize("kind", ["decode", "decode_fused", "verify"])
-    def test_program_aliases_the_pool(self, kind):
+    @pytest.mark.parametrize("program,kind", ALIASED,
+                             ids=[f"{p}-{k}" for p, k in ALIASED])
+    def test_program_aliases_the_pool(self, program, kind):
         """Compiled with the pool donated, a decode-family program's
-        output pool IS its input pool (``alias_size_in_bytes``) and its
-        temporaries stay under half a pool: before PR 24 the slabs were
-        sliced out and stacked back, one whole pool of temporaries."""
+        output pool IS its input pool — every array of the layout, whatever
+        kind (``alias_size_in_bytes``) — and its temporaries stay under
+        half a pool: before PR 24 the slabs were sliced out and stacked
+        back, one whole pool of temporaries."""
         import functools
 
         import jax
@@ -926,53 +755,65 @@ class TestInPlacePool:
         # four layers: XLA:CPU still copies one layer's K and V slabs out
         # for the attention dots, a quarter of the pool
         slots, max_len = 8, 512
-        lm = _lm("rope", max_len=max_len, num_layers=4)
-        kv = SlotKVCache(lm, slots, max_len, "float32").state
+        lm = (_lm("rope", max_len=max_len, num_layers=4) if kind == "dense"
+              else _gdn_lm(("gdn",) + ("attn",) * 4) if kind == "gdn"
+              else KINDS[kind][0]())
+        cache = SlotKVCache(lm, slots, max_len, "float32")
+        kv = cache.state
+        assert sum(len(v) if isinstance(v, list) else 1 for v in kv.values(
+            )) == sum(map(len, kv_cache.pool_layout(
+                lm, slots, max_len, "float32").values()))
         vec = jnp.zeros(slots, jnp.int32)
         keys = jnp.zeros((slots, 2), jnp.uint32)
         sampler = eng._row_sampler(0.0, None)
-        if kind == "decode":
+        donate = (1,)
+        if program == "decode":
             fn = functools.partial(eng._serve_decode_impl, lm, sampler)
-            args, donate = (lm.params, kv, vec, vec, keys), (1,)
-        elif kind == "decode_fused":
-            fn = functools.partial(eng._serve_decode_fused_impl, lm,
-                                   sampler, 2)
-            loop = {"cursors": vec, "tok": vec, "remaining": vec,
-                    "keys": keys}
-            args, donate = (lm.params, kv, loop), (1,)
-        else:
+            args = (lm.params, kv, vec, vec, keys, vec > 0)
+        elif program == "verify":
             fn = functools.partial(eng._serve_verify_impl, lm)
             pos = jnp.zeros((slots, 3), jnp.int32)
-            args, donate = (lm.params, kv, pos, pos), (1,)
+            args = (lm.params, kv, pos, pos)
+        elif program == "round":
+            fn = functools.partial(eng._serve_mtp_impl, lm, None, True, 1)
+            args = (lm.params, kv, cache.loop)
+        else:
+            rung = 64
+            fn = functools.partial(eng._serve_prefill_block_impl, lm,
+                                   sampler)
+            carry = {name: jnp.full(shape, fill, jnp.dtype(dt))
+                     for name, (shape, dt, fill) in
+                     eng.prefill_carry_layout(lm, rung).items()}
+            args = (lm.params, kv, carry, jnp.zeros((1, rung), jnp.int32),
+                    jnp.int32(40), jnp.int32(1), keys[0], jnp.int32(0))
+            donate = (1, 2)
         mem = jax.jit(fn, donate_argnums=donate).lower(
             *args).compile().memory_analysis()
-        pool = sum(int(a.nbytes) for a in kv.values())
+        pool = cache.nbytes
         donated = sum(int(leaf.nbytes) for i in donate
                       for leaf in jax.tree_util.tree_leaves(args[i]))
         assert mem.alias_size_in_bytes == donated >= pool
         assert mem.temp_size_in_bytes < pool // 2, (
             mem.temp_size_in_bytes, pool)
 
-    @pytest.mark.parametrize("kv_dtype,scale", [
-        ("float32", None), ("bfloat16", None),
-        ("int8", 0.0),      # every write's absmax grows the scale
-        ("int8", 1e3),      # no write does: the scatter-only branch
-    ])
+    @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", ["axes", "rows"])
     @pytest.mark.parametrize("queries", [1, 3])
-    def test_bitwise_equal_to_the_slab_formulation(self, rng, kv_dtype,
-                                                   scale, queries):
+    def test_bitwise_equal_to_the_slab_formulation(self, rng, layout,
+                                                   kv_dtype, queries):
         """Scattering into the pool and attending to ``pool[li]`` is the
         old slice / scatter / stack step bit for bit: logits and the
-        returned pool, one query a slot (decode) and several (verify).
-        Slot 1 is frozen past ``T_max``: its write is dropped and, unless
-        a grown int8 scale requantizes them, its rows come back
-        untouched."""
+        returned pool, one query a slot (decode) and several (verify), the
+        pool in its five axes or stored as the rows of heads 256 wide.
+        Slot 1 is frozen past ``T_max``: its write is dropped and its rows
+        come back untouched."""
         import jax.numpy as jnp
         from deeplearning4j_tpu.serving import engine as eng
 
-        lm = _lm("rope", attn_window=16)
-        kv = _random_pool(rng, lm, self.SLOTS, self.MAX_LEN, kv_dtype,
-                          scale)
+        lm = (_lm("rope", attn_window=16) if layout == "axes"
+              else _gdn_lm(("attn", "attn")))
+        kv = _random_pool(rng, lm, self.SLOTS, self.MAX_LEN, kv_dtype)
+        assert kv["k"].ndim == (5 if layout == "axes" else 4)
         first = jnp.asarray([5, self.MAX_LEN, self.MAX_LEN - queries])
         positions = first[:, None] + jnp.arange(queries)[None, :]
         toks = jnp.asarray(
@@ -992,14 +833,116 @@ class TestInPlacePool:
             got = np.asarray(new_kv[name].astype(jnp.float32))
             assert np.array_equal(
                 got, np.asarray(want_kv[name].astype(jnp.float32))), name
-            if scale != 0.0:
-                assert np.array_equal(
-                    got[:, 1],
-                    np.asarray(kv[name].astype(jnp.float32))[:, 1])
-        if scale == 0.0:
-            assert (np.asarray(new_kv["k_scale"])[:, 0] > 0).all()
-        elif scale:
-            assert (np.asarray(new_kv["k_scale"]) == scale).all()
+            assert np.array_equal(
+                got[:, 1], np.asarray(kv[name].astype(jnp.float32))[:, 1])
+            assert not np.array_equal(
+                got[:, 0], np.asarray(kv[name].astype(jnp.float32))[:, 0])
+
+
+# ---------------------------------------------------------------------------
+# a round's accept / resample rule, held to its law
+# ---------------------------------------------------------------------------
+class TestAcceptRound:
+    """``engine._accept_round`` on hand-made logits: one caller is left
+    (the model's own module, one proposal a round), and the rule stays
+    general in the number of proposals."""
+
+    V = 5
+
+    @staticmethod
+    def _accept(logits, d, q, act, greedy, seed=0):
+        import jax
+        import jax.numpy as jnp
+        from deeplearning4j_tpu.serving import engine as eng
+
+        slots, gamma = d.shape
+        keys = jax.vmap(jax.random.PRNGKey)(seed + jnp.arange(slots))
+        count, corr, block, new_keys = eng._accept_round(
+            jnp.asarray(act), jnp.asarray(logits, jnp.float32),
+            jnp.asarray(d, jnp.int32), None if q is None else jnp.asarray(
+                q, jnp.float32), keys, gamma, greedy,
+            eng._filtered_logits_fn(1.0, None))
+        return (np.asarray(count), np.asarray(corr), np.asarray(block),
+                np.asarray(keys), np.asarray(new_keys))
+
+    def _peaked(self, tokens):
+        """Logits ``[len(tokens), V]`` whose argmax at row i is tokens[i]."""
+        return 5.0 * np.eye(self.V, dtype=np.float32)[list(tokens)]
+
+    def test_greedy_agreement_takes_every_proposal_and_the_bonus(self):
+        """The target's argmax equals every proposal: a round emits them
+        all and then the target's own next token, ``[count, e_1..e_G+1]``."""
+        logits = np.stack([self._peaked([1, 2, 3]), self._peaked([4, 0, 2])])
+        count, corr, block, keys, new_keys = self._accept(
+            logits, np.array([[1, 2], [4, 0]]), None, [True, True], True)
+        assert count.tolist() == [3, 3] and corr.tolist() == [3, 2]
+        assert block.tolist() == [[3, 1, 2, 3], [3, 4, 0, 2]]
+        assert np.array_equal(keys, new_keys)      # greedy draws nothing
+
+    def test_greedy_disagreement_cuts_at_the_first_miss(self):
+        """A proposal the target's argmax differs from ends the accepted
+        prefix: the target's token takes its place, what the draft proposed
+        after it is dropped even where it agrees again, and the block's
+        rows past ``count`` are zeros."""
+        logits = np.stack([self._peaked([1, 2, 3]), self._peaked([1, 2, 3]),
+                           self._peaked([1, 2, 3])])
+        d = np.array([[4, 2], [1, 4], [1, 2]])
+        count, corr, block, _, _ = self._accept(
+            logits, d, None, [True] * 3, True)
+        assert count.tolist() == [1, 2, 3] and corr.tolist() == [1, 2, 3]
+        assert block.tolist() == [[1, 1, 0, 0], [2, 1, 2, 0], [3, 1, 2, 3]]
+
+    @pytest.mark.parametrize("gamma", [1, 2])
+    def test_sampled_rule_draws_from_the_target(self, gamma):
+        """Proposals drawn from ``q`` are accepted with probability
+        ``min(1, p / q)``; after the first rejection the token comes from
+        ``norm(max(p - q, 0))``; so every emitted token is distributed as
+        the target's ``p`` at its position, whatever ``q`` was. 40,000
+        slots share the distributions and differ in their keys."""
+        n, v = 40_000, self.V
+        rng = np.random.default_rng(gamma)
+        p = rng.dirichlet(np.ones(v), gamma + 1).astype(np.float32)
+        q = rng.dirichlet(np.ones(v), gamma).astype(np.float32)
+        q[0, 0] = 0.0               # a token the draft never proposes
+        q /= q.sum(-1, keepdims=True)
+        d = np.stack([rng.choice(v, n, p=q[i]) for i in range(gamma)], 1)
+        count, corr, block, keys, new_keys = self._accept(
+            np.broadcast_to(np.log(p), (n, gamma + 1, v)), d,
+            np.broadcast_to(q, (n, gamma, v)), np.ones(n, bool), False)
+        assert not np.array_equal(keys, new_keys)
+        accepted = count - 1
+        tol = 4 / np.sqrt(n)        # four standard errors of a share
+        # the first proposal's acceptance, given what was proposed
+        for x in range(v):
+            rate = (accepted[d[:, 0] == x] >= 1).mean() if q[0, x] else 0.0
+            want = min(1.0, p[0, x] / q[0, x]) if q[0, x] else 0.0
+            assert abs(rate - want) < 4 * tol, (x, rate, want)
+        # the token after a first-position rejection is the residual's
+        res = np.maximum(p[0] - q[0], 0.0)
+        got = np.bincount(corr[accepted == 0], minlength=v) / max(
+            1, (accepted == 0).sum())
+        assert np.abs(got - res / res.sum()).max() < 4 * tol
+        # and every position's emitted token is the target's p there
+        for i in range(gamma + 1):
+            took = count > i
+            got = np.bincount(block[took, 1 + i], minlength=v) / took.sum()
+            assert np.abs(got - p[i]).max() < 4 * tol, (i, got, p[i])
+        assert np.array_equal(block[:, 0], count)
+        assert np.array_equal(corr, block[np.arange(n), count])
+
+    @pytest.mark.parametrize("greedy", [True, False],
+                             ids=["greedy", "sampled"])
+    def test_a_frozen_slot_emits_nothing(self, greedy):
+        """A slot that owes no token (``act`` false) counts 0 whatever its
+        row would have accepted: the host takes none of its block, and the
+        slot beside it is not disturbed."""
+        logits = np.stack([self._peaked([1, 2])] * 2)
+        d = np.array([[1], [1]])
+        q = None if greedy else np.eye(self.V, dtype=np.float32)[d]
+        count, _, block, _, _ = self._accept(logits, d, q, [False, True],
+                                             greedy)
+        assert count.tolist() == [0, 2] and block[:, 0].tolist() == [0, 2]
+        assert block[1].tolist() == [2, 1, 2]
 
 
 # ---------------------------------------------------------------------------
@@ -1007,12 +950,15 @@ class TestInPlacePool:
 # ---------------------------------------------------------------------------
 class SyncLoop:
     """The straightforward loop the pipelined server is held to: FIFO
-    admission into free slots, then ONE decode dispatch per step from HOST
-    arrays (last tokens, cursors, keys, the live mask) through the one-step
-    program body, one blocking read, the cursors advanced by the host —
-    nothing but the pool kept on the device. Every request keeps its
-    tokens, its routing rows and the key its stream held after each
-    token."""
+    admission into free slots (the whole prompt at once: every block of a
+    prompt that is prefilled in blocks, back to back), then ONE decode
+    dispatch per step from HOST arrays (last tokens, cursors, keys, the live
+    mask; for a model that drafts from its own module also the drafts and
+    the tokens owed) through the one-step program body — or the one-round
+    body — one blocking read, the cursors advanced by the host — nothing
+    but the pool kept on the device. Every request keeps its tokens, its
+    routing rows, its selections, the drafts its rounds verified and the key
+    its stream held after each token."""
 
     def __init__(self, lm, slots, max_len, **engine_kw):
         import functools
@@ -1024,11 +970,19 @@ class SyncLoop:
         self.lm, self.slots = lm, slots
         self.engine = eng.DecodeEngine(lm, slots, max_len=max_len,
                                        **engine_kw)
-        self.run = jax.jit(functools.partial(
-            eng._serve_decode_impl, lm, self.engine._sample_row))
+        if lm.mtp:
+            greedy = self.engine.temperature == 0.0
+            self.run = jax.jit(functools.partial(
+                eng._serve_mtp_impl, lm,
+                None if greedy else eng._filtered_logits_fn(
+                    self.engine.temperature, self.engine.top_k), greedy, 1))
+        else:
+            self.run = jax.jit(functools.partial(
+                eng._serve_decode_impl, lm, self.engine._sample_row))
         self.tok = np.zeros(slots, np.int32)
         self.cursors = np.zeros(slots, np.int32)
         self.keys = np.zeros((slots, 2), np.uint32)
+        self.draft = np.zeros(slots, np.int32)
         self.req = [None] * slots
         self.queue = deque()
 
@@ -1036,54 +990,97 @@ class SyncLoop:
         from types import SimpleNamespace
 
         req = SimpleNamespace(prompt=prompt, max_new=max_new, seed=seed,
-                              tokens=[], keys=[], routing=[])
+                              tokens=[], keys=[], routing=[], selection=[],
+                              drafts=[] if self.lm.mtp else None)
         self.queue.append(req)
         return req
 
     def _rows(self, packed):
         from deeplearning4j_tpu.serving.engine import unpack_routing
 
-        return unpack_routing(np.asarray(packed), self.lm.num_experts,
+        return unpack_routing(np.asarray(packed), self.lm.experts_held,
                               self.lm.experts_per_token)[1:3]
 
-    def _took(self, slot, req, tok, key, rows):
-        req.tokens.append(int(tok))
-        req.keys.append(np.asarray(key))
+    def _took(self, slot, req, toks, key, rows):
+        req.tokens += [int(t) for t in toks]
+        req.keys += [np.asarray(key)] * len(toks)
         if rows is not None:
             req.routing.append(rows)
-        self.tok[slot], self.keys[slot] = tok, key
-        self.req[slot] = req if len(req.tokens) < req.max_new else None
+        self.tok[slot], self.keys[slot] = toks[-1], key
+        if len(req.tokens) >= req.max_new:
+            self.req[slot] = None
+
+    def _admit(self, slot, req):
+        import jax
+
+        tok, key, record = self.engine.prefill(
+            req.prompt, slot, jax.random.PRNGKey(req.seed))
+        routing, selection = (record if isinstance(record, tuple)
+                              else (record, None))
+        n = len(req.prompt)
+        self.cursors[slot], self.req[slot] = n, req
+        if self.lm.mtp:
+            self.draft[slot] = self.engine._first_draft.pop(slot)
+        if selection is not None:
+            req.selection.append(np.asarray(selection)[:, None])
+        self._took(slot, req, [tok], key, None if routing is None else
+                   tuple(a[:, :n] for a in self._rows(routing)))
 
     def step(self):
-        import jax
         import jax.numpy as jnp
 
         for slot in range(self.slots):
             if self.req[slot] is None and self.queue:
-                req = self.queue.popleft()
-                tok, key, packed = self.engine.prefill(
-                    req.prompt, slot, jax.random.PRNGKey(req.seed))
-                n = len(req.prompt)
-                self.cursors[slot] = n
-                self._took(slot, req, tok, key, None if packed is None else
-                           tuple(a[:, :n] for a in self._rows(packed)))
+                self._admit(slot, self.queue.popleft())
         live = np.array([r is not None for r in self.req])
         if not live.any():
             return bool(self.queue)
-        args = [self.lm.params, self.engine.cache.state,
-                jnp.asarray(self.tok), jnp.asarray(self.cursors),
-                jnp.asarray(self.keys)]
-        if self.lm.num_experts:
-            args.append(jnp.asarray(live))
-        toks, keys, state, *packed = self.run(*args)
+        state = self.engine.cache.state
+        if self.lm.mtp:
+            return self._round(state, live)
+        toks, keys, state, *extra = self.run(
+            self.lm.params, state, jnp.asarray(self.tok),
+            jnp.asarray(self.cursors), jnp.asarray(self.keys),
+            jnp.asarray(live))
         self.engine.cache.install(state)
         toks, keys = np.asarray(toks), np.asarray(keys)
-        rows = self._rows(packed[0]) if packed else None
+        rows = self._rows(extra[0]) if self.lm.num_experts else None
+        selection = np.asarray(extra[1]) if self.lm.dsa else None
         for slot in np.flatnonzero(live):
-            self._took(slot, self.req[slot], toks[slot], keys[slot],
+            if selection is not None:
+                self.req[slot].selection.append(selection[:, slot:slot + 1])
+            self._took(slot, self.req[slot], toks[slot:slot + 1], keys[slot],
                        None if rows is None else
                        tuple(a[:, slot:slot + 1] for a in rows))
             self.cursors[slot] += 1
+        return True
+
+    def _round(self, state, live):
+        """One round for the live slots: what ``step`` does for a model
+        that drafts from its own module."""
+        import jax.numpy as jnp
+
+        owed = np.array([0 if r is None else r.max_new - len(r.tokens)
+                         for r in self.req], np.int32)
+        blocks, loop, state, routing = self.run(self.lm.params, state, {
+            "cursors": jnp.asarray(self.cursors), "tok": jnp.asarray(self.tok),
+            "remaining": jnp.asarray(owed), "keys": jnp.asarray(self.keys),
+            "draft": jnp.asarray(self.draft)})
+        self.engine.cache.install(state)
+        block, keys = np.asarray(blocks)[0], np.asarray(loop["keys"])
+        rows = self._rows(np.asarray(routing)[0])
+        self.draft = np.array(loop["draft"])
+        for slot in np.flatnonzero(live):
+            req, count = self.req[slot], int(block[slot, 0])
+            take = min(count, int(owed[slot]))
+            req.drafts.append((len(req.prompt) + len(req.tokens),
+                               int(block[slot, 3])))
+            self.cursors[slot] += count
+            self._took(slot, req, block[slot, 1:1 + take], keys[slot], tuple(
+                a.reshape(a.shape[0], self.slots, 2, -1)[:, slot, :take]
+                for a in rows))
+            # the device's last token is the round's, taken or not
+            self.tok[slot] = block[slot, count]
         return True
 
     def drain(self):
@@ -1097,18 +1094,6 @@ class ManualClock:
 
     def __call__(self):
         return self.t
-
-
-def _routed_lm():
-    """OLMoE's block, tiny: RMSNorm, QK-norm, untied head, RoPE, 4 SwiGLU
-    experts with 2 a token (float32: a row depends on that row alone, bit
-    for bit, whatever the batch holds)."""
-    return TransformerLM(vocab_size=61, d_model=32, num_heads=4,
-                         num_layers=2, d_ff=16, max_len=32,
-                         pos_encoding="rope", attn_impl="xla",
-                         norm="rmsnorm", qk_norm=True, num_experts=4,
-                         experts_per_token=2, tie_embeddings=False,
-                         seed=3).init()
 
 
 def _executions(fn):
@@ -1134,31 +1119,6 @@ def _executions(fn):
                    for ev in line.events)
 
 
-def _module_lm():
-    """Two latent-attention layers, routed experts in the second, and a
-    multi-token-prediction module the server drafts from (float32;
-    ``tests/test_gigachat_mtp.py`` holds the model to its reference)."""
-    return TransformerLM(
-        vocab_size=61, d_model=32, num_heads=4, num_layers=2, d_ff=16,
-        max_len=32, pos_encoding="rope", norm="rmsnorm",
-        tie_embeddings=False, seed=3, num_experts=8, experts_per_token=2,
-        norm_topk_prob=True, mixers=("mla",) * 2, ffns=("glu", "moe"),
-        glu_width=32, mtp={"loss_weight": 0.3},
-        mla={"q_lora_rank": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
-             "qk_rope_head_dim": 8, "v_head_dim": 12, "gate": False}).init()
-
-
-# every kind of decode block: the model, what the server is built with, and
-# the tokens one dispatch can hold for a slot at most
-KINDS = {
-    "plain": (_routed_lm, {}, 1),
-    "fused": (lambda: _lm("rope", max_len=32), {"fuse_steps": 4}, 4),
-    "draft": (lambda: _lm("rope", max_len=32),
-              {"draft_layers": 1, "spec_tokens": 2}, 3),
-    "module": (_module_lm, {}, 2),
-}
-
-
 class TestPipelinedLoop:
     SLOTS, MAX_LEN, BUCKETS = 3, 32, (8, 16, 32)
     # (prompt length, max_new_tokens): ends at admission (1), after one
@@ -1178,25 +1138,45 @@ class TestPipelinedLoop:
         return SyncLoop(lm, self.SLOTS, self.MAX_LEN,
                         buckets=self.BUCKETS, **sampling)
 
+    def _work(self, rng, lm):
+        """``EARLY + LATE`` as ``(prompt, max_new_tokens, seed)``; a round
+        verifies a position past the end, which a request leaves free."""
+        slack = 1 if lm.mtp else 0
+        return [(p, min(m, self.MAX_LEN - len(p) - slack), seed)
+                for seed, (p, m) in enumerate(zip(
+                    _prompts(rng, [n for n, _ in self.EARLY + self.LATE]),
+                    [m for _, m in self.EARLY + self.LATE]))]
+
     @staticmethod
-    def _same_routing(req, want):
-        got = [np.concatenate(x, axis=1) for x in zip(*req.routing)]
-        ref = [np.concatenate(x, axis=1) for x in zip(*want.routing)]
-        assert got[0].shape[1] == len(req.prompt) + len(req.tokens) - 1
-        for g, w in zip(got, ref):
-            assert np.array_equal(g, w)
+    def _same_record(req, want):
+        """The routing rows, the selections and the drafts a request kept
+        (``record_routing``) are ``want``'s."""
+        assert req.drafts == want.drafts
+        assert bool(req.routing) == bool(want.routing)
+        if req.routing:     # a row a position but the last token's
+            got = [np.concatenate(x, axis=1) for x in zip(*req.routing)]
+            ref = [np.concatenate(x, axis=1) for x in zip(*want.routing)]
+            assert got[0].shape[1] == len(req.prompt) + len(req.tokens) - 1
+            for g, w in zip(got, ref):
+                assert np.array_equal(g, w)
+        assert bool(req.selection) == bool(want.selection)
+        if req.selection:   # of the query that emitted each token
+            got = np.concatenate(req.selection, axis=1)
+            assert got.shape[1] == len(req.tokens)
+            assert np.array_equal(got, np.concatenate(want.selection, axis=1))
 
     @pytest.mark.parametrize("sampled", [False, True],
                              ids=["greedy", "sampled"])
-    @pytest.mark.parametrize("model", ["dense", "routed"])
-    def test_equals_the_synchronous_loop(self, rng, model, sampled):
-        """Token for token, key for key and routing row for routing row
-        what the synchronous loop gives the same requests in the same
-        admission order, with admissions mid-stream."""
-        lm = _lm("rope", max_len=32) if model == "dense" else _routed_lm()
-        work = [(p, m, seed) for seed, (p, m) in enumerate(
-            zip(_prompts(rng, [n for n, _ in self.EARLY + self.LATE]),
-                [m for _, m in self.EARLY + self.LATE]))]
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_equals_the_synchronous_loop(self, rng, monkeypatch, kind,
+                                         sampled):
+        """Token for token, key for key, routing row for routing row,
+        selection for selection and draft for draft what the synchronous
+        loop gives the same requests in the same admission order, with
+        admissions mid-stream — whatever a slot holds, a prompt admitted
+        whole or a block a step."""
+        lm, _ = _kind(monkeypatch, kind)
+        work = self._work(rng, lm)
         ref = self._reference(lm, sampled)
         want = [ref.submit(p, m, seed) for p, m, seed in work]
         ref.drain()
@@ -1208,7 +1188,7 @@ class TestPipelinedLoop:
             srv.step()
         # mid-stream, with a block unread: the device's keys are those of
         # every DISPATCHED token, one ahead of the tokens the host holds
-        assert srv._unread is not None
+        assert srv._unread is not None and srv._owed()
         for slot, req in srv._owed().items():
             _, _, key = srv.engine.slot_state(slot)
             assert np.array_equal(key, want[reqs.index(req)].keys[
@@ -1222,22 +1202,34 @@ class TestPipelinedLoop:
             assert req.state == "finished"
             assert req.tokens == ref_req.tokens
             if lm.num_experts:
-                self._same_routing(req, ref_req)
+                self._same_record(req, ref_req)
         # a slot's key froze with its last request's last token
         last = {r.slot: i for i, r in enumerate(reqs)}
         for slot, i in last.items():
             cursor, tok, key = srv.engine.slot_state(slot)
-            assert np.array_equal(key, want[i].keys[-1])
-            assert tok == reqs[i].tokens[-1]
-            assert cursor == len(reqs[i].prompt) + len(reqs[i].tokens) - 1
+            end = len(reqs[i].prompt) + len(reqs[i].tokens) - 1
+            if lm.mtp:
+                # a draft accepted past the end moves the cursor on, and a
+                # sampled round splits every slot's key, frozen or not
+                assert cursor in (end, end + 1)
+            else:
+                assert np.array_equal(key, want[i].keys[-1])
+                assert (cursor, tok) == (end, reqs[i].tokens[-1])
         assert srv.steps == srv.stats()["decode_dispatches"]
 
-    @pytest.mark.parametrize("model", ["dense", "routed"])
-    def test_deadline_and_cancel_with_a_block_in_flight(self, rng, model):
+    def _until_all_live(self, srv):
+        """Step until a block dispatched for all three slots is unread (the
+        prompts of a model prefilled in blocks enter one after another)."""
+        while not (srv._unread and set(srv._unread[2]) == {0, 1, 2}):
+            srv.step()
+
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_deadline_and_cancel_with_a_block_in_flight(self, rng,
+                                                        monkeypatch, kind):
         """A request shed on its deadline, and one canceled, while a block
         holding their next token is unread: the token is dropped, the
         slots stop decoding and are re-used, the others never notice."""
-        lm = _lm("rope", max_len=32) if model == "dense" else _routed_lm()
+        lm, _ = _kind(monkeypatch, kind)
         prompts = _prompts(rng, (5, 9, 7, 6, 4))
         ref = self._reference(lm, False)
         want = [ref.submit(p, 8, s) for s, p in enumerate(prompts)]
@@ -1248,7 +1240,7 @@ class TestPipelinedLoop:
         keep = srv.submit(prompts[0], 8, seed=0)
         late = srv.submit(prompts[1], 8, seed=1, deadline_s=5.0)
         loser = srv.submit(prompts[2], 8, seed=2)
-        srv.step()
+        self._until_all_live(srv)
         srv.step()
         assert set(srv._unread[2]) == {0, 1, 2}
         held = [len(r.tokens) for r in (late, loser)]
@@ -1266,7 +1258,7 @@ class TestPipelinedLoop:
             assert req.state == "finished"
             assert req.tokens == ref_req.tokens
             if lm.num_experts:
-                self._same_routing(req, ref_req)
+                self._same_record(req, ref_req)
 
     @staticmethod
     def _run(srv, submit, sync):
@@ -1294,24 +1286,19 @@ class TestPipelinedLoop:
     @pytest.mark.parametrize("sampled", [False, True],
                              ids=["greedy", "sampled"])
     @pytest.mark.parametrize("kind", list(KINDS))
-    def test_every_kind_is_read_one_dispatch_behind(self, rng, kind,
-                                                    sampled):
-        """A plain step, K fused steps, rounds of a separate draft and
-        rounds of the model's own module: dispatched while the block before
-        is unread, a server gives every request the tokens, the drafts and
-        the routing rows of the synchronous order, with admissions
-        mid-stream, slots re-used and requests that end inside a block."""
-        make, kw, most = KINDS[kind]
-        lm = make()
-        # the rounds verify up to ``spec_tokens`` positions past the end
-        slack = kw.get("spec_tokens", 1 if kind == "module" else 0)
-        work = [(p, min(m, self.MAX_LEN - len(p) - slack), seed)
-                for seed, (p, m) in enumerate(zip(
-                    _prompts(rng, [n for n, _ in self.EARLY + self.LATE]),
-                    [m for _, m in self.EARLY + self.LATE]))]
+    def test_every_kind_is_read_one_dispatch_behind(self, rng, monkeypatch,
+                                                    kind, sampled):
+        """A plain step over whatever a slot holds, and a round of the
+        model's own module: dispatched while the block before
+        is unread, a server gives every request the tokens, the drafts, the
+        selections and the routing rows of the synchronous order, with
+        admissions mid-stream, slots re-used and requests that end inside a
+        block."""
+        lm, most = _kind(monkeypatch, kind)
+        work = self._work(rng, lm)
         submit = [work[:len(self.EARLY)], [], [], [], work[len(self.EARLY):]]
-        want, sync = self._run(self._server(lm, sampled, **kw), submit, True)
-        srv = self._server(lm, sampled, **kw)
+        want, sync = self._run(self._server(lm, sampled), submit, True)
+        srv = self._server(lm, sampled)
         reqs, spans = self._run(srv, submit, False)
         assert not any(sp["ahead"] for sp in sync) and sum(
             sp["ahead"] for sp in spans) >= len(spans) - 4
@@ -1320,27 +1307,28 @@ class TestPipelinedLoop:
             assert req.state == "finished"
             assert len(req.tokens) == req.max_new_tokens
             assert req.tokens == ref_req.tokens
-            assert req.drafts == ref_req.drafts
-            if lm.num_experts:
-                self._same_routing(req, ref_req)
+            self._same_record(req, ref_req)
         assert (kind == "module") == (reqs[0].drafts is not None)
+        assert (kind == "sparse") == (reqs[0].selection is not None)
         # the host's cursors are the device's once every block is read,
         # and the slots a dispatch served are no fewer than the rows that
         # yielded a token
         assert np.array_equal(srv._cursors,
                               np.asarray(srv.engine.cache.loop["cursors"]))
         st = srv.stats()
+        if kind == "sparse":    # prompts of two and of three blocks
+            assert st["prefill_blocks"] > len(work)
         assert st["decode_tokens"] == sum(m - 1 for _, m, _ in work)
         assert st["decode_tokens"] <= most * srv.slot_dispatches
         if not srv.engine.spec:
             assert st["empty_dispatches"] == 0
 
     @pytest.mark.parametrize("kind", list(KINDS))
-    def test_ahead_of_a_lone_request(self, rng, kind):
+    def test_ahead_of_a_lone_request(self, rng, monkeypatch, kind):
         """``ahead`` reads 0, 1, 1, ..., 0 on every kind: the first
         dispatch had no block before it, and the last span only reads."""
-        make, kw, most = KINDS[kind]
-        srv = self._server(make(), False, **kw)
+        lm, most = _kind(monkeypatch, kind)
+        srv = self._server(lm, False)
         _, spans = self._run(srv, [[(_prompts(rng, (5,))[0], 21, 0)]], False)
         n = srv.stats()["decode_dispatches"]
         assert n >= -(-20 // most) and len(spans) == n + 1
@@ -1349,29 +1337,27 @@ class TestPipelinedLoop:
         assert [sp["kind"] for sp in spans] == [srv._decode_kind] * (n + 1)
         assert srv.stats()["decode_ahead_share"] == round((n - 1) / n, 4)
 
-    @pytest.mark.parametrize("kind", [k for k in KINDS if k != "plain"])
-    def test_sweep_and_cancel_with_fused_steps_or_rounds_unread(self, rng,
-                                                                 kind):
-        """``test_deadline_and_cancel_with_a_block_in_flight`` for the
-        blocks that hold more than a token a slot: the rows of a request
-        shed on its deadline and of one canceled, in a block that is
-        unread, are dropped; the requests admitted into their slots before
-        that block is read take none of its tokens."""
-        make, kw, most = KINDS[kind]
-        lm = make()
+    @pytest.mark.parametrize("kind", list(KINDS))
+    def test_sweep_and_cancel_with_steps_or_rounds_unread(self, rng,
+                                                          monkeypatch, kind):
+        """``test_deadline_and_cancel_with_a_block_in_flight`` against a
+        server of its own kind, so that the drafts and the cursors are held
+        too: the rows of a request shed on its deadline and of one
+        canceled, in a block that is unread, are dropped; the requests
+        admitted into their slots before that block is read take none of
+        its tokens, and the slots' cursors are theirs."""
+        lm, most = _kind(monkeypatch, kind)
         prompts = _prompts(rng, (5, 9, 7, 6, 4))
-        ref = self._server(lm, False, **kw)
+        ref = self._server(lm, False)
         want = [ref.submit(p, 14, seed=s) for s, p in enumerate(prompts)]
         ref.drain()
 
         clock = ManualClock()
-        srv = self._server(lm, False, clock=clock, **kw)
+        srv = self._server(lm, False, clock=clock)
         keep = srv.submit(prompts[0], 14, seed=0)
         late = srv.submit(prompts[1], 14, seed=1, deadline_s=5.0)
         loser = srv.submit(prompts[2], 14, seed=2)
-        # the module's prompts enter a block a step, one after another
-        while not (srv._unread and set(srv._unread[2]) == {0, 1, 2}):
-            srv.step()
+        self._until_all_live(srv)
         srv.step()
         assert set(srv._unread[2]) == {0, 1, 2}
         held = [len(r.tokens) for r in (late, loser)]
@@ -1400,23 +1386,22 @@ class TestPipelinedLoop:
                 req.slot] == len(req.prompt) + len(req.tokens) - 1
 
     @pytest.mark.parametrize("kind", list(KINDS))
-    def test_a_slot_is_free_once_its_last_block_is_dispatched(self, rng,
-                                                              kind):
+    def test_a_slot_is_free_once_its_last_block_is_dispatched(
+            self, rng, monkeypatch, kind):
         """Reading a block one dispatch behind costs a request's slot
         nothing: the slot is free when the block that is certain to end
         its request is dispatched, so the next tenant enters at the step
         it enters in the synchronous order, while that block is unread;
         the request that left books its last tokens, its TPOT from its
         own clock, and retires one read later."""
-        make, kw, most = KINDS[kind]
-        lm = make()
+        lm, most = _kind(monkeypatch, kind)
         pa, pb = _prompts(rng, (5, 7))
         tpot = metrics().histogram("serve_tpot_seconds")
 
         def run(sync):
             clock = ManualClock()
             srv = DecodeServer(lm, slots=1, max_len=self.MAX_LEN,
-                               buckets=self.BUCKETS, clock=clock, **kw)
+                               buckets=self.BUCKETS, clock=clock)
             a = srv.submit(pa, 1 + 2 * most, seed=0)
             b = srv.submit(pb, 4, seed=1)
             while srv.busy():
@@ -1448,6 +1433,21 @@ class TestPipelinedLoop:
         # an observation a token, summing to each request's decode span
         assert spent == pytest.approx(sum(
             r.finish_s - r.first_token_s for r in (a0, b0, a, b)))
+
+    def test_a_round_needs_a_row_past_the_last_position(self, rng):
+        """A round writes its draft's row one past the cursor, accepted or
+        not: ``submit()`` keeps that row free of the slot's capacity for a
+        model that decodes in rounds, and for no other."""
+        prompt = _prompts(rng, (20,))[0]
+        srv = self._server(_module_lm(), False)
+        with pytest.raises(ValueError, match="speculative slack"):
+            srv.submit(prompt, 12)                  # 32 + 1 > 32
+        req = srv.submit(prompt, 11)
+        srv.drain()
+        assert req.state == "finished" and len(req.tokens) == 11
+        assert srv.engine.slot_state(req.slot)[0] <= self.MAX_LEN - 1
+        plain = self._server(_lm("rope", max_len=32), False)
+        assert plain.submit(prompt, 12) is not None
 
     def test_a_swept_slot_stops_decoding_on_the_device(self, rng):
         """The lone request is canceled with its next token unread:
@@ -1501,7 +1501,7 @@ class TestPipelinedLoop:
         for _ in range(3):
             srv.step()
         probe = (srv.engine.decode if kind == "plain"
-                 else lambda: srv.engine.decode_spec(1))
+                 else srv.engine.decode_spec)
         if _executions(probe) != 1:
             pytest.skip("this jax's CPU trace does not show launches")
         srv.step()          # books the extra block's token too
@@ -1535,13 +1535,6 @@ class TestPipelinedLoop:
         # 40 dispatches, the first not ahead; one more span reads the last
         assert [sp.attrs["ahead"] for sp in decode] == [0] + [1] * 39 + [0]
         assert [sp.attrs["live"] for sp in decode] == [1] * 40 + [0]
-        # the fused path is read one dispatch behind too: two dispatches
-        # of four steps for eight tokens, the second ahead
-        fused = DecodeServer(lm, slots=2, max_len=64, fuse_steps=4)
-        fused.submit(_prompts(rng, (5,))[0], 9)
-        fused.drain()
-        assert fused.stats()["decode_dispatches"] == 2
-        assert fused.stats()["decode_ahead_share"] == 0.5
 
 
 class TestKernelRead:
@@ -1563,9 +1556,9 @@ class TestKernelRead:
         srv = DecodeServer(lm, slots=self.SLOTS, max_len=self.MAX_LEN,
                            buckets=self.BUCKETS, **kw)
         # the decode programs are built at the first dispatch
-        srv.engine._decode_jit = lambda donate, impl, *bound: (
+        srv.engine._decode_jit = lambda impl, *bound: (
             eng.DecodeEngine._decode_jit(
-                srv.engine, donate,
+                srv.engine,
                 functools.partial(impl, pool_kernel=kernel), *bound))
         return srv
 
@@ -1593,12 +1586,10 @@ class TestKernelRead:
         assert c.slot == a.slot and b.state == c.state == "finished"
         return [list(r.tokens) for r in (a, b, c)]
 
-    @pytest.mark.parametrize("kind", ["plain", "fused"])
     def test_tokens_before_and_after_a_slot_changes_tenant(
-            self, monkeypatch, kind):
-        kw = {"fuse_steps": 3} if kind == "fused" else {}
-        want = self._serve(5, self._server(monkeypatch, False, **kw))
-        srv = self._server(monkeypatch, True, **kw)
+            self, monkeypatch):
+        want = self._serve(5, self._server(monkeypatch, False))
+        srv = self._server(monkeypatch, True)
         assert self._serve(5, srv, poison=True) == want
         # the host's cursors are the device's, and what the kernel read
         # is less than every slot's span: the long tenant's frozen cursor

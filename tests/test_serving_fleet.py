@@ -51,6 +51,18 @@ def _lm(key="greedy", **kw):
     return _LM_CACHE[key]
 
 
+def _module_lm():
+    """Latent attention, routed experts and a multi-token-prediction module
+    the server drafts from: its decode block is a speculative round."""
+    return _lm(
+        "module", num_kv_heads=None, d_ff=16, norm="rmsnorm",
+        tie_embeddings=False, num_experts=8, experts_per_token=2,
+        norm_topk_prob=True, mixers=("mla",) * 2, ffns=("glu", "moe"),
+        glu_width=32, mtp={"loss_weight": 0.3},
+        mla={"q_lora_rank": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+             "qk_rope_head_dim": 8, "v_head_dim": 12, "gate": False})
+
+
 def _replica(rid, lm=None, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("max_len", 64)
@@ -231,14 +243,13 @@ class TestRouterPlacement:
 class TestFailover:
     def test_greedy_continuation_token_identity(self):
         lm = _lm()
-        reps = [_replica(f"r{i}", slots=2, fuse_steps=2)
-                for i in range(2)]
+        reps = [_replica(f"r{i}", slots=2) for i in range(2)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=5.0)
         prompt = np.arange(1, 7, dtype=np.int32)
         fr = router.submit(prompt, 8)
         victim = fr.replica_id
-        router._by_id[victim].step_once()   # prefill + one fused pair
+        router._by_id[victim].step_once()   # prefill + one dispatch
         emitted_before = len(fr.tokens)
         assert 0 < emitted_before < 8
         decision = controller.evict(victim, reason="test-kill")
@@ -284,15 +295,14 @@ class TestFailover:
                             seed=123))
 
     def test_exact_dispatch_counts_across_failover(self):
-        """The dryrun smoke's arithmetic, asserted here too: K=4 fused,
-        A needs 9 (prefill 1 + 4 on r0 before the kill, then re-prefill
-        emits 1 + 3 fused on r1), B needs 5 (prefill 1 + 4 fused) — one
-        shared dispatch on the survivor covers both. A fused block is
-        read one dispatch behind: r0's is held once ``flush()`` has read
-        it, and a kill before that would have lost its four tokens."""
+        """The dryrun smoke's arithmetic, asserted here too: A needs 9
+        (prefill 1 + 1 on r0 before the kill, then re-prefill emits 1 + 6
+        steps on r1), B needs 5 (prefill 1 + 4 steps) — six dispatches on
+        the survivor, the first four shared. A block is read one dispatch
+        behind: r0's is held once ``flush()`` has read it, and a kill
+        before that would have lost its token."""
         lm = _lm()
-        reps = [_replica(f"f{i}", slots=2, fuse_steps=4)
-                for i in range(2)]
+        reps = [_replica(f"f{i}", slots=2) for i in range(2)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=5.0)
         prompt = np.arange(1, 9, dtype=np.int32)
@@ -302,38 +312,27 @@ class TestFailover:
         reps[0].step_once()
         assert len(fa.tokens) == 1
         reps[0].server.flush()
-        assert len(fa.tokens) == 5
+        assert len(fa.tokens) == 2
         controller.evict("f0", reason="test-kill")
         while reps[1].busy():
             reps[1].step_once()
         assert fa.finished and fb.finished
-        assert reps[0].server.steps == 1 and reps[1].server.steps == 1
+        assert reps[0].server.steps == 1 and reps[1].server.steps == 6
         assert np.array_equal(fa.output, _ref(lm, prompt, 9))
         assert np.array_equal(fb.output, _ref(lm, prompt + 1, 5))
 
-    @pytest.mark.parametrize("kind", ["fused", "module"])
+    @pytest.mark.parametrize("kind", ["plain", "module"])
     def test_a_kill_with_a_block_unread_costs_recompute_not_tokens(self,
                                                                    kind):
         """The same kill WITHOUT the ``flush()``: f0 dies with its one
-        dispatched block unread — K=4 fused steps, or a round drafted
+        dispatched block unread — a plain step, or a round drafted
         from the model's own module — and that block's tokens die with
         it. A holds its prefill's token alone, re-prefills from prompt+1
         on the survivor (emitting 1) and decodes the 7 it still lacks
-        there, beside B's 4: two fused dispatches where the flushed kill
-        needed one. Both streams are the reference's, token for token."""
-        if kind == "fused":
-            lm, kw = _lm(), {"fuse_steps": 4}
-        else:
-            lm, kw = _lm(
-                "module", num_kv_heads=None, d_ff=16, norm="rmsnorm",
-                tie_embeddings=False, num_experts=8, experts_per_token=2,
-                norm_topk_prob=True, mixers=("mla",) * 2,
-                ffns=("glu", "moe"), glu_width=32,
-                mtp={"loss_weight": 0.3},
-                mla={"q_lora_rank": 16, "kv_lora_rank": 16,
-                     "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
-                     "v_head_dim": 12, "gate": False}), {}
-        reps = [_replica(f"f{i}", lm, slots=2, **kw) for i in range(2)]
+        there, beside B's 4: seven steps where the flushed kill
+        needed six. Both streams are the reference's, token for token."""
+        lm = _lm() if kind == "plain" else _module_lm()
+        reps = [_replica(f"f{i}", lm, slots=2) for i in range(2)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=5.0)
         prompt = np.arange(1, 9, dtype=np.int32)
@@ -349,17 +348,17 @@ class TestFailover:
             reps[1].step_once()
         assert fa.finished and fb.finished
         # the unfailed run (``generate`` holds no latent rows: a server's)
-        whole = DecodeServer(lm, slots=2, max_len=64, **kw)
+        whole = DecodeServer(lm, slots=2, max_len=64)
         want = [whole.submit(prompt, 9), whole.submit(prompt + 1, 5)]
         whole.drain()
         assert np.array_equal(fa.output, want[0].output)
         assert np.array_equal(fb.output, want[1].output)
-        if kind == "fused":
+        if kind == "plain":
             assert np.array_equal(fa.output, _ref(lm, prompt, 9))
         assert reps[0].server.steps == 0        # its block was never read
         assert reps[1].server.decode_tokens == 7 + 4
-        if kind == "fused":
-            assert reps[1].server.steps == 2
+        if kind == "plain":
+            assert reps[1].server.steps == 7
 
     def test_fully_emitted_requeue_completes_without_survivor_work(self):
         """A max_new=1 split request whose handoff never installed: the
@@ -555,14 +554,41 @@ class TestHandoff:
             fr.output,
             _ref(lm, prompt, 6, temperature=0.7, top_k=20, seed=42))
 
+    def test_handoffs_of_array_prompts_park_side_by_side(self):
+        """Three prefilled requests for a decode replica with one slot: the
+        first is placed, the other two park and wait their turn. Requests
+        are told apart by identity — their prompts are arrays, and a
+        field-wise ``==`` between two parked ones raised ``ValueError``."""
+        lm = _lm()
+        pre = ServeReplica("p0", lm, role="prefill", slots=2, max_len=64)
+        dec = ServeReplica("d0", lm, role="decode", slots=1, max_len=64)
+        router = FleetRouter([pre, dec])
+        prompts = [np.arange(i, i + 6, dtype=np.int32) for i in (1, 2, 3)]
+        frs = [router.submit(p, 4) for p in prompts]
+        assert all(isinstance(fr.prompt, np.ndarray) for fr in frs)
+        while pre.busy():
+            pre.step_once()
+        assert frs[0].replica_id == "d0"
+        assert router._pending == frs[1:] and frs[1] != frs[2]
+        # parking one again holds it once
+        assert not router.place_handoff(frs[2], frs[2]._parked_handoff)
+        assert router._pending == frs[1:]
+        for _ in range(40):
+            if all(fr.finished for fr in frs):
+                break
+            dec.step_once()
+            router.retry_pending()
+        for fr, p in zip(frs, prompts):
+            assert fr.finished and np.array_equal(fr.output, _ref(lm, p, 4))
+
     def test_split_fleet_config_and_capacity_validation(self):
         lm = _lm()
         # a speculative decode replica can never take handoffs: loud at
         # construction, not as a worker-thread death on first handoff
         pre = ServeReplica("p0", lm, role="prefill", slots=2,
                            max_len=64)
-        spec_dec = ServeReplica("d0", lm, role="decode", server=(
-            DecodeServer(lm, slots=2, max_len=64, draft_layers=1)))
+        spec_dec = ServeReplica("d0", _module_lm(), role="decode", server=(
+            DecodeServer(_module_lm(), slots=2, max_len=64)))
         with pytest.raises(ValueError, match="speculative"):
             FleetRouter([pre, spec_dec])
         # oversized requests raise at submission like the mixed path,
@@ -595,9 +621,9 @@ class TestHandoff:
         with pytest.raises(ValueError, match="kv_dtype"):
             install_slot(
                 DecodeServer(lm, slots=2, max_len=64,
-                             kv_dtype="int8").engine, 0, handoff())
-        # a speculative target has no draft-pool prompt K/V: reject
-        spec = DecodeServer(lm, slots=2, max_len=64, draft_layers=1)
+                             kv_dtype="bfloat16").engine, 0, handoff())
+        # a hand-off carries no draft for a round to verify: reject
+        spec = DecodeServer(_module_lm(), slots=2, max_len=64)
         from deeplearning4j_tpu.serving.scheduler import ServeRequest
 
         req = ServeRequest(prompt=prompt, max_new_tokens=2)
@@ -624,8 +650,7 @@ class TestVirtualDriver:
             return 0.01
 
         def run(n):
-            reps = [_replica(f"r{i}", slots=2, fuse_steps=2)
-                    for i in range(n)]
+            reps = [_replica(f"r{i}", slots=2) for i in range(n)]
             router = FleetRouter(reps)
             driver = FleetLoadDriver(
                 router, FleetController(router, None,
@@ -651,8 +676,7 @@ class TestVirtualDriver:
             replica.step_once()
             return 0.01
 
-        reps = [_replica(f"r{i}", slots=2, fuse_steps=2)
-                for i in range(2)]
+        reps = [_replica(f"r{i}", slots=2) for i in range(2)]
         router = FleetRouter(reps)
         controller = FleetController(router, None, evict_timeout_s=5.0)
         driver = FleetLoadDriver(router, controller,
@@ -684,7 +708,7 @@ class TestReplicaKillChaos:
         tracker = InMemoryStateTracker()
         reps = [ServeReplica(f"r{i}", lm, tracker=tracker,
                              heartbeat_interval_s=0.05, slots=2,
-                             max_len=64, fuse_steps=2)
+                             max_len=64)
                 for i in range(2)]
         # warm the programs on this thread (jax tracing is not the
         # worker loop's job) and reset the bookkeeping

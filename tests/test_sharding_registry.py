@@ -22,6 +22,12 @@ epoch program routed through it + TP serving), on the conftest-forced
   sparse (cond-gated) collective over an undeclared axis.
 """
 
+import contextlib
+import os
+import signal
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -29,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.datasets.dataset import DataSet
 from deeplearning4j_tpu.datasets.iterator import ListDataSetIterator
 from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration, Updater
@@ -236,7 +243,85 @@ def _fit_pair(factory, data_factory, batch, variant):
 VARIANTS = ["plain", "accum", "guard", "telemetry"]
 
 
+@contextlib.contextmanager
+def _persistent_cache(directory):
+    """JAX's persistent compilation cache off (``None``) or at
+    ``directory`` for the block, whatever the process had: jax decides once
+    a process whether it uses the cache, so it is reset on the way in and
+    out. The package's own one-time placement runs first, or the first
+    ``fit_epochs`` inside the block would move the directory back."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    ensure_compile_cache()
+    old = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_compilation_cache_dir)
+    jax.config.update("jax_enable_compilation_cache", directory is not None)
+    if directory is not None:
+        jax.config.update("jax_compilation_cache_dir", directory)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old[0])
+        jax.config.update("jax_compilation_cache_dir", old[1])
+        cc.reset_cache()
+
+
+def _reload_twice(directory):
+    """The telemetry variant's programs compiled into the private
+    ``directory``, every in-memory executable dropped, and the same body
+    again: its programs come back from the directory (cache hits counted)
+    and train to the same parameters and history, bit for bit."""
+    from jax._src import monitoring
+
+    hits = []
+    monitoring.register_event_listener(
+        lambda event, **kw: hits.append(event)
+        if event == "/jax/compilation_cache/cache_hits" else None)
+    with _persistent_cache(directory):
+        _, first, _, h_first = _fit_pair(_rnn_net, _rnn_data, 8, "telemetry")
+        programs = [f for f in os.listdir(directory)
+                    if f.endswith("-cache")]
+        cold = len(hits)
+        jax.clear_caches()
+        _, again, _, h_again = _fit_pair(_rnn_net, _rnn_data, 8, "telemetry")
+    # every program of the second pass came from the directory
+    assert len(hits) - cold >= len(programs) > 2, (len(hits), cold, programs)
+    assert again._train_dispatches == 1
+    _assert_params_close(first.params, again.params, rtol=0, atol=0)
+    np.testing.assert_array_equal(np.asarray(h_first), np.asarray(h_again))
+
+
 class TestDpTpFusedParity:
+    @pytest.fixture(autouse=True, scope="class")
+    def _compiled_here(self):
+        """These eight-device programs are compiled in the process that
+        runs them: loaded from a persistent cache that an EARLIER process
+        wrote, ``test_rnn[telemetry]``'s aborted the interpreter in about
+        one run of three (ROADMAP D0; XLA:CPU, not this package)."""
+        with _persistent_cache(None):
+            yield
+
+    def test_rnn_reloaded_from_a_cache_this_process_wrote(self, tmp_path):
+        """The load-from-cache path, now that the class keeps out of the
+        checkout's cache (``_reload_twice``), in a process of its own: there
+        too XLA:CPU sometimes ends the interpreter (its eight devices wait at
+        different collectives of the loaded program until ``rendezvous.cc``'s
+        40 s termination timeout; 4 of 16 runs alone, most runs beside five
+        busy workers), and that is recorded as an expected failure, not as a
+        dead worker."""
+        tests = os.path.dirname(os.path.abspath(__file__))
+        run = subprocess.run(
+            [sys.executable, "-c", "import conftest, test_sharding_registry "
+             f"as t; t._reload_twice({str(tmp_path)!r})"],
+            env=dict(os.environ, PYTHONPATH=os.pathsep.join(
+                [os.path.dirname(tests), tests])),
+            capture_output=True, text=True, timeout=600)
+        if run.returncode == -signal.SIGABRT:
+            pytest.xfail("XLA:CPU aborted running an eight-device program "
+                         "loaded from the persistent cache (ROADMAP D0)")
+        assert run.returncode == 0, run.stderr[-3000:]
+
     @pytest.mark.parametrize("variant", VARIANTS)
     def test_ff(self, variant):
         ref, tp, h0, h1 = _fit_pair(_ff_net, _ff_data, 16, variant)

@@ -246,7 +246,15 @@ class TransformerLM:
         # each head of q and of k, gain [head_dim], instead of ``qk_norm``'s
         # over the whole projection), gate (``wq`` is twice as wide: per
         # head, head_dim of query then head_dim of gate, and the heads'
-        # output is multiplied by sigmoid(gate) before ``wo``)}. ``moe``
+        # output is multiplied by sigmoid(gate) before ``wo``), windows (a
+        # sliding window a layer, None on a layer that attends its whole
+        # prefix and on every layer that is no 'attn' layer, instead of the
+        # model's one ``attn_window``; a served slot keeps a ring of rows
+        # for the window layers beside the T_max rows of the others,
+        # ``serving/kv_cache.py``), rope ({"window": {theta, scaling},
+        # "full": {theta, scaling}}: RoPE's base and YaRN scaling by the
+        # layer's kind, whichever of the two it names, instead of the
+        # model's ``rope_theta`` / ``rope_scaling``)}. ``moe``
         # without ``n_group`` keeps the softmax router over ``num_experts``
         # and names the share alone ({first, held}); ``shared_gate``: the
         # shared expert's output times sigmoid(x . w), one number a token.
@@ -299,11 +307,22 @@ class TransformerLM:
                                              d_model // num_heads))
         self.rotary_dim = int((attn or {}).get("rotary_dim", self.head_dim))
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
-        kind = (rope_scaling or {}).get("rope_type", "yarn")
-        if kind != "yarn" or rope_scaling and pos_encoding != "rope":
-            raise ValueError(f"rope_scaling: rope_type {kind!r} with "
-                             f"pos_encoding={pos_encoding!r}; YaRN over RoPE "
-                             "is the one scaling written")
+        by_kind = dict((attn or {}).get("rope") or {})
+        if set(by_kind) - {"window", "full"}:
+            raise ValueError(f"attn['rope'] names the layer kinds 'window' "
+                             f"and 'full' (got {sorted(by_kind)})")
+        for scaling in [rope_scaling] + [
+                r.get("scaling") for r in by_kind.values()]:
+            kind = (scaling or {}).get("rope_type", "yarn")
+            if kind != "yarn" or scaling and pos_encoding != "rope":
+                raise ValueError(f"rope_scaling: rope_type {kind!r} with "
+                                 f"pos_encoding={pos_encoding!r}; YaRN over "
+                                 "RoPE is the one scaling written")
+        # {layer kind: (base, scaling)} where ``attn['rope']`` names the kind
+        self.rope_by_kind = {
+            kind: (float(r.get("theta", rope_theta)),
+                   dict(r["scaling"]) if r.get("scaling") else None)
+            for kind, r in by_kind.items()}
         if self.rope_scaling and self.mla:
             # read by ``models/mla.softmax_scale``; set, not multiplied, so a
             # model rebuilt from ``get_config`` carries it once
@@ -339,10 +358,10 @@ class TransformerLM:
         if pos_encoding == "rope" and (
                 self.rotary_dim % 2 or self.rotary_dim > self.head_dim):
             raise ValueError(
-                f"RoPE needs an even head_dim, or an even rotary_dim within "
-                f"it (got {self.rotary_dim} of head_dim {self.head_dim}: "
-                f"d_model={d_model} / num_heads={num_heads} unless attn= "
-                "says otherwise); the rotation pairs dimensions")
+                f"RoPE needs an even rotary_dim within head_dim (got "
+                f"rotary_dim {self.rotary_dim} of head_dim {self.head_dim}; "
+                f"both are d_model={d_model} / num_heads={num_heads} unless "
+                "attn= says otherwise): the rotation pairs dimensions")
         self.pos_encoding = pos_encoding
         # GQA/MQA: fewer key/value heads than query heads — KV cache and
         # wk/wv params shrink by num_heads/num_kv_heads; K/V are repeated
@@ -358,6 +377,23 @@ class TransformerLM:
         if attn_window is not None and attn_window < 1:
             raise ValueError(f"attn_window={attn_window} must be >= 1")
         self.attn_window = attn_window
+        # the window a layer: ``attn['windows']``, else the model's one
+        windows = (attn or {}).get("windows")
+        self.windows = (attn_window,) * num_layers if windows is None else (
+            tuple(windows))
+        if windows is not None and (
+                attn_window is not None or len(self.windows) != num_layers
+                or any(w is not None and (w < 1 or m != "attn")
+                       for w, m in zip(self.windows, self.mixers))):
+            raise ValueError(
+                f"attn['windows']={windows!r} gives a window >= 1 or None for "
+                f"each of the {num_layers} layers (None on a layer that is no "
+                "'attn' layer), in place of attn_window")
+        if self.mtp and self.by_layer:
+            raise ValueError(
+                "mtp= with attn['windows'] or attn['rope']: the module's "
+                "block is one more layer, which has no place in a "
+                "description by layer")
         # sequence-parallel strategy when training with
         # sequence_parallel=True: "ring" (K/V rotate around the sequence
         # axis via ppermute — best at huge T) or "ulysses" (two
@@ -542,11 +578,13 @@ class TransformerLM:
                sequence_parallel: bool = False, attention=None,
                positions=None, train: bool = False, live=None,
                moe_info: Optional[list] = None, state=None,
-               indexer=None, selection=None):
+               indexer=None, selection=None, layer: Optional[int] = None):
         """One pre-norm block on ``h`` [b, t, D], as the model describes
         that layer (``blk``'s own keys say which mixer and which
         feed-forward it is; norm kind, QK-norm — chosen here,
-        at trace time, for training, prefill and decode alike). Returns
+        at trace time, for training, prefill and decode alike; ``layer``,
+        its index, says which window and which RoPE an 'attn' layer of a
+        model described by layer has: ``_window``, ``_rope_head``). Returns
         ``(h, k, v)``
         with k/v in [b, t, H, Dh] — ``forward`` discards them (XLA DCE),
         the KV-cache prefill keeps them (k/v are post-RoPE under
@@ -624,9 +662,11 @@ class TransformerLM:
             if self.pos_encoding == "rope":
                 if positions is None:
                     positions = jnp.arange(t)
-                q, k = (self._rope_head(a, positions) for a in (q, k))
+                q, k = (self._rope_head(a, positions, layer)
+                        for a in (q, k))
         # the returned k/v stay at num_kv_heads (what the KV cache
         # stores); attention sees them repeated per query-head group
+        window = self._window(layer)
         if attention is not None:
             o = attention(q, k, v)
         elif sequence_parallel and mesh is not None:
@@ -636,21 +676,21 @@ class TransformerLM:
 
                 o = ulysses_attention(
                     q, self._repeat_kv(k), self._repeat_kv(v), mesh,
-                    causal=True, window=self.attn_window)
+                    causal=True, window=window)
             else:
                 o = ring_attention(q, self._repeat_kv(k),
                                    self._repeat_kv(v), mesh, causal=True,
                                    impl=self._attn_impl(t, train=train),
-                                   window=self.attn_window)
+                                   window=window)
         elif self._attn_impl(t, train=train) == "flash":
             o = flash_attention(q, self._repeat_kv(k), self._repeat_kv(v),
-                                causal=True, window=self.attn_window)
+                                causal=True, window=window)
         else:
             # grouped attention broadcasts each kv head over its query
             # group — no materialized repeat (= dot_product_attention
             # when H == Hkv)
             o = grouped_query_attention(q, k, v, causal=True,
-                                        window=self.attn_window)
+                                        window=window)
         with scope("attn.proj"):
             if gate is not None:
                 o = (o.astype(jnp.float32) * jax.nn.sigmoid(
@@ -659,12 +699,37 @@ class TransformerLM:
                 blk["attn"]["wo"])
         return self._ffn(blk, h, live, moe_info, train), k, v
 
-    def _rope_head(self, x, positions):
+    @property
+    def by_layer(self) -> bool:
+        """True when ``attn=`` describes the 'attn' layers one by one: a
+        window a layer or a RoPE a layer kind. ``_block`` then needs its
+        ``layer``."""
+        return bool(self.attn and (self.attn.get("windows") is not None
+                                   or self.attn.get("rope")))
+
+    def _window(self, layer: Optional[int]) -> Optional[int]:
+        """The sliding window of 'attn' layer ``layer`` (None: it attends
+        its whole prefix): the model's one ``attn_window`` unless
+        ``attn['windows']`` gives one a layer."""
+        if layer is None:
+            if self.by_layer:
+                raise ValueError(
+                    "this model's 'attn' layers are described one by one "
+                    "(attn['windows'], attn['rope']): _block needs layer=")
+            return self.attn_window
+        return self.windows[layer]
+
+    def _rope_head(self, x, positions, layer: Optional[int] = None):
         """RoPE on the first ``rotary_dim`` dimensions of each head of ``x``
-        [b, t, H, Dh] (all of them unless ``attn=`` says fewer)."""
+        [b, t, H, Dh] (all of them unless ``attn=`` says fewer), with the
+        base and scaling of the layer's kind where ``attn['rope']`` names
+        it ('window' or 'full', by ``_window``), else the model's."""
         r = self.rotary_dim
-        turned = _rope(x[..., :r], positions, self.rope_theta,
-                       self.rope_interleaved, self.rope_scaling)
+        kind = "full" if self._window(layer) is None else "window"
+        theta, scaling = self.rope_by_kind.get(
+            kind, (self.rope_theta, self.rope_scaling))
+        turned = _rope(x[..., :r], positions, theta,
+                       self.rope_interleaved, scaling)
         return turned if r == x.shape[-1] else jnp.concatenate(
             [turned, x[..., r:]], axis=-1)
 
@@ -827,8 +892,8 @@ class TransformerLM:
         norm."""
         if moe_info is not None and (self.remat or self.scan_layers):
             raise ValueError("moe_info needs remat=False, scan_layers=False")
-        if self.scan_layers and max(map(len, map(set, (
-                self.mixers, self.ffns, self.indexers)))) > 1:
+        if self.scan_layers and (self.by_layer or max(map(len, map(set, (
+                self.mixers, self.ffns, self.indexers)))) > 1):
             raise ValueError("scan_layers needs every layer the same block")
         policy = self.policy
         b, t = tokens.shape
@@ -838,14 +903,16 @@ class TransformerLM:
                 h = h + params["pos"][:t][None]
             h = policy.cast_compute(h)
 
-        def block_fn(blk, h, selection=None):
+        def block_fn(blk, h, selection=None, layer=None):
             return self._block(blk, h, mesh=mesh,
                                sequence_parallel=sequence_parallel,
                                train=train, moe_info=moe_info,
-                               selection=selection)
+                               selection=selection, layer=layer)
 
         if self.remat:
-            block_fn = jax.checkpoint(block_fn)
+            # ``layer`` picks a window and a RoPE at trace time: static
+            block_fn = jax.checkpoint(
+                block_fn, static_argnums=(3,) if self.by_layer else ())
         if self.scan_layers:
             # one scan over the stacked per-layer params: the traced
             # program holds ONE block body however deep the net is
@@ -860,8 +927,10 @@ class TransformerLM:
             # a selection of key positions is the one value that goes from
             # a layer to the layers after it beside the residual stream
             selection = None
-            for blk in params["blocks"]:
-                h, _, left = block_fn(blk, h, selection)
+            for i, blk in enumerate(params["blocks"]):
+                # a model described by layer says which layer this is
+                h, _, left = block_fn(blk, h, selection,
+                                      *((i,) if self.by_layer else ()))
                 if self.dsa and "mla" in blk:
                     selection = left
         return h
@@ -1130,8 +1199,8 @@ class TransformerLM:
             h = policy.cast_compute(h)
         cache = []
         pad_t = ((0, 0), (0, max_new_tokens), (0, 0), (0, 0))
-        for blk in params["blocks"]:
-            h, kk, vv = self._block(blk, h)
+        for i, blk in enumerate(params["blocks"]):
+            h, kk, vv = self._block(blk, h, layer=i)
             cache.append({"k": jnp.pad(kk.astype(cdt), pad_t),
                           "v": jnp.pad(vv.astype(cdt), pad_t)})
         return h[:, -1], cache
@@ -1148,13 +1217,17 @@ class TransformerLM:
             if self.pos_encoding == "learned":
                 h = h + params["pos"][t]
             h = policy.cast_compute(h)[:, None, :]          # [B, 1, D]
-        live = jnp.arange(total) <= t                       # [total]
-        if self.attn_window is not None:
-            live &= jnp.arange(total) > t - self.attn_window
-        live = live[None, :]                                # [1, total]
         new_cache = []
+        masks = {}      # {window: [1, total] keys a query at t may see}
 
-        def cached_attention(c):
+        def cached_attention(c, window):
+            if window not in masks:
+                live = jnp.arange(total) <= t               # [total]
+                if window is not None:
+                    live &= jnp.arange(total) > t - window
+                masks[window] = live[None, :]
+            live = masks[window]
+
             def attn(q, kk, vv):
                 ck = lax.dynamic_update_slice(
                     c["k"], kk.astype(cdt), (0, t, 0, 0))
@@ -1165,9 +1238,10 @@ class TransformerLM:
                     q, ck, cv, mask=jnp.broadcast_to(live, (B, total)))
             return attn
 
-        for blk, c in zip(params["blocks"], cache):
-            h, _, _ = self._block(blk, h, attention=cached_attention(c),
-                                  positions=jnp.asarray(t)[None])
+        for i, (blk, c) in enumerate(zip(params["blocks"], cache)):
+            h, _, _ = self._block(
+                blk, h, attention=cached_attention(c, self.windows[i]),
+                positions=jnp.asarray(t)[None], layer=i)
         return h[:, 0], new_cache
 
     def _validate_decode_args(self, prompt_len, max_new_tokens):

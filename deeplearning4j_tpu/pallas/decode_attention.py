@@ -42,12 +42,22 @@ more items (0.54 against 0.49 ms for 20 live slots of 32 at 16 kv heads),
 a 1 MiB block fetches more than short contexts hold (0.073 against 0.058
 ms for 4 live slots of 64 at 2 kv heads).
 
+A **ring** (``ring=True``; ``serving/kv_cache.py``: the rows a slot keeps
+for a layer with a sliding window, ``R`` positions instead of ``T_max``) is
+read by the same walk: position ``t`` lies at row ``t mod R``, so at a query's
+position ``c`` row ``r`` holds position ``c - ((c - r) mod R)``, which the
+mask admits when it is not negative (the row has been written) and inside
+the window. A slot's blocks are the ring's first ``min(c + 1, R)`` rows
+whatever its length: ``key_block_span`` with no window over a pool of ``R``
+positions.
+
 ``interpret=True`` runs the same kernel through the Pallas interpreter
 (CPU tests).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -119,7 +129,7 @@ def _work_list(positions, live, *, block, hkv, window, t_max):
 def _kernel(n_ref, slot_ref, lo_ref, hi_ref, first_ref, pos_ref,
             q_ref, qhead_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sem, acc_ref, m_ref, l_ref, *,
-            layer, scale, block, hkv, window, heads, queries):
+            layer, scale, block, hkv, window, heads, queries, ring):
     # dead slots, and rows the work list never reaches, read zeros
     o_ref[...] = jnp.zeros_like(o_ref)
     n = n_ref[0]
@@ -176,7 +186,15 @@ def _kernel(n_ref, slot_ref, lo_ref, hi_ref, first_ref, pos_ref,
         for i in range(queries):
             qpos = jnp.where((mrow >= i * heads) & (mrow < (i + 1) * heads),
                              pos_ref[s * queries + i], qpos)
-        keep = (head == qhead_ref[...]) & (t <= qpos)
+        if ring is not None:
+            # the position this ring row holds at the query's: negative
+            # where it has not been written (or the query is a pad row)
+            back = qpos - t
+            t = qpos - (back & (ring - 1) if ring & (ring - 1) == 0
+                        else jnp.remainder(back, ring))
+            keep = (head == qhead_ref[...]) & (t >= 0)
+        else:
+            keep = (head == qhead_ref[...]) & (t <= qpos)
         if window is not None:
             keep &= t > qpos - window
         logits = jnp.where(keep, logits, MASK_VALUE)
@@ -207,7 +225,8 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
                           window: Optional[int] = None,
                           block_rows: Optional[int] = None,
                           interpret: bool = False, live=None,
-                          hkv: Optional[int] = None):
+                          hkv: Optional[int] = None, ring: bool = False,
+                          name: Optional[str] = None):
     """Attention of ``q [S, Q, H, Dh]`` at absolute ``positions [S, Q]``
     against layer ``layer`` of the ``[L, S, T_max, Hkv, Dh]`` pools (or
     their rows ``[L, S, T_max Hkv, Dh]`` with ``hkv`` kv heads, the shape a
@@ -220,7 +239,14 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
     The pools may store another float dtype; blocks are cast to
     ``q.dtype`` in VMEM. ``live [S]`` (bool) names the slots that hold a
     request: nothing of the others is fetched or multiplied and their
-    rows are zeros. ``None``: every slot is live."""
+    rows are zeros. ``None``: every slot is live.
+
+    ``ring``: the pools are rings of ``R`` = their third axis' positions
+    (the module's docstring): one query a slot, which attends the positions
+    ``> positions[s, 0] - window`` that the ring's rows hold. ``name``: a
+    scope of ``scopes.py`` to open round the ``pallas_call``, which names
+    the kernel's instruction in a device trace (a window layer's read is
+    ``attn.window``; with none the call keeps its own name)."""
     s_, nq, h, dh = q.shape
     if pool_k.ndim == 4:
         n_layers, t_max = pool_k.shape[0], pool_k.shape[2] // hkv
@@ -232,6 +258,11 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
         raise ValueError(
             f"pool {pool_k.shape} ({pool_k.dtype}) with {h} query heads "
             "does not fit the decode kernel's blocks")
+    if ring and nq != 1:
+        raise NotImplementedError(
+            "a ring of rows is read by one query a slot (a decode step): "
+            f"{nq} queries a slot would each need the ring as of their own "
+            "position")
     m = nq * h
     m_pad = -(-m // 16) * 16            # whole bf16 sublane tiles
     # the operands' layout and the work list are XLA ops of the pool's side
@@ -246,17 +277,19 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
             jnp.arange(h, dtype=jnp.int32) // (h // hkv), nq),
             (0, m_pad - m))[:, None]
         operands = (*_work_list(positions, live, block=block, hkv=hkv,
-                                window=window, t_max=t_max),
+                                window=None if ring else window,
+                                t_max=t_max),
                     positions.reshape(-1), qf, qhead,
                     pool_k.reshape(n_layers, s_, t_max * hkv, dh),
                     pool_v.reshape(n_layers, s_, t_max * hkv, dh))
 
     kernel = functools.partial(
         _kernel, layer=layer, scale=float(1.0 / (dh ** 0.5)), block=block,
-        hkv=hkv, window=window, heads=h, queries=nq)
+        hkv=hkv, window=window, heads=h, queries=nq,
+        ring=t_max if ring else None)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
@@ -273,6 +306,8 @@ def pool_decode_attention(q, pool_k, pool_v, layer: int, positions, *,
             ]),
         out_shape=jax.ShapeDtypeStruct((s_, m_pad, dh), q.dtype),
         interpret=interpret,
-    )(*operands)
+    )
+    with scope(name) if name else contextlib.nullcontext():
+        out = call(*operands)
     with scope("kv.write"):
         return out[:, :m].reshape(s_, nq, h, dh)

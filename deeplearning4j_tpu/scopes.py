@@ -16,7 +16,9 @@ this list is open where ``attention(q, k, v)``, ``flash_attention``,
 ``ring_attention`` or ``grouped_query_attention`` is called from the block,
 nor round the decode-attention kernel's ``pallas_call``**: those kernels
 keep the names the accepted readers know (``tests/test_scopes.py`` holds
-this). ``attn.core`` is opened inside the XLA attention op itself
+this). The one exception names a kernel on purpose: ``attn.window`` is opened
+round the decode kernel's call for a window layer of a model that gives a
+window a layer, so that a trace tells its ring reads from the full layers'. ``attn.core`` is opened inside the XLA attention op itself
 (``ops/attention.py``), which holds no kernel. The routed experts' kernel
 sits under ``moe.experts`` since PR 30 and is read by that name.
 
@@ -36,6 +38,9 @@ SCOPES = {
     "lm.embed": "token gather, learned positions, cast into the compute dtype",
     "attn.proj": "wq/wk/wv, QK-norm, head reshapes, RoPE, the kv-head repeat; "
                  "reopened for the output gate, wo and its residual add",
+    "attn.window": "the decode kernel's read of a layer with a window, of a "
+                   "model that gives a window a layer (attn['windows']): "
+                   "opened round the pallas_call alone, to name it",
     "attn.core": "the XLA attention op (scores, mask, softmax, values) where "
                  "no kernel applies",
     "kv.write": "a decode-family step's new rows into the slot pool or a "

@@ -86,7 +86,8 @@ from deeplearning4j_tpu.perf.bucketing import (
     DEFAULT_PROMPT_BUCKETS, pad_prompt, prompt_bucket)
 from deeplearning4j_tpu.scopes import scope
 from deeplearning4j_tpu.serving.kv_cache import (
-    SlotKVCache, advance_loop, slot_admit, write_pool_rows)
+    SlotKVCache, advance_loop, attn_places, ring_positions, slot_admit,
+    write_pool_rows)
 
 __all__ = ["DecodeEngine"]
 
@@ -173,8 +174,9 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     live = ((jnp.arange(p) < prompt_len)[None]
             if model.num_experts or model.hybrid else None)
     moe_info: list = []
-    for blk in params["blocks"]:
-        h, kk, vv = model._block(blk, h, live=live, moe_info=moe_info)
+    for i, blk in enumerate(params["blocks"]):
+        h, kk, vv = model._block(blk, h, live=live, moe_info=moe_info,
+                                 layer=i)
         if "kda" in blk or "gdn" in blk:
             left["kda"].append(kk)
             left["conv"].append(vv)
@@ -195,17 +197,30 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
 
     new_kv = {name: [into(pool, new) for pool, new in zip(kv[name], rows)]
               for name, rows in left.items() if rows}
-    kcat = jnp.stack(ks) if ks else None     # [L, 1, P, Hkv, Dh]
-    vcat = jnp.stack(vs) if ks else None
-    if ks:
-        def put(pool, cat):
-            cat = cat.astype(pool.dtype)
-            if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
-                cat = cat.reshape(cat.shape[:2] + (-1, cat.shape[-1]))
-            return lax.dynamic_update_slice(
-                pool, cat, (0, slot) + (0,) * (pool.ndim - 2))
+    def put(pool, cat):
+        cat = cat.astype(pool.dtype)
+        if pool.ndim == 4:      # rows of wide heads (``pool_shape``)
+            cat = cat.reshape(cat.shape[:2] + (-1, cat.shape[-1]))
+        return lax.dynamic_update_slice(
+            pool, cat, (0, slot) + (0,) * (pool.ndim - 2))
 
-        new_kv.update(k=put(kv["k"], kcat), v=put(kv["v"], vcat))
+    # each 'attn' layer's rows into its own pool: the T_max rows of ``k`` and
+    # ``v``, or, for a layer with a window of a model that keeps a ring
+    # (``kw``, ``vw``), the whole ring: row r takes the position it holds once
+    # the prompt's last token is written (``ring_positions``; a row that
+    # holds none yet takes position 0, which the mask never admits)
+    places = [name for name, _ in attn_places(model, "kw" in kv)]
+    for name, kn, vn in (("kv", "k", "v"), ("ring", "kw", "vw")):
+        mine = [j for j, n in enumerate(places) if n == name]
+        if not mine:
+            continue
+        kcat = jnp.stack([ks[j] for j in mine])     # [L, 1, P, Hkv, Dh]
+        vcat = jnp.stack([vs[j] for j in mine])
+        if name == "ring":
+            r = _logical_dims(kv[kn], model.num_kv_heads)[2]
+            held = jnp.clip(ring_positions(prompt_len - 1, r), 0, p - 1)
+            kcat, vcat = (jnp.take(cat, held, axis=2) for cat in (kcat, vcat))
+        new_kv.update({kn: put(kv[kn], kcat), vn: put(kv[vn], vcat)})
     h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
     logits = model._unembed(params, h_last)
     with scope("lm.head"):
@@ -250,6 +265,14 @@ def unpack_routing(packed, num_experts: int, experts_per_token: int):
             packed[:, -1])
 
 
+def _logical_dims(pool, hkv: int):
+    """``(L, S, T, Hkv, Dh)`` of a K (or V) pool or ring, whichever shape it
+    is stored in: rows of wide heads are stored flat
+    (``kv_cache.pool_shape``)."""
+    return pool.shape if pool.ndim == 5 else (
+        pool.shape[:2] + (pool.shape[2] // hkv, hkv, pool.shape[3]))
+
+
 @traced
 def _pool_attention(model, pool, positions, pool_kernel, live=None):
     """``li -> attention(q, kk, vv)`` for a decode-family forward over
@@ -261,7 +284,11 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     every layer rebinds its entries, so after the layer loop it holds the
     program's output pool — the arrays it was given, updated in place
     when the program's pool argument is donated. Nothing here stacks
-    slabs back into a pool.
+    slabs back into a pool. A model that gives a window a layer
+    (``attn['windows']``) meets each layer with its own window, and where it
+    keeps a ring (``pool`` holds ``kw``, ``vw``: ``kv_cache.attn_places``) a
+    window layer writes its row at ``position mod R`` of the ring and attends
+    the positions the ring's rows hold (``ring_positions``).
 
     The read has two forms. XLA:TPU copies ``pool[li]`` out as a slab
     before any dot may use it, so where the Pallas kernel applies
@@ -277,43 +304,67 @@ def _pool_attention(model, pool, positions, pool_kernel, live=None):
     from deeplearning4j_tpu.ops.attention import grouped_query_attention
     from deeplearning4j_tpu.pallas import decode_attention as kernel
 
-    window = model.attn_window
     dtype = model.policy.compute_dtype
     rows = jnp.arange(positions.shape[0])
-    if "k" not in pool:         # no layer of this model keeps keys
-        return None
+    if "k" not in pool and "kw" not in pool:
+        return None             # no layer of this model keeps keys
     if pool_kernel is None:
         pool_kernel = not flash_default_interpret()
-    # the pools' logical axes: rows of wide heads are stored flat
-    # (``kv_cache.pool_shape``)
+    if "kw" in pool and positions.shape[1] != 1:
+        raise NotImplementedError(
+            "a ring of rows (a model with attn['windows']) is read by one "
+            "query a slot: a round's candidates would each need the ring as "
+            "of their own position")
+    # the layers' places in the pools and their windows, in the layers' order
+    places = attn_places(model, "kw" in pool)
+    windows = [model.windows[i] for i in model.layers_of("attn")]
     hkv = model.num_kv_heads
-    dims = pool["k"].shape if pool["k"].ndim == 5 else (
-        pool["k"].shape[:2] + (pool["k"].shape[2] // hkv, hkv,
-                               pool["k"].shape[3]))
-    block = None
-    if pool_kernel:
-        block = kernel.pool_block_rows(dims, pool["k"].dtype)
-    if block is None:
-        keys = jnp.arange(dims[2])
-        mask = keys <= positions[:, :, None]               # [S, Q, T]
+    # per pool: its arrays' names, its logical axes, the kernel's rows a key
+    # block (None: the XLA read)
+    pools = {}
+    for name, kn, vn in (("kv", "k", "v"), ("ring", "kw", "vw")):
+        if kn in pool:
+            dims = _logical_dims(pool[kn], hkv)
+            pools[name] = (kn, vn, dims, kernel.pool_block_rows(
+                dims, pool[kn].dtype) if pool_kernel else None)
+    # the XLA read's masks [S, Q, rows], one a (pool, window)
+    masks = {}
+    for (name, _), window in zip(places, windows):
+        _, _, dims, block = pools[name]
+        if block is not None or (name, window) in masks:
+            continue
+        if name == "ring":
+            keys = ring_positions(positions, dims[2])
+            mask = keys >= 0
+        else:
+            keys = jnp.arange(dims[2])
+            mask = keys <= positions[:, :, None]
         if window is not None:
             mask &= keys > positions[:, :, None] - window
+        masks[name, window] = mask
 
     def layer(li):
+        (name, place), window = places[li], windows[li]
+        kn, vn, dims, block = pools[name]
+        ring = name == "ring"
+
         def attn(q, kk, vv):
             with scope("kv.write"):
-                for name, new in (("k", kk), ("v", vv)):
-                    pool[name] = write_pool_rows(pool[name], li, new, rows,
-                                                 positions)
+                at = positions % dims[2] if ring else positions
+                for n, new in ((kn, kk), (vn, vv)):
+                    pool[n] = write_pool_rows(pool[n], place, new, rows, at)
             if block is not None:
                 return kernel.pool_decode_attention(
-                    q, pool["k"], pool["v"], li, positions, window=window,
+                    q, pool[kn], pool[vn], place, positions, window=window,
                     block_rows=block, interpret=flash_default_interpret(),
-                    live=live, hkv=hkv)
-            views = ((pool[name][li] if pool[name].ndim == 5
-                      else pool[name][li].reshape(dims[1:])).astype(dtype)
-                     for name in ("k", "v"))
-            return grouped_query_attention(q, *views, mask=mask)
+                    live=live, hkv=hkv, ring=ring,
+                    name="attn.window" if model.by_layer
+                    and window is not None else None)
+            views = ((pool[n][place] if pool[n].ndim == 5
+                      else pool[n][place].reshape(dims[1:])).astype(dtype)
+                     for n in (kn, vn))
+            return grouped_query_attention(q, *views,
+                                           mask=masks[name, window])
         return attn
 
     return layer
@@ -657,7 +708,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
                             live=live)
     seen = {"attn": 0, "mla": 0, "kda": 0}
     selection = None
-    for blk, kw in zip(params["blocks"], latent):
+    for i, (blk, kw) in enumerate(zip(params["blocks"], latent)):
         kind = "kda" if "gdn" in blk else next(k for k in seen if k in blk)
         j = seen[kind]
         seen[kind] += 1
@@ -669,7 +720,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
             kw = {"attention": cached_attention(j)}
         h, a, b = model._block(
             blk, h, positions=positions[:, None], moe_info=moe_info,
-            live=None if live is None else live[:, None], **kw)
+            live=None if live is None else live[:, None], layer=i, **kw)
         if kind == "kda":
             new_kv["kda"][j], new_kv["conv"][j] = a, b
         elif kind == "mla" and model.dsa:
@@ -960,7 +1011,8 @@ class DecodeEngine:
                  max_len: Optional[int] = None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None,
-                 kv_dtype: Optional[str] = None, mesh=None):
+                 kv_dtype: Optional[str] = None, mesh=None,
+                 ring: bool = True):
         if temperature < 0.0:
             raise ValueError(f"temperature={temperature} must be >= 0")
         if top_k is not None and not 1 <= top_k <= model.vocab_size:
@@ -985,8 +1037,10 @@ class DecodeEngine:
 
             self.registry = ShardingRegistry.for_transformer(model, mesh)
             model.params = self.registry.place(model.params)
+        # ``ring=False``: the window layers of a model with
+        # ``attn['windows']`` keep T_max rows like the others
         self.cache = SlotKVCache(model, slots, max_len, kv_dtype,
-                                 registry=self.registry)
+                                 registry=self.registry, ring=ring)
         self.slots = self.cache.slots
         self.max_len = self.cache.max_len
         self.kv_dtype = self.cache.kv_dtype

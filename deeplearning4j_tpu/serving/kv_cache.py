@@ -46,6 +46,17 @@ of it side by side:
 - ``attn`` layers: the K/V pools above, ``[L_attn, S, T_max, Hkv, Dh]``
   (heads wider than one lane tile: stored ``[L_attn, S, T_max Hkv, Dh]``,
   the same rows in the same order; ``pool_shape``);
+- ``attn`` layers of a model that gives a window a layer
+  (``TransformerLM(attn={"windows": ...})``): the layers with a window keep a
+  **ring** of ``R`` rows a slot, ``[L_win, S, R, Hkv, Dh]`` (``kw``, ``vw``;
+  ``ring_rows``: the window in whole kernel blocks), beside the ``T_max`` rows
+  of the layers that attend their whole prefix, ``[L_full, S, T_max, Hkv,
+  Dh]``. Position ``t`` lies at ring row ``t mod R``; at cursor ``c`` row
+  ``r`` holds position ``c - ((c - r) mod R)`` (``ring_positions``), which
+  the mask admits when it is not negative and within the window. A prefill
+  writes the whole ring (the prompt's last ``min(P, R)`` rows where they
+  belong); a frozen slot's step writes at its frozen cursor's row, which the
+  next prefill rewrites with the rest;
 - ``mla`` layers: latent rows, one ``[S, T_max, r + dr]`` array a layer
   (``latent``; each row padded with zeros to whole 128-lane tiles,
   ``latent_row_width``), written at the cursor and masked like keys;
@@ -76,6 +87,8 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import numpy as np
+
 from deeplearning4j_tpu.analysis.annotations import traced
 
 __all__ = [
@@ -84,6 +97,9 @@ __all__ = [
     "kv_pool_nbytes",
     "pool_layout",
     "pool_shape",
+    "ring_rows",
+    "ring_positions",
+    "attn_places",
     "max_slots_in_budget",
     "write_pool_rows",
     "advance_loop",
@@ -112,9 +128,57 @@ def _elem_bytes(name: str) -> int:
     return {"float32": 4, "bfloat16": 2}.get(name, 4)
 
 
-def _pool_dims(model, slots: int, max_len: int):
-    return (len(model.layers_of("attn")), slots, max_len,
-            model.num_kv_heads, model.head_dim)
+def ring_rows(model, max_len: int, kv_dtype: str) -> Optional[int]:
+    """Positions ``R`` a slot's ring holds for each 'attn' layer that has a
+    window, or None where the model keeps no ring: it gives no window a
+    layer (``attn['windows']``; a model with the one ``attn_window`` keeps
+    ``T_max`` rows a layer), or the ring would be no shorter than ``T_max``.
+    ``R`` is the widest window, rounded up to whole key blocks of the decode
+    kernel where it is longer than one (``pallas/decode_attention.py``: 512
+    KiB of K), so that the kernel reads a ring as it reads a pool."""
+    windows = [w for w in model.windows if w is not None]
+    if not windows or not (model.attn or {}).get("windows"):
+        return None
+    from deeplearning4j_tpu.pallas.decode_attention import _BLOCK_BYTES
+
+    block = max(_BLOCK_BYTES // (model.head_dim * _elem_bytes(kv_dtype))
+                // model.num_kv_heads, 1)
+    rows = max(windows)
+    if rows > block:
+        rows = -(-rows // block) * block
+    return rows if rows < max_len else None
+
+
+def ring_positions(cursors, rows: int):
+    """``[..., R]``: the position each ring row holds once the token at
+    ``cursors [...]`` is written, ``c - ((c - r) mod R)``; negative where the
+    row has never been written. A numpy or a jax integer array."""
+    c = cursors[..., None]
+    return c - (c - np.arange(rows)) % rows
+
+
+def attn_places(model, ring: bool):
+    """Where each 'attn' layer's rows lie, in the layers' order: ``(name,
+    place)`` with ``name`` ``"ring"`` (``kw``, ``vw``) for a layer with a
+    window of a model that keeps a ring, else ``"kv"`` (``k``, ``v``), and
+    ``place`` its index among that pool's layers."""
+    seen = {"kv": 0, "ring": 0}
+    out = []
+    for i in model.layers_of("attn"):
+        name = "ring" if ring and model.windows[i] is not None else "kv"
+        out.append((name, seen[name]))
+        seen[name] += 1
+    return out
+
+
+def _pool_dims(model, slots: int, max_len: int, ring: Optional[int] = None):
+    """The logical axes of the K (or V) pool of ``T_max`` rows a layer and,
+    where ``ring`` (``ring_rows``) says the model keeps one, of the ring:
+    ``((L, S, T_max, Hkv, Dh), (L_win, S, R, Hkv, Dh) or None)``."""
+    places = [name for name, _ in attn_places(model, ring is not None)]
+    tail = (model.num_kv_heads, model.head_dim)
+    return ((places.count("kv"), slots, max_len) + tail,
+            (places.count("ring"), slots, ring) + tail if ring else None)
 
 
 def pool_shape(dims, sharded: bool = False):
@@ -147,18 +211,25 @@ def _recurrent_dims(model, kind: str):
 
 
 def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
-                sharded: bool = False) -> dict:
+                sharded: bool = False, ring: bool = True) -> dict:
     """``{kind: [(shape, dtype name), ...]}`` of every array a slot pool
     of this model holds, by what it is: ``kv`` (the K and the V pool),
-    ``latent`` (one array an ``mla`` layer),
+    ``ring`` (the K and the V ring of the layers with a window, where the
+    model keeps one: ``ring_rows``; ``ring=False``: those layers keep
+    ``T_max`` rows like the others), ``latent`` (one array an ``mla`` layer),
     ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
     ``recurrent`` and ``conv`` (one each a ``kda`` or ``gdn`` layer, in the
     layers' order). A kind the model has no layer of is an empty list.
     ``sharded``: the pool lies over a mesh (``pool_shape``)."""
-    out = {"kv": [], "latent": [], "index": [], "recurrent": [], "conv": []}
-    dims = _pool_dims(model, slots, max_len)
+    out = {"kv": [], "ring": [], "latent": [], "index": [], "recurrent": [],
+           "conv": []}
+    dims, ring_dims = _pool_dims(
+        model, slots, max_len,
+        ring_rows(model, max_len, kv_dtype) if ring else None)
     if dims[0]:
         out["kv"] += [(pool_shape(dims, sharded), kv_dtype)] * 2
+    if ring_dims:
+        out["ring"] += [(pool_shape(ring_dims, sharded), kv_dtype)] * 2
     if model.mla:
         # a multi-token-prediction module's block keeps one more layer of
         # rows, the last of the list (``TransformerLM.n_layers``)
@@ -192,14 +263,16 @@ def _layout_nbytes(arrays) -> int:
 
 
 def kv_pool_nbytes(model, slots: int, max_len: Optional[int] = None,
-                   kv_dtype: Optional[str] = None) -> int:
+                   kv_dtype: Optional[str] = None, ring: bool = True) -> int:
     """Analytic device footprint of a slot pool: the K/V pool pair, the
+    ring pair of a model that keeps one (``ring=False``: it does not), the
     latent rows, an indexer's keys and the recurrent state with
     its convolution tails, whichever the model's layers keep — the
     serving term of the HBM budget model. Matches ``SlotKVCache.nbytes``
     exactly (asserted in tests)."""
     name = resolve_kv_dtype(kv_dtype, model)
-    layout = pool_layout(model, slots, int(max_len or model.max_len), name)
+    layout = pool_layout(model, slots, int(max_len or model.max_len), name,
+                         ring=ring)
     return sum(_layout_nbytes(arrays) for arrays in layout.values())
 
 
@@ -282,7 +355,8 @@ def slot_admit(loop, at, tok, key, draft=None):
 
 
 class SlotKVCache:
-    """``[L, S, T_max, Hkv, Dh]`` K/V pools, the other layer kinds' state
+    """``[L, S, T_max, Hkv, Dh]`` K/V pools, the window layers' ``[L_win,
+    S, R, Hkv, Dh]`` rings, the other layer kinds' state
     (latent rows, index keys, recurrent matrices, convolution tails:
     ``pool_layout``)
     + the decode loop's device per-slot state (cursors, last tokens,
@@ -294,11 +368,15 @@ class SlotKVCache:
     n_shard = 1
 
     def __init__(self, model, slots: int, max_len: Optional[int] = None,
-                 kv_dtype: Optional[str] = None, registry=None):
+                 kv_dtype: Optional[str] = None, registry=None,
+                 ring: bool = True):
         """``registry=`` (a ``ShardingRegistry``) shards the pool over the
         mesh ``model`` axis with the SAME head split the attention params
         use — each TP shard holds ``Hkv/tp`` heads of every slot, so the
-        pool budget (``nbytes / n_shard``) becomes per-shard."""
+        pool budget (``nbytes / n_shard``) becomes per-shard.
+        ``ring=False``: a model that gives a window a layer keeps ``T_max``
+        rows for every layer and no ring (what the ring is compared
+        with)."""
         import jax.numpy as jnp
 
         if slots < 1:
@@ -320,18 +398,31 @@ class SlotKVCache:
                 "chip: the mesh's head split is written for a model whose "
                 "every layer keeps K/V rows, not for latent rows, an "
                 "indexer's keys or recurrent state beside them")
+        # positions a window layer's ring holds; None: the model keeps none
+        self.ring = ring_rows(model, self.max_len, self.kv_dtype) if (
+            ring) else None
+        if self.ring and registry is not None:
+            raise ValueError(
+                "a model that gives a window a layer (attn['windows']) is "
+                "served on one chip: the mesh's head split is written for "
+                "one K/V pool, not for a ring of rows beside it")
         layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype,
-                             sharded=registry is not None)
+                             sharded=registry is not None, ring=ring)
         self.latent, self.index, self.kda, self.conv = (
             [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
             for kind in ("latent", "index", "recurrent", "conv"))
         # the pools' logical axes (L, S, T_max, Hkv, Dh), whichever shape
         # they are stored in
-        self.pool_dims = _pool_dims(model, self.slots, self.max_len)
+        self.pool_dims, self.ring_dims = _pool_dims(
+            model, self.slots, self.max_len, self.ring)
         self.k = self.v = None      # no layer keeps keys and values
         if layout["kv"]:
             self.k, self.v = (jnp.zeros(shape, jnp.dtype(dt))
                               for shape, dt in layout["kv"])
+        self.kw = self.vw = None    # no layer keeps a ring
+        if layout["ring"]:
+            self.kw, self.vw = (jnp.zeros(shape, jnp.dtype(dt))
+                                for shape, dt in layout["ring"])
         # the decode loop's per-slot state, DEVICE arrays the decode
         # programs take and return advanced (not donated: the token block
         # a program returns is its ``tok``, which the host reads one step
@@ -373,10 +464,12 @@ class SlotKVCache:
     @property
     def state(self) -> dict:
         """The pool pytree a jitted program consumes (and is donated):
-        ``{k, v}`` and the other layer kinds' lists. The
+        ``{k, v}``, a ring's ``{kw, vw}`` and the other layer kinds' lists. The
         engine's programs write into these buffers and hand them back
         (``install``); once donated, the arrays returned here are dead."""
         st = {} if self.k is None else {"k": self.k, "v": self.v}
+        if self.kw is not None:
+            st.update(kw=self.kw, vw=self.vw)
         for name in ("latent", "index", "kda", "conv"):
             if getattr(self, name):
                 st[name] = list(getattr(self, name))
@@ -386,8 +479,8 @@ class SlotKVCache:
         """Install the pool state a jitted program returned: the
         buffers ``state`` donated to it, updated in place — the same
         device memory, not a copy of it."""
-        self.k = state.get("k")
-        self.v = state.get("v")
+        self.k, self.v = state.get("k"), state.get("v")
+        self.kw, self.vw = state.get("kw"), state.get("vw")
         for name in ("latent", "index", "kda", "conv"):
             setattr(self, name, list(state.get(name, ())))
 
@@ -399,13 +492,16 @@ class SlotKVCache:
 
     @property
     def nbytes_by_kind(self) -> dict:
-        """``nbytes`` apart: ``kv`` (the K/V pools), ``latent``, ``index``,
-        ``recurrent``, ``conv`` (``pool_layout``'s kinds)."""
+        """``nbytes`` apart: ``kv`` (the K/V pools), ``ring`` (the window
+        layers' rings), ``latent``, ``index``, ``recurrent``, ``conv``
+        (``pool_layout``'s kinds)."""
         kv = [a for a in (self.k, self.v) if a is not None]
         kinds = [("kv", kv), ("latent", self.latent),
                  ("recurrent", self.kda), ("conv", self.conv)]
         if self.index:      # only a model with an indexer names the kind
             kinds.insert(2, ("index", self.index))
+        if self.kw is not None:     # and only one with a ring this one
+            kinds.insert(1, ("ring", [self.kw, self.vw]))
         return {kind: sum(int(a.nbytes) for a in arrays)
                 for kind, arrays in kinds}
 

@@ -100,7 +100,11 @@ runs):
   what a read of every slot's cursor, live or frozen, would fetch —
   counted from the host's slot table, ``_book_kv_blocks``, as are
   ``kv_rows``, the K/V rows the live slots hold up to their cursors over the
-  'attn' layers, and, for a model with 'kda' or 'gdn' layers,
+  'attn' layers (for a model that gives a window a layer also apart:
+  ``kv_rows_window``, ``min(c + 1, window)`` a layer with a window, and
+  ``kv_rows_full``, ``c + 1`` a layer without; ``kv_blocks`` are then over
+  all the 'attn' layers, each pool's blocks times its layers), and, for a
+  model with 'kda' or 'gdn' layers,
   ``state_slots``, the slots whose recurrent state the step moves; for a
   model with learned sparse attention ``keys_cached``, ``keys_attended`` and
   ``rows_gathered`` instead) — the decode
@@ -134,6 +138,7 @@ from deeplearning4j_tpu.pallas.decode_attention import (
     key_block_span, pool_block_rows)
 from deeplearning4j_tpu.serving.engine import (
     DecodeEngine, prefill_block_count, unpack_routing)
+from deeplearning4j_tpu.serving.kv_cache import attn_places
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
     criticality_rank, serve_deadline_s, serve_max_queue, serve_slots)
@@ -157,7 +162,8 @@ class DecodeServer:
                  buckets: Optional[Sequence[int]] = None,
                  fuse_steps: Optional[int] = None,
                  kv_dtype: Optional[str] = None, mesh=None,
-                 clock=time.monotonic, record_routing: bool = False):
+                 clock=time.monotonic, record_routing: bool = False,
+                 ring: bool = True):
         # the drivers under benchmarks/ pass fuse_steps=1 (ROADMAP D12)
         if fuse_steps not in (None, 1):
             raise ValueError(
@@ -175,7 +181,7 @@ class DecodeServer:
         self.engine = DecodeEngine(
             model, slots if slots is not None else serve_slots(),
             max_len=max_len, temperature=temperature, top_k=top_k,
-            buckets=buckets, kv_dtype=kv_dtype, mesh=mesh)
+            buckets=buckets, kv_dtype=kv_dtype, mesh=mesh, ring=ring)
         self.model = model
         self.slots = self.engine.slots
         self.max_len = self.engine.max_len
@@ -185,16 +191,30 @@ class DecodeServer:
         self._slot_req: List[Optional[ServeRequest]] = [None] * self.slots
         # the host's copy of the device's cursors (written where the
         # device's are: admission, release, a dispatch's own advance) and
-        # the pool kernel's rows a key block — None where the pool has no
-        # kernel read (a mesh, a head size off the lanes): what
-        # ``kv_blocks`` is counted from
+        # what ``kv_rows`` and ``kv_blocks`` are counted from: the 'attn'
+        # layers in groups that read alike, ``(layers, window, positions
+        # the pool holds a slot, hkv, is it a ring, the pool kernel's rows a
+        # key block)`` — the last None where the pool has no kernel read (a
+        # mesh, a head size off the lanes). One group, unless the model
+        # gives a window a layer.
         self._cursors = np.zeros(self.slots, np.int64)
         pool = self.engine.cache
-        self._kv_block = (None if mesh is not None or pool.k is None
-                          else pool_block_rows(pool.pool_dims, pool.k.dtype))
+        groups: dict = {}
+        for (name, _), i in zip(attn_places(model, pool.ring is not None),
+                                model.layers_of("attn")):
+            dims = pool.ring_dims if name == "ring" else pool.pool_dims
+            key = (model.windows[i], dims[2], dims[3], name == "ring",
+                   None if mesh is not None
+                   else pool_block_rows(dims, pool.kv_dtype))
+            groups[key] = groups.get(key, 0) + 1
+        self._kv_reads = [(n,) + key for key, n in groups.items()]
         self.kv_blocks = 0
         self.kv_blocks_pool = 0
         self.kv_rows = 0
+        # of ``kv_rows``, the layers' with a window and the others' (a model
+        # that gives a window a layer)
+        self.kv_rows_window = 0
+        self.kv_rows_full = 0
         self.state_slots = 0
         self._recurrent = bool(model.kda or model.gdn)
         # learned sparse attention: latent rows the dispatched slots held
@@ -709,25 +729,38 @@ class DecodeServer:
             self.keys_cached += attrs["keys_cached"]
             self.keys_attended += attrs["keys_attended"]
             self.rows_gathered += attrs["rows_gathered"]
-        if live and self.engine.cache.k is not None:
+        if live and self._kv_reads:
             # a query at cursor c attends c + 1 rows (its own among them)
             # of every 'attn' layer, its window's at most
-            layers, _, t_max = self.engine.cache.pool_dims[:3]
-            held = np.minimum(self._cursors[list(live)] + 1,
-                              self.model.attn_window or t_max)
-            attrs["kv_rows"] = int(held.sum()) * layers
+            held = self._cursors[list(live)] + 1
+            rows = {True: 0, False: 0}      # {the layers have a window: rows}
+            for layers, window, t_max, *_ in self._kv_reads:
+                rows[window is not None] += int(np.minimum(
+                    held, window or t_max).sum()) * layers
+            attrs["kv_rows"] = rows[True] + rows[False]
             self.kv_rows += attrs["kv_rows"]
+            if self.model.by_layer:
+                attrs.update(kv_rows_window=rows[True],
+                             kv_rows_full=rows[False])
+                self.kv_rows_window += rows[True]
+                self.kv_rows_full += rows[False]
         if live and self._recurrent:
             attrs["state_slots"] = len(live)
             self.state_slots += len(live)
-        if live and self._kv_block is not None:
-            _, _, t_max, hkv, _ = self.engine.cache.pool_dims
-            lo, hi = key_block_span(
-                self._cursors, self._cursors, block=self._kv_block,
-                hkv=hkv, window=self.model.attn_window, t_max=t_max)
-            blocks = hi - lo + 1
-            attrs.update(kv_blocks=int(blocks[list(live)].sum()),
-                         kv_blocks_pool=int(blocks.sum()))
+        if live and self._kv_reads and all(
+                r[-1] is not None for r in self._kv_reads):
+            # one group: the blocks a layer, as every layer reads the same;
+            # a model that gives a window a layer: over all its 'attn'
+            # layers, each group's blocks times its layers
+            every = len(self._kv_reads) > 1
+            attrs.update(kv_blocks=0, kv_blocks_pool=0)
+            for layers, window, t_max, hkv, ring, block in self._kv_reads:
+                lo, hi = key_block_span(
+                    self._cursors, self._cursors, block=block, hkv=hkv,
+                    window=None if ring else window, t_max=t_max)
+                blocks = (hi - lo + 1) * (layers if every else 1)
+                attrs["kv_blocks"] += int(blocks[list(live)].sum())
+                attrs["kv_blocks_pool"] += int(blocks.sum())
             self.kv_blocks += attrs["kv_blocks"]
             self.kv_blocks_pool += attrs["kv_blocks_pool"]
             self._reg.counter("serve_decode_kv_blocks_total").inc(
@@ -1058,6 +1091,9 @@ class DecodeServer:
             # the 'attn' layers, and slots whose recurrent state a step
             # moved, summed over the decode dispatches
             "kv_rows": self.kv_rows,
+            **({"kv_rows_window": self.kv_rows_window,
+                "kv_rows_full": self.kv_rows_full}
+               if self.model.by_layer else {}),
             "state_slots": self.state_slots,
             "decode_tokens": self.decode_tokens,
             "dispatches_per_token": (

@@ -421,7 +421,7 @@ class TestRoPE:
         import jax.numpy as jnp
         from deeplearning4j_tpu.models.transformer import TransformerLM
 
-        with _pytest.raises(ValueError, match="even head_dim"):
+        with _pytest.raises(ValueError, match="even rotary_dim"):
             TransformerLM(vocab_size=16, d_model=96, num_heads=32,
                           pos_encoding="rope")
         # RoPE decodes past max_len (no position table); learned cannot

@@ -10,8 +10,10 @@ supports (depth as configured, weights random from a seed):
                mixed_bf16, Pallas flash attention: per-step and fused-K
                training steps
   kernels      every Pallas flash entry point against the XLA attention op
-               and its jax.grad, and the serving decode kernel against the
-               XLA op over the pool's slab, compiled, on the chip
+               and its jax.grad, the serving decode kernel against the XLA
+               op over the pool's slab, the routed experts against a loop
+               and the delta-rule decode step against kda_step, compiled,
+               on the chip
   train_graph  ResNet-18 (ComputationGraph) through fit_epochs
   serve        DecodeServer on the d512/L8 LM, ragged prompts, checked
                against lm.generate
@@ -167,8 +169,8 @@ def train_lm_phase(*, lm_kwargs=LM_WIDTH, batch=16, steps=3, fused_k=2,
 def kernels_phase(*, batch=4, heads=8, head_dim=64,
                   cases=((1024, None), (4096, 1024)), dtype="bfloat16",
                   decode=(8, 4096, 1024), decode_heads=((8, 2), (16, 16)),
-                  moe=(2048, 1024, 64, 8, (8, 32, 4096)), tol=2e-2,
-                  interpret=None) -> dict:
+                  moe=(2048, 1024, 64, 8, (8, 32, 4096)),
+                  delta=(16, 32, 128), tol=2e-2, interpret=None) -> dict:
     """Flash forward, dk/dv and dq against ``dot_product_attention`` and its
     ``jax.grad``, for each ``(seq_len, window)`` case. ``interpret=None``
     is the library default: compiled by Mosaic on a TPU. The reference runs
@@ -189,7 +191,10 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
     order of the sum over
     experts and in where the weighted hidden state is rounded to bf16
     (2^-9 an element), which ``tol`` holds with room and a wrong expert,
-    weight or dropped token does not."""
+    weight or dropped token does not. Last the delta-rule decode step
+    (``delta = (slots, heads, head size)``: one layer of the two hybrid
+    cells' state), a decay a head and a decay a channel, against
+    ``kda.kda_step`` in float32 (``_delta_errors``)."""
     import jax
     import jax.numpy as jnp
 
@@ -272,10 +277,46 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
                                       some == 0, some == got))), (
             f"{tag}: live rows moved or dead rows were read")
     errors.update(_moe_errors(*moe, dtype=jnp.dtype(dtype)))
+    errors.update(_delta_errors(*delta, interpret=interpret))
     bad = {k: e for k, e in errors.items() if not e <= tol}
     assert not bad, f"kernels off the XLA op beyond {tol}: {bad}"
     return {"first_call_s": first_s, "steady_s": steady_s,
             "rel_err": {k: round(e, 5) for k, e in errors.items()}}
+
+
+def _delta_errors(slots, heads, dim, *, interpret):
+    """``pallas/delta_step.py`` with every other slot owing no token, against
+    ``kda.kda_step`` with those rows' ``g`` and ``beta`` zero, both float32;
+    ``{tag: rel_err}`` over output and state together. A dead slot's state
+    (NaN here) is not read: its bits stay and its output is zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import kda
+    from deeplearning4j_tpu.pallas.delta_step import delta_step
+
+    errors = {}
+    live = jnp.arange(slots) % 2 == 0
+    for tag, width in (("delta_head", 1), ("delta_channel", dim)):
+        ks = jax.random.split(jax.random.PRNGKey(width), 6)
+        q = kda.l2norm(jax.random.normal(ks[0], (slots, heads, dim)))
+        k = kda.l2norm(jax.random.normal(ks[1], (slots, heads, dim)))
+        v = jax.random.normal(ks[2], (slots, heads, dim))
+        g = -3.0 * jax.random.uniform(ks[3], (slots, heads, width))
+        beta = jax.random.uniform(ks[4], (slots, heads))
+        state = jax.random.normal(ks[5], (slots, heads, dim, dim))
+        gm, bm = kda.mask_dead(g[:, None], beta[:, None], live[:, None])
+        want = jax.jit(kda.kda_step)(q, k, v, gm[:, 0], bm[:, 0], state)
+        nan = jnp.where(live[:, None, None, None], state, jnp.nan)
+        o, s = jax.jit(functools.partial(delta_step, interpret=interpret))(
+            q, k, v, g, beta, nan, live)
+        rows = np.asarray(live)
+        o, s = np.asarray(o), np.asarray(s)
+        assert np.isnan(s[~rows]).all() and not o[~rows].any(), (
+            f"{tag}: a dead slot's state was read or moved")
+        errors[tag] = max(_rel_err(o[rows], np.asarray(want[0])[rows]),
+                          _rel_err(s[rows], np.asarray(want[1])[rows]))
+    return errors
 
 
 def _moe_errors(d_model, d_ff, n_experts, per_token, token_counts, *, dtype):

@@ -86,11 +86,11 @@ def init_gdn(key, d_model: int, dims: Dict[str, Any], dtype
 
 def gdn_mixer(x, p: Dict[str, Any], *, dims: Dict[str, Any], eps: float,
               cast: Callable = lambda w: w, live=None,
-              state=None) -> Tuple[Any, Any, Any]:
+              state=None, kernel: bool = False) -> Tuple[Any, Any, Any]:
     """The mixer on ``x`` [b, t, D] with the block's ``gdn`` parameters
-    ``p`` (``init_gdn``); ``eps`` is the output norm's. ``live`` and
-    ``state`` = ``(S [b, Hv, dk, dv] float32, tail [b, K-1, 2 Hk dk + Hv
-    dv])`` as ``kda.kda_mixer``'s.
+    ``p`` (``init_gdn``); ``eps`` is the output norm's. ``live``,
+    ``kernel`` and ``state`` = ``(S [b, Hv, dk, dv] float32, tail [b, K-1,
+    2 Hk dk + Hv dv])`` as ``kda.kda_mixer``'s.
 
     Returns ``(y [b, t, D] in x.dtype, S, tail)``: the state and the
     convolution tail as of each row's last live position."""
@@ -135,7 +135,8 @@ def gdn_mixer(x, p: Dict[str, Any], *, dims: Dict[str, Any], eps: float,
         new_tail = kda.live_tail(rows, live, p["conv"].shape[0])
         if s0 is None:
             s0 = jnp.zeros((b, hv, dk, dk), f32)
-    o, s = kda.recur(q, k, v, g, beta, state, s0, ("gdn.step", "gdn.scan"))
+    o, s = kda.recur(q, k, v, g, beta, state, s0, ("gdn.step", "gdn.scan"),
+                     live, kernel)
     with scope("gdn.proj"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) \
             * p["o_norm"]["g"].astype(f32)

@@ -25,7 +25,13 @@ Two forms of the same recurrence:
   ``exp(G_t - G_i)`` of the cumulated log-decays themselves, never as a
   quotient ``exp(G_t) / exp(G_i)``: with ``lower = -5`` a chunk's
   cumulated decay leaves float32's range.
-- ``kda_step`` (decode): the recurrence itself, one token a row.
+- ``kda_step`` (decode): the recurrence itself, one token a row, as XLA
+  ops over every row. With one decay a head (``models/gdn.py``) a serving
+  decode step runs it as ``pallas/delta_step.py`` instead
+  (``recur(kernel=True)``): the same arithmetic over the rows that owe a
+  token only, their state moved once, in place. ``kda_step`` is the form
+  that kernel is held to, and the path where it does not apply (a pool
+  sharded over a mesh, a plain loop) or is not yet taken (``recur``).
 
 A row that holds no token (``live`` false: a prompt's pad tail, a slot
 that owes nothing) takes ``beta = 0`` and ``g = 0`` and so leaves the state
@@ -54,6 +60,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.pallas.delta_step import delta_step
 from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["CHUNK", "init_kda", "kda_mixer", "kda_scan", "kda_step",
@@ -233,15 +240,32 @@ def live_tail(rows, live, width: int):
     return jnp.take_along_axis(rows, idx[:, :, None], axis=1)
 
 
-def recur(q, k, v, g, beta, state, s0, names):
-    """``kda_step`` on a carried state and one position, else ``kda_scan``,
-    under the mixer's own scope names ``(step, scan)``. ``q, k, v, g, beta``
-    [b, t, H, .]; ``state``: what the mixer was handed; ``s0`` the matrix to
-    start from."""
+def recur(q, k, v, g, beta, state, s0, names, live=None, kernel=False):
+    """One position on a carried state, else ``kda_scan``, under the mixer's
+    own scope names ``(step, scan)``. ``q, k, v, g, beta`` [b, t, H, .]
+    (``g``, ``beta`` through ``mask_dead``); ``state``: what the mixer was
+    handed; ``s0`` the matrix to start from. The one position is
+    ``kda_step``, or with ``kernel`` and one decay a head the Pallas step
+    over the rows of ``live`` [b, 1] (``pallas/delta_step.py``, inside the
+    scope, which names its instruction in a trace). Either way a row that is
+    not live gives zeros: the kernel never reads its state.
+
+    A decay a channel (KDA) stays on ``kda_step`` for now, though the kernel
+    takes it and is as fast there (its docstring): with it
+    ``ling-serve-reason`` read ``kda_roofline`` 110 %, because that metric's
+    numerator counts the mixers' weights whose prefetch waits its time does
+    not (ROADMAP S2 (b0) item 7, PERF.md sections 6 and 7); the branch goes
+    when the reader is put right."""
     if state is not None and q.shape[1] == 1:
         with scope(names[0]):
-            o, s = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            s0)
+            rows = (a[:, 0] for a in (q, k, v, g, beta))
+            if kernel and g.shape[-1] == 1:
+                o, s = delta_step(
+                    *rows, s0, None if live is None else live[:, 0])
+            else:
+                o, s = kda_step(*rows, s0)
+                if live is not None:
+                    o = jnp.where(live[:, :, None], o, 0.0)
             return o[:, None], s
     with scope(names[1]):
         return kda_scan(q, k, v, g, beta, s0)
@@ -249,13 +273,14 @@ def recur(q, k, v, g, beta, state, s0, names):
 
 def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
               cast: Callable = lambda w: w, live=None,
-              state=None) -> Tuple[Any, Any, Any]:
+              state=None, kernel: bool = False) -> Tuple[Any, Any, Any]:
     """The mixer on ``x`` [b, t, D] with the block's ``kda`` parameters
     ``p`` (``init_kda``). ``live`` [b, t] (bool, default all) marks the
     rows that hold a token; within a row they are a prefix. ``state`` =
     ``(S [b, H, dk, dv] float32, tail [b, K-1, 3 H dk])`` is what the
     positions before ``x`` left (default: a request's start, zeros); with a
-    state and ``t == 1`` the recurrence runs as ``kda_step``.
+    state and ``t == 1`` the recurrence runs as ``kda_step``, or with
+    ``kernel`` as the Pallas step over the live rows (``recur``).
 
     Returns ``(y [b, t, D] in x.dtype, S, tail)``: the state and the
     convolution tail as of each row's last live position."""
@@ -282,7 +307,8 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
         new_tail = live_tail(rows, live, width)
         if s0 is None:
             s0 = jnp.zeros((b, h, dk, v.shape[-1]), f32)
-    o, s = recur(q, k, v, g, beta, state, s0, ("kda.step", "kda.scan"))
+    o, s = recur(q, k, v, g, beta, state, s0, ("kda.step", "kda.scan"),
+                 live, kernel)
     with scope("kda.proj"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + _EPS) \
             * p["o_norm"]["g"].astype(f32)

@@ -578,6 +578,7 @@ class TransformerLM:
                sequence_parallel: bool = False, attention=None,
                positions=None, train: bool = False, live=None,
                moe_info: Optional[list] = None, state=None,
+               state_kernel: bool = False,
                indexer=None, selection=None, layer: Optional[int] = None):
         """One pre-norm block on ``h`` [b, t, D], as the model describes
         that layer (``blk``'s own keys say which mixer and which
@@ -605,9 +606,11 @@ class TransformerLM:
         The other mixers leave other things behind. A ``kda`` or ``gdn``
         layer returns ``(h, S, tail)``, its recurrent state and convolution
         tail as of each row's last live position, and continues from
-        ``state`` = ``(S, tail)`` (default: a request's start). An ``mla``
-        layer returns ``(h, latent, None)``, each position's latent row
-        [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
+        ``state`` = ``(S, tail)`` (default: a request's start); one position
+        on a state runs as ``pallas/delta_step.py`` over the live rows with
+        ``state_kernel`` (the serving decode step; ``kda.recur`` says where),
+        else as ``kda_step``. An ``mla`` layer returns ``(h, latent, None)``,
+        each position's latent row [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
         attends a cache of such rows instead of the block's own.
 
         In a model with learned sparse attention (``dsa``) an ``mla`` layer
@@ -628,11 +631,12 @@ class TransformerLM:
                 y, k, v = kda_mod.kda_mixer(
                     x, blk["kda"], num_heads=self.num_heads,
                     lower=self.kda["lower"], cast=policy.cast_compute,
-                    live=live, state=state)
+                    live=live, state=state, kernel=state_kernel)
             elif "gdn" in blk:
                 y, k, v = gdn_mod.gdn_mixer(
                     x, blk["gdn"], dims=self.gdn, eps=self.norm_eps,
-                    cast=policy.cast_compute, live=live, state=state)
+                    cast=policy.cast_compute, live=live, state=state,
+                    kernel=state_kernel)
             else:
                 y, k, v = self._mla(blk["mla"], x, attention, positions,
                                     train, indexer, selection)

@@ -91,7 +91,8 @@ def expert_blocks(n: int, d: int, f: int, dtype) -> Optional[Tuple[int, int]]:
 
 def work_list(load):
     """``(count [1], ids [held])`` int32 from ``load [held]``: the experts
-    that received a pair, lowest id first, then zeros."""
+    that received a pair, lowest id first, then zeros. (Also the live slots
+    of ``delta_step.py``, from a bool mask.)"""
     reached = load > 0
     ids = jnp.nonzero(reached, size=load.shape[0], fill_value=0)[0]
     return (jnp.sum(reached, dtype=jnp.int32)[None], ids.astype(jnp.int32))

@@ -20,7 +20,9 @@ this). The one exception names a kernel on purpose: ``attn.window`` is opened
 round the decode kernel's call for a window layer of a model that gives a
 window a layer, so that a trace tells its ring reads from the full layers'. ``attn.core`` is opened inside the XLA attention op itself
 (``ops/attention.py``), which holds no kernel. The routed experts' kernel
-sits under ``moe.experts`` since PR 30 and is read by that name.
+sits under ``moe.experts`` since PR 30 and is read by that name, and the
+delta-rule decode step's (``pallas/delta_step.py``) under ``gdn.step``
+since PR 45 (under ``kda.step`` once ``kda.recur`` takes it there).
 
 Norms between the halves of a block stay bare on purpose: XLA fuses the next
 norm's statistics into the fusion that ends the previous matmul, and which op
@@ -61,7 +63,8 @@ SCOPES = {
     "kda.scan": "KDA's chunked recurrence (prefill, training)",
     "gdn.proj": "Gated DeltaNet projections, convolution, gates, output norm "
                 "and output projection",
-    "gdn.step": "Gated DeltaNet's one-position recurrence (decode)",
+    "gdn.step": "Gated DeltaNet's one-position recurrence (decode): the "
+                "delta-step kernel over the live slots, or kda_step",
     "gdn.scan": "Gated DeltaNet's chunked recurrence (prefill, training)",
     "mla.proj": "MLA query/latent projections, norms, RoPE, output",
     "mla.attend": "MLA attention over latent rows or expanded keys",

@@ -689,7 +689,10 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     latent rows (``_latent_attention``), a ``kda`` or ``gdn`` layer its
     recurrent matrix and convolution tail (one list, in the layers' order),
     which it takes and hands back advanced for the live slots and untouched
-    for the others. In a model with
+    for the others: where ``kda.recur`` takes ``pallas/delta_step.py`` it
+    reads and writes the live slots' matrices only, in place in the donated
+    pool (``pool_kernel`` False, a pool over a mesh: ``kda_step`` over every
+    slot's). In a model with
     learned sparse attention a layer with an indexer also meets its index
     keys, selects, and its selection goes to the layers after it
     (``_latent_layers``); ``selections`` receives each such layer's."""
@@ -713,7 +716,11 @@ def _decode_step_body(model, params, kv, tok, positions, *,
         j = seen[kind]
         seen[kind] += 1
         if kind == "kda":
-            kw = {"state": (new_kv["kda"][j], new_kv["conv"][j])}
+            # the recurrence's kernel follows the pool kernel's rule: not
+            # over a pool sharded over a mesh (``_decode_jit``); with no
+            # TPU attached it runs through the Pallas interpreter
+            kw = {"state": (new_kv["kda"][j], new_kv["conv"][j]),
+                  "state_kernel": pool_kernel is not False}
         elif kind == "mla":
             kw = dict(kw, selection=selection)
         else:
@@ -1238,8 +1245,9 @@ class DecodeEngine:
     def _decode_jit(self, impl, *bound):
         """The jitted decode-family program ``impl`` with its static
         leading arguments bound and its pool argument donated. A pool
-        sharded over a mesh keeps the XLA read: GSPMD would gather the
-        whole pool onto every chip for the kernel's custom call."""
+        sharded over a mesh keeps the XLA read, and a recurrent state over
+        one the XLA step: GSPMD would gather the whole pool onto every
+        chip for a kernel's custom call."""
         import jax
 
         kw = {} if self.mesh is None else {"pool_kernel": False}

@@ -12,8 +12,9 @@ supports (depth as configured, weights random from a seed):
   kernels      every Pallas flash entry point against the XLA attention op
                and its jax.grad, the serving decode kernel against the XLA
                op over the pool's slab, the routed experts against a loop
-               and the delta-rule decode step against kda_step, compiled,
-               on the chip
+               (and their reached form against the dense one at Mellum2's
+               widths) and the delta-rule decode step against kda_step,
+               compiled, on the chip
   train_graph  ResNet-18 (ComputationGraph) through fit_epochs
   serve        DecodeServer on the d512/L8 LM, ragged prompts, checked
                against lm.generate
@@ -169,7 +170,8 @@ def train_lm_phase(*, lm_kwargs=LM_WIDTH, batch=16, steps=3, fused_k=2,
 def kernels_phase(*, batch=4, heads=8, head_dim=64,
                   cases=((1024, None), (4096, 1024)), dtype="bfloat16",
                   decode=(8, 4096, 1024), decode_heads=((8, 2), (16, 16)),
-                  moe=(2048, 1024, 64, 8, (8, 32, 4096)),
+                  moe=(2048, 1024, 64, 8, (8, 32, 512, 4096)),
+                  reached=(2304, 896, 64, 8, 64, 12),
                   delta=(16, 32, 128), tol=2e-2, interpret=None) -> dict:
     """Flash forward, dk/dv and dq against ``dot_product_attention`` and its
     ``jax.grad``, for each ``(seq_len, window)`` case. ``interpret=None``
@@ -182,11 +184,16 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
     heads under a window; OLMoE's 16, full causal), and again with every
     other slot holding no request (``live``). Then the routed
     experts (``moe = (hidden, expert width, experts, per token, token
-    counts)``: OLMoE's widths; 8 rows, a decode step's 32 and a 4,096-token
-    prefill, so all three of ``routed_ffn``'s forms) against a masked loop over
+    counts)``: OLMoE's widths; 8 rows and a decode step's 32, a 512-token
+    prompt and a 4,096-token one, so all three of ``routed_ffn``'s forms:
+    reached, dense, sorted) against a masked loop over
     the experts, and past the dense form once more with a quarter of a
     four times wider router's experts held here, so that the sorted form
-    runs in passes (``_moe_share_error``). Both sides of that check feed
+    runs in passes (``_moe_share_error``); and the reached form against the
+    dense one (``reached = (hidden, expert width, experts, per token, rows,
+    live rows)``: a decode step of Mellum2's 64 slots with 12 live, whose
+    widths give the kernel blocks of 384 and 128 rows; ``_reached_error``).
+    Both sides of that check feed
     the MXU bf16 operands and accumulate in float32; they differ in the
     order of the sum over
     experts and in where the weighted hidden state is rounded to bf16
@@ -277,6 +284,10 @@ def kernels_phase(*, batch=4, heads=8, head_dim=64,
                                       some == 0, some == got))), (
             f"{tag}: live rows moved or dead rows were read")
     errors.update(_moe_errors(*moe, dtype=jnp.dtype(dtype)))
+    errors.update(_reached_error(
+        *reached, dtype=jnp.dtype(dtype),
+        interpret=flash_default_interpret() if interpret is None
+        else interpret))
     errors.update(_delta_errors(*delta, interpret=interpret))
     bad = {k: e for k, e in errors.items() if not e <= tol}
     assert not bad, f"kernels off the XLA op beyond {tol}: {bad}"
@@ -365,6 +376,49 @@ def _moe_errors(d_model, d_ff, n_experts, per_token, token_counts, *, dtype):
             errors[f"moe_share_n{n}"] = _moe_share_error(
                 x, p, per_token, ffn, loop)
     return errors
+
+
+def _reached_error(d_model, d_ff, n_experts, per_token, rows, n_live, *,
+                   dtype, interpret):
+    """``pallas/reached_experts.py`` on float32 matrices as stored against
+    ``_dense_experts`` on their ``dtype`` casts, ``n_live`` of ``rows`` rows
+    live: ``{tag: rel_err}``, the tag naming the kernel's blocks. A dead row
+    comes back zero and an expert no live row chose is not fetched."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.models import routed_experts
+    from deeplearning4j_tpu.pallas import reached_experts as kernel
+
+    key = jax.random.PRNGKey(d_model + rows)
+    p = jax.jit(lambda k: routed_experts.init_experts(
+        k, d_model, d_ff, n_experts, jnp.float32))(key)
+    blocks = kernel.expert_blocks(rows, d_model, d_ff, jnp.float32)
+    assert blocks is not None, (rows, d_model, d_ff)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (rows, d_model),
+                          jnp.float32).astype(dtype)
+    live = np.zeros((rows,), bool)
+    live[np.random.default_rng(rows).permutation(rows)[:n_live]] = True
+    live = jnp.asarray(live)
+
+    @jax.jit
+    def both(x, p, live):
+        w, e = routed_experts.route(x, p["router"], per_token)
+        w = jnp.where(live[:, None], w, 0.0)
+        load = routed_experts._load(live, e, n_experts)
+        mats = (p["w_gate"], p["w_up"], p["w_down"])
+        dense = routed_experts._dense_experts(
+            x, w, e, *(m.astype(dtype) for m in mats))
+        got = kernel.reached_experts(
+            x, routed_experts._combine(w, e, n_experts), load, *mats,
+            blocks=blocks, interpret=interpret)
+        return got, dense, load
+
+    got, dense, load = both(x, p, live)
+    assert 0 < int(jnp.sum(load > 0)) < n_experts, "nothing to skip"
+    assert not bool(jnp.any(got[~live])), "a dead row is not zero"
+    return {f"reached_n{rows}_b{blocks[0]}x{blocks[1]}":
+            _rel_err(got, dense)}
 
 
 def _moe_share_error(x, p, per_token, ffn, loop, share=4, keep=64):

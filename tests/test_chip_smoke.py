@@ -62,12 +62,13 @@ def test_kernels_tiny():
     r = chip_smoke.kernels_phase(
         batch=1, heads=2, head_dim=64, cases=((128, None), (256, 128)),
         decode=(3, 64, 24), moe=(64, 32, 8, 2, (8, 1032)),
-        delta=(3, 8, 16), interpret=True)
+        reached=(384, 128, 8, 2, 16, 3), delta=(3, 8, 16), interpret=True)
     assert set(r["rel_err"]) == {
         f"{case}_{k}" for case in ("t128", "t256_w128")
         for k in ("fwd", "dq", "dk", "dv")} | {
             "decode_t64_kv2_w24", "decode_t64_kv16", "moe_n8", "moe_n1032",
-            "moe_share_n1032", "delta_head", "delta_channel"}
+            "moe_share_n1032", "reached_n16_b384x128", "delta_head",
+            "delta_channel"}
 
 
 def test_kernels_tolerance_is_enforced():
@@ -75,7 +76,9 @@ def test_kernels_tolerance_is_enforced():
         chip_smoke.kernels_phase(batch=1, heads=1, head_dim=64,
                                  cases=((128, None),), decode=(2, 32, 32),
                                  decode_heads=((8, 2),),
-                                 moe=(64, 32, 8, 2, (8,)), delta=(2, 2, 8),
+                                 moe=(64, 32, 8, 2, (8,)),
+                                 reached=(128, 128, 8, 2, 16, 3),
+                                 delta=(2, 2, 8),
                                  interpret=True, tol=0.0)
 
 

@@ -425,9 +425,8 @@ def test_stats_report_state_bytes_and_the_share():
 # ---- the reached form of the experts (pallas/reached_experts.py) ------------
 def _lane_wide(policy):
     """The small model with hidden and expert widths of one lane tile,
-    which the kernel's blocks need; 3 slots x 4 a token over 16 experts is
-    under two pairs an expert, so the decode step qualifies and the
-    16-token prefill rung does not."""
+    which the kernel's blocks need: the decode step of 3 slots and the
+    prefill rungs of 16 to 64 rows are all under ``REACHED_MAX_ROWS``."""
     return _lm(policy, d_model=128, d_ff=128)
 
 
@@ -446,8 +445,9 @@ def test_served_tokens_are_the_same_in_the_reached_form(policy, monkeypatch):
 
 def test_experts_read_says_which_form_a_program_took(monkeypatch):
     """``experts_read`` on the span and in ``stats()``: the cells touched
-    where the reached form was traced (the decode step, with the kernel
-    allowed), layers x held where not (the prefill rungs; every program on
+    where the reached form was traced (with the kernel allowed, the traces
+    of no more rows than the bound: here the decode step), layers x held
+    where not (the prefill rungs above a bound of 8 rows; every program on
     the CPU default)."""
     from deeplearning4j_tpu.monitor.trace import tracer
 
@@ -466,6 +466,7 @@ def test_experts_read_says_which_form_a_program_took(monkeypatch):
     tracer().clear()
     monkeypatch.setattr(routed_experts, "_kernel_backend",
                         lambda: "interpret")
+    monkeypatch.setattr(routed_experts, "REACHED_MAX_ROWS", 8)
     server, _ = _served(_lane_wide("float32"), lengths)
     by_name = {name: [s for s in spans(server) if s.name == name]
                for name in ("serve.decode", "serve.prefill")}

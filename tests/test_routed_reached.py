@@ -5,6 +5,8 @@ row) — on the CPU through the Pallas interpreter. The two differ in the
 order of the float32 sum over experts only.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,6 +129,7 @@ def test_work_list_holds_each_reached_expert_once(load):
     (64, 2560, 768, "float32", True),       # Ling's decode step
     (64, 2560, 768, "bfloat16", True),
     (8, 2048, 1024, "float32", True),       # OLMoE's widths, 8 rows
+    (64, 2304, 896, "float32", True),       # Mellum2's decode step
     (4, 64, 32, "float32", False),          # widths off the lane tile
     (512, 2560, 768, "float32", False)])    # rows that do not fit VMEM
 def test_blocks_fit_the_widths_or_the_kernel_does_not_apply(n, d, f, store,
@@ -141,10 +144,10 @@ def test_blocks_fit_the_widths_or_the_kernel_does_not_apply(n, d, f, store,
 
 
 # ---------------------------------------------------------------------------
-# the choice: shapes, ``train`` and the backend, at trace time
+# the choice: the rows of the trace, ``train`` and the backend
 # ---------------------------------------------------------------------------
 def _trace(n, k, e, held, *, d=256, f=128, train=False, backend="interpret",
-           monkeypatch=None):
+           groups=None, monkeypatch=None):
     """The jaxpr of ``routed_ffn`` at these shapes, nothing computed."""
     if backend is not None:
         monkeypatch.setattr(routed_experts, "_kernel_backend",
@@ -158,49 +161,129 @@ def _trace(n, k, e, held, *, d=256, f=128, train=False, backend="interpret",
     return str(jax.make_jaxpr(
         lambda x, p: routed_experts.routed_ffn(
             x, p, experts_per_token=k, cast=lambda w: w.astype(x.dtype),
-            groups=(8, 4, 2.5) if e == 512 else None, train=train)[0])(x, p))
+            groups=groups, train=train)[0])(x, p))
 
 
 def _dense_product(held, n, f):
     return f"f32[{held},{n},{f}]"
 
 
-def test_lings_decode_step_takes_the_reached_form(monkeypatch):
-    text = _trace(64, 8, 512, 64, monkeypatch=monkeypatch)
-    assert "pallas_call" in text
-    assert _dense_product(64, 64, 128) not in text
-    # the matrices go to the kernel as stored: no compute-dtype copy
-    assert "bf16[64,256,128]" not in text
+LING = dict(n=64, k=8, e=512, held=64, groups=(8, 4, 2.5))
 
 
 @pytest.mark.parametrize("why,kw", [
-    ("olmoe_decode_4_pairs_an_expert", dict(n=32, k=8, e=64, held=64)),
-    ("olmoe_smallest_prefill_rung_2_pairs", dict(n=16, k=8, e=64, held=64)),
-    ("a_trace_that_takes_a_gradient",
-     dict(n=64, k=8, e=512, held=64, train=True)),
-    ("no_mosaic_backend", dict(n=64, k=8, e=512, held=64, backend=None)),
-    ("widths_off_the_lane_tile",
-     dict(n=64, k=8, e=512, held=64, d=64, f=32))])
+    ("lings_decode_step", LING),
+    ("olmoes_decode_step_32_slots", dict(n=32, k=8, e=64, held=64)),
+    ("mellum2s_decode_step_64_slots", dict(n=64, k=8, e=64, held=64)),
+    ("olmoes_smallest_prefill_rung", dict(n=16, k=8, e=64, held=64))])
+def test_a_trace_the_kernel_holds_takes_the_reached_form(why, kw,
+                                                         monkeypatch):
+    text = _trace(monkeypatch=monkeypatch, **kw)
+    assert text.count("pallas_call") == 1           # one a layer
+    assert _dense_product(kw["held"], kw["n"], 128) not in text
+    # the matrices go to the kernel as stored: no compute-dtype copy
+    assert f"bf16[{kw['held']},256,128]" not in text
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("rows_over_the_bound",
+     dict(n=2 * routed_experts.REACHED_MAX_ROWS, k=8, e=64, held=64)),
+    ("rows_over_the_bound_of_a_share",
+     dict(LING, n=2 * routed_experts.REACHED_MAX_ROWS)),
+    ("a_trace_that_takes_a_gradient", dict(LING, train=True)),
+    ("no_mosaic_backend", dict(LING, backend=None)),
+    ("widths_off_the_lane_tile", dict(LING, d=64, f=32))])
 def test_every_other_trace_is_the_one_it_was(why, kw, monkeypatch):
     text = _trace(monkeypatch=monkeypatch, **kw)
     assert "pallas_call" not in text
     n, held, f = kw["n"], kw["held"], kw.get("f", 128)
     assert _dense_product(held, n, f) in text
     # and it is the dense form's own jaxpr, with the kernel out of reach
-    monkeypatch.setattr(routed_experts, "REACHED_MAX_PAIRS_PER_EXPERT", 0)
-    assert text == _trace(monkeypatch=monkeypatch, **kw)
+    assert text == _trace(monkeypatch=monkeypatch, **dict(kw, backend=None))
 
 
 def test_the_cpu_default_keeps_the_dense_form():
     assert routed_experts._kernel_backend() is None
-    text = _trace(64, 8, 512, 64, backend=None)
+    text = _trace(**dict(LING, backend=None))
     assert "pallas_call" not in text and _dense_product(64, 64, 128) in text
 
 
-def test_the_threshold_sits_between_the_two_cells():
-    ling, olmoe = 64 * 8 / 512, 32 * 8 / 64
-    assert ling < routed_experts.REACHED_MAX_PAIRS_PER_EXPERT <= 16 * 8 / 64
-    assert routed_experts.REACHED_MAX_PAIRS_PER_EXPERT < olmoe
+def test_the_bound_is_in_rows_and_covers_every_decode_program():
+    """The rule reads the rows of the trace: both cells whose slots' pairs
+    an expert (4.0, 8.0) kept them on the dense form are under it, and it
+    asks the kernel for no more rows than its VMEM holds."""
+    olmoe_slots, mellum2_slots = 32, 64
+    assert max(olmoe_slots, mellum2_slots) <= routed_experts.REACHED_MAX_ROWS
+    assert routed_experts.REACHED_MAX_ROWS <= kernel._MAX_ROWS
+    assert kernel.expert_blocks(routed_experts.REACHED_MAX_ROWS, 2304, 896,
+                                "float32") == (384, 128)
+
+
+def _rule_before_pr46(x, w_gate, train, *, k, num_experts):
+    """``N k / E < 2``, the slots' pairs an expert: what chose the form
+    until PR 46."""
+    n = x.shape[0]
+    if (train or n * k >= 2 * num_experts
+            or routed_experts._kernel_backend() is None):
+        return None
+    return kernel.expert_blocks(n, x.shape[1], w_gate.shape[2], w_gate.dtype)
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("ling_64_slots_8_of_512_64_held", LING),
+    ("qwen3next_64_slots_10_of_512_128_held",
+     dict(n=64, k=10, e=512, held=128)),
+    ("glm_16_slots_8_of_256_8_held",
+     dict(n=16, k=8, e=256, held=8, groups=(1, 1, 2.5))),
+    ("gigachat_a_round_of_16_slots_8_of_256_8_held",
+     dict(n=32, k=8, e=256, held=8, groups=(8, 4, 2.5)))])
+def test_the_share_cells_decode_programs_did_not_move(why, kw, monkeypatch):
+    """The four cells that were under the old bound already: their decode
+    shapes trace to the same jaxpr under the old rule and the new."""
+    new = _trace(monkeypatch=monkeypatch, **kw)
+    assert new.count("pallas_call") == 1
+    monkeypatch.setattr(
+        routed_experts, "_reached_blocks",
+        functools.partial(_rule_before_pr46, k=kw["k"], num_experts=kw["e"]))
+    assert new == _trace(monkeypatch=monkeypatch, **kw)
+
+
+@pytest.mark.parametrize("store", ["float32", "bfloat16"])
+def test_mellum2s_decode_step_is_the_same_rows_in_either_form(store,
+                                                              monkeypatch):
+    """64 slots, 8 of 64 experts, 12 slots live, no shared expert: the form
+    the decode program had (dense) against the one it takes now."""
+    n, e, k, n_live = 64, 64, 8, 12
+    p = routed_experts.init_experts(jax.random.PRNGKey(6), D, F, e,
+                                    jnp.float32)
+    p = {name: (v.astype(store) if name.startswith("w_") else v)
+         for name, v in p.items()}
+    x = _rows(seed=7, n=n)
+    live = np.zeros(n, bool)
+    live[np.random.default_rng(8).permutation(n)[:n_live]] = True
+    live = jnp.asarray(live)
+    run = lambda: routed_experts.routed_ffn(
+        x, p, experts_per_token=k, cast=lambda w: w.astype(x.dtype),
+        live=live)
+    y_dense, info_dense = run()
+    monkeypatch.setattr(routed_experts, "_kernel_backend",
+                        lambda: "interpret")
+    y, info = run()
+    assert int(info_dense["read"]) == e
+    assert int(info["read"]) == int((info["load"] > 0).sum()) < e
+    assert int(info["load"].sum()) == n_live * k
+    np.testing.assert_array_equal(info["experts"], info_dense["experts"])
+    np.testing.assert_allclose(
+        y.astype(jnp.float32), y_dense.astype(jnp.float32), rtol=0,
+        atol=2.0 ** -7 * float(jnp.max(jnp.abs(y_dense.astype(jnp.float32)))))
+    assert not np.asarray(y.astype(jnp.float32))[~np.asarray(live)].any()
+    # and the kernel's float32 sum before the output is rounded
+    dense32, reached32, _ = _both(
+        x, info["experts"], info["weights"], live,
+        (p["w_gate"], p["w_up"], p["w_down"]), 0)
+    np.testing.assert_allclose(
+        reached32, dense32, rtol=0,
+        atol=TOL * float(jnp.max(jnp.abs(dense32))))
 
 
 @pytest.mark.parametrize("store", ["float32", "bfloat16"])

@@ -213,7 +213,8 @@ def _jaxpr(n, k, e, held, *, train=False, backend=None, groups=None,
                                      groups=(8, 4, 2.5))),
     ("gigachat_rung_1024_dense", dict(n=1024, k=8, e=256, held=8,
                                       groups=(8, 4, 2.5))),
-    ("olmoe_decode_dense", dict(n=32, k=8, e=64, held=64, backend="mosaic")),
+    ("olmoe_decode_reached", dict(n=32, k=8, e=64, held=64,
+                                  backend="mosaic")),
     ("ling_decode_reached", dict(n=64, k=8, e=512, held=64,
                                  groups=(8, 4, 2.5), backend="mosaic")),
     ("glm_decode_reached", dict(n=16, k=8, e=256, held=8,

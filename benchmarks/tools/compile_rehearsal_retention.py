@@ -1,0 +1,106 @@
+"""``compile_rehearsal.py`` for the power-retention cell
+(``brumby-serve-continue``): its decode program and its prefill rungs at the
+timed sizes, compiled for a described ``v5e:2x2``, with ``memory_analysis()``.
+Nothing runs and nothing here is a chip number.
+
+    JAX_PLATFORMS=cpu python3 benchmarks/tools/compile_rehearsal_retention.py [decode] [prefill] [--rungs 28672,8192,256] [--slots 32]
+
+(run from ``benchmarks/tools/``.) The report, the abstract arguments and the
+switch that puts the kernels on their Mosaic path are ``compile_rehearsal``'s,
+by import.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+
+from compile_rehearsal import (  # noqa: F401  (sets the environment first)
+    _abstract, _force_mosaic, _load, _report)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+
+def _model(one_chip, slots):
+    from benchmarks.drivers import lm_serve_retention as drv
+    from deeplearning4j_tpu.serving import kv_cache
+
+    cell = _load("benchmarks/workloads/brumby-serve-continue.json")
+    cfg = _load("benchmarks/configs/brumby-14b-l4.json")
+    sv = dict(cell["server"], slots=slots or cell["server"]["slots"])
+    lm = drv.build_lm(cfg, policy=sv["policy"], seed=0,
+                      max_len=int(sv["max_len"]))
+    shapes = jax.eval_shape(
+        lambda: type(lm)(**lm.get_config()).init().params)
+    layout = kv_cache.pool_layout(lm, int(sv["slots"]), int(sv["max_len"]),
+                                  "bfloat16")
+    kv = {name: [jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                      sharding=one_chip)
+                 for shape, dt in layout[kind]]
+          for name, kind in (("kda", "recurrent"), ("norm", "normaliser"))}
+    total = sum(x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(shapes))
+    print(f"  weights {total / 2 ** 30:.2f} GiB; state by kind (GiB): "
+          + ", ".join(f"{k} {kv_cache._layout_nbytes(v) / 2 ** 30:.2f}"
+                      for k, v in layout.items() if v), flush=True)
+    return lm, sv, _abstract(shapes, one_chip), kv
+
+
+def decode(one_chip, slots):
+    import deeplearning4j_tpu.serving.engine as eng
+
+    lm, sv, params, kv = _model(one_chip, slots)
+    slots = int(sv["slots"])
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    loop = {"cursors": vec, "tok": vec, "remaining": vec,
+            "keys": jax.ShapeDtypeStruct((slots, 2), jnp.uint32,
+                                         sharding=one_chip)}
+    fn = jax.jit(functools.partial(
+        eng._serve_decode_loop_impl, lm, eng._row_sampler(0.0, None)),
+        donate_argnums=(1,))
+    return _report(f"retention decode {slots} slots x {sv['max_len']}",
+                   lambda: fn.lower(params, kv, loop).compile())
+
+
+def prefill(one_chip, rungs, slots):
+    import deeplearning4j_tpu.serving.engine as eng
+
+    lm, sv, params, kv = _model(one_chip, slots)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    for p in rungs:
+        prompt = jax.ShapeDtypeStruct((1, p), jnp.int32, sharding=one_chip)
+        fn = jax.jit(functools.partial(
+            eng._serve_prefill_impl, lm, eng._row_sampler(0.0, None), False),
+            donate_argnums=(1,))
+        _report(f"retention prefill rung {p} into {sv['slots']} slots",
+                lambda: fn.lower(params, kv, prompt, scalar, scalar,
+                                 key).compile())
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("programs", nargs="*", default=["decode", "prefill"])
+    ap.add_argument("--rungs", default="28672,8192,256")
+    ap.add_argument("--slots", type=int, default=0)
+    args = ap.parse_args()
+
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    _force_mosaic()     # the step kernel asks pallas.flash_attention too
+    print("compile rehearsal (retention) for a described v5e:2x2 -- nothing "
+          "runs, none of this is a chip number", flush=True)
+    if "decode" in args.programs:
+        decode(one_chip, args.slots)
+    if "prefill" in args.programs:
+        prefill(one_chip, [int(s) for s in args.rungs.split(",")],
+                args.slots)
+
+
+if __name__ == "__main__":
+    main()

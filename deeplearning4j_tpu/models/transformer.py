@@ -34,6 +34,7 @@ from deeplearning4j_tpu.models import dsa as dsa_mod
 from deeplearning4j_tpu.models import gdn as gdn_mod
 from deeplearning4j_tpu.models import kda as kda_mod
 from deeplearning4j_tpu.models import mla as mla_mod
+from deeplearning4j_tpu.models import ret as ret_mod
 from deeplearning4j_tpu.models import routed_experts
 from deeplearning4j_tpu.monitor import tracer
 from deeplearning4j_tpu.ops.attention import (
@@ -180,7 +181,8 @@ class TransformerLM:
                  rope_scaling: Optional[Dict[str, Any]] = None,
                  mtp: Optional[Dict[str, Any]] = None,
                  gdn: Optional[Dict[str, Any]] = None,
-                 attn: Optional[Dict[str, Any]] = None):
+                 attn: Optional[Dict[str, Any]] = None,
+                 ret: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -258,7 +260,13 @@ class TransformerLM:
         # without ``n_group`` keeps the softmax router over ``num_experts``
         # and names the share alone ({first, held}); ``shared_gate``: the
         # shared expert's output times sigmoid(x . w), one number a token.
-        kinds = ("attn", "kda", "mla", "gdn"), ("mlp", "glu", "moe")
+        # mixers[i] may also be "ret" (power retention, models/ret.py; ``ret``
+        # = {power}: ``num_heads`` queries over ``num_kv_heads`` key/value
+        # heads of ``head_dim``, per-head RMSNorm and the model's RoPE on q
+        # and k, a gate a key/value head; a [Hkv, D, dh] float32 state and
+        # its normaliser [Hkv, dh, dh] instead of rows a position; degree 2
+        # is the one written).
+        kinds = ("attn", "kda", "mla", "gdn", "ret"), ("mlp", "glu", "moe")
         self.mixers = tuple(mixers) if mixers is not None else (
             "attn",) * num_layers
         self.ffns = tuple(ffns) if ffns is not None else (
@@ -271,11 +279,13 @@ class TransformerLM:
         for kind, sizes, used in (("kda", kda, self.mixers),
                                   ("mla", mla, self.mixers),
                                   ("gdn", gdn, self.mixers),
+                                  ("ret", ret, self.mixers),
                                   ("glu", glu_width, self.ffns),
                                   ("moe", num_experts, self.ffns)):
             if kind in used and not sizes:
                 raise ValueError(f"a {kind!r} layer needs its sizes (kda=, "
-                                 "mla=, gdn=, glu_width=, num_experts=)")
+                                 "mla=, gdn=, ret=, glu_width=, "
+                                 "num_experts=)")
         self.indexers = tuple(indexers) if indexers is not None else (
             None,) * num_layers
         if len(self.indexers) != num_layers or dsa is None and any(
@@ -302,6 +312,13 @@ class TransformerLM:
             raise ValueError(
                 f"gdn: value_heads={self.gdn['value_heads']} must be a "
                 f"multiple of key_heads={self.gdn['key_heads']}")
+        self.ret = dict(ret) if ret else None
+        if self.ret and (self.ret.get("power", 2) != 2
+                         or pos_encoding != "rope"):
+            raise ValueError(
+                f"ret={ret!r} with pos_encoding={pos_encoding!r}: power "
+                "retention is written for degree 2 (power=2: the state is "
+                "the symmetric square of a key) and takes the model's RoPE")
         self.attn = dict(attn) if attn else None
         self.head_dim = int((attn or {}).get("head_dim",
                                              d_model // num_heads))
@@ -470,6 +487,9 @@ class TransformerLM:
                     self.kda["conv"], dt)
             elif self.mixers[i] == "gdn":
                 blk["gdn"] = gdn_mod.init_gdn(k[0], D, self.gdn, dt)
+            elif self.mixers[i] == "ret":
+                blk["ret"] = ret_mod.init_ret(
+                    k[0], D, self.num_heads, self.num_kv_heads, Dh, dt)
             elif self.mixers[i] == "mla":
                 blk["mla"] = mla_mod.init_mla(k[0], D, self.num_heads,
                                               self.mla, dt)
@@ -609,7 +629,12 @@ class TransformerLM:
         ``state`` = ``(S, tail)`` (default: a request's start); one position
         on a state runs as ``pallas/delta_step.py`` over the live rows with
         ``state_kernel`` (the serving decode step; ``kda.recur`` says where),
-        else as ``kda_step``. An ``mla`` layer returns ``(h, latent, None)``,
+        else as ``kda_step``. A ``ret`` layer (power retention,
+        ``models/ret.py``) returns ``(h, S, Z)``, its state and normaliser,
+        continues from ``state`` = ``(S, Z)`` and, alone among the recurrent
+        mixers, takes ``positions`` (RoPE on q and k); its one position on a
+        state is ``pallas/retention_step.py`` with ``state_kernel``, else
+        ``ret_step``. An ``mla`` layer returns ``(h, latent, None)``,
         each position's latent row [b, t, r + dr]; its ``attention(q_nope, q_rope, latent) -> o``
         attends a cache of such rows instead of the block's own.
 
@@ -623,7 +648,7 @@ class TransformerLM:
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
         x = self._norm(h, blk["ln1"])
-        if "kda" in blk or "mla" in blk or "gdn" in blk:
+        if "kda" in blk or "mla" in blk or "gdn" in blk or "ret" in blk:
             if sequence_parallel:
                 raise NotImplementedError(
                     "sequence parallelism is written for 'attn' layers only")
@@ -637,6 +662,16 @@ class TransformerLM:
                     x, blk["gdn"], dims=self.gdn, eps=self.norm_eps,
                     cast=policy.cast_compute, live=live, state=state,
                     kernel=state_kernel)
+            elif "ret" in blk:
+                # the first recurrent mixer that takes positions: RoPE
+                y, k, v = ret_mod.ret_mixer(
+                    x, blk["ret"], heads=self.num_heads,
+                    kv_heads=self.num_kv_heads,
+                    rope=lambda a, at: self._rope_head(a, at, layer),
+                    rmsnorm=lambda a, g: _rmsnorm(a, g, self.norm_eps),
+                    positions=jnp.arange(t) if positions is None
+                    else positions, cast=policy.cast_compute, live=live,
+                    state=state, kernel=state_kernel)
             else:
                 y, k, v = self._mla(blk["mla"], x, attention, positions,
                                     train, indexer, selection)
@@ -1140,6 +1175,7 @@ class TransformerLM:
             "moe": self.moe, "indexers": list(self.indexers),
             "dsa": self.dsa, "rope_scaling": self.rope_scaling,
             "mtp": self.mtp, "gdn": self.gdn, "attn": self.attn,
+            "ret": self.ret,
         }
 
     def _ensure_init(self):
@@ -1187,10 +1223,11 @@ class TransformerLM:
         """One parallel forward over the prompt capturing per-layer K/V.
         Returns ``(h_last [b, D], cache)`` with cache entries padded out
         to ``prompt_len + max_new_tokens`` positions."""
-        if self.hybrid:
+        if set(self.mixers) - {"attn", "ret"}:
             raise NotImplementedError(
-                "generate() and generate_beam() carry key/value caches only: "
-                "a 'kda' or 'gdn' layer's recurrent state, an 'mla' layer's "
+                "generate() and generate_beam() carry key/value caches and a "
+                "'ret' layer's state only: a 'kda' or 'gdn' layer's "
+                "recurrent state and convolution tail, an 'mla' layer's "
                 "latent rows and an indexer's keys are held by "
                 "serving.DecodeServer's slot cache")
         policy = self.policy
@@ -1205,6 +1242,9 @@ class TransformerLM:
         pad_t = ((0, 0), (0, max_new_tokens), (0, 0), (0, 0))
         for i, blk in enumerate(params["blocks"]):
             h, kk, vv = self._block(blk, h, layer=i)
+            if "ret" in blk:    # no rows a position: the state and its norm
+                cache.append({"s": kk, "z": vv})
+                continue
             cache.append({"k": jnp.pad(kk.astype(cdt), pad_t),
                           "v": jnp.pad(vv.astype(cdt), pad_t)})
         return h[:, -1], cache
@@ -1243,6 +1283,11 @@ class TransformerLM:
             return attn
 
         for i, (blk, c) in enumerate(zip(params["blocks"], cache)):
+            if "ret" in blk:
+                h, s, z = self._block(blk, h, state=(c["s"], c["z"]),
+                                      positions=jnp.asarray(t)[None], layer=i)
+                new_cache.append({"s": s, "z": z})
+                continue
             h, _, _ = self._block(
                 blk, h, attention=cached_attention(c, self.windows[i]),
                 positions=jnp.asarray(t)[None], layer=i)
@@ -1341,8 +1386,8 @@ class TransformerLM:
             scores, tok0 = lax.top_k(logp0, K)              # [b, K]
             tok0 = tok0.astype(jnp.int32)
             # beams ride the batch dim, batch-major: row = batch*K + beam
-            cache = [{"k": jnp.repeat(c["k"], K, axis=0),
-                      "v": jnp.repeat(c["v"], K, axis=0)} for c in cache]
+            cache = jax.tree_util.tree_map(
+                lambda a: jnp.repeat(a, K, axis=0), cache)
             seqs = jnp.zeros((b, K, max_new_tokens), jnp.int32)
             seqs = lax.dynamic_update_slice(
                 seqs, tok0[:, :, None], (0, 0, 0))
@@ -1358,8 +1403,7 @@ class TransformerLM:
                 parent = idx // V                            # [b, K]
                 tok = (idx % V).astype(jnp.int32)
                 rows = (jnp.arange(b)[:, None] * K + parent).reshape(-1)
-                cache = [{"k": c["k"][rows], "v": c["v"][rows]}
-                         for c in cache]
+                cache = jax.tree_util.tree_map(lambda a: a[rows], cache)
                 seqs = jnp.take_along_axis(seqs, parent[..., None], axis=1)
                 seqs = lax.dynamic_update_slice(
                     seqs, tok[:, :, None], (0, 0, i))
@@ -1469,7 +1513,7 @@ class TransformerLM:
                     blk["attn"]["q_norm"] = {"g": P()}
                     blk["attn"]["k_norm"] = {"g": P()}
             elif mixer == "kda":
-                # no Megatron split is written for the three other mixers:
+                # no Megatron split is written for the four other mixers:
                 # every chip holds them whole
                 blk["kda"] = {n: P() for n in (
                     "wq", "wk", "wv", "wa", "wb", "wg", "wo", "conv_q",
@@ -1479,6 +1523,11 @@ class TransformerLM:
                 blk["gdn"] = {n: P() for n in (
                     "w_qkvz", "w_ba", "wo", "conv", "a_log", "dt_bias")}
                 blk["gdn"]["o_norm"] = {"g": P()}
+            elif mixer == "ret":
+                blk["ret"] = {n: P() for n in (
+                    "wq", "wk", "wv", "wo", "wg", "bg")}
+                blk["ret"]["q_norm"] = {"g": P()}
+                blk["ret"]["k_norm"] = {"g": P()}
             else:
                 names = ["wdkv", "wukv", "wo"] + (
                     ["wq_a", "wq_b"] if self.mla.get("q_lora_rank")

@@ -22,7 +22,8 @@ window a layer, so that a trace tells its ring reads from the full layers'. ``at
 (``ops/attention.py``), which holds no kernel. The routed experts' kernel
 sits under ``moe.experts`` since PR 30 and is read by that name, and the
 delta-rule decode step's (``pallas/delta_step.py``) under ``gdn.step``
-since PR 45 (under ``kda.step`` once ``kda.recur`` takes it there).
+since PR 45 (under ``kda.step`` once ``kda.recur`` takes it there), power
+retention's (``pallas/retention_step.py``) under ``ret.step``.
 
 Norms between the halves of a block stay bare on purpose: XLA fuses the next
 norm's statistics into the fusion that ends the previous matmul, and which op
@@ -66,6 +67,11 @@ SCOPES = {
     "gdn.step": "Gated DeltaNet's one-position recurrence (decode): the "
                 "delta-step kernel over the live slots, or kda_step",
     "gdn.scan": "Gated DeltaNet's chunked recurrence (prefill, training)",
+    "ret.proj": "power retention's projections, head norms, RoPE, gate and "
+                "output projection",
+    "ret.step": "power retention's one-position recurrence (decode): the "
+                "retention-step kernel over the live slots, or ret_step",
+    "ret.scan": "power retention's chunked recurrence (prefill, training)",
     "mla.proj": "MLA query/latent projections, norms, RoPE, output",
     "mla.attend": "MLA attention over latent rows or expanded keys",
     "dsa.index": "the lightning indexer: index keys, scores, selection",

@@ -167,7 +167,8 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
             h = h + params["pos"][:p][None]
         h = policy.cast_compute(h)
     ks, vs = [], []
-    left = {"latent": [], "kda": [], "conv": []}    # the other layer kinds
+    # the other layer kinds
+    left = {"latent": [], "kda": [], "conv": [], "norm": []}
     # the pad tail holds no token: it takes no part in routed experts and
     # moves no recurrent state (a 'kda' or 'gdn' layer's state is as of
     # prompt_len)
@@ -180,12 +181,16 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         if "kda" in blk or "gdn" in blk:
             left["kda"].append(kk)
             left["conv"].append(vv)
+        elif "ret" in blk:
+            left["kda"].append(kk)
+            left["norm"].append(vv)
         elif "mla" in blk:
             left["latent"].append(kk)
         else:
             ks.append(kk.astype(cdt))
             vs.append(vv.astype(cdt))
-    # a 'kda' or 'gdn' layer's [1, H, dk, dk] state and [1, K-1, C] tail and
+    # a 'kda' or 'gdn' layer's [1, H, dk, dk] state and [1, K-1, C] tail, a
+    # 'ret' layer's [1, Hkv, D, dh] state and [1, Hkv, dh, dh] normaliser and
     # an 'mla' layer's [1, P, r + dr] rows, each into the slot's place in its
     # layer's own array: the slot's old state is overwritten whole
     def into(pool, new):
@@ -687,12 +692,14 @@ def _decode_step_body(model, params, kv, tok, positions, *,
     Each layer meets its own kind of state, at its place among the layers
     of its kind: an ``attn`` layer the K/V pools, an ``mla`` layer its
     latent rows (``_latent_attention``), a ``kda`` or ``gdn`` layer its
-    recurrent matrix and convolution tail (one list, in the layers' order),
-    which it takes and hands back advanced for the live slots and untouched
-    for the others: where ``kda.recur`` takes ``pallas/delta_step.py`` it
-    reads and writes the live slots' matrices only, in place in the donated
-    pool (``pool_kernel`` False, a pool over a mesh: ``kda_step`` over every
-    slot's). In a model with
+    recurrent matrix and convolution tail (one list each, in the layers'
+    order), which it takes and hands back advanced for the live slots and
+    untouched for the others: where ``kda.recur`` takes
+    ``pallas/delta_step.py`` it reads and writes the live slots' matrices
+    only, in place in the donated pool (``pool_kernel`` False, a pool over a
+    mesh: ``kda_step`` over every slot's); a ``ret`` layer its state (in the
+    recurrent matrices' list) and its normaliser (a list of their own)
+    likewise, through ``pallas/retention_step.py``. In a model with
     learned sparse attention a layer with an indexer also meets its index
     keys, selects, and its selection goes to the layers after it
     (``_latent_layers``); ``selections`` receives each such layer's."""
@@ -709,17 +716,23 @@ def _decode_step_body(model, params, kv, tok, positions, *,
         model, new_kv, positions[:, None], pool_kernel, live)
     latent = _latent_layers(model, params, new_kv, positions[:, None],
                             live=live)
-    seen = {"attn": 0, "mla": 0, "kda": 0}
+    # a layer's place in its kind's state; a recurrent layer's matrix lies
+    # among all of them ("kda"), its other half among its own kind's
+    seen = {"attn": 0, "mla": 0, "kda": 0, "conv": 0, "norm": 0}
     selection = None
     for i, (blk, kw) in enumerate(zip(params["blocks"], latent)):
-        kind = "kda" if "gdn" in blk else next(k for k in seen if k in blk)
+        kind = ("kda" if "gdn" in blk or "ret" in blk
+                else next(k for k in ("attn", "mla", "kda") if k in blk))
         j = seen[kind]
         seen[kind] += 1
         if kind == "kda":
+            other = "norm" if "ret" in blk else "conv"
+            jo = seen[other]
+            seen[other] += 1
             # the recurrence's kernel follows the pool kernel's rule: not
             # over a pool sharded over a mesh (``_decode_jit``); with no
             # TPU attached it runs through the Pallas interpreter
-            kw = {"state": (new_kv["kda"][j], new_kv["conv"][j]),
+            kw = {"state": (new_kv["kda"][j], new_kv[other][jo]),
                   "state_kernel": pool_kernel is not False}
         elif kind == "mla":
             kw = dict(kw, selection=selection)
@@ -729,7 +742,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
             blk, h, positions=positions[:, None], moe_info=moe_info,
             live=None if live is None else live[:, None], layer=i, **kw)
         if kind == "kda":
-            new_kv["kda"][j], new_kv["conv"][j] = a, b
+            new_kv["kda"][j], new_kv[other][jo] = a, b
         elif kind == "mla" and model.dsa:
             selection = b
             if selections is not None and "indexer" in kw:
@@ -1063,10 +1076,10 @@ class DecodeEngine:
         # prefill and its admit_slot
         self._first_draft: Dict[int, object] = {}
 
-        if model.mtp and (model.kda or model.gdn or model.dsa):
+        if model.mtp and (model.kda or model.gdn or model.ret or model.dsa):
             raise ValueError(
-                "speculative decoding is not written for a model with 'kda' "
-                "or 'gdn' layers or an indexer: a rejected draft token would "
+                "speculative decoding is not written for a model with 'kda', "
+                "'gdn' or 'ret' layers or an indexer: a rejected draft token would "
                 "have to be taken back out of the recurrent state, which "
                 "keeps no history to rewind to, and the verify forward knows "
                 "no indexer's keys: it neither writes them nor selects")

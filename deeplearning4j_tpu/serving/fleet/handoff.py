@@ -94,9 +94,9 @@ def _kv_only(engine) -> None:
     a 'kda' layer's recurrent state or an 'mla' layer's latent rows."""
     if engine.model.hybrid:
         raise ValueError(
-            "the fleet hand-off carries K/V slabs only: a model with 'kda' "
-            "or 'mla' layers keeps recurrent state and latent rows that "
-            "export_slot / install_slot do not move")
+            "the fleet hand-off carries K/V slabs only: a model with 'kda', "
+            "'gdn', 'ret' or 'mla' layers keeps recurrent state and latent "
+            "rows that export_slot / install_slot do not move")
 
 
 def export_slot(engine, slot: int) -> Dict[str, np.ndarray]:

@@ -71,7 +71,14 @@ of it side by side:
   own order. They have no time axis: a prefill writes them **as of the
   prompt's length** (pad rows move no state, ``models/kda.py``), a decode
   step replaces a live slot's and leaves a frozen slot's as they are, and
-  the next prefill into the slot overwrites them whole.
+  the next prefill into the slot overwrites them whole;
+- ``ret`` layers (power retention, ``models/ret.py``): the state ``[S, Hkv,
+  D, dh]`` in float32 among the recurrent matrices above (``D`` = 8,704 rows
+  at heads of 128: 35.7 MB a slot and layer) and the normaliser ``[S, Hkv,
+  dh, dh]`` in float32 (``norm``), written, stepped and overwritten as the
+  delta-rule state is. A model whose every layer is one has **no array with
+  a time axis**: no K/V pool, no ring, no latent rows; ``max_len`` then
+  bounds positions (RoPE, admission), not memory.
 
 The new kinds are lists of per-layer arrays (no layer axis to slice a slab
 out of). ``pool_layout`` is the one description of all of it: the arrays
@@ -218,11 +225,14 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
     model keeps one: ``ring_rows``; ``ring=False``: those layers keep
     ``T_max`` rows like the others), ``latent`` (one array an ``mla`` layer),
     ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
-    ``recurrent`` and ``conv`` (one each a ``kda`` or ``gdn`` layer, in the
-    layers' order). A kind the model has no layer of is an empty list.
+    ``recurrent`` (one a ``kda``, ``gdn`` or ``ret`` layer, in the layers'
+    order), ``conv`` (one a ``kda`` or ``gdn`` layer) and ``normaliser``
+    (one a ``ret`` layer). The last three are float32 or follow ``kv_dtype``
+    as their layers' docstrings say: a recurrent state is float32 whatever
+    the pool's rows are. A kind the model has no layer of is an empty list.
     ``sharded``: the pool lies over a mesh (``pool_shape``)."""
     out = {"kv": [], "ring": [], "latent": [], "index": [], "recurrent": [],
-           "conv": []}
+           "conv": [], "normaliser": []}
     dims, ring_dims = _pool_dims(
         model, slots, max_len,
         ring_rows(model, max_len, kv_dtype) if ring else None)
@@ -244,6 +254,13 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
             taps = (model.kda if kind == "kda" else model.gdn)["conv"]
             out["recurrent"].append(((slots, h, dk, dk), "float32"))
             out["conv"].append(((slots, taps - 1, width), kv_dtype))
+        elif kind == "ret":
+            from deeplearning4j_tpu.models.ret import state_rows
+
+            h, dh = model.num_kv_heads, model.head_dim
+            out["recurrent"].append(
+                ((slots, h, state_rows(dh), dh), "float32"))
+            out["normaliser"].append(((slots, h, dh, dh), "float32"))
     return out
 
 
@@ -267,7 +284,7 @@ def kv_pool_nbytes(model, slots: int, max_len: Optional[int] = None,
     """Analytic device footprint of a slot pool: the K/V pool pair, the
     ring pair of a model that keeps one (``ring=False``: it does not), the
     latent rows, an indexer's keys and the recurrent state with
-    its convolution tails, whichever the model's layers keep — the
+    its convolution tails or normalisers, whichever the model's layers keep — the
     serving term of the HBM budget model. Matches ``SlotKVCache.nbytes``
     exactly (asserted in tests)."""
     name = resolve_kv_dtype(kv_dtype, model)
@@ -357,8 +374,8 @@ def slot_admit(loop, at, tok, key, draft=None):
 class SlotKVCache:
     """``[L, S, T_max, Hkv, Dh]`` K/V pools, the window layers' ``[L_win,
     S, R, Hkv, Dh]`` rings, the other layer kinds' state
-    (latent rows, index keys, recurrent matrices, convolution tails:
-    ``pool_layout``)
+    (latent rows, index keys, recurrent matrices, convolution tails,
+    normalisers: ``pool_layout``)
     + the decode loop's device per-slot state (cursors, last tokens,
     tokens owed, RNG keys)."""
 
@@ -394,10 +411,10 @@ class SlotKVCache:
         self.kv_dtype = resolve_kv_dtype(kv_dtype, model)
         if model.hybrid and registry is not None:
             raise ValueError(
-                "a model with 'kda', 'gdn' or 'mla' layers is served on one "
-                "chip: the mesh's head split is written for a model whose "
-                "every layer keeps K/V rows, not for latent rows, an "
-                "indexer's keys or recurrent state beside them")
+                "a model with 'kda', 'gdn', 'ret' or 'mla' layers is served "
+                "on one chip: the mesh's head split is written for a model "
+                "whose every layer keeps K/V rows, not for latent rows, an "
+                "indexer's keys or recurrent state beside or instead of them")
         # positions a window layer's ring holds; None: the model keeps none
         self.ring = ring_rows(model, self.max_len, self.kv_dtype) if (
             ring) else None
@@ -408,9 +425,10 @@ class SlotKVCache:
                 "one K/V pool, not for a ring of rows beside it")
         layout = pool_layout(model, self.slots, self.max_len, self.kv_dtype,
                              sharded=registry is not None, ring=ring)
-        self.latent, self.index, self.kda, self.conv = (
+        self.latent, self.index, self.kda, self.conv, self.norm = (
             [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
-            for kind in ("latent", "index", "recurrent", "conv"))
+            for kind in ("latent", "index", "recurrent", "conv",
+                         "normaliser"))
         # the pools' logical axes (L, S, T_max, Hkv, Dh), whichever shape
         # they are stored in
         self.pool_dims, self.ring_dims = _pool_dims(
@@ -470,7 +488,7 @@ class SlotKVCache:
         st = {} if self.k is None else {"k": self.k, "v": self.v}
         if self.kw is not None:
             st.update(kw=self.kw, vw=self.vw)
-        for name in ("latent", "index", "kda", "conv"):
+        for name in ("latent", "index", "kda", "conv", "norm"):
             if getattr(self, name):
                 st[name] = list(getattr(self, name))
         return st
@@ -481,7 +499,7 @@ class SlotKVCache:
         device memory, not a copy of it."""
         self.k, self.v = state.get("k"), state.get("v")
         self.kw, self.vw = state.get("kw"), state.get("vw")
-        for name in ("latent", "index", "kda", "conv"):
+        for name in ("latent", "index", "kda", "conv", "norm"):
             setattr(self, name, list(state.get(name, ())))
 
     @property
@@ -493,8 +511,8 @@ class SlotKVCache:
     @property
     def nbytes_by_kind(self) -> dict:
         """``nbytes`` apart: ``kv`` (the K/V pools), ``ring`` (the window
-        layers' rings), ``latent``, ``index``, ``recurrent``, ``conv``
-        (``pool_layout``'s kinds)."""
+        layers' rings), ``latent``, ``index``, ``recurrent``, ``conv``,
+        ``normaliser`` (``pool_layout``'s kinds)."""
         kv = [a for a in (self.k, self.v) if a is not None]
         kinds = [("kv", kv), ("latent", self.latent),
                  ("recurrent", self.kda), ("conv", self.conv)]
@@ -502,6 +520,8 @@ class SlotKVCache:
             kinds.insert(2, ("index", self.index))
         if self.kw is not None:     # and only one with a ring this one
             kinds.insert(1, ("ring", [self.kw, self.vw]))
+        if self.norm:               # and only one with 'ret' layers this one
+            kinds.append(("normaliser", self.norm))
         return {kind: sum(int(a.nbytes) for a in arrays)
                 for kind, arrays in kinds}
 

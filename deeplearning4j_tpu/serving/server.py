@@ -104,8 +104,9 @@ runs):
   ``kv_rows_window``, ``min(c + 1, window)`` a layer with a window, and
   ``kv_rows_full``, ``c + 1`` a layer without; ``kv_blocks`` are then over
   all the 'attn' layers, each pool's blocks times its layers), and, for a
-  model with 'kda' or 'gdn' layers,
-  ``state_slots``, the slots whose recurrent state the step moves; for a
+  model with 'kda', 'gdn' or 'ret' layers,
+  ``state_slots``, the slots whose recurrent state the step moves (beside
+  ``kv_rows`` 0 where no layer keeps rows a position); for a
   model with learned sparse attention ``keys_cached``, ``keys_attended`` and
   ``rows_gathered`` instead) — the decode
   dispatch (``live`` slots; 0: none may be owed a token), then the
@@ -216,7 +217,7 @@ class DecodeServer:
         self.kv_rows_window = 0
         self.kv_rows_full = 0
         self.state_slots = 0
-        self._recurrent = bool(model.kda or model.gdn)
+        self._recurrent = bool(model.kda or model.gdn or model.ret)
         # learned sparse attention: latent rows the dispatched slots held
         # below their cursors, and rows their queries attended (at most
         # ``topk`` each), summed over decode steps, live slots and 'mla'
@@ -706,8 +707,9 @@ class DecodeServer:
         span's attrs (none where the pool has no kernel read, or nothing
         is dispatched); beside them ``kv_rows`` (a pool of K/V rows: the
         rows the live slots hold up to their cursors, over the 'attn'
-        layers) and ``state_slots`` (a model with 'kda' or 'gdn' layers: the
-        live slots, whose recurrent state the step moves). For a model with
+        layers; absent for a model none of whose layers keeps rows a
+        position) and ``state_slots`` (a model with 'kda', 'gdn' or 'ret'
+        layers: the live slots, whose recurrent state the step moves). For a model with
         learned sparse attention the attrs are ``keys_cached``,
         ``keys_attended`` and ``rows_gathered`` instead: the latent rows the live slots hold up to their cursors,
         the rows their queries attend, and the rows the step's gathers fetch
@@ -745,6 +747,8 @@ class DecodeServer:
                 self.kv_rows_window += rows[True]
                 self.kv_rows_full += rows[False]
         if live and self._recurrent:
+            # a model none of whose layers keeps rows a position says so
+            attrs.setdefault("kv_rows", 0)
             attrs["state_slots"] = len(live)
             self.state_slots += len(live)
         if live and self._kv_reads and all(
@@ -1055,7 +1059,8 @@ class DecodeServer:
             "kv_dtype": self.engine.kv_dtype,
             "kv_pool_bytes": self.engine.cache.nbytes,
             # the pool's bytes by what they are: K/V rows, latent
-            # rows, an indexer's keys, recurrent matrices, convolution tails
+            # rows, an indexer's keys, recurrent matrices, convolution tails,
+            # normalisers
             "state_bytes": self.engine.cache.nbytes_by_kind,
             # slots a decode dispatch served, mean (with rounds: the slots
             # that MAY owe a token, ``_owed``'s superset)

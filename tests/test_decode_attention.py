@@ -411,3 +411,40 @@ class TestCompiledForV5e:
         assert compiled.as_text().count("tpu_custom_call") == 1
         matrix = held * d * f * 2           # one stacked matrix in bf16
         assert compiled.memory_analysis().temp_size_in_bytes < matrix // 16
+
+    def test_retention_step_moves_the_state_in_place(self, one_chip,
+                                                     monkeypatch):
+        """``pallas/retention_step.py`` at ``brumby-serve-continue``'s
+        sizes (32 slots, 8 kv heads of 128 serving 5 queries each, a state
+        of 8,704 rows a head): Mosaic takes the kernel (dynamic trip counts,
+        VMEM scratch, a 4.46 MB block a head), the donated state is the
+        output state, and nothing is copied beside it."""
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from deeplearning4j_tpu.models import ret
+        from deeplearning4j_tpu.pallas import retention_step as step
+
+        b, hkv, rep, d = 32, 8, 5, 128
+        rows = ret.state_rows(d)
+        assert rows == 8704
+
+        def arg(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        fn = jax.jit(lambda *a: step.retention_step(
+            *a, eps=ret.EPS, interpret=False), donate_argnums=(4, 5))
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            compiled = fn.lower(
+                arg((b, hkv * rep, d)), arg((b, hkv, d)), arg((b, hkv, d)),
+                arg((b, hkv)), arg((b, hkv, rows, d)), arg((b, hkv, d, d)),
+                arg((b,), jnp.bool_)).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+        assert compiled.as_text().count("tpu_custom_call") == 1
+        mem = compiled.memory_analysis()
+        state = b * hkv * (rows * d + d * d) * 4
+        assert mem.alias_size_in_bytes == state
+        assert mem.temp_size_in_bytes < state // 256, mem.temp_size_in_bytes

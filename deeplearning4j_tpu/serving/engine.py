@@ -26,10 +26,20 @@ compiles outside it:
   owes nothing freezes itself (``remaining``): its token, cursor and key
   carry unchanged while its rows ride along computing garbage no one
   reads.
+- ``("prefill", P_bucket, "admit")`` — the server's admission, ONE
+  program a request (``_serve_admit_impl``, ``DecodeEngine.admit``): the
+  prefill above, the request's key made from its seed inside it, and the
+  slot's loop state written behind it (``kv_cache.slot_admit``: first
+  token, cursor, tokens owed, key). Its arguments from the host are the
+  padded prompt and one int32 vector, NumPy, transferred with the call:
+  no eager one-op program runs beside it. The bare prefill stays for the
+  hand-off's prefill replica (``fleet/replica.py``) and the compile
+  rehearsals under ``benchmarks/tools/``.
 - ``("slot_admit",)`` — the loop state's one write from the host
-  (``kv_cache.slot_admit``): a request enters a slot after its prefill
-  or hand-off, or leaves it before its last token (the same write with
-  nothing owed). One small program.
+  (``kv_cache.slot_admit``): a request enters a slot after its hand-off
+  or the last of its prefill blocks, or leaves it before its last token
+  (the same write with nothing owed). One small program, which an
+  engine's first admission runs once so that no release compiles it.
 - ``("decode_spec", S)`` — a model with a multi-token-prediction module
   (``TransformerLM(mtp=)``) decodes in speculative rounds instead of
   plain steps, one round a dispatch (``_serve_mtp_impl``): the target
@@ -233,6 +243,26 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
     if model.num_experts:
         return tok, key, new_kv, _stack_routing(moe_info)
     return tok, key, new_kv
+
+
+@traced
+def _serve_admit_impl(model, sample_row, params, kv, loop, prompt, at):
+    """The server's admission as ONE program: ``_serve_prefill_impl`` of
+    the bucket-padded prompt ([1, P]) and then the slot's loop state
+    (``slot_admit``), from ``at`` = ``[slot, prompt_len, remaining, seed]``
+    (int32, the one small transfer an admission makes beside its prompt:
+    ``slot_admit``'s own vector and the seed): the key is made here from
+    the request's seed — the bits ``jax.random.PRNGKey(seed)`` gives on the
+    host — and the slot takes the first token, the cursor ``prompt_len``,
+    the tokens it is still owed and the key as the sampler left it.
+    Returns ``(token, loop, pool)`` and, for a model with routed experts,
+    ``(token, loop, pool, routing)``."""
+    import jax
+
+    tok, key, new_kv, *record = _serve_prefill_impl(
+        model, sample_row, False, params, kv, prompt, at[1], at[0],
+        jax.random.PRNGKey(at[3]))
+    return (tok, slot_admit(loop, at, tok, key), new_kv) + tuple(record)
 
 
 def _stack_routing(moe_info):
@@ -1000,6 +1030,21 @@ def _serve_mtp_impl(model, sample_filtered, greedy, k_rounds, params, kv,
     return blocks, loop, kv, routing
 
 
+def seed_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s words, made on the host and handed to a
+    program with its other arguments, where the eager call launches a program
+    of its own: threefry's key is the seed as 64 bits, high word first, and
+    without x64 ``PRNGKey`` keeps a Python int's low word alone. Any other
+    generator's key is jax's to make."""
+    import jax
+
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return np.asarray(jax.random.PRNGKey(seed))
+    seed = int(np.int64(seed))
+    high = seed >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed], np.int64).astype(np.uint32)
+
+
 def _record(extra):
     """What a serving program returned beside tokens, keys and pool (and a
     prefill block's carry), as the
@@ -1164,11 +1209,7 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         model, cache = self.model, self.cache
-        prompt = np.asarray(prompt, np.int32)
-        if prompt.ndim != 1:
-            raise ValueError(f"prompt must be [t] (got {prompt.shape})")
-        bucket = self.prompt_bucket(int(prompt.shape[0]))
-        padded, plen = pad_prompt(prompt, bucket)
+        bucket, padded, plen = self._padded(prompt)
 
         def build():
             fn = functools.partial(_serve_prefill_block_impl, model,
@@ -1181,11 +1222,11 @@ class DecodeEngine:
             name: jnp.full(shape, fill, jnp.dtype(dt))
             for name, (shape, dt, fill) in prefill_carry_layout(
                 model, bucket).items()}
-        self.admit_slot(slot, 0, int(plen), 0, key)
-        tokens = jnp.asarray(padded)[None]
-        plen_, slot_ = jnp.asarray(plen, jnp.int32), jnp.asarray(
-            slot, jnp.int32)
-        last = prefill_block_count(int(plen), bucket) - 1
+        self.admit_slot(slot, 0, plen, 0, key)
+        # one transfer for all the blocks, and no program of its own
+        tokens = jax.device_put(padded)
+        plen_, slot_ = np.int32(plen), np.int32(slot)
+        last = prefill_block_count(plen, bucket) - 1
         try:
             for i in range(last + 1):
                 tok, new_key, state, carry, *record = run(
@@ -1206,16 +1247,11 @@ class DecodeEngine:
         learned sparse attention: ``_record``). The slot decodes once
         ``admit_slot`` has written its loop state."""
         import jax
-        import jax.numpy as jnp
 
         if self.block_prefill:      # every block, back to back
             *_, out = self.prefill_blocks(prompt, slot, key)
             return out
-        prompt = np.asarray(prompt, np.int32)
-        if prompt.ndim != 1:
-            raise ValueError(f"prompt must be [t] (got {prompt.shape})")
-        bucket = self.prompt_bucket(int(prompt.shape[0]))
-        padded, plen = pad_prompt(prompt, bucket)
+        bucket, padded, plen = self._padded(prompt)
 
         def build():
             fn = functools.partial(_serve_prefill_impl, self.model,
@@ -1224,10 +1260,57 @@ class DecodeEngine:
 
         run = self._program(("prefill", bucket), build)
         tok, key, state, *record = run(
-            self.model.params, self.cache.state, jnp.asarray(padded)[None],
-            jnp.asarray(plen, jnp.int32), jnp.asarray(slot, jnp.int32), key)
+            self.model.params, self.cache.state, padded, np.int32(plen),
+            np.int32(slot), key)
         self.cache.install(state)
         return tok, key, _record(record)
+
+    def admit(self, prompt, slot: int, max_new_tokens: int, seed: int):
+        """The server's admission of one prompt ([t] int) into ``slot``:
+        ONE program (``_serve_admit_impl``, one compile a rung) that
+        prefills the slot's pool rows, samples the request's first token
+        under the key of ``seed`` and writes the slot's loop state, so the
+        slot decodes from the next dispatch on with nothing more from the
+        host. Everything it is handed from the host is NumPy, transferred
+        with the call: the padded prompt and one int32 vector. Returns
+        ``(first_token, routing)`` (device values, ``routing`` as
+        ``prefill``'s); nothing here waits for them."""
+        import jax
+
+        if self.block_prefill:
+            raise ValueError(
+                "a model prefilled in blocks is admitted a block a step "
+                "(prefill_blocks, admit_slot)")
+        bucket, padded, plen = self._padded(prompt)
+        if ("slot_admit",) not in self._programs:
+            # a slot is released (a deadline, a cancel) only after an
+            # admission: the first one of an engine runs the release
+            # program, so that no later moment meets it uncompiled
+            self.release_slot(slot)
+
+        def build():
+            fn = functools.partial(_serve_admit_impl, self.model,
+                                   self._sample_row)
+            return jax.jit(fn, donate_argnums=(1,))
+
+        run = self._program(("prefill", bucket, "admit"), build)
+        # the seed's low 32 bits, as PRNGKey takes a Python int without x64
+        at = np.array([slot, plen, max_new_tokens - 1, seed],
+                      np.int64).astype(np.int32)
+        tok, self.cache.loop, state, *record = run(
+            self.model.params, self.cache.state, self.cache.loop, padded, at)
+        self.cache.install(state)
+        return tok, _record(record)
+
+    def _padded(self, prompt):
+        """``(rung, the prompt padded to it as [1, P] int32 on the host,
+        its length)``."""
+        prompt = np.asarray(prompt, np.int32)
+        if prompt.ndim != 1:
+            raise ValueError(f"prompt must be [t] (got {prompt.shape})")
+        bucket = self.prompt_bucket(int(prompt.shape[0]))
+        padded, plen = pad_prompt(prompt, bucket)
+        return bucket, padded[None], plen
 
     def admit_slot(self, slot: int, tok, cursor: int, remaining: int,
                    key) -> None:
@@ -1237,12 +1320,11 @@ class DecodeEngine:
         will land, ``remaining`` the tokens it is still owed, ``key`` its
         RNG stream."""
         import jax
-        import jax.numpy as jnp
 
         run = self._program(("slot_admit",), lambda: jax.jit(slot_admit))
         self.cache.loop = run(
             self.cache.loop, np.asarray([slot, cursor, remaining], np.int32),
-            jnp.asarray(tok, jnp.int32), key,
+            tok if isinstance(tok, jax.Array) else np.int32(tok), key,
             # one signature: always a draft where the loop state has one
             self._first_draft.pop(slot, np.int32(0)) if self.model.mtp
             else None)

@@ -9,24 +9,33 @@ The loop, per ``step()``:
 
 1. **sweep** — an in-flight request past its deadline, or canceled,
    leaves its slot (its ``remaining`` on the device goes to zero).
-2. **admit** — pop queued requests into free slots; each admission runs
-   the bucket-compiled prefill (``serve.prefill`` span), writes the
-   slot's loop state (``engine.admit_slot``), records TTFT, and may
-   retire immediately when ``max_new_tokens == 1``. Admission happens
-   ONLY here.
+2. **admit** — pop queued requests into free slots; each admission
+   DISPATCHES one program (``engine.admit``, the ``serve.prefill`` span:
+   the bucket-compiled prefill and the slot's loop state, the request's
+   key made from its seed inside it) and books the request in its slot
+   with its first token PENDING on the device: nothing here waits for
+   the chip. Admission happens ONLY here.
    A model with learned sparse attention is prefilled in blocks
    (``engine.prefill_blocks``), and its admission is spread over steps:
    a request takes its slot at once, ONE block of one prompt runs a step
    (``serve.prefill_block``; the prompts part-way in take turns), and
-   the last block is the request's ``serve.prefill`` (``_admit_blocks``).
+   the last block is the request's ``serve.prefill`` (``_admit_blocks``),
+   its loop state written behind it (``engine.admit_slot``).
    The live slots decode between the blocks.
-3. **decode** — if any slot is owed a token, run ONE decode dispatch,
+3. **decode** — if any slot is owed a token (a pending first token counts
+   as the one token it is), run ONE decode dispatch,
    of the one kind of block the model has: the plain single-step program
    (the PR-10 step), or, for a model with a multi-token-prediction module
    (``TransformerLM(mtp=)``), one speculative round drafted from it.
-4. **read and emit** — the token block reaches the host; every slot that
-   was live in it appends its tokens; finished requests retire and free
-   their slots.
+4. **read** — the token block dispatched a step earlier reaches the host,
+   then the first tokens of this step's admissions
+   (``serve.first_token``): their prefills ran before the block just
+   dispatched, so the read returns when a read before the dispatch would
+   have — ``first_token_s`` and TTFT are what they were, in the step
+   that admitted — and the chip runs that block while the host books
+   them; a request with ``max_new_tokens == 1`` retires here.
+5. **emit** — every slot that was live in the block read appends its
+   tokens; finished requests retire and free their slots.
 
 The loop's state — per slot the cursor, the last token, the tokens still
 owed and the RNG key — lives ON THE DEVICE (``SlotKVCache.loop``): each
@@ -62,8 +71,10 @@ The host sees one token-block readback per dispatch ([S] of a plain
 step, [1, S, 4] of a round; a model with routed experts
 sends its routing in the same read, carried with ITS block) — that is
 the decode loop's entire host/device chatter: between two decode steps
-with no admission nothing travels host → device. Everything else
-(queue, slot table) is host bookkeeping the scheduler needs anyway.
+with no admission nothing travels host → device, and an admission sends
+its padded prompt and one int32 vector with its one program's call.
+Everything else (queue, slot table) is host bookkeeping the scheduler
+needs anyway.
 
 Observability: queue depth / occupancy gauges, token + dispatch
 counters (``serve_decode_steps_total`` counts DISPATCHES — a round
@@ -79,20 +90,26 @@ trace's clock in any ``jax.profiler`` trace taken while the server
 runs):
 
 - ``serve.step`` (``live``, ``admitted``) — one scheduler iteration;
-  parent of the three phases:
-- ``serve.admit`` (``n``) — all admissions of the step; opened only when
-  a slot is free and a request or hand-off is waiting. Holds, per
-  request, ``serve.queued`` (``request``, ``criticality``: ``submit_s``
-  → popped from the queue; recorded when it ends) and ``serve.prefill``
-  (``request``, ``slot``, ``prompt_len``, ``bucket``, ``queue_wait_us``:
-  key, pad, prefill dispatch, cursor, the first token's read-back, up to
-  ``first_token_s``), or ``serve.handoff.install``. Learned sparse
+  parent of the four phases:
+- ``serve.admit`` (``n``) — all admissions of the step, dispatched; opened
+  only when a slot is free and a request or hand-off is waiting. Holds,
+  per request, ``serve.queued`` (``request``, ``criticality``:
+  ``submit_s`` → popped from the queue; recorded when it ends) and
+  ``serve.prefill`` (``request``, ``slot``, ``prompt_len``, ``bucket``,
+  ``queue_wait_us``: pad and the dispatch of the admission's one
+  program), or ``serve.handoff.install``. Learned sparse
   attention: ``serve.prefill`` is the prompt's last block (``blocks``:
   how many it had) and each block before it a ``serve.prefill_block``
   (``request``, ``slot``, ``block``, ``blocks``) in an earlier step's
-  ``serve.admit``. Routed experts on a share: a ``serve.passes``
-  (``moe_rows_run``, ``moe_pairs_run``) inside the ``serve.prefill`` that
-  read a routing whose sorted form ran in passes (``_read_block``).
+  ``serve.admit``.
+- ``serve.first_token`` (``request``, ``slot``, ``ahead`` = 1 when a
+  decode block was dispatched behind the prefill) — after the step's
+  ``serve.decode``, one a request admitted in the step: the first
+  token's read-back, up to ``first_token_s``, and its booking. A
+  prompt's ``experts_touched`` and ``experts_read`` land HERE, never on
+  ``serve.decode``; routed experts on a share: a ``serve.passes``
+  (``moe_rows_run``, ``moe_pairs_run``) inside the span that read a
+  routing whose sorted form ran in passes (``_read_block``).
 - ``serve.decode`` (``live``, ``kind`` = ``plain`` | ``spec``,
   ``ahead`` = 1 when the dispatch was issued while the
   previous block was unread; ``kv_blocks``, ``kv_blocks_pool``: the key
@@ -114,8 +131,8 @@ runs):
   ``experts_touched`` and ``experts_read``, and a round's ``rounds``,
   ``proposed``, ``accepted`` and ``emitted`` (the device's counts), are
   of the block READ.
-- ``serve.emit`` (``tokens``, ``retired``) — the per-slot token loop
-  over the block read, histograms, retirement.
+- ``serve.emit`` (``tokens``, ``retired``) — after the reads: the
+  per-slot token loop over the block read, histograms, retirement.
 - ``serve.request`` (``request``, ``tokens``, ``slot``) — ``submit_s`` →
   ``finish_s``, recorded at retirement; ``request`` is
   ``ServeRequest.id`` on every span of one request.
@@ -138,7 +155,7 @@ from deeplearning4j_tpu.monitor import metrics, tracer
 from deeplearning4j_tpu.pallas.decode_attention import (
     key_block_span, pool_block_rows)
 from deeplearning4j_tpu.serving.engine import (
-    DecodeEngine, prefill_block_count, unpack_routing)
+    DecodeEngine, prefill_block_count, seed_key, unpack_routing)
 from deeplearning4j_tpu.serving.kv_cache import attn_places
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionVerdict, RequestQueue, ServeQueueFull, ServeRequest,
@@ -235,6 +252,11 @@ class DecodeServer:
         # {slot: request}, {slot: last token's instant})`` — device arrays,
         # the slots live in it and those of them freed at the dispatch
         self._unread: Optional[Tuple[object, object, dict, dict]] = None
+        # the admissions of the step in hand whose first token is still on
+        # the device: ``(request, token, routing)``, booked in their slots
+        # already; read behind the step's decode dispatch
+        # (``_read_first_tokens``), so none outlives its step
+        self._pending: List[Tuple[ServeRequest, object, object]] = []
         # externally-prefilled requests waiting for a free slot: each
         # entry carries an ``install(engine, slot) -> (last_tok, cursor,
         # key)`` that lands the handed-off KV slab into the slot
@@ -250,6 +272,9 @@ class DecodeServer:
         self.expired_in_flight = 0
         self.steps = 0
         self.decode_ahead = 0
+        # admissions whose first token was read with a decode block already
+        # dispatched behind their prefill
+        self.admit_ahead = 0
         # dispatches whose block gave no slot a token, by the device's
         # own counts: what dispatching on "may owe" costs (rounds only)
         self.empty_dispatches = 0
@@ -519,8 +544,6 @@ class DecodeServer:
         return None
 
     def _admit_into(self, free: List[int]) -> int:
-        import jax
-
         admitted = 0
         for slot in free:
             # handed-off slabs first: their prefill compute is already
@@ -540,9 +563,9 @@ class DecodeServer:
                     prompt_len=prompt_len,
                     bucket=self.engine.prompt_bucket(prompt_len),
                     queue_wait_us=waited):
-                self._first_token(req, slot, *self.engine.prefill(
-                    req.prompt, slot, jax.random.PRNGKey(req.seed)))
-            self._enter(req, slot)
+                # one program: the prefill and the slot's loop state
+                self._await_first_token(req, slot, *self.engine.admit(
+                    req.prompt, slot, req.max_new_tokens, req.seed))
             admitted += 1
         return admitted
 
@@ -555,9 +578,7 @@ class DecodeServer:
         decode dispatch, so a 28k-token prompt holds the live slots back a
         block at a time and not for all of its fourteen, and a short prompt
         behind it shares the chip with it instead of waiting it out.
-        Returns how many requests took their first token."""
-        import jax
-
+        Returns how many requests' last block ran."""
         for slot in free:
             popped = self._pop_request()
             if popped is None:
@@ -571,7 +592,7 @@ class DecodeServer:
             self._slot_req[slot] = req
             self._prefilling[slot] = [
                 self.engine.prefill_blocks(
-                    req.prompt, slot, jax.random.PRNGKey(req.seed)),
+                    req.prompt, slot, seed_key(req.seed)),
                 blocks, waited, 0]
         if not self._prefilling:
             return 0
@@ -593,45 +614,63 @@ class DecodeServer:
                 prompt_len=prompt_len,
                 bucket=self.engine.prompt_bucket(prompt_len),
                 queue_wait_us=waited, blocks=blocks):
-            self._first_token(req, slot, *next(run))
+            tok, key, routing = next(run)
+            # the slot's loop state straight from the program's outputs
+            self.engine.admit_slot(slot, tok, prompt_len,
+                                   req.max_new_tokens - 1, key)
+            self._await_first_token(req, slot, tok, routing)
         run.close()
-        self._enter(req, slot)
         return 1
 
-    def _first_token(self, req: ServeRequest, slot: int, tok, key,
-                     routing) -> None:
-        """What a finished prefill hands to the slot and the request,
-        inside its ``serve.prefill`` span: the loop state, the first token
-        read back, the routing and selection where they are recorded."""
-        prompt_len = int(req.prompt.shape[0])
-        # the slot's loop state straight from the program's outputs,
-        # queued on the device before the host waits
-        self.engine.admit_slot(slot, tok, prompt_len,
-                               req.max_new_tokens - 1, key)
-        self._cursors[slot] = prompt_len
-        tok, rows, selection = self._read_block(tok, routing, prompt_len)
+    def _await_first_token(self, req: ServeRequest, slot: int, tok,
+                           routing) -> None:
+        """A prefill is dispatched and the slot's loop state queued behind
+        it, inside the request's ``serve.prefill`` span: the request holds
+        ``slot`` from here on, its first token PENDING on the device
+        (``_owed`` counts it) until the step has dispatched its decode block
+        (``_read_first_tokens``). Nothing here waits for the device."""
         req.state = "running"
         req.slot = slot
-        req.first_token_s = self.clock()
-        if self.record_routing:
-            req.routing = [tuple(a[:, :prompt_len].copy() for a in rows)]
-            if selection is not None:   # of the prompt's last position
-                req.selection = [selection[:, None]]
-            if self.model.mtp:
-                req.drafts = []
-        req.tokens.append(int(tok))
-
-    def _enter(self, req: ServeRequest, slot: int) -> None:
-        """The request holds ``slot`` and its first token: booked."""
         self._slot_req[slot] = req
-        self._last_tok_s[slot] = req.first_token_s
-        if req.ttft_s is not None:
-            self._reg.histogram("serve_ttft_seconds",
-                                buckets=_LATENCY_BUCKETS
-                                ).observe(req.ttft_s)
-        self._reg.counter("serve_tokens_total").inc()
-        if len(req.tokens) >= req.max_new_tokens:
-            self._retire(slot, req, req.first_token_s)
+        self._cursors[slot] = int(req.prompt.shape[0])
+        self._pending.append((req, tok, routing))
+
+    def _read_first_tokens(self, ahead: bool) -> None:
+        """The first tokens of the step's admissions reach the host, behind
+        the step's decode dispatch (``ahead``: a block was dispatched, so
+        the chip runs it while the host books these): a ``serve.first_token``
+        span a request, and in it the read (``_read_block``: the routing
+        the token came with is booked, on this span), ``first_token_s``,
+        the routing and selection where they are recorded, TTFT, and
+        retirement where one token was all it asked for."""
+        pending, self._pending = self._pending, []
+        for req, tok, routing in pending:
+            slot = req.slot
+            with tracer().span("serve.first_token", request=req.id,
+                               slot=slot, ahead=int(ahead)):
+                prompt_len = int(req.prompt.shape[0])
+                tok, rows, selection = self._read_block(tok, routing,
+                                                        prompt_len)
+                req.first_token_s = self.clock()
+                if self.record_routing:
+                    req.routing = [tuple(a[:, :prompt_len].copy()
+                                         for a in rows)]
+                    if selection is not None:   # the prompt's last position
+                        req.selection = [selection[:, None]]
+                    if self.model.mtp:
+                        req.drafts = []
+                req.tokens.append(int(tok))
+                self._last_tok_s[slot] = req.first_token_s
+                if req.ttft_s is not None:
+                    self._reg.histogram("serve_ttft_seconds",
+                                        buckets=_LATENCY_BUCKETS
+                                        ).observe(req.ttft_s)
+                self._reg.counter("serve_tokens_total").inc()
+                if len(req.tokens) >= req.max_new_tokens:
+                    self._retire(slot, req, req.first_token_s)
+        if ahead:
+            self.admit_ahead += len(pending)
+            self._reg.counter("serve_admit_ahead_total").inc(len(pending))
 
     def _retire(self, slot: int, req: ServeRequest, now: float) -> None:
         req.state = "finished"
@@ -654,16 +693,18 @@ class DecodeServer:
         known without reading a token because a request ends on
         ``max_new_tokens`` alone (the device's ``remaining > 0``). What
         the host has counted a request owed, less the one token the unread
-        block holds for it at least: exact
+        block holds for it at least, or the first token it has pending
+        (``_pending``: a request of one token is not live): exact
         for plain steps; with rounds, which yield one token or two, a
         superset by the slots
         whose unread rounds accepted a draft past their end, which the
         device has frozen already (their rows come back with count 0)."""
         unread = self._unread[2] if self._unread is not None else {}
+        pending = {req.slot for req, _, _ in self._pending}
         return {s: r for s, r in enumerate(self._slot_req)
                 if r is not None and s not in self._prefilling
                 and r.max_new_tokens - len(r.tokens)
-                > (unread.get(s) is r)}
+                > (unread.get(s) is r) + (s in pending)}
 
     def _dispatch(self, live: dict):
         """ONE decode dispatch for the live set, from the loop state on
@@ -782,7 +823,7 @@ class DecodeServer:
         ``serve_moe_routed_pairs_total``, the gauge
         ``serve_moe_max_expert_share`` (the busiest expert's share of its
         layer's pairs in this dispatch) and, on the span open now,
-        ``serve.decode`` or ``serve.prefill``, ``experts_touched`` (the
+        ``serve.decode`` or ``serve.first_token``, ``experts_touched`` (the
         (layer, expert) cells that received a token) and ``experts_read``
         (the cells whose matrices the program fetched: the same where it
         was traced in the reached form, layers x held where not;
@@ -872,11 +913,15 @@ class DecodeServer:
             self._cursors[slot] = 0
 
     def step(self) -> bool:
-        """One scheduler iteration: shed expired/canceled slots, admit,
-        one decode dispatch (a plain step, or a round of the model's own
-        module), then read and book a token block
-        — the one dispatched a step EARLIER, so the
-        chip runs this step's dispatch meanwhile — and the slots this
+        """One scheduler iteration: shed expired/canceled slots; admit —
+        dispatch only: a request's one program (its prefill and its slot's
+        loop state) is queued and its first token left pending on the
+        device; one decode dispatch (a plain step, or a round of the
+        model's own module) for every slot that may owe a token, the ones
+        just admitted among them; then read and book a token block — the
+        one dispatched a step EARLIER — and the pending first tokens, in
+        this same step: the chip runs this step's dispatch meanwhile, and
+        at no admission does it wait for the host. The slots this
         step's dispatch is certain to finish are free for the next step's
         admission (``_vacate``). Returns False when
         nothing was dispatched or read and no prompt is part-way through
@@ -887,7 +932,7 @@ class DecodeServer:
             live = self._owed()
             self._reg.gauge("serve_queue_depth").set(len(self.queue))
             self._reg.gauge("serve_slot_occupancy").set(self.occupancy())
-            if not live and self._unread is None:
+            if not live and self._unread is None and not self._pending:
                 return bool(self._prefilling)   # a prefill block ran
             sp.attrs["live"] = len(live)
             self._decode(live)
@@ -895,10 +940,12 @@ class DecodeServer:
             return True
 
     def _decode(self, live: dict) -> None:
-        """The ``serve.decode`` and ``serve.emit`` phases: dispatch a
-        block for ``live`` (none when empty), then read and book the
-        block that was unread, the one dispatched a step earlier — the
-        same order for a plain step and a round."""
+        """The ``serve.decode``, ``serve.first_token`` and ``serve.emit``
+        phases: dispatch a block for ``live`` (none when empty), then read
+        the block that was unread, the one dispatched a step earlier — the
+        same order for a plain step and a round — then the first tokens of
+        this step's admissions (``_read_first_tokens``), whose prefills
+        ran before the block just dispatched, and book the block read."""
         unread = self._unread
         ahead = bool(live) and unread is not None
         with tracer().span("serve.decode", live=len(live),
@@ -910,32 +957,43 @@ class DecodeServer:
             if ahead:
                 self.decode_ahead += 1
                 self._reg.counter("serve_decode_ahead_total").inc()
-            if unread is None:      # the first dispatch after idling
-                return
-            candidates = 1 + bool(self.model.mtp)   # rows a slot and layer
-            toks, rows, selection = self._read_block(
-                *unread[:2], candidates * len(unread[2]), decode=True)
-            counts = drafts = None
-            if self.engine.spec:
-                # the block's one round, [S, 4]: the tokens it yields, the
-                # two it may yield, the draft it verified
-                counts, toks, drafts = (toks[0, :, 0], toks[0, :, 1:3],
-                                        toks[0, :, 3])
-                # what the device says of the round (no further read)
-                c = counts[list(unread[2])]
-                rounds = int(np.count_nonzero(c > 0))
-                sp.attrs.update(
-                    rounds=rounds, proposed=rounds * self.engine.spec_tokens,
-                    accepted=int(np.maximum(c - 1, 0).sum()),
-                    emitted=int(c.sum()))
-                self.spec_rounds += rounds
-                self.spec_emitted += sp.attrs["emitted"]
-                # every slot of the live set had finished on a draft that
-                # an unread round accepted: a dispatch for nothing
-                self.empty_dispatches += rounds == 0
+            if unread is not None:
+                block = self._read_unread(unread, sp)
+        if self._pending:
+            self._read_first_tokens(bool(live))
+        if unread is None:      # the first dispatch after idling
+            return
         with tracer().span("serve.emit") as emit:
             emit.attrs["tokens"], emit.attrs["retired"] = self._emit(
-                *unread[2:], toks, counts, rows, selection, drafts)
+                *unread[2:], *block)
+
+    def _read_unread(self, unread, sp) -> tuple:
+        """The block ``unread`` on the host, inside the ``serve.decode``
+        span ``sp``, as ``_emit`` takes it after its slots: ``(tokens,
+        counts, rows, selection, drafts)``; a round's counts are booked
+        here, on the span."""
+        candidates = 1 + bool(self.model.mtp)   # rows a slot and layer
+        toks, rows, selection = self._read_block(
+            *unread[:2], candidates * len(unread[2]), decode=True)
+        counts = drafts = None
+        if self.engine.spec:
+            # the block's one round, [S, 4]: the tokens it yields, the
+            # two it may yield, the draft it verified
+            counts, toks, drafts = (toks[0, :, 0], toks[0, :, 1:3],
+                                    toks[0, :, 3])
+            # what the device says of the round (no further read)
+            c = counts[list(unread[2])]
+            rounds = int(np.count_nonzero(c > 0))
+            sp.attrs.update(
+                rounds=rounds, proposed=rounds * self.engine.spec_tokens,
+                accepted=int(np.maximum(c - 1, 0).sum()),
+                emitted=int(c.sum()))
+            self.spec_rounds += rounds
+            self.spec_emitted += sp.attrs["emitted"]
+            # every slot of the live set had finished on a draft that
+            # an unread round accepted: a dispatch for nothing
+            self.empty_dispatches += rounds == 0
+        return toks, counts, rows, selection, drafts
 
     def flush(self) -> None:
         """Read and book the block the host has not read yet (no-op with
@@ -1080,6 +1138,11 @@ class DecodeServer:
             # empty), on either kind of block
             "decode_ahead_share": (round(self.decode_ahead / self.steps, 4)
                                    if self.steps else None),
+            # admissions whose first token the host read with a decode block
+            # already dispatched behind their prefill: the chip did not
+            # wait for the host at them (every admission but one with
+            # ``max_new_tokens`` 1 into a server no slot of which is live)
+            "admit_ahead": self.admit_ahead,
             # dispatches of rounds in which every slot of the live set had
             # ended on a draft accepted in the block before (count 0 in
             # every row): the cost of dispatching on "may owe a token"

@@ -622,9 +622,10 @@ def test_the_blocks_of_a_prompt_add_up_the_rows_their_passes_ran(
     rows = sum(-(-int(h) // pass_rows) * pass_rows
                for block in blocks for h in block)
     spans = program_trace.tracer().spans()
-    prefill, = [s for s in spans if s.name == "serve.prefill"]
+    # the prompt's routing comes with its first token (ISSUE 48)
+    token, = [s for s in spans if s.name == "serve.first_token"]
     passes, = [s for s in spans if s.name == "serve.passes"
-               and s.parent_id == prefill.span_id]
+               and s.parent_id == token.span_id]
     assert passes.attrs["moe_rows_run"] == rows > 0
     assert passes.attrs["moe_pairs_run"] == int(here.sum()) <= rows
     # the rounds' six rows are past the lowered threshold too

@@ -447,14 +447,14 @@ def test_experts_read_says_which_form_a_program_took(monkeypatch):
     """``experts_read`` on the span and in ``stats()``: the cells touched
     where the reached form was traced (with the kernel allowed, the traces
     of no more rows than the bound: here the decode step), layers x held
-    where not (the prefill rungs above a bound of 8 rows; every program on
-    the CPU default)."""
+    where not (the prefill rungs above a bound of 8 rows, booked on the span
+    that read the prompt's first token; every program on the CPU default)."""
     from deeplearning4j_tpu.monitor.trace import tracer
 
     def spans(server):
         return [s for s in tracer().spans()
                 if "experts_read" in s.attrs
-                and s.name in ("serve.decode", "serve.prefill")]
+                and s.name in ("serve.decode", "serve.first_token")]
 
     lengths = [(5, 9), (16, 5), (20, 7)]
     tracer().clear()
@@ -469,12 +469,12 @@ def test_experts_read_says_which_form_a_program_took(monkeypatch):
     monkeypatch.setattr(routed_experts, "REACHED_MAX_ROWS", 8)
     server, _ = _served(_lane_wide("float32"), lengths)
     by_name = {name: [s for s in spans(server) if s.name == name]
-               for name in ("serve.decode", "serve.prefill")}
-    assert by_name["serve.decode"] and by_name["serve.prefill"]
+               for name in ("serve.decode", "serve.first_token")}
+    assert by_name["serve.decode"] and by_name["serve.first_token"]
     assert all(s.attrs["experts_read"] == s.attrs["experts_touched"]
                for s in by_name["serve.decode"])
     assert all(s.attrs["experts_read"] == 3 * HELD
-               for s in by_name["serve.prefill"])
+               for s in by_name["serve.first_token"])
     st = server.stats()
     assert st["moe_experts_read_per_step"] == \
         st["moe_experts_touched_per_step"]

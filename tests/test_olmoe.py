@@ -364,10 +364,11 @@ def test_server_books_the_expert_load_of_live_rows_only():
     assert metrics().counter("serve_moe_routed_pairs_total").value() \
         == tokens * K * L
     assert 1 / E <= metrics().gauge("serve_moe_max_expert_share").value() <= 1
-    # on the span that READ the routing: every prefill, and every decode
-    # span but the first, which dispatched a block and had none to read
+    # on the span that READ the routing: every prompt's first token, and
+    # every decode span but the first, which dispatched a block and had
+    # none to read
     spans = [s for s in tracer().spans()
-             if s.name in ("serve.decode", "serve.prefill")]
+             if s.name in ("serve.decode", "serve.first_token")]
     touched = [s.attrs["experts_touched"] for s in spans
                if "experts_touched" in s.attrs]
     assert len(touched) == len(prompts) + server.steps == len(spans) - 1

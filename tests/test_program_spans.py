@@ -222,14 +222,20 @@ class TestServeSpans:
         assert progressed == [True, True, True, False]
         mine = [s for s in t.spans() if s.attrs.get("request") == req.id]
         by_start = sorted(mine, key=lambda s: s.start_s)
+        # the first token is read behind the step's decode dispatch, in
+        # the step that admitted (ISSUE 48): a span of its own
         assert [s.name for s in by_start] == ["serve.queued",
                                               "serve.request",
-                                              "serve.prefill"]
-        queued, request, prefill = by_start
+                                              "serve.prefill",
+                                              "serve.first_token"]
+        queued, request, prefill, token = by_start
         assert queued.start_s == request.start_s == req.submit_s
         assert queued.end_s <= prefill.start_s <= prefill.end_s
         assert request.end_s == req.finish_s
-        assert prefill.start_s < req.first_token_s <= prefill.end_s
+        assert prefill.end_s < token.start_s < req.first_token_s \
+            <= token.end_s
+        assert token.attrs == {"request": req.id, "slot": req.slot,
+                               "ahead": 1}
         assert prefill.attrs["prompt_len"] == 19
         assert prefill.attrs["bucket"] == 32
         assert prefill.attrs["slot"] == req.slot == request.attrs["slot"]
@@ -248,9 +254,10 @@ class TestServeSpans:
             return sorted((s for s in spans if s.parent_id == step.span_id),
                           key=lambda s: s.start_s)
 
-        # the first dispatch after idling has no block behind it to book
+        # the first dispatch after idling has no block behind it to book;
+        # the admission's first token is read behind it
         assert [s.name for s in children(steps[0])] == [
-            "serve.admit", "serve.decode"]
+            "serve.admit", "serve.decode", "serve.first_token"]
         # nothing waits at the later boundaries: no serve.admit at all
         for step in steps[1:3]:
             assert [s.name for s in children(step)] == [
@@ -280,10 +287,12 @@ class TestServeSpans:
         assert retire.parent_id == emits[1].span_id
 
     def test_spans_a_step_stay_inside_the_budget(self, served):
-        """At most 4 spans a step and 3 a request (ISSUE 23)."""
+        """At most 4 spans a step and 4 a request (ISSUE 23;
+        ``serve.first_token``: ISSUE 48)."""
         t, _server, _req, _ = served
         names = [s.name for s in t.spans()]
-        assert len(names) == 3 + 1 + 3 + 2 + 4
+        assert len(names) == 4 + 1 + 3 + 2 + 4
+        assert names.count("serve.first_token") == 1
         assert names.count("serve.admit") == 1
 
     def test_kind_and_mirror_carry_the_request(self, fresh_tracer,

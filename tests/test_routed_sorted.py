@@ -276,10 +276,10 @@ def test_the_gradient_of_a_share_is_the_dense_forms(monkeypatch):
 
 
 # ---- the server books what the programs counted -----------------------------
-def test_the_server_books_the_rows_run_on_the_prefill_span_and_in_stats():
+def test_the_server_books_the_rows_run_on_the_first_token_span_and_in_stats():
     """A prefill whose rung took the sorted form hands its passes' rows over
     with its routing; the server opens a ``serve.passes`` span inside the
-    ``serve.prefill`` span that read it, with the rows and the pairs they
+    ``serve.first_token`` span that read it, with the rows and the pairs they
     were run for as the attrs it is opened with (those reach a profiler
     trace), and sums both in ``stats()``. Held to the routing the server
     recorded: whole passes, as many as each layer's pairs here need. A
@@ -303,7 +303,7 @@ def test_the_server_books_the_rows_run_on_the_prefill_span_and_in_stats():
             for n in (5, 20, 37, 64)]
     server.drain()
     spans = {s.attrs["request"]: s for s in tracer().spans()
-             if s.name == "serve.prefill"}
+             if s.name == "serve.first_token"}
     passes = {s.parent_id: s.attrs for s in tracer().spans()
               if s.name == "serve.passes"}
     total_rows = total_pairs = 0

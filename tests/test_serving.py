@@ -1537,6 +1537,258 @@ class TestPipelinedLoop:
         assert [sp.attrs["live"] for sp in decode] == [1] * 40 + [0]
 
 
+class TickClock:
+    """A clock that moves on by one at every reading."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+class TestAdmission:
+    """ISSUE 48: an admission is ONE device program (the prefill and the
+    slot's loop state, its key made inside it), and its first token is
+    read behind the step's decode dispatch, in the step that admitted."""
+
+    SLOTS, MAX_LEN, BUCKETS = 3, 32, (8, 16, 32)
+    SAMPLING = dict(temperature=0.8, top_k=7)
+    # 2**31 + 5 and -3: PRNGKey keeps a Python int's low 32 bits
+    SEEDS = (0, 7, 2**31 + 5, -3)
+
+    def _server(self, lm, sampled=False, **kw):
+        kw.setdefault("record_routing", bool(lm.num_experts))
+        return DecodeServer(lm, slots=self.SLOTS, max_len=self.MAX_LEN,
+                            buckets=self.BUCKETS,
+                            **(self.SAMPLING if sampled else {}), **kw)
+
+    @pytest.mark.parametrize("seed", [0, 1, 7, 2**31 - 1, 2**31 + 5,
+                                      2**32 + 3, -3])
+    def test_seed_key_is_prngkey(self, seed):
+        """The key a block prefill is handed from the host, and the one the
+        admission program makes from the low word: ``PRNGKey(seed)``."""
+        import jax
+        from deeplearning4j_tpu.serving.engine import seed_key
+
+        want = np.asarray(jax.random.PRNGKey(seed))
+        assert np.array_equal(seed_key(seed), want)
+        assert seed_key(seed).dtype == want.dtype
+        assert np.array_equal(want, jax.jit(jax.random.PRNGKey)(
+            np.int64(seed).astype(np.int32)))
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("kind", ["dense", "routed"])
+    def test_one_program_is_prefill_then_admit_slot(self, rng, kind,
+                                                    sampled):
+        """``engine.admit`` against ``engine.prefill`` + ``admit_slot``:
+        the same first token, routing, loop state (key included) and
+        pool, over several seeds, slots and rungs."""
+        import jax
+        from deeplearning4j_tpu.serving.engine import DecodeEngine
+
+        lm = KINDS[kind][0]()
+        kw = dict(max_len=self.MAX_LEN, buckets=self.BUCKETS,
+                  **(self.SAMPLING if sampled else {}))
+        two, one = (DecodeEngine(lm, self.SLOTS, **kw) for _ in range(2))
+        work = zip(_prompts(rng, (5, 11, 20, 3)), (2, 0, 1, 2), (9, 1, 12, 4),
+                   self.SEEDS)
+        for prompt, slot, new, seed in work:
+            tok, key, routing = two.prefill(prompt, slot,
+                                            jax.random.PRNGKey(seed))
+            two.admit_slot(slot, tok, len(prompt), new - 1, key)
+            got, got_routing = one.admit(prompt, slot, new, seed)
+            assert int(got) == int(tok)
+            assert (routing is None) == (got_routing is None) == (
+                kind == "dense")
+            if routing is not None:
+                assert np.array_equal(got_routing, routing)
+            for name, want in two.cache.loop.items():
+                assert np.array_equal(one.cache.loop[name], want), name
+            assert int(one.cache.loop["remaining"][slot]) == new - 1
+            assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+                np.array_equal, one.cache.state, two.cache.state))
+        # one program a rung, and the release program behind the first
+        assert one.compile_counts() == {
+            "decode": 0, "prefill_buckets": [8, 16, 32], "total": 4}
+
+    def test_block_prefill_keeps_its_programs(self):
+        """A model prefilled in blocks is admitted a block a step."""
+        from deeplearning4j_tpu.serving.engine import DecodeEngine
+
+        with pytest.raises(ValueError, match="a block a step"):
+            DecodeEngine(_module_lm(), 2, max_len=32).admit(
+                np.arange(1, 6, dtype=np.int32), 0, 4, 0)
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("kind", ["dense", "routed"])
+    def test_answers_are_the_synchronous_loops(self, rng, kind, sampled):
+        """Whole answers through the server against the loop that admits
+        with ``engine.prefill`` and keys made on the host: one token, two
+        and many, several seeds, two admissions in one step."""
+        lm = KINDS[kind][0]()
+        news = (1, 2, 9, 1, 12, 2, 6, 5)
+        work = list(zip(_prompts(rng, (5, 11, 7, 3, 20, 9, 4, 6)), news,
+                        self.SEEDS * 2))
+        ref = SyncLoop(lm, self.SLOTS, self.MAX_LEN, buckets=self.BUCKETS,
+                       **(self.SAMPLING if sampled else {}))
+        want = [ref.submit(p, m, seed) for p, m, seed in work]
+        ref.drain()
+        srv = self._server(lm, sampled)
+        reqs = [srv.submit(p, m, seed=seed) for p, m, seed in work]
+        srv.step()
+        # three admissions in the first step, their first tokens booked in
+        # it; the one-token request is out of its slot already
+        assert [len(r.tokens) for r in reqs[:4]] == [1, 1, 1, 0]
+        assert reqs[0].state == "finished" and srv._slot_req[0] is None
+        assert not srv._pending
+        srv.drain()
+        for req, ref_req in zip(reqs, want):
+            assert req.state == "finished"
+            assert req.tokens == ref_req.tokens
+            if lm.num_experts:
+                TestPipelinedLoop._same_record(req, ref_req)
+        assert srv.stats()["finished"] == len(work)
+
+    def test_a_lone_one_token_request(self, rng):
+        """Nothing owes a token: no decode dispatch, the first token is
+        read all the same, in the step that admitted, and no block was
+        dispatched behind it."""
+        srv = self._server(_lm("rope", max_len=32))
+        req = srv.submit(_prompts(rng, (5,))[0], 1)
+        assert srv.step() and req.state == "finished"
+        assert len(req.tokens) == 1 and req.first_token_s == req.finish_s
+        assert not srv.busy() and not srv.step()
+        st = srv.stats()
+        assert (st["admit_ahead"], st["decode_dispatches"]) == (0, 0)
+
+    def test_a_deadline_that_passes_with_the_first_token_pending(self, rng):
+        """A first token is pending only inside the step that admitted:
+        a deadline that passes meanwhile sheds the request at the next
+        step's sweep, its first token booked, the block that holds its
+        second dropped; the slot's next tenant never notices."""
+        lm = _lm("rope", max_len=32)
+        prompts = _prompts(rng, (5, 9, 7))
+        ref = SyncLoop(lm, self.SLOTS, self.MAX_LEN, buckets=self.BUCKETS)
+        want = [ref.submit(p, 8, s) for s, p in enumerate(prompts)]
+        ref.drain()
+        clock = TickClock()
+        srv = self._server(lm, clock=clock)
+        keep = srv.submit(prompts[0], 8, seed=0)
+        # alive when it is popped, past its deadline when its token is read
+        late = srv.submit(prompts[1], 8, seed=1, deadline_s=clock.t + 5.5)
+        srv.step()
+        assert late.state == "running" and len(late.tokens) == 1
+        assert late.first_token_s > late.deadline_s
+        nxt = srv.submit(prompts[2], 8, seed=2)
+        srv.step()
+        assert late.state == "shed" and srv.expired_in_flight == 1
+        assert nxt.slot == late.slot
+        srv.drain()
+        assert late.tokens == want[1].tokens[:1]
+        for req, ref_req in ((keep, want[0]), (nxt, want[2])):
+            assert req.state == "finished" and req.tokens == ref_req.tokens
+
+    def test_admission_sends_its_arguments_and_launches_one_program(self,
+                                                                    rng):
+        """Beside PR 26's guard on the decode step: after warm-up an
+        admission launches exactly one program — and the step's decode
+        program, where a slot owes a token — compiles nothing, and sends
+        the host's values nowhere but into that program's call: under
+        ``disallow_explicit``, which refuses every transfer, a jitted call's
+        own NumPy arguments too (the prompt has to travel), the guard is
+        lifted inside the engine's programs alone, so a ``jnp.asarray``, a
+        ``device_put`` or an eager ``PRNGKey`` beside them fails."""
+        import jax
+
+        srv = self._server(_lm("rope", max_len=32))
+        for p in _prompts(rng, (5, 12)):
+            srv.submit(p, 3)
+        srv.drain()
+        if _executions(lambda: srv.engine.release_slot(0)) != 1:
+            pytest.skip("this jax's CPU trace does not show launches")
+        builds = srv.engine.program_builds
+        programs = dict(srv.engine._programs)
+        sizes = {sig: fn._cache_size() for sig, fn in programs.items()}
+
+        def lifted(fn):
+            def run(*args):
+                with jax.transfer_guard_host_to_device("allow"):
+                    return fn(*args)
+            return run
+
+        srv.engine._programs.update(
+            {sig: lifted(fn) for sig, fn in programs.items()})
+        one, many, more = (srv.submit(p, m, seed=s) for s, (p, m) in
+                           enumerate(zip(_prompts(rng, (4, 7, 11)),
+                                         (1, 9, 9))))
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            with pytest.raises(Exception, match="Disallowed host-to-device"):
+                jax.random.PRNGKey(0)
+            # the engine's one program of an admission, nothing else
+            assert _executions(lambda: srv.engine.admit(
+                one.prompt, 2, 1, 0)) == 1
+            srv.engine.release_slot(2)
+            # three admissions and the decode dispatch behind them
+            assert _executions(srv.step) == 4
+            srv.drain()
+        assert [len(r.tokens) for r in (one, many, more)] == [1, 9, 9]
+        assert srv.engine.program_builds == builds
+        assert sizes == {sig: fn._cache_size()
+                         for sig, fn in programs.items()}
+
+    def test_the_order_of_a_step_that_admits(self, rng):
+        """From the tracer's spans, in a step that admits with a slot
+        live: ``serve.prefill`` closes (inside ``serve.admit``) before
+        ``serve.decode`` opens, ``serve.first_token`` opens after the
+        decode dispatch and inside the same ``serve.step``,
+        ``admit_ahead`` counts the admission, and the routing of the
+        prompt is booked on ``serve.first_token`` — ``serve.decode``
+        carries the decode block's ``experts_touched``."""
+        lm = _routed_lm()
+        cells = lm.n_layers("moe") * lm.experts_per_token   # a live row's
+        ahead0 = metrics().counter("serve_admit_ahead_total").value()
+        tr = SpanTracer(clock=TickClock())
+        set_tracer(tr)
+        try:
+            srv = self._server(lm)
+            first = srv.submit(_prompts(rng, (5,))[0], 12, seed=0)
+            for _ in range(3):
+                srv.step()
+            tr.clear()
+            req = srv.submit(_prompts(rng, (20,))[0], 6, seed=1)
+            srv.step()
+            spans = {sp.name: sp for sp in tr.spans()}
+            srv.drain()
+        finally:
+            set_tracer(None)
+        step, admit, prefill, decode, token, emit = (
+            spans["serve." + n] for n in (
+                "step", "admit", "prefill", "decode", "first_token", "emit"))
+        assert admit.start_s < prefill.start_s < prefill.end_s < admit.end_s
+        assert admit.end_s < decode.start_s < decode.end_s < token.start_s
+        assert token.end_s < emit.start_s < emit.end_s < step.end_s
+        assert token.parent_id == decode.parent_id == step.span_id
+        assert prefill.attrs["prompt_len"] == 20
+        assert "experts_touched" not in prefill.attrs
+        assert (decode.attrs["live"], decode.attrs["ahead"]) == (2, 1)
+        assert (token.attrs["request"], token.attrs["slot"],
+                token.attrs["ahead"]) == (req.id, req.slot, 1)
+        # one live slot's row in the block read; the prompt's 20 rows
+        assert 0 < decode.attrs["experts_touched"] <= cells
+        experts = req.routing[0][0]                     # [L, 20, k]
+        assert token.attrs["experts_touched"] == sum(
+            len(np.unique(layer)) for layer in experts) > cells
+        # the lone first request too: its first decode block was behind it
+        assert srv.stats()["admit_ahead"] == 2
+        assert metrics().counter(
+            "serve_admit_ahead_total").value() == ahead0 + 2
+        assert len(first.tokens) == 12 and len(req.tokens) == 6
+
+
 class TestKernelRead:
     """The pool read by the Pallas kernel (interpreted here) under a
     server: its work list is the live slots' own key blocks."""

@@ -37,11 +37,8 @@ from deeplearning4j_tpu.nn.conf.graph import (
     SubsetVertex,
     UnstackVertex,
 )
-from deeplearning4j_tpu.nn.conf.preprocessors import (
-    InputPreProcessor,
-    apply_preprocessor,
-)
-from deeplearning4j_tpu.nn.layers.base import get_layer_impl
+from deeplearning4j_tpu.nn.conf.preprocessors import InputPreProcessor
+from deeplearning4j_tpu.nn.layers.base import forward_layer, get_layer_impl
 from deeplearning4j_tpu.nn.updater import (
     UpdaterSpec,
     flat_apply_safe,
@@ -51,6 +48,7 @@ from deeplearning4j_tpu.nn.updater import (
     per_layer_apply_updaters,
 )
 from deeplearning4j_tpu.ops.losses import compute_loss
+from deeplearning4j_tpu.scopes import layer_scope, scope
 from deeplearning4j_tpu.perf.bucketing import (
     bucket_size,
     pad_axis0,
@@ -200,22 +198,14 @@ class ComputationGraph:
             in_mask = next((masks.get(n) for n in in_names
                             if masks.get(n) is not None), None)
             if name in conf.layers:
-                impl = self.layer_impls[name]
                 h = in_vals[0]
-                batch = h.shape[0]
-                pre = conf.preprocessors.get(name)
-                if pre is not None:
-                    h, rng = apply_preprocessor(pre, h, batch=batch, rng=rng)
-                sub_rng = None
-                if rng is not None:
-                    rng, sub_rng = jax.random.split(rng)
-                mask = in_mask if h.ndim == 3 else None
                 lstate = dict(net_state.get(name, {}))
                 if rnn_state is not None and name in rnn_state:
                     lstate.update(rnn_state[name])
-                h, lstate_out = impl.forward(
-                    params[name], h, lstate,
-                    train=train, rng=sub_rng, mask=mask)
+                h, lstate_out, rng = forward_layer(
+                    self.layer_impls[name], name, params[name], h, lstate,
+                    pre=conf.preprocessors.get(name), batch=h.shape[0],
+                    train=train, rng=rng, mask=in_mask)
                 if rnn_state is not None and name in rnn_state:
                     new_rnn_state[name] = {
                         k: lstate_out[k] for k in rnn_state[name]
@@ -229,8 +219,10 @@ class ComputationGraph:
                 values[name] = h
                 masks[name] = in_mask
             else:
-                values[name] = self._apply_vertex(
-                    conf.vertices[name], in_vals, in_names, values, masks)
+                with layer_scope("dsl.vertex", name):
+                    values[name] = self._apply_vertex(
+                        conf.vertices[name], in_vals, in_names, values,
+                        masks)
                 masks[name] = in_mask
         if collect:
             return values, new_net_state, new_rnn_state
@@ -300,14 +292,16 @@ class ComputationGraph:
             params, net_state, inputs, train=train, rng=rng,
             feature_masks=feature_masks, rnn_state=rnn_state)
         total = 0.0
-        for i, out_name in enumerate(self.conf.outputs):
-            lc = self.conf.layers.get(out_name)
-            if lc is None or not hasattr(lc, "loss_function"):
-                continue
-            lm = None if label_masks is None else label_masks[i]
-            total = total + compute_loss(lc.loss_function, outs[i], labels[i], lm)
-        for name, impl in self.layer_impls.items():
-            total = total + impl.l1_l2_penalty(params[name])
+        with scope("dsl.loss"):
+            for i, out_name in enumerate(self.conf.outputs):
+                lc = self.conf.layers.get(out_name)
+                if lc is None or not hasattr(lc, "loss_function"):
+                    continue
+                lm = None if label_masks is None else label_masks[i]
+                total = total + compute_loss(
+                    lc.loss_function, outs[i], labels[i], lm)
+            for name, impl in self.layer_impls.items():
+                total = total + impl.l1_l2_penalty(params[name])
         return total, (new_state, new_rnn)
 
     # ------------------------------------------------------------------
@@ -352,16 +346,17 @@ class ComputationGraph:
             params, net_state, inputs, train=True, rng=rng,
             feature_masks=feature_masks)
         total = 0.0
-        for i, out_name in enumerate(self.conf.outputs):
-            lc = self.conf.layers.get(out_name)
-            if lc is None or not hasattr(lc, "loss_function"):
-                continue
-            core = compute_loss(
-                lc.loss_function, outs[i], labels[i], label_masks[i])
-            d_mb = jnp.maximum(jnp.sum(label_masks[i]), 1.0)
-            total = total + core * (d_mb / d_full[i])
-        for name, impl in self.layer_impls.items():
-            total = total + impl.l1_l2_penalty(params[name]) / k
+        with scope("dsl.loss"):
+            for i, out_name in enumerate(self.conf.outputs):
+                lc = self.conf.layers.get(out_name)
+                if lc is None or not hasattr(lc, "loss_function"):
+                    continue
+                core = compute_loss(
+                    lc.loss_function, outs[i], labels[i], label_masks[i])
+                d_mb = jnp.maximum(jnp.sum(label_masks[i]), 1.0)
+                total = total + core * (d_mb / d_full[i])
+            for name, impl in self.layer_impls.items():
+                total = total + impl.l1_l2_penalty(params[name]) / k
         return total, new_state
 
     @functools.cached_property

@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.layers import LayerConf
+from deeplearning4j_tpu.nn.conf.preprocessors import apply_preprocessor
 from deeplearning4j_tpu.ops.activations import get_activation
+from deeplearning4j_tpu.scopes import layer_scope
 
 Params = Dict[str, jnp.ndarray]
 State = Dict[str, jnp.ndarray]
@@ -60,7 +62,32 @@ def get_layer_impl(conf: LayerConf) -> "LayerImpl":
     return impl_cls(conf)
 
 
+def forward_layer(impl: "LayerImpl", name, params, x, state, *, pre=None,
+                  batch=None, train: bool, rng, mask):
+    """One layer of a network as both network classes run it: its input
+    preprocessor, its share of the key, ``impl.forward`` -- all under the
+    scope of the layer's kind and, inside it, of the user's ``name`` for it
+    (``scopes.layer_scope``), so every op a layer lowers to carries both in
+    a device trace, forward and backward. ``mask`` reaches a layer whose
+    input is a series only. Returns ``(y, new_state, rng)`` with ``rng``
+    advanced."""
+    with layer_scope(impl.kind, name):
+        if pre is not None:
+            x, rng = apply_preprocessor(pre, x, batch=batch, rng=rng)
+        sub_rng = None
+        if rng is not None:
+            rng, sub_rng = jax.random.split(rng)
+        y, new_state = impl.forward(
+            params, x, state, train=train, rng=sub_rng,
+            mask=mask if x.ndim == 3 else None)
+    return y, new_state, rng
+
+
 class LayerImpl:
+    # the layer's scope in a lowered program: a ``dsl.*`` name of
+    # ``deeplearning4j_tpu/scopes.py``, by kind of layer
+    kind = "dsl.layer"
+
     def __init__(self, conf: LayerConf):
         self.conf = conf
 

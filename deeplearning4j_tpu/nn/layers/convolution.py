@@ -26,6 +26,8 @@ _DIMSPEC = ("NHWC", "HWIO", "NHWC")
 
 @register_layer_impl(L.ConvolutionLayer)
 class ConvolutionImpl(LayerImpl):
+    kind = "dsl.conv"
+
     def init_params(self, key):
         conf = self.conf
         kh, kw = conf.kernel_size
@@ -65,6 +67,8 @@ class GlobalPoolingImpl(LayerImpl):
     """Mean/max/sum/pnorm over spatial axes (NHWC [b,h,w,c] → [b,c]) or the
     time axis (RNN [b,t,f] → [b,f]); honors the feature mask for
     variable-length series (masked steps excluded from the statistic)."""
+
+    kind = "dsl.pool"
 
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         conf = self.conf
@@ -108,6 +112,8 @@ class GlobalPoolingImpl(LayerImpl):
 
 @register_layer_impl(L.SubsamplingLayer)
 class SubsamplingImpl(LayerImpl):
+    kind = "dsl.pool"
+
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         conf = self.conf
         kh, kw = conf.kernel_size

@@ -23,6 +23,8 @@ from deeplearning4j_tpu.ops.initializers import init_weights
 
 @register_layer_impl(L.DenseLayer)
 class DenseImpl(LayerImpl):
+    kind = "dsl.dense"
+
     def init_params(self, key):
         conf = self.conf
         wkey, _ = jax.random.split(key)
@@ -59,6 +61,8 @@ class RnnOutputImpl(DenseImpl):
 
 @register_layer_impl(L.EmbeddingLayer)
 class EmbeddingImpl(LayerImpl):
+    kind = "dsl.embed"
+
     def init_params(self, key):
         conf = self.conf
         policy = get_policy()
@@ -86,6 +90,8 @@ class EmbeddingImpl(LayerImpl):
 
 @register_layer_impl(L.ActivationLayer)
 class ActivationImpl(LayerImpl):
+    kind = "dsl.act"
+
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout(x, train=train, rng=rng)
         return self.activation_fn()(x), state
@@ -93,11 +99,15 @@ class ActivationImpl(LayerImpl):
 
 @register_layer_impl(L.DropoutLayer)
 class DropoutImpl(LayerImpl):
+    kind = "dsl.act"
+
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         return self.maybe_dropout(x, train=train, rng=rng), state
 
 
 @register_layer_impl(L.LossLayer)
 class LossLayerImpl(LayerImpl):
+    kind = "dsl.act"
+
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         return self.activation_fn()(x), state

@@ -23,6 +23,8 @@ class BatchNormImpl(LayerImpl):
     channels for NHWC 4-D), matching the reference's per-feature/per-channel
     statistics."""
 
+    kind = "dsl.norm"
+
     def init_params(self, key):
         conf = self.conf
         policy = get_policy()
@@ -69,6 +71,8 @@ class BatchNormImpl(LayerImpl):
 @register_layer_impl(L.LocalResponseNormalization)
 class LRNImpl(LayerImpl):
     """Cross-channel LRN on NHWC: y = x / (k + α·Σ_{j∈window} x_j²)^β."""
+
+    kind = "dsl.norm"
 
     def forward(self, params, x, state, *, train=False, rng=None, mask=None):
         conf = self.conf

@@ -33,6 +33,8 @@ class AutoEncoderImpl(LayerImpl):
     """Encoder y = act(xW + b); decoder z = act(yWᵀ + vb) (tied weights, as
     in the reference's params W, b, vb from PretrainParamInitializer)."""
 
+    kind = "dsl.dense"
+
     def init_params(self, key):
         conf = self.conf
         policy = get_policy()
@@ -78,6 +80,8 @@ class RecursiveAutoEncoderImpl(LayerImpl):
     encoding. Masked timesteps (variable-length series) hold the carry and
     contribute no reconstruction loss. Rank-2 inputs are length-1 sequences.
     """
+
+    kind = "dsl.dense"
 
     def init_params(self, key):
         conf = self.conf
@@ -140,6 +144,8 @@ class RecursiveAutoEncoderImpl(LayerImpl):
 
 @register_layer_impl(L.RBM)
 class RBMImpl(LayerImpl):
+    kind = "dsl.dense"
+
     def init_params(self, key):
         conf = self.conf
         policy = get_policy()

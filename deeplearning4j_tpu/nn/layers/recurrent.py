@@ -104,6 +104,7 @@ def _lstm_scan(params, x, act, *, peepholes: bool, mask=None, h0=None, c0=None,
 
 @register_layer_impl(L.GravesLSTM)
 class GravesLSTMImpl(LayerImpl):
+    kind = "dsl.recurrent"
     peepholes = True
 
     def init_params(self, key):
@@ -134,6 +135,8 @@ class BiLSTMImpl(LayerImpl):
     """Forward + backward Graves LSTM, outputs summed (the reference's ADD
     combination, GravesBidirectionalLSTM.java)."""
 
+    kind = "dsl.recurrent"
+
     def init_params(self, key):
         kf, kb = jax.random.split(key)
         conf = self.conf
@@ -153,6 +156,8 @@ class BiLSTMImpl(LayerImpl):
 
 @register_layer_impl(L.GRU)
 class GRUImpl(LayerImpl):
+    kind = "dsl.recurrent"
+
     def init_params(self, key):
         conf = self.conf
         policy = get_policy()
@@ -217,6 +222,8 @@ class ImageLSTMImpl(LayerImpl):
     host-driven beam search (the reference's BeamSearch inner class :282)
     around a jitted single-step cell.
     """
+
+    kind = "dsl.recurrent"
 
     def _hidden(self) -> int:
         return self.conf.hidden_size or self.conf.n_out

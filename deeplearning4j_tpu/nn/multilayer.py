@@ -32,7 +32,7 @@ from deeplearning4j_tpu.nn.conf.enums import (
 )
 from deeplearning4j_tpu.nn.conf.neural_net import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.conf.preprocessors import apply_preprocessor
-from deeplearning4j_tpu.nn.layers.base import get_layer_impl
+from deeplearning4j_tpu.nn.layers.base import forward_layer, get_layer_impl
 from deeplearning4j_tpu.nn.updater import (
     UpdaterSpec,
     apply_updater,
@@ -43,6 +43,7 @@ from deeplearning4j_tpu.nn.updater import (
     per_layer_apply_updaters,
 )
 from deeplearning4j_tpu.ops.losses import compute_loss
+from deeplearning4j_tpu.scopes import scope
 from deeplearning4j_tpu.perf.bucketing import (
     bucket_size,
     pad_axis0,
@@ -162,20 +163,14 @@ class MultiLayerNetwork:
         new_rnn_state = {} if rnn_state is not None else None
         h = x
         for i, impl in enumerate(self.layers):
-            pre = self.conf.input_preprocessors.get(i)
-            if pre is not None:
-                h, rng = apply_preprocessor(pre, h, batch=batch, rng=rng)
-            sub_rng = None
-            if rng is not None:
-                rng, sub_rng = jax.random.split(rng)
             si = str(i)
             lstate = dict(net_state.get(si, {}))
             if rnn_state is not None and si in rnn_state:
                 lstate.update(rnn_state[si])
-            mask = feature_mask if h.ndim == 3 else None
-            h, lstate_out = impl.forward(
-                params[si], h, lstate, train=train, rng=sub_rng, mask=mask
-            )
+            h, lstate_out, rng = forward_layer(
+                impl, si, params[si], h, lstate,
+                pre=self.conf.input_preprocessors.get(i), batch=batch,
+                train=train, rng=rng, mask=feature_mask)
             if rnn_state is not None and si in rnn_state:
                 new_rnn_state[si] = {
                     k: lstate_out[k] for k in rnn_state[si]
@@ -205,11 +200,14 @@ class MultiLayerNetwork:
             params, net_state, x, train=train, rng=rng,
             feature_mask=feature_mask, rnn_state=rnn_state,
         )
-        loss = compute_loss(self._output_conf.loss_function, out, y, label_mask)
-        penalty = 0.0
-        for i, impl in enumerate(self.layers):
-            penalty = penalty + impl.l1_l2_penalty(params[str(i)])
-        return loss + penalty, (new_state, new_rnn)
+        with scope("dsl.loss"):
+            loss = compute_loss(
+                self._output_conf.loss_function, out, y, label_mask)
+            penalty = 0.0
+            for i, impl in enumerate(self.layers):
+                penalty = penalty + impl.l1_l2_penalty(params[str(i)])
+            total = loss + penalty
+        return total, (new_state, new_rnn)
 
     # ------------------------------------------------------------------
     # the jitted train step (replaces Solver/StochasticGradientDescent +
@@ -260,13 +258,15 @@ class MultiLayerNetwork:
         out, new_state, _, _ = self._forward(
             params, net_state, x, train=True, rng=rng,
             feature_mask=feature_mask)
-        core = compute_loss(
-            self._output_conf.loss_function, out, y, label_mask)
-        d_mb = jnp.maximum(jnp.sum(label_mask), 1.0)
-        pen = 0.0
-        for i, impl in enumerate(self.layers):
-            pen = pen + impl.l1_l2_penalty(params[str(i)])
-        return core * (d_mb / d_full) + pen / k, new_state
+        with scope("dsl.loss"):
+            core = compute_loss(
+                self._output_conf.loss_function, out, y, label_mask)
+            d_mb = jnp.maximum(jnp.sum(label_mask), 1.0)
+            pen = 0.0
+            for i, impl in enumerate(self.layers):
+                pen = pen + impl.l1_l2_penalty(params[str(i)])
+            share = core * (d_mb / d_full) + pen / k
+        return share, new_state
 
     @functools.cached_property
     def _train_step(self):

@@ -41,6 +41,7 @@ from deeplearning4j_tpu.perf.epoch_cache import (
     epoch_schedule,
     stream_epochs,
 )
+from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["loss_grads", "accum_grads", "optimizer_step", "epoch_run_fn",
            "multi_step_fn", "tbptt_fn", "jit_step", "epoch_train_step",
@@ -142,7 +143,8 @@ def optimizer_step(net, params, updater, net_state, iteration,
         # master-weights policy: ONE bf16 copy for forward/backward,
         # grads upcast ONCE, updater applies to the f32 masters
         # (identity casts under the single-dtype policies)
-        fwd_params = policy.compute_copy(params)
+        with scope("dsl.cast"):
+            fwd_params = policy.compute_copy(params)
         if accum_steps > 1:
             grads, loss, stepped_state = accum_grads(
                 net, fwd_params, net_state, batch, rng, accum_steps)
@@ -152,17 +154,20 @@ def optimizer_step(net, params, updater, net_state, iteration,
                 net, fwd_params, net_state, batch, rng, rnn_state)
         # sentinel + telemetry norms read the f32 grads (post-upcast): a
         # bf16 overflow to inf is preserved by the widening cast
-        grads = policy.master_grads(grads)
+        with scope("dsl.cast"):
+            grads = policy.master_grads(grads)
 
         def apply(_=None):
-            p2, u2 = net._apply_updaters(params, updater, grads,
-                                         iteration, lr_scale_host)
+            with scope("dsl.update"):
+                p2, u2 = net._apply_updaters(params, updater, grads,
+                                             iteration, lr_scale_host)
             return p2, u2, stepped_state
 
         if guard:
             from deeplearning4j_tpu.resilience.guard import tree_all_finite
 
-            ok = jnp.isfinite(loss) & tree_all_finite(grads)
+            with scope("dsl.update"):
+                ok = jnp.isfinite(loss) & tree_all_finite(grads)
             new_params, new_updater, new_state = jax.lax.cond(
                 ok, apply, lambda _: (params, updater, net_state), None)
             tripped = ~ok
@@ -173,10 +178,11 @@ def optimizer_step(net, params, updater, net_state, iteration,
         if metrics_stride:
             from deeplearning4j_tpu.monitor.pack import step_metrics
 
-            metrics = step_metrics(
-                params, new_params, grads,
-                net._lr_scale(iteration, lr_scale_host), iteration,
-                metrics_stride)
+            with scope("dsl.update"):
+                metrics = step_metrics(
+                    params, new_params, grads,
+                    net._lr_scale(iteration, lr_scale_host), iteration,
+                    metrics_stride)
     return (new_params, new_updater, new_state, loss, new_rnn, tripped,
             metrics)
 
@@ -204,12 +210,14 @@ def epoch_run_fn(net, shuffle: bool, accum_steps: int = 1,
         n = jax.tree_util.tree_leaves(xs)[0].shape[0]
 
         def epoch_body(carry, ekey):
-            order, step_keys = epoch_schedule(ekey, n, shuffle)
+            with scope("dsl.data"):
+                order, step_keys = epoch_schedule(ekey, n, shuffle)
 
             def batch_body(c2, inp):
                 params, upd, nst, it = c2
                 i, rng = inp
-                batch = jax.tree_util.tree_map(lambda a: a[i], stacks)
+                with scope("dsl.data"):
+                    batch = jax.tree_util.tree_map(lambda a: a[i], stacks)
                 p2, u2, s2, loss, _, tripped, m = optimizer_step(
                     net, params, upd, nst, it, lr_scale_host, batch, rng,
                     accum_steps=accum_steps, guard=guard,
@@ -276,7 +284,8 @@ def tbptt_fn(net):
             shaped = a.reshape((a.shape[0], n_win, window) + a.shape[2:])
             return jnp.moveaxis(shaped, 1, 0)
 
-        wins = [to_windows(a) for a, w in zip(flat, windowed) if w]
+        with scope("dsl.data"):
+            wins = [to_windows(a) for a, w in zip(flat, windowed) if w]
 
         def body(carry, inp):
             params, upd, nst, rnn, it = carry
@@ -362,7 +371,7 @@ def run_fused_epochs(net, cache, num_epochs: int, chunk_epochs, program, *,
     guarded = guard != "off"
     stride = fused_metrics_stride(telemetry)
 
-    def scope():
+    def on_mesh():
         return contextlib.nullcontext() if mesh is None else mesh()
 
     def launch(epoch_keys):
@@ -372,7 +381,7 @@ def run_fused_epochs(net, cache, num_epochs: int, chunk_epochs, program, *,
         # the wrapper's programs are pinned to their mesh), so this must
         # pick up the program traced for the NEW placements
         step = program(shuffle, accum, guarded, stride)
-        with scope():
+        with on_mesh():
             out = step(*step_state(net), *cache.stacks, epoch_keys)
         net.params, net.updater_state, net.net_state = out[:3]
         return (out[3], out[4] if guarded else None,
@@ -387,7 +396,7 @@ def run_fused_epochs(net, cache, num_epochs: int, chunk_epochs, program, *,
         # pre-raise diagnostic even under FSDP, where it temporarily
         # re-replicates the state it is about to abort with
         batch = jax.tree_util.tree_map(lambda a: a[i], cache.stacks)
-        with scope():
+        with on_mesh():
             p, u, s, loss, *_ = net._train_step(
                 params, upd, nst, jnp.asarray(it, jnp.int32),
                 jnp.asarray(net._lr_scale_host, jnp.float32), batch, rng,

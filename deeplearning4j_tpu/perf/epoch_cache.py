@@ -616,7 +616,10 @@ def drive_epoch_chunks(net, cache, num_epochs: int,
     whole run without listeners, one epoch with them. Returns the
     concatenated ``[E, N]`` loss history.
 
-    Telemetry (the observability bus around the fast path): every chunk
+    Telemetry (the observability bus around the fast path): the whole
+    run is one ``epoch.run`` tracer span (key splits, dispatches,
+    listeners, readbacks, the history's concatenation — not the caller's
+    wait for the history); every chunk
     dispatch runs inside an ``epoch.chunk`` tracer span (and bumps the
     ``train_chunk_dispatches_total`` counter); per-chunk host readbacks
     get ``epoch.readback`` spans; the metrics-pack history (when the
@@ -706,129 +709,136 @@ def drive_epoch_chunks(net, cache, num_epochs: int,
     watchdog = StepWatchdog(
         chunk_deadline_s(chunk_epochs * cache.n_batches))
     net._chunk_watchdog = watchdog  # introspection (tests, metrics)
-    # the run-ledger window opens here and closes in the finally below:
-    # the ledger (and the flight recorder, when DL4J_FLIGHT is on) only
-    # ever hears from this driver at chunk boundaries — never from
-    # inside a traced program (dl4j-lint's host-sync rule enforces it)
-    ledger_run_start(model=model_name, epochs=num_epochs,
+    # the whole run under ONE program span: every device program the
+    # driver launches (the chunk program, the eager key split and score
+    # read beside it) and every idle nanosecond between them lies inside
+    # a ``dl4j.epoch.run`` event of a device trace. The wait for the loss
+    # history is the caller's and stays outside
+    run_attrs = dict(model=model_name, epochs=num_epochs,
                      steps=num_epochs * cache.n_batches,
                      chunk_epochs=chunk_epochs, guard=guard)
-    try:
-        with watchdog:
-            while done < num_epochs:
-                pending = getattr(net, "_pending_mesh", None)
-                if pending is not None:
-                    net._pending_mesh = None
-                    new_mesh = pending[0]
-                    if reshard is None:
-                        logging.getLogger(__name__).warning(
-                            "elastic reshard requested but this fit "
-                            "path pins per-mesh programs; request "
-                            "dropped (use the plain fit_epochs path)")
-                    else:
-                        with tracer().span("reshard.elastic",
-                                           model=model_name,
-                                           epoch0=done) as rs:
-                            reshard(new_mesh)
-                            rs.attrs["n_shard"] = cache.n_shard
-                        record_counter("elastic_reshards_total",
-                                       model=model_name)
-                        watchdog.set_deadline(chunk_deadline_s(
-                            chunk_epochs * cache.n_batches,
-                            base_shard / max(1, cache.n_shard)))
-                k = min(chunk_epochs, num_epochs - done)
-                faults.fault_point("epoch.chunk")
-                keys = jax.random.split(net._rng, k + 1)
-                net._rng = keys[0]
-                snapshot = None
-                it0 = net.iteration_count
-                if guard == "raise":
-                    # launch donates params/updater/net state; keep the
-                    # last-good copy so a trip can be replayed per-step
-                    snapshot = tuple(
-                        jax.tree_util.tree_map(jnp.copy, t)
-                        for t in (net.params, net.updater_state,
-                                  net.net_state))
-                # the span times the HOST-side dispatch (the XLA launch
-                # returns before the chunk completes; completion shows up
-                # in the next blocking read's epoch.readback span)
-                ledger_chunk_start(model=model_name, epoch0=done,
-                                   epochs=k)
-                with tracer().span("epoch.chunk", model=model_name,
-                                   epochs=k,
-                                   steps=k * cache.n_batches,
-                                   epoch0=done):
-                    hist, trips, mets = launch_chunk(keys[1:])
-                watchdog.beat()
-                ledger_chunk_done(model=model_name, epoch0=done,
-                                  epochs=k)
-                net._train_dispatches += 1
-                record_counter("train_chunk_dispatches_total",
-                               model=model_name)
-                if profiling:
-                    from deeplearning4j_tpu.monitor.memory import (
-                        sample_hbm_watermark)
+    with tracer().span("epoch.run", **run_attrs):
+        # the run-ledger window opens here and closes in the finally
+        # below: the ledger (and the flight recorder, when DL4J_FLIGHT is
+        # on) only ever hears from this driver at chunk boundaries — never
+        # from inside a traced program (dl4j-lint's host-sync rule)
+        ledger_run_start(**run_attrs)
+        try:
+            with watchdog:
+                while done < num_epochs:
+                    pending = getattr(net, "_pending_mesh", None)
+                    if pending is not None:
+                        net._pending_mesh = None
+                        new_mesh = pending[0]
+                        if reshard is None:
+                            logging.getLogger(__name__).warning(
+                                "elastic reshard requested but this fit "
+                                "path pins per-mesh programs; request "
+                                "dropped (use the plain fit_epochs path)")
+                        else:
+                            with tracer().span("reshard.elastic",
+                                               model=model_name,
+                                               epoch0=done) as rs:
+                                reshard(new_mesh)
+                                rs.attrs["n_shard"] = cache.n_shard
+                            record_counter("elastic_reshards_total",
+                                           model=model_name)
+                            watchdog.set_deadline(chunk_deadline_s(
+                                chunk_epochs * cache.n_batches,
+                                base_shard / max(1, cache.n_shard)))
+                    k = min(chunk_epochs, num_epochs - done)
+                    faults.fault_point("epoch.chunk")
+                    keys = jax.random.split(net._rng, k + 1)
+                    net._rng = keys[0]
+                    snapshot = None
+                    it0 = net.iteration_count
+                    if guard == "raise":
+                        # launch donates params/updater/net state; keep the
+                        # last-good copy so a trip can be replayed per-step
+                        snapshot = tuple(
+                            jax.tree_util.tree_map(jnp.copy, t)
+                            for t in (net.params, net.updater_state,
+                                      net.net_state))
+                    # the span times the HOST-side dispatch (the XLA launch
+                    # returns before the chunk completes; completion shows up
+                    # in the next blocking read's epoch.readback span)
+                    ledger_chunk_start(model=model_name, epoch0=done,
+                                       epochs=k)
+                    with tracer().span("epoch.chunk", model=model_name,
+                                       epochs=k,
+                                       steps=k * cache.n_batches,
+                                       epoch0=done):
+                        hist, trips, mets = launch_chunk(keys[1:])
+                    watchdog.beat()
+                    ledger_chunk_done(model=model_name, epoch0=done,
+                                      epochs=k)
+                    net._train_dispatches += 1
+                    record_counter("train_chunk_dispatches_total",
+                                   model=model_name)
+                    if profiling:
+                        from deeplearning4j_tpu.monitor.memory import (
+                            sample_hbm_watermark)
 
-                    net._hbm_watermarks.append(
-                        sample_hbm_watermark(tag="epoch.chunk"))
-                net.iteration_count += k * cache.n_batches
-                net._score = hist[-1, -1]  # device scalar
-                if mets is not None:
-                    metrics_chunks.append(mets)  # device; no sync
-                if trips is not None:
-                    if defer_inspect:
-                        sentinel_chunks.append(trips)  # device; no sync
-                    else:
-                        # halve_lr/raise act between chunks: this read
-                        # blocks on the chunk's completion — the one
-                        # host sync those policies cost per chunk
-                        with tracer().span("epoch.readback",
-                                           what="sentinel"):
-                            t = np.asarray(trips)
-                        sentinel_chunks.append(t)
-                        if t.any():
-                            _enforce_nan_guard(net, guard, t, done,
-                                               keys[1:], shuffle,
-                                               cache.n_batches, snapshot,
-                                               it0, replay_step)
-                history.append(hist)
-                done += k
-                for listener in net.listeners:
-                    chunk_cb = getattr(listener, "chunk_done", None)
-                    if chunk_cb is not None:
-                        chunk_cb(net, it0, hist, metrics=mets)
-                    else:  # pre-telemetry listener protocol
-                        listener.iteration_done(net, net.iteration_count)
-                if on_chunk is not None and on_chunk(done):
-                    stopped = True
-                    break
-    except BaseException as e:
-        run_error = e
-        raise
-    finally:
-        # flush even when the raise policy aborts the run mid-chunk: a
-        # TrainingDivergedError handler reads the history that tripped it
-        if metrics_chunks:
-            net._last_metrics = _concat_chunks(metrics_chunks)
-        if sentinel_chunks:
-            with tracer().span("epoch.readback", what="sentinel_flush"):
-                full = np.concatenate([np.asarray(t)
-                                       for t in sentinel_chunks])
-            net._last_sentinel = full
-            if defer_inspect and full.any():
-                # the deferred skip-policy report (epoch indices are
-                # absolute: the history covers the run from epoch 0)
-                _enforce_nan_guard(net, guard, full, 0, None, shuffle,
-                                   cache.n_batches, None, 0, None)
-        # close the ledger window LAST so the sentinel flush above is
-        # still inside the run it belongs to; the status string is what
-        # flight_report classifies a dead run's sibling from
-        ledger_run_end(
-            status=(f"error:{type(run_error).__name__}"
-                    if run_error is not None
-                    else ("stopped" if stopped else "clean")),
-            model=model_name, epochs_done=done)
-    return _concat_chunks(history)
+                        net._hbm_watermarks.append(
+                            sample_hbm_watermark(tag="epoch.chunk"))
+                    net.iteration_count += k * cache.n_batches
+                    net._score = hist[-1, -1]  # device scalar
+                    if mets is not None:
+                        metrics_chunks.append(mets)  # device; no sync
+                    if trips is not None:
+                        if defer_inspect:
+                            sentinel_chunks.append(trips)  # device; no sync
+                        else:
+                            # halve_lr/raise act between chunks: this read
+                            # blocks on the chunk's completion — the one
+                            # host sync those policies cost per chunk
+                            with tracer().span("epoch.readback",
+                                               what="sentinel"):
+                                t = np.asarray(trips)
+                            sentinel_chunks.append(t)
+                            if t.any():
+                                _enforce_nan_guard(net, guard, t, done,
+                                                   keys[1:], shuffle,
+                                                   cache.n_batches, snapshot,
+                                                   it0, replay_step)
+                    history.append(hist)
+                    done += k
+                    for listener in net.listeners:
+                        chunk_cb = getattr(listener, "chunk_done", None)
+                        if chunk_cb is not None:
+                            chunk_cb(net, it0, hist, metrics=mets)
+                        else:  # pre-telemetry listener protocol
+                            listener.iteration_done(net, net.iteration_count)
+                    if on_chunk is not None and on_chunk(done):
+                        stopped = True
+                        break
+        except BaseException as e:
+            run_error = e
+            raise
+        finally:
+            # flush even when the raise policy aborts the run mid-chunk: a
+            # TrainingDivergedError handler reads the history that tripped it
+            if metrics_chunks:
+                net._last_metrics = _concat_chunks(metrics_chunks)
+            if sentinel_chunks:
+                with tracer().span("epoch.readback", what="sentinel_flush"):
+                    full = np.concatenate([np.asarray(t)
+                                           for t in sentinel_chunks])
+                net._last_sentinel = full
+                if defer_inspect and full.any():
+                    # the deferred skip-policy report (epoch indices are
+                    # absolute: the history covers the run from epoch 0)
+                    _enforce_nan_guard(net, guard, full, 0, None, shuffle,
+                                       cache.n_batches, None, 0, None)
+            # close the ledger window LAST so the sentinel flush above is
+            # still inside the run it belongs to; the status string is what
+            # flight_report classifies a dead run's sibling from
+            ledger_run_end(
+                status=(f"error:{type(run_error).__name__}"
+                        if run_error is not None
+                        else ("stopped" if stopped else "clean")),
+                model=model_name, epochs_done=done)
+        return _concat_chunks(history)
 
 
 def _concat_chunks(chunks):

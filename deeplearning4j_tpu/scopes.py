@@ -1,5 +1,5 @@
-"""The scope vocabulary: every ``jax.named_scope`` the model and serving code
-opens, listed once.
+"""The scope vocabulary: every ``jax.named_scope`` the model, serving and
+config-DSL training code opens, listed once.
 
 A scope is metadata on the lowered program: it reaches a device trace as the
 ``tf_op`` stat of XLA's own ops (``jit(step)/transpose(jvp(ffn.dense))/
@@ -25,6 +25,16 @@ delta-rule decode step's (``pallas/delta_step.py``) under ``gdn.step``
 since PR 45 (under ``kda.step`` once ``kda.recur`` takes it there), power
 retention's (``pallas/retention_step.py``) under ``ret.step``.
 
+The config DSL's names (``dsl.*``, PR 49) are by KIND of layer: a
+``LayerImpl`` says its kind as a class attribute (``dsl.layer`` on the base
+class), both network classes run a layer through ``nn/layers/base.
+forward_layer``, and that opens ``layer_scope(kind, name)``: the kind and,
+inside it, ``layer.<the user's name for the layer>``. The readers label by
+the kind, whatever a user calls a layer (no vocabulary name starts with
+``layer.``);
+``benchmarks/tools/dsl_layer_report.py`` reads the names. No kernel sits under
+``nn/`` today; the rule above holds for one that comes.
+
 Norms between the halves of a block stay bare on purpose: XLA fuses the next
 norm's statistics into the fusion that ends the previous matmul, and which op
 of a multi-output fusion gives it its ``tf_op`` is XLA's choice (PERF.md
@@ -32,6 +42,9 @@ section 5 says where each straddling fusion landed on the chip).
 """
 
 from __future__ import annotations
+
+import contextlib
+import re
 
 import jax
 
@@ -83,7 +96,41 @@ SCOPES = {
     "mtp.proj": "the module's two input norms and M, [2 D, D]",
     "spec.accept": "a speculative round's accept / resample rule, its "
                    "emitted block and the loop state's advance",
+    # the config DSL (nn/): a layer's scope is its kind, LayerImpl.kind,
+    # and inside it the user's own name for the layer (layer_scope)
+    "dsl.data": "a chunk program's own input work: the epoch's permutation "
+                "and key splits, the gather of a batch from the resident "
+                "stacks; TBPTT's cut into windows",
+    "dsl.conv": "ConvolutionImpl: input dropout, casts, the convolution, the "
+                "bias add, the activation -- so, backward, the weight, data "
+                "and bias gradients",
+    "dsl.norm": "BatchNormImpl, LRNImpl: statistics, running averages, "
+                "normalisation, scale and shift, activation",
+    "dsl.pool": "SubsamplingImpl, GlobalPoolingImpl",
+    "dsl.dense": "DenseImpl, OutputImpl, RnnOutputImpl, the pretrain "
+                 "layers' forward (auto-encoders, RBM)",
+    "dsl.embed": "EmbeddingImpl",
+    "dsl.recurrent": "the LSTM / GRU family of nn/layers/recurrent.py",
+    "dsl.act": "ActivationImpl, DropoutImpl, LossLayerImpl's forward",
+    "dsl.vertex": "a ComputationGraph vertex: merge, element-wise (a "
+                  "residual add), subset, stack, the rest",
+    "dsl.layer": "a LayerImpl of a kind with no name of its own (a user's "
+                 "registered layer): the default on the base class",
+    "dsl.loss": "compute_loss and the L1/L2 penalties",
+    "dsl.cast": "optimizer_step's compute-dtype copy of the parameters and "
+                "the gradients' cast back to the master dtype",
+    "dsl.update": "net._apply_updaters: gradient normalisation, the "
+                  "updater's math, the parameter update; the sentinel's "
+                  "finite check and the metrics pack where compiled in",
 }
+
+# a user's layer or vertex name as a path component: no vocabulary name
+# starts with it, so a layer called "lm.head" is read by its kind. Letters and
+# a dot, as the vocabulary's own names: XLA's exporter cuts an op's name at
+# the first "@" (a layer's name and the primitive after it never reached the
+# compiled program's metadata with that prefix; tests/test_dsl_scopes.py)
+LAYER_PREFIX = "layer."
+_NOT_IN_A_NAME = re.compile(r"[^\w.\-]")
 
 
 def scope(name: str):
@@ -92,3 +139,15 @@ def scope(name: str):
         raise ValueError(f"{name!r} is no scope of scopes.py: "
                          f"{sorted(SCOPES)}")
     return jax.named_scope(name)
+
+
+@contextlib.contextmanager
+def layer_scope(kind: str, name):
+    """The scope of one layer or vertex of a DSL network: its kind, a name
+    of the vocabulary, and inside it ``layer.<the user's name>`` (an index
+    for a ``MultiLayerNetwork``), so an op's ``tf_op`` reads
+    ``.../transpose(jvp(dsl.conv))/layer.s0b0_c1/conv_general_dilated``. The readers label by the kind; the name is for
+    ``benchmarks/tools/dsl_layer_report.py``."""
+    with scope(kind), jax.named_scope(
+            LAYER_PREFIX + _NOT_IN_A_NAME.sub("_", str(name))):
+        yield

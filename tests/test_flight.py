@@ -294,6 +294,49 @@ class TestRunLedger:
                            chunk_epochs=1, guard="raise")
         runs = run_ledger().report()["runs"]
         assert runs and runs[-1]["status"].startswith("error:")
+        # the run's own span closed too, with what failed stamped on it
+        (run,) = [s for s in tracer().spans() if s.name == "epoch.run"]
+        assert run.end_s is not None
+        assert run.attrs["error"].startswith("TrainingDivergedError")
+
+    def test_a_fit_epochs_call_is_one_epoch_run_span(self):
+        """``epoch.run`` holds every chunk's dispatch and readback; its
+        attrs are the ledger's ``run_start`` ones, all scalars (they reach
+        the profiler)."""
+        net = _ff_net()
+        net.fit_epochs(ListDataSetIterator(_ff_data(), 12), 3,
+                       chunk_epochs=1, guard="halve_lr")
+        spans = tracer().spans()
+        (run,) = [s for s in spans if s.name == "epoch.run"]
+        assert run.attrs == {"model": "MultiLayerNetwork", "epochs": 3,
+                             "steps": 12, "chunk_epochs": 1,
+                             "guard": "halve_lr"}
+        assert "error" not in run.attrs and run.parent_id is None
+        inside = [s for s in spans
+                  if s.name in ("epoch.chunk", "epoch.readback")]
+        # a dispatch and a sentinel read a chunk, the flush at the end
+        assert len(inside) == 7
+        assert all(s.parent_id == run.span_id for s in inside)
+        assert all(run.start_s <= s.start_s and s.end_s <= run.end_s
+                   for s in inside)
+
+    def test_the_ledger_books_nothing_for_epoch_run(self):
+        """A span name the ledger does not know moves no state: the report
+        of a window with an ``epoch.run`` span is the report without."""
+        def report(spans):
+            clock = FakeClock(0.0)
+            ledger = RunLedger(clock=clock, span_source=lambda: spans)
+            clock.t = 2.0
+            ledger.run_start(model="X", epochs=2)
+            clock.t = 9.0
+            ledger.run_end(status="clean")
+            clock.t = 10.0
+            return ledger.report()
+
+        spans = [_span("cache.build", 0, 2), _span("epoch.chunk", 2, 3),
+                 _span("retry.sleep", 4, 5)]
+        assert report(spans + [_span("epoch.run", 2, 9, model="X")]) \
+            == report(spans)
 
 
 # ---------------------------------------------------------------------------

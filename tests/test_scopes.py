@@ -24,6 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from lowered_paths import op_paths  # noqa: E402  (tests/ is on the path)
+
 from benchmarks.layer_metrics import _scopes  # noqa: E402
 from benchmarks.lib import xplane  # noqa: E402
 from deeplearning4j_tpu import scopes  # noqa: E402
@@ -112,32 +114,8 @@ CASES = [(m, p) for m in MODELS for p in ("train", "prefill", "decode")]
 
 # ---- (a) no matmul is left unscoped -----------------------------------------
 def _matmul_paths(text):
-    """The name-stack path of every ``dot_general`` of a lowered module
-    (``as_text(debug_info=True)``). A function lowered once and called
-    (``closed_call``: a scan's body, an inner jit) names its ops relative to
-    itself, and XLA joins the call's name on when it inlines: so does this,
-    once for each place the function is called from."""
-    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    calls, dots, func = {}, [], None
-    for line in text.splitlines():
-        m = re.search(r"func\.func (?:\w+ )?@([\w.]+)\(", line)
-        if m:
-            func = m.group(1)
-        at = re.search(r"loc\((#loc\d+)\)\s*$", line)
-        name = locs.get(at.group(1), "") if at else ""
-        m = re.search(r"\bcall @([\w.]+)\(", line)
-        if m:
-            calls.setdefault(m.group(1), []).append((func, name))
-        elif "stablehlo.dot_general" in line:
-            dots.append((func, name))
-
-    def paths(func, name):
-        sites = calls.get(func)
-        if not sites:
-            return [name]
-        return [p + "/" + name for f, n in sites for p in paths(f, n)]
-
-    return [p for func, name in dots for p in paths(func, name)]
+    """The name-stack path of every ``dot_general`` of a lowered module."""
+    return op_paths(text, ("stablehlo.dot_general",))
 
 
 @pytest.mark.parametrize("model,program", CASES)

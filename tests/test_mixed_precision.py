@@ -476,6 +476,106 @@ class TestFusedUpdaterSweep:
             np.asarray(params["1"]["W"] - 0.1 * g1["W"]),
             rtol=0, atol=1e-7)
 
+    @pytest.mark.parametrize("n", [15, 1024, 1025, 2 * 1024 + 10])
+    def test_flat_vectors_are_whole_tiles(self, n):
+        """Every flat vector of a group is a multiple of 1,024 elements
+        long (the TPU's dense one-dimensional tile; ResNet-18's
+        11,176,970 became ``[1117697, 10]`` under an (8, 128) tile), by a
+        zeros tail INSIDE the one concatenate, and a group that already
+        is gets no tail."""
+        spec = UpdaterSpec(kind=Updater.ADAM, learning_rate=0.05)
+        sweep = _sweep([("0", spec)])
+        params, state, grads = _layers_of({"W": (n - 3, 1), "b": (3,)}, spec)
+        cats = _concat_eqns(sweep, params, state, grads)
+        assert len(cats) == 3                       # g, m, v
+        for e in cats:
+            assert e.outvars[0].aval.shape[0] % 1024 == 0
+            assert e.outvars[0].aval.shape[0] - n < 1024
+            assert len(e.invars) == (2 if n % 1024 == 0 else 3)
+        new_p, new_u = sweep(params, state, grads)
+        assert new_p["0"]["W"].shape == (n - 3, 1)
+        assert new_u["0"]["b"]["m"].shape == (3,)
+
+    @pytest.mark.parametrize("kind", [Updater.SGD, Updater.NESTEROVS,
+                                      Updater.ADAGRAD, Updater.RMSPROP,
+                                      Updater.ADADELTA, Updater.ADAM])
+    def test_zero_tail_is_finite_and_dropped(self, kind):
+        """The tail is g = 0 over zero state: its step is
+        0 / (sqrt(0) + eps) = 0 for every kind, never a NaN or an
+        infinity (each op checked as it runs), and the live leaves are
+        bitwise the per-layer loop's — on a first step and on a second
+        one over the state the first left."""
+        from deeplearning4j_tpu.nn.updater import per_layer_apply_updaters
+
+        spec = UpdaterSpec(kind=kind, learning_rate=0.05)
+        items = [("0", spec), ("1", spec)]
+        params, state, grads = _layers_of({"W": (5, 3), "b": (3,)}, spec,
+                                          keys=("0", "1"), seed=11)
+        with jax.debug_nans(True), jax.debug_infs(True):
+            for step in (1, 2):
+                new_p, new_u = grouped_apply_updaters(
+                    items, params, state, grads, jnp.asarray(1.0),
+                    jnp.asarray(step))
+                ref_p, ref_u = per_layer_apply_updaters(
+                    items, params, state, grads, jnp.asarray(1.0),
+                    jnp.asarray(step))
+                _assert_bitwise(new_p, ref_p)
+                _assert_bitwise(new_u, ref_u)
+                assert (jax.tree_util.tree_structure(new_u)
+                        == jax.tree_util.tree_structure(ref_u))
+                params, state = new_p, new_u
+
+    @pytest.mark.parametrize("case", ["one_leaf", "bias_lr"])
+    def test_tail_keeps_the_groups_structure(self, case):
+        """A group of one leaf stays that leaf (no concatenate, no
+        tail); ``bias_learning_rate`` splits the leaves into two groups
+        and each is padded on its own."""
+        from deeplearning4j_tpu.nn.updater import per_layer_apply_updaters
+
+        if case == "one_leaf":
+            spec = UpdaterSpec(kind=Updater.ADAM, learning_rate=0.05)
+            items = [("0", spec)]
+            params, state, grads = _layers_of({"W": (7, 3)}, spec)
+            want = []
+        else:
+            spec = UpdaterSpec(kind=Updater.ADAM, learning_rate=0.05,
+                               bias_learning_rate=0.01)
+            items = [("0", spec), ("1", spec)]
+            params, state, grads = _layers_of({"W": (600, 1), "b": (3,)},
+                                              spec, keys=("0", "1"))
+            # g, m, v of the weights (1,200 -> 2,048), then of the biases
+            want = [2048] * 3 + [1024] * 3
+        cats = _concat_eqns(_sweep(items), params, state, grads)
+        assert [e.outvars[0].aval.shape[0] for e in cats] == want
+        assert all(len(e.invars) == 3 for e in cats)
+        new = _sweep(items)(params, state, grads)
+        ref = per_layer_apply_updaters(items, params, state, grads,
+                                       jnp.asarray(1.0), jnp.asarray(1))
+        _assert_bitwise(new, ref)
+        assert (jax.tree_util.tree_structure(new)
+                == jax.tree_util.tree_structure(ref))
+
+
+def _layers_of(shapes, spec, keys=("0",), seed=0):
+    """``(params, state, grads)`` of layers ``keys``, each with float32
+    leaves of ``shapes``."""
+    rng = np.random.default_rng(seed)
+    draw = lambda: {key: {k: jnp.asarray(rng.normal(size=s), jnp.float32)
+                          for k, s in shapes.items()} for key in keys}
+    params = draw()
+    state = {key: init_updater_state(spec, params[key]) for key in keys}
+    return params, state, draw()
+
+
+def _sweep(items):
+    return lambda p, u, g: grouped_apply_updaters(
+        items, p, u, g, jnp.asarray(1.0), jnp.asarray(1))
+
+
+def _concat_eqns(fn, *args):
+    return [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "concatenate"]
+
 
 # ---------------------------------------------------------------------------
 # preempt -> resume: masters round-trip through the checkpoint

@@ -42,8 +42,27 @@ the shapes at trace time, and the attention over ``S_t`` (the absorbed form of
   keys nor 2,048 rows at 11 ns each (PERF.md section 6, PR 34). Threshold,
   packing and the attention over the gathered rows stay one op over all rows.
 
-Queries are taken ``ATTEND_BLOCK`` at a time, so that a prefill block's scores
-``[q, hI, T]`` and logits ``[H, q, T]`` stay under a gigabyte and a half.
+**Pooled index keys** (``dsa["pool"]`` = n > 1; GLM-5.3's ``index_kpool``,
+read literally, ``docs/glm53_flash.md``): pool p holds positions n p .. n p +
+n - 1 and its key is the mean of their index keys (after norm and RoPE). The
+index cache keeps that mean alone for a complete pool and, beside it, the
+running sum of the open one (``pool_keys``; ``serving/kv_cache.py``): 1 / n
+of the rows. A query at t scores the P(t) = floor(t / n) pools that end
+before its own, selects the ``topk / n`` best (all while P(t) <= topk / n),
+attends their n positions each, and always the **tail**, positions n P(t) ..
+t, its own open pool up to itself, never scored:
+
+    I(t, p) = sum_j w_{t,j} relu(q^I_{t,j} . mean_{s in pool p} k^I_s)
+    S_t     = union of the topk / n pools p < P(t) of largest I(t, p),
+              and {n P(t), ..., t}
+
+Both forms carry it: the mask is the pools' mask repeated n times a pool
+with the tail set, the positions are ``topk`` pool positions and then the n
+of the tail (``valid`` false beyond t). Scope ``dsa.pool`` names the pooling
+itself.
+
+Queries are taken ``ATTEND_BLOCK`` at a time, fewer where a block's float32
+scores ``[q, hI, T]`` or logits ``[H, q, T]`` would pass ``ATTEND_BYTES``.
 Scopes ``dsa.index`` (projections, scores, selection) and ``mla.attend``
 (gather and attention).
 """
@@ -62,7 +81,8 @@ from deeplearning4j_tpu.scopes import scope
 
 __all__ = ["ATTEND_BLOCK", "init_indexer", "index_project",
            "index_scores", "live_slots", "select", "kth_largest_mask",
-           "mask_positions", "selected_positions", "attend_selected"]
+           "mask_positions", "selected_positions", "attend_selected",
+           "pool_keys", "selection_width"]
 
 ATTEND_BLOCK = mla.QUERY_BLOCK     # queries alive at once
 
@@ -103,9 +123,42 @@ def index_project(x, c_q, p, *, dims: Dict[str, int], rope, layernorm,
     return q, k, w
 
 
-def _by_query_blocks(fn, *args):
-    """``mla.by_query_blocks``, ``ATTEND_BLOCK`` queries at a time."""
-    return mla.by_query_blocks(fn, *args, block=ATTEND_BLOCK)
+def pool_keys(keys, pool: int):
+    """``keys`` [b, t, dI] -> [b, ceil(t / pool), dI] in ``keys``' dtype: the
+    mean of each run of ``pool`` positions, taken in float32 (a last run
+    short of ``pool`` is padded with zeros: an open pool, which no query
+    scores)."""
+    b, t, d = keys.shape
+    with scope("dsa.pool"):
+        k = jnp.pad(keys.astype(jnp.float32), ((0, 0), (0, -t % pool), (0, 0)))
+        return jnp.mean(k.reshape(b, -1, pool, d), axis=2).astype(keys.dtype)
+
+
+def selection_width(dims: Dict[str, int], rows: int) -> int:
+    """Positions a query's selection names at most, over a cache of ``rows``
+    positions: ``min(topk, rows)``, and with pooled keys the whole pools
+    among them and the tail's ``pool`` more."""
+    pool = int(dims.get("pool", 1))
+    if pool == 1:
+        return min(int(dims["topk"]), rows)
+    return (min(int(dims["topk"]) // pool, rows // pool) + 1) * pool
+
+
+# the float32 logits [heads, block, keys] of one block of queries may take
+# this much: ``ATTEND_BLOCK`` halves until they do (64 heads x 128 queries x
+# 32,768 keys is the gigabyte exactly; against 57,344 keys 128 queries would
+# take 1.9 GB beside 8 GB of weights and the pool, so 64 are taken)
+ATTEND_BYTES = 1 << 30
+
+
+def _by_query_blocks(fn, *args, heads: int = 0, keys: int = 0):
+    """``mla.by_query_blocks``, ``ATTEND_BLOCK`` queries at a time, fewer
+    where ``fn`` makes float32 logits [heads, block, keys] of more than
+    ``ATTEND_BYTES``."""
+    block = ATTEND_BLOCK
+    while block > 8 and 4 * heads * block * keys > ATTEND_BYTES:
+        block //= 2
+    return mla.by_query_blocks(fn, *args, block=block)
 
 
 def live_slots(live, slots: int):
@@ -198,10 +251,14 @@ def mask_positions(mask, k: int):
     return (jnp.minimum(tile * LANES + lane, t - 1), r < upto[..., -1:])
 
 
-def select(iq, iw, keys, q_pos, topk: int):
+def select(iq, iw, keys, q_pos, topk: int, pool: int = 1):
     """The selection ``S_t`` of each query, the positions ``s <=
     q_pos[b, q]`` of ``keys`` [b, T, dI] with the k = min(topk, T) largest
-    index scores, in one of two forms (the module's docstring):
+    index scores, in one of two forms (the module's docstring). With
+    ``pool`` > 1 ``keys`` are pooled keys [b, T / pool, dI] and the
+    selection is over positions all the same: the ``topk / pool`` best
+    pools that end before the query's own, ``pool`` positions each, and
+    the tail (``_select_pooled``).
 
     - ``mask [b, q, T]`` bool. Several queries a row.
     - ``(idx [b, q, k] int32, valid [b, q, k] bool)``, the mask's positions
@@ -212,6 +269,8 @@ def select(iq, iw, keys, q_pos, topk: int):
       owes no token, ``serving/engine._index_selection``) has nothing to
       select and its keys are not read: the scores are taken a row a trip
       of a loop over the others (``live_slots``)."""
+    if pool > 1:
+        return _select_pooled(iq, iw, keys, q_pos, int(topk) // pool, pool)
     b, t = keys.shape[:2]
     k = min(int(topk), t)
 
@@ -222,23 +281,75 @@ def select(iq, iw, keys, q_pos, topk: int):
         return kth_largest_mask(behind(index_scores(iq, iw, keys), q_pos), k)
 
     def one(iq, iw, q_pos):
-        order, count = live_slots(q_pos[:, 0] >= 0, b)
-
-        def trip(i, scores):
-            s = order[i]
-            return lax.dynamic_update_slice_in_dim(scores, index_scores(*(
-                lax.dynamic_slice_in_dim(a, s, 1) for a in (iq, iw, keys))),
-                s, 0)
-
-        scores = lax.fori_loop(0, count, trip,
-                               jnp.full((b, 1, t), -jnp.inf, jnp.float32))
+        scores = _scores_of_live(iq, iw, keys, q_pos)
         return mask_positions(
             kth_largest_mask(behind(scores, q_pos), k, unroll=True), k)
 
     with scope("dsa.index"):
         if iq.shape[1] == 1:
             return one(iq, iw, q_pos)
-        return _by_query_blocks(block, iq, iw, q_pos)
+        return _by_query_blocks(block, iq, iw, q_pos, heads=iq.shape[2],
+                                keys=t)
+
+
+def _scores_of_live(iq, iw, keys, q_pos):
+    """``index_scores`` [b, 1, T] of one query a row, a row a trip of a loop
+    over the rows whose query stands at a position (``q_pos`` [b, 1] >= 0:
+    ``live_slots``); the others' keys are not read and their scores are
+    -inf."""
+    b, t = keys.shape[:2]
+    order, count = live_slots(q_pos[:, 0] >= 0, b)
+
+    def trip(i, scores):
+        s = order[i]
+        return lax.dynamic_update_slice_in_dim(scores, index_scores(*(
+            lax.dynamic_slice_in_dim(a, s, 1) for a in (iq, iw, keys))),
+            s, 0)
+
+    return lax.fori_loop(0, count, trip,
+                         jnp.full((b, 1, t), -jnp.inf, jnp.float32))
+
+
+def _select_pooled(iq, iw, keys, q_pos, k_pools: int, pool: int):
+    """``select`` over pooled keys ``keys`` [b, Tp, dI]: query (b, q) at
+    position t = ``q_pos`` scores the pools p < P(t) = t // pool, keeps the
+    ``min(k_pools, Tp)`` best and its tail ``pool P(t) .. t``. A mask [b, q,
+    Tp pool] over positions, or (one query a row) ``(idx [b, 1, (k + 1)
+    pool], valid)``: the pools' positions ascending, then the tail's. A row
+    with ``q_pos < 0`` (a slot that owes no token) reads no key and selects
+    nothing."""
+    b, tp = keys.shape[:2]
+    k = min(k_pools, tp)
+    inside = jnp.arange(pool)
+
+    def before(scores, q_pos):      # pools that end before the query's own
+        return jnp.where(jnp.arange(tp) < (q_pos // pool)[..., None],
+                         scores, -jnp.inf)
+
+    def block(iq, iw, q_pos):
+        pools = kth_largest_mask(before(index_scores(iq, iw, keys), q_pos), k)
+        at = jnp.arange(tp * pool)
+        tail = ((at >= (q_pos // pool * pool)[..., None])
+                & (at <= q_pos[..., None]))
+        return jnp.repeat(pools, pool, axis=-1) | tail
+
+    def one(iq, iw, q_pos):
+        scores = _scores_of_live(iq, iw, keys, q_pos)
+        idx, valid = mask_positions(
+            kth_largest_mask(before(scores, q_pos), k, unroll=True), k)
+        idx = (idx[..., None] * pool + inside).reshape(b, 1, k * pool)
+        valid = jnp.repeat(valid, pool, axis=-1)
+        # below the cache's rows whatever the cursor: a gather's index
+        tail = jnp.minimum(jnp.maximum(q_pos, 0)[..., None] // pool * pool
+                           + inside, tp * pool - 1)
+        return (jnp.concatenate([idx, tail], axis=-1),
+                jnp.concatenate([valid, (tail <= q_pos[..., None])], axis=-1))
+
+    with scope("dsa.index"):
+        if iq.shape[1] == 1:
+            return one(iq, iw, q_pos)
+        return _by_query_blocks(block, iq, iw, q_pos, heads=iq.shape[2],
+                                keys=tp)
 
 
 def selected_positions(selection, k: int):
@@ -290,4 +401,5 @@ def attend_selected(q_nope, q_rope, rows, selection, p, *, dims,
 
     if isinstance(selection, tuple):
         return _by_query_blocks(gathered, q_nope, q_rope, *selection)
-    return _by_query_blocks(masked, q_nope, q_rope, selection)
+    return _by_query_blocks(masked, q_nope, q_rope, selection,
+                            heads=q_nope.shape[2], keys=rows.shape[1])

@@ -72,11 +72,24 @@ _EPS = 1e-6
 
 
 def init_kda(key, d_model: int, num_heads: int, head_dim: int, conv: int,
-             dtype) -> Dict[str, Any]:
+             dtype, gate_rank: int = 0, out_gate: str = "head"
+             ) -> Dict[str, Any]:
     """Glorot-normal projections ``wq/wk/wv/wa`` [D, H dk], ``wo``
     [H dk, D], ``wb/wg`` [D, H]; convolution taps ``conv_q/k/v``
     [conv, H dk] (normal / sqrt(conv)); ``a_log`` [H] zero, ``dt_bias``
-    [H dk] zero, the output norm's gain ``o_norm.g`` [dk] one."""
+    [H dk] zero, the output norm's gain ``o_norm.g`` [dk] one.
+
+    Kimi Linear's published forms of the two gates, beside Ling's:
+    ``gate_rank`` = r > 0: the decay's projection is low-rank, ``wa_down``
+    [D, r] and ``wa_up`` [r, H dk] in place of ``wa``; ``out_gate`` =
+    ``"channel"``: the output gate is one number a channel through the same
+    rank, ``wg_down`` [D, r] and ``wg_up`` [r, H dk] in place of the
+    head-wise ``wg`` (it needs ``gate_rank``)."""
+    if out_gate not in ("head", "channel") or (out_gate == "channel"
+                                               and not gate_rank):
+        raise ValueError(
+            f"kda: out_gate={out_gate!r} is 'head' or 'channel', and a gate "
+            f"a channel goes through gate_rank (got {gate_rank})")
     ks = jax.random.split(key, 10)
     d, c = d_model, num_heads * head_dim
 
@@ -87,16 +100,25 @@ def init_kda(key, d_model: int, num_heads: int, head_dim: int, conv: int,
     def taps(k):
         return jax.random.normal(k, (conv, c), dtype) * (conv ** -0.5)
 
-    return {"wq": glorot(ks[0], d, c), "wk": glorot(ks[1], d, c),
-            "wv": glorot(ks[2], d, c), "wa": glorot(ks[3], d, c),
-            "wb": glorot(ks[4], d, num_heads),
-            "wg": glorot(ks[5], d, num_heads),
-            "wo": glorot(ks[6], c, d),
-            "conv_q": taps(ks[7]), "conv_k": taps(ks[8]),
-            "conv_v": taps(ks[9]),
-            "a_log": jnp.zeros((num_heads,), dtype),
-            "dt_bias": jnp.zeros((c,), dtype),
-            "o_norm": {"g": jnp.ones((head_dim,), dtype)}}
+    p = {"wq": glorot(ks[0], d, c), "wk": glorot(ks[1], d, c),
+         "wv": glorot(ks[2], d, c), "wa": glorot(ks[3], d, c),
+         "wb": glorot(ks[4], d, num_heads),
+         "wg": glorot(ks[5], d, num_heads),
+         "wo": glorot(ks[6], c, d),
+         "conv_q": taps(ks[7]), "conv_k": taps(ks[8]),
+         "conv_v": taps(ks[9]),
+         "a_log": jnp.zeros((num_heads,), dtype),
+         "dt_bias": jnp.zeros((c,), dtype),
+         "o_norm": {"g": jnp.ones((head_dim,), dtype)}}
+    if gate_rank:
+        del p["wa"]
+        p["wa_down"] = glorot(ks[3], d, gate_rank)
+        p["wa_up"] = glorot(jax.random.fold_in(ks[3], 1), gate_rank, c)
+    if out_gate == "channel":
+        del p["wg"]
+        p["wg_down"] = glorot(ks[5], d, gate_rank)
+        p["wg_up"] = glorot(jax.random.fold_in(ks[5], 1), gate_rank, c)
+    return p
 
 
 def l2norm(x):
@@ -298,11 +320,19 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
         dk = q.shape[-1]
         q = l2norm(q) * dk ** -0.5
         k = l2norm(k)
-        a = (x @ cast(p["wa"])).astype(f32) + p["dt_bias"].astype(f32)
+        # the parameters say which form each gate has (``init_kda``)
+        a = (x @ cast(p["wa"]) if "wa" in p
+             else (x @ cast(p["wa_down"])) @ cast(p["wa_up"]))
+        a = a.astype(f32) + p["dt_bias"].astype(f32)
         g = lower * jax.nn.sigmoid(
             jnp.exp(p["a_log"].astype(f32))[:, None] * a.reshape(b, t, h, dk))
         beta = jax.nn.sigmoid((x @ cast(p["wb"])).astype(f32))   # [b, t, H]
-        gate = jax.nn.sigmoid((x @ cast(p["wg"])).astype(f32))
+        if "wg" in p:       # one number a head
+            gate = jax.nn.sigmoid((x @ cast(p["wg"])).astype(f32))
+        else:               # one a channel
+            gate = jax.nn.sigmoid(
+                ((x @ cast(p["wg_down"])) @ cast(p["wg_up"])).astype(f32)
+            ).reshape(b, t, h, -1)
         g, beta = mask_dead(g, beta, live)
         new_tail = live_tail(rows, live, width)
         if s0 is None:
@@ -312,6 +342,7 @@ def kda_mixer(x, p: Dict[str, Any], *, num_heads: int, lower: float,
     with scope("kda.proj"):
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + _EPS) \
             * p["o_norm"]["g"].astype(f32)
-        o = (o * gate[..., None]).astype(x.dtype).reshape(b, t, -1)
+        o = (o * (gate[..., None] if gate.ndim == 3 else gate)).astype(
+            x.dtype).reshape(b, t, -1)
         y = o @ cast(p["wo"])
     return y, s, new_tail.astype(tail.dtype)

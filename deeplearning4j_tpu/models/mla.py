@@ -19,6 +19,9 @@ read it off the parameters they are given. ``dv`` need not equal ``dn``.
 
 What a position leaves behind is its latent row ``[c ; k_r]`` after the
 norm and after RoPE: ``r + dr`` numbers whatever the number of heads.
+``qk_rope_head_dim`` 0 (GLM-5.3's ``mla_use_nope``) is latent attention
+without a rotary part: ``q_rope`` and ``k_r`` are empty, the row is ``c``
+alone and the scale ``dn^-1/2``.
 
 Two forms: ``attend_full`` expands every position's keys and values from
 its latent (prefill, training: causal attention over 32 heads, the flash
@@ -134,10 +137,12 @@ def mla_project(x, p, *, num_heads: int, dims: Dict[str, int], rope,
     with scope("mla.proj"):
         q = (x @ cast(p["wq"]) if c_q is None else c_q @ cast(p["wq_b"]))
         q = q.reshape(b, t, num_heads, dn + dr)
-        q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
+        # no rotary part (NoPE): q_rope and k_r are empty, nothing to turn
+        turn = rope if dr else (lambda a: a)
+        q_nope, q_rope = q[..., :dn], turn(q[..., dn:])
         down = x @ cast(p["wdkv"])
         c = rmsnorm(down[..., :r], p["kv_norm"]["g"])
-        k_r = rope(down[..., r:][:, :, None, :])[:, :, 0]
+        k_r = turn(down[..., r:][:, :, None, :])[:, :, 0]
         gate = (jax.nn.sigmoid((x @ cast(p["wg"])).astype(jnp.float32))
                 if "wg" in p else None)
     return q_nope, q_rope, jnp.concatenate([c, k_r], axis=-1), gate

@@ -241,7 +241,18 @@ def route(x, router, experts_per_token: int, norm_topk_prob: bool = False,
     return weights, experts.astype(jnp.int32)
 
 
-def _dense_experts(x, weights, experts, w_gate, w_up, w_down):
+def swiglu(gate, up, limit: Optional[float] = None):
+    """``silu(gate) * up``; with ``limit`` (GLM-5.3's ``swiglu_limit``, as
+    Ling reads its limit lists) the gate is first clamped to at most
+    ``limit`` and the up projection to ``[-limit, limit]``. ``None`` is the
+    plain product, op for op what the forms below had inline."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def _dense_experts(x, weights, experts, w_gate, w_up, w_down, limit=None):
     """Every expert held on every token (``experts`` counts from the first
     one held; an expert elsewhere matches none). A token's weight for an
     expert it did
@@ -253,7 +264,7 @@ def _dense_experts(x, weights, experts, w_gate, w_up, w_down):
                       preferred_element_type=jnp.float32)
     up = jnp.einsum("nd,edf->enf", x, w_up,
                     preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up * combine.T[..., None]).astype(x.dtype)
+    hidden = (swiglu(gate, up, limit) * combine.T[..., None]).astype(x.dtype)
     return jnp.einsum("enf,efd->nd", hidden, w_down,
                       preferred_element_type=jnp.float32)
 
@@ -276,8 +287,9 @@ def _reached_blocks(x, w_gate, train: bool):
                                         w_gate.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
-def _reached(x, combine, load, w_gate, w_up, w_down, *, blocks, interpret):
+@functools.partial(jax.jit, static_argnames=("blocks", "interpret", "limit"))
+def _reached(x, combine, load, w_gate, w_up, w_down, *, blocks, interpret,
+             limit=None):
     """``reached_kernel.reached_experts`` under a ``jit`` of its own, as
     ``_grouped_experts`` has one: the layers of a program share one trace
     and one lowering of the kernel (a call site of its own costs 0.1 s of
@@ -288,7 +300,7 @@ def _reached(x, combine, load, w_gate, w_up, w_down, *, blocks, interpret):
     with scope("moe.experts"):
         return reached_kernel.reached_experts(
             x, combine, load, w_gate, w_up, w_down, blocks=blocks,
-            interpret=interpret)
+            interpret=interpret, limit=limit)
 
 
 def _combine(weights, experts, held: int):
@@ -325,7 +337,7 @@ def _pass_rows(n: int, k: int, held: int, num_experts: int,
     return min(tiles * PASS_TILE, pairs)
 
 
-def _expert_rows(xs, sizes, w_gate, w_up, w_down):
+def _expert_rows(xs, sizes, w_gate, w_up, w_down, limit=None):
     """The three grouped matmuls on rows sorted by expert, ``sizes [held]``
     of them each; float32. Rows past the last group are not the grouped
     matmul's to define: zero."""
@@ -333,16 +345,16 @@ def _expert_rows(xs, sizes, w_gate, w_up, w_down):
                           preferred_element_type=jnp.float32)
     up = lax.ragged_dot(xs, w_up, sizes,
                         preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate) * up).astype(xs.dtype)
+    hidden = swiglu(gate, up, limit).astype(xs.dtype)
     out = lax.ragged_dot(hidden, w_down, sizes,
                          preferred_element_type=jnp.float32)
     in_group = jnp.arange(xs.shape[0]) < jnp.sum(sizes)
     return jnp.where(in_group[:, None], out, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("pass_rows",))
+@functools.partial(jax.jit, static_argnames=("pass_rows", "limit"))
 def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down, *,
-                     pass_rows: int):
+                     pass_rows: int, limit=None):
     """(token, expert) pairs sorted by expert, the pairs whose expert is
     held here first; those rows alone are gathered, meet the three grouped
     matmuls and go back to their tokens, ``pass_rows`` sorted rows at a
@@ -376,7 +388,7 @@ def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down, *,
     weights = jnp.where(here, weights, 0.0)
     if pass_rows == n * k:
         out = _expert_rows(jnp.take(x, order // k, axis=0), sizes,
-                           w_gate, w_up, w_down)
+                           w_gate, w_up, w_down, limit)
         back = jnp.argsort(order)                           # pair -> row
         out = jnp.take(out, back, axis=0).reshape(n, k, -1)
         return (jnp.sum(weights[..., None] * out, axis=1),
@@ -396,7 +408,7 @@ def _grouped_experts(x, weights, experts, live, w_gate, w_up, w_down, *,
         share = jnp.clip(jnp.minimum(ends, lo + pass_rows)
                          - jnp.maximum(starts, lo), 0)
         out = _expert_rows(jnp.take(x, rows, axis=0), share,
-                           w_gate, w_up, w_down)
+                           w_gate, w_up, w_down, limit)
         return y.at[rows].add(jnp.take(pair_weight, pairs)[:, None] * out)
 
     y = lax.fori_loop(0, passes, one_pass,
@@ -408,7 +420,8 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
                norm_topk_prob: bool = False,
                cast: Callable = lambda w: w,
                live=None, groups: Optional[Tuple[int, int, float]] = None,
-               first: int = 0, train: bool = False
+               first: int = 0, train: bool = False,
+               limit: Optional[float] = None
                ) -> Tuple[Any, Dict[str, Any]]:
     """The routed feed-forward on ``x [N, D]`` with the block's ``moe``
     parameters ``p`` (``init_experts``). ``cast`` brings an expert matrix
@@ -418,7 +431,8 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
     form, reach no expert. ``groups`` is ``route``'s; ``first`` is the
     router's index of the first expert held here (the stacked matrices say
     how many are). ``train``: the trace takes a gradient, which the
-    reached form does not have.
+    reached form does not have. ``limit``: ``swiglu``'s clamp, on every
+    routed expert and on the shared one.
 
     Returns ``(y [N, D] in x.dtype, info)`` with ``info["experts"]``
     ``[N, k]`` int32 (the router's indices), ``info["weights"]`` ``[N, k]``
@@ -451,16 +465,18 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
             y = _reached(
                 x.astype(jnp.promote_types(x.dtype, rounded)),
                 _combine(weights, local, held), load, *stored,
-                blocks=blocks, interpret=_kernel_backend() == "interpret")
+                blocks=blocks, interpret=_kernel_backend() == "interpret",
+                limit=limit)
             read = jnp.sum(load > 0, dtype=jnp.int32)
         elif n <= DENSE_MAX_TOKENS:
-            y = _dense_experts(x, weights, local, *map(cast, stored))
+            y = _dense_experts(x, weights, local, *map(cast, stored),
+                               limit=limit)
         else:
             blocked = n > 2 * ROW_BLOCK and n % ROW_BLOCK == 0
             sort = functools.partial(
                 _grouped_experts, pass_rows=_pass_rows(
                     ROW_BLOCK if blocked else n, experts_per_token, held,
-                    p["router"].shape[1], train))
+                    p["router"].shape[1], train), limit=limit)
             matrices = tuple(map(cast, stored))
             if blocked:
                 y, run = lax.map(
@@ -473,8 +489,12 @@ def routed_ffn(x, p: Dict[str, Any], *, experts_per_token: int,
     if "shared" in p:
         with scope("moe.shared"):
             sh = p["shared"]
-            hidden = (jax.nn.silu(x @ cast(sh["w_gate"]))
-                      * (x @ cast(sh["w_up"])))
+            if limit is None:
+                hidden = (jax.nn.silu(x @ cast(sh["w_gate"]))
+                          * (x @ cast(sh["w_up"])))
+            else:
+                hidden = swiglu(x @ cast(sh["w_gate"]), x @ cast(sh["w_up"]),
+                                limit)
             keep = live[:, None]
             out = hidden @ cast(sh["w_down"])
             if "gate" in sh:

@@ -32,6 +32,7 @@ from deeplearning4j_tpu.analysis.annotations import traced
 from deeplearning4j_tpu.compile_cache import ensure_compile_cache
 from deeplearning4j_tpu.models import dsa as dsa_mod
 from deeplearning4j_tpu.models import gdn as gdn_mod
+from deeplearning4j_tpu.models import hc as hc_mod
 from deeplearning4j_tpu.models import kda as kda_mod
 from deeplearning4j_tpu.models import mla as mla_mod
 from deeplearning4j_tpu.models import ret as ret_mod
@@ -182,7 +183,8 @@ class TransformerLM:
                  mtp: Optional[Dict[str, Any]] = None,
                  gdn: Optional[Dict[str, Any]] = None,
                  attn: Optional[Dict[str, Any]] = None,
-                 ret: Optional[Dict[str, Any]] = None):
+                 ret: Optional[Dict[str, Any]] = None,
+                 hc: Optional[Dict[str, Any]] = None):
         assert d_model % num_heads == 0
         # The block, described per model; the defaults are StarCoder2's
         # (LayerNorm with bias, biased GELU MLP, tied unembedding).
@@ -265,7 +267,19 @@ class TransformerLM:
         # heads of ``head_dim``, per-head RMSNorm and the model's RoPE on q
         # and k, a gate a key/value head; a [Hkv, D, dh] float32 state and
         # its normaliser [Hkv, dh, dh] instead of rows a position; degree 2
-        # is the one written).
+        # is the one written). ``kda`` may also give ``gate_rank`` and
+        # ``out_gate: "channel"`` (Kimi Linear's low-rank decay gate and
+        # channel-wise output gate, ``models/kda.init_kda``). ``mla`` with
+        # ``qk_rope_head_dim`` 0 is latent attention without a rotary part.
+        # ``dsa`` may also give ``pool`` (index keys pooled ``pool`` positions
+        # to a key: the indexer scores pools, the selection is ``topk / pool``
+        # pools and the query's own open pool, ``models/dsa.py``).
+        # ``swiglu_limit`` in ``moe`` clamps every expert's and dense GLU's
+        # gate to at most the limit and its up projection to within it,
+        # before their product. ``hc`` = {streams, sinkhorn_iters, eps}: the
+        # residual is ``streams`` streams mixed by manifold-constrained
+        # hyper-connections round every sub-layer (``models/hc.py``); absent,
+        # the plain residual ``h + y``.
         kinds = ("attn", "kda", "mla", "gdn", "ret"), ("mlp", "glu", "moe")
         self.mixers = tuple(mixers) if mixers is not None else (
             "attn",) * num_layers
@@ -313,6 +327,12 @@ class TransformerLM:
                 f"gdn: value_heads={self.gdn['value_heads']} must be a "
                 f"multiple of key_heads={self.gdn['key_heads']}")
         self.ret = dict(ret) if ret else None
+        self.hc = dict(hc) if hc else None
+        if self.hc and (mtp or int(self.hc["streams"]) < 1):
+            raise ValueError(
+                f"hc={hc!r} with mtp={mtp!r}: hyper-connections need "
+                "streams >= 1, and the multi-token-prediction module reads "
+                "one residual stream")
         if self.ret and (self.ret.get("power", 2) != 2
                          or pos_encoding != "rope"):
             raise ValueError(
@@ -347,6 +367,12 @@ class TransformerLM:
                 self.rope_scaling["factor"],
                 self.rope_scaling.get("mscale_all_dim", 0)) ** 2
         self.mtp = dict(mtp) if mtp else None
+        if self.dsa and self.dsa.get("pool", 1) > 1 and (
+                self.dsa["topk"] % self.dsa["pool"]
+                or "shared" in self.indexers):
+            raise ValueError(
+                f"dsa={dsa!r}: pooled index keys select topk / pool whole "
+                "pools, each layer for itself (no 'shared' indexer)")
         if self.mtp and (pos_encoding != "rope" or self.mixers[-1] != "mla"
                          or self.indexers[-1]):
             raise ValueError(
@@ -354,6 +380,9 @@ class TransformerLM:
                 "'mla' without an indexer: the module's block is one more "
                 "layer of that kind")
         self.moe = dict(moe) if moe else None
+        # the clamp of every expert's and dense GLU's gate and up projection
+        # (``routed_experts.swiglu``); None: no clamp
+        self.swiglu_limit = float((moe or {}).get("swiglu_limit") or 0) or None
         self.glu_width = glu_width
         self.rope_theta = float(rope_theta)
         self.rope_interleaved = bool(rope_interleaved)
@@ -481,10 +510,16 @@ class TransformerLM:
         def block(k, i):
             """Layer ``i``'s block from the six keys ``k``."""
             blk = {"ln1": norm(), "ln2": norm()}
+            if self.hc:     # one set of maps a sub-layer: mixer, feed-forward
+                blk["hc1"] = hc_mod.init_hc(
+                    jax.random.fold_in(k[0], 0x4c1), D, self.hc, dt)
+                blk["hc2"] = hc_mod.init_hc(
+                    jax.random.fold_in(k[4], 0x4c2), D, self.hc, dt)
             if self.mixers[i] == "kda":
                 blk["kda"] = kda_mod.init_kda(
                     k[0], D, self.num_heads, self.kda["head_dim"],
-                    self.kda["conv"], dt)
+                    self.kda["conv"], dt, self.kda.get("gate_rank", 0),
+                    self.kda.get("out_gate", "head"))
             elif self.mixers[i] == "gdn":
                 blk["gdn"] = gdn_mod.init_gdn(k[0], D, self.gdn, dt)
             elif self.mixers[i] == "ret":
@@ -647,7 +682,8 @@ class TransformerLM:
         as its fourth argument."""
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
-        x = self._norm(h, blk["ln1"])
+        u, maps = self._read(blk.get("hc1"), h)
+        x = self._norm(u, blk["ln1"])
         if "kda" in blk or "mla" in blk or "gdn" in blk or "ret" in blk:
             if sequence_parallel:
                 raise NotImplementedError(
@@ -675,7 +711,8 @@ class TransformerLM:
             else:
                 y, k, v = self._mla(blk["mla"], x, attention, positions,
                                     train, indexer, selection)
-            return self._ffn(blk, h + y, live, moe_info, train), k, v
+            return self._ffn(blk, self._write(h, y, maps), live, moe_info,
+                             train), k, v
         # ``attn.proj`` is closed wherever the attention core is called and
         # opened again for the output projection: a scope open round a
         # Pallas kernel would rename it in the trace (``scopes.py``)
@@ -734,9 +771,36 @@ class TransformerLM:
             if gate is not None:
                 o = (o.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(x.dtype)
-            h = h + o.reshape(b, t, -1) @ policy.cast_compute(
-                blk["attn"]["wo"])
+            if maps is None:
+                h = h + o.reshape(b, t, -1) @ policy.cast_compute(
+                    blk["attn"]["wo"])
+            else:
+                o = o.reshape(b, t, -1) @ policy.cast_compute(
+                    blk["attn"]["wo"])
+        if maps is not None:
+            h = self._write(h, o, maps)
         return self._ffn(blk, h, live, moe_info, train), k, v
+
+    def _read(self, p, h):
+        """A sub-layer's input from the residual ``h`` and what its output
+        is written back through: ``(h, None)`` on the plain residual; with
+        hyper-connections (``p``: the sub-layer's ``hc`` parameters, ``h``
+        [b, t, n, D]) ``(H_pre X, (H_post, H_res))`` (``models/hc.py``)."""
+        if p is None:
+            return h, None
+        pre, post, res = hc_mod.maps(h, p, dims=self.hc,
+                                     norm_eps=self.norm_eps)
+        return hc_mod.read(h, pre), (post, res)
+
+    def _write(self, h, y, maps):
+        """The residual after a sub-layer's output ``y``: ``h + y``, or
+        ``H_res X + H_post^T y`` through ``_read``'s maps."""
+        return h + y if maps is None else hc_mod.write(h, y, *maps)
+
+    def _enter(self, h):
+        """The embedding [b, t, D] as the stack's residual: itself, or with
+        hyper-connections a copy in each stream, [b, t, n, D]."""
+        return hc_mod.expand(h, int(self.hc["streams"])) if self.hc else h
 
     @property
     def by_layer(self) -> bool:
@@ -778,7 +842,9 @@ class TransformerLM:
         ``glu`` or ``mlp``). ``train``: the trace takes a gradient."""
         policy = self.policy
         b, t = h.shape[0], h.shape[1]
-        x = self._norm(h, blk["ln2"])
+        u, maps = self._read(blk.get("hc2"), h)
+        x = self._norm(u, blk["ln2"])
+        limit = self.swiglu_limit
         if "moe" in blk:
             m = self.moe
             y, info = routed_experts.routed_ffn(
@@ -789,20 +855,35 @@ class TransformerLM:
                 live=None if live is None else live.reshape(b * t),
                 groups=(m["n_group"], m["topk_group"], m["scale"])
                 if m and "n_group" in m else None,
-                first=m["first"] if m else 0, train=train)
+                first=m["first"] if m else 0, train=train, limit=limit)
             if moe_info is not None:
                 moe_info.append(info)
-            return h + y.reshape(b, t, -1)
+            return self._write(h, y.reshape(b, t, -1), maps)
+        # on the plain residual each form keeps its own order of ops (the
+        # add inside the scope, the bias after it): the lowered programs of
+        # the models without ``hc`` or a limit are letter for letter the same
         with scope("ffn.dense"):
             if "glu" in blk:
                 g = blk["glu"]
-                x = (jax.nn.silu(x @ policy.cast_compute(g["w1"]))
-                     * (x @ policy.cast_compute(g["w3"])))
-                return h + x @ policy.cast_compute(g["w2"])
-            x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
-                            + policy.cast_compute(blk["mlp"]["b1"]))
-            return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
-                    + policy.cast_compute(blk["mlp"]["b2"]))
+                if limit:
+                    x = routed_experts.swiglu(
+                        x @ policy.cast_compute(g["w1"]),
+                        x @ policy.cast_compute(g["w3"]), limit)
+                else:
+                    x = (jax.nn.silu(x @ policy.cast_compute(g["w1"]))
+                         * (x @ policy.cast_compute(g["w3"])))
+                y = x @ policy.cast_compute(g["w2"])
+                if maps is None:
+                    return h + y
+            else:
+                x = jax.nn.gelu(x @ policy.cast_compute(blk["mlp"]["w1"])
+                                + policy.cast_compute(blk["mlp"]["b1"]))
+                if maps is None:
+                    return (h + x @ policy.cast_compute(blk["mlp"]["w2"])
+                            + policy.cast_compute(blk["mlp"]["b2"]))
+                y = (x @ policy.cast_compute(blk["mlp"]["w2"])
+                     + policy.cast_compute(blk["mlp"]["b2"]))
+        return self._write(h, y, maps)
 
     def _mla(self, p, x, attention, positions, train, indexer=None,
              selection=None):
@@ -840,8 +921,16 @@ class TransformerLM:
                 selection = indexer(iq, ik, iw)
             else:
                 pos = jnp.broadcast_to(positions, x.shape[:2])
-                selection = dsa_mod.select(iq, iw, ik, pos,
-                                           self.dsa["topk"])
+                pool = self.dsa.get("pool", 1)
+                if pool > 1:    # the block's own keys, pooled
+                    selection = dsa_mod.select(
+                        iq, iw, dsa_mod.pool_keys(ik, pool), pos,
+                        self.dsa["topk"], pool=pool)
+                    if not isinstance(selection, tuple):
+                        selection = selection[..., :t]  # whole pools -> t
+                else:
+                    selection = dsa_mod.select(iq, iw, ik, pos,
+                                               self.dsa["topk"])
         if selection is not None:
             # each query attends its selected rows, absorbed: the block's
             # own latents unless ``attention`` holds a cache of them
@@ -941,6 +1030,7 @@ class TransformerLM:
             if self.pos_encoding == "learned":
                 h = h + params["pos"][:t][None]
             h = policy.cast_compute(h)
+        h = self._enter(h)
 
         def block_fn(blk, h, selection=None, layer=None):
             return self._block(blk, h, mesh=mesh,
@@ -1175,7 +1265,7 @@ class TransformerLM:
             "moe": self.moe, "indexers": list(self.indexers),
             "dsa": self.dsa, "rope_scaling": self.rope_scaling,
             "mtp": self.mtp, "gdn": self.gdn, "attn": self.attn,
-            "ret": self.ret,
+            "ret": self.ret, "hc": self.hc,
         }
 
     def _ensure_init(self):
@@ -1211,6 +1301,8 @@ class TransformerLM:
         accumulation — one of the largest matmuls in the step, so a
         plain f32 matmul here would cost MXU rate."""
         policy = self.policy
+        if self.hc:     # the exit: the streams' sum, [..., n, D] -> [..., D]
+            h = hc_mod.collapse(h)
         with scope("lm.head"):
             hf = self._norm(h, params["ln_f"] if ln is None else ln)
             head = params["embed" if self.tie_embeddings else "head"]
@@ -1238,6 +1330,7 @@ class TransformerLM:
             if self.pos_encoding == "learned":
                 h = h + params["pos"][:prompt_len][None]
             h = policy.cast_compute(h)
+        h = self._enter(h)
         cache = []
         pad_t = ((0, 0), (0, max_new_tokens), (0, 0), (0, 0))
         for i, blk in enumerate(params["blocks"]):
@@ -1261,6 +1354,7 @@ class TransformerLM:
             if self.pos_encoding == "learned":
                 h = h + params["pos"][t]
             h = policy.cast_compute(h)[:, None, :]          # [B, 1, D]
+        h = self._enter(h)
         new_cache = []
         masks = {}      # {window: [1, total] keys a query at t may see}
 
@@ -1506,6 +1600,9 @@ class TransformerLM:
         for mixer, ffn, indexer in zip(self.mixers, self.ffns,
                                        self.indexers):
             blk = {"ln1": norm(), "ln2": norm()}
+            if self.hc:     # every chip holds the maps' parameters whole
+                blk["hc1"] = {"phi": P(), "alpha": P(), "b": P()}
+                blk["hc2"] = {"phi": P(), "alpha": P(), "b": P()}
             if mixer == "attn":
                 blk["attn"] = {"wq": col, "wk": kv_col, "wv": kv_col,
                                "wo": row}
@@ -1515,9 +1612,13 @@ class TransformerLM:
             elif mixer == "kda":
                 # no Megatron split is written for the four other mixers:
                 # every chip holds them whole
+                rank = (self.kda or {}).get("gate_rank")
+                chan = (self.kda or {}).get("out_gate") == "channel"
                 blk["kda"] = {n: P() for n in (
-                    "wq", "wk", "wv", "wa", "wb", "wg", "wo", "conv_q",
-                    "conv_k", "conv_v", "a_log", "dt_bias")}
+                    "wq", "wk", "wv", "wb", "wo", "conv_q",
+                    "conv_k", "conv_v", "a_log", "dt_bias")
+                    + (("wa_down", "wa_up") if rank else ("wa",))
+                    + (("wg_down", "wg_up") if chan else ("wg",))}
                 blk["kda"]["o_norm"] = {"g": P()}
             elif mixer == "gdn":
                 blk["gdn"] = {n: P() for n in (

@@ -100,7 +100,7 @@ def work_list(load):
 
 def _kernel(n_ref, ids_ref, x_ref, combine_ref, gate_hbm, up_hbm, down_hbm,
             o_ref, gu_buf, down_buf, gu_sem, down_sem, gate_acc, up_acc,
-            hidden_ref, *, d_block, f_block):
+            hidden_ref, *, d_block, f_block, limit=None):
     o_ref[...] = jnp.zeros_like(o_ref)
     n = n_ref[0]
     d, f = gate_hbm.shape[1:]
@@ -162,8 +162,14 @@ def _kernel(n_ref, ids_ref, x_ref, combine_ref, gate_hbm, up_hbm, down_hbm,
                 weight = jnp.sum(
                     jnp.where(lane == e, combine_ref[...], 0.0), axis=1,
                     keepdims=True)                          # [N, 1]
-                hidden_ref[...] = (jax.nn.silu(gate_acc[...]) * up_acc[...]
-                                   * weight).astype(cdt)
+                if limit is None:
+                    hidden_ref[...] = (jax.nn.silu(gate_acc[...])
+                                       * up_acc[...] * weight).astype(cdt)
+                else:   # ``routed_experts.swiglu``'s clamp
+                    hidden_ref[...] = (
+                        jax.nn.silu(jnp.minimum(gate_acc[...], limit))
+                        * jnp.clip(up_acc[...], -limit, limit)
+                        * weight).astype(cdt)
         return carry
 
     lax.fori_loop(0, n, expert, 0)
@@ -171,12 +177,13 @@ def _kernel(n_ref, ids_ref, x_ref, combine_ref, gate_hbm, up_hbm, down_hbm,
 
 def reached_experts(x, combine, load, w_gate, w_up, w_down, *,
                     blocks: Optional[Tuple[int, int]] = None,
-                    interpret: bool = False):
+                    interpret: bool = False, limit: Optional[float] = None):
     """``sum_e (silu(x Wgate[e]) * (x Wup[e]) * combine[:, e]) Wdown[e]``
     over the experts ``e`` with ``load[e] > 0``: ``x [N, D]`` in the
     compute dtype, ``combine [N, held]`` float32 (a row's weight for each
     expert held, zero where it did not choose it), ``load [held]`` the
-    live pairs each expert received, ``w_gate`` / ``w_up`` ``[held, D,
+    live pairs each expert received (``limit``: the gate clamped to at most
+    it and the up projection to within it before the product), ``w_gate`` / ``w_up`` ``[held, D,
     F]`` and ``w_down`` ``[held, F, D]`` in the dtype they are stored in
     (rounded to ``x.dtype`` after the fetch). Returns ``y [N, D]``
     float32. A pair of a row whose expert has ``load`` 0 must carry
@@ -196,7 +203,8 @@ def reached_experts(x, combine, load, w_gate, w_up, w_down, *,
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
-        functools.partial(_kernel, d_block=d_block, f_block=f_block),
+        functools.partial(_kernel, d_block=d_block, f_block=f_block,
+                          limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(),

@@ -88,6 +88,13 @@ SCOPES = {
     "mla.proj": "MLA query/latent projections, norms, RoPE, output",
     "mla.attend": "MLA attention over latent rows or expanded keys",
     "dsa.index": "the lightning indexer: index keys, scores, selection",
+    "dsa.pool": "pooled index keys (dsa['pool']): the open pool's running "
+                "sum and the write of a pool's mean key into the index cache",
+    "hc.map": "hyper-connections (hc=): the n-stream residual's norm, the "
+              "projection onto the three maps, sigmoids, Sinkhorn sweeps",
+    "hc.mix": "hyper-connections: a sub-layer's input H_pre X and its "
+              "write-back H_res X + H_post^T y; the entry's copies and the "
+              "exit's sum of the streams",
     "mtp": "the multi-token-prediction module's own block and its pass "
            "through the shared head; the block's scopes are open inside it "
            "(mtp/mla.proj, mtp/moe.experts, ...)",

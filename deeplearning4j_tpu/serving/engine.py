@@ -176,6 +176,7 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
         if model.pos_encoding == "learned":
             h = h + params["pos"][:p][None]
         h = policy.cast_compute(h)
+    h = model._enter(h)
     ks, vs = [], []
     # the other layer kinds
     left = {"latent": [], "kda": [], "conv": [], "norm": []}
@@ -236,7 +237,7 @@ def _serve_prefill_impl(model, sample_row, quantized, params, kv,
             held = jnp.clip(ring_positions(prompt_len - 1, r), 0, p - 1)
             kcat, vcat = (jnp.take(cat, held, axis=2) for cat in (kcat, vcat))
         new_kv.update({kn: put(kv[kn], kcat), vn: put(kv[vn], vcat)})
-    h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D]
+    h_last = jnp.take(h[0], prompt_len - 1, axis=0)        # [D] ([n, D])
     logits = model._unembed(params, h_last)
     with scope("lm.head"):
         tok, key = sample_row(logits, key)
@@ -478,7 +479,7 @@ def _latent_attention(model, pool, positions, slot=None, keys=None,
 
 
 def _index_selection(model, pool, positions, slot=None, keys=None,
-                     live=None):
+                     live=None, prompt_len=None):
     """``j -> indexer(q^I, k^I, w)`` for a decode-family forward of the
     model's j-th layer with a lightning indexer, over its cached index
     keys: the new keys land at ``positions [S, Q]`` of ``pool["index"][j]``
@@ -487,11 +488,61 @@ def _index_selection(model, pool, positions, slot=None, keys=None,
     A slot that owes no token (``live [S]`` false) queries from position -1:
     no key lies behind it, so its selection is empty and ``dsa.select``
     reads none of its keys. ``slot`` and ``keys`` as
-    ``_latent_attention``'s."""
+    ``_latent_attention``'s.
+
+    With pooled index keys (``dsa["pool"]`` = n; ``kv_cache``'s ``index``
+    and ``index_open``) what is cached is a mean key a pool. A decode step
+    adds its key to the slot's open pool's running sum (from zero where the
+    position opens a pool) and writes ``sum / n`` at the pool's row: the
+    pool's key once its last position has come, and before that a row no
+    query scores. A prefill block (its start a multiple of n) writes the
+    means of its own pools and leaves the sum as of ``prompt_len``, the
+    keys of the prompt's last, open pool. A slot that owes no token changes
+    neither. Scope ``dsa.pool``."""
     import jax.numpy as jnp
+    from jax import lax
     from deeplearning4j_tpu.models import dsa
 
     rows = jnp.arange(positions.shape[0])
+    n = model.dsa.get("pool", 1) if model.dsa else 1
+
+    def pooled(j):
+        def indexer(iq, ik, iw):
+            cache, open_ = pool["index"][j], pool["index_open"][j]
+            with scope("dsa.pool"):
+                if slot is None:
+                    at = positions[:, 0]
+                    key = ik[:, 0].astype(jnp.float32)
+                    total = jnp.where((at % n == 0)[:, None], 0.0,
+                                      open_) + key
+                    owes = jnp.ones_like(at, bool) if live is None else live
+                    pool["index_open"][j] = jnp.where(owes[:, None], total,
+                                                      open_)
+                    # a slot that owes nothing writes beyond the rows: dropped
+                    cache = cache.at[rows, jnp.where(
+                        owes, at // n, cache.shape[1])].set(
+                            (total / n).astype(cache.dtype), mode="drop")
+                else:
+                    means = dsa.pool_keys(ik, n)
+                    cache = lax.dynamic_update_slice(
+                        cache, means.astype(cache.dtype),
+                        (slot, positions[0, 0] // n, 0))
+                    last = ((positions[0] >= prompt_len // n * n)
+                            & (positions[0] < prompt_len))
+                    pool["index_open"][j] = lax.dynamic_update_slice(
+                        open_, jnp.sum(jnp.where(
+                            last[:, None], ik[0].astype(jnp.float32), 0.0),
+                            axis=0)[None], (slot, 0))
+            pool["index"][j] = cache
+            q_pos = positions if live is None else jnp.where(
+                live[:, None], positions, -1)
+            view = cache if slot is None else lax.dynamic_slice(
+                cache, (slot, 0, 0), (1, keys // n, cache.shape[2]))
+            return dsa.select(iq, iw, view, q_pos, model.dsa["topk"], pool=n)
+        return indexer
+
+    if n > 1:
+        return pooled
 
     def layer(j):
         def indexer(iq, ik, iw):
@@ -509,12 +560,13 @@ def _index_selection(model, pool, positions, slot=None, keys=None,
 
 
 def _latent_layers(model, params, pool, positions, slot=None, keys=None,
-                   live=None):
+                   live=None, prompt_len=None):
     """Per block of ``params`` the keywords ``TransformerLM._block`` takes
     for an ``mla`` layer served from the pool (``attention`` and, for a
     layer with an indexer, ``indexer``; None for another kind of layer)."""
     attention = _latent_attention(model, pool, positions, slot, keys, live)
-    indexer = _index_selection(model, pool, positions, slot, keys, live)
+    indexer = _index_selection(model, pool, positions, slot, keys, live,
+                               prompt_len)
     out, j, jf = [], 0, 0
     for blk in params["blocks"]:
         if "mla" not in blk:
@@ -570,9 +622,12 @@ def prefill_carry_layout(model, bucket: int) -> dict:
     import jax.numpy as jnp
 
     n_moe, k = model.n_layers("moe"), model.experts_per_token
-    topk = min(model.dsa["topk"], bucket) if model.dsa else 0
+    from deeplearning4j_tpu.models import dsa
+
+    topk = dsa.selection_width(model.dsa, bucket) if model.dsa else 0
+    streams = (int(model.hc["streams"]),) if model.hc else ()
     return {
-        "h_last": ((model.d_model,),
+        "h_last": (streams + (model.d_model,),
                    jnp.dtype(model.policy.compute_dtype).name, 0),
         "sel_last": ((model.indexers.count("full"), topk), "int32", -1),
         "load": ((n_moe, model.experts_held), "int32", 0),
@@ -598,7 +653,10 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     A block is the decode-family forward at Q = its positions: it writes
     its latent rows and index keys into the slot, then every query scores
     the slot's index keys up to its own position, selects, and attends the
-    selected rows (``_latent_layers``). The pad tail of the last block is
+    selected rows (``_latent_layers``). A ``kda`` layer between them takes
+    the block as a prefill takes a prompt, from the slot's recurrent matrix
+    and convolution tail as the block before left them (``block 0``: zeros)
+    and writes both back. The pad tail of the last block is
     inert by causality and writes rows beyond the cursor, which the decode
     steps overwrite before any query reads them.
 
@@ -621,10 +679,11 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     import jax.numpy as jnp
     from jax import lax
 
-    if set(model.mixers) != {"mla"}:
+    if set(model.mixers) - {"mla", "kda"}:
         raise NotImplementedError(
-            "prefill in query blocks is written for a stack of 'mla' "
-            f"layers; this model's are {model.mixers}")
+            "prefill in query blocks is written for 'mla' layers and, "
+            "between them, 'kda' layers, whose recurrence a block continues; "
+            f"this model's are {model.mixers}")
     policy = model.policy
     p = prompt.shape[1]
     c = p // prefill_block_count(p, p)
@@ -637,11 +696,29 @@ def _serve_prefill_block_impl(model, sample_row, params, kv, carry, prompt,
     toks = lax.dynamic_slice(prompt, (0, start), (1, c))
     with scope("lm.embed"):
         h = policy.cast_compute(jnp.take(params["embed"], toks, axis=0))
+    h = model._enter(h)
     live = positions < prompt_len
     moe_info: list = []
     selections, selection = [], None
+    recurrent = 0       # the 'kda' layers seen: their place in the state
     for blk, kw in zip(params["blocks"], _latent_layers(
-            model, params, pool, positions, slot, p)):
+            model, params, pool, positions, slot, p,
+            prompt_len=prompt_len)):
+        if kw is None:
+            # a 'kda' layer of block i continues from what the blocks
+            # before left in the slot, matrix and convolution tail (block
+            # 0: from a request's start), and leaves its own there; the pad
+            # tail moves neither (``live``)
+            j, recurrent = recurrent, recurrent + 1
+            state = tuple(
+                jnp.where(i == 0, 0, lax.dynamic_slice_in_dim(a, slot, 1))
+                .astype(a.dtype) for a in (pool["kda"][j], pool["conv"][j]))
+            h, *state = model._block(blk, h, live=live, moe_info=moe_info,
+                                     state=state)
+            for name, a in zip(("kda", "conv"), state):
+                pool[name][j] = lax.dynamic_update_slice_in_dim(
+                    pool[name][j], a.astype(pool[name][j].dtype), slot, 0)
+            continue
         h, _, selection = model._block(
             blk, h, positions=positions, live=live, moe_info=moe_info,
             selection=selection, **kw)
@@ -740,6 +817,7 @@ def _decode_step_body(model, params, kv, tok, positions, *,
         if model.pos_encoding == "learned":
             h = h + params["pos"][positions]
         h = model.policy.cast_compute(h)[:, None, :]       # [S, 1, D]
+    h = model._enter(h)     # hyper-connections: [S, 1, n, D]
     new_kv = {k: list(v) if isinstance(v, list) else v
               for k, v in kv.items()}
     cached_attention = _pool_attention(
@@ -803,7 +881,9 @@ def _serve_decode_impl(model, sample_row, params, kv, tok, positions,
         toks, keys = jax.vmap(sample_row)(logits, keys)
     routing = _stack_routing(moe_info) if model.num_experts else None
     if model.dsa:
-        k = min(model.dsa["topk"], new_kv["latent"][0].shape[1])
+        from deeplearning4j_tpu.models import dsa
+
+        k = dsa.selection_width(model.dsa, new_kv["latent"][0].shape[1])
         return (toks, keys, new_kv, routing,
                 _stack_selection(selections, k)[:, :, 0])
     if model.num_experts:
@@ -852,6 +932,7 @@ def _serve_verify_impl(model, params, kv, toks, positions, live=None, *,
         if model.pos_encoding == "learned":
             h = h + params["pos"][positions]
         h = model.policy.cast_compute(h)
+    h = model._enter(h)
     new_kv = {k: list(v) if isinstance(v, list) else v
               for k, v in kv.items()}
     cached_attention = _pool_attention(model, new_kv, positions, pool_kernel,
@@ -1113,6 +1194,12 @@ class DecodeEngine:
         self.top_k = top_k
         self.buckets = tuple(b for b in (buckets or DEFAULT_PROMPT_BUCKETS)
                              if b <= self.max_len) or (self.max_len,)
+        pool = (model.dsa or {}).get("pool", 1)
+        if any(n % pool for n in self.buckets + (self.max_len,)):
+            raise ValueError(
+                f"pooled index keys (dsa['pool']={pool}): max_len="
+                f"{self.max_len} and every prompt bucket {self.buckets} "
+                "hold whole pools")
         self._sample_row = _row_sampler(self.temperature, top_k)
         self._programs: Dict[tuple, object] = {}
         self.program_builds = 0

@@ -63,7 +63,13 @@ of it side by side:
 - ``mla`` layers with a lightning indexer (``TransformerLM(indexers=)``'s
   ``"full"`` layers, ``models/dsa.py``): beside their latent rows, index
   keys, one ``[S, T_max, dI]`` array a layer (``index``), written and
-  masked as the latent rows are; a ``"shared"`` layer keeps none;
+  masked as the latent rows are; a ``"shared"`` layer keeps none. With
+  pooled index keys (``dsa["pool"]`` = n) the array is ``[S, T_max / n,
+  dI]``, one mean key a complete pool of n positions, and beside it the
+  open pool's running sum in float32, ``[S, dI]`` a layer (``index_open``):
+  a decode step adds its key to the sum and writes ``sum / n`` at the open
+  pool's row, which no query scores before the pool's last position has
+  made it the mean;
 - ``kda`` and ``gdn`` layers (the delta-rule mixers): a recurrent matrix
   in float32 (``kda``: ``[S, H, dk, dk]``, a ``gdn`` layer's ``[S, Hv, dk,
   dk]``) and a convolution tail (``conv``: ``[S, K - 1, 3 H dk]``, a
@@ -114,6 +120,8 @@ __all__ = [
 ]
 
 _KV_DTYPES = ("float32", "bfloat16")
+# the per-layer lists of a pool's state beside the K/V pools and rings
+_LISTS = ("latent", "index", "index_open", "kda", "conv", "norm")
 _ALIASES = {"f32": "float32", "bf16": "bfloat16"}
 
 
@@ -224,7 +232,9 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
     ``ring`` (the K and the V ring of the layers with a window, where the
     model keeps one: ``ring_rows``; ``ring=False``: those layers keep
     ``T_max`` rows like the others), ``latent`` (one array an ``mla`` layer),
-    ``index`` (one array an ``mla`` layer with a ``"full"`` indexer),
+    ``index`` (one array an ``mla`` layer with a ``"full"`` indexer, and
+    with pooled keys one more, the open pool's running sum: the keys of all
+    the layers first, then the sums),
     ``recurrent`` (one a ``kda``, ``gdn`` or ``ret`` layer, in the layers'
     order), ``conv`` (one a ``kda`` or ``gdn`` layer) and ``normaliser``
     (one a ``ret`` layer). The last three are float32 or follow ``kv_dtype``
@@ -246,8 +256,12 @@ def pool_layout(model, slots: int, max_len: int, kv_dtype: str,
         out["latent"] = [((slots, max_len, latent_row_width(model)),
                           kv_dtype)] * model.n_layers("mla")
     if model.dsa:
-        out["index"] = [((slots, max_len, model.dsa["head_dim"]),
-                         kv_dtype)] * model.indexers.count("full")
+        pool, full = model.dsa.get("pool", 1), model.indexers.count("full")
+        out["index"] = [((slots, -(-max_len // pool), model.dsa["head_dim"]),
+                         kv_dtype)] * full
+        if pool > 1:
+            out["index"] += [((slots, model.dsa["head_dim"]),
+                              "float32")] * full
     for kind in model.mixers:
         if kind in ("kda", "gdn"):
             h, dk, width = _recurrent_dims(model, kind)
@@ -429,6 +443,10 @@ class SlotKVCache:
             [jnp.zeros(shape, jnp.dtype(dt)) for shape, dt in layout[kind]]
             for kind in ("latent", "index", "recurrent", "conv",
                          "normaliser"))
+        # pooled index keys: the open pools' running sums are a list of
+        # their own in a program's state (``index_open``)
+        full = model.indexers.count("full")
+        self.index, self.index_open = self.index[:full], self.index[full:]
         # the pools' logical axes (L, S, T_max, Hkv, Dh), whichever shape
         # they are stored in
         self.pool_dims, self.ring_dims = _pool_dims(
@@ -488,7 +506,7 @@ class SlotKVCache:
         st = {} if self.k is None else {"k": self.k, "v": self.v}
         if self.kw is not None:
             st.update(kw=self.kw, vw=self.vw)
-        for name in ("latent", "index", "kda", "conv", "norm"):
+        for name in _LISTS:
             if getattr(self, name):
                 st[name] = list(getattr(self, name))
         return st
@@ -499,7 +517,7 @@ class SlotKVCache:
         device memory, not a copy of it."""
         self.k, self.v = state.get("k"), state.get("v")
         self.kw, self.vw = state.get("kw"), state.get("vw")
-        for name in ("latent", "index", "kda", "conv", "norm"):
+        for name in _LISTS:
             setattr(self, name, list(state.get(name, ())))
 
     @property
@@ -517,7 +535,7 @@ class SlotKVCache:
         kinds = [("kv", kv), ("latent", self.latent),
                  ("recurrent", self.kda), ("conv", self.conv)]
         if self.index:      # only a model with an indexer names the kind
-            kinds.insert(2, ("index", self.index))
+            kinds.insert(2, ("index", self.index + self.index_open))
         if self.kw is not None:     # and only one with a ring this one
             kinds.insert(1, ("ring", [self.kw, self.vw]))
         if self.norm:               # and only one with 'ret' layers this one

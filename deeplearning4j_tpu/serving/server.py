@@ -125,7 +125,8 @@ runs):
   ``state_slots``, the slots whose recurrent state the step moves (beside
   ``kv_rows`` 0 where no layer keeps rows a position); for a
   model with learned sparse attention ``keys_cached``, ``keys_attended`` and
-  ``rows_gathered`` instead) — the decode
+  ``rows_gathered`` instead, and with pooled index keys ``pools_scored``,
+  ``pools_selected`` and ``tail_attended`` beside them) — the decode
   dispatch (``live`` slots; 0: none may be owed a token), then the
   read-back of the block dispatched a step earlier, whatever its kind.
   ``experts_touched`` and ``experts_read``, and a round's ``rounds``,
@@ -150,7 +151,7 @@ from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from deeplearning4j_tpu.models.dsa import live_slots
+from deeplearning4j_tpu.models.dsa import live_slots, selection_width
 from deeplearning4j_tpu.monitor import metrics, tracer
 from deeplearning4j_tpu.pallas.decode_attention import (
     key_block_span, pool_block_rows)
@@ -243,6 +244,13 @@ class DecodeServer:
         self.keys_attended = 0
         self.rows_gathered = 0
         self.prefill_blocks = 0
+        # pooled index keys (dsa["pool"]): pools the decode steps' queries
+        # scored and selected, tail positions they attended unscored; prefill
+        # blocks that continued a recurrence ('kda' layers, block > 0)
+        self.pools_scored = 0
+        self.pools_selected = 0
+        self.tail_attended = 0
+        self.recurrence_blocks = 0
         # {slot: [engine.prefill_blocks generator, its blocks, queue wait in
         # us, blocks run]} of the requests whose prompt is part-way into
         # its slot, the next to run first (``_admit_blocks``)
@@ -588,6 +596,8 @@ class DecodeServer:
             blocks = prefill_block_count(
                 prompt_len, self.engine.prompt_bucket(prompt_len))
             self.prefill_blocks += blocks
+            if self.model.kda:  # every block but the first continues one
+                self.recurrence_blocks += blocks - 1
             req.state, req.slot = "running", slot
             self._slot_req[slot] = req
             self._prefilling[slot] = [
@@ -764,11 +774,29 @@ class DecodeServer:
             cached = self._cursors[list(live)] + 1
             topk = min(self.model.dsa["topk"], self.max_len)
             owing = np.isin(np.arange(self.slots), list(live))
-            attrs = {"keys_cached": int(cached.sum()) * layers,
-                     "keys_attended": int(np.minimum(
-                         cached, topk).sum()) * layers,
-                     "rows_gathered": int(live_slots(owing, self.slots)[1])
-                     * topk * layers}
+            pool = self.model.dsa.get("pool", 1)
+            attended = np.minimum(cached, topk)
+            # rows a trip of the program's work list gathers
+            width = selection_width(self.model.dsa, self.max_len)
+            if pool > 1:
+                # a query at cursor c scores the c // pool pools that end
+                # before its own, attends the topk / pool best whole and its
+                # own open pool up to itself; the gather fetches that many
+                # rows and the open pool's others
+                scored = (cached - 1) // pool
+                chosen = np.minimum(scored, topk // pool)
+                tail = (cached - 1) % pool + 1
+                attended = chosen * pool + tail
+                attrs = {"pools_scored": int(scored.sum()) * layers,
+                         "pools_selected": int(chosen.sum()) * layers,
+                         "tail_attended": int(tail.sum()) * layers}
+                self.pools_scored += attrs["pools_scored"]
+                self.pools_selected += attrs["pools_selected"]
+                self.tail_attended += attrs["tail_attended"]
+            attrs.update(keys_cached=int(cached.sum()) * layers,
+                         keys_attended=int(attended.sum()) * layers,
+                         rows_gathered=int(live_slots(owing, self.slots)[1])
+                         * width * layers)
             self.keys_cached += attrs["keys_cached"]
             self.keys_attended += attrs["keys_attended"]
             self.rows_gathered += attrs["rows_gathered"]
@@ -1187,6 +1215,11 @@ class DecodeServer:
                 round(self.keys_attended / self.keys_cached, 4)
                 if self.keys_cached else None)
             out["prefill_blocks"] = self.prefill_blocks
+            if self.model.dsa.get("pool", 1) > 1:
+                out["pools_scored"] = self.pools_scored
+                out["pools_selected"] = self.pools_selected
+                out["tail_attended"] = self.tail_attended
+                out["recurrence_blocks"] = self.recurrence_blocks
         if self.model.num_experts:
             out["moe_expert_load"] = self.moe_expert_load.tolist()
             out["moe_rows"] = self.moe_rows
